@@ -11,10 +11,9 @@ import jax
 
 
 def timed_ms(fn: Callable, *args: Any, warmup: int = 2, repeat: int = 20) -> float:
-    """Mean wall milliseconds per call (see
-    :func:`byzpy_tpu.utils.metrics.timed_call_s` for the tunnel-measurement
-    hazards this defends against)."""
-    from byzpy_tpu.utils.metrics import timed_call_s
+    """Mean wall milliseconds per call
+    (:func:`byzpy_tpu.observability.compat.timed_call_s`)."""
+    from byzpy_tpu.observability.compat import timed_call_s
 
     return timed_call_s(fn, *args, warmup=warmup, repeat=repeat) * 1e3
 
@@ -28,9 +27,8 @@ def report(name: str, ms: float, **extra: Any) -> Dict[str, Any]:
 
 def force_cpu_platform(n_devices: int = 1) -> None:
     """Rebuild jax on the CPU platform in-process (optionally with virtual
-    devices). Env vars are inoperative once a platform is pre-registered
-    (e.g. by a sitecustomize), so the switch goes through jax.config +
-    clear_backends. One copy for every benchmark script;
+    devices), through jax.config + clear_backends so it also works after
+    a backend initialized. One copy for every benchmark script;
     ``__graft_entry__._ensure_devices`` stays self-contained by design
     (the driver runs it without this package on the path)."""
     from jax.extend import backend as jeb

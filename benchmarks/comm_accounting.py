@@ -34,9 +34,9 @@ def main() -> int:
     parser.add_argument("--d", type=int, default=1_000_000, help="model params")
     args = parser.parse_args()
 
-    from byzpy_tpu.utils.platform import apply_env_platform
+    from byzpy_tpu.utils.platform import enable_compile_cache
 
-    apply_env_platform()
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -131,6 +131,15 @@ def main() -> int:
             "compiled round steps (`byzpy_tpu.parallel.comms`), so they are",
             "properties of the artifact XLA actually runs, not estimates.",
             f"Mesh: {n} devices; model: d = {d:,} f32 params.",
+            "",
+            "**These are XLA:CPU's artifacts** (the 8-virtual-device mesh). On four",
+            "v5e chips (PR 21, `chip_smoke.py` phase 5, ResNet-18 d = 11.17M, n = 8)",
+            "the gradient-transpose all-to-all matches the law below exactly, but",
+            "XLA:TPU lowers the params all-gather to a dynamic-update-slice plus an",
+            "all-reduce of the whole d-vector — on a ring, twice the all-gather's",
+            "bytes. Everything below that counts the PS round at `2·d·dtype` per",
+            "device is `3·d·dtype` for that compiler until someone shows otherwise;",
+            "whether it costs time has not been measured (ROADMAP S5).",
             "",
             "| step | wire bytes / device / round | by collective |",
             "|---|---|---|",

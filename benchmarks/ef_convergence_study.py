@@ -80,9 +80,9 @@ def main() -> int:
             os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count=8"
         )
-    from byzpy_tpu.utils.platform import apply_env_platform
+    from byzpy_tpu.utils.platform import enable_compile_cache
 
-    apply_env_platform()
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp

@@ -42,9 +42,9 @@ def main() -> None:
         + f" --xla_force_host_platform_device_count={n}"
     )
     os.environ["JAX_PLATFORMS"] = "cpu"
-    from byzpy_tpu.utils.platform import apply_env_platform
+    from byzpy_tpu.utils.platform import enable_compile_cache
 
-    apply_env_platform()
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp

@@ -11,7 +11,6 @@ produces:
 Matplotlib only; no seaborn, no style deps.
 """
 
-import json
 import os
 import sys
 
@@ -24,19 +23,6 @@ def load_grid(path=None):
         row for row in load_jsonl(path)
         if "ref_best_pool_ms" in row or "ref_direct_ms" in row
     ]
-    # supersede rows with re-measured values (each override carries a
-    # provenance note; see results/overrides.jsonl)
-    override_path = os.path.join(os.path.dirname(path), "overrides.jsonl")
-    if os.path.exists(override_path):
-        by_name = {r["workload"]: i for i, r in enumerate(rows)}
-        with open(override_path) as fh:
-            for line in fh:
-                line = line.strip()
-                if not line:
-                    continue
-                ov = json.loads(line)
-                if ov["workload"] in by_name:
-                    rows[by_name[ov["workload"]]] = ov
     return rows
 
 

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from byzpy_tpu.ops import robust
-from byzpy_tpu.utils.metrics import timed_call_s
+from byzpy_tpu.observability.compat import timed_call_s
 
 
 def main() -> None:
@@ -71,13 +71,13 @@ def main() -> None:
     }, indent=2))
 
     if args.trace:
-        from byzpy_tpu.utils.metrics import force_result, trace
+        from byzpy_tpu.observability.compat import trace
         fn = jax.jit(partial(robust.multi_krum, f=8, q=12))
-        force_result(fn(x))
+        jax.block_until_ready(fn(x))
         with trace(args.trace):
             for _ in range(10):
                 out = fn(x)
-            force_result(out)
+            jax.block_until_ready(out)
         print(f"trace written to {args.trace}")
 
 

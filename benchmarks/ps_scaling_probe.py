@@ -27,9 +27,9 @@ os.environ["XLA_FLAGS"] = (
 )
 os.environ["JAX_PLATFORMS"] = "cpu"
 
-from byzpy_tpu.utils.platform import apply_env_platform
+from byzpy_tpu.utils.platform import enable_compile_cache
 
-apply_env_platform()
+enable_compile_cache()
 
 import jax
 import jax.numpy as jnp

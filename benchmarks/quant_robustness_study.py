@@ -54,9 +54,9 @@ def main() -> int:
     ap.add_argument("--d", type=int, default=None)
     args = ap.parse_args()
 
-    from byzpy_tpu.utils.platform import apply_env_platform
+    from byzpy_tpu.utils.platform import enable_compile_cache
 
-    apply_env_platform()
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp

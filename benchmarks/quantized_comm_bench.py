@@ -16,7 +16,7 @@ are facts about the program XLA runs, not estimates):
    shrink by the same factor.
 3. **steps/sec** of that PS step per mode (on CPU the interconnect is
    memcpy so the win is bytes, not time; on ICI both move together —
-   the on-chip sweep rides ``rerun_round5.sh``).
+   not measured on a chip yet, ROADMAP S5).
 
 A quantize/dequantize round-trip error-bound parity check runs first —
 `--smoke` is the CI leg (small shapes, asserts the ratio floor and the
@@ -65,9 +65,9 @@ def main() -> int:
             os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count=8"
         )
-    from byzpy_tpu.utils.platform import apply_env_platform
+    from byzpy_tpu.utils.platform import enable_compile_cache
 
-    apply_env_platform()
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -81,7 +81,7 @@ def main() -> int:
     from byzpy_tpu.parallel.comms import collective_traffic
     from byzpy_tpu.parallel.mesh import node_mesh, sharding
     from byzpy_tpu.parallel.ps import PSStepConfig, build_ps_train_step
-    from byzpy_tpu.utils.metrics import timed_call_s
+    from byzpy_tpu.observability.compat import timed_call_s
 
     platform = jax.default_backend()
     d = args.d or (8_192 if args.smoke else 262_144)

@@ -83,9 +83,9 @@ def main() -> int:
         parser.error("--grad-dtype is a PS-mode knob (gossip exchanges "
                      "parameters, not gradients)")
 
-    from byzpy_tpu.utils.platform import apply_env_platform
+    from byzpy_tpu.utils.platform import enable_compile_cache
 
-    apply_env_platform()
+    enable_compile_cache()
 
     import jax
 

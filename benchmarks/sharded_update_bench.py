@@ -63,9 +63,9 @@ def main() -> int:
             os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count=8"
         )
-    from byzpy_tpu.utils.platform import apply_env_platform
+    from byzpy_tpu.utils.platform import enable_compile_cache
 
-    apply_env_platform()
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -91,7 +91,7 @@ def main() -> int:
         ShardedUpdateConfig,
         build_ps_train_step,
     )
-    from byzpy_tpu.utils.metrics import timed_call_s
+    from byzpy_tpu.observability.compat import timed_call_s
 
     platform = jax.default_backend()
     n_dev = 2 if args.smoke else min(8, len(jax.devices()))

@@ -1521,7 +1521,7 @@ class MetricContractRule(Rule):
             node,
             f"span label {name!r} is not in the observability catalog — "
             "add it to byzpy_tpu/observability/catalog.py and the "
-            "docs/observability.md span taxonomy",
+            "docs/observability.md span catalog",
         )
 
 

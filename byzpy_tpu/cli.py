@@ -52,43 +52,6 @@ def cmd_version(_args: argparse.Namespace) -> int:
     return 0
 
 
-def _devices_with_timeout(jax_mod, timeout_s: float = 20.0):
-    """``jax.devices()`` bounded by a timeout: platform plugins that dial a
-    remote accelerator (e.g. a tunneled TPU) can block indefinitely when
-    the link is down, and a diagnostics command must degrade, not hang.
-    The probe thread is daemonic — if it never returns it dies with the
-    process. Override via BYZPY_TPU_DOCTOR_TIMEOUT (seconds)."""
-    import os
-    import threading
-
-    try:
-        timeout_s = float(os.environ.get("BYZPY_TPU_DOCTOR_TIMEOUT", timeout_s))
-    except ValueError:
-        pass  # malformed override (e.g. "20s"): keep the default
-    result: list = []
-
-    def probe() -> None:
-        try:
-            result.append(("ok", jax_mod.devices()))
-        except Exception as exc:  # noqa: BLE001 — forwarded to caller
-            result.append(("err", exc))
-
-    # plain daemon thread: a ThreadPoolExecutor worker is non-daemonic and
-    # its atexit join would hang interpreter shutdown on a stuck probe
-    t = threading.Thread(target=probe, name="doctor-device-probe", daemon=True)
-    t.start()
-    t.join(timeout_s)
-    if not result:
-        raise TimeoutError(
-            f"device platform did not initialize within {timeout_s:g}s "
-            "(accelerator link down?)"
-        )
-    kind, value = result[0]
-    if kind == "err":
-        raise value
-    return value
-
-
 def doctor_report() -> Dict[str, Any]:
     """Environment probe (ref: ``byzpy doctor``, cli.py:38-74)."""
     report: Dict[str, Any] = {"version": __version__, "python": sys.version.split()[0]}
@@ -97,21 +60,23 @@ def doctor_report() -> Dict[str, Any]:
 
         report["jax"] = {"version": jax.__version__, "ok": True}
         try:
-            devices = _devices_with_timeout(jax)
+            devices = jax.devices()
+        except RuntimeError as exc:  # backend failed to initialize
+            report["devices_error"] = repr(exc)
+        else:
             report["devices"] = [
                 {
                     "id": d.id,
                     "platform": d.platform,
-                    "kind": getattr(d, "device_kind", "?"),
-                    "process": getattr(d, "process_index", 0),
+                    "kind": d.device_kind,
+                    "process": d.process_index,
                 }
                 for d in devices
             ]
             report["default_backend"] = jax.default_backend()
+            report["device_kind"] = devices[0].device_kind
             report["device_count"] = len(devices)
             report["process_count"] = jax.process_count()
-        except Exception as exc:  # noqa: BLE001 — report, don't crash doctor
-            report["devices_error"] = repr(exc)
     except Exception as exc:  # noqa: BLE001
         report["jax"] = {"ok": False, "error": repr(exc)}
     for mod in ("flax", "optax", "cloudpickle"):
@@ -152,21 +117,21 @@ def bench_report(*, n: int = 16, d: int = 65_536, repeat: int = 10) -> Dict[str,
     """Quick on-device micro-benchmark of the hot aggregators (one JSON
     row per op, milliseconds per call) — the sanity companion to
     ``doctor``: is this device delivering the expected order of
-    magnitude? Full methodology and the measured grid live in
-    ``benchmarks/`` (this uses the same chained-timing helper)."""
+    magnitude? The report names the device it ran on; a device or
+    compile failure raises (no ``{"error": ...}`` with exit 0)."""
     import jax
     import jax.numpy as jnp
 
     from .ops import robust
     from .observability.compat import timed_call_s
 
-    try:
-        devices = _devices_with_timeout(jax)
-    except Exception as exc:  # noqa: BLE001 — report, don't hang/crash bench
-        return {"error": f"device probe failed: {type(exc).__name__}: {exc}"}
+    devices = jax.devices()
     x = jax.random.normal(jax.random.PRNGKey(0), (n, d), jnp.float32)
     rows: Dict[str, Any] = {
         "device": str(devices[0]),
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
         "shape": [n, d],
         "repeat": repeat,
     }
@@ -180,11 +145,8 @@ def bench_report(*, n: int = 16, d: int = 65_536, repeat: int = 10) -> Dict[str,
         "geometric_median": partial(robust.geometric_median, max_iter=32),
     }
     for name, fn in ops.items():
-        try:
-            ms = timed_call_s(jax.jit(fn), x, warmup=2, repeat=repeat) * 1e3
-            rows[name] = {"ms": round(ms, 3)}
-        except Exception as exc:  # noqa: BLE001 — report, don't crash bench
-            rows[name] = {"error": f"{type(exc).__name__}: {exc}"}
+        ms = timed_call_s(jax.jit(fn), x, warmup=2, repeat=repeat) * 1e3
+        rows[name] = {"ms": round(ms, 3)}
     return rows
 
 

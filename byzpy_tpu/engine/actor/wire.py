@@ -232,7 +232,7 @@ def _np_blockwise_encode(
 def _code_values_f32(codes: np.ndarray, mode: str) -> np.ndarray:
     """Decoded f32 code values BEFORE the per-block scale multiply —
     the expensive half of a blockwise decode (the fp8 bit-pattern cast
-    alone is ~57 % of that mode's decode; ``ROUND19_NOTES.md``), shared
+    alone is ~57 % of that mode's decode on one CPU core, PR 19), shared
     by dequantization and the pre-decode inflation forensics so
     :func:`decode_with_stats` converts each frame's codes exactly once.
     Per-frame analogue of :func:`_rows_code_values`."""

@@ -392,16 +392,7 @@ class DecentralizedPeerToPeer:
                 task.cancel()
             except asyncio.CancelledError:
                 cur = asyncio.current_task()
-                # Task.cancelling() is 3.11+; on 3.10 there is no way to
-                # distinguish "the awaited removal task was cancelled
-                # elsewhere" from "shutdown itself was cancelled", so
-                # treat the CancelledError as aimed at us and propagate
-                # (the conservative reading — a swallowed cancellation
-                # would break caller timeouts).
-                cancelling = getattr(cur, "cancelling", None)
-                if cur is not None and (
-                    cancelling is None or cancelling() > 0
-                ):
+                if cur is not None and cur.cancelling() > 0:
                     # shutdown ITSELF was cancelled — don't swallow it;
                     # drop pending removals and let cancellation propagate
                     for t in self._removal_tasks:

@@ -45,7 +45,7 @@ _ACTIVE: "weakref.WeakSet[SLOWatchdog]" = weakref.WeakSet()
 @dataclass(frozen=True)
 class BurnRatePolicy:
     """Multiwindow burn-rate alerting pair (the SRE-workbook
-    convention ROUND13_NOTES.md queued): an objective breaches only
+    convention): an objective breaches only
     when the burn exceeds ``burn_threshold`` over BOTH the short and
     the long window — the long window proves the budget spend is
     significant, the short window proves it is still happening (no

@@ -36,28 +36,16 @@ from .quantization import (
 
 _FP8_MODES = ("fp8", "fp8_e5m2")
 
-try:
-    from jax import shard_map  # jax >= 0.8 (replication check kw: check_vma)
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover — older jax (kw: check_rep)
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_CHECK_KW = "check_rep"
+from jax import shard_map
 
 Array = jnp.ndarray
 
 
 def axis_size(axis_name: str) -> int:
-    """Static size of the named mesh axis, inside ``shard_map``/``pmap``.
-
-    ``lax.axis_size`` where the jax build ships it; otherwise
-    ``psum(1, axis)``, which constant-folds to a Python int at trace time
-    (the axis extent is static). Every in-SPMD helper in this package
-    resolves the axis through here so one jax rename can't strand them.
-    """
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of the named mesh axis, inside ``shard_map``/``pmap``
+    (``lax.axis_size``). Every in-SPMD helper in this package resolves
+    the axis through here."""
+    return lax.axis_size(axis_name)
 
 
 # ---------------------------------------------------------------------------
@@ -583,7 +571,7 @@ def sharded_fn(
     out_spec = out_spec if out_spec is not None else default_out
     mapped = shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
-        **{_SHARD_MAP_CHECK_KW: False},
+        check_vma=False,
     )
     return jax.jit(mapped)
 

@@ -40,11 +40,15 @@ _COLLECTIVES = (
 )
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-# matches sync collectives AND the -start half of async pairs (TPU HLO
-# lowers to all-reduce-start/-done etc.); the -done twin repeats the
-# shape and is excluded so nothing double-counts
+# matches sync collectives AND the -start half of async pairs; the -done
+# twin repeats the shape and is excluded so nothing double-counts. The
+# result shape is whatever lies between "= " and the opcode: TPU layouts
+# carry parentheses of their own (`f32[11173964]{0:T(1024)S(1)}`), so a
+# tuple shape cannot be matched as one balanced group — that form made
+# every tuple-shaped collective of a TPU program invisible (PR 21: the
+# d-sized all-reduce XLA:TPU lowers the params gather to).
 _INSTR_RE = re.compile(
-    r"=\s*(\([^)]*\)|\S+)\s+(" + "|".join(_COLLECTIVES) + r")(-start)?\(",
+    r"=\s*(.*?)\s+(" + "|".join(_COLLECTIVES) + r")(-start)?\(",
 )
 _ENTRY_RE = re.compile(r"^ENTRY\s")
 _COMPUTATION_RE = re.compile(r"^%?\S+\s*(?:\([^)]*\))?\s*->.*\{\s*$|^ENTRY\s")

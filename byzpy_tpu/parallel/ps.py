@@ -558,7 +558,7 @@ def build_serving_ps_step(
     def step(params, opt_state, matrix, valid, weights):
         # named_scope = the in-jit analogue of the host tracing spans:
         # the stage names land in HLO op metadata, so an XLA device
-        # profile shows the same serving.* stage taxonomy as the host
+        # profile shows the same serving.* stage names as the host
         # timeline (docs/observability.md)
         with jax.named_scope("serving.staleness_scale"):
             # staleness discount: scale each row before the robust

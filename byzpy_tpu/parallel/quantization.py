@@ -24,9 +24,12 @@ tier of that fabric:
   scaled fp8 (``e4m3fn``/``e5m2`` — the per-block scale centers the
   format's dynamic range, so the mantissa spends its bits on relative
   accuracy) and packed s4 (two symmetric 4-bit codes per byte, half
-  the int8 payload). Same non-finite guards; Pallas kernels exist but
-  the XLA fallback is authoritative until the on-chip Mosaic parity
-  capture (``BYZPY_TPU_SUBINT8_PALLAS=1`` opt-in, ROUND15_NOTES.md).
+  the int8 payload). Same non-finite guards; the XLA codec is the
+  default, the Pallas kernels an opt-in
+  (``BYZPY_TPU_SUBINT8_PALLAS=1``). On a v5e (PR 21) the fp8 kernels
+  compile; e5m2 codes are bit-equal to XLA's, e4m3 codes at most one
+  code apart (XLA's f32->f8 convert double-rounds through f16), decode
+  bit-equal. The s4 kernels do not lower and raise (ROADMAP S4).
 * :func:`ef_encode` — per-round **error feedback**: fold the previous
   round's quantization residual into this round's payload so the
   transmitted stream telescopes (compression stops compounding; the
@@ -253,6 +256,31 @@ def _auto_quant_tile(
     return min(d_pad, max(block, min(8192 // block * block or block, per_row)))
 
 
+def _whole_blocks_tile(tile: int, block: int) -> int:
+    """``tile`` cut to a whole number of quantization blocks, at most the
+    128 blocks whose scales fill one lane group (:func:`_scales_lane_dense`)."""
+    return min(max(block, tile // block * block), _LANES * block)
+
+
+def _scales_lane_dense(scales: Array, bpt: int) -> Array:
+    """``(rows, n_tiles * bpt)`` per-block scales -> ``(rows, n_tiles *
+    128)``: each feature tile's ``bpt`` scales in the first lanes of a
+    128-lane group. Mosaic only takes blocks whose last dim is a
+    multiple of 128 (or the whole array), and ``bpt`` — a tile's worth
+    of scales, 16-32 — is neither; the kernels address scale ``j`` of
+    the tile as lane ``j`` of its group."""
+    rows = scales.shape[0]
+    grouped = scales.reshape(rows, -1, bpt)
+    grouped = jnp.pad(grouped, ((0, 0), (0, 0), (0, _LANES - bpt)))
+    return grouped.reshape(rows, -1)
+
+
+def _scales_compact(dense: Array, bpt: int) -> Array:
+    """Inverse of :func:`_scales_lane_dense`."""
+    rows = dense.shape[0]
+    return dense.reshape(rows, -1, _LANES)[:, :, :bpt].reshape(rows, -1)
+
+
 def _quantize_kernel(x_ref, v_ref, s_ref, *, block: int, blocks_per_tile: int):
     """Quantize one (rows, tile) VMEM block: per-(row, block) absmax ->
     f32 scale -> round-to-nearest int8. The block loop is unrolled at
@@ -300,7 +328,9 @@ def _quantize_pallas_call(
         functools.partial(_quantize_kernel, block=block, blocks_per_tile=bpt),
         out_shape=(
             jax.ShapeDtypeStruct((rows_pad, d_pad), jnp.int8),
-            jax.ShapeDtypeStruct((rows_pad, nb_pad), jnp.float32),
+            jax.ShapeDtypeStruct(
+                (rows_pad, (d_pad // tile) * _LANES), jnp.float32
+            ),
         ),
         grid=(d_pad // tile,),
         in_specs=[
@@ -308,12 +338,14 @@ def _quantize_pallas_call(
         ],
         out_specs=(
             pl.BlockSpec((rows_pad, tile), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((rows_pad, bpt), lambda i: (0, i), memory_space=pltpu.VMEM),
+            pl.BlockSpec(
+                (rows_pad, _LANES), lambda i: (0, i), memory_space=pltpu.VMEM
+            ),
         ),
         interpret=interpret,
     )(xp)
     nb = -(-d // block)
-    return values[:rows, :d], scales[:rows, :nb]
+    return values[:rows, :d], _scales_compact(scales, bpt)[:rows, :nb]
 
 
 @functools.partial(
@@ -338,13 +370,15 @@ def _dequantize_pallas_call(
         grid=(d_pad // tile,),
         in_specs=[
             pl.BlockSpec((rows_pad, tile), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((rows_pad, bpt), lambda i: (0, i), memory_space=pltpu.VMEM),
+            pl.BlockSpec(
+                (rows_pad, _LANES), lambda i: (0, i), memory_space=pltpu.VMEM
+            ),
         ],
         out_specs=pl.BlockSpec(
             (rows_pad, tile), lambda i: (0, i), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
-    )(vp, sp)
+    )(vp, _scales_lane_dense(sp, bpt))
     return out[:rows, :d].astype(dtype)
 
 
@@ -383,10 +417,10 @@ def _quantize_xla(
 def _subint8_pallas_default() -> bool:
     """Pre-trace dispatch default for the sub-int8 Pallas kernels: on
     TPU AND explicitly opted in (``BYZPY_TPU_SUBINT8_PALLAS=1``). The
-    XLA fallback stays authoritative until the queued on-chip sweep
-    (ROUND15_NOTES.md) validates Mosaic bit parity for the f8 casts and
-    the nibble packing — the same conservative stance the ragged door
-    took (``BYZPY_TPU_RAGGED_PALLAS``)."""
+    XLA codec stays the default: the kernels have compiled and agreed
+    on a chip (fp8; the module docstring has the details) but have no
+    chip time yet, and a kernel that wins nothing is removed, not
+    defaulted (ROADMAP S4/D5)."""
     import os
 
     from ..ops.pallas_kernels import _on_tpu
@@ -553,7 +587,9 @@ def _quantize_fp8_pallas_call(
         ),
         out_shape=(
             jax.ShapeDtypeStruct((rows_pad, d_pad), jnp.uint8),
-            jax.ShapeDtypeStruct((rows_pad, nb_pad), jnp.float32),
+            jax.ShapeDtypeStruct(
+                (rows_pad, (d_pad // tile) * _LANES), jnp.float32
+            ),
         ),
         grid=(d_pad // tile,),
         in_specs=[
@@ -561,7 +597,9 @@ def _quantize_fp8_pallas_call(
         ],
         out_specs=(
             pl.BlockSpec((rows_pad, tile), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((rows_pad, bpt), lambda i: (0, i), memory_space=pltpu.VMEM),
+            pl.BlockSpec(
+                (rows_pad, _LANES), lambda i: (0, i), memory_space=pltpu.VMEM
+            ),
         ),
         interpret=interpret,
     )(xp)
@@ -570,7 +608,7 @@ def _quantize_fp8_pallas_call(
 
     return (
         _lax.bitcast_convert_type(values[:rows, :d], fp_dtype),
-        scales[:rows, :nb],
+        _scales_compact(scales, bpt)[:rows, :nb],
     )
 
 
@@ -589,7 +627,9 @@ def _quantize_s4_pallas_call(
         functools.partial(_quantize_s4_kernel, block=block, blocks_per_tile=bpt),
         out_shape=(
             jax.ShapeDtypeStruct((rows_pad, d_pad // 2), jnp.uint8),
-            jax.ShapeDtypeStruct((rows_pad, nb_pad), jnp.float32),
+            jax.ShapeDtypeStruct(
+                (rows_pad, (d_pad // tile) * _LANES), jnp.float32
+            ),
         ),
         grid=(d_pad // tile,),
         in_specs=[
@@ -599,13 +639,15 @@ def _quantize_s4_pallas_call(
             pl.BlockSpec(
                 (rows_pad, tile // 2), lambda i: (0, i), memory_space=pltpu.VMEM
             ),
-            pl.BlockSpec((rows_pad, bpt), lambda i: (0, i), memory_space=pltpu.VMEM),
+            pl.BlockSpec(
+                (rows_pad, _LANES), lambda i: (0, i), memory_space=pltpu.VMEM
+            ),
         ),
         interpret=interpret,
     )(xp)
     nb = -(-d // block)
     d_blocks_pad = nb * block // 2
-    return values[:rows, :d_blocks_pad], scales[:rows, :nb]
+    return values[:rows, :d_blocks_pad], _scales_compact(scales, bpt)[:rows, :nb]
 
 
 @functools.partial(
@@ -635,13 +677,15 @@ def _dequantize_s4_pallas_call(
             pl.BlockSpec(
                 (rows_pad, tile // 2), lambda i: (0, i), memory_space=pltpu.VMEM
             ),
-            pl.BlockSpec((rows_pad, bpt), lambda i: (0, i), memory_space=pltpu.VMEM),
+            pl.BlockSpec(
+                (rows_pad, _LANES), lambda i: (0, i), memory_space=pltpu.VMEM
+            ),
         ],
         out_specs=pl.BlockSpec(
             (rows_pad, tile), lambda i: (0, i), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
-    )(vp, sp)
+    )(vp, _scales_lane_dense(sp, bpt))
     return out[:rows, :d].astype(dtype)
 
 
@@ -692,15 +736,14 @@ def quantize_blockwise(
 
         use_pallas = _on_tpu() and not stochastic
     if use_pallas and not stochastic:
-        if interpret is None:
-            from ..ops.pallas_kernels import _on_tpu
+        from ..ops.pallas_kernels import _resolve_interpret
 
-            interpret = not _on_tpu()
+        interpret = _resolve_interpret(interpret)
         rows_pad = max(_SUBLANES, -(-rows // _SUBLANES) * _SUBLANES)
         d_pad = -(-d // block) * block
         if tile is None:
             tile = _auto_quant_tile(rows_pad, d_pad, block)
-        tile = max(block, tile // block * block)
+        tile = _whole_blocks_tile(tile, block)
         values, scales = _quantize_pallas_call(
             x2d, block=block, tile=tile, interpret=interpret
         )
@@ -756,10 +799,9 @@ def dequantize_blockwise(
 
             use_pallas = _on_tpu()
     if use_pallas:
-        if interpret is None:
-            from ..ops.pallas_kernels import _on_tpu
+        from ..ops.pallas_kernels import _resolve_interpret
 
-            interpret = not _on_tpu()
+        interpret = _resolve_interpret(interpret)
         rows_pad = max(_SUBLANES, -(-rows // _SUBLANES) * _SUBLANES)
         d_pad = -(-d // block) * block
         if tile is None:
@@ -767,7 +809,7 @@ def dequantize_blockwise(
                 rows_pad, d_pad, block,
                 family="quant_fp8" if sub8 else "quant",
             )
-        tile = max(block, tile // block * block)
+        tile = _whole_blocks_tile(tile, block)
         out = _dequantize_pallas_call(
             v2d, s2d, block=block, tile=tile, interpret=interpret,
             dtype=out_dtype,
@@ -801,15 +843,19 @@ def _dequantize_s4(
     if use_pallas is None:
         use_pallas = _subint8_pallas_default()
     if use_pallas:
-        if interpret is None:
-            from ..ops.pallas_kernels import _on_tpu
+        from ..ops.pallas_kernels import (
+            _resolve_interpret,
+            s4_kernels_unsupported,
+        )
 
-            interpret = not _on_tpu()
+        interpret = _resolve_interpret(interpret)
+        if not interpret:
+            raise s4_kernels_unsupported("uint8 -> float32")
         rows_pad = max(_SUBLANES, -(-rows // _SUBLANES) * _SUBLANES)
         d_pad = -(-d // block) * block
         if tile is None:
             tile = _auto_quant_tile(rows_pad, d_pad, block, family="quant_s4")
-        tile = max(block, tile // block * block)
+        tile = _whole_blocks_tile(tile, block)
         out = _dequantize_s4_pallas_call(
             v2d, s2d, block=block, tile=tile, interpret=interpret,
             d=d, dtype=dtype,
@@ -874,16 +920,20 @@ def encode_blockwise(
     if use_pallas is None:
         use_pallas = _subint8_pallas_default() and not p.stochastic
     if use_pallas and not p.stochastic:
-        if interpret is None:
-            from ..ops.pallas_kernels import _on_tpu
+        from ..ops.pallas_kernels import (
+            _resolve_interpret,
+            s4_kernels_unsupported,
+        )
 
-            interpret = not _on_tpu()
+        interpret = _resolve_interpret(interpret)
+        if p.mode == "s4" and not interpret:
+            raise s4_kernels_unsupported("float32 -> uint8")
         rows_pad = max(_SUBLANES, -(-rows // _SUBLANES) * _SUBLANES)
         d_pad = -(-d // p.block) * p.block
         family = "quant_s4" if p.mode == "s4" else "quant_fp8"
         if tile is None:
             tile = _auto_quant_tile(rows_pad, d_pad, p.block, family=family)
-        tile = max(p.block, tile // p.block * p.block)
+        tile = _whole_blocks_tile(tile, p.block)
         if p.mode == "s4":
             values, scales = _quantize_s4_pallas_call(
                 x2d, block=p.block, tile=tile, interpret=interpret

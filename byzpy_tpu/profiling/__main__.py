@@ -22,9 +22,9 @@ import sys
 
 def main(argv=None) -> int:
     """Entry point (``python -m byzpy_tpu.profiling``)."""
-    from ..utils.platform import apply_env_platform
+    from ..utils.platform import enable_compile_cache
 
-    apply_env_platform()
+    enable_compile_cache()
 
     ap = argparse.ArgumentParser(
         prog="byzpy_tpu.profiling",
@@ -42,8 +42,8 @@ def main(argv=None) -> int:
     ap.add_argument("--force", action="store_true",
                     help="re-sweep even on cache hits")
     ap.add_argument("--cache", default=None,
-                    help="tile cache path (default: BYZPY_TPU_TUNE_CACHE "
-                         "or ~/.cache/byzpy_tpu/tiles.json)")
+                    help="tile cache path (default: BYZPY_TPU_TUNE_CACHE; "
+                         "one of the two is required to store a sweep)")
     args = ap.parse_args(argv)
 
     if args.autotune:

@@ -27,8 +27,8 @@ A sweep is skipped when the cache already holds a valid entry for the
 (family, platform, shape) key (pass ``force=True`` to re-measure). Off
 TPU the kernels run in interpret mode: the sweep machinery still works —
 that is what the cache/override tests exercise — but interpret-mode
-timings say nothing about Mosaic, so on-chip re-tunes go through
-``benchmarks/rerun_round5.sh``.
+timings say nothing about Mosaic; a tune that changes dispatch is a
+chip run (ROADMAP D4/D5).
 """
 
 from __future__ import annotations

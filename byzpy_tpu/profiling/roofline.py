@@ -121,30 +121,32 @@ def calibrate_cpu() -> HardwareSpec:
 
 
 def detect_hardware(calibrate: bool = False) -> HardwareSpec:
-    """Spec for jax's default device: known-chip table by ``device_kind``,
-    env overrides (``BYZPY_TPU_MEM_GBPS`` / ``BYZPY_TPU_PEAK_GFLOPS_*``)
-    applied on top. On CPU, ``calibrate=True`` micro-benchmarks the host
-    (preferred for real profiling runs); otherwise a labeled conservative
-    default is used."""
+    """Spec for jax's default device: the known-chip table keyed by
+    ``device_kind``, env overrides (``BYZPY_TPU_MEM_GBPS`` /
+    ``BYZPY_TPU_PEAK_GFLOPS_*``) applied on top. An accelerator whose
+    ``device_kind`` is not in the table raises — a roofline share
+    against another chip's peaks is not a number. On CPU,
+    ``calibrate=True`` micro-benchmarks the host (preferred for real
+    profiling runs); otherwise a labeled conservative default is used."""
     import jax
 
     dev = jax.devices()[0]
-    kind = getattr(dev, "device_kind", "") or ""
-    if dev.platform == "tpu":
-        for marker, spec in _KNOWN.items():
-            if marker in kind.lower():
-                return _env_overrides(spec)
+    if dev.platform == "cpu":
+        if calibrate:
+            return _env_overrides(calibrate_cpu())
         return _env_overrides(
-            HardwareSpec(f"tpu-unknown({kind})", 819.0,
-                         {"float32": 49_250.0, "bfloat16": 197_000.0},
+            HardwareSpec("cpu-default", 30.0,
+                         {"float32": 100.0, "bfloat16": 100.0},
                          source="default")
         )
-    if dev.platform == "cpu" and calibrate:
-        return _env_overrides(calibrate_cpu())
-    return _env_overrides(
-        HardwareSpec(f"{dev.platform}-default", 30.0,
-                     {"float32": 100.0, "bfloat16": 100.0},
-                     source="default")
+    kind = dev.device_kind
+    for marker, spec in _KNOWN.items():
+        if marker in kind.lower():
+            return _env_overrides(spec)
+    raise ValueError(
+        f"no published peaks for {dev.platform} device_kind {kind!r}; add "
+        f"it to profiling.roofline._KNOWN with its source (known markers: "
+        f"{sorted(_KNOWN)})"
     )
 
 

@@ -11,10 +11,12 @@ falling back to their analytic defaults. Resolution order everywhere is
 2. this cache, keyed ``(family, platform, n, d)``,
 3. the in-code heuristic.
 
-The cache file is plain JSON (default ``~/.cache/byzpy_tpu/tiles.json``,
-override with ``BYZPY_TPU_TUNE_CACHE``). Robustness contract, pinned by
+The cache file is plain JSON at the path ``BYZPY_TPU_TUNE_CACHE`` names.
+There is no default location: with the variable unset no file is read,
+so state outside the checkout never changes which kernel is compiled and
+every machine resolves the same tiles. Robustness contract, pinned by
 ``tests/test_autotune_cache.py``: a missing, corrupt, or stale file —
-and any individual entry that fails validation — silently degrades to
+and any individual entry that fails validation — degrades to
 the heuristic; the cache can never crash a dispatch. This module is
 stdlib-only so the kernels' lazy import of it costs nothing.
 """
@@ -28,9 +30,6 @@ import threading
 from typing import Any, Dict, Optional
 
 _ENV_CACHE_PATH = "BYZPY_TPU_TUNE_CACHE"
-_DEFAULT_PATH = os.path.join(
-    os.path.expanduser("~"), ".cache", "byzpy_tpu", "tiles.json"
-)
 
 # (path, mtime) -> parsed dict; guarded by _LOCK. Reload on mtime change
 # so a sweep in the same process is visible to later dispatches.
@@ -43,10 +42,10 @@ LANE = 128
 MAX_TILE = 1 << 16
 
 
-def cache_path() -> str:
-    """Resolved cache file path (``BYZPY_TPU_TUNE_CACHE`` or the default
-    under ``~/.cache/byzpy_tpu``)."""
-    return os.environ.get(_ENV_CACHE_PATH) or _DEFAULT_PATH
+def cache_path() -> Optional[str]:
+    """The cache file ``BYZPY_TPU_TUNE_CACHE`` names, or ``None`` (no
+    cache: lookups miss, stores need an explicit ``path``)."""
+    return os.environ.get(_ENV_CACHE_PATH) or None
 
 
 def valid_tile(tile: Any) -> bool:
@@ -90,8 +89,9 @@ def _load(path: str) -> Dict[str, Any]:
 
 
 def load_cache(path: Optional[str] = None) -> Dict[str, Any]:
-    """Parsed cache contents (``{}`` for a missing or corrupt file)."""
-    return dict(_load(path or cache_path()))
+    """Parsed cache contents (``{}`` for no, a missing or a corrupt file)."""
+    path = path or cache_path()
+    return dict(_load(path)) if path else {}
 
 
 def lookup(
@@ -99,9 +99,10 @@ def lookup(
 ) -> Optional[int]:
     """Tuned tile for ``(family, platform, n, d)``, or ``None`` when no
     valid entry exists (missing key, corrupt file, failed validation)."""
-    entry = _load(path or cache_path()).get(
-        cache_key(family, platform=platform, n=n, d=d)
-    )
+    path = path or cache_path()
+    if path is None:
+        return None
+    entry = _load(path).get(cache_key(family, platform=platform, n=n, d=d))
     if isinstance(entry, dict):
         tile = entry.get("tile")
         return tile if valid_tile(tile) else None
@@ -124,6 +125,10 @@ def store(
     if not valid_tile(tile):
         raise ValueError(f"refusing to cache invalid tile {tile!r}")
     path = path or cache_path()
+    if path is None:
+        raise ValueError(
+            f"no tile cache file: pass path= or set {_ENV_CACHE_PATH}"
+        )
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     with _LOCK:
         try:

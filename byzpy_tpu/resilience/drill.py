@@ -49,6 +49,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..utils.platform import compile_cache_dir
+
 #: Above the wire codec's WIRE_QUANT_MIN_SIZE floor, so the EF-residual
 #: leg's s4 downlink actually quantizes (a smaller dim would ride the
 #: lossless small-array path and the residual invariants would be
@@ -143,6 +145,7 @@ class _Server:
         self.directory = directory
         env = dict(os.environ)
         env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir()
         env["BYZPY_TPU_TELEMETRY"] = "1"
         self.proc = subprocess.Popen(
             [sys.executable, "-m", "byzpy_tpu.resilience.drill",

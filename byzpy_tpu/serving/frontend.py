@@ -1738,6 +1738,9 @@ class ServingFrontend:
                     vec = t.executor.aggregate(cohort)
                     prep = None
             except Exception:  # noqa: BLE001 — same contract as the scheduler
+                _LOG.exception(
+                    "round %d of tenant %s failed", t.round_id, t.cfg.name
+                )
                 self._fail_round(t, cohort, subs)
                 return None
             return (

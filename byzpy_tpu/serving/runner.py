@@ -99,6 +99,7 @@ from ..observability import runtime as obs_runtime
 from ..observability import tracing as obs_tracing
 from ..resilience.durable import DurabilityConfig
 from ..resilience.retry import RetryPolicy
+from ..utils.platform import compile_cache_dir
 from .frontend import LOSSLESS_REPLY, TenantConfig
 from .sharded import (
     MergeTopology,
@@ -1487,6 +1488,9 @@ class Runner:
     def _spawn(self, role: str, index: int, extra: List[str]) -> _Child:
         env = dict(os.environ)
         env.setdefault("JAX_PLATFORMS", "cpu")
+        # children share the checkout's compile cache (never a directory
+        # under the runner's temporary workdir: the path is part of the key)
+        env["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir()
         if self.spec.telemetry:
             env["BYZPY_TPU_TELEMETRY"] = "1"
         proc = subprocess.Popen(

@@ -2,8 +2,8 @@
 
 Lazy re-exports: submodules here (checkpoint, metrics, training) import
 jax at module import time, but some consumers — example launcher
-processes, ``utils.platform`` callers racing a plugin sitecustomize —
-must be importable before/without the jax backend. Mirrors the lazy
+processes, ``utils.platform`` callers that run before the first backend
+touch — must be importable before/without the jax backend. Mirrors the lazy
 ``__getattr__`` pattern of the top-level package.
 """
 

@@ -4,8 +4,8 @@
 The seed-era :class:`MetricsLogger`/:class:`StepTimer` now live in the
 telemetry subsystem and publish into its process-wide metrics registry
 (``byzpy_logged_<key>`` gauges, the ``byzpy_step_seconds`` histogram)
-while keeping their exact public behavior; :func:`trace`,
-:func:`force_result` and :func:`timed_call_s` moved with them. This
+while keeping their exact public behavior; :func:`trace` and
+:func:`timed_call_s` moved with them. This
 module re-exports everything so existing imports keep working, and
 will be removed in a future major version — import from
 ``byzpy_tpu.observability`` instead.
@@ -18,7 +18,6 @@ import warnings
 from ..observability.compat import (  # noqa: F401 — re-exports
     MetricsLogger,
     StepTimer,
-    force_result,
     timed_call_s,
     trace,
 )
@@ -30,4 +29,4 @@ warnings.warn(
     stacklevel=2,
 )
 
-__all__ = ["MetricsLogger", "trace", "StepTimer", "force_result", "timed_call_s"]
+__all__ = ["MetricsLogger", "trace", "StepTimer", "timed_call_s"]

@@ -29,10 +29,9 @@ sys.path.insert(0, REPO)
 
 
 def worker(coordinator: str, num_processes: int, process_id: int) -> None:
-    # Platform choice must precede any jax backend touch — and must go
-    # through jax.config, not the environment: a sitecustomize (or any
-    # earlier import) may already have imported jax, after which env vars
-    # are ignored. One CPU device per process plays one chip per host.
+    # Platform choice must precede any jax backend touch; jax.config pins
+    # it whatever the environment says. One CPU device per process plays
+    # one chip per host.
     import jax
 
     jax.config.update("jax_platforms", "cpu")

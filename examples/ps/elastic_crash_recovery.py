@@ -24,9 +24,9 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 
-from byzpy_tpu.utils.platform import apply_env_platform
+from byzpy_tpu.utils.platform import enable_compile_cache
 
-apply_env_platform()
+enable_compile_cache()
 
 import numpy as np
 

@@ -2,7 +2,7 @@
 
 Instruments drifting from the observability catalog: an uncatalogued
 metric name, a catalogued name registered under the wrong type, and a
-span label the taxonomy has never heard of.
+span label the span catalog has never heard of.
 """
 
 from byzpy_tpu.observability import tracing
@@ -17,6 +17,6 @@ def register(reg):
 
 
 def run_phase(payload):
-    # finding: span label missing from the taxonomy
+    # finding: span label missing from the span catalog
     with tracing.span("serving.bogus_phase", tenant="t0"):
         return payload

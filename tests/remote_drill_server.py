@@ -57,7 +57,7 @@ async def _serve() -> None:
 
 if __name__ == "__main__":
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from byzpy_tpu.utils.platform import apply_env_platform
+    from byzpy_tpu.utils.platform import enable_compile_cache
 
-    apply_env_platform()
+    enable_compile_cache()
     asyncio.run(_serve())

@@ -107,7 +107,7 @@ def test_sweep_persists_winner(cache_file):
 
 
 def test_dispatch_decisions_resolve_before_trace(cache_file, monkeypatch):
-    """The round-5 ADVICE pitfall: env-var dispatch knobs used to be read
+    """The stale-closure pitfall: env-var dispatch knobs used to be read
     inside jitted functions, so flipping them after a shape had traced
     changed nothing. All knobs now resolve in the Python wrappers —
     flipping one between two calls of the SAME shape changes the very
@@ -117,8 +117,13 @@ def test_dispatch_decisions_resolve_before_trace(cache_file, monkeypatch):
 
     def spy(xs, **kw):
         calls.append(xs.shape)
-        kw.setdefault("interpret", True)  # still off-chip in reality
-        return real(xs, **kw)
+        # the dispatch pretended to be on chip (below); the kernel itself
+        # runs where it really is — interpreted, which a TPU forbids
+        with monkeypatch.context() as really:
+            really.setattr(
+                "byzpy_tpu.ops.pallas_kernels._on_tpu", lambda: False
+            )
+            return real(xs, **kw)
 
     monkeypatch.setattr(
         "byzpy_tpu.ops.pallas_kernels.meamed_stream_pallas", spy

@@ -159,3 +159,30 @@ def test_loop_body_collectives_reported_separately(devices):
     x = jnp.ones((8, 256), jnp.float32)
     traffic = collective_traffic(fn, x)
     assert traffic["loop_body_bytes_per_iteration"] > 0, traffic
+
+
+def test_tpu_layouts_and_tuple_shapes_are_parsed():
+    """Lines recorded from the fused PS step compiled for four v5e chips
+    (PR 21, ``node_mesh(4)``, MNIST MLP, Multi-Krum): TPU layouts carry
+    parentheses (``T(1024)S(1)``), which hid every tuple-shaped collective
+    from the parser — here the d-sized all-reduce XLA:TPU lowers the params
+    gather to (dynamic-update-slice into zeros, then all-reduce, combined
+    with the two scalar psums)."""
+    hlo = """
+HloModule jit_train_step
+
+ENTRY %main.17_spmd (param.7: f32[128]) -> f32[128] {
+  %all-to-all = f32[2,4,25443]{2,0,1:T(2,128)S(1)} all-to-all(%copy.11), channel_id=1, replica_groups=[1,4]<=[4], dimensions={1}, metadata={op_name="jit(train_step)/slice" stack_frame_id=30}
+  %bitcast.49 = f32[4,2,25443]{2,1,0:T(2,128)S(1)} bitcast(%all-to-all)
+  %all-reduce = f32[8,8]{1,0:T(8,128)S(1)} all-reduce(%fusion.684), channel_id=2, replica_groups=[1,4]<=[4], use_global_device_ids=true, to_apply=%add.clone
+  %all-reduce.3 = (f32[101772]{0:T(1024)S(1)}, f32[]{:T(128)}, f32[]{:T(128)}) all-reduce(%get-tuple-element.41, %get-tuple-element.46, %fusion.38), channel_id=2, replica_groups=[1,4]<=[4], use_global_device_ids=true, to_apply=%add
+  %get-tuple-element.38 = f32[101772]{0:T(1024)S(1)} get-tuple-element(%all-reduce.3), index=0
+}
+"""
+    ops = collectives_in_hlo(hlo, default_group=4)
+    assert [(op.opcode, op.result_bytes, op.group_size) for op in ops] == [
+        ("all-to-all", 2 * 4 * 25443 * 4, 4),
+        ("all-reduce", 8 * 8 * 4, 4),
+        ("all-reduce", 101772 * 4 + 4 + 4, 4),
+    ]
+    assert all(op.in_entry for op in ops)

@@ -159,7 +159,7 @@ def test_heartbeat_drives_removal_end_to_end():
 
 
 def test_heartbeat_policy_excises_dead_peer_without_wiring():
-    """The shipped default policy (VERDICT r4 #7): construct with
+    """The shipped default policy: construct with
     ``elastic=HeartbeatPolicy(...)`` and a dead peer is excised with NO
     test-side monitor/responder/callback wiring at all."""
     async def run():

@@ -1,11 +1,10 @@
 """Declared license metadata must match the committed LICENSE text.
 
-ADVICE r5 flagged an Apache-2.0/MIT flip across rounds; this pins the
-two sources of truth together so a future edit to either one fails
+The declared license once flipped Apache-2.0/MIT across rounds; this pins
+the two sources of truth together so a future edit to either one fails
 loudly instead of shipping contradictory licensing."""
 
 import os
-import re
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -18,15 +17,13 @@ _FINGERPRINTS = {
 
 
 def _declared_license() -> str:
-    with open(os.path.join(REPO, "pyproject.toml")) as fh:
-        text = fh.read()
-    # tomllib only exists on >=3.11 and the floor is 3.10: the license
-    # line is simple enough to pin textually
-    m = re.search(r'^license\s*=\s*\{\s*text\s*=\s*"([^"]+)"', text, re.M)
-    if m is None:
-        m = re.search(r'^license\s*=\s*"([^"]+)"', text, re.M)
-    assert m is not None, "pyproject.toml declares no license"
-    return m.group(1)
+    import tomllib
+
+    with open(os.path.join(REPO, "pyproject.toml"), "rb") as fh:
+        license_field = tomllib.load(fh)["project"]["license"]
+    if isinstance(license_field, dict):
+        return license_field["text"]
+    return license_field
 
 
 def test_pyproject_license_matches_license_file():

@@ -1,7 +1,7 @@
 """Observability wired through the real fabrics.
 
 * a serving round closed through the production path emits the full
-  lifecycle span taxonomy and publishes the tenant's registry metrics;
+  lifecycle span catalog and publishes the tenant's registry metrics;
 * the TCP ingress answers an HTTP GET with a Prometheus scrape of the
   registry (and wire frames still work on the same port);
 * the actor-mode ParameterServer emits round/gather/aggregate/broadcast

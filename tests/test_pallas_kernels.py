@@ -205,7 +205,7 @@ def test_sort_columns_bf16_roundtrip():
 
 def test_inf_attack_into_median_large_dim(monkeypatch):
     """Integration: InfAttack output flowing into CoordinateWiseMedian at
-    d >= 256k routed through the Pallas path (VERDICT r2 item 2) — the
+    d >= 256k routed through the Pallas path — the
     framework's own attack must not break its own median."""
     from byzpy_tpu.aggregators.coordinate_wise.median import CoordinateWiseMedian
     from byzpy_tpu.attacks.inf import InfAttack

@@ -169,7 +169,7 @@ class _ActorEmpireNode(_ActorHonestNode):
 
 
 def test_actor_ps_matches_fused_spmd_ps(setup):
-    """The one seam between the two PS implementations (VERDICT r4 #10):
+    """The one seam between the two PS implementations:
     actor-mode rounds (engine/parameter_server/ps.py) and the fused SPMD
     step (parallel/ps.py) must produce the same trajectory on a fixed
     seed — same shards, same empire attack, same trimmed-mean, same

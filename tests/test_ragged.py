@@ -171,8 +171,7 @@ def test_pallas_segment_sum_opt_in_parity(monkeypatch):
     """The opt-in fused Pallas contraction (interpret mode off-TPU)
     reproduces the XLA ragged program to ~1 ulp — which is exactly why
     it stays opt-in: the XLA program is the authoritative bit-parity
-    path (see ``ragged_segment_sum_pallas``'s docstring; on-chip
-    parity capture rides the rerun bundle)."""
+    path (see ``ragged_segment_sum_pallas``'s docstring)."""
     agg = MultiKrum(f=1, q=3)
     grads = _grads(seed=23)
     exact = np.asarray(agg.aggregate(grads))

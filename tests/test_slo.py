@@ -326,7 +326,7 @@ class TestChaosVirtualClock:
 
 
 class TestMultiwindowBurn:
-    """ISSUE-14 satellite: the ROUND13_NOTES.md multiwindow convention
+    """ISSUE-14 satellite: the SRE-workbook multiwindow convention
     — short/long-window burn pairs with page (~14×) / ticket (~1–6×)
     presets; breach requires BOTH windows over threshold; the
     single-window path stays byte-identical when no policy is set."""

@@ -71,7 +71,7 @@ def test_ring_lm_matches_full_lm(devices):
 def test_ring_lm_init_and_apply_outside_shard_map():
     """Ring models must initialize (and run) on a single device with no
     mesh bound: the ring axis degrades to position 0 / full attention,
-    which is exactly one-block ring semantics (ADVICE r2)."""
+    which is exactly one-block ring semantics."""
     vocab, dim, depth, heads = 32, 32, 1, 4
     ring = TransformerLM(vocab_size=vocab, dim=dim, depth=depth,
                          num_heads=heads, attention="ring", ring_axis="sp")
