@@ -35,9 +35,10 @@ incident behind each one):
   (``_agg_digest``, ``fold_merge_*``, ``combine_partials``,
   ``gram_block``, trace digests) must not call clocks/RNG or iterate
   bare sets into folded bytes (the PR 7 np.mean digest drift, class of).
-* ``METRIC-CONTRACT`` — every metric registration and span label must
-  appear, with a matching type, in ``byzpy_tpu/observability/catalog.py``
-  (single source of truth; the docs tables are checked against it).
+* ``METRIC-CONTRACT`` — every metric registration, span label,
+  ``named_scope`` label and ``pallas_call`` name must appear, with a
+  matching type, in ``byzpy_tpu/observability/catalog.py`` (single
+  source of truth; the docs tables are checked against it).
 
 Rules are deliberately *precise over complete*: each stays silent when
 static resolution fails rather than guessing, so a finding is worth
@@ -1428,6 +1429,10 @@ METRIC_RECEIVER_HINTS = ("reg", "metric")
 #: tracing entry points that take a span/instant label
 SPAN_CALL_NAMES = {"span", "device_span", "begin_span", "instant"}
 SPAN_RECEIVER_HINTS = ("tracing", "tracer", "trace")
+#: in-jit names: the scope label of ``jax.named_scope`` and the kernel
+#: name of ``pl.pallas_call(name=...)`` (both reach the compiled text)
+SCOPE_CALL_NAME = "named_scope"
+KERNEL_CALL_NAME = "pallas_call"
 
 
 class MetricContractRule(Rule):
@@ -1435,23 +1440,34 @@ class MetricContractRule(Rule):
 
     id = METRIC_CONTRACT
     summary = (
-        "every Counter/Gauge/Histogram registration and span() label "
-        "must appear, with a matching type, in "
-        "byzpy_tpu/observability/catalog.py (and the docs tables)"
+        "every Counter/Gauge/Histogram registration, span() label, "
+        "named_scope label and pallas_call name must appear, with a "
+        "matching type, in byzpy_tpu/observability/catalog.py (and the "
+        "docs tables)"
     )
 
     def check(self, mod: ModuleInfo, ctx: ScanContext) -> Iterator[Finding]:
-        """Check the literal first argument of registry factory calls
-        and tracing span/instant calls against the catalog. Computed
+        """Check the literal first argument of registry factory calls,
+        tracing span/instant calls and ``named_scope`` calls, and the
+        ``name=`` of every ``pallas_call``, against the catalog. Computed
         names stay silent unless a declared dynamic prefix covers them —
         a new dynamic family must be catalogued as a prefix."""
         for node in ast.walk(mod.tree):
             if not isinstance(node, ast.Call):
                 continue
+            func = node.func
+            called = func.attr if isinstance(func, ast.Attribute) else (
+                func.id if isinstance(func, ast.Name) else ""
+            )
+            if called == KERNEL_CALL_NAME:
+                yield from self._check_kernel(mod, node)
+                continue
             name = self._literal_name(node)
             if name is None:
                 continue
-            func = node.func
+            if called == SCOPE_CALL_NAME:
+                yield from self._check_scope(mod, node, name)
+                continue
             if (
                 isinstance(func, ast.Attribute)
                 and func.attr in METRIC_FACTORY_ATTRS
@@ -1523,6 +1539,48 @@ class MetricContractRule(Rule):
             "add it to byzpy_tpu/observability/catalog.py and the "
             "docs/observability.md span catalog",
         )
+
+    def _check_scope(
+        self, mod: ModuleInfo, node: ast.Call, name: str
+    ) -> Iterator[Finding]:
+        if name in catalog.SCOPES:
+            return
+        yield self.finding(
+            mod,
+            node,
+            f"named_scope label {name!r} is not in the observability "
+            "catalog — add it to SCOPES in "
+            "byzpy_tpu/observability/catalog.py and the "
+            "docs/observability.md scope table",
+        )
+
+    def _check_kernel(
+        self, mod: ModuleInfo, node: ast.Call
+    ) -> Iterator[Finding]:
+        """A ``pallas_call`` names its kernel with a catalogued literal:
+        without ``name=`` the custom call carries a name the compiler
+        made, which no trace reader can hold on to."""
+        given = [kw.value for kw in node.keywords if kw.arg == "name"]
+        if not given:
+            yield self.finding(
+                mod,
+                node,
+                "pallas_call without name= — give the kernel a literal "
+                "name from KERNELS in byzpy_tpu/observability/catalog.py",
+            )
+            return
+        expr = given[0]
+        if not (isinstance(expr, ast.Constant) and isinstance(expr.value, str)):
+            return  # computed names stay silent, as computed labels do
+        if expr.value not in catalog.KERNELS:
+            yield self.finding(
+                mod,
+                node,
+                f"kernel name {expr.value!r} is not in the observability "
+                "catalog — add it to KERNELS in "
+                "byzpy_tpu/observability/catalog.py and the "
+                "docs/observability.md kernel table",
+            )
 
 
 #: the shipped rule set, in reporting order

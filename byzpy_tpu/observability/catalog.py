@@ -4,8 +4,9 @@ Single source of truth for the telemetry namespace. The tables in
 ``docs/observability.md`` were the original source (this module was
 generated from them once, PR 20); from here on the *catalog* is
 authoritative — the byzlint ``METRIC-CONTRACT`` rule statically checks
-every ``Counter``/``Gauge``/``Histogram`` registration and ``span()``
-label in the tree against it, and ``tests/test_observability_catalog``
+every ``Counter``/``Gauge``/``Histogram`` registration, ``span()``
+label, ``jax.named_scope`` label and ``pallas_call(name=...)`` in the
+tree against it, and ``tests/test_observability_catalog``
 cross-checks the docs tables so prose and code cannot drift.
 
 Adding an instrument is therefore a three-line change: register it at
@@ -128,4 +129,61 @@ SPANS: FrozenSet[str] = frozenset(
 #: dynamic span families (``chaos.<kind>`` event-trace mirror instants)
 SPAN_PREFIXES: Tuple[str, ...] = ("chaos.",)
 
-__all__ = ["METRICS", "METRIC_PREFIXES", "SPANS", "SPAN_PREFIXES"]
+#: every ``jax.named_scope`` label inside a jitted step. A scope rides
+#: each HLO instruction's ``op_name`` in the compiled program's text (a
+#: TPU trace event carries none); ``round.*`` partitions the fused
+#: training step, ``serving.*`` names the serving steps' stages
+SCOPES: FrozenSet[str] = frozenset(
+    {
+        "round.aggregate",
+        "round.build_matrix",
+        "round.fwdbwd",
+        "round.param_gather",
+        "round.pre_aggregate",
+        "round.transpose",
+        "round.update",
+        "serving.masked_aggregate",
+        "serving.opt_update",
+        "serving.ragged_aggregate",
+        "serving.ragged_dequant",
+        "serving.ragged_evidence",
+        "serving.ragged_scale",
+        "serving.staleness_scale",
+    }
+)
+
+#: every ``pl.pallas_call(name=...)``: the enclosing ``_<name>_call``
+#: function's name without the underscore and the ``_call``. The name is
+#: the custom call's instruction name and a segment of its ``op_name``
+#: in the compiled text, so a reader finds the kernel after a refactor
+KERNELS: FrozenSet[str] = frozenset(
+    {
+        "arc_selection_mean_stream",
+        "clip_selection_mean_stream",
+        "dequantize_pallas",
+        "dequantize_s4_pallas",
+        "gram_pallas",
+        "meamed_stream",
+        "nnm_selection_mean_stream",
+        "nnm_stream",
+        "quantize_fp8_pallas",
+        "quantize_pallas",
+        "quantize_s4_pallas",
+        "ragged_segment_sum",
+        "ragged_segment_sum_dequant",
+        "selection_from_gram",
+        "selection_mean_stream",
+        "sort_columns",
+        "sorted_reduce_stream",
+        "weighted_center_step",
+    }
+)
+
+__all__ = [
+    "KERNELS",
+    "METRICS",
+    "METRIC_PREFIXES",
+    "SCOPES",
+    "SPANS",
+    "SPAN_PREFIXES",
+]

@@ -263,6 +263,7 @@ def _sort_columns_call(x: Array, *, tile: int, interpret: bool) -> Array:
             (n_pad, tile), lambda i: (0, i), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name="sort_columns",
     )(xp)
     return out[:n, :d]
 
@@ -356,6 +357,7 @@ def _gram_pallas_call(x: Array, *, tile: int, interpret: bool) -> Array:
             (n_pad, n_pad), lambda i: (0, 0), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name="gram_pallas",
     )(xp)
     return out[:n, :n].astype(
         jnp.float32 if x.dtype in (jnp.bfloat16, jnp.float16) else x.dtype
@@ -471,6 +473,7 @@ def _sorted_reduce_stream_call(
             (1, 1, tile), lambda k, c: (k, 0, c), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name="sorted_reduce_stream",
     )(xp)
     return out[:, 0, :d]
 
@@ -622,6 +625,7 @@ def _weighted_center_step_call(
             pltpu.VMEM((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="weighted_center_step",
     )(xp, zp)
     return out[0, :d]
 
@@ -767,6 +771,7 @@ def _meamed_stream_call(
             (1, 1, tile), lambda k, c: (k, 0, c), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name="meamed_stream",
     )(xp)
     return out[:, 0, :d]
 
@@ -1072,6 +1077,7 @@ def _selection_mean_stream_call(
             pltpu.VMEM((n_pad, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="selection_mean_stream",
     )(xp)
     return out[:, 0, :d]
 
@@ -1218,6 +1224,7 @@ def _selection_from_gram_call(
         ),
         scratch_shapes=[pltpu.VMEM((n_pad, 1), jnp.float32)],
         interpret=interpret,
+        name="selection_from_gram",
     )(xp, gp)
     return out[0, :d]
 
@@ -1370,6 +1377,7 @@ def _nnm_stream_call(
             pltpu.VMEM((2, n_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="nnm_stream",
     )(xp)
     return out[:, :n, :d]
 
@@ -1659,6 +1667,7 @@ def _clip_selection_mean_stream_call(
             pltpu.VMEM((1, n_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="clip_selection_mean_stream",
     )(xp)
     return out[:, 0, :d]
 
@@ -1752,6 +1761,7 @@ def _arc_selection_mean_stream_call(
             pltpu.VMEM((1, n_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="arc_selection_mean_stream",
     )(xp)
     return out[:, 0, :d]
 
@@ -1848,6 +1858,7 @@ def _nnm_selection_mean_stream_call(
             pltpu.VMEM((1, n_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="nnm_selection_mean_stream",
     )(xp)
     return out[:, 0, :d]
 
@@ -1990,6 +2001,7 @@ def _ragged_segment_sum_call(
         out_shape=jax.ShapeDtypeStruct((c_pad, d_pad), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="ragged_segment_sum",
     )(fill, ohp, xp)
     return out[:n_cohorts, :d].astype(x.dtype)
 
@@ -2187,6 +2199,7 @@ def _ragged_segment_sum_dequant_call(
         out_shape=jax.ShapeDtypeStruct((c_pad, d_pad), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="ragged_segment_sum_dequant",
     )(fill, ohp, cp, sp)
     return out[:n_cohorts, :d]
 

@@ -392,110 +392,132 @@ def build_ps_train_step(
         )
 
     def train_step(params, opt_state, xs, ys, key):
+        # Every op lies in exactly one innermost round.* scope
+        # (observability.catalog.SCOPES): the label rides each HLO
+        # instruction's op_name metadata, and the benchmark reads
+        # per-scope device time through the compiled text (the note in
+        # build_serving_ps_step says how).
         ef_state = {}
         if has_ef:
             opt_state, ef_state = opt_state
-        if node_spec is not None:
-            xs = jax.lax.with_sharding_constraint(xs, node_spec)
-            ys = jax.lax.with_sharding_constraint(ys, node_spec)
-        # Every node's forward/backward runs in parallel across the mesh:
-        # vmap over the node axis of node-sharded data with replicated params.
-        losses, grads = jax.vmap(per_node_grad, in_axes=(None, 0, 0))(params, xs, ys)
+        with jax.named_scope("round.fwdbwd"):
+            if node_spec is not None:
+                xs = jax.lax.with_sharding_constraint(xs, node_spec)
+                ys = jax.lax.with_sharding_constraint(ys, node_spec)
+            # Every node's forward/backward runs in parallel across the
+            # mesh: vmap over the node axis of node-sharded data with
+            # replicated params.
+            losses, grads = jax.vmap(per_node_grad, in_axes=(None, 0, 0))(
+                params, xs, ys
+            )
         if feat_spec is not None and comm.enabled:
             # Compressed fabric: every node's RAW gradient row crosses the
             # wire encoded (exactly what a deployment ships — byzantine
             # nodes transmit too), and the attack/masking runs on the
             # decoded, feature-sharded rows: the omniscient adversary sees
             # the wire view of the honest gradients.
-            if ef_transpose:
-                # EF: the wire carries g + e, the new residual stays
-                # node-sharded beside the optimizer state
-                decoded, new_tr = reshard_q_ef(
-                    grads, ef_state["transpose"], row_spec, feat_spec,
-                    precision=comm,
-                )
-                ef_state = {**ef_state, "transpose": new_tr}
-            else:
-                decoded = transpose_compressed(grads)
-            matrix = jax.lax.with_sharding_constraint(
-                build_matrix(decoded, key), feat_spec
-            )
+            with jax.named_scope("round.transpose"):
+                if ef_transpose:
+                    # EF: the wire carries g + e, the new residual stays
+                    # node-sharded beside the optimizer state
+                    decoded, new_tr = reshard_q_ef(
+                        grads, ef_state["transpose"], row_spec, feat_spec,
+                        precision=comm,
+                    )
+                    ef_state = {**ef_state, "transpose": new_tr}
+                else:
+                    decoded = transpose_compressed(grads)
+            with jax.named_scope("round.build_matrix"):
+                matrix = build_matrix(decoded, key)
+            with jax.named_scope("round.transpose"):
+                matrix = jax.lax.with_sharding_constraint(matrix, feat_spec)
         else:
-            matrix = build_matrix(grads, key)
+            with jax.named_scope("round.build_matrix"):
+                matrix = build_matrix(grads, key)
             if feat_spec is not None:
                 # Gradient transpose: node-sharded rows -> feature-sharded
                 # columns (XLA lowers this constraint to an all_to_all over
                 # ICI), so the robust aggregation below is chip-local per
                 # coordinate.
-                matrix = jax.lax.with_sharding_constraint(matrix, feat_spec)
+                with jax.named_scope("round.transpose"):
+                    matrix = jax.lax.with_sharding_constraint(matrix, feat_spec)
         if su_on and d_pad != d:
             # zero-pad the feature axis to the shard grid BEFORE the
             # robust reduce: every shipped aggregator maps all-zero
             # columns to zero, row norms/Gram blocks are unchanged, and
             # the padded tail is re-zeroed below regardless
-            matrix = jnp.pad(matrix, ((0, 0), (0, d_pad - d)))
-            if feat_spec is not None:
-                matrix = jax.lax.with_sharding_constraint(matrix, feat_spec)
+            with jax.named_scope("round.build_matrix"):
+                matrix = jnp.pad(matrix, ((0, 0), (0, d_pad - d)))
+                if feat_spec is not None:
+                    matrix = jax.lax.with_sharding_constraint(matrix, feat_spec)
         if pre_aggregate is not None:
-            matrix = pre_aggregate(matrix)
-        agg_flat = aggregate(matrix).astype(param_dtype)
-        if flat_sharding is not None:
-            agg_flat = jax.lax.with_sharding_constraint(agg_flat, flat_sharding)
-        if su_on and d_pad != d:
-            # pin the pad tail to exactly zero so padded params/momenta
-            # never drift (and the norm below matches the unpadded round)
-            agg_flat = jnp.where(jnp.arange(d_pad) < d, agg_flat, 0.0)
+            with jax.named_scope("round.pre_aggregate"):
+                matrix = pre_aggregate(matrix)
+        with jax.named_scope("round.aggregate"):
+            agg_flat = aggregate(matrix).astype(param_dtype)
             if flat_sharding is not None:
                 agg_flat = jax.lax.with_sharding_constraint(
                     agg_flat, flat_sharding
                 )
-        # shard-local norm: per-shard partial sums of squares + a scalar
-        # psum — the aggregated gradient is never gathered for a metric
-        agg_norm = jnp.sqrt(jnp.sum(jnp.square(agg_flat)))
-        if su_on:
-            flat_params, inner = opt_state
-            if flat_sharding is not None:
-                flat_params = jax.lax.with_sharding_constraint(
-                    flat_params, flat_sharding
-                )
-            updates, inner = opt.update(agg_flat, inner, flat_params)
-            new_flat = optax.apply_updates(flat_params, updates)
-            if flat_sharding is not None:
-                new_flat = jax.lax.with_sharding_constraint(
-                    new_flat, flat_sharding
-                )
-                inner = jax.tree_util.tree_map(
-                    lambda leaf: jax.lax.with_sharding_constraint(
-                        leaf, flat_sharding
+        with jax.named_scope("round.update"):
+            if su_on and d_pad != d:
+                # pin the pad tail to exactly zero so padded params/momenta
+                # never drift (and the norm below matches the unpadded round)
+                agg_flat = jnp.where(jnp.arange(d_pad) < d, agg_flat, 0.0)
+                if flat_sharding is not None:
+                    agg_flat = jax.lax.with_sharding_constraint(
+                        agg_flat, flat_sharding
                     )
-                    if getattr(leaf, "shape", None) == (d_pad,)
-                    else leaf,
-                    inner,
-                )
-            gathered, ef_state = gather_flat_params(new_flat, ef_state)
-            params = unravel(gathered[:d])
-            opt_state = (new_flat, inner)
-        else:
-            update = unravel(agg_flat)
-            updates, opt_state = opt.update(update, opt_state, params)
-            params = optax.apply_updates(params, updates)
-        metrics = {
-            "honest_loss": jnp.mean(losses[:h]),
-            "agg_grad_norm": agg_norm,
-        }
-        if has_ef:
-            # shard-local residual-energy metrics (the convergence study
-            # watches these stay bounded — a drifting residual is the
-            # "EF compounding" failure mode)
-            if ef_transpose:
-                metrics["ef_transpose_norm"] = jnp.sqrt(
-                    jnp.sum(jnp.square(ef_state["transpose"].astype(jnp.float32)))
-                )
-            if ef_gather:
-                metrics["ef_gather_norm"] = jnp.sqrt(
-                    jnp.sum(jnp.square(ef_state["gather"].astype(jnp.float32)))
-                )
-            opt_state = (opt_state, ef_state)
+            # shard-local norm: per-shard partial sums of squares + a scalar
+            # psum — the aggregated gradient is never gathered for a metric
+            agg_norm = jnp.sqrt(jnp.sum(jnp.square(agg_flat)))
+            if su_on:
+                flat_params, inner = opt_state
+                if flat_sharding is not None:
+                    flat_params = jax.lax.with_sharding_constraint(
+                        flat_params, flat_sharding
+                    )
+                updates, inner = opt.update(agg_flat, inner, flat_params)
+                new_flat = optax.apply_updates(flat_params, updates)
+                if flat_sharding is not None:
+                    new_flat = jax.lax.with_sharding_constraint(
+                        new_flat, flat_sharding
+                    )
+                    inner = jax.tree_util.tree_map(
+                        lambda leaf: jax.lax.with_sharding_constraint(
+                            leaf, flat_sharding
+                        )
+                        if getattr(leaf, "shape", None) == (d_pad,)
+                        else leaf,
+                        inner,
+                    )
+                with jax.named_scope("round.param_gather"):
+                    gathered, ef_state = gather_flat_params(new_flat, ef_state)
+                params = unravel(gathered[:d])
+                opt_state = (new_flat, inner)
+            else:
+                update = unravel(agg_flat)
+                updates, opt_state = opt.update(update, opt_state, params)
+                params = optax.apply_updates(params, updates)
+            metrics = {
+                "honest_loss": jnp.mean(losses[:h]),
+                "agg_grad_norm": agg_norm,
+            }
+            if has_ef:
+                # shard-local residual-energy metrics (the convergence study
+                # watches these stay bounded — a drifting residual is the
+                # "EF compounding" failure mode)
+                if ef_transpose:
+                    metrics["ef_transpose_norm"] = jnp.sqrt(
+                        jnp.sum(
+                            jnp.square(ef_state["transpose"].astype(jnp.float32))
+                        )
+                    )
+                if ef_gather:
+                    metrics["ef_gather_norm"] = jnp.sqrt(
+                        jnp.sum(jnp.square(ef_state["gather"].astype(jnp.float32)))
+                    )
+                opt_state = (opt_state, ef_state)
         return params, opt_state, metrics
 
     return train_step, opt_state0
@@ -556,10 +578,14 @@ def build_serving_ps_step(
         feat_spec = NamedSharding(mesh, P(None, (axis, *extra)))
 
     def step(params, opt_state, matrix, valid, weights):
-        # named_scope = the in-jit analogue of the host tracing spans:
-        # the stage names land in HLO op metadata, so an XLA device
-        # profile shows the same serving.* stage names as the host
-        # timeline (docs/observability.md)
+        # named_scope = the in-jit analogue of the host tracing spans,
+        # here and in the training step (round.*). How a scope reaches
+        # a metric: the label becomes a segment of each instruction's
+        # op_name in the COMPILED program's text, not of any trace event
+        # (a TPU trace names an op by its instruction alone). A reader
+        # joins trace event -> instruction name -> compiled-text line ->
+        # op_name -> innermost scope (chipbench/scope_join.py); labels
+        # are catalogued in observability.catalog.SCOPES.
         with jax.named_scope("serving.staleness_scale"):
             # staleness discount: scale each row before the robust
             # reduce (a weight of exactly 1.0 leaves the row

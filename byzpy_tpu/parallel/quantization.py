@@ -343,6 +343,7 @@ def _quantize_pallas_call(
             ),
         ),
         interpret=interpret,
+        name="quantize_pallas",
     )(xp)
     nb = -(-d // block)
     return values[:rows, :d], _scales_compact(scales, bpt)[:rows, :nb]
@@ -378,6 +379,7 @@ def _dequantize_pallas_call(
             (rows_pad, tile), lambda i: (0, i), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name="dequantize_pallas",
     )(vp, _scales_lane_dense(sp, bpt))
     return out[:rows, :d].astype(dtype)
 
@@ -602,6 +604,7 @@ def _quantize_fp8_pallas_call(
             ),
         ),
         interpret=interpret,
+        name="quantize_fp8_pallas",
     )(xp)
     nb = -(-d // block)
     from jax import lax as _lax
@@ -644,6 +647,7 @@ def _quantize_s4_pallas_call(
             ),
         ),
         interpret=interpret,
+        name="quantize_s4_pallas",
     )(xp)
     nb = -(-d // block)
     d_blocks_pad = nb * block // 2
@@ -685,6 +689,7 @@ def _dequantize_s4_pallas_call(
             (rows_pad, tile), lambda i: (0, i), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name="dequantize_s4_pallas",
     )(vp, _scales_lane_dense(sp, bpt))
     return out[:rows, :d].astype(dtype)
 

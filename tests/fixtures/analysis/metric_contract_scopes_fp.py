@@ -1,0 +1,26 @@
+"""byzlint fixture: METRIC-CONTRACT false-positive guards for the in-jit
+names.
+
+Catalogued scope labels and kernel names, and the shapes the rule must
+resolve to nothing: computed labels and names (silent by design).
+"""
+
+import jax
+from jax.experimental import pallas as pl
+
+
+def step(x, stage):
+    with jax.named_scope("round.aggregate"):
+        x = x * 2
+    with jax.named_scope("serving.opt_update"):
+        x = x + 1
+    with jax.named_scope(f"round.{stage}"):  # computed: silent
+        return x
+
+
+def _sorted_reduce_stream_call(x, kernel, shape):
+    return pl.pallas_call(kernel, out_shape=shape, name="sorted_reduce_stream")(x)
+
+
+def _family_call(x, kernel, shape, family):
+    return pl.pallas_call(kernel, out_shape=shape, name=family + "_stream")(x)

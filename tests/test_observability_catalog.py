@@ -2,8 +2,8 @@
 
 The catalog is the machine-readable single source of truth the
 byzlint ``METRIC-CONTRACT`` rule checks code against; the docs tables
-are its human rendering. This test parses every metric and span row
-out of the markdown and pins BOTH directions: a docs row naming an
+are its human rendering. This test parses every metric, span, scope
+and kernel row out of the markdown and pins BOTH directions: a docs row naming an
 uncatalogued instrument is drift, and a catalogued instrument with no
 docs row is an undocumented instrument. Metric types must match
 cell-for-cell (one name, one type).
@@ -23,23 +23,34 @@ DOCS = os.path.join(
 )
 
 _TYPES = ("counter", "gauge", "histogram")
+#: the docs section whose tables are the in-jit names (scopes: dotted;
+#: kernels: not), not host spans
+_IN_JIT_SECTION = "## In-jit names"
 
 
 def _doc_tables():
     """Parse the markdown tables: ``(metrics, metric_prefixes, spans,
-    span_prefixes)``. Metric rows may carry several backticked names
-    per cell with one shared type or a slash-separated type per name;
-    ``<...>`` placeholders declare prefix families."""
+    span_prefixes, scopes, kernels)``. Metric rows may carry several
+    backticked names per cell with one shared type or a slash-separated
+    type per name; ``<...>`` placeholders declare prefix families."""
     with open(DOCS, encoding="utf-8") as fh:
         text = fh.read()
     metrics, metric_prefixes = {}, set()
     spans, span_prefixes = set(), set()
+    scopes, kernels = set(), set()
+    in_jit = False
     for line in text.splitlines():
+        if line.startswith("## "):
+            in_jit = line.startswith(_IN_JIT_SECTION)
         if not line.startswith("| `"):
             continue
         cells = [c.strip() for c in line.strip().strip("|").split("|")]
         names = re.findall(r"`([a-zA-Z0-9_.<>]+)`", cells[0])
         if not names:
+            continue
+        if in_jit:
+            for name in names:
+                (scopes if "." in name else kernels).add(name)
             continue
         types = [t.strip() for t in cells[1].split("/")] if len(cells) > 1 else []
         if all(t in _TYPES for t in types) and types:
@@ -63,7 +74,7 @@ def _doc_tables():
                 span_prefixes.add(name.split("<", 1)[0])
             else:
                 spans.add(name)
-    return metrics, metric_prefixes, spans, span_prefixes
+    return metrics, metric_prefixes, spans, span_prefixes, scopes, kernels
 
 
 def test_catalog_is_well_formed():
@@ -74,10 +85,16 @@ def test_catalog_is_well_formed():
         assert mtype in _TYPES, (name, mtype)
     for prefix in catalog.METRIC_PREFIXES:
         assert prefix.startswith("byzpy_"), prefix
+    for scope in catalog.SCOPES:
+        assert re.fullmatch(r"(round|serving)\.[a-z_]+", scope), scope
+    for kernel in catalog.KERNELS:
+        assert re.fullmatch(r"[a-z][a-z0-9_]+", kernel), kernel
+    # one namespace: an in-jit scope never reuses a host span's label
+    assert not set(catalog.SCOPES) & set(catalog.SPANS)
 
 
 def test_docs_metric_tables_match_catalog_both_ways():
-    metrics, prefixes, _spans, _sp = _doc_tables()
+    metrics, prefixes, *_ = _doc_tables()
     assert metrics, "no metric rows parsed from docs/observability.md"
     mismatched = {
         n: (t, catalog.METRICS.get(n))
@@ -91,10 +108,28 @@ def test_docs_metric_tables_match_catalog_both_ways():
 
 
 def test_docs_span_table_matches_catalog_both_ways():
-    _m, _p, spans, span_prefixes = _doc_tables()
+    _m, _p, spans, span_prefixes, *_ = _doc_tables()
     assert spans, "no span rows parsed from docs/observability.md"
     unknown = sorted(spans - set(catalog.SPANS))
     assert not unknown, f"docs span rows drifting from catalog: {unknown}"
     undocumented = sorted(set(catalog.SPANS) - spans)
     assert not undocumented, f"catalogued but not in docs: {undocumented}"
     assert span_prefixes == set(catalog.SPAN_PREFIXES)
+
+
+def test_docs_scope_table_matches_catalog_both_ways():
+    *_, scopes, _kernels = _doc_tables()
+    assert scopes, "no scope rows parsed from docs/observability.md"
+    unknown = sorted(scopes - set(catalog.SCOPES))
+    assert not unknown, f"docs scope rows drifting from catalog: {unknown}"
+    undocumented = sorted(set(catalog.SCOPES) - scopes)
+    assert not undocumented, f"catalogued but not in docs: {undocumented}"
+
+
+def test_docs_kernel_table_matches_catalog_both_ways():
+    *_, kernels = _doc_tables()
+    assert kernels, "no kernel rows parsed from docs/observability.md"
+    unknown = sorted(kernels - set(catalog.KERNELS))
+    assert not unknown, f"docs kernel rows drifting from catalog: {unknown}"
+    undocumented = sorted(set(catalog.KERNELS) - kernels)
+    assert not undocumented, f"catalogued but not in docs: {undocumented}"
