@@ -2293,6 +2293,29 @@ def use_pallas_for(n: int, d: int, *, min_dim: Optional[int] = None) -> bool:
     return _on_tpu() and n <= MAX_NETWORK_ROWS and d >= floor
 
 
+# The widest feature tile the stream kernels' heuristics try
+# (``_auto_sort_tile``, ``_auto_selection_tile``); every narrower
+# candidate divides it.
+_WIDEST_TILE = 16384
+
+
+def aligned_width(n: int, d: int) -> int:
+    """The column count at which to allocate an ``(n, ·)`` matrix with
+    ``d`` real columns so that a stream kernel reads it in place.
+
+    ``d`` where the Pallas route will not serve the matrix on this
+    backend (:func:`use_pallas_for` is false); else ``d`` rounded up to
+    the widest candidate tile, so that whichever tile a kernel's
+    heuristic can afford divides the width and its wrapper takes the
+    ``xp = xs`` path instead of a zero-padded copy of the whole matrix.
+    The caller keeps the extra columns exactly zero (every shipped
+    aggregator maps all-zero columns to zero and leaves row norms and
+    Gram blocks unchanged) and cuts the result back to ``d``."""
+    if not use_pallas_for(n, d):
+        return d
+    return _round_up(d, _WIDEST_TILE)
+
+
 __all__ = [
     "sort_columns",
     "median_pallas",
@@ -2316,4 +2339,5 @@ __all__ = [
     "selection_mean_stream_pallas",
     "sharding_allows_pallas",
     "use_pallas_for",
+    "aligned_width",
 ]

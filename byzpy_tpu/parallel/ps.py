@@ -217,6 +217,19 @@ def build_ps_train_step(
     The returned params pytree stays replicated either way, so callers
     thread state identically.
 
+    The ``(n, ·)`` gradient matrix is allocated once, ``d_pad`` columns
+    wide with an exactly-zero tail, and every stage writes into it: on
+    one device ``d_pad`` is the width the Pallas stream kernels read in
+    place (:func:`~byzpy_tpu.ops.pallas_kernels.aligned_width`; ``d``
+    wherever they will not serve the matrix) and each worker's row is
+    ravelled at that width; on a mesh it is the sharded update's grid.
+    The byzantine rows are selected into it where it stands (one pass,
+    in place), their tail forced to zero.
+    ``pre_aggregate`` and ``aggregate`` see the ``(n, d_pad)`` matrix
+    and must map all-zero columns to zero without changing the other
+    columns (every shipped one does, ``docs/performance.md``); the
+    aggregate's tail is cut or re-zeroed before the update.
+
     Returns ``(params, opt_state, metrics)`` where metrics carries the mean
     honest loss and the aggregated-gradient norm (computed shard-locally
     as a psum of per-shard partial sums of squares — the aggregated
@@ -260,13 +273,6 @@ def build_ps_train_step(
         for a in extra:
             feat_shards *= mesh.shape[a]
 
-    def per_node_grad(params, x, y):
-        loss, g = jax.value_and_grad(loss_fn)(params, x, y)
-        flat = ravel(g)
-        if grad_dtype is not None:
-            flat = flat.astype(grad_dtype)
-        return loss, flat
-
     flat0 = ravel(bundle.params)
     param_dtype = flat0.dtype
     d = flat0.shape[0]
@@ -284,20 +290,43 @@ def build_ps_train_step(
         # update modes, and the sharded update carries state in it
         flat_sharding = NamedSharding(mesh, P((axis, *extra)))
         repl_sharding = NamedSharding(mesh, P())
+    # ONE padded width for the round: the (n, d_pad) gradient matrix, the
+    # aggregate and, under the sharded update, the carried flat state all
+    # have d_pad columns, of which the last d_pad - d are exactly zero
+    # (the contract in the docstring; the tail is re-zeroed or cut after
+    # the aggregate regardless).
     d_pad = d
-    if su_on:
-        # pad to the shard grid so every chip owns an equal slice, and to
-        # the quantization block so an int8 params gather never splits a
-        # block (scales shard alongside the codes)
-        pad_grid = 1
-        if mesh is not None and feat_shards > 1:
-            # blockwise gathers (int8/fp8/s4) pad to the quantization
-            # block too, so no shard ever splits a block (and the packed
-            # s4 payload's half-length stays grid-divisible)
-            pad_grid = feat_shards * (
-                gather_p.block if gather_p.blockwise else 1
-            )
+    if mesh is None:
+        # the width the stream kernels read without a padded copy of the
+        # whole matrix (d itself wherever the kernels will not serve it)
+        from ..ops.pallas_kernels import aligned_width
+
+        d_pad = aligned_width(cfg.n_nodes, d)
+    elif su_on and feat_shards > 1:
+        # the shard grid, so every chip owns an equal slice; blockwise
+        # gathers (int8/fp8/s4) pad to the quantization block too, so no
+        # shard ever splits a block (scales shard alongside the codes,
+        # and the packed s4 payload's half-length stays grid-divisible)
+        pad_grid = feat_shards * (gather_p.block if gather_p.blockwise else 1)
         d_pad = -(-d // pad_grid) * pad_grid
+    # Where no transpose stands between the rows and the aggregate, each
+    # row is born d_pad wide and the matrix is never rebuilt; on a mesh
+    # the rows cross the wire d wide and are padded after the transpose.
+    row_width = d_pad if mesh is None else d
+
+    def per_node_grad(params, x, y):
+        loss, g = jax.value_and_grad(loss_fn)(params, x, y)
+        if row_width != d:
+            # the zero tail rides the ravel's own concatenate, so the
+            # leaves are written straight into the (n, d_pad) buffer
+            # (padding the ravelled row afterwards costs a copy of it)
+            g = (g, jnp.zeros((row_width - d,), param_dtype))
+        flat = ravel(g)
+        if grad_dtype is not None:
+            flat = flat.astype(grad_dtype)
+        return loss, flat
+
+    if su_on:
         flat_padded0 = jnp.pad(flat0, (0, d_pad - d))
         if flat_sharding is not None:
             flat_padded0 = jax.device_put(flat_padded0, flat_sharding)
@@ -348,17 +377,36 @@ def build_ps_train_step(
         uncompressed fabric, feature-sharded after a compressed
         transpose; all attacks are coordinate-wise over the node axis,
         so both layouts partition cleanly)."""
-        honest = grads_n[:h] if b else grads_n
         if not b:
-            return honest
+            return grads_n
+        honest = grads_n[:h]
         if attack is not None:
-            byz = attack(honest, key)
+            byz = jnp.asarray(attack(honest, key))
         else:
             # no attack configured: byzantine nodes echo honest
             # gradients (cycled, so any b < n works)
             byz = jnp.tile(honest, ((b + h - 1) // h, 1))[:b]
-        byz = jnp.broadcast_to(byz, (b, honest.shape[1])).astype(honest.dtype)
-        return jnp.concatenate([honest, byz], axis=0)
+        width = honest.shape[1]
+        # one row for every byzantine worker stays one row: its
+        # broadcast then fuses into the write below
+        rows_given = 1 if byz.ndim == 1 or byz.shape[0] == 1 else b
+        byz = jnp.broadcast_to(byz, (rows_given, width)).astype(honest.dtype)
+        if width != d:
+            # an attack need not map zero columns to zero (additive
+            # noise): the pad tail of its rows is forced back to zero
+            byz = jnp.where(jnp.arange(width) < d, byz, 0)
+        # The byzantine rows are selected into the stack where it stands:
+        # one elementwise pass over the matrix, run in place. (Rows are
+        # sublanes of the TPU's (8, 128) tiles, so a two-row
+        # dynamic-update-slice touches every tile too, as 1 KB DMA chunks,
+        # and measured slower than this pass or the concatenate it
+        # replaces.)
+        at = jnp.arange(cfg.n_nodes)[:, None]
+        if rows_given == 1:
+            return jnp.where(at >= h, byz, grads_n)
+        for r in range(b):
+            grads_n = jnp.where(at == h + r, byz[r], grads_n)
+        return grads_n
 
     def transpose_compressed(grads_n):
         """Encoded gradient transpose: pin the encoded payload to the node
@@ -410,6 +458,13 @@ def build_ps_train_step(
             losses, grads = jax.vmap(per_node_grad, in_axes=(None, 0, 0))(
                 params, xs, ys
             )
+            if mesh is None:
+                # The matrix is complete here, in one buffer. Without the
+                # barrier the TPU compiler sinks the kernel wrapper's
+                # reshape through build_matrix's select into the ravel's
+                # concatenate and writes the whole matrix twice (two chains
+                # of dynamic-update-slices, two buffers).
+                grads = jax.lax.optimization_barrier(grads)
         if feat_spec is not None and comm.enabled:
             # Compressed fabric: every node's RAW gradient row crosses the
             # wire encoded (exactly what a deployment ships — byzantine
@@ -441,11 +496,9 @@ def build_ps_train_step(
                 # coordinate.
                 with jax.named_scope("round.transpose"):
                     matrix = jax.lax.with_sharding_constraint(matrix, feat_spec)
-        if su_on and d_pad != d:
+        if d_pad != row_width:
             # zero-pad the feature axis to the shard grid BEFORE the
-            # robust reduce: every shipped aggregator maps all-zero
-            # columns to zero, row norms/Gram blocks are unchanged, and
-            # the padded tail is re-zeroed below regardless
+            # robust reduce
             with jax.named_scope("round.build_matrix"):
                 matrix = jnp.pad(matrix, ((0, 0), (0, d_pad - d)))
                 if feat_spec is not None:
@@ -460,9 +513,13 @@ def build_ps_train_step(
                     agg_flat, flat_sharding
                 )
         with jax.named_scope("round.update"):
-            if su_on and d_pad != d:
-                # pin the pad tail to exactly zero so padded params/momenta
-                # never drift (and the norm below matches the unpadded round)
+            if d_pad != d and not su_on:
+                # the state mirrors the parameter tree: the tail is cut
+                agg_flat = agg_flat[:d]
+            elif d_pad != d:
+                # the flat state is carried d_pad wide: pin the pad tail to
+                # exactly zero so padded params/momenta never drift (and
+                # the norm below matches the unpadded round)
                 agg_flat = jnp.where(jnp.arange(d_pad) < d, agg_flat, 0.0)
                 if flat_sharding is not None:
                     agg_flat = jax.lax.with_sharding_constraint(

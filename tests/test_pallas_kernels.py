@@ -647,11 +647,17 @@ def test_meamed_pallas_matches_xla_path_with_nonfinite():
     got = meamed_stream_pallas(x[None], f=3, tile=128, interpret=True)[0]
     import os
 
+    prev = os.environ.get("BYZPY_TPU_PALLAS")
     os.environ["BYZPY_TPU_PALLAS"] = "0"
     try:
         want = robust.mean_of_medians(x, f=3)
     finally:
-        os.environ["BYZPY_TPU_PALLAS"] = "auto"
+        # leave the variable as it was found: a later test on this worker
+        # (tests/test_chip_smoke.py) asserts that nothing leaked one
+        if prev is None:
+            del os.environ["BYZPY_TPU_PALLAS"]
+        else:
+            os.environ["BYZPY_TPU_PALLAS"] = prev
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6, equal_nan=True
     )
