@@ -8,7 +8,7 @@ round collapses into a single compiled step over a ``Mesh``:
 
 * per-node gradients: data is sharded ``P("nodes", ...)``; a ``vmap`` over
   the node axis computes every node's gradient in parallel, each on its own
-  chip;
+  chip (on one device: the honest nodes' only, one after another);
 * byzantine behavior: honest rows are a static slice of the stacked
   gradient matrix; the attack is a pure function of them writing the
   byzantine rows (SURVEY §7e — functional masking instead of separate
@@ -217,14 +217,30 @@ def build_ps_train_step(
     The returned params pytree stays replicated either way, so callers
     thread state identically.
 
-    The ``(n, ·)`` gradient matrix is allocated once, ``d_pad`` columns
-    wide with an exactly-zero tail, and every stage writes into it: on
-    one device ``d_pad`` is the width the Pallas stream kernels read in
-    place (:func:`~byzpy_tpu.ops.pallas_kernels.aligned_width`; ``d``
-    wherever they will not serve the matrix) and each worker's row is
-    ravelled at that width; on a mesh it is the sharded update's grid.
-    The byzantine rows are selected into it where it stands (one pass,
-    in place), their tail forced to zero.
+    Which workers' gradients are computed, and how: nothing reads a
+    byzantine worker's own gradient or loss (its row of the matrix is
+    the attack's, or an honest row echoed; ``honest_loss`` is the honest
+    mean). On one device (no mesh) the step therefore runs
+    forward/backward for the first ``h = n_nodes - n_byzantine`` workers
+    only, one after another (``lax.map``; ``xs[h:]``, ``ys[h:]`` are not
+    read): on the TPU a convolutional model's per-worker gradients cost
+    about half as much one worker at a time as vmapped, and no more for
+    an MLP (``docs/performance.md``). Signature, shapes, state, metrics
+    and values are those of a round that computes all n rows and
+    overwrites b of them. On a mesh all n are computed under ``vmap``:
+    the node axis carries every worker, a byzantine worker's chip runs
+    beside the others (skipping it frees no time) and h need not divide
+    the axis.
+
+    The gradient matrix is ``d_pad`` columns wide with an exactly-zero
+    tail: on one device ``d_pad`` is the width the Pallas stream
+    kernels read in place
+    (:func:`~byzpy_tpu.ops.pallas_kernels.aligned_width`; ``d`` wherever
+    they will not serve the matrix) and each computed row is ravelled
+    at that width; on a mesh it is the sharded update's grid. The
+    byzantine rows are selected into the stack in one pass (in place
+    where the stack has n rows; as the ``(h, ·)`` stack is padded to n
+    rows on one device), their tail forced to zero.
     ``pre_aggregate`` and ``aggregate`` see the ``(n, d_pad)`` matrix
     and must map all-zero columns to zero without changing the other
     columns (every shipped one does, ``docs/performance.md``); the
@@ -313,13 +329,11 @@ def build_ps_train_step(
     # row is born d_pad wide and the matrix is never rebuilt; on a mesh
     # the rows cross the wire d wide and are padded after the transpose.
     row_width = d_pad if mesh is None else d
-
     def per_node_grad(params, x, y):
         loss, g = jax.value_and_grad(loss_fn)(params, x, y)
         if row_width != d:
-            # the zero tail rides the ravel's own concatenate, so the
-            # leaves are written straight into the (n, d_pad) buffer
-            # (padding the ravelled row afterwards costs a copy of it)
+            # the zero tail rides the ravel's own concatenate (padding
+            # the ravelled row afterwards costs a copy of it)
             g = (g, jnp.zeros((row_width - d,), param_dtype))
         flat = ravel(g)
         if grad_dtype is not None:
@@ -372,8 +386,10 @@ def build_ps_train_step(
         opt_state0 = (opt_state0, ef0)
 
     def build_matrix(grads_n, key):
-        """Honest rows + byzantine rows from the (n, d) per-node gradient
-        stack (pure function of the rows — runs node-sharded in the
+        """Honest rows + byzantine rows, ``(n, width)``, from the
+        per-node gradient stack: its first h rows are the honest
+        workers'; the stack has only those on one device and all n on a
+        mesh (pure function of the rows — runs node-sharded in the
         uncompressed fabric, feature-sharded after a compressed
         transpose; all attacks are coordinate-wise over the node axis,
         so both layouts partition cleanly)."""
@@ -395,12 +411,18 @@ def build_ps_train_step(
             # an attack need not map zero columns to zero (additive
             # noise): the pad tail of its rows is forced back to zero
             byz = jnp.where(jnp.arange(width) < d, byz, 0)
-        # The byzantine rows are selected into the stack where it stands:
-        # one elementwise pass over the matrix, run in place. (Rows are
-        # sublanes of the TPU's (8, 128) tiles, so a two-row
+        if grads_n.shape[0] != cfg.n_nodes:
+            # the honest stack alone: the b missing rows open up inside
+            # the pass below (the pad fuses into the select)
+            grads_n = jax.lax.pad(
+                grads_n, jnp.zeros((), grads_n.dtype), ((0, b, 0), (0, 0, 0))
+            )
+        # The byzantine rows are selected into the stack: one elementwise
+        # pass over the matrix, in place where the stack has n rows. (Rows
+        # are sublanes of the TPU's (8, 128) tiles, so a two-row
         # dynamic-update-slice touches every tile too, as 1 KB DMA chunks,
         # and measured slower than this pass or the concatenate it
-        # replaces.)
+        # replaces; an (h, .) stack fills the same tiles as an (n, .) one.)
         at = jnp.arange(cfg.n_nodes)[:, None]
         if rows_given == 1:
             return jnp.where(at >= h, byz, grads_n)
@@ -452,19 +474,37 @@ def build_ps_train_step(
             if node_spec is not None:
                 xs = jax.lax.with_sharding_constraint(xs, node_spec)
                 ys = jax.lax.with_sharding_constraint(ys, node_spec)
-            # Every node's forward/backward runs in parallel across the
-            # mesh: vmap over the node axis of node-sharded data with
-            # replicated params.
-            losses, grads = jax.vmap(per_node_grad, in_axes=(None, 0, 0))(
-                params, xs, ys
-            )
             if mesh is None:
-                # The matrix is complete here, in one buffer. Without the
-                # barrier the TPU compiler sinks the kernel wrapper's
-                # reshape through build_matrix's select into the ravel's
-                # concatenate and writes the whole matrix twice (two chains
-                # of dynamic-update-slices, two buffers).
-                grads = jax.lax.optimization_barrier(grads)
+                # One device: nothing reads a byzantine worker's own
+                # gradient or loss (its row of the matrix is the attack's),
+                # so only the h honest workers run, and one after another:
+                # under vmap the TPU compiler turns each convolution's
+                # per-worker weight gradient into one grouped convolution
+                # over the worker axis and relays activations out around
+                # the merged-batch convolutions, at about twice the cost a
+                # worker for ResNet-18 and none less for an MLP
+                # (docs/performance.md). With no byzantine worker the
+                # slices are the whole arrays and emit nothing.
+                # A row is stacked as (row_width / 128, 128): whole (8, 128)
+                # tiles, contiguous at one index of the stack. As one row
+                # of an (h, row_width) array it is a sublane of every tile
+                # and costs ten times as much to write. (Any width the
+                # stream kernels read in place is a multiple of 1024.)
+                row_shape = (-1, 128) if row_width % 1024 == 0 else (row_width,)
+
+                def one_worker(xy):
+                    loss, flat = per_node_grad(params, *xy)
+                    return loss, flat.reshape(row_shape)
+
+                losses, grads = jax.lax.map(one_worker, (xs[:h], ys[:h]))
+                grads = grads.reshape(h, row_width)
+            else:
+                # Every node's forward/backward runs in parallel across
+                # the mesh: vmap over the node axis of node-sharded data
+                # with replicated params.
+                losses, grads = jax.vmap(per_node_grad, in_axes=(None, 0, 0))(
+                    params, xs, ys
+                )
         if feat_spec is not None and comm.enabled:
             # Compressed fabric: every node's RAW gradient row crosses the
             # wire encoded (exactly what a deployment ships — byzantine

@@ -231,10 +231,12 @@ def _matrix_rebuilds(step, args, shape):
 
 @pytest.mark.parametrize("wide", [False, True])
 def test_the_matrix_is_built_by_the_ravel_alone(monkeypatch, bundle, batches, wide):
-    """The one ``concatenate`` whose result is the whole matrix is the
-    ravel's, under ``vmap`` in ``round.fwdbwd`` (on the chip: a chain of
-    in-place ``dynamic-update-slice``s into the one buffer); nothing
-    after it concatenates or pads the matrix again."""
+    """Each honest worker's row is made by one ``concatenate``, the
+    ravel's (leaves and zero tail together), inside the one-device loop
+    in ``round.fwdbwd``; the loop stacks the rows; the one op that makes
+    the n-row matrix of the stack is the ``pad`` the byzantine rows are
+    selected into (one fused pass on the chip); nothing concatenates or
+    pads rows or matrix again."""
     if wide:
         monkeypatch.setattr(pallas_kernels, "aligned_width", _round_to_128)
     d = tree_size(bundle.params)
@@ -242,9 +244,13 @@ def test_the_matrix_is_built_by_the_ravel_alone(monkeypatch, bundle, batches, wi
     step, opt_state = build_ps_train_step(
         bundle, AGGREGATORS["trimmed_mean"], CFG, attack=ATTACKS["noise"])
     xs, ys, keys = batches
-    rebuilds = _matrix_rebuilds(step, (bundle.params, opt_state, xs[0], ys[0], keys[0]), (N, width))
-    assert [name for name, _ in rebuilds] == ["concatenate"]
-    assert "round.fwdbwd" in rebuilds[0][1]
+    args = (bundle.params, opt_state, xs[0], ys[0], keys[0])
+    rows = _matrix_rebuilds(step, args, (width,))
+    assert [name for name, _ in rows] == ["concatenate"]
+    assert _matrix_rebuilds(step, args, (CFG.n_honest, width)) == []
+    rebuilds = _matrix_rebuilds(step, args, (N, width))
+    assert [name for name, _ in rebuilds] == ["pad"]
+    assert "round.build_matrix" in rebuilds[0][1]
     # the reference round above does rebuild it: the probe sees a second one
     ref_step, ref_opt = _concatenating_step(bundle, AGGREGATORS["trimmed_mean"], ATTACKS["noise"])
     ref = _matrix_rebuilds(ref_step, (bundle.params, ref_opt, xs[0], ys[0], keys[0]), (N, d))
