@@ -162,15 +162,20 @@ def _keys_to_float(keys: Array, dtype) -> Array:
     return jax.lax.bitcast_convert_type(keys, dtype)
 
 
+def _batcher_network(rows):
+    """``rows`` (equal-shaped arrays, one per worker) put in ascending
+    order elementwise by Batcher's network of min/max. The shape of a row
+    is the caller's: every exchange is dense over it."""
+    rows = list(rows)
+    for i, j in batcher_pairs(len(rows)):
+        rows[i], rows[j] = jnp.minimum(rows[i], rows[j]), jnp.maximum(rows[i], rows[j])
+    return rows
+
+
 def _batcher_sort_rows(keys: Array, n_rows: int) -> Array:
     """Sort each column of ``keys`` (first axis ascending) via Batcher's
     network of elementwise min/max; ``n_rows`` is static."""
-    rows = [keys[i] for i in range(n_rows)]
-    for i, j in batcher_pairs(n_rows):
-        lo = jnp.minimum(rows[i], rows[j])
-        hi = jnp.maximum(rows[i], rows[j])
-        rows[i], rows[j] = lo, hi
-    return jnp.stack(rows)
+    return jnp.stack(_batcher_network([keys[i] for i in range(n_rows)]))
 
 
 def _sort_columns_kernel(x_ref, out_ref, *, n_rows: int, is_float: bool):
@@ -381,35 +386,66 @@ def pairwise_sq_dists_pallas(
 _INF_KEY = 0x7F800000  # sort key of +inf; canonical NaN keys upper-bound it
 
 
-def _sorted_reduce_stream_kernel(
-    x_ref, o_ref, *, n_pad: int, n_real: int, f: int, mode: str,
-):
-    """Per feature tile: key-sort the column block in VMEM and emit ONLY
-    the reduction — the coordinate median or the f-trimmed mean — so the
-    sorted matrix never returns to HBM. Traffic per round: 1 read of
-    ``x`` + a (1, d) write, vs sort_columns' read + full write + the
-    reduction's re-read. Padded rows carry the absolute max key (above
-    canonical NaN), so positions [0, n_real) hold exactly the real
-    ordering; a column contains a real NaN iff sorted position
-    ``n_real - 1`` holds a NaN key. Means/midpoints accumulate in f32 and
-    cast to the output dtype at the end (the midpoint is computed in the
-    output dtype to match ``jnp.median`` bit-for-bit on 16-bit floats)."""
-    blk = x_ref[0].astype(jnp.float32)
-    keys = _float_sort_keys(blk)
-    row_i = lax.broadcasted_iota(jnp.int32, keys.shape, 0)
-    keys = jnp.where(row_i >= n_real, jnp.iinfo(jnp.int32).max, keys)
-    srt = _batcher_sort_rows(keys, n_pad)
+def _sublane_order_sum(vals):
+    """``vals[0] + ... + vals[m-1]`` associated as Mosaic associates a sum
+    over the sublanes of (8, 128) tiles, which is how the trimmed sum was
+    taken while the window's rows were sublanes of one block: the values
+    at window positions c, c + 8, c + 16, ... added in that order (vreg
+    after vreg), then the eight partial sums folded 8 -> 4 -> 2 -> 1 (the
+    sublane rotate-and-add), a position the window does not reach left
+    out. Four values give ``(v0 + v2) + (v1 + v3)``. Where the window was
+    not whole vregs (``m`` no multiple of eight, and more than the one
+    row that needs no sum) the sublanes past its end entered that sum as
+    +0.0, which shows in one case only: a window of nothing but -0.0
+    sums to +0.0. Keeping order and sign keeps the aggregate's bits on
+    the TPU (PERF.md section 6, PR 29); a plain left-to-right sum is an
+    ulp away on a third of the columns."""
+    sums = [functools.reduce(jnp.add, vals[c::_SUBLANES])
+            for c in range(min(_SUBLANES, len(vals)))]
+    width = _SUBLANES
+    while width > 1:
+        width //= 2
+        sums = [sums[c] + sums[c + width] if c + width < len(sums) else sums[c]
+                for c in range(min(width, len(sums)))]
+    total = sums[0]
+    if len(vals) > 1 and len(vals) % _SUBLANES:
+        # total + 0.0, which XLA (the interpreter's compiler) folds away
+        total = jnp.where(total == 0.0, 0.0, total)
+    return total
+
+
+def _sorted_reduce_stream_kernel(x_ref, o_ref, *, n: int, f: int, mode: str):
+    """Per block of folded rows: key-sort the ``n`` workers' values of
+    every coordinate in VMEM and emit ONLY the reduction — the coordinate
+    median or the f-trimmed mean — so the sorted matrix never returns to
+    HBM. Traffic per round: 1 read of ``x`` + a (1, d) write, vs
+    sort_columns' read + full write + the reduction's re-read.
+
+    The block is ``(1, n, r, 128)``: worker ``i``'s part of it,
+    ``x_ref[0, i]``, is ``r / 8`` whole (8, 128) vregs, so the key
+    transform, each compare-exchange and the trimmed sum are dense
+    elementwise ops over full vregs, and ``n`` is whatever it is (no
+    rows padded to a sublane multiple, none to mask). A column contains
+    a NaN iff the last sorted key is a NaN key. Means/midpoints
+    accumulate in f32 and cast to the output dtype at the end (the
+    midpoint is computed in the output dtype to match ``jnp.median``
+    bit-for-bit on 16-bit floats); the trimmed sum keeps the order it
+    had on the TPU as a reduction over sublanes
+    (:func:`_sublane_order_sum`)."""
+    srt = _batcher_network(
+        _float_sort_keys(x_ref[0, i].astype(jnp.float32)) for i in range(n)
+    )
     if mode == "median":
-        lo, hi = (n_real - 1) // 2, n_real // 2
+        lo, hi = (n - 1) // 2, n // 2
         vlo = _keys_to_float(srt[lo], jnp.float32).astype(o_ref.dtype)
         vhi = _keys_to_float(srt[hi], jnp.float32).astype(o_ref.dtype)
         out = (vlo + vhi) * jnp.asarray(0.5, o_ref.dtype)
-        has_nan = srt[n_real - 1] > _INF_KEY
+        has_nan = srt[n - 1] > _INF_KEY
         out = jnp.where(has_nan, jnp.asarray(jnp.nan, o_ref.dtype), out)
-    else:  # trimmed mean of rows [f, n_real - f)
-        vals = _keys_to_float(srt[f:n_real - f], jnp.float32)
-        out = (jnp.sum(vals, axis=0) / (n_real - 2 * f)).astype(o_ref.dtype)
-    o_ref[0] = out[None, :]
+    else:  # trimmed mean of sorted rows [f, n - f)
+        vals = [_keys_to_float(k, jnp.float32) for k in srt[f:n - f]]
+        out = (_sublane_order_sum(vals) / (n - 2 * f)).astype(o_ref.dtype)
+    o_ref[0] = out
 
 
 def sorted_reduce_stream_pallas(
@@ -423,9 +459,27 @@ def sorted_reduce_stream_pallas(
     """Coordinate-wise median (``mode='median'``) or f-trimmed mean
     (``mode='trimmed'``) over ``K`` stacked rounds ``xs: (K, n, d)`` in
     one kernel launch, returning ``(K, d)``. Float dtypes only (16-bit
-    floats up-convert per-tile in VMEM — half the HBM traffic of a
-    pre-pass conversion). Tile resolved pre-trace (family
-    ``"sorted_reduce"``)."""
+    floats up-convert per-block in VMEM — half the HBM traffic of a
+    pre-pass conversion).
+
+    The kernel reads FOLDED rows: the wrapper reshapes its argument to
+    ``(K, n, d_pad / 128, 128)``, so that a worker's row is whole
+    (8, 128) tiles and not one sublane of every tile of an ``(n, d)``
+    matrix (where every op of the network would run on an eighth of a
+    vreg). A caller that holds its rows folded already and hands over
+    ``stack.reshape(n, d_pad)`` pays nothing: the two reshapes cancel in
+    the compiler and the kernel's operand is the caller's buffer (the
+    one-device round of ``parallel.ps.build_ps_train_step``). A caller
+    that holds an ``(n, d)`` matrix pays XLA's one relayout pass in
+    front of the kernel (and, for an unaligned ``d``, the zero pad as a
+    pass of its own in front of that). The result ``(K, d_pad / 128,
+    128)`` is the flat ``(K, d_pad)`` vector in memory. ``tile`` stays a
+    column count (the block holds ``tile / 128`` sublane rows of each
+    worker), resolved pre-trace (family ``"sorted_reduce"``) and, for
+    Mosaic, rounded up to whole native tiles of those rows: 1024 columns
+    of f32, 2048 of a 16-bit dtype; a ``tile`` given by the caller that
+    is not such a multiple raises there. The interpreter takes any
+    multiple of 128."""
     if mode not in {"median", "trimmed"}:
         raise ValueError(f"unknown mode {mode!r}")
     K, n, d = xs.shape
@@ -434,11 +488,26 @@ def sorted_reduce_stream_pallas(
     if xs.dtype not in (jnp.bfloat16, jnp.float16, jnp.float32):
         raise ValueError(f"unsupported dtype {xs.dtype}")
     interpret = _resolve_interpret(interpret)
-    n_pad = max(_SUBLANES, _round_up(n, _SUBLANES))
+    # a block holds tile / 128 sublane rows of each worker, in whole
+    # native tiles: (8, 128) of f32 (Mosaic refuses fewer rows), (16, 128)
+    # of a 16-bit dtype (eight compile, as half-filled tiles); or the
+    # whole array, however short
+    whole = _LANES * _SUBLANES * (4 // xs.dtype.itemsize)
     if tile is None:
+        # the tile cache keys every family by the sublane-padded row count
+        n_key = max(_SUBLANES, _round_up(n, _SUBLANES))
         # sort happens on f32 rows in VMEM regardless of input dtype
-        tile = _tuned_tile("sorted_reduce", n_pad, d) or _auto_sort_tile(
-            d, n_pad
+        tile = _tuned_tile("sorted_reduce", n_key, d) or _auto_sort_tile(d, n)
+        if not interpret:
+            # a narrower tile than Mosaic's block can be (the heuristic's
+            # for an odd d, an old cache entry, the environment's)
+            tile = _round_up(tile, whole)
+    elif tile % _LANES:
+        raise ValueError(f"tile must be a multiple of {_LANES} columns (got {tile})")
+    elif not interpret and tile % whole and tile < d:
+        raise ValueError(
+            f"a {xs.dtype} block of {tile} columns is {tile // _LANES} sublane rows a "
+            f"worker; Mosaic is given whole tiles: a multiple of {whole} columns"
         )
     return _sorted_reduce_stream_call(
         xs, mode=mode, f=f, tile=tile, interpret=interpret
@@ -450,32 +519,27 @@ def _sorted_reduce_stream_call(
     xs: Array, *, mode: str, f: int, tile: int, interpret: bool
 ) -> Array:
     K, n, d = xs.shape
-    n_pad = max(_SUBLANES, _round_up(n, _SUBLANES))
     d_pad = _round_up(max(d, 1), tile)
-    if (n_pad, d_pad) == (n, d):
-        xp = xs
-    else:
-        xp = jnp.zeros((K, n_pad, d_pad), xs.dtype).at[:, :n, :d].set(xs)
-
+    if d_pad != d:
+        xs = jnp.pad(xs, ((0, 0), (0, 0), (0, d_pad - d)))
+    rows, r = d_pad // _LANES, tile // _LANES
     out = pl.pallas_call(
-        functools.partial(
-            _sorted_reduce_stream_kernel, n_pad=n_pad, n_real=n, f=f, mode=mode
-        ),
-        out_shape=jax.ShapeDtypeStruct((K, 1, d_pad), xs.dtype),
-        grid=(K, d_pad // tile),
+        functools.partial(_sorted_reduce_stream_kernel, n=n, f=f, mode=mode),
+        out_shape=jax.ShapeDtypeStruct((K, rows, _LANES), xs.dtype),
+        grid=(K, rows // r),
         in_specs=[
             pl.BlockSpec(
-                (1, n_pad, tile), lambda k, c: (k, 0, c),
+                (1, n, r, _LANES), lambda k, c: (k, 0, c, 0),
                 memory_space=pltpu.VMEM,
             )
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, tile), lambda k, c: (k, 0, c), memory_space=pltpu.VMEM
+            (1, r, _LANES), lambda k, c: (k, c, 0), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
         name="sorted_reduce_stream",
-    )(xp)
-    return out[:, 0, :d]
+    )(xs.reshape(K, n, rows, _LANES))
+    return out.reshape(K, d_pad)[:, :d]
 
 
 # ---------------------------------------------------------------------------
@@ -2310,7 +2374,16 @@ def aligned_width(n: int, d: int) -> int:
     ``xp = xs`` path instead of a zero-padded copy of the whole matrix.
     The caller keeps the extra columns exactly zero (every shipped
     aggregator maps all-zero columns to zero and leaves row norms and
-    Gram blocks unchanged) and cuts the result back to ``d``."""
+    Gram blocks unchanged) and cuts the result back to ``d``.
+
+    A rounded-up width is a multiple of 1024, so a row of it FOLDS:
+    ``(width / 128, 128)`` is whole (8, 128) tiles of f32, the same
+    bytes in the same order as the flat row. A caller that keeps its
+    rows folded, stacked ``(n, width / 128, 128)``, writes and reads a
+    row as whole tiles, and the sort family's kernel
+    (:func:`sorted_reduce_stream_pallas`) reads that stack as it is; as
+    rows of an ``(n, width)`` matrix they are one sublane of every tile
+    each (``docs/performance.md``, "A folded row")."""
     if not use_pallas_for(n, d):
         return d
     return _round_up(d, _WIDEST_TILE)

@@ -222,8 +222,8 @@ def build_ps_train_step(
     the attack's, or an honest row echoed; ``honest_loss`` is the honest
     mean). On one device (no mesh) the step therefore runs
     forward/backward for the first ``h = n_nodes - n_byzantine`` workers
-    only, one after another (``lax.map``; ``xs[h:]``, ``ys[h:]`` are not
-    read): on the TPU a convolutional model's per-worker gradients cost
+    only, one after another (a ``fori_loop``; ``xs[h:]``, ``ys[h:]`` are
+    not read): on the TPU a convolutional model's per-worker gradients cost
     about half as much one worker at a time as vmapped, and no more for
     an MLP (``docs/performance.md``). Signature, shapes, state, metrics
     and values are those of a round that computes all n rows and
@@ -237,10 +237,23 @@ def build_ps_train_step(
     kernels read in place
     (:func:`~byzpy_tpu.ops.pallas_kernels.aligned_width`; ``d`` wherever
     they will not serve the matrix) and each computed row is ravelled
-    at that width; on a mesh it is the sharded update's grid. The
-    byzantine rows are selected into the stack in one pass (in place
-    where the stack has n rows; as the ``(h, ·)`` stack is padded to n
-    rows on one device), their tail forced to zero.
+    at that width; on a mesh it is the sharded update's grid.
+
+    On one device a row is kept FOLDED wherever ``d_pad % 1024 == 0``:
+    shaped ``(d_pad / 128, 128)``, whole (8, 128) TPU tiles, and not one
+    sublane of every tile of an ``(n, d_pad)`` matrix. The loop writes
+    row i of the ``(n, d_pad / 128, 128)`` stack it carries, the
+    byzantine rows (their tail forced to zero) are written into rows
+    h..n-1 of the same buffer, and ``pre_aggregate`` / ``aggregate`` are
+    handed ``stack.reshape(n, d_pad)``. Who relays out is decided by the
+    compiler from what that function does with it: the sort family's
+    kernel folds its argument again and reads the loop's buffer; a
+    consumer that wants the workers in sublanes (Multi-Krum's Gram, any
+    XLA sort) gets the one relayout pass it needs, where it reads the
+    matrix (``docs/performance.md``, "A folded row"). On a mesh the
+    ``(n, d)`` stack has all n rows and the byzantine ones are selected
+    into it in one pass.
+
     ``pre_aggregate`` and ``aggregate`` see the ``(n, d_pad)`` matrix
     and must map all-zero columns to zero without changing the other
     columns (every shipped one does, ``docs/performance.md``); the
@@ -329,6 +342,14 @@ def build_ps_train_step(
     # row is born d_pad wide and the matrix is never rebuilt; on a mesh
     # the rows cross the wire d wide and are padded after the transpose.
     row_width = d_pad if mesh is None else d
+    # On one device a row is FOLDED wherever it can be: (row_width / 128,
+    # 128), whole (8, 128) tiles, contiguous at one index of the stack. As
+    # one row of an (n, row_width) array it is a sublane of every tile:
+    # ten times the cost to write, and an eighth of every vreg to compute
+    # on. (Any width the stream kernels read in place is a multiple of
+    # 1024.)
+    row_shape = (row_width // 128, 128) if row_width % 1024 == 0 else (row_width,)
+
     def per_node_grad(params, x, y):
         loss, g = jax.value_and_grad(loss_fn)(params, x, y)
         if row_width != d:
@@ -386,16 +407,22 @@ def build_ps_train_step(
         opt_state0 = (opt_state0, ef0)
 
     def build_matrix(grads_n, key):
-        """Honest rows + byzantine rows, ``(n, width)``, from the
-        per-node gradient stack: its first h rows are the honest
-        workers'; the stack has only those on one device and all n on a
-        mesh (pure function of the rows — runs node-sharded in the
-        uncompressed fabric, feature-sharded after a compressed
-        transpose; all attacks are coordinate-wise over the node axis,
-        so both layouts partition cleanly)."""
+        """Honest rows + byzantine rows from the per-node gradient stack,
+        whose first h rows are the honest workers'. On a mesh the stack
+        is ``(n, width)`` and all n rows are computed (pure function of
+        the rows — runs node-sharded in the uncompressed fabric,
+        feature-sharded after a compressed transpose; all attacks are
+        coordinate-wise over the node axis, so both layouts partition
+        cleanly). On one device it is the loop's ``(n, *row_shape)``
+        buffer with rows h..n-1 still to write, and it is returned in
+        that shape."""
         if not b:
             return grads_n
         honest = grads_n[:h]
+        if mesh is None:
+            # the attack is the caller's (h, width) function; a reduction
+            # over workers reads the folded rows all the same
+            honest = honest.reshape(h, row_width)
         if attack is not None:
             byz = jnp.asarray(attack(honest, key))
         else:
@@ -411,18 +438,17 @@ def build_ps_train_step(
             # an attack need not map zero columns to zero (additive
             # noise): the pad tail of its rows is forced back to zero
             byz = jnp.where(jnp.arange(width) < d, byz, 0)
-        if grads_n.shape[0] != cfg.n_nodes:
-            # the honest stack alone: the b missing rows open up inside
-            # the pass below (the pad fuses into the select)
-            grads_n = jax.lax.pad(
-                grads_n, jnp.zeros((), grads_n.dtype), ((0, b, 0), (0, 0, 0))
-            )
-        # The byzantine rows are selected into the stack: one elementwise
-        # pass over the matrix, in place where the stack has n rows. (Rows
-        # are sublanes of the TPU's (8, 128) tiles, so a two-row
-        # dynamic-update-slice touches every tile too, as 1 KB DMA chunks,
-        # and measured slower than this pass or the concatenate it
-        # replaces; an (h, .) stack fills the same tiles as an (n, .) one.)
+        if mesh is None:
+            # Row writes into the loop's own buffer: a folded row is whole
+            # tiles, so b rows cost b rows' bytes and the other rows are
+            # not touched.
+            byz = jnp.broadcast_to(byz.reshape(rows_given, *row_shape), (b, *row_shape))
+            return grads_n.at[h:].set(byz)
+        # The byzantine rows are selected into the (n, width) matrix: one
+        # elementwise pass over it, in place. (Its rows are sublanes of the
+        # TPU's (8, 128) tiles, so a two-row dynamic-update-slice touches
+        # every tile too, as 1 KB DMA chunks, and measured slower than this
+        # pass or the concatenate it replaces.)
         at = jnp.arange(cfg.n_nodes)[:, None]
         if rows_given == 1:
             return jnp.where(at >= h, byz, grads_n)
@@ -483,21 +509,27 @@ def build_ps_train_step(
                 # over the worker axis and relays activations out around
                 # the merged-batch convolutions, at about twice the cost a
                 # worker for ResNet-18 and none less for an MLP
-                # (docs/performance.md). With no byzantine worker the
-                # slices are the whole arrays and emit nothing.
-                # A row is stacked as (row_width / 128, 128): whole (8, 128)
-                # tiles, contiguous at one index of the stack. As one row
-                # of an (h, row_width) array it is a sublane of every tile
-                # and costs ten times as much to write. (Any width the
-                # stream kernels read in place is a multiple of 1024.)
-                row_shape = (-1, 128) if row_width % 1024 == 0 else (row_width,)
+                # (docs/performance.md). The loop carries the n-row stack
+                # and writes row i (what lax.map does with h rows): the
+                # byzantine rows are written into the same buffer after it.
+                # With no byzantine worker the slices are the whole arrays
+                # and emit nothing.
+                xs_h, ys_h = xs[:h], ys[:h]
+                loss0, row0 = jax.eval_shape(
+                    lambda: per_node_grad(params, xs_h[0], ys_h[0]))
 
-                def one_worker(xy):
-                    loss, flat = per_node_grad(params, *xy)
-                    return loss, flat.reshape(row_shape)
+                def one_worker(i, carry):
+                    losses, grads = carry
+                    loss, flat = per_node_grad(params, xs_h[i], ys_h[i])
+                    put = jax.lax.dynamic_update_index_in_dim
+                    return (put(losses, loss, i, 0),
+                            put(grads, flat.reshape(row_shape), i, 0))
 
-                losses, grads = jax.lax.map(one_worker, (xs[:h], ys[:h]))
-                grads = grads.reshape(h, row_width)
+                # (an uninitialised buffer: every row is written, h here
+                # and b by the attack; zeros would cost a pass over it)
+                losses, grads = jax.lax.fori_loop(0, h, one_worker, (
+                    jnp.zeros((h,), loss0.dtype),
+                    jax.lax.empty((cfg.n_nodes, *row_shape), row0.dtype)))
             else:
                 # Every node's forward/backward runs in parallel across
                 # the mesh: vmap over the node axis of node-sharded data
@@ -529,6 +561,13 @@ def build_ps_train_step(
         else:
             with jax.named_scope("round.build_matrix"):
                 matrix = build_matrix(grads, key)
+                if mesh is None:
+                    # The aggregate is the caller's (n, d_pad) function. One
+                    # that folds its rows again (the sort family's kernel)
+                    # cancels this reshape and reads the loop's buffer; one
+                    # that wants workers in sublanes (a Gram, any XLA
+                    # route) makes the compiler emit the one relayout here.
+                    matrix = matrix.reshape(cfg.n_nodes, row_width)
             if feat_spec is not None:
                 # Gradient transpose: node-sharded rows -> feature-sharded
                 # columns (XLA lowers this constraint to an all_to_all over

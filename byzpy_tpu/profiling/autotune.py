@@ -46,7 +46,7 @@ CANDIDATES: Dict[str, Tuple[int, ...]] = {
     "sort": (1024, 2048, 4096, 8192),
     "gram": (512, 1024, 2048, 4096, 8192),
     "selection": (2048, 4096, 8192, 16384),
-    "sorted_reduce": (512, 1024, 2048, 4096),
+    "sorted_reduce": (1024, 2048, 4096, 8192, 16384),
     "meamed": (256, 512, 1024, 2048),
     "quant": (1024, 2048, 4096, 8192, 16384),
     "quant_fp8": (1024, 2048, 4096, 8192, 16384),

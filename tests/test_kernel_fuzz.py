@@ -56,6 +56,29 @@ def test_fuzz_selection_mean_krum(seed):
     )
 
 
+def _sum_over_sublanes(window):
+    """The f32 sum of ``window``'s rows in the order the kernel adds
+    them, which is the TPU's for a sum over the sublanes of (8, 128)
+    tiles (``pallas_kernels._sublane_order_sum``; PERF.md section 6, PR
+    29), spelled as the hardware does it: the rows sit at the first
+    sublanes of zeroed eight-row vregs, the vregs are added one after
+    another, then the eight partial sums by rotate-and-add (4, 2, 1);
+    one row is itself. A top-to-bottom ``jnp.mean`` is an ulp away on a
+    third of the columns, and further where the window cancels."""
+    m, width = window.shape
+    if m == 1:
+        return window[0]
+    block = np.zeros((-(-m // 8) * 8, width), np.float32)
+    block[:m] = window
+    with np.errstate(invalid="ignore"):  # inf - inf is the kernel's NaN too
+        acc = block[:8].copy()
+        for at in range(8, len(block), 8):
+            acc = acc + block[at:at + 8]
+        for shift in (4, 2, 1):
+            acc = acc + np.roll(acc, -shift, axis=0)
+    return acc[0]
+
+
 @pytest.mark.parametrize("seed", range(N_CASES))
 def test_fuzz_sorted_reduce(seed):
     n, d, x = _random_case(2000 + seed)
@@ -71,8 +94,8 @@ def test_fuzz_sorted_reduce(seed):
         got = sorted_reduce_stream_pallas(
             xa[None], mode="trimmed", f=f, tile=128, interpret=True
         )[0]
-        s = jnp.sort(xa, axis=0)
-        want = jnp.mean(s[f : n - f], axis=0)
+        s = np.asarray(jnp.sort(xa, axis=0))
+        want = _sum_over_sublanes(s[f : n - f]) / np.float32(n - 2 * f)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6,
             equal_nan=True,

@@ -597,6 +597,224 @@ def test_coordinate_median_dispatches_to_fused_reduce(monkeypatch):
     )
 
 
+def _sum_over_sublanes(vals):
+    """``jnp.sum(vals, axis=0)`` as Mosaic takes it where the rows of
+    ``vals`` are sublanes of (8, 128) tiles: the window brought to the
+    first sublane, the sublanes past its end read as zero, the eight-row
+    vregs added one after another, then the sublane rotate-and-add (by 4,
+    2, 1); one row is itself. Measured on the v5e against the kernel
+    below, the sign of a sum of nothing but -0.0 included (PERF.md
+    section 6, PR 29); the interpreter's own ``jnp.sum`` adds top to
+    bottom, an ulp away on a third of the columns."""
+    m, width = vals.shape
+    if m == 1:
+        return vals[0]
+    rows = -(-m // 8) * 8
+    block = vals if rows == m else jnp.zeros((rows, width), vals.dtype).at[:m].set(vals)
+    acc = block[:8]
+    for at in range(8, rows, 8):
+        acc = acc + block[at:at + 8]
+    for shift in (4, 2, 1):
+        acc = acc + jnp.roll(acc, -shift, axis=0)
+    return acc[0]
+
+
+def _sublane_row_sorted_reduce(xs, *, mode, f=0, tile=128, sum_rows=_sum_over_sublanes):
+    """The sorted-reduce kernel as it was while a worker's row was a
+    SUBLANE of the block (``(1, n_pad, tile)`` blocks, rows padded to
+    eight with max-key rows, the trimmed sum a reduction over sublanes,
+    here spelled in the order the TPU gives it): kept, interpreted, as
+    the oracle of the folded body that replaced it in the library."""
+    from jax import lax
+    from jax.experimental import pallas as pl
+
+    from byzpy_tpu.ops import pallas_kernels as pk
+
+    K, n, d = xs.shape
+    n_pad = max(8, -(-n // 8) * 8)
+    d_pad = -(-d // tile) * tile
+
+    def kernel(x_ref, o_ref):
+        blk = x_ref[0].astype(jnp.float32)
+        keys = pk._float_sort_keys(blk)
+        row_i = lax.broadcasted_iota(jnp.int32, keys.shape, 0)
+        keys = jnp.where(row_i >= n, jnp.iinfo(jnp.int32).max, keys)
+        srt = pk._batcher_sort_rows(keys, n_pad)
+        if mode == "median":
+            lo, hi = (n - 1) // 2, n // 2
+            vlo = pk._keys_to_float(srt[lo], jnp.float32).astype(o_ref.dtype)
+            vhi = pk._keys_to_float(srt[hi], jnp.float32).astype(o_ref.dtype)
+            out = (vlo + vhi) * jnp.asarray(0.5, o_ref.dtype)
+            has_nan = srt[n - 1] > pk._INF_KEY
+            out = jnp.where(has_nan, jnp.asarray(jnp.nan, o_ref.dtype), out)
+        else:
+            vals = pk._keys_to_float(srt[f:n - f], jnp.float32)
+            out = (sum_rows(vals) / (n - 2 * f)).astype(o_ref.dtype)
+        o_ref[0] = out[None, :]
+
+    xp = jnp.zeros((K, n_pad, d_pad), xs.dtype).at[:, :n, :d].set(xs)
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((K, 1, d_pad), xs.dtype),
+        grid=(K, d_pad // tile),
+        in_specs=[pl.BlockSpec((1, n_pad, tile), lambda k, c: (k, 0, c))],
+        out_specs=pl.BlockSpec((1, 1, tile), lambda k, c: (k, 0, c)),
+        interpret=True,
+    )(xp)
+    return out[:, 0, :d]
+
+
+def _rows_with_every_hard_case(n, d, dtype):
+    """Seeded rows with NaN, both infinities, -0.0 beside +0.0, -0.0
+    alone and tied columns, some columns holding several of them."""
+    a = np.asarray(jax.random.normal(jax.random.PRNGKey(n * 7919 + d), (2, n, d))).copy()
+    a = a.astype(np.float32) * 4
+    a[0, 1 % n, ::5] = np.inf
+    a[0, 2 % n, ::7] = -np.inf
+    a[0, 0, ::9] = np.nan
+    a[1, :, 3] = np.nan  # a column of nothing else
+    a[0, :, 4] = 1.5  # every worker tied
+    a[0, : n // 2, 6] = -0.0
+    a[0, n // 2:, 6] = 0.0
+    a[1, :, 8] = np.where(np.arange(n) % 2, -0.0, 0.0)
+    a[1, ::2, 10:20] = a[1, 1 % n, 10:20]  # ties among some workers
+    a[1, :, 11] = np.inf
+    # nothing but -0.0 in the window: the zeros a sublane reduction adds
+    # past the end of a window that is not whole vregs turn its -0.0 into +0.0
+    a[0, :, 12] = -0.0
+    f = (n - 1) // 3
+    a[1, :, 13] = np.where(np.arange(n) < f, -2.0, np.where(np.arange(n) >= n - f, 2.0, -0.0))
+    return jnp.asarray(a).astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("d", [384, 300], ids=["aligned", "unaligned"])
+@pytest.mark.parametrize("n", [3, 6, 8, 9, 16])
+@pytest.mark.parametrize("mode", ["median", "trimmed"])
+def test_folded_sorted_reduce_equals_the_sublane_row_body_bitwise(mode, n, d, dtype):
+    """Same keys, same network, same order of the trimmed sum: the bits
+    of the sublane-row kernel, NaN, infinities, signed zeros and ties
+    included; and the values of a ``jnp.sort`` reference."""
+    from byzpy_tpu.ops.pallas_kernels import sorted_reduce_stream_pallas
+
+    xs = _rows_with_every_hard_case(n, d, dtype)
+    f = (n - 1) // 3 if mode == "trimmed" else 0
+    got = sorted_reduce_stream_pallas(xs, mode=mode, f=f, tile=128, interpret=True)
+    want = _sublane_row_sorted_reduce(xs, mode=mode, f=f)
+    assert got.dtype == want.dtype == dtype and got.shape == (2, d)
+    np.testing.assert_array_equal(
+        np.asarray(got.astype(jnp.float32)).view(np.uint32),
+        np.asarray(want.astype(jnp.float32)).view(np.uint32),
+    )
+    if mode == "median":
+        ref = jnp.median(xs, axis=1)
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32), np.asarray(ref, np.float32))
+    else:
+        s = jnp.sort(xs.astype(jnp.float32), axis=1)
+        with np.errstate(invalid="ignore"):
+            ref = np.asarray(jnp.mean(s[:, f:n - f], axis=1).astype(dtype), np.float32)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), ref, rtol=1e-2 if dtype == jnp.bfloat16 else 1e-5,
+            atol=1e-6)
+
+
+# (n, f) -> the sign bit of the parent's trimmed mean of a window of
+# nothing but -0.0, read on the v5e (PR 29, second session, 46 cases; a
+# sample): -0.0 where the window is whole vregs (a multiple of eight rows,
+# wherever it starts) or one row, +0.0 otherwise
+_NEGATIVE_ZERO_WINDOWS = [(3, 0, 0), (3, 1, 1), (6, 2, 0), (7, 3, 1), (8, 0, 1), (8, 2, 0),
+                          (9, 2, 0), (10, 1, 1), (12, 2, 1), (16, 3, 0), (16, 4, 1), (24, 6, 0)]
+
+
+@pytest.mark.parametrize("n, f, negative", _NEGATIVE_ZERO_WINDOWS)
+def test_trimmed_mean_of_nothing_but_negative_zero_has_the_tpus_sign(n, f, negative):
+    from byzpy_tpu.ops.pallas_kernels import sorted_reduce_stream_pallas
+
+    rows = np.where(np.arange(n) < f, -2.0, np.where(np.arange(n) >= n - f, 2.0, -0.0))
+    xs = jnp.asarray(np.tile(rows[::-1, None].astype(np.float32), (1, 256)))[None]
+    got = np.asarray(sorted_reduce_stream_pallas(xs, mode="trimmed", f=f, tile=128,
+                                                 interpret=True))
+    oracle = np.asarray(_sublane_row_sorted_reduce(xs, mode="trimmed", f=f))
+    want = np.uint32(0x80000000 if negative else 0)
+    assert set(got.view(np.uint32).ravel()) == {want} == set(oracle.view(np.uint32).ravel())
+
+
+def test_trimmed_sum_is_within_an_ulp_or_two_of_a_top_to_bottom_sum():
+    """The order is the TPU's sublane reduction, not the reading order:
+    against the old body summed with the interpreter's ``jnp.sum`` the
+    folded kernel differs in the last bits of some columns and nowhere by
+    more."""
+    from byzpy_tpu.ops.pallas_kernels import sorted_reduce_stream_pallas
+
+    xs = jax.random.normal(jax.random.PRNGKey(29), (1, 16, 1024), jnp.float32) * 4
+    got = np.asarray(sorted_reduce_stream_pallas(xs, mode="trimmed", f=3, tile=128,
+                                                 interpret=True))
+    top_down = np.asarray(_sublane_row_sorted_reduce(
+        xs, mode="trimmed", f=3, sum_rows=lambda vals: jnp.sum(vals, axis=0)))
+    assert np.any(got != top_down)
+    np.testing.assert_allclose(got, top_down, rtol=0, atol=4 * np.spacing(np.float32(4.0)))
+
+
+def test_sorted_reduce_wider_blocks_and_a_tile_that_is_no_lane_multiple():
+    """A block of several sublane rows a worker (``tile / 128`` of them)
+    gives the one-row block's bits; a tile that cannot be folded is
+    refused before anything is traced."""
+    from byzpy_tpu.ops.pallas_kernels import sorted_reduce_stream_pallas
+
+    xs = _rows_with_every_hard_case(8, 4096, jnp.float32)
+    want = sorted_reduce_stream_pallas(xs, mode="trimmed", f=2, tile=128, interpret=True)
+    for tile in (1024, 2048, 4096):
+        got = sorted_reduce_stream_pallas(xs, mode="trimmed", f=2, tile=tile, interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(got).view(np.uint32), np.asarray(want).view(np.uint32))
+    with pytest.raises(ValueError, match="multiple of 128"):
+        sorted_reduce_stream_pallas(xs, mode="median", tile=100, interpret=True)
+
+
+@pytest.mark.parametrize("dtype, whole", [(jnp.float32, 1024), (jnp.bfloat16, 2048)],
+                         ids=["f32", "bf16"])
+def test_sorted_reduce_resolves_only_blocks_of_whole_native_tiles(monkeypatch, dtype, whole):
+    """A block holds ``tile / 128`` sublane rows of each worker and Mosaic
+    takes whole (8, 128) tiles of f32, (16, 128) of 16-bit rows: a
+    narrower tile from the environment, an old cache entry or the
+    heuristic (an odd ``d``, many workers) is rounded up before the call
+    where Mosaic compiles it; one the caller gives for Mosaic raises,
+    unless it covers the array."""
+    from byzpy_tpu.ops import pallas_kernels as pk
+
+    seen = []
+
+    def recording_call(xs, *, mode, f, tile, interpret):
+        seen.append(tile)
+        return jnp.zeros((xs.shape[0], xs.shape[2]), xs.dtype)
+
+    monkeypatch.setattr(pk, "_sorted_reduce_stream_call", recording_call)
+    xs = jnp.ones((1, 8, 4096), dtype)
+    monkeypatch.setenv("BYZPY_TPU_TILE_SORTED_REDUCE", "512")
+    pk.sorted_reduce_stream_pallas(xs, mode="median", interpret=False)
+    monkeypatch.delenv("BYZPY_TPU_TILE_SORTED_REDUCE")
+    monkeypatch.setattr(pk, "_tuned_tile", lambda family, n, d: 256)  # an old cache entry
+    pk.sorted_reduce_stream_pallas(xs, mode="median", interpret=False)
+    monkeypatch.setattr(pk, "_tuned_tile", lambda family, n, d: None)
+    pk.sorted_reduce_stream_pallas(  # 128 | d only
+        jnp.ones((1, 8, 128 * 33), dtype), mode="median", interpret=False)
+    pk.sorted_reduce_stream_pallas(
+        jnp.ones((1, 128, 1024 * 3), dtype), mode="median", interpret=False)
+    assert seen == [whole] * 4
+    pk.sorted_reduce_stream_pallas(xs, mode="median", interpret=False)  # a wide tile stays
+    pk.sorted_reduce_stream_pallas(  # and the interpreter takes what was resolved
+        jnp.ones((1, 8, 128 * 33), dtype), mode="median", interpret=True)
+    assert seen[-2:] == [4096, 128]
+    # a caller's tile, for Mosaic: whole native tiles, or the whole array
+    with pytest.raises(ValueError, match="whole tiles"):
+        pk.sorted_reduce_stream_pallas(xs, mode="median", tile=whole // 2, interpret=False)
+    pk.sorted_reduce_stream_pallas(xs, mode="median", tile=whole, interpret=False)
+    pk.sorted_reduce_stream_pallas(xs[:, :, :512], mode="median", tile=512, interpret=False)
+    pk.sorted_reduce_stream_pallas(xs, mode="median", tile=128, interpret=True)
+    assert seen[-3:] == [whole, 512, 128]
+
+
 # ---------------------------------------------------------------------------
 # Fused MeaMed kernel
 # ---------------------------------------------------------------------------

@@ -11,7 +11,13 @@ sees the zero-tailed matrix every backend's wide path would.
 
 from __future__ import annotations
 
+import importlib.util
+import os
+import re
+import subprocess
+import sys
 from functools import partial
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -233,10 +239,9 @@ def _matrix_rebuilds(step, args, shape):
 def test_the_matrix_is_built_by_the_ravel_alone(monkeypatch, bundle, batches, wide):
     """Each honest worker's row is made by one ``concatenate``, the
     ravel's (leaves and zero tail together), inside the one-device loop
-    in ``round.fwdbwd``; the loop stacks the rows; the one op that makes
-    the n-row matrix of the stack is the ``pad`` the byzantine rows are
-    selected into (one fused pass on the chip); nothing concatenates or
-    pads rows or matrix again."""
+    in ``round.fwdbwd``; the loop writes it into the n-row stack it
+    carries and the byzantine rows are written into the same stack;
+    nothing concatenates or pads rows or matrix again."""
     if wide:
         monkeypatch.setattr(pallas_kernels, "aligned_width", _round_to_128)
     d = tree_size(bundle.params)
@@ -248,9 +253,7 @@ def test_the_matrix_is_built_by_the_ravel_alone(monkeypatch, bundle, batches, wi
     rows = _matrix_rebuilds(step, args, (width,))
     assert [name for name, _ in rows] == ["concatenate"]
     assert _matrix_rebuilds(step, args, (CFG.n_honest, width)) == []
-    rebuilds = _matrix_rebuilds(step, args, (N, width))
-    assert [name for name, _ in rebuilds] == ["pad"]
-    assert "round.build_matrix" in rebuilds[0][1]
+    assert _matrix_rebuilds(step, args, (N, width)) == []
     # the reference round above does rebuild it: the probe sees a second one
     ref_step, ref_opt = _concatenating_step(bundle, AGGREGATORS["trimmed_mean"], ATTACKS["noise"])
     ref = _matrix_rebuilds(ref_step, (bundle.params, ref_opt, xs[0], ys[0], keys[0]), (N, d))
@@ -339,6 +342,187 @@ def test_forced_kernels_read_the_wide_matrix_without_a_padded_copy(monkeypatch):
         assert copies[width] == [] and copies[d] != []
 
 
+# -- (iv) folded rows: the stack reaches the sort family's kernel as written ----
+
+
+def _clip(x):
+    return preagg.clip_rows(x, threshold=5.0)
+
+
+def _nnm(x):
+    return preagg.nnm(x, f=2)
+
+
+FOLDED_ROUNDS = {
+    # name: (aggregate, pre_aggregate, whole-matrix sublane writes on the TPU)
+    "trimmed_mean": ("trimmed_mean", None, 0),
+    "median": ("median", None, 0),
+    "multi_krum": ("multi_krum", None, 1),  # its Gram wants the workers in sublanes
+    # a row-wise scaling is elementwise over the folded rows too
+    "clip_then_trimmed_mean": ("trimmed_mean", _clip, 0),
+    # a mixing of rows is a matmul over the workers: one relayout in front of
+    # its kernel, and the kernel's own (n, d_pad) result
+    "nnm_then_trimmed_mean": ("trimmed_mean", _nnm, 2),
+}
+
+
+@pytest.mark.parametrize("attack", ["sign_flip", "noise", "empire"])
+@pytest.mark.parametrize("name", sorted(FOLDED_ROUNDS))
+def test_folded_round_with_forced_kernels_equals_the_concatenating_round(
+        monkeypatch, bundle, batches, name, attack):
+    """Kernels forced (interpreted here), so the rows are 16384 wide and
+    stacked as (n, 128, 128): the loop's n-row stack, the byzantine rows
+    written into it and the folded kernel give the parameters of the round
+    that concatenates (n, d) rows, bit for bit where the aggregate is
+    coordinate-wise."""
+    monkeypatch.setenv("BYZPY_TPU_PALLAS", "1")
+    agg, pre, _ = FOLDED_ROUNDS[name]
+    d = tree_size(bundle.params)
+    assert pallas_kernels.aligned_width(N, d) == 16384
+    step, opt_state = jit_ps_train_step(
+        bundle, AGGREGATORS[agg], CFG, attack=ATTACKS[attack], pre_aggregate=pre, donate=False)
+    reference = AGGREGATORS[agg] if pre is None else (lambda x: AGGREGATORS[agg](pre(x)))
+    ref_step, ref_opt = _concatenating_step(bundle, reference, ATTACKS[attack])
+    got, got_norms = _drive(step, bundle.params, opt_state, batches)
+    want, want_norms = _drive(ref_step, bundle.params, ref_opt, batches)
+    if name in ("trimmed_mean", "median"):
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got_norms, want_norms)
+    else:  # norms and Gram blocks summed over another number of columns
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
+        np.testing.assert_allclose(got_norms, want_norms, rtol=1e-5)
+
+
+def test_folded_stack_is_what_the_aggregate_is_handed(monkeypatch, bundle, batches):
+    """Eagerly, kernels forced: the (n, d_pad) matrix the aggregate sees is
+    the concatenated one with an exactly-zero tail, all n rows written."""
+    monkeypatch.setenv("BYZPY_TPU_PALLAS", "1")
+    xs, ys, keys = batches
+    d = tree_size(bundle.params)
+    matrix = _matrix_the_aggregate_sees(bundle, ATTACKS["noise"], batches)
+    assert matrix.shape == (N, 16384)
+    ravel, _ = ravel_pytree_fn(bundle.params)
+    grads = jax.vmap(lambda x, y: ravel(jax.grad(bundle.loss_fn)(bundle.params, x, y)))(
+        xs[0], ys[0])
+    honest = grads[: CFG.n_honest]
+    byz = jnp.broadcast_to(_noise(honest, keys[0]), (B, d))
+    np.testing.assert_array_equal(matrix[:, :d], np.asarray(jnp.concatenate([honest, byz])))
+    assert np.count_nonzero(matrix[:, d:]) == 0
+
+
+def _write_tpu_texts(out_dir):
+    """The optimised text of the one-device step of every
+    ``FOLDED_ROUNDS`` entry, compiled for a described (not attached) TPU
+    v5e chip with its kernels compiled by Mosaic, as the chip's process
+    would: ``<out_dir>/<name>.hlo.txt``. The TPU's own text is where a
+    relayout shows (on the CPU every reshape is a bitcast). Run in a
+    process of its own (:func:`tpu_texts`): it loads libtpu, turns the
+    persistent compile cache off (a compile for a described chip cannot
+    be read back without the chip) and replaces the interpreter switch."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    os.environ["BYZPY_TPU_PALLAS"] = "1"
+    pallas_kernels._resolve_interpret = lambda interpret: False
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    toy = mnist_mlp(0, hidden=16)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    for name, (agg, pre, _) in FOLDED_ROUNDS.items():
+        step, opt_state = build_ps_train_step(
+            toy, AGGREGATORS[agg], CFG, attack=ATTACKS["sign_flip"], pre_aggregate=pre)
+        args = (described(toy.params), described(opt_state),
+                jax.ShapeDtypeStruct((N, 4, 28, 28, 1), jnp.float32, sharding=one_chip),
+                jax.ShapeDtypeStruct((N, 4), jnp.int32, sharding=one_chip),
+                jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip))
+        text = jax.jit(step).lower(*args).compile().as_text()
+        with open(os.path.join(out_dir, name + ".hlo.txt"), "w", encoding="utf-8") as fh:
+            fh.write(text)
+
+
+@pytest.fixture(scope="module")
+def tpu_texts(tmp_path_factory):
+    """``{round's name: its text}`` from :func:`_write_tpu_texts`, run once
+    in a child process with a temporary directory of its own (libtpu's
+    logs land there) and ``ALLOW_MULTIPLE_LIBTPU_LOAD``, so that neither
+    another test worker nor a job on this host that holds libtpu's lock
+    can turn these checks off. Skipped only where no libtpu is installed;
+    a child that fails where one is installed fails the tests."""
+    pytest.importorskip("libtpu")
+    out_dir = tmp_path_factory.mktemp("tpu_texts")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", ALLOW_MULTIPLE_LIBTPU_LOAD="1",
+               TMPDIR=str(out_dir), TPU_STDERR_LOG_LEVEL="3",
+               PYTHONPATH=os.pathsep.join(filter(None, [root, os.environ.get("PYTHONPATH")])))
+    done = subprocess.run([sys.executable, os.path.abspath(__file__), str(out_dir)], env=env,
+                          cwd=root, capture_output=True, text=True, timeout=900, check=False)
+    if done.returncode:
+        pytest.fail("compiling the toy rounds for a described v5e failed "
+                    f"(exit {done.returncode}):\n{done.stderr[-3000:]}")
+    texts = {}
+    for name in FOLDED_ROUNDS:
+        with open(os.path.join(out_dir, name + ".hlo.txt"), encoding="utf-8") as fh:
+            texts[name] = fh.read()
+    return texts
+
+
+def _sublane_matrix_writes(text, d):
+    """The benchmark's own counter (``sublane_matrix_writes.train``)."""
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "chipbench", "layer_metrics", "sublane_matrix_writes.train.py")
+    spec = importlib.util.spec_from_file_location("sublane_matrix_writes_train", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    config = {"n_nodes": N, "n_byzantine": B, "n_parameters": d}
+    return reader.read(SimpleNamespace(outcome={"compiled_text": text}, config=config))
+
+
+def _entry_instructions(text):
+    """``{name: (opcode, [operand names], line)}`` of the entry computation."""
+    entry = text.partition("\nENTRY ")[2].partition("\n}")[0]
+    found = {}
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%\S+) = .*? ([\w\-]+)\(([^)]*)\)", line)
+        if m:
+            found[m.group(1)] = (m.group(2), re.findall(r"%[\w.\-]+", m.group(3)), line)
+    return found
+
+
+@pytest.mark.parametrize("name", sorted(FOLDED_ROUNDS))
+def test_on_the_tpu_only_an_aggregate_that_wants_sublane_rows_relays_the_stack(
+        tpu_texts, bundle, name):
+    text = tpu_texts[name]
+    writes = FOLDED_ROUNDS[name][2]
+    assert "tpu_custom_call" in text
+    assert _sublane_matrix_writes(text, tree_size(bundle.params)) == writes
+
+
+@pytest.mark.parametrize("agg", ["trimmed_mean", "median"])
+def test_on_the_tpu_the_sort_kernels_operand_is_the_loops_stack(tpu_texts, agg):
+    """From the kernel's custom call back to the loop: the row write of
+    the byzantine rows (a fusion around a ``dynamic-update-slice``, which
+    updates its operand in place) and bitcasts; no copy, no relayout."""
+    entry = _entry_instructions(tpu_texts[agg])
+    (kernel,) = [v for v in entry.values() if "sorted_reduce_stream" in v[2]
+                 and v[0] == "custom-call"]
+    assert "f32[1,8,128,128]" in kernel[2]  # the folded operand, whole (8, 128) tiles a row
+    at, path = kernel[1][0], []
+    while entry[at][0] != "while":
+        opcode, operands, line = entry[at]
+        path.append(opcode)
+        if opcode == "fusion":
+            assert "dynamic-update-slice" in line  # named after what it fuses
+        at = operands[0]
+    assert set(path) <= {"fusion", "bitcast", "get-tuple-element"} and "fusion" in path
+    # and the result is the flat aggregate: no relayout between kernel and update
+    assert " copy(" not in "".join(v[2] for v in entry.values() if "round.update" in v[2])
+
+
 # -- every shipped aggregator and pre-aggregator maps zero columns to zero ----
 
 D_SMALL, K_PAD = 1000, 24
@@ -410,3 +594,7 @@ def test_pre_aggregator_maps_zero_columns_to_zero_and_keeps_the_rest(attacked_ma
         np.testing.assert_allclose(got[:, :D_SMALL], want, rtol=1e-6, atol=1e-7)
     else:
         np.testing.assert_array_equal(got[:, :D_SMALL], want)
+
+
+if __name__ == "__main__":  # the child process of the ``tpu_texts`` fixture
+    _write_tpu_texts(sys.argv[1])
