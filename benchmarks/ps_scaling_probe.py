@@ -8,10 +8,9 @@ collectives follows the saturating ``(g-1)/g`` law, so the n=8
 measurement extrapolates exactly to larger meshes; v5e ICI bandwidth and
 the MLP's per-chip FLOPs then give the weak-scaling efficiency table.
 
-Prints ONE JSON object (consumed by ``bench.py`` to attach the
-``ps_mnist_trimmed_mean_steps_per_sec`` projection; also runnable
-standalone). Designed to run in a SUBPROCESS of the TPU-facing bench —
-the CPU platform pin below happens before any backend touch.
+Prints ONE JSON object (the ``ps_mnist_trimmed_mean_steps_per_sec``
+projection). Runs on the CPU mesh: the platform pin below happens
+before any backend touch.
 """
 
 from __future__ import annotations
@@ -120,7 +119,7 @@ def main() -> None:
 
     # the default round (sharded_update="auto") resolves to the sharded
     # f32 program on this mesh — its already-measured variant carries the
-    # bench.py-facing projection keys (no fifth compile)
+    # top-level projection keys (no fifth compile)
     default = variants["sharded_f32"]
     wire8 = float(default["hlo_wire_bytes_per_device_n8"])
 

@@ -52,9 +52,9 @@ class CoordinateWiseMedian(FeatureChunkedAggregator, Aggregator):
         stays bit-for-bit), per-cohort masked program on the XLA
         fallback."""
         from ...ops import ragged as ragged_ops
-        from ...ops.pallas_kernels import _on_tpu
+        from ...ops.pallas_kernels import targets_tpu
 
-        if not _on_tpu():
+        if not targets_tpu():
             return super().ragged_matrix_fn()
 
         def fn(flat, seg, offsets, lengths, *, n_cohorts, segment_sum=None):

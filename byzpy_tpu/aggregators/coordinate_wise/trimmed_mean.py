@@ -92,10 +92,10 @@ class CoordinateWiseTrimmedMean(FeatureChunkedAggregator, Aggregator):
         ``ragged_coalesce`` is False: one cohort per call, still ONE
         compiled program across every cohort size)."""
         from ...ops import ragged as ragged_ops
-        from ...ops.pallas_kernels import _on_tpu
+        from ...ops.pallas_kernels import targets_tpu
 
         f = self.f
-        if not _on_tpu():
+        if not targets_tpu():
             return super().ragged_matrix_fn()
 
         def fn(flat, seg, offsets, lengths, *, n_cohorts, segment_sum=None):

@@ -44,8 +44,8 @@ def build_import_map(tree: ast.Module) -> Dict[str, str]:
 
     ``import jax.numpy as jnp`` → ``{"jnp": "jax.numpy"}``; ``from jax
     import lax`` → ``{"lax": "jax.lax"}``. Relative imports are stored
-    with the leading dots stripped (``from ..profiling import tilecache``
-    → ``{"tilecache": "profiling.tilecache"}``) — matching is therefore
+    with the leading dots stripped (``from ..ops import robust``
+    → ``{"robust": "ops.robust"}``) — matching is therefore
     done on name suffixes, not full paths, where relative imports occur.
     """
     imports: Dict[str, str] = {}

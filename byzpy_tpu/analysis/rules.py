@@ -109,9 +109,11 @@ COLLECTIVE_AXIS_ARG: Dict[str, int] = {
     "all_to_all_q": 1,
 }
 
-#: in-repo pre-trace dispatch helpers (reading them mid-trace bakes the
-#: first-call answer into the compiled executable — the PR-2 incident)
-DISPATCH_HELPERS = {"_tuned_tile", "matmul_input_dtype"}
+#: the kernel dispatch gate and its parts that read ``BYZPY_TPU_PALLAS``
+#: (``ops/pallas_kernels.py``, "Dispatch policy"): asked mid-trace, the
+#: first call's answer is baked into the compiled executable — the PR-2
+#: incident
+DISPATCH_HELPERS = {"pallas_serves", "use_pallas_for", "aligned_width"}
 
 #: blocking callables by resolved qualified name
 BLOCKING_QUALNAMES = {
@@ -225,16 +227,16 @@ class Rule:
 
 
 class TraceDispatchRule(Rule):
-    """No env/tile-cache/dispatch-config reads inside traced bodies."""
+    """No env/dispatch-gate reads inside traced bodies."""
 
     id = TRACE_DISPATCH
     summary = (
-        "os.environ / tile-cache / dispatch-config reads must resolve in "
+        "os.environ / dispatch-gate reads must resolve in "
         "the Python wrapper before trace, never inside a jitted body"
     )
 
     def check(self, mod: ModuleInfo, ctx: ScanContext) -> Iterator[Finding]:
-        """Flag env and dispatch-cache reads lexically inside any traced
+        """Flag env and dispatch-gate reads lexically inside any traced
         function (jit/shard_map/pmap decorated, wrapped, or a pallas
         kernel), including nested defs."""
         seen: Set[Tuple[int, int]] = set()
@@ -264,10 +266,7 @@ class TraceDispatchRule(Rule):
                         )
                     elif (
                         fq is not None
-                        and (
-                            fq.endswith("tilecache.lookup")
-                            or last_component(fq) in DISPATCH_HELPERS
-                        )
+                        and last_component(fq) in DISPATCH_HELPERS
                         and key not in seen
                     ):
                         seen.add(key)
@@ -275,7 +274,7 @@ class TraceDispatchRule(Rule):
                             mod,
                             node,
                             f"dispatch helper {last_component(fq)!r} called "
-                            "inside a traced body — tile/dtype dispatch is a "
+                            "inside a traced body — the kernel route is a "
                             "static jit argument and must be read pre-trace",
                         )
 

@@ -36,6 +36,10 @@ Array = jnp.ndarray
 
 _LANES = 128
 _SUBLANES = 8
+# What the stream kernels take, and so what the dispatch gate admits
+# (16-bit floats up-convert per block in VMEM); f64, fp8 and integer
+# matrices stay on XLA.
+_KERNEL_DTYPES = (jnp.float32, jnp.bfloat16, jnp.float16)
 
 
 def _on_tpu() -> bool:
@@ -76,47 +80,6 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
-
-
-def _tuned_tile(family: str, n: int, d: int) -> Optional[int]:
-    """Autotuned tile for ``(family, shape)``, or ``None`` for "use the
-    heuristic". Resolution order: ``BYZPY_TPU_TILE_<FAMILY>`` env
-    override, then the autotune cache file ``BYZPY_TPU_TUNE_CACHE``
-    names, if any (``byzpy_tpu.profiling.tilecache``; no file outside
-    the checkout is read by default, so every machine resolves the same
-    tiles). Every caller runs this in the kernel's Python
-    wrapper — BEFORE the jitted inner function traces — so flipping the
-    env var or re-running a sweep changes the very next dispatch (tile
-    is a static jit argument, a new value retraces)."""
-    import os
-
-    env = os.environ.get(f"BYZPY_TPU_TILE_{family.upper()}")
-    if env:
-        try:
-            tile = int(env)
-        except ValueError:
-            tile = None
-        if tile is not None and tile > 0 and tile % _LANES == 0:
-            return tile
-    from ..profiling import tilecache
-
-    return tilecache.lookup(family, platform=jax.default_backend(), n=n, d=d)
-
-
-def matmul_input_dtype(x_dtype) -> Optional[str]:
-    """Resolve the ``BYZPY_TPU_MATMUL_DTYPE`` policy for a contraction
-    operand: returns ``"bf16"`` when f32 inputs should be cast to
-    bfloat16 before the MXU dot (f32 accumulation stays — the EQuARX-
-    style low-precision Gram path, halving the dominant HBM read), else
-    ``None`` (exact f32 multiplication, the default). Read per call in
-    the dispatch wrappers, before trace, so the policy participates in
-    the jit key."""
-    import os
-
-    flag = os.environ.get("BYZPY_TPU_MATMUL_DTYPE", "auto")
-    if flag == "bf16" and x_dtype == jnp.float32:
-        return "bf16"
-    return None
 
 
 # ---------------------------------------------------------------------------
@@ -199,17 +162,11 @@ def _sort_columns_kernel(x_ref, out_ref, *, n_rows: int, is_float: bool):
     out_ref[:] = _keys_to_float(keys, block.dtype) if is_float else keys
 
 
-def _auto_tile(n_pad: int, d: Optional[int] = None) -> int:
-    """Feature-tile width for ``sort_columns``. The autotune cache / env
-    override (family ``"sort"``; see :func:`_tuned_tile`) wins when a
-    valid entry exists; the heuristic targets ~1 MiB f32 blocks: wide
-    tiles amortize per-grid-step overhead for small n (n=8 wants 8192);
-    narrower ones keep VMEM sane as n grows (n=128 measured best at
-    1024–2048)."""
-    if d is not None:
-        tuned = _tuned_tile("sort", n_pad, d)
-        if tuned is not None:
-            return tuned
+def _auto_tile(n_pad: int) -> int:
+    """Feature-tile width for ``sort_columns``: targets ~1 MiB f32
+    blocks. Wide tiles amortize per-grid-step overhead for small n (n=8
+    wants 8192); narrower ones keep VMEM sane as n grows (n=128 measured
+    best at 1024–2048)."""
     return max(512, min(8192, _round_up(262144 // n_pad, _LANES)))
 
 
@@ -227,8 +184,7 @@ def sort_columns(
     are sliced off; ``iinfo.max`` for ints) and ``d`` up to a lane-aligned
     tile. 16-bit floats sort through an exact f32 round-trip: the kernel's
     int32 key path needs 32-bit rows, and every bf16/f16 value is exactly
-    representable in f32. The tile is resolved here, before the jitted
-    inner function traces (env/cache overrides apply per call).
+    representable in f32.
     """
     interpret = _resolve_interpret(interpret)
     dtype = x.dtype
@@ -242,7 +198,7 @@ def sort_columns(
     n, d = x.shape
     n_pad = max(_SUBLANES, _round_up(n, _SUBLANES))
     if tile is None:
-        tile = _auto_tile(n_pad, d)
+        tile = _auto_tile(n_pad)
     return _sort_columns_call(x, tile=tile, interpret=interpret)
 
 
@@ -333,15 +289,10 @@ def _gram_kernel(x_ref, out_ref):
 def gram_pallas(
     x: Array, *, tile: Optional[int] = None, interpret: Optional[bool] = None
 ) -> Array:
-    """``x @ x.T`` accumulated in f32 over lane-aligned feature tiles.
-    Tile resolved pre-trace (family ``"gram"``: env override / autotune
-    cache / the 1024 default)."""
+    """``x @ x.T`` accumulated in f32 over lane-aligned feature tiles
+    (1024 columns unless ``tile`` says otherwise)."""
     interpret = _resolve_interpret(interpret)
-    n, d = x.shape
-    n_pad = max(_SUBLANES, _round_up(n, _SUBLANES))
-    if tile is None:
-        tile = _tuned_tile("gram", n_pad, d) or 1024
-    return _gram_pallas_call(x, tile=tile, interpret=interpret)
+    return _gram_pallas_call(x, tile=tile or 1024, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -475,17 +426,16 @@ def sorted_reduce_stream_pallas(
     pass of its own in front of that). The result ``(K, d_pad / 128,
     128)`` is the flat ``(K, d_pad)`` vector in memory. ``tile`` stays a
     column count (the block holds ``tile / 128`` sublane rows of each
-    worker), resolved pre-trace (family ``"sorted_reduce"``) and, for
-    Mosaic, rounded up to whole native tiles of those rows: 1024 columns
-    of f32, 2048 of a 16-bit dtype; a ``tile`` given by the caller that
-    is not such a multiple raises there. The interpreter takes any
-    multiple of 128."""
+    worker); the heuristic's is, for Mosaic, rounded up to whole native
+    tiles of those rows: 1024 columns of f32, 2048 of a 16-bit dtype; a
+    ``tile`` given by the caller that is not such a multiple raises
+    there. The interpreter takes any multiple of 128."""
     if mode not in {"median", "trimmed"}:
         raise ValueError(f"unknown mode {mode!r}")
     K, n, d = xs.shape
     if mode == "trimmed" and not 0 <= 2 * f < n:
         raise ValueError(f"f must satisfy 0 <= 2f < n (got n={n}, f={f})")
-    if xs.dtype not in (jnp.bfloat16, jnp.float16, jnp.float32):
+    if xs.dtype not in _KERNEL_DTYPES:
         raise ValueError(f"unsupported dtype {xs.dtype}")
     interpret = _resolve_interpret(interpret)
     # a block holds tile / 128 sublane rows of each worker, in whole
@@ -494,13 +444,11 @@ def sorted_reduce_stream_pallas(
     # whole array, however short
     whole = _LANES * _SUBLANES * (4 // xs.dtype.itemsize)
     if tile is None:
-        # the tile cache keys every family by the sublane-padded row count
-        n_key = max(_SUBLANES, _round_up(n, _SUBLANES))
         # sort happens on f32 rows in VMEM regardless of input dtype
-        tile = _tuned_tile("sorted_reduce", n_key, d) or _auto_sort_tile(d, n)
+        tile = _auto_sort_tile(d, n)
         if not interpret:
-            # a narrower tile than Mosaic's block can be (the heuristic's
-            # for an odd d, an old cache entry, the environment's)
+            # a narrower tile than Mosaic's block can be (the
+            # heuristic's for an odd d)
             tile = _round_up(tile, whole)
     elif tile % _LANES:
         raise ValueError(f"tile must be a multiple of {_LANES} columns (got {tile})")
@@ -628,14 +576,13 @@ def weighted_center_step_pallas(
     """One fused Weiszfeld / centered-clipping iteration: ``x`` ``(n, d)``,
     center ``z`` ``(d,)`` -> new center ``(d,)``. See the kernel docstring;
     ``ops.robust.geometric_median`` / ``centered_clipping`` call this
-    inside their ``lax`` loops when the dispatch gate allows. Tile
-    resolved pre-trace."""
+    inside their ``lax`` loops when the dispatch gate allows."""
     if mode not in {"weiszfeld", "clip"}:
         raise ValueError(f"unknown mode {mode!r}")
     n, d = x.shape
     if z.shape != (d,):
         raise ValueError(f"z must have shape ({d},), got {z.shape}")
-    if x.dtype not in (jnp.bfloat16, jnp.float16, jnp.float32):
+    if x.dtype not in _KERNEL_DTYPES:
         raise ValueError(f"unsupported dtype {x.dtype}")
     interpret = _resolve_interpret(interpret)
     n_pad = max(_SUBLANES, _round_up(n, _SUBLANES))
@@ -785,8 +732,7 @@ def meamed_stream_pallas(
     from HBM exactly ONCE (median, window-minimum cut, and the selected
     mean all compute from one in-VMEM sort — see the kernel docstring);
     ``MEAMED_MAX_DIM`` is retained as a dispatch-gate cap for parity
-    with the other fused kernels' tested envelope. Tile resolved
-    pre-trace (family ``"meamed"``)."""
+    with the other fused kernels' tested envelope."""
     K, n, d = xs.shape
     if not 0 <= f < n:
         raise ValueError(f"f must satisfy 0 <= f < n (got n={n}, f={f})")
@@ -795,7 +741,7 @@ def meamed_stream_pallas(
             f"meamed_stream_pallas requires d <= {MEAMED_MAX_DIM} (got {d}): "
             "use ops.robust.mean_of_medians (the XLA path) beyond that"
         )
-    if xs.dtype not in (jnp.bfloat16, jnp.float16, jnp.float32):
+    if xs.dtype not in _KERNEL_DTYPES:
         raise ValueError(f"unsupported dtype {xs.dtype}")
     interpret = _resolve_interpret(interpret)
     n_pad = max(_SUBLANES, _round_up(n, _SUBLANES))
@@ -803,9 +749,7 @@ def meamed_stream_pallas(
         # sort-aware budget; the kernel additionally keeps the original
         # block, the decoded sorted floats, and the deviation/mask
         # temporaries live across the sort, so budget 3 extra copies
-        tile = _tuned_tile("meamed", n_pad, d) or _auto_sort_tile(
-            d, n_pad, copies=13
-        )
+        tile = _auto_sort_tile(d, n_pad, copies=13)
     return _meamed_stream_call(xs, f=f, tile=tile, interpret=interpret)
 
 
@@ -902,22 +846,16 @@ def _stable_k_select_mask(keys, *, n_pad: int, k: int):
     return _stable_threshold_select(keys, cut, k=k), cut
 
 
-def _accumulate_gram(x_block, gram_ref, c, cast: Optional[str] = None):
+def _accumulate_gram(x_block, gram_ref, c):
     """Phase-0 body shared by the fused kernels: zero the scratch on the
     round's first chunk, then accumulate this feature tile's Gram
     contribution on the MXU (f32 accumulation; each tile of ``x`` is read
     from HBM exactly once — XLA's einsum streams ``x`` twice, as lhs and
-    rhs: 0.91 ms vs the 0.31 ms one-read floor at 64x1M f32 on v5e).
-    ``cast='bf16'`` (the ``BYZPY_TPU_MATMUL_DTYPE`` policy, resolved
-    pre-trace by the wrappers) multiplies f32 tiles at the MXU's native
-    bf16 rate while keeping the f32 accumulator — distances lose ~2^-8
-    relative precision, which only perturbs score near-ties."""
+    rhs: 0.91 ms vs the 0.31 ms one-read floor at 64x1M f32 on v5e)."""
     @pl.when(c == 0)
     def _():
         gram_ref[:] = jnp.zeros_like(gram_ref)
 
-    if cast == "bf16":
-        x_block = x_block.astype(jnp.bfloat16)
     gram_ref[:] += jax.lax.dot_general(
         x_block, x_block,
         dimension_numbers=(((1,), (1,)), ((), ())),
@@ -1012,7 +950,7 @@ def _auto_sort_tile(
 
 def _selection_mean_stream_kernel(
     x_ref, o_ref, gram_ref, w_ref, *, n_pad: int, n_real: int, f: int, q: int,
-    mode: str, reference_index: int, cast: Optional[str] = None,
+    mode: str, reference_index: int,
 ):
     """Two HBM sweeps per round inside ONE kernel launch, over a grid of
     ``(K, 2, C)`` (round, phase, feature-chunk).
@@ -1039,7 +977,7 @@ def _selection_mean_stream_kernel(
 
     @pl.when(p == 0)
     def _():
-        _accumulate_gram(x_ref[0], gram_ref, c, cast)
+        _accumulate_gram(x_ref[0], gram_ref, c)
 
     @pl.when((p == 1) & (c == 0))
     def _():
@@ -1073,9 +1011,7 @@ def selection_mean_stream_pallas(
     intermediate copies. This is the training-loop / replay shape of
     ``selection_mean_pallas`` — see that kernel for the per-round
     algorithm and ``ops.robust.aggregate_stream`` for when a stream of
-    rounds per dispatch is the right shape. Tile and
-    the ``BYZPY_TPU_MATMUL_DTYPE`` Gram-cast policy are resolved here,
-    pre-trace (family ``"selection"``)."""
+    rounds per dispatch is the right shape."""
     if mode not in {"krum", "cge", "monna"}:
         raise ValueError(f"unknown mode {mode!r}")
     K, n, d = xs.shape
@@ -1086,28 +1022,24 @@ def selection_mean_stream_pallas(
     if not 0 <= reference_index < n:
         raise ValueError(f"reference_index out of range (got {reference_index})")
     interpret = _resolve_interpret(interpret)
-    if xs.dtype not in (jnp.bfloat16, jnp.float16, jnp.float32):
+    if xs.dtype not in _KERNEL_DTYPES:
         raise ValueError(f"unsupported dtype {xs.dtype}")
     n_pad = max(_SUBLANES, _round_up(n, _SUBLANES))
     if tile is None:
-        tile = _tuned_tile("selection", n_pad, d) or _auto_selection_tile(
-            d, n_pad, jnp.dtype(xs.dtype).itemsize
-        )
+        tile = _auto_selection_tile(d, n_pad, jnp.dtype(xs.dtype).itemsize)
     return _selection_mean_stream_call(
         xs, f=f, q=q, mode=mode, reference_index=reference_index, tile=tile,
-        interpret=interpret, cast=matmul_input_dtype(xs.dtype),
+        interpret=interpret,
     )
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "f", "q", "mode", "reference_index", "tile", "interpret", "cast"
-    ),
+    static_argnames=("f", "q", "mode", "reference_index", "tile", "interpret"),
 )
 def _selection_mean_stream_call(
     xs: Array, *, f: int, q: int, mode: str, reference_index: int, tile: int,
-    interpret: bool, cast: Optional[str],
+    interpret: bool,
 ) -> Array:
     K, n, d = xs.shape
     n_pad = max(_SUBLANES, _round_up(n, _SUBLANES))
@@ -1120,7 +1052,7 @@ def _selection_mean_stream_call(
     out = pl.pallas_call(
         functools.partial(
             _selection_mean_stream_kernel, n_pad=n_pad, n_real=n, f=f, q=q,
-            mode=mode, reference_index=reference_index, cast=cast,
+            mode=mode, reference_index=reference_index,
         ),
         out_shape=jax.ShapeDtypeStruct((K, 1, d_pad), xs.dtype),
         grid=(K, 2, d_pad // tile),
@@ -1220,7 +1152,7 @@ def selection_mean_from_gram_pallas(
     ``mode='krum'`` (selection ties to documented tolerance: scores sum
     identical values in a different reduction order). One HBM read of
     ``x`` + a (1, d) write; pairwise distances never materialize in HBM
-    at all. Tile resolved pre-trace (family ``"selection"``)."""
+    at all."""
     if mode not in {"krum", "cge", "monna"}:
         raise ValueError(f"unknown mode {mode!r}")
     n, d = x.shape
@@ -1232,14 +1164,12 @@ def selection_mean_from_gram_pallas(
         raise ValueError(f"q must be in [1, n] (got q={q}, n={n})")
     if not 0 <= reference_index < n:
         raise ValueError(f"reference_index out of range (got {reference_index})")
-    if x.dtype not in (jnp.bfloat16, jnp.float16, jnp.float32):
+    if x.dtype not in _KERNEL_DTYPES:
         raise ValueError(f"unsupported dtype {x.dtype}")
     interpret = _resolve_interpret(interpret)
     n_pad = max(_SUBLANES, _round_up(n, _SUBLANES))
     if tile is None:
-        tile = _tuned_tile("selection", n_pad, d) or _auto_selection_tile(
-            d, n_pad, jnp.dtype(x.dtype).itemsize
-        )
+        tile = _auto_selection_tile(d, n_pad, jnp.dtype(x.dtype).itemsize)
     return _selection_from_gram_call(
         x, gram, f=f, q=q, mode=mode, reference_index=reference_index,
         tile=tile, interpret=interpret,
@@ -1384,13 +1314,12 @@ def nnm_stream_pallas(
 ) -> Array:
     """Nearest-Neighbor Mixing over ``K`` stacked rounds ``xs: (K, n, d)``
     in one fused kernel launch; equals ``jax.vmap(lambda x:
-    ops.preagg.nnm(x, f=f))(xs)``. See ``nnm_pallas`` for the K=1 form.
-    Tile resolved pre-trace."""
+    ops.preagg.nnm(x, f=f))(xs)``. See ``nnm_pallas`` for the K=1 form."""
     K, n, d = xs.shape
     if not 0 <= f < n:
         raise ValueError(f"f must satisfy 0 <= f < n (got n={n}, f={f})")
     interpret = _resolve_interpret(interpret)
-    if xs.dtype not in (jnp.bfloat16, jnp.float16, jnp.float32):
+    if xs.dtype not in _KERNEL_DTYPES:
         raise ValueError(f"unsupported dtype {xs.dtype}")
     n_pad = max(_SUBLANES, _round_up(n, _SUBLANES))
     if tile is None:
@@ -1662,8 +1591,7 @@ def clip_selection_mean_stream_pallas(
     ``xs: (K, n, d)`` in ONE fused launch; equals
     ``selection_mean(clip_rows(x, threshold=tau), f=f, q=q)`` per round
     at 2 HBM reads + a (1, d) write. See
-    ``_clip_selection_stream_kernel`` (and its non-finite note). Tile
-    resolved pre-trace (family ``"selection"``)."""
+    ``_clip_selection_stream_kernel`` (and its non-finite note)."""
     if mode not in {"krum", "cge", "monna"}:
         raise ValueError(f"unknown mode {mode!r}")
     K, n, d = xs.shape
@@ -1675,14 +1603,12 @@ def clip_selection_mean_stream_pallas(
         raise ValueError(f"q must be in [1, n] (got q={q}, n={n})")
     if not 0 <= reference_index < n:
         raise ValueError(f"reference_index out of range (got {reference_index})")
-    if xs.dtype not in (jnp.bfloat16, jnp.float16, jnp.float32):
+    if xs.dtype not in _KERNEL_DTYPES:
         raise ValueError(f"unsupported dtype {xs.dtype}")
     interpret = _resolve_interpret(interpret)
     n_pad = max(_SUBLANES, _round_up(n, _SUBLANES))
     if tile is None:
-        tile = _tuned_tile("selection", n_pad, d) or _auto_selection_tile(
-            d, n_pad, jnp.dtype(xs.dtype).itemsize
-        )
+        tile = _auto_selection_tile(d, n_pad, jnp.dtype(xs.dtype).itemsize)
     return _clip_selection_mean_stream_call(
         xs, tau=tau, f=f, q=q, mode=mode, reference_index=reference_index,
         tile=tile, interpret=interpret,
@@ -1753,8 +1679,7 @@ def arc_selection_mean_stream_pallas(
     factors are norm-derived like static clipping's — the data-dependent
     threshold (the ``cut_off``-th smallest norm) computes by stable rank
     counting in int32 key space inside VMEM — so the same Gram-collapse
-    applies (see ``_clip_selection_stream_kernel``, ``pre='arc'``). Tile
-    resolved pre-trace (family ``"selection"``)."""
+    applies (see ``_clip_selection_stream_kernel``, ``pre='arc'``)."""
     if mode not in {"krum", "cge", "monna"}:
         raise ValueError(f"unknown mode {mode!r}")
     K, n, d = xs.shape
@@ -1766,14 +1691,12 @@ def arc_selection_mean_stream_pallas(
         raise ValueError(f"q must be in [1, n] (got q={q}, n={n})")
     if not 0 <= reference_index < n:
         raise ValueError(f"reference_index out of range (got {reference_index})")
-    if xs.dtype not in (jnp.bfloat16, jnp.float16, jnp.float32):
+    if xs.dtype not in _KERNEL_DTYPES:
         raise ValueError(f"unsupported dtype {xs.dtype}")
     interpret = _resolve_interpret(interpret)
     n_pad = max(_SUBLANES, _round_up(n, _SUBLANES))
     if tile is None:
-        tile = _tuned_tile("selection", n_pad, d) or _auto_selection_tile(
-            d, n_pad, jnp.dtype(xs.dtype).itemsize
-        )
+        tile = _auto_selection_tile(d, n_pad, jnp.dtype(xs.dtype).itemsize)
     return _arc_selection_mean_stream_call(
         xs, f_arc=f_arc, f=f, q=q, mode=mode,
         reference_index=reference_index, tile=tile, interpret=interpret,
@@ -1852,8 +1775,7 @@ def nnm_selection_mean_stream_pallas(
     scores from the full-f32 derived Gram — strictly higher fidelity,
     but a near-tie in krum scores (within ~2^-8 relative for bf16) may
     select a different row than the rounded two-step would. f32 inputs
-    match the composition to float precision. Tile resolved pre-trace
-    (family ``"selection"``)."""
+    match the composition to float precision."""
     if mode not in {"krum", "cge", "monna"}:
         raise ValueError(f"unknown mode {mode!r}")
     K, n, d = xs.shape
@@ -1865,14 +1787,12 @@ def nnm_selection_mean_stream_pallas(
         raise ValueError(f"q must be in [1, n] (got q={q}, n={n})")
     if not 0 <= reference_index < n:
         raise ValueError(f"reference_index out of range (got {reference_index})")
-    if xs.dtype not in (jnp.bfloat16, jnp.float16, jnp.float32):
+    if xs.dtype not in _KERNEL_DTYPES:
         raise ValueError(f"unsupported dtype {xs.dtype}")
     interpret = _resolve_interpret(interpret)
     n_pad = max(_SUBLANES, _round_up(n, _SUBLANES))
     if tile is None:
-        tile = _tuned_tile("selection", n_pad, d) or _auto_selection_tile(
-            d, n_pad, jnp.dtype(xs.dtype).itemsize
-        )
+        tile = _auto_selection_tile(d, n_pad, jnp.dtype(xs.dtype).itemsize)
     return _nnm_selection_mean_stream_call(
         xs, f_nnm=f_nnm, f=f, q=q, mode=mode,
         reference_index=reference_index, tile=tile, interpret=interpret,
@@ -1982,9 +1902,8 @@ def ragged_segment_sum_pallas(
     aggregate ends in, tiled over (row tiles × feature tiles) with the
     batch ``fill`` (an int32 scalar, default ``R``) scalar-prefetched
     so capacity row tiles skip their MXU work — the padding a dense
-    program would pay for is skipped, not multiplied. Tile resolved
-    here, pre-trace (family ``"ragged"``: ``BYZPY_TPU_TILE_RAGGED``
-    env override / autotune cache). The weight-transpose dot mirrors
+    program would pay for is skipped, not multiplied. The
+    weight-transpose dot mirrors
     the XLA fallback's per-cohort einsum contraction row-for-row;
     interpret mode reproduces it bit-for-bit, Mosaic is ulp-level
     (1.8e-7 max against the f32 einsum at 64x65,536 on v5e, PR 21,
@@ -1997,14 +1916,7 @@ def ragged_segment_sum_pallas(
     n, d = x.shape
     n_cohorts = weights.shape[0]
     if tile is None:
-        # cache keys carry the sublane-padded row count, like every
-        # sibling family (autotune.sweep stores them that way)
-        tuned = _tuned_tile(
-            "ragged", max(_SUBLANES, _round_up(n, _SUBLANES)), d
-        )
-        tile = tuned if tuned is not None else max(
-            _LANES, min(4096, _round_up(d, _LANES))
-        )
+        tile = max(_LANES, min(4096, _round_up(d, _LANES)))
     if rows_tile is None:
         rows_tile = max(_SUBLANES, min(256, _round_up(n, _SUBLANES)))
     if fill is None:
@@ -2158,12 +2070,7 @@ def ragged_segment_sum_dequant_pallas(
     if mode == "s4" and block % 2:
         raise ValueError("s4 fused dequant requires an even block")
     if tile is None:
-        tuned = _tuned_tile(
-            "ragged", max(_SUBLANES, _round_up(n, _SUBLANES)), d
-        )
-        tile = tuned if tuned is not None else max(
-            _LANES, min(4096, _round_up(d, _LANES))
-        )
+        tile = max(_LANES, min(4096, _round_up(d, _LANES)))
     # a feature tile must hold whole codec blocks (the scale block
     # boundary) AND whole lanes; round up to the lcm of both
     lcm = block * _LANES // math.gcd(block, _LANES)
@@ -2281,26 +2188,10 @@ MIN_PALLAS_DIM = 256 * 1024
 # MeaMed's fused kernel amortizes differently from the single-sort
 # kernels: its XLA fallback moves a large multiple of the read-once
 # traffic floor (sort + window + masked selection, ~4 passes by the
-# kernel docstrings' bandwidth model; benchmarks/meamed_gate_tune.py
-# prints the derivation) where the fused kernel reads the matrix exactly
-# once. The committed floor is 1/4 of the generic MIN_PALLAS_DIM — a
-# model estimate, not a chip measurement (ROADMAP S4/D5).
+# kernel docstrings' bandwidth model) where the fused kernel reads the
+# matrix exactly once. The committed floor is 1/4 of the generic
+# MIN_PALLAS_DIM — a model estimate, not a chip measurement (ROADMAP D5).
 MEAMED_MIN_DIM = 1 << 16
-
-
-def meamed_min_dim() -> int:
-    """MeaMed's dispatch floor; ``BYZPY_TPU_MEAMED_MIN_DIM`` overrides
-    per call. ``ops.robust.mean_of_medians`` reads this in its Python
-    dispatch wrapper BEFORE the jitted implementation traces, so
-    flipping the env var between calls changes the very next dispatch
-    (no stale-trace pitfall). The one remaining caveat: a caller who
-    wraps ``mean_of_medians`` in their OWN ``jax.jit`` freezes the
-    decision into that outer trace — tuning harnesses should call the
-    public function directly (as ``benchmarks/meamed_gate_tune.py``
-    does)."""
-    import os
-
-    return int(os.environ.get("BYZPY_TPU_MEAMED_MIN_DIM", MEAMED_MIN_DIM))
 
 
 def s4_kernels_unsupported(cast: str) -> NotImplementedError:
@@ -2342,10 +2233,14 @@ def sharding_allows_pallas(x: Array) -> bool:
 
 
 def use_pallas_for(n: int, d: int, *, min_dim: Optional[int] = None) -> bool:
-    """True when the Pallas path should serve a coordinate-wise selection
-    over an ``(n, d)`` matrix on this backend. ``min_dim`` overrides the
-    generic dispatch floor for kernels with a different amortization
-    profile (e.g. ``MEAMED_MIN_DIM``)."""
+    """The shape-and-backend part of :func:`pallas_serves`: true when the
+    Pallas path should serve an ``(n, d)`` matrix on this backend.
+    ``min_dim`` overrides the generic dispatch floor for kernels with a
+    different amortization profile (``MEAMED_MIN_DIM``).
+    ``BYZPY_TPU_PALLAS`` — the only environment variable the dispatch
+    reads — forces the answer: ``0`` never, ``1`` wherever ``n`` fits
+    the network (any ``d``, any backend: how the CPU tests and the toy
+    cell reach the interpreted kernels), anything else this rule."""
     import os
 
     flag = os.environ.get("BYZPY_TPU_PALLAS", "auto")
@@ -2357,21 +2252,61 @@ def use_pallas_for(n: int, d: int, *, min_dim: Optional[int] = None) -> bool:
     return _on_tpu() and n <= MAX_NETWORK_ROWS and d >= floor
 
 
+def pallas_serves(
+    x: Array,
+    *,
+    stream: bool = False,
+    min_dim: int = MIN_PALLAS_DIM,
+    max_dim: Optional[int] = None,
+) -> bool:
+    """THE dispatch gate: does the Pallas route serve this array?
+
+    ``x`` is an ``(n, d)`` matrix, or with ``stream`` a ``(K, n, d)``
+    stack of rounds (a concrete array, a tracer or a
+    ``ShapeDtypeStruct``: only its type is read). True when it is a
+    float32 / bfloat16 / float16 array of that rank, ``n`` fits the
+    sorting network (``MAX_NETWORK_ROWS``), ``d`` is at or above the
+    family's floor (``min_dim``; and at most ``max_dim`` where a family
+    has a cap: MeaMed passes both of its own) on a TPU — or
+    ``BYZPY_TPU_PALLAS`` forces the route, see :func:`use_pallas_for` —
+    and the operand is not device-sharded
+    (:func:`sharding_allows_pallas`). Every public entry point of
+    ``ops.robust``, ``ops.preagg`` and the pre-aggregators asks this
+    and nothing else, in its Python wrapper, so the answer is a fact of
+    the call and never of an inner trace
+    (``tests/test_kernel_route.py`` holds both)."""
+    return bool(
+        x.ndim == (3 if stream else 2)
+        and x.dtype in _KERNEL_DTYPES
+        and use_pallas_for(x.shape[-2], x.shape[-1], min_dim=min_dim)
+        and (max_dim is None or x.shape[-1] <= max_dim)
+        and sharding_allows_pallas(x)
+    )
+
+
+def targets_tpu() -> bool:
+    """Whether programs dispatched now run on a TPU — the backend
+    question for callers outside the kernel modules that choose between
+    two XLA programs by backend and have no array to show the gate (the
+    coordinate-wise aggregators' ragged sort strategy)."""
+    return _on_tpu()
+
+
 # The widest feature tile the stream kernels' heuristics try
 # (``_auto_sort_tile``, ``_auto_selection_tile``); every narrower
-# candidate divides it.
+# candidate divides it (``tests/test_kernel_route.py``).
 _WIDEST_TILE = 16384
 
 
 def aligned_width(n: int, d: int) -> int:
-    """The column count at which to allocate an ``(n, ·)`` matrix with
-    ``d`` real columns so that a stream kernel reads it in place.
+    """The column count at which to allocate an ``(n, ·)`` float matrix
+    with ``d`` real columns so that a stream kernel reads it in place.
 
-    ``d`` where the Pallas route will not serve the matrix on this
-    backend (:func:`use_pallas_for` is false); else ``d`` rounded up to
-    the widest candidate tile, so that whichever tile a kernel's
-    heuristic can afford divides the width and its wrapper takes the
-    ``xp = xs`` path instead of a zero-padded copy of the whole matrix.
+    ``d`` where :func:`pallas_serves` refuses an ``(n, d)`` float32
+    matrix here; else ``d`` rounded up to the widest candidate tile, so
+    that whichever tile a kernel's heuristic can afford divides the
+    width and its wrapper takes the ``xp = xs`` path instead of a
+    zero-padded copy of the whole matrix.
     The caller keeps the extra columns exactly zero (every shipped
     aggregator maps all-zero columns to zero and leaves row norms and
     Gram blocks unchanged) and cuts the result back to ``d``.
@@ -2384,7 +2319,7 @@ def aligned_width(n: int, d: int) -> int:
     (:func:`sorted_reduce_stream_pallas`) reads that stack as it is; as
     rows of an ``(n, width)`` matrix they are one sublane of every tile
     each (``docs/performance.md``, "A folded row")."""
-    if not use_pallas_for(n, d):
+    if not pallas_serves(jax.ShapeDtypeStruct((n, d), jnp.float32)):
         return d
     return _round_up(d, _WIDEST_TILE)
 
@@ -2399,7 +2334,6 @@ __all__ = [
     "meamed_stream_pallas",
     "arc_selection_mean_stream_pallas",
     "clip_selection_mean_stream_pallas",
-    "matmul_input_dtype",
     "nnm_pallas",
     "nnm_stream_pallas",
     "nnm_selection_mean_stream_pallas",
@@ -2412,5 +2346,7 @@ __all__ = [
     "selection_mean_stream_pallas",
     "sharding_allows_pallas",
     "use_pallas_for",
+    "pallas_serves",
+    "targets_tpu",
     "aligned_width",
 ]

@@ -16,6 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .pallas_kernels import pallas_serves
 from .robust import gram_matrix
 
 Array = jnp.ndarray
@@ -58,7 +59,6 @@ def bucket_means(x: Array, perm: Array, *, bucket_size: int) -> Array:
     return jnp.sum(xb * wb[:, :, None], axis=1) / jnp.sum(wb, axis=1, keepdims=True)
 
 
-@partial(jax.jit, static_argnames=("f",))
 def nnm(x: Array, *, f: int) -> Array:
     """Nearest-Neighbor Mixing: replace each row by the mean of its
     ``k = n - f`` nearest neighbors (self included)
@@ -73,19 +73,21 @@ def nnm(x: Array, *, f: int) -> Array:
     neighbor yields NaN here instead of ±inf — both non-finite, both
     ranked last by every downstream NaN-aware aggregator in this package.
     On TPU at large ``d`` this dispatches to the fused two-sweep kernel
-    (``pallas_kernels.nnm_pallas``)."""
+    (``pallas_kernels.nnm_pallas``); dispatch resolves here, pre-trace."""
     n = x.shape[0]
     if not 0 <= f < n:
         raise ValueError(f"f must satisfy 0 <= f < n (got n={n}, f={f})")
-    k = n - f
-    if (
-        x.ndim == 2
-        and x.dtype in (jnp.float32, jnp.bfloat16, jnp.float16)
-    ):
-        from .pallas_kernels import nnm_pallas, sharding_allows_pallas, use_pallas_for
+    return _nnm_impl(x, f=f, use_kernel=pallas_serves(x))
 
-        if use_pallas_for(*x.shape) and sharding_allows_pallas(x):
-            return nnm_pallas(x, f=f)
+
+@partial(jax.jit, static_argnames=("f", "use_kernel"))
+def _nnm_impl(x: Array, *, f: int, use_kernel: bool) -> Array:
+    if use_kernel:
+        from .pallas_kernels import nnm_pallas
+
+        return nnm_pallas(x, f=f)
+    n = x.shape[0]
+    k = n - f
     gram = gram_matrix(x)  # f32 accumulation for 16-bit floats, f64 for f64
     norms = jnp.diagonal(gram)
     d2 = jnp.maximum(norms[:, None] + norms[None, :] - 2.0 * gram, 0.0)

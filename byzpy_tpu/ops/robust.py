@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .pallas_kernels import MEAMED_MAX_DIM, MEAMED_MIN_DIM, pallas_serves
+
 Array = jnp.ndarray
 
 
@@ -48,24 +50,15 @@ def _feature_matmul_dtype(x: Array):
 
 
 def gram_matrix(x: Array) -> Array:
-    """``(n, n)`` Gram matrix ``x @ x.T`` with f32 accumulation for bf16.
-
-    The ``BYZPY_TPU_MATMUL_DTYPE=bf16`` policy (resolved per call,
-    before trace — see ``pallas_kernels.matmul_input_dtype``) multiplies
-    f32 operands at the MXU's native bf16 rate while keeping the f32
-    accumulator; distances lose ~2^-8 relative precision, which only
-    perturbs score near-ties (parity pinned in
-    ``tests/test_fused_parity.py``)."""
-    from .pallas_kernels import matmul_input_dtype
-
-    if matmul_input_dtype(x.dtype) == "bf16":
-        xb = x.astype(jnp.bfloat16)
-        return jnp.einsum(
-            "id,jd->ij", xb, xb, preferred_element_type=jnp.float32
-        )
+    """``(n, n)`` Gram matrix ``x @ x.T`` with f32 accumulation for bf16."""
     return jnp.einsum(
         "id,jd->ij", x, x, preferred_element_type=_feature_matmul_dtype(x)
     )
+
+
+def _has_key_sort(x: Array) -> bool:
+    # f32, and bf16 / f16 through their exact f32 round-trip
+    return jnp.issubdtype(x.dtype, jnp.floating) and x.dtype.itemsize in (2, 4)
 
 
 def sort_rows(x: Array) -> Array:
@@ -142,37 +135,20 @@ def coordinate_median(x: Array) -> Array:
     (:func:`sort_rows` — 3.8x the float sort's throughput on XLA:CPU)
     for float matrices elsewhere. Dispatch resolves here, before any
     jit traces."""
-    from .pallas_kernels import (
-        sharding_allows_pallas,
-        sorted_reduce_stream_pallas,
-        use_pallas_for,
-    )
+    if pallas_serves(x):
+        from .pallas_kernels import sorted_reduce_stream_pallas
 
-    if x.ndim == 2 and x.dtype in (jnp.float32, jnp.bfloat16, jnp.float16):
-        # a device-sharded operand stays on XLA: a pallas_call there
-        # all-gathers the whole (n, d) matrix onto every chip
-        if use_pallas_for(*x.shape) and sharding_allows_pallas(x):
-            return sorted_reduce_stream_pallas(x[None], mode="median")[0]
+        return sorted_reduce_stream_pallas(x[None], mode="median")[0]
+    if x.ndim == 2 and _has_key_sort(x):
         return _median_from_sorted(sort_rows(x))
     return jnp.median(x, axis=0)
-
-
-def _use_stream_kernel(xs: Array) -> bool:
-    from .pallas_kernels import sharding_allows_pallas, use_pallas_for
-
-    return (
-        xs.ndim == 3
-        and xs.dtype in (jnp.float32, jnp.bfloat16, jnp.float16)
-        and use_pallas_for(xs.shape[-2], xs.shape[-1])
-        and sharding_allows_pallas(xs)
-    )
 
 
 def coordinate_median_stream(xs: Array) -> Array:
     """Coordinate-wise median over ``K`` stacked rounds ``(K, n, d)`` in
     one fused launch (see ``aggregate_stream`` for why streaming is the
     training-loop shape); XLA scan fallback elsewhere."""
-    if _use_stream_kernel(xs):
+    if pallas_serves(xs, stream=True):
         from .pallas_kernels import sorted_reduce_stream_pallas
 
         return sorted_reduce_stream_pallas(xs, mode="median")
@@ -181,7 +157,7 @@ def coordinate_median_stream(xs: Array) -> Array:
 
 def trimmed_mean_stream(xs: Array, *, f: int) -> Array:
     """f-trimmed coordinate mean over stacked rounds in one fused launch."""
-    if _use_stream_kernel(xs):
+    if pallas_serves(xs, stream=True):
         from .pallas_kernels import sorted_reduce_stream_pallas
 
         return sorted_reduce_stream_pallas(xs, mode="trimmed", f=f)
@@ -190,9 +166,7 @@ def trimmed_mean_stream(xs: Array, *, f: int) -> Array:
 
 def mean_of_medians_stream(xs: Array, *, f: int) -> Array:
     """MeaMed over stacked rounds in one fused launch."""
-    from .pallas_kernels import MEAMED_MAX_DIM
-
-    if _use_stream_kernel(xs) and xs.shape[-1] <= MEAMED_MAX_DIM:
+    if pallas_serves(xs, stream=True, max_dim=MEAMED_MAX_DIM):
         from .pallas_kernels import meamed_stream_pallas
 
         return meamed_stream_pallas(xs, f=f)
@@ -208,20 +182,10 @@ def trimmed_mean(x: Array, *, f: int) -> Array:
     n = x.shape[0]
     if not 0 <= 2 * f < n:
         raise ValueError(f"trim parameter f must satisfy 0 <= 2f < n (got n={n}, f={f})")
-    from .pallas_kernels import (
-        sharding_allows_pallas,
-        sorted_reduce_stream_pallas,
-        use_pallas_for,
-    )
+    if pallas_serves(x):
+        from .pallas_kernels import sorted_reduce_stream_pallas
 
-    if (
-        x.ndim == 2
-        and x.dtype in (jnp.float32, jnp.bfloat16, jnp.float16)
-        and use_pallas_for(*x.shape)
-        and sharding_allows_pallas(x)
-    ):
         return sorted_reduce_stream_pallas(x[None], mode="trimmed", f=f)[0]
-    # includes device-sharded operands (see coordinate_median)
     return _trimmed_mean_xla(x, f=f)
 
 
@@ -268,55 +232,27 @@ def mean_of_medians(x: Array, *, f: int) -> Array:
     order via a cumulative count — exactly the stable-argsort tie rule
     (the cut VALUE is identical, so tie semantics are unchanged).
 
-    Dispatch — including the tuned ``MEAMED_MIN_DIM`` floor and its
-    ``BYZPY_TPU_MEAMED_MIN_DIM`` override — resolves HERE, in Python,
-    before the jitted implementation traces: flipping the override
-    between calls changes the very next dispatch. The XLA fallback
-    sorts int32 keys (:func:`sort_rows`, 2.4x the old fallback's
-    throughput on XLA:CPU at the 64x65,536 grid row).
+    Dispatch — with MeaMed's own floor and cap, ``MEAMED_MIN_DIM`` and
+    ``MEAMED_MAX_DIM`` — resolves HERE, in Python, before the jitted
+    implementation traces. The XLA fallback sorts int32 keys
+    (:func:`sort_rows`, 2.4x the old fallback's throughput on XLA:CPU at
+    the 64x65,536 grid row), or through the Pallas sort network where
+    the generic gate holds (d past MeaMed's cap).
     """
     n = x.shape[0]
     if not 0 <= f < n:
         raise ValueError(f"f must satisfy 0 <= f < n (got n={n}, f={f})")
-    from .pallas_kernels import (
-        MEAMED_MAX_DIM,
-        meamed_min_dim,
-        meamed_stream_pallas,
-        sharding_allows_pallas,
-        use_pallas_for,
-    )
-
-    if (
-        x.ndim == 2
-        and x.dtype in (jnp.float32, jnp.bfloat16, jnp.float16)
-        and use_pallas_for(*x.shape, min_dim=meamed_min_dim())
-        and x.shape[1] <= MEAMED_MAX_DIM
-        and sharding_allows_pallas(x)
-    ):
+    if pallas_serves(x, min_dim=MEAMED_MIN_DIM, max_dim=MEAMED_MAX_DIM):
         # one fused launch: 1 HBM read + a (1, d) write, vs ~4 passes for
         # the sort/window/mask pipeline below
+        from .pallas_kernels import meamed_stream_pallas
+
         return meamed_stream_pallas(x[None], f=f)[0]
-    use_network = bool(
-        x.ndim == 2 and use_pallas_for(*x.shape) and sharding_allows_pallas(x)
-    )
-    network_tile = None
-    if use_network:
-        # resolve the sort kernel's tile HERE too — sort_columns runs
-        # inside the jitted impl below, where an env/cache read would
-        # freeze into the trace
-        from .pallas_kernels import _SUBLANES, _auto_tile, _round_up
-
-        n_pad = max(_SUBLANES, _round_up(x.shape[0], _SUBLANES))
-        network_tile = _auto_tile(n_pad, x.shape[1])
-    return _mean_of_medians_xla(
-        x, f=f, use_network=use_network, network_tile=network_tile
-    )
+    return _mean_of_medians_xla(x, f=f, use_network=pallas_serves(x))
 
 
-@partial(jax.jit, static_argnames=("f", "use_network", "network_tile"))
-def _mean_of_medians_xla(
-    x: Array, *, f: int, use_network: bool, network_tile=None
-) -> Array:
+@partial(jax.jit, static_argnames=("f", "use_network"))
+def _mean_of_medians_xla(x: Array, *, f: int, use_network: bool) -> Array:
     n = x.shape[0]
     k = n - f
     from .pallas_kernels import sort_columns
@@ -331,7 +267,7 @@ def _mean_of_medians_xla(
             ).dtype
         )
     if use_network:
-        xs = sort_columns(x, tile=network_tile)
+        xs = sort_columns(x)
     elif x.ndim == 2:
         xs = sort_rows(x)
     else:
@@ -463,23 +399,6 @@ def ranked_mean(x: Array, scores: Array, q: int) -> Array:
     return out.astype(x.dtype)
 
 
-def _use_selection_kernel(x: Array) -> bool:
-    """True when the fused two-sweep Pallas selection kernel should serve
-    this input (see ``pallas_kernels.selection_mean_pallas``): float data,
-    network-sized ``n``, ``d`` large enough that the kernel's single-read
-    Gram beats XLA's two-read einsum (XLA streams ``x`` as both lhs and
-    rhs: 0.91 ms vs the 0.31 ms one-read floor at 64x1M f32 on v5e), and
-    an unsharded (or per-shard) operand."""
-    from .pallas_kernels import sharding_allows_pallas, use_pallas_for
-
-    return (
-        x.ndim in (2, 3)  # (n, d) single round or (K, n, d) stream
-        and x.dtype in (jnp.float32, jnp.bfloat16, jnp.float16)
-        and use_pallas_for(x.shape[-2], x.shape[-1])
-        and sharding_allows_pallas(x)
-    )
-
-
 def _selection_mean_xla(
     x: Array, scores: Array, q: int, any_bad: Array
 ) -> Array:
@@ -534,7 +453,7 @@ def multi_krum(x: Array, *, f: int, q: int) -> Array:
     n = x.shape[0]
     if not 1 <= q <= n - f:
         raise ValueError(f"q must satisfy 1 <= q <= n - f (got n={n}, f={f}, q={q})")
-    if _use_selection_kernel(x):
+    if pallas_serves(x):
         from .pallas_kernels import selection_mean_pallas
 
         return selection_mean_pallas(x, f=f, q=q, mode="krum")
@@ -560,7 +479,7 @@ def multi_krum_stream(xs: Array, *, f: int, q: int) -> Array:
     (``pallas_kernels.selection_mean_stream_pallas``; an XLA-level scan
     materializes each round's 256 MB slice before the Gram can read it —
     measured 1.23 ms vs 0.85 ms per 64x1M f32 round on v5e)."""
-    if xs.ndim == 3 and _use_selection_kernel(xs):
+    if pallas_serves(xs, stream=True):
         from .pallas_kernels import selection_mean_stream_pallas
 
         return selection_mean_stream_pallas(xs, f=f, q=q, mode="krum")
@@ -582,7 +501,7 @@ def nnm_multi_krum(x: Array, *, f_nnm: int, f: int, q: int) -> Array:
     collapses to source-space weights, so the whole pipeline costs the
     2 HBM sweeps of a lone aggregator instead of the two-step path's ~5
     (``pallas_kernels.nnm_selection_mean_stream_pallas``)."""
-    if _use_selection_kernel(x):
+    if pallas_serves(x):
         from .pallas_kernels import nnm_selection_mean_stream_pallas
 
         return nnm_selection_mean_stream_pallas(
@@ -596,7 +515,7 @@ def nnm_multi_krum(x: Array, *, f_nnm: int, f: int, q: int) -> Array:
 def nnm_multi_krum_stream(xs: Array, *, f_nnm: int, f: int, q: int) -> Array:
     """``nnm_multi_krum`` over ``K`` stacked rounds ``(K, n, d)`` in one
     dispatch (the training-loop / replay shape; see ``aggregate_stream``)."""
-    if xs.ndim == 3 and _use_selection_kernel(xs):
+    if pallas_serves(xs, stream=True):
         from .pallas_kernels import nnm_selection_mean_stream_pallas
 
         return nnm_selection_mean_stream_pallas(
@@ -616,7 +535,7 @@ def clipped_multi_krum(x: Array, *, tau: float, f: int, q: int) -> Array:
         # validate BEFORE dispatch: the fallback's clip_rows would accept
         # tau <= 0 and silently sign-flip/zero every row
         raise ValueError(f"tau must be positive (got {tau})")
-    if _use_selection_kernel(x):
+    if pallas_serves(x):
         from .pallas_kernels import clip_selection_mean_stream_pallas
 
         return clip_selection_mean_stream_pallas(
@@ -634,7 +553,7 @@ def clipped_multi_krum_stream(
     one dispatch (see ``aggregate_stream``)."""
     if not tau > 0:
         raise ValueError(f"tau must be positive (got {tau})")
-    if xs.ndim == 3 and _use_selection_kernel(xs):
+    if pallas_serves(xs, stream=True):
         from .pallas_kernels import clip_selection_mean_stream_pallas
 
         return clip_selection_mean_stream_pallas(
@@ -655,7 +574,7 @@ def arc_multi_krum(x: Array, *, f_arc: int, f: int, q: int) -> Array:
         raise ValueError(
             f"f_arc must satisfy 0 <= f_arc <= n (got {f_arc}, n={x.shape[0]})"
         )
-    if _use_selection_kernel(x):
+    if pallas_serves(x):
         from .pallas_kernels import arc_selection_mean_stream_pallas
 
         return arc_selection_mean_stream_pallas(
@@ -674,7 +593,7 @@ def arc_multi_krum_stream(xs: Array, *, f_arc: int, f: int, q: int) -> Array:
             f"f_arc must satisfy 0 <= f_arc <= n (got {f_arc}, "
             f"n={xs.shape[-2]})"
         )
-    if xs.ndim == 3 and _use_selection_kernel(xs):
+    if pallas_serves(xs, stream=True):
         from .pallas_kernels import arc_selection_mean_stream_pallas
 
         return arc_selection_mean_stream_pallas(
@@ -701,7 +620,7 @@ def geometric_median(
         raise ValueError("init must be 'median' or 'mean'")
     return _geometric_median_impl(
         x, tol=tol, max_iter=max_iter, eps=eps, init=init,
-        use_kernel=_use_selection_kernel(x),
+        use_kernel=pallas_serves(x),
     )
 
 
@@ -776,7 +695,7 @@ def centered_clipping(
         raise ValueError("init must be one of {'mean','median','zero'}")
     return _centered_clipping_impl(
         x, c_tau=c_tau, M=M, eps=eps, init=init,
-        use_kernel=_use_selection_kernel(x),
+        use_kernel=pallas_serves(x),
     )
 
 
@@ -824,7 +743,7 @@ def cge_stream(xs: Array, *, f: int) -> Array:
     n = xs.shape[-2]
     if not 0 <= f < n:
         raise ValueError(f"f must satisfy 0 <= f < n (got n={n}, f={f})")
-    if _use_stream_kernel(xs):
+    if pallas_serves(xs, stream=True):
         from .pallas_kernels import selection_mean_stream_pallas
 
         return selection_mean_stream_pallas(xs, f=0, q=n - f, mode="cge")
@@ -836,7 +755,7 @@ def monna_stream(xs: Array, *, f: int, reference_index: int = 0) -> Array:
     n = xs.shape[-2]
     if 2 * f >= n:
         raise ValueError(f"Cannot tolerate 2f >= n (got n={n}, f={f})")
-    if _use_stream_kernel(xs):
+    if pallas_serves(xs, stream=True):
         from .pallas_kernels import selection_mean_stream_pallas
 
         return selection_mean_stream_pallas(
@@ -855,7 +774,7 @@ def cge(x: Array, *, f: int) -> Array:
     n = x.shape[0]
     if not 0 <= f < n:
         raise ValueError(f"f must satisfy 0 <= f < n (got n={n}, f={f})")
-    if _use_selection_kernel(x):
+    if pallas_serves(x):
         from .pallas_kernels import selection_mean_pallas
 
         return selection_mean_pallas(x, f=0, q=n - f, mode="cge")
@@ -883,7 +802,7 @@ def monna(x: Array, *, f: int, reference_index: int = 0) -> Array:
         raise ValueError(f"Cannot tolerate 2f >= n (got n={n}, f={f})")
     if not 0 <= reference_index < n:
         raise ValueError(f"reference_index must be in [0, {n}) (got {reference_index})")
-    if _use_selection_kernel(x):
+    if pallas_serves(x):
         from .pallas_kernels import selection_mean_pallas
 
         return selection_mean_pallas(
@@ -1295,7 +1214,7 @@ def multi_krum_from_gram(x: Array, gram: Array, *, f: int, q: int) -> Array:
     n = x.shape[0]
     if not 1 <= q <= n - f:
         raise ValueError(f"q must satisfy 1 <= q <= n - f (got n={n}, f={f}, q={q})")
-    if x.ndim == 2 and _use_selection_kernel(x):
+    if pallas_serves(x):
         from .pallas_kernels import selection_mean_from_gram_pallas
 
         return selection_mean_from_gram_pallas(x, gram, f=f, q=q, mode="krum")

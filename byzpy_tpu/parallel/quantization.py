@@ -15,10 +15,8 @@ tier of that fabric:
   replaces; scales ride along as a ``(..., n_blocks)`` side array.
 * Pallas kernels (:func:`quantize_blockwise` with ``use_pallas=True``)
   for the on-chip path — one HBM read per tensor, scales computed in
-  VMEM — with an XLA fallback that is the default off-TPU. Tile
-  selection happens in the Python wrapper, pre-trace, via the PR-2
-  resolution order (``BYZPY_TPU_TILE_QUANT`` env override, then the
-  autotune cache family ``"quant"``, then the heuristic).
+  VMEM — with an XLA fallback that is the default off-TPU. The tile is
+  the wrapper's ``tile=`` argument or :func:`_auto_quant_tile`'s.
 * :func:`encode_blockwise` / :func:`dequantize_blockwise` — the
   mode-generic door down the SUB-INT8 tier (ISSUE 15): blockwise-
   scaled fp8 (``e4m3fn``/``e5m2`` — the per-block scale centers the
@@ -238,20 +236,10 @@ class QuantizedBlocks:
         return dequantize_blockwise(self, dtype=dtype)
 
 
-def _auto_quant_tile(
-    rows_pad: int, d_pad: int, block: int, family: str = "quant"
-) -> int:
-    """Feature-tile width for the quantize/dequantize kernels. The
-    autotune cache / env override (families ``"quant"`` for int8,
-    ``"quant_fp8"``/``"quant_s4"`` for the sub-int8 tier) wins when the
-    entry is a block multiple; the heuristic targets ~1 MiB f32 tiles,
-    rounded to the quantization block so scales never straddle a grid
-    step."""
-    from ..ops.pallas_kernels import _tuned_tile
-
-    tuned = _tuned_tile(family, rows_pad, d_pad)
-    if tuned is not None and tuned % block == 0:
-        return min(tuned, d_pad)
+def _auto_quant_tile(rows_pad: int, d_pad: int, block: int) -> int:
+    """Feature-tile width for the quantize/dequantize kernels: targets
+    ~1 MiB f32 tiles, rounded to the quantization block so scales never
+    straddle a grid step."""
     per_row = max(block, (262144 // max(rows_pad, 1)) // block * block)
     return min(d_pad, max(block, min(8192 // block * block or block, per_row)))
 
@@ -810,10 +798,7 @@ def dequantize_blockwise(
         rows_pad = max(_SUBLANES, -(-rows // _SUBLANES) * _SUBLANES)
         d_pad = -(-d // block) * block
         if tile is None:
-            tile = _auto_quant_tile(
-                rows_pad, d_pad, block,
-                family="quant_fp8" if sub8 else "quant",
-            )
+            tile = _auto_quant_tile(rows_pad, d_pad, block)
         tile = _whole_blocks_tile(tile, block)
         out = _dequantize_pallas_call(
             v2d, s2d, block=block, tile=tile, interpret=interpret,
@@ -859,7 +844,7 @@ def _dequantize_s4(
         rows_pad = max(_SUBLANES, -(-rows // _SUBLANES) * _SUBLANES)
         d_pad = -(-d // block) * block
         if tile is None:
-            tile = _auto_quant_tile(rows_pad, d_pad, block, family="quant_s4")
+            tile = _auto_quant_tile(rows_pad, d_pad, block)
         tile = _whole_blocks_tile(tile, block)
         out = _dequantize_s4_pallas_call(
             v2d, s2d, block=block, tile=tile, interpret=interpret,
@@ -935,9 +920,8 @@ def encode_blockwise(
             raise s4_kernels_unsupported("float32 -> uint8")
         rows_pad = max(_SUBLANES, -(-rows // _SUBLANES) * _SUBLANES)
         d_pad = -(-d // p.block) * p.block
-        family = "quant_s4" if p.mode == "s4" else "quant_fp8"
         if tile is None:
-            tile = _auto_quant_tile(rows_pad, d_pad, p.block, family=family)
+            tile = _auto_quant_tile(rows_pad, d_pad, p.block)
         tile = _whole_blocks_tile(tile, p.block)
         if p.mode == "s4":
             values, scales = _quantize_s4_pallas_call(

@@ -30,10 +30,9 @@ class NearestNeighborMixing(PreAggregator):
         return preagg.nnm(x, f=self.f)
 
     def _transform_stream_matrix(self, xs: jnp.ndarray) -> jnp.ndarray:
-        from ..ops.pallas_kernels import nnm_stream_pallas
-        from ..ops.robust import _use_stream_kernel
+        from ..ops.pallas_kernels import nnm_stream_pallas, pallas_serves
 
-        if _use_stream_kernel(xs):
+        if pallas_serves(xs, stream=True):
             return nnm_stream_pallas(xs, f=self.f)
         return super()._transform_stream_matrix(xs)
 

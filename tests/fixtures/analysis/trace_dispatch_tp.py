@@ -19,11 +19,12 @@ def getenv_under_jit(x, n):
 
 def make_kernel(x):
     def traced(y):
-        tile = _tuned_tile("sort", 8, y.shape[0])  # finding: dispatch helper
-        return y * tile
+        if pallas_serves(y):  # finding: the dispatch gate asked mid-trace
+            return y * 2
+        return y
 
     return jax.jit(traced)(x)
 
 
-def _tuned_tile(family, n, d):
-    return 128
+def pallas_serves(x):
+    return False

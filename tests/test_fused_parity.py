@@ -8,9 +8,7 @@
   inputs across dtypes;
 * the fused from-Gram Pallas pass matches the unfused
   ``multi_krum_from_gram`` (documented tolerance — score sums reduce in
-  a different order), including through the streaming fold;
-* the ``BYZPY_TPU_MATMUL_DTYPE=bf16`` Gram policy stays within bf16
-  tolerance of the exact f32 path and resolves per call.
+  a different order), including through the streaming fold.
 """
 
 import numpy as np
@@ -213,36 +211,3 @@ def test_fold_guards_slot_errors():
         agg.fold(state, 2, jnp.ones((9,), jnp.float32))
 
 
-# ---------------------------------------------------------------------------
-# bf16 Gram policy
-# ---------------------------------------------------------------------------
-
-
-def test_matmul_dtype_policy_resolves_per_call(monkeypatch):
-    x = _rand(10, 512, seed=9)
-    exact = np.asarray(robust.gram_matrix(x))
-    monkeypatch.setenv("BYZPY_TPU_MATMUL_DTYPE", "bf16")
-    approx = np.asarray(robust.gram_matrix(x))
-    assert approx.dtype == np.float32  # f32 accumulator survives
-    # bf16 input rounding perturbs each product by ~2^-8 relative to the
-    # OPERAND norms, not the (possibly tiny) entry value — tolerance is
-    # therefore absolute, scaled by the diagonal magnitude
-    tol = 2e-2 * float(np.abs(np.diagonal(exact)).mean())
-    np.testing.assert_allclose(approx, exact, atol=tol)
-    assert not np.array_equal(approx, exact)  # the cast really happened
-    monkeypatch.delenv("BYZPY_TPU_MATMUL_DTYPE")
-    np.testing.assert_array_equal(np.asarray(robust.gram_matrix(x)), exact)
-    # bf16 inputs are unaffected by the policy (already narrow)
-    xb = x.astype(jnp.bfloat16)
-    monkeypatch.setenv("BYZPY_TPU_MATMUL_DTYPE", "bf16")
-    assert pk.matmul_input_dtype(xb.dtype) is None
-
-
-def test_bf16_policy_multi_krum_parity(monkeypatch):
-    x = _rand(16, 640, seed=10)
-    exact = np.asarray(robust.multi_krum(x, f=3, q=5))
-    monkeypatch.setenv("BYZPY_TPU_MATMUL_DTYPE", "bf16")
-    approx = np.asarray(robust.multi_krum(x, f=3, q=5))
-    # scores shift by ~2^-8 relative; on generic (tie-free) data the
-    # selection is identical, so the aggregate matches to bf16 tolerance
-    np.testing.assert_allclose(approx, exact, rtol=2e-2, atol=1e-2)

@@ -777,10 +777,9 @@ def test_sorted_reduce_wider_blocks_and_a_tile_that_is_no_lane_multiple():
 def test_sorted_reduce_resolves_only_blocks_of_whole_native_tiles(monkeypatch, dtype, whole):
     """A block holds ``tile / 128`` sublane rows of each worker and Mosaic
     takes whole (8, 128) tiles of f32, (16, 128) of 16-bit rows: a
-    narrower tile from the environment, an old cache entry or the
-    heuristic (an odd ``d``, many workers) is rounded up before the call
-    where Mosaic compiles it; one the caller gives for Mosaic raises,
-    unless it covers the array."""
+    narrower tile from the heuristic (an odd ``d``, many workers) is
+    rounded up before the call where Mosaic compiles it; one the caller
+    gives for Mosaic raises, unless it covers the array."""
     from byzpy_tpu.ops import pallas_kernels as pk
 
     seen = []
@@ -791,17 +790,13 @@ def test_sorted_reduce_resolves_only_blocks_of_whole_native_tiles(monkeypatch, d
 
     monkeypatch.setattr(pk, "_sorted_reduce_stream_call", recording_call)
     xs = jnp.ones((1, 8, 4096), dtype)
-    monkeypatch.setenv("BYZPY_TPU_TILE_SORTED_REDUCE", "512")
-    pk.sorted_reduce_stream_pallas(xs, mode="median", interpret=False)
-    monkeypatch.delenv("BYZPY_TPU_TILE_SORTED_REDUCE")
-    monkeypatch.setattr(pk, "_tuned_tile", lambda family, n, d: 256)  # an old cache entry
-    pk.sorted_reduce_stream_pallas(xs, mode="median", interpret=False)
-    monkeypatch.setattr(pk, "_tuned_tile", lambda family, n, d: None)
+    with pytest.raises(ValueError, match="whole tiles"):  # where a variable once put 512
+        pk.sorted_reduce_stream_pallas(xs, mode="median", tile=512, interpret=False)
     pk.sorted_reduce_stream_pallas(  # 128 | d only
         jnp.ones((1, 8, 128 * 33), dtype), mode="median", interpret=False)
     pk.sorted_reduce_stream_pallas(
         jnp.ones((1, 128, 1024 * 3), dtype), mode="median", interpret=False)
-    assert seen == [whole] * 4
+    assert seen == [whole] * 2
     pk.sorted_reduce_stream_pallas(xs, mode="median", interpret=False)  # a wide tile stays
     pk.sorted_reduce_stream_pallas(  # and the interpreter takes what was resolved
         jnp.ones((1, 8, 128 * 33), dtype), mode="median", interpret=True)

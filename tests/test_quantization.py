@@ -1,7 +1,6 @@
 """Kernel tier of the quantized comm fabric: blockwise int8 round-trip
-error bounds, Pallas/XLA parity, stochastic rounding, pytree behavior,
-and the pre-trace tile dispatch (env override + autotune cache family
-``"quant"``)."""
+error bounds, Pallas/XLA parity, stochastic rounding and pytree
+behavior."""
 
 import jax
 import jax.numpy as jnp
@@ -164,45 +163,3 @@ def test_comm_precision_coercion_and_validation():
     assert qz.CommPrecision(mode="int8", block=256).wire_bytes_per_value() == \
         pytest.approx(1.0 + 4.0 / 256)
     assert qz.CommPrecision().wire_bytes_per_value() == 4.0
-
-
-def test_tile_env_override_resolves_pre_trace(monkeypatch):
-    """The quant family obeys the PR-2 dispatch contract: the env
-    override is read in the wrapper, per call, before the jitted inner
-    function traces."""
-    x = _rand((8, 2048), seed=4)
-    ref = qz.quantize_blockwise(x, use_pallas=True, interpret=True)
-    monkeypatch.setenv("BYZPY_TPU_TILE_QUANT", "512")
-    out = qz.quantize_blockwise(x, use_pallas=True, interpret=True)
-    np.testing.assert_array_equal(np.asarray(ref.values), np.asarray(out.values))
-
-
-def test_autotune_cache_consulted(tmp_path, monkeypatch):
-    from byzpy_tpu.profiling import tilecache
-
-    cache = tmp_path / "tiles.json"
-    monkeypatch.setenv("BYZPY_TPU_TUNE_CACHE", str(cache))
-    monkeypatch.delenv("BYZPY_TPU_TILE_QUANT", raising=False)
-    tilecache.store("quant", platform=jax.default_backend(), n=8, d=2048,
-                    tile=512, path=str(cache))
-    assert qz._auto_quant_tile(8, 2048, 256) == 512
-    # a cached tile that is not a block multiple degrades to the heuristic
-    tilecache.store("quant", platform=jax.default_backend(), n=8, d=2048,
-                    tile=384, path=str(cache))
-    assert qz._auto_quant_tile(8, 2048, 256) % 256 == 0
-
-
-def test_autotune_sweep_registers_quant_family(tmp_path, monkeypatch):
-    from byzpy_tpu.profiling import autotune
-
-    cache = tmp_path / "tiles.json"
-    row = autotune.sweep(
-        "quant", n=8, d=2048, candidates=(1024, 2048), repeat=1,
-        cache_path=str(cache), verbose=False,
-    )
-    assert row["tile"] in (1024, 2048)
-    hit = autotune.sweep(
-        "quant", n=8, d=2048, candidates=(1024, 2048), repeat=1,
-        cache_path=str(cache), verbose=False,
-    )
-    assert hit["cached"] is True
