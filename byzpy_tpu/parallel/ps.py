@@ -36,6 +36,7 @@ parameter server.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Callable, Optional, Tuple, Union
@@ -46,7 +47,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.bundle import ModelBundle
-from ..utils.trees import ravel_pytree_fn
+from ..utils.trees import ravel_pytree_fn, row_layout, tree_size
 from .collectives import reshard_q, reshard_q_ef
 from .mesh import node_axis
 from .quantization import (
@@ -236,16 +237,30 @@ def build_ps_train_step(
     tail: on one device ``d_pad`` is the width the Pallas stream
     kernels read in place
     (:func:`~byzpy_tpu.ops.pallas_kernels.aligned_width`; ``d`` wherever
-    they will not serve the matrix) and each computed row is ravelled
-    at that width; on a mesh it is the sharded update's grid.
+    they will not serve the matrix) and each computed row is made at
+    that width; on a mesh it is the sharded update's grid.
 
     On one device a row is kept FOLDED wherever ``d_pad % 1024 == 0``:
     shaped ``(d_pad / 128, 128)``, whole (8, 128) TPU tiles, and not one
-    sublane of every tile of an ``(n, d_pad)`` matrix. The loop writes
-    row i of the ``(n, d_pad / 128, 128)`` stack it carries, the
-    byzantine rows (their tail forced to zero) are written into rows
-    h..n-1 of the same buffer, and ``pre_aggregate`` / ``aggregate`` are
-    handed ``stack.reshape(n, d_pad)``. Who relays out is decided by the
+    sublane of every tile of an ``(n, d_pad)`` matrix. The loop carries
+    the ``(n, d_pad / 128, 128)`` stack and writes row i of it LEAF BY
+    LEAF (:func:`~byzpy_tpu.utils.trees.row_layout`): every leaf whose
+    size is a multiple of 1024 has whole tiles of the row to itself and
+    its gradient is written there once, in the order it lies in memory
+    (a leaf ``(..., C)`` with ``C`` a multiple of 128 above 128: (8, 128)
+    tile after tile; else row-major), and the other leaves follow,
+    ravelled together with the zero tail. No row-wide ``concatenate`` and
+    no relayout of a weight gradient stands in front of the write. So
+    the ORDER OF THE COLUMNS of a folded row is the round's own: fixed by
+    the parameter tree and ``d_pad``, the same for every row, the
+    aggregate and (sharded update) the carried flat state, the ``d`` real
+    columns first and the zero tail last, and NOT ``ravel_pytree``'s.
+    Where rows are not folded (a CPU, a mesh, ``d`` under the kernels'
+    floor) no leaf is placed on its own and the order is
+    ``ravel_pytree``'s. The byzantine rows (their tail forced to zero)
+    are written into rows h..n-1 of the same buffer, and
+    ``pre_aggregate`` / ``aggregate`` are handed
+    ``stack.reshape(n, d_pad)``. Who relays out is decided by the
     compiler from what that function does with it: the sort family's
     kernel folds its argument again and reads the loop's buffer; a
     consumer that wants the workers in sublanes (Multi-Krum's Gram, any
@@ -254,10 +269,16 @@ def build_ps_train_step(
     ``(n, d)`` stack has all n rows and the byzantine ones are selected
     into it in one pass.
 
-    ``pre_aggregate`` and ``aggregate`` see the ``(n, d_pad)`` matrix
-    and must map all-zero columns to zero without changing the other
-    columns (every shipped one does, ``docs/performance.md``); the
-    aggregate's tail is cut or re-zeroed before the update.
+    ``pre_aggregate``, ``aggregate`` and ``attack`` see ``(·, d_pad)``
+    matrices whose columns are the parameters in that fixed order. They
+    must map all-zero columns to zero and give the other columns what
+    they would give from the ``(n, d)`` matrix with its columns in the
+    same order: true of anything that works coordinate by coordinate or
+    reads rows through norms and inner products, so of every shipped
+    aggregator, pre-aggregator and attack (``docs/performance.md``,
+    "The contract an aggregator or pre-aggregator meets"). The
+    aggregate's tail is cut or re-zeroed before the update, which maps
+    the columns back to the tree.
 
     Returns ``(params, opt_state, metrics)`` where metrics carries the mean
     honest loss and the aggregated-gradient norm (computed shard-locally
@@ -268,8 +289,7 @@ def build_ps_train_step(
     comm = as_comm_precision(comm_precision)
     su = as_sharded_update(sharded_update)
     gather_p = as_comm_precision(su.param_gather_precision)
-    ravel, unravel = ravel_pytree_fn(bundle.params)
-    loss_fn = bundle.loss_fn
+    grad_of = jax.value_and_grad(bundle.loss_fn)
     h, b = cfg.n_honest, cfg.n_byzantine
     if not 0 <= b < cfg.n_nodes:
         raise ValueError(f"need 0 <= n_byzantine < n_nodes (got {b}/{cfg.n_nodes})")
@@ -302,9 +322,7 @@ def build_ps_train_step(
         for a in extra:
             feat_shards *= mesh.shape[a]
 
-    flat0 = ravel(bundle.params)
-    param_dtype = flat0.dtype
-    d = flat0.shape[0]
+    d = tree_size(bundle.params)
 
     # -- sharded weight update setup -------------------------------------
     # The flat layouts reuse the aggregation grid: a (d,) vector sharded
@@ -349,20 +367,17 @@ def build_ps_train_step(
     # on. (Any width the stream kernels read in place is a multiple of
     # 1024.)
     row_shape = (row_width // 128, 128) if row_width % 1024 == 0 else (row_width,)
-
-    def per_node_grad(params, x, y):
-        loss, g = jax.value_and_grad(loss_fn)(params, x, y)
-        if row_width != d:
-            # the zero tail rides the ravel's own concatenate (padding
-            # the ravelled row afterwards costs a copy of it)
-            g = (g, jnp.zeros((row_width - d,), param_dtype))
-        flat = ravel(g)
-        if grad_dtype is not None:
-            flat = flat.astype(grad_dtype)
-        return loss, flat
+    # The order of a row's columns is the round's own, the same for every
+    # row, the aggregate and (sharded update) the carried flat state.
+    # Folded, each leaf that is whole tiles has its own place in the row,
+    # in the order its gradient lies in memory, so that the loop below
+    # writes it there as it is made; elsewhere the order is ravel_pytree's.
+    # The real columns are the first d either way, the zero tail the rest.
+    layout = row_layout(bundle.params, row_width, folded=len(row_shape) == 2)
+    param_dtype = layout.dtype
 
     if su_on:
-        flat_padded0 = jnp.pad(flat0, (0, d_pad - d))
+        flat_padded0 = jnp.pad(layout.ravel(bundle.params), (0, d_pad - row_width))
         if flat_sharding is not None:
             flat_padded0 = jax.device_put(flat_padded0, flat_sharding)
         # optax init builds state via zeros_like, so every (d_pad,) moment
@@ -515,26 +530,40 @@ def build_ps_train_step(
                 # With no byzantine worker the slices are the whole arrays
                 # and emit nothing.
                 xs_h, ys_h = xs[:h], ys[:h]
-                loss0, row0 = jax.eval_shape(
-                    lambda: per_node_grad(params, xs_h[0], ys_h[0]))
+                loss0, _ = jax.eval_shape(lambda: grad_of(params, xs_h[0], ys_h[0]))
+                # a row's first axis counts units of `lane` columns: 128
+                # where rows are folded, single columns where they are flat
+                lanes = row_shape[1:]
+                lane = math.prod(lanes)
 
                 def one_worker(i, carry):
                     losses, grads = carry
-                    loss, flat = per_node_grad(params, xs_h[i], ys_h[i])
-                    put = jax.lax.dynamic_update_index_in_dim
-                    return (put(losses, loss, i, 0),
-                            put(grads, flat.reshape(row_shape), i, 0))
+                    loss, g = grad_of(params, xs_h[i], ys_h[i])
+                    pieces = layout.place(g, grad_dtype)
+                    losses = jax.lax.dynamic_update_index_in_dim(losses, loss, i, 0)
+                    # each piece of the row goes where the layout has it:
+                    # a leaf's gradient, whole tiles of row i, is written
+                    # once, from where the backward pass left it
+                    for first, piece in zip(layout.offsets, pieces):
+                        grads = jax.lax.dynamic_update_slice(
+                            grads, jax.lax.expand_dims(piece.reshape(-1, *lanes), (0,)),
+                            (i, first // lane, *(0 for _ in lanes)))
+                    return losses, grads
 
                 # (an uninitialised buffer: every row is written, h here
                 # and b by the attack; zeros would cost a pass over it)
                 losses, grads = jax.lax.fori_loop(0, h, one_worker, (
                     jnp.zeros((h,), loss0.dtype),
-                    jax.lax.empty((cfg.n_nodes, *row_shape), row0.dtype)))
+                    jax.lax.empty((cfg.n_nodes, *row_shape), grad_res_dtype)))
             else:
                 # Every node's forward/backward runs in parallel across
                 # the mesh: vmap over the node axis of node-sharded data
                 # with replicated params.
-                losses, grads = jax.vmap(per_node_grad, in_axes=(None, 0, 0))(
+                def per_node_row(params, x, y):
+                    loss, g = grad_of(params, x, y)
+                    return loss, layout.ravel(g, grad_dtype)
+
+                losses, grads = jax.vmap(per_node_row, in_axes=(None, 0, 0))(
                     params, xs, ys
                 )
         if feat_spec is not None and comm.enabled:
@@ -629,10 +658,10 @@ def build_ps_train_step(
                     )
                 with jax.named_scope("round.param_gather"):
                     gathered, ef_state = gather_flat_params(new_flat, ef_state)
-                params = unravel(gathered[:d])
+                params = layout.unravel(gathered[:d])
                 opt_state = (new_flat, inner)
             else:
-                update = unravel(agg_flat)
+                update = layout.unravel(agg_flat)
                 updates, opt_state = opt.update(update, opt_state, params)
                 params = optax.apply_updates(params, updates)
             metrics = {

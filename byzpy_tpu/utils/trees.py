@@ -11,6 +11,9 @@ directly — sharding it over a mesh replaces chunking it over workers.
 
 from __future__ import annotations
 
+import itertools
+import math
+from dataclasses import dataclass
 from typing import Any, Callable, List, Sequence, Tuple
 
 import jax
@@ -82,3 +85,127 @@ def ravel_pytree_fn(
         return ravel_pytree(tree)[0]
 
     return ravel, unravel
+
+
+# A folded row is (width / 128, 128) in whole (8, 128) tiles: 1024 columns.
+_TILE = 1024
+
+
+def _tile_order(shape: Tuple[int, ...]) -> Tuple[int, int] | None:
+    """``(R, C)`` where a leaf of this shape lies on the TPU as (8, 128)
+    tiles of an ``(R, C)`` matrix that a row-major flattening would have
+    to relay: its minor dimension ``C`` is more than one tile wide, and
+    its other dimensions together are whole tiles high. ``None``
+    elsewhere (a row-major leaf whose ``C`` is 128 is those tiles
+    already; a narrower one has no dense tiles to keep)."""
+    if len(shape) < 2:
+        return None
+    cols = shape[-1]
+    rows = math.prod(shape[:-1])
+    if cols % 128 or cols <= 128 or rows % 8:
+        return None
+    return rows, cols
+
+
+@dataclass(frozen=True)
+class RowLayout:
+    """Where each leaf of a parameter tree sits in a ``width``-column row
+    of the round's gradient matrix (:func:`row_layout`)."""
+
+    width: int
+    d: int
+    dtype: Any
+    offsets: Tuple[int, ...]  # first column of each piece ``place`` returns
+    place: Callable[..., List[jnp.ndarray]]
+    ravel: Callable[..., jnp.ndarray]
+    unravel: Callable[[jnp.ndarray], Any]
+
+    @property
+    def tile_leaves(self) -> int:
+        """Leaves placed on their own (every piece but the last)."""
+        return len(self.offsets) - 1
+
+    @property
+    def placed_share(self) -> float:
+        """Share of the ``d`` real columns written leaf by leaf."""
+        return self.offsets[-1] / self.d if self.d else 0.0
+
+
+def row_layout(example: Any, width: int, *, folded: bool) -> RowLayout:
+    """The order of a row's columns for trees shaped like ``example``.
+
+    A row is ``width >= d`` columns: the ``d`` parameters first, an
+    exactly-zero tail after them. Where rows are not ``folded`` the order
+    is ``ravel_pytree``'s and the row is one piece. Where they are (a row
+    is kept ``(width / 128, 128)``, whole (8, 128) tiles), every *tile
+    leaf* (size a multiple of 1024) comes first, in tree order, each at
+    its running offset (a multiple of 1024 too, so a leaf is whole tiles
+    of the row), and all other leaves follow, ravelled in tree order
+    together with the tail. A tile leaf ``(..., C)`` with ``C`` a multiple
+    of 128 above 128 keeps the order of its TPU tiles (of 8 rows x 128
+    columns, row of tiles after row of tiles), every other one is
+    row-major: either way the bytes of the gradient as the backward pass
+    leaves them, so a worker's loop writes the leaf into its place with
+    no relayout and no row-wide ``concatenate`` in front.
+
+    The order is fixed by the tree and ``width`` alone, the same for
+    every row, and its two maps are inverse: ``unravel(ravel(t)[:d])`` is
+    ``t``. ``place(tree, cast=None)`` returns the row's pieces as 1-D
+    arrays (first columns: ``offsets``; the last piece is the ravelled
+    rest with the tail), ``ravel`` joins them into the ``(width,)`` row,
+    and ``unravel`` takes the first ``d`` columns back to the tree. All
+    are trace-safe.
+    """
+    leaves, treedef = jax.tree_util.tree_flatten(example)
+    shapes = [tuple(leaf.shape) for leaf in leaves]
+    sizes = [math.prod(shape) for shape in shapes]
+    dtypes = [leaf.dtype for leaf in leaves]
+    del leaves  # the closures below outlive this call: they keep shapes, not arrays
+    d = sum(sizes)
+    dtype = jnp.result_type(*dtypes) if dtypes else jnp.float32
+    if width < d:
+        raise ValueError(f"a row of {width} columns cannot hold {d} parameters")
+    tile_at = [k for k, size in enumerate(sizes) if folded and size and size % _TILE == 0]
+    rest_at = [k for k in range(len(sizes)) if k not in tile_at]
+    offsets = tuple(itertools.accumulate((sizes[k] for k in tile_at), initial=0))
+    placed = offsets[-1]
+    # (leaf, first column, its (R, C) where it keeps the order of its tiles)
+    tiles = [(k, first, _tile_order(shapes[k])) for k, first in zip(tile_at, offsets)]
+    _, unravel_rest = ravel_pytree([jnp.zeros(shapes[k], dtypes[k]) for k in rest_at])
+
+    def place(tree: Any, cast: Any = None) -> List[jnp.ndarray]:
+        got = treedef.flatten_up_to(tree)
+        pieces = []
+        for k, _, order in tiles:
+            leaf = got[k]
+            if order is not None:
+                rows, cols = order
+                leaf = leaf.reshape(rows // 8, 8, cols // 128, 128).transpose(0, 2, 1, 3)
+            pieces.append(leaf.reshape(-1).astype(cast or dtype))
+        rest = [got[k] for k in rest_at]
+        if width != d:
+            # the zero tail rides the ravel's own concatenate (padding the
+            # ravelled row afterwards costs a copy of it)
+            rest.append(jnp.zeros((width - d,), dtype))
+        flat = ravel_pytree(rest)[0]
+        pieces.append(flat if cast is None else flat.astype(cast))
+        return pieces
+
+    def ravel(tree: Any, cast: Any = None) -> jnp.ndarray:
+        pieces = place(tree, cast)
+        return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
+
+    def unravel(flat: jnp.ndarray) -> Any:
+        got: List[Any] = [None] * len(shapes)
+        for k, first, order in tiles:
+            leaf = flat[first : first + sizes[k]]
+            if order is not None:
+                rows, cols = order
+                leaf = leaf.reshape(rows // 8, cols // 128, 8, 128).transpose(0, 2, 1, 3)
+            got[k] = leaf.reshape(shapes[k]).astype(dtypes[k])
+        for k, leaf in zip(rest_at, unravel_rest(flat[placed:d])):
+            got[k] = leaf.astype(dtypes[k])
+        return jax.tree_util.tree_unflatten(treedef, got)
+
+    return RowLayout(width=width, d=d, dtype=dtype, offsets=tuple(offsets),
+                     place=place, ravel=ravel, unravel=unravel)

@@ -7,6 +7,13 @@ cuts the aggregate's zero tail before the update. On the CPU the width is
 ``d`` and only the in-place row write differs from a round that
 concatenates: bit-identical. With the width forced wider, the XLA route
 sees the zero-tailed matrix every backend's wide path would.
+
+Where rows are folded (``(width / 128, 128)``: the TPU's wide path, forced
+here by the width or by the interpreted kernels) the order of a row's
+columns is the round's own (``utils.trees.row_layout``): leaves that are
+whole tiles first, each written into the loop's stack on its own. The
+tests that pin the matrix to ``ravel_pytree``'s order are restated there
+against the layout's order, case for case.
 """
 
 from __future__ import annotations
@@ -25,6 +32,7 @@ import numpy as np
 import optax
 import pytest
 
+from byzpy_tpu.models.bundle import ModelBundle
 from byzpy_tpu.models.nets import mnist_mlp
 from byzpy_tpu.ops import attack_ops, pallas_kernels, preagg, robust
 from byzpy_tpu.parallel.ps import (
@@ -32,7 +40,9 @@ from byzpy_tpu.parallel.ps import (
     build_ps_train_step,
     jit_ps_train_step,
 )
-from byzpy_tpu.utils.trees import ravel_pytree_fn, tree_size
+from byzpy_tpu.parallel import ps as ps_module
+from byzpy_tpu.parallel.mesh import node_mesh
+from byzpy_tpu.utils.trees import RowLayout, ravel_pytree_fn, row_layout, tree_size
 
 N, B, STEPS = 8, 2, 3
 CFG = PSStepConfig(n_nodes=N, n_byzantine=B, learning_rate=0.05, momentum=0.9)
@@ -64,7 +74,7 @@ def _noise(honest, key):
     (so in a pad tail too). Drawn at one fixed length and cut, so that the
     first d columns do not depend on the matrix's width."""
     width = honest.shape[1]
-    noise = attack_ops.gaussian(key, (1 << 14,), sigma=0.1)[:width]
+    noise = attack_ops.gaussian(key, (1 << 15,), sigma=0.1)[:width]
     return jnp.mean(honest, axis=0) + noise
 
 
@@ -76,6 +86,44 @@ def bundle():
     return mnist_mlp(0, hidden=16)
 
 
+def _tiles_apply(params, x):
+    y = x.reshape(x.shape[0], -1)
+    for k in range(4):
+        y = jnp.tanh(y @ params[f"w{k}"] + params[f"b{k}"])
+    return y @ params["w4"] + params["b4"]
+
+
+TILES_SHAPES = {
+    "w0": (784, 16), "b0": (16,),   # ragged: 12544 is no multiple of 1024
+    "w1": (16, 256), "b1": (256,),  # a tile leaf kept in the order of its (8, 128) tiles
+    "w2": (256, 32), "b2": (32,),   # a tile leaf narrower than a tile: row-major
+    "w3": (32, 128), "b3": (128,),  # a tile leaf one tile wide: row-major is its tiles
+    "w4": (128, 10), "b4": (10,),
+}
+TILE_LEAVES = 3
+
+
+@pytest.fixture(scope="module")
+def tiles_bundle():
+    """A hand-made MLP on the same batches with three tile leaves (one of
+    each kind) between ragged ones: d = 30,650, folded rows 31,744 wide
+    (32,768 under the forced kernels)."""
+    keys = jax.random.split(jax.random.PRNGKey(3), len(TILES_SHAPES))
+    params = {name: jax.random.normal(k, shape, jnp.float32) / np.sqrt(shape[0])
+              for k, (name, shape) in zip(keys, TILES_SHAPES.items())}
+    return ModelBundle(_tiles_apply, params)
+
+
+def _round_to_1024(n, d):
+    return -(-d // 1024) * 1024
+
+
+def _layout_order(bundle, folded=True):
+    """``(ravel, unravel)`` of rows ``d`` wide in the layout's order."""
+    layout = row_layout(bundle.params, tree_size(bundle.params), folded=folded)
+    return layout.ravel, layout.unravel
+
+
 @pytest.fixture(scope="module")
 def batches():
     kx, ky = jax.random.split(jax.random.PRNGKey(7))
@@ -85,11 +133,13 @@ def batches():
     return xs, ys, keys
 
 
-def _concatenating_step(bundle, aggregate, attack):
+def _concatenating_step(bundle, aggregate, attack, order=None):
     """The round as it was before the matrix was written once: rows
-    ravelled at width d, the matrix rebuilt by ``concatenate``."""
+    ravelled at width d (in ``ravel_pytree``'s order, or in the
+    ``order = (ravel, unravel)`` given), the matrix rebuilt by
+    ``concatenate``."""
     opt = optax.sgd(CFG.learning_rate, momentum=CFG.momentum)
-    ravel, unravel = ravel_pytree_fn(bundle.params)
+    ravel, unravel = order or ravel_pytree_fn(bundle.params)
     h = CFG.n_honest
 
     def per_node_grad(params, x, y):
@@ -123,7 +173,7 @@ def _drive(step, params, opt_state, batches, state=None):
     return flat, np.asarray(norms)
 
 
-def _matrix_the_aggregate_sees(bundle, attack, batches):
+def _matrix_the_aggregate_sees(bundle, attack, batches, **step_kwargs):
     """One round run eagerly (no jit), so that the aggregate is handed a
     real array: the matrix of the first step."""
     seen = []
@@ -132,11 +182,26 @@ def _matrix_the_aggregate_sees(bundle, attack, batches):
         seen.append(np.asarray(x))
         return jnp.mean(x, axis=0)
 
-    step, opt_state = build_ps_train_step(bundle, recording_mean, CFG, attack=attack)
+    step, opt_state = build_ps_train_step(bundle, recording_mean, CFG, attack=attack,
+                                          **step_kwargs)
     xs, ys, keys = batches
     step(bundle.params, opt_state, xs[0], ys[0], keys[0])
     (matrix,) = seen
     return matrix
+
+
+def _concatenated_matrix(bundle, attack, batches, ravel, width):
+    """The first step's matrix as a round that concatenates makes it: rows
+    ravelled by ``ravel`` and zero-padded to ``width``, the attack's rows
+    (tail forced to zero) under the honest ones."""
+    xs, ys, keys = batches
+    d = tree_size(bundle.params)
+    grads = jax.lax.map(lambda xy: ravel(jax.grad(bundle.loss_fn)(bundle.params, *xy)),
+                        (xs[0], ys[0]))  # one worker after another, as the round's loop
+    honest = jnp.pad(grads[: CFG.n_honest], ((0, 0), (0, width - d)))
+    byz = jnp.broadcast_to(attack(honest, keys[0]), (B, width))
+    byz = jnp.where(jnp.arange(width) < d, byz, 0)
+    return np.asarray(jnp.concatenate([honest, byz]))
 
 
 def _round_to_128(n, d):
@@ -166,17 +231,57 @@ def test_three_steps_equal_the_concatenating_round_bitwise(bundle, batches, agg,
 
 
 @pytest.mark.parametrize("attack", sorted(ATTACKS))
+@pytest.mark.parametrize("agg", sorted(AGGREGATORS))
+def test_three_folded_steps_equal_the_concatenating_round_in_the_layouts_order(
+        monkeypatch, tiles_bundle, batches, agg, attack):
+    """The same on the wide path with folded rows (the width forced to a
+    multiple of 1024; XLA route): the reference concatenates rows ``d``
+    wide in the layout's order. A coordinate-wise aggregate gives the same
+    bits; a selection sums its distances over the columns in another order
+    and over a zero tail (the last bit of a norm)."""
+    monkeypatch.setattr(pallas_kernels, "aligned_width", _round_to_1024)
+    step, opt_state = jit_ps_train_step(
+        tiles_bundle, AGGREGATORS[agg], CFG, attack=ATTACKS[attack], donate=False)
+    ref_step, ref_opt = _concatenating_step(
+        tiles_bundle, AGGREGATORS[agg], ATTACKS[attack], order=_layout_order(tiles_bundle))
+    got, got_norms = _drive(step, tiles_bundle.params, opt_state, batches)
+    want, want_norms = _drive(ref_step, tiles_bundle.params, ref_opt, batches)
+    if agg in ("trimmed_mean", "median"):
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got_norms, want_norms)
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
+        np.testing.assert_allclose(got_norms, want_norms, rtol=1e-5)
+
+
+@pytest.mark.parametrize("attack", sorted(ATTACKS))
 def test_the_matrix_the_aggregate_sees_equals_the_concatenated_one_bitwise(
         bundle, batches, attack):
     """Whatever the aggregator: eagerly, row for row and bit for bit."""
-    xs, ys, keys = batches
     matrix = _matrix_the_aggregate_sees(bundle, ATTACKS[attack], batches)
     ravel, _ = ravel_pytree_fn(bundle.params)
-    grads = jax.vmap(lambda x, y: ravel(jax.grad(bundle.loss_fn)(bundle.params, x, y)))(
-        xs[0], ys[0])
-    honest = grads[: CFG.n_honest]
-    byz = jnp.broadcast_to(ATTACKS[attack](honest, keys[0]), (B, honest.shape[1]))
-    np.testing.assert_array_equal(matrix, np.asarray(jnp.concatenate([honest, byz])))
+    want = _concatenated_matrix(bundle, ATTACKS[attack], batches, ravel, matrix.shape[1])
+    np.testing.assert_array_equal(matrix, want)
+
+
+@pytest.mark.parametrize("attack", sorted(ATTACKS))
+def test_the_folded_matrix_equals_the_concatenated_one_in_the_layouts_order_bitwise(
+        monkeypatch, tiles_bundle, batches, attack):
+    """Folded rows: the columns are the parameters in the layout's order,
+    the same in every row (the attack is handed them in that order and
+    its rows land in it), then the exactly-zero tail."""
+    monkeypatch.setattr(pallas_kernels, "aligned_width", _round_to_1024)
+    d = tree_size(tiles_bundle.params)
+    matrix = _matrix_the_aggregate_sees(tiles_bundle, ATTACKS[attack], batches)
+    assert matrix.shape == (N, _round_to_1024(N, d)) and matrix.shape[1] > d
+    ravel, _ = _layout_order(tiles_bundle)
+    want = _concatenated_matrix(tiles_bundle, ATTACKS[attack], batches, ravel, matrix.shape[1])
+    np.testing.assert_array_equal(matrix, want)
+    assert np.count_nonzero(matrix[:, d:]) == 0
+    # and that order is not ravel_pytree's
+    other, _ = ravel_pytree_fn(tiles_bundle.params)
+    assert not np.array_equal(
+        want, _concatenated_matrix(tiles_bundle, ATTACKS[attack], batches, other, matrix.shape[1]))
 
 
 # -- (ii) the wide path, on the XLA route ------------------------------------
@@ -201,12 +306,20 @@ def test_wide_rows_give_the_unpadded_rounds_parameters(monkeypatch, bundle, batc
 
 
 @pytest.mark.parametrize("attack", sorted(ATTACKS))
-def test_pad_tail_is_zero_in_every_row_the_aggregate_sees(monkeypatch, bundle, batches, attack):
-    monkeypatch.setattr(pallas_kernels, "aligned_width", _round_to_128)
+@pytest.mark.parametrize("folded", [False, True], ids=["flat", "folded"])
+def test_pad_tail_is_zero_in_every_row_the_aggregate_sees(
+        monkeypatch, bundle, tiles_bundle, batches, attack, folded):
+    """The real columns are the first d and the tail is zero, in the
+    computed rows and in the attack's: flat rows in ``ravel_pytree``'s
+    order, and folded rows in the layout's."""
+    round_up = _round_to_1024 if folded else _round_to_128
+    bundle = tiles_bundle if folded else bundle
+    monkeypatch.setattr(pallas_kernels, "aligned_width", round_up)
     d = tree_size(bundle.params)
     matrix = _matrix_the_aggregate_sees(bundle, ATTACKS[attack], batches)
     keys = batches[2]
-    assert matrix.shape == (N, _round_to_128(N, d)) and matrix.shape[1] > d
+    assert matrix.shape == (N, round_up(N, d)) and matrix.shape[1] > d
+    assert np.count_nonzero(matrix[:, d - 1]) == N  # the last real column is one
     assert np.count_nonzero(matrix[:, d:]) == 0
     assert np.count_nonzero(matrix[CFG.n_honest:, :d]) > 0  # the attack's rows are there
     if attack == "noise":  # and the attack itself did write into the tail
@@ -260,6 +373,52 @@ def test_the_matrix_is_built_by_the_ravel_alone(monkeypatch, bundle, batches, wi
     assert [name for name, _ in ref] == ["concatenate", "concatenate"]
 
 
+def _stack_writes(step, args, stack_shape):
+    """``[(update's shape, scope)]`` of the step's ``dynamic_update_slice``
+    equations whose result is the ``stack_shape`` stack."""
+
+    def walk(jaxpr, outer):
+        for eqn in jaxpr.eqns:
+            scope = outer + "/" + str(eqn.source_info.name_stack)
+            if (eqn.primitive.name == "dynamic_update_slice"
+                    and eqn.outvars[0].aval.shape == stack_shape):
+                yield eqn.invars[1].aval.shape, scope
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (list, tuple)) else [value]:
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):  # a loop's body names its scopes from here
+                        yield from walk(inner, scope)
+
+    return list(walk(jax.make_jaxpr(step)(*args).jaxpr, ""))
+
+
+def test_the_folded_matrix_is_built_leaf_by_leaf(monkeypatch, tiles_bundle, batches):
+    """Folded rows: nothing concatenates a whole row. Inside the loop in
+    ``round.fwdbwd`` each tile leaf's gradient is written into row i of
+    the stack by a ``dynamic_update_slice`` of its own, and one more
+    writes the other leaves, ravelled with the zero tail by the one small
+    ``concatenate``."""
+    monkeypatch.setattr(pallas_kernels, "aligned_width", _round_to_1024)
+    d = tree_size(tiles_bundle.params)
+    width = _round_to_1024(N, d)
+    layout = row_layout(tiles_bundle.params, width, folded=True)
+    assert layout.tile_leaves == TILE_LEAVES
+    step, opt_state = build_ps_train_step(
+        tiles_bundle, AGGREGATORS["trimmed_mean"], CFG, attack=ATTACKS["noise"])
+    xs, ys, keys = batches
+    args = (tiles_bundle.params, opt_state, xs[0], ys[0], keys[0])
+    for shape in [(width,), (d,), (CFG.n_honest, width), (N, width)]:
+        assert _matrix_rebuilds(step, args, shape) == []
+    rest = width - layout.offsets[-1]
+    assert [name for name, _ in _matrix_rebuilds(step, args, (rest,))] == ["concatenate"]
+    writes = _stack_writes(step, args, (N, width // 128, 128))
+    in_loop = [shape for shape, scope in writes if "round.fwdbwd" in scope]
+    sizes = [int(np.prod(TILES_SHAPES[k])) for k in ("w1", "w2", "w3")]
+    assert in_loop == [(1, size // 128, 128) for size in sizes] + [(1, rest // 128, 128)]
+    assert len(in_loop) == layout.tile_leaves + 1
+    assert len(writes) == len(in_loop)  # (the byzantine rows are set by a scatter)
+
+
 def test_sharded_update_on_one_device_carries_its_state_at_the_same_width(
         monkeypatch, bundle, batches):
     """One padded width serves the matrix and the sharded update's flat
@@ -283,6 +442,71 @@ def test_sharded_update_on_one_device_carries_its_state_at_the_same_width(
     for leaf in jax.tree_util.tree_leaves(inner):
         if leaf.shape == flat.shape:
             assert np.count_nonzero(np.asarray(leaf)[d:]) == 0
+
+
+def test_folded_sharded_update_carries_its_flat_state_in_the_layouts_order(
+        monkeypatch, tiles_bundle, batches):
+    """Folded rows and the sharded update on one device: the carried flat
+    parameters and momentum are ``d_pad`` wide in the layout's order (the
+    aggregate's own, so the update reads it as it comes), their tail zero,
+    and the parameters returned are those of the replicated update."""
+    monkeypatch.setattr(pallas_kernels, "aligned_width", _round_to_1024)
+    kwargs = dict(attack=_sign_flip, donate=False)
+    tree_step, tree_opt = jit_ps_train_step(
+        tiles_bundle, AGGREGATORS["trimmed_mean"], CFG, **kwargs)
+    want, want_norms = _drive(tree_step, tiles_bundle.params, tree_opt, batches)
+    step, opt_state = jit_ps_train_step(
+        tiles_bundle, AGGREGATORS["trimmed_mean"], CFG, sharded_update="on", **kwargs)
+    d = tree_size(tiles_bundle.params)
+    width = _round_to_1024(N, d)
+    layout = row_layout(tiles_bundle.params, width, folded=True)
+    np.testing.assert_array_equal(opt_state[0], layout.ravel(tiles_bundle.params))
+    last = []
+    got, got_norms = _drive(step, tiles_bundle.params, opt_state, batches, state=last)
+    # the flat update is compiled for another length: the last bit may differ
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(got_norms, want_norms, rtol=1e-5)
+    flat, inner = last[0]
+    assert flat.shape == (width,) and np.count_nonzero(np.asarray(flat)[d:]) == 0
+    carried = layout.unravel(flat[:d])
+    np.testing.assert_allclose(
+        np.concatenate([np.asarray(v).ravel() for v in jax.tree_util.tree_leaves(carried)]),
+        got, rtol=1e-6, atol=1e-7)
+
+
+def _ravel_pytree_layout(example, width, *, folded):
+    """``row_layout`` as the round had it before: ``ravel_pytree`` and
+    nothing placed on its own."""
+    ravel, unravel = ravel_pytree_fn(example)
+    d = tree_size(example)
+    assert width == d
+    return RowLayout(width=width, d=d, dtype=ravel(example).dtype, offsets=(0,),
+                     place=lambda tree, cast=None: [ravel(tree) if cast is None
+                                                    else ravel(tree).astype(cast)],
+                     ravel=lambda tree, cast=None: (ravel(tree) if cast is None
+                                                    else ravel(tree).astype(cast)),
+                     unravel=unravel)
+
+
+@pytest.mark.parametrize("kwargs", [{}, {"sharded_update": "off"}, {"grad_dtype": jnp.bfloat16}],
+                         ids=["sharded_update", "replicated_update", "grad_bf16"])
+def test_the_mesh_step_is_the_ravel_pytree_round(monkeypatch, tiles_bundle, batches, kwargs):
+    """On a mesh rows are ``d`` wide and not folded: the layout places no
+    leaf on its own, and the step's jaxpr is, equation for equation, the
+    one that ``ravel_pytree`` and its inverse give."""
+    mesh = node_mesh(8)
+    xs, ys, keys = batches
+
+    def jaxpr_text():
+        step, opt_state = build_ps_train_step(
+            tiles_bundle, AGGREGATORS["trimmed_mean"], CFG, attack=_sign_flip, mesh=mesh,
+            **kwargs)
+        return str(jax.make_jaxpr(step)(tiles_bundle.params, opt_state, xs[0], ys[0], keys[0]))
+
+    with_layout = jaxpr_text()
+    monkeypatch.setattr(ps_module, "row_layout", _ravel_pytree_layout)
+    assert with_layout == jaxpr_text()
+    assert "concatenate" in with_layout and "dynamic_update_slice" not in with_layout
 
 
 # -- (iii) the width the dispatch layer publishes ------------------------------
@@ -368,21 +592,29 @@ FOLDED_ROUNDS = {
 
 @pytest.mark.parametrize("attack", ["sign_flip", "noise", "empire"])
 @pytest.mark.parametrize("name", sorted(FOLDED_ROUNDS))
+@pytest.mark.parametrize("leaves", ["ragged", "tiles"])
 def test_folded_round_with_forced_kernels_equals_the_concatenating_round(
-        monkeypatch, bundle, batches, name, attack):
-    """Kernels forced (interpreted here), so the rows are 16384 wide and
-    stacked as (n, 128, 128): the loop's n-row stack, the byzantine rows
-    written into it and the folded kernel give the parameters of the round
-    that concatenates (n, d) rows, bit for bit where the aggregate is
-    coordinate-wise."""
+        monkeypatch, bundle, tiles_bundle, batches, leaves, name, attack):
+    """Kernels forced (interpreted here), so the rows are 16384 (32768)
+    wide and stacked as (n, 128, 128): the loop's n-row stack, the
+    byzantine rows written into it and the folded kernel give the
+    parameters of the round that concatenates (n, d) rows in the layout's
+    order, bit for bit where the aggregate is coordinate-wise. ``ragged``:
+    no leaf is whole tiles and the order is ``ravel_pytree``'s; ``tiles``:
+    three leaves are placed on their own."""
     monkeypatch.setenv("BYZPY_TPU_PALLAS", "1")
     agg, pre, _ = FOLDED_ROUNDS[name]
+    bundle = tiles_bundle if leaves == "tiles" else bundle
     d = tree_size(bundle.params)
-    assert pallas_kernels.aligned_width(N, d) == 16384
+    assert pallas_kernels.aligned_width(N, d) == (32768 if leaves == "tiles" else 16384)
     step, opt_state = jit_ps_train_step(
         bundle, AGGREGATORS[agg], CFG, attack=ATTACKS[attack], pre_aggregate=pre, donate=False)
     reference = AGGREGATORS[agg] if pre is None else (lambda x: AGGREGATORS[agg](pre(x)))
-    ref_step, ref_opt = _concatenating_step(bundle, reference, ATTACKS[attack])
+    order = _layout_order(bundle)
+    if leaves == "ragged":
+        flat, _ = ravel_pytree_fn(bundle.params)
+        np.testing.assert_array_equal(order[0](bundle.params), flat(bundle.params))
+    ref_step, ref_opt = _concatenating_step(bundle, reference, ATTACKS[attack], order=order)
     got, got_norms = _drive(step, bundle.params, opt_state, batches)
     want, want_norms = _drive(ref_step, bundle.params, ref_opt, batches)
     if name in ("trimmed_mean", "median"):
@@ -393,20 +625,20 @@ def test_folded_round_with_forced_kernels_equals_the_concatenating_round(
         np.testing.assert_allclose(got_norms, want_norms, rtol=1e-5)
 
 
-def test_folded_stack_is_what_the_aggregate_is_handed(monkeypatch, bundle, batches):
+@pytest.mark.parametrize("leaves", ["ragged", "tiles"])
+def test_folded_stack_is_what_the_aggregate_is_handed(
+        monkeypatch, bundle, tiles_bundle, batches, leaves):
     """Eagerly, kernels forced: the (n, d_pad) matrix the aggregate sees is
-    the concatenated one with an exactly-zero tail, all n rows written."""
+    the concatenated one (columns in the layout's order) with an
+    exactly-zero tail, all n rows written."""
     monkeypatch.setenv("BYZPY_TPU_PALLAS", "1")
-    xs, ys, keys = batches
+    bundle = tiles_bundle if leaves == "tiles" else bundle
     d = tree_size(bundle.params)
     matrix = _matrix_the_aggregate_sees(bundle, ATTACKS["noise"], batches)
-    assert matrix.shape == (N, 16384)
-    ravel, _ = ravel_pytree_fn(bundle.params)
-    grads = jax.vmap(lambda x, y: ravel(jax.grad(bundle.loss_fn)(bundle.params, x, y)))(
-        xs[0], ys[0])
-    honest = grads[: CFG.n_honest]
-    byz = jnp.broadcast_to(_noise(honest, keys[0]), (B, d))
-    np.testing.assert_array_equal(matrix[:, :d], np.asarray(jnp.concatenate([honest, byz])))
+    assert matrix.shape == (N, 32768 if leaves == "tiles" else 16384)
+    ravel, _ = _layout_order(bundle)
+    want = _concatenated_matrix(bundle, ATTACKS["noise"], batches, ravel, matrix.shape[1])
+    np.testing.assert_array_equal(matrix, want)
     assert np.count_nonzero(matrix[:, d:]) == 0
 
 
@@ -521,6 +753,100 @@ def test_on_the_tpu_the_sort_kernels_operand_is_the_loops_stack(tpu_texts, agg):
     assert set(path) <= {"fusion", "bitcast", "get-tuple-element"} and "fusion" in path
     # and the result is the flat aggregate: no relayout between kernel and update
     assert " copy(" not in "".join(v[2] for v in entry.values() if "round.update" in v[2])
+
+
+# -- (v) the layout of a row ---------------------------------------------------
+
+LAYOUT_TREES = {
+    # name: (shapes in tree order, tile leaves when folded)
+    "tile_leaves": ({"a": (3, 3, 16, 256), "b": (8, 128), "c": (2, 512)}, 3),
+    "ragged_leaves": ({"k": (3, 3, 3, 64), "s": (64,), "w": (512, 10), "z": (7,)}, 1),
+    "mixed": (TILES_SHAPES, TILE_LEAVES),
+    "no_leaf": ({}, 0),
+    "only_ragged": ({"s": (64,), "t": (5, 5), "u": (1000,)}, 0),
+    # whole tiles, but no tile of its own to keep: rows not a multiple of 8
+    "odd_rows": ({"a": (4, 256), "b": (12, 256)}, 2),
+}
+
+
+def _random_tree(shapes, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), max(len(shapes), 1))
+    return {name: jax.random.normal(k, shape, jnp.float32)
+            for k, (name, shape) in zip(keys, shapes.items())}
+
+
+@pytest.mark.parametrize("cast", [None, jnp.bfloat16], ids=["f32", "grad_bf16"])
+@pytest.mark.parametrize("folded", [False, True], ids=["flat", "folded"])
+@pytest.mark.parametrize("name", sorted(LAYOUT_TREES))
+def test_the_layouts_two_maps_are_inverse(name, folded, cast):
+    shapes, tile_leaves = LAYOUT_TREES[name]
+    tree = _random_tree(shapes)
+    d = tree_size(tree)
+    width = d + 24
+    layout = row_layout(tree, width, folded=folded)
+    assert (layout.d, layout.width) == (d, width)
+    assert layout.tile_leaves == (tile_leaves if folded else 0)
+    row = layout.ravel(tree, cast)
+    assert row.shape == (width,) and row.dtype == (cast or jnp.float32)
+    assert np.count_nonzero(np.asarray(row[d:], np.float32)) == 0
+    pieces = layout.place(tree, cast)
+    assert len(pieces) == len(layout.offsets) == layout.tile_leaves + 1
+    at = 0
+    for first, piece in zip(layout.offsets, pieces):
+        assert first == at and first % 1024 == 0
+        at += piece.shape[0]
+    assert at == width
+    # a permutation of the leaves' values: nothing dropped, nothing doubled
+    flat = np.concatenate([np.asarray(v, np.float32).ravel() for v in
+                           jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+                               lambda v: v.astype(cast or v.dtype), tree))] + [np.zeros(0)])
+    np.testing.assert_array_equal(np.sort(np.asarray(row[:d], np.float32)), np.sort(flat))
+    back = layout.unravel(row[:d])
+    assert jax.tree_util.tree_structure(back) == jax.tree_util.tree_structure(tree)
+    for got, want in zip(jax.tree_util.tree_leaves(back), jax.tree_util.tree_leaves(tree)):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want.astype(cast or want.dtype).astype(want.dtype))
+    if not layout.tile_leaves:  # ravel_pytree's order, with the tail
+        ravel, _ = ravel_pytree_fn(tree)
+        np.testing.assert_array_equal(np.asarray(row[:d], np.float32),
+                                      np.asarray(ravel(tree).astype(cast or jnp.float32),
+                                                 np.float32))
+
+
+def test_a_wide_leaf_is_laid_down_tile_after_tile():
+    """A tile leaf (..., C) with C a multiple of 128 above 128 and whole
+    tiles high keeps the order of its (8, 128) tiles: 1024 consecutive
+    columns of the row are 8 rows x 128 columns of the leaf."""
+    leaf = jnp.arange(16 * 384, dtype=jnp.float32).reshape(2, 8, 384)
+    layout = row_layout({"w": leaf}, leaf.size, folded=True)
+    row = np.asarray(layout.ravel({"w": leaf}))
+    as_matrix = np.asarray(leaf).reshape(16, 384)
+    for t, (r, c) in enumerate((r, c) for r in range(2) for c in range(3)):
+        np.testing.assert_array_equal(row[1024 * t: 1024 * (t + 1)].reshape(8, 128),
+                                      as_matrix[8 * r: 8 * r + 8, 128 * c: 128 * c + 128])
+    # a leaf one tile wide, or not whole tiles high, is row-major
+    for shape in [(16, 128), (4, 256), (1024,)]:
+        leaf = jnp.arange(int(np.prod(shape)), dtype=jnp.float32).reshape(shape)
+        row = row_layout({"w": leaf}, leaf.size, folded=True).ravel({"w": leaf})
+        np.testing.assert_array_equal(row, leaf.reshape(-1))
+
+
+def test_the_layout_of_resnet18_places_all_but_a_thousandth():
+    """The benchmark's model, shapes only: 20 of its 62 leaves are whole
+    tiles and hold 99.9 % of the parameters; the row is 11,190,272 wide."""
+    from byzpy_tpu.models.nets import cifar_resnet18
+
+    shapes = jax.eval_shape(lambda: cifar_resnet18(0).params)
+    layout = row_layout(shapes, 11_190_272, folded=True)
+    assert layout.d == RESNET18_D and len(jax.tree_util.tree_leaves(shapes)) == 62
+    assert layout.tile_leaves == 20 and layout.offsets[-1] == 11_162_624
+    assert round(layout.placed_share, 5) == 0.99899
+    assert row_layout(shapes, RESNET18_D, folded=False).placed_share == 0.0
+
+
+def test_a_row_too_narrow_for_the_tree_is_refused():
+    with pytest.raises(ValueError, match="cannot hold"):
+        row_layout({"w": jnp.zeros((4, 4))}, 15, folded=False)
 
 
 # -- every shipped aggregator and pre-aggregator maps zero columns to zero ----
