@@ -8,8 +8,8 @@ needs "the model" takes one of these.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field, replace
-from typing import Any, Callable, Optional
+from dataclasses import dataclass, replace
+from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,13 +26,63 @@ def softmax_cross_entropy_loss(apply_fn: Callable) -> Callable:
     return loss_fn
 
 
+@dataclass(frozen=True)
+class Segment:
+    """One link of a model that is a chain: ``params[key]`` is its
+    parameter subtree and ``apply`` its function. Every segment but the
+    last maps ``(subtree, h) -> h`` (the first is handed the worker's
+    batch ``x``); the last is the loss head, ``(subtree, h, y) -> loss``.
+    With ``aux`` a segment that is not the head returns ``(h, aux)``,
+    ``aux`` a tree of small arrays the round reports per honest worker
+    (an expert layer's token counts) and takes no gradient through."""
+
+    key: str
+    apply: Callable
+    aux: bool = False
+
+
+def chain_loss(segments: Sequence[Segment]) -> Callable:
+    """``loss_fn(params, x, y)`` of the whole chain."""
+
+    def loss_fn(params: Any, x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
+        h = x
+        for seg in segments[:-1]:
+            h = seg.apply(params[seg.key], h)
+            if seg.aux:
+                h = h[0]
+        return segments[-1].apply(params[segments[-1].key], h, y)
+
+    return loss_fn
+
+
 @dataclass
 class ModelBundle:
+    """``segments``, where a bundle declares them, say that the model is
+    a chain whose links own disjoint subtrees of ``params`` (a dict keyed
+    by ``Segment.key``) and that ``loss_fn`` is
+    :func:`chain_loss` of them. A round may then take gradients, aggregate
+    and update one segment at a time
+    (:func:`~byzpy_tpu.parallel.ps.build_ps_train_step`); everything else
+    uses ``loss_fn`` and never looks."""
+
     apply_fn: Callable[[Any, jnp.ndarray], jnp.ndarray]
     params: Any
     loss_fn: Optional[Callable[[Any, jnp.ndarray, jnp.ndarray], jnp.ndarray]] = None
+    segments: Optional[Tuple[Segment, ...]] = None
 
     def __post_init__(self) -> None:
+        if self.segments is not None:
+            self.segments = tuple(self.segments)
+            keys = [seg.key for seg in self.segments]
+            if len(keys) < 2 or len(set(keys)) != len(keys) or set(self.params) != set(keys):
+                raise ValueError(
+                    "a segmented bundle's params are a dict keyed by its segments' "
+                    f"keys (segments {keys}, params {list(self.params)})"
+                )
+            if self.segments[-1].aux:
+                raise ValueError("the loss head returns the loss alone")
+            if self.loss_fn is None:
+                self.loss_fn = chain_loss(self.segments)
         if self.loss_fn is None:
             self.loss_fn = softmax_cross_entropy_loss(self.apply_fn)
 
@@ -46,4 +96,4 @@ class ModelBundle:
         return replace(self, params=params)
 
 
-__all__ = ["ModelBundle", "softmax_cross_entropy_loss"]
+__all__ = ["ModelBundle", "Segment", "chain_loss", "softmax_cross_entropy_loss"]

@@ -132,14 +132,24 @@ SPAN_PREFIXES: Tuple[str, ...] = ("chaos.",)
 #: every ``jax.named_scope`` label inside a jitted step. A scope rides
 #: each HLO instruction's ``op_name`` in the compiled program's text (a
 #: TPU trace event carries none); ``round.*`` partitions the fused
-#: training step, ``serving.*`` names the serving steps' stages
+#: training step, ``serving.*`` names the serving steps' stages. In a
+#: round that streams segment by segment ``round.segment_*`` says which
+#: pass an op of the model belongs to (``round.fwdbwd`` stays the
+#: innermost ``round.*`` of them all) and ``model.*`` which mixer
 SCOPES: FrozenSet[str] = frozenset(
     {
+        "model.attention",
+        "model.moe_experts",
+        "model.moe_route",
+        "model.ssm_scan",
         "round.aggregate",
         "round.build_matrix",
         "round.fwdbwd",
         "round.param_gather",
         "round.pre_aggregate",
+        "round.segment_bwd",
+        "round.segment_fwd",
+        "round.segment_recompute",
         "round.transpose",
         "round.update",
         "serving.masked_aggregate",

@@ -1,0 +1,135 @@
+"""What works coordinate by coordinate: the ONE declared table.
+
+A round that aggregates segment by segment
+(:func:`~byzpy_tpu.parallel.ps.build_ps_train_step` with a bundle that
+declares segments) never holds the ``(n, d)`` gradient matrix: it hands
+the aggregate, and the attack before it, the ``(n, d_segment)`` columns
+of one segment at a time. That is exact — the segments' results
+concatenated ARE the result on ``(n, d)`` — for a function that treats
+every column alone, and wrong for anything that reads a whole row (a
+norm, an inner product, a Gram block, a selection of rows by score). So
+the round asks this table, and nothing else, which is which. It is a
+declaration and not a probe: a function that is not listed is refused,
+however it behaves. A Gram-type aggregate over a streamed round needs a
+second pass (its ``(n, n)`` statistics summed over the segments first;
+``ROADMAP.md``).
+
+The optimizer meets the same question: the update of one segment's
+leaves may read nothing of another's. Nothing here looks inside an
+optimizer to find out: the round's own default (SGD with momentum) is
+leaf by leaf, and a caller who passes another says so with
+:func:`leafwise`; one that is not so marked is refused (a global-norm
+clip reads every leaf, and must not be marked).
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass, field
+from functools import partial
+from typing import Any, Callable, Dict, FrozenSet, Mapping, NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from . import attack_ops, robust
+
+Array = jnp.ndarray
+
+
+def mean(x: Array) -> Array:
+    """The plain average of the rows: the aggregate of a round that
+    trusts every worker."""
+    return jnp.mean(x, axis=0)
+
+
+#: aggregates ``(n, d) -> (d,)`` whose column j reads column j alone
+AGGREGATES: FrozenSet[Callable] = frozenset(
+    {mean, robust.trimmed_mean, robust.coordinate_median, robust.mean_of_medians}
+)
+
+#: attacks whose column j reads column j of the honest rows alone (a
+#: reduction over WORKERS is fine: it is the same for every column)
+ATTACKS: FrozenSet[Callable] = frozenset(
+    {attack_ops.sign_flip, attack_ops.empire, attack_ops.little, attack_ops.mimic}
+)
+
+
+class Leafwise(NamedTuple):
+    """An optimizer (``init``, ``update`` as optax's) whose caller declares
+    that its update of a leaf reads that leaf's gradient, state and
+    parameter alone: SGD, momentum, Adam, AdamW are; a global-norm clip,
+    LAMB or anything else that reduces over the whole tree is not."""
+
+    init: Callable
+    update: Callable
+
+
+def leafwise(optimizer: Any) -> Leafwise:
+    """Mark ``optimizer`` as one that updates leaf by leaf. The caller's
+    word: nothing checks it."""
+    return Leafwise(optimizer.init, optimizer.update)
+
+
+@dataclass(frozen=True)
+class RoundAttack:
+    """A round's attack ``(honest (h, width), key) -> rows`` made of one
+    of the table's functions: ``fn`` is handed the honest rows, or with
+    ``of="honest_mean"`` their mean over the workers (what an omniscient
+    sign flip negates). The table sees through it to ``fn``."""
+
+    fn: Callable
+    of: str = "honest"
+    kwargs: Mapping[str, Any] = field(default_factory=dict)
+
+    def __post_init__(self) -> None:
+        if self.of not in ("honest", "honest_mean"):
+            raise ValueError(f"of must be 'honest' or 'honest_mean', got {self.of!r}")
+
+    def __call__(self, honest: Array, key: jax.Array) -> Array:
+        given = jnp.mean(honest, axis=0) if self.of == "honest_mean" else honest
+        return self.fn(given, **dict(self.kwargs))
+
+
+def _listed(fn: Callable) -> Callable:
+    """The function under ``functools.partial`` and :class:`RoundAttack`."""
+    while True:
+        if isinstance(fn, partial):
+            fn = fn.func
+        elif isinstance(fn, RoundAttack):
+            fn = fn.fn
+        else:
+            return fn
+
+
+def is_coordinatewise_aggregate(fn: Callable) -> bool:
+    return _listed(fn) in AGGREGATES
+
+
+def is_coordinatewise_attack(fn: Callable) -> bool:
+    return _listed(fn) in ATTACKS
+
+
+def refusal(aggregate: Callable, attack: Any, optimizer: Any) -> Dict[str, str]:
+    """What of a segmented round's three functions this table does not
+    list, by role: empty where the round may stream."""
+    out: Dict[str, str] = {}
+    if not is_coordinatewise_aggregate(aggregate):
+        out["aggregate"] = repr(_listed(aggregate))
+    if attack is not None and not is_coordinatewise_attack(attack):
+        out["attack"] = repr(_listed(attack))
+    if optimizer is not None and not isinstance(optimizer, Leafwise):
+        out["optimizer"] = "not marked with coordinatewise.leafwise(...)"
+    return out
+
+
+__all__ = [
+    "AGGREGATES",
+    "ATTACKS",
+    "Leafwise",
+    "RoundAttack",
+    "is_coordinatewise_aggregate",
+    "is_coordinatewise_attack",
+    "leafwise",
+    "mean",
+    "refusal",
+]

@@ -24,6 +24,7 @@ Design notes (TPU-shaped):
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import flax.linen as nn
@@ -112,6 +113,154 @@ def moe_ffn(
     return jnp.einsum("ecd,tec->td", out_blocks, combine)
 
 
+def _expert(h_in: Array, up: Array, down: Array) -> Array:
+    """``down relu(up h_in)^2``."""
+    hidden = jnp.square(jax.nn.relu(h_in @ up.astype(h_in.dtype)))
+    return hidden @ down.astype(h_in.dtype)
+
+
+def _expert_round(x, w_up, w_down, gate, rank, routed, r, rows):
+    """Round ``r`` of the held experts' part: every expert multiplies the
+    ``rows`` of its tokens whose rank among them is in ``[r rows, (r + 1)
+    rows)``. Returns the round's share of ``out (T, D)`` and how many
+    (token, expert) pairs it computed."""
+    tokens, d = x.shape
+    held = w_up.shape[0]
+    local = rank - r * rows
+    mine = routed & (local >= 0) & (local < rows)  # (T, held): this round's pairs
+    slot = jnp.where(mine, jnp.arange(held)[None, :] * rows + local, held * rows)
+    token_at = jnp.zeros((held * rows,), jnp.int32).at[slot.reshape(-1)].set(
+        jnp.repeat(jnp.arange(tokens, dtype=jnp.int32), held), mode="drop")
+    per_expert = jax.vmap(_expert)(
+        x[token_at].reshape(held, rows, d), w_up, w_down).reshape(held * rows, d)
+    # back to the tokens: each reads its slots (a slot past the end reads
+    # zero; a slot no token fills is read by none)
+    read = jnp.take(per_expert, slot, axis=0, mode="fill", fill_value=0)  # (T, held, D)
+    return (jnp.einsum("te,ted->td", gate.astype(x.dtype), read),
+            jnp.sum(mine, dtype=jnp.int32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _expert_rounds(x, w_up, w_down, gate, rank, routed, rows):
+    """The held experts' part of every routed token: round 0, then as many
+    more as the fullest expert needs. The count of rounds is read from the
+    batch, so the loop is a ``while`` with a backward pass of its own (the
+    same rounds again, each one's vector-Jacobian product added up)."""
+    return _expert_rounds_fwd(x, w_up, w_down, gate, rank, routed, rows)[0]
+
+
+def _rounds_needed(routed, rows):
+    fullest = jnp.max(jnp.sum(routed, axis=0, dtype=jnp.int32))
+    return jnp.maximum(1, -(-fullest // rows))
+
+
+def _expert_rounds_fwd(x, w_up, w_down, gate, rank, routed, rows):
+    rounds = _rounds_needed(routed, rows)
+
+    def more(r, acc):
+        out, computed = _expert_round(x, w_up, w_down, gate, rank, routed, r, rows)
+        return acc[0] + out, acc[1] + computed
+
+    out, computed = lax.fori_loop(
+        1, rounds, more, _expert_round(x, w_up, w_down, gate, rank, routed, 0, rows))
+    return (out, computed, rounds), (x, w_up, w_down, gate, rank, routed)
+
+
+def _expert_rounds_bwd(rows, kept, cotangents):
+    x, w_up, w_down, gate, rank, routed = kept
+    g = cotangents[0]  # the pairs computed and the rounds are counts
+
+    def pulled(r):
+        return jax.vjp(
+            lambda *wrt: _expert_round(*wrt, rank, routed, r, rows)[0],
+            x, w_up, w_down, gate)[1](g)
+
+    def more(r, acc):
+        return jax.tree_util.tree_map(jnp.add, acc, pulled(r))
+
+    return (*lax.fori_loop(1, _rounds_needed(routed, rows), more, pulled(0)), None, None)
+
+
+_expert_rounds.defvjp(_expert_rounds_fwd, _expert_rounds_bwd)
+
+
+def held_experts_ffn(
+    x: Array,
+    router_w: Array,
+    w_up: Array,
+    w_down: Array,
+    shared_up: Optional[Array] = None,
+    shared_down: Optional[Array] = None,
+    *,
+    first_held: int,
+    n_experts: int,
+    top_k: int,
+    scale: float = 1.0,
+    round_rows: Optional[int] = None,
+):
+    """One chip's part of an expert layer whose experts are spread over
+    chips: it is told which experts it holds, routes over all of them,
+    and computes its own experts' part for the tokens routed to them.
+
+    ``x (T, D)`` tokens; ``router_w (D, n_experts)`` at its full width;
+    ``w_up (held, D, F)``, ``w_down (held, F, D)`` the experts
+    ``first_held .. first_held + held - 1``. Routing (DeepSeek-V3 /
+    Nemotron-H style): ``s = sigmoid(x router_w)``, the ``top_k`` largest
+    a token, their ``s`` normalised to sum 1 and times ``scale``. Expert:
+    ``w_down relu(w_up x)^2``. A token's routed part is the weighted sum
+    over those of its ``top_k`` that are held here; what the absent
+    experts would add is another chip's part. The shared expert
+    (``shared_up (D, Fs)``, ``shared_down (Fs, D)``), where given, is
+    added for every token.
+
+    NO TOKEN IS DROPPED, by construction: the held experts work in rounds
+    of ``round_rows`` gathered tokens each (one batched product over the
+    experts a round), and there are as many rounds as the fullest expert
+    of the batch at hand needs. ``round_rows`` is a size and not a limit:
+    any value gives the same result; a small one spends more rounds on a
+    popular expert, a large one multiplies more empty rows. Default: a
+    quarter of the tokens (in whole sublanes of 8), so a second round
+    runs only where one held expert draws more than a quarter of the batch.
+
+    Returns ``(out (T, D), aux)``: ``aux["held_expert_tokens"]`` the
+    ``(held,)`` tokens each held expert got, ``aux["tokens_dropped"]``
+    the routed (token, held expert) pairs that no round computed (0),
+    ``aux["expert_rounds"]`` the rounds that ran.
+    """
+    tokens = x.shape[0]
+    held = w_up.shape[0]
+    rows = round_rows or -(-max(tokens // 4, 1) // 8) * 8
+    with jax.named_scope("model.moe_route"):
+        # in float32 at full precision whatever the activations' type: a
+        # rounding that swaps a token's sixth and seventh expert is a
+        # different result, not a small error (and the matrix is small)
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router_w.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        top_s, top_e = lax.top_k(scores, top_k)  # (T, k)
+        weight = top_s / jnp.sum(top_s, axis=-1, keepdims=True) * scale
+        local = top_e - first_held
+        here = (local >= 0) & (local < held)  # (T, k): this pick's expert is held
+        # (T, held): the weight of each held expert for each token (an
+        # expert is picked at most once a token)
+        onehot = (local[:, :, None] == jnp.arange(held)[None, None, :]) & here[:, :, None]
+        gate = jnp.sum(jnp.where(onehot, weight[:, :, None], 0.0), axis=1)
+        routed = jnp.any(onehot, axis=1)  # (T, held)
+        counts = jnp.sum(routed, axis=0, dtype=jnp.int32)  # (held,)
+        # a token's place among its expert's tokens
+        rank = jnp.cumsum(routed, axis=0, dtype=jnp.int32) - 1
+    with jax.named_scope("model.moe_experts"):
+        out, computed, rounds = _expert_rounds(x, w_up, w_down, gate, rank, routed, rows)
+        if shared_up is not None:
+            out = out + _expert(x, shared_up, shared_down)
+    aux = {
+        "held_expert_tokens": counts,
+        "tokens_dropped": jnp.sum(counts) - computed,
+        "expert_rounds": rounds,
+    }
+    return out, aux
+
+
 class MoEFFN(nn.Module):
     """Flax MoE FFN block (top-1 routing, GShard-style static capacity).
 
@@ -164,4 +313,4 @@ class MoEFFN(nn.Module):
         )
 
 
-__all__ = ["top1_dispatch", "moe_ffn", "MoEFFN"]
+__all__ = ["top1_dispatch", "moe_ffn", "held_experts_ffn", "MoEFFN"]

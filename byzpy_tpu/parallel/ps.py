@@ -161,6 +161,209 @@ def as_sharded_update(
     raise TypeError(f"cannot interpret {value!r} as a ShardedUpdateConfig")
 
 
+def _byzantine_rows(attack, honest, key, b: int, d: int):
+    """The ``b`` byzantine workers' rows from the honest ``(h, width)``
+    rows, as ``(1, width)`` where the attack gives every one of them the
+    same row (its broadcast then fuses into the write that follows) and
+    ``(b, width)`` elsewhere; the columns past ``d`` exactly zero."""
+    h, width = honest.shape
+    if attack is not None:
+        byz = jnp.asarray(attack(honest, key))
+    else:
+        # no attack configured: byzantine nodes echo honest
+        # gradients (cycled, so any b < n works)
+        byz = jnp.tile(honest, ((b + h - 1) // h, 1))[:b]
+    rows_given = 1 if byz.ndim == 1 or byz.shape[0] == 1 else b
+    byz = jnp.broadcast_to(byz, (rows_given, width)).astype(honest.dtype)
+    if width != d:
+        # an attack need not map zero columns to zero (additive
+        # noise): the pad tail of its rows is forced back to zero
+        byz = jnp.where(jnp.arange(width) < d, byz, 0)
+    return byz
+
+
+def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtype, unstreamable):
+    """:func:`build_ps_train_step` for a bundle that declares segments, on
+    one device: the round whose working set is not ``(n, d)``.
+
+    Forward for the h honest workers, one after another, keeping each
+    segment's output (a block-boundary activation) for every one of them.
+    Then from the loss head back to the first segment, for each segment:
+    every honest worker's vector-Jacobian product of that segment, its
+    forward recomputed from the boundary kept; the h gradients placed,
+    folded, in the segment's ``(n, width_segment)`` stack; the byzantine
+    rows written into it; the aggregate; the optimizer's update of that
+    segment's leaves. The next segment starts only when this one's
+    parameters are updated, so its n rows are dead by then: at most one
+    segment's rows exist at a time, and no ``(n, d)`` array ever does.
+
+    Exact where aggregate and attack treat every column alone and the
+    optimizer every leaf alone: ``ops/coordinatewise.py`` is the table of
+    those, and anything it does not list is refused here.
+    """
+    from ..ops import coordinatewise
+    from ..ops.pallas_kernels import aligned_width
+
+    refused = coordinatewise.refusal(aggregate, attack, optimizer)
+    refused.update({name: "given" for name, given in unstreamable.items() if given})
+    if refused:
+        raise ValueError(
+            "a bundle that declares segments streams its round segment by segment on one "
+            "device, which is exact only for what byzpy_tpu/ops/coordinatewise.py lists "
+            "(AGGREGATES, ATTACKS, an optimizer marked leafwise(...); no pre-aggregator, no sharded "
+            f"update); not listed there: {refused}. A Gram-type aggregate needs a second "
+            "pass over the segments (ROADMAP.md)."
+        )
+    opt = optimizer or default_optimizer(cfg)
+    segs = bundle.segments
+    n, h, b = cfg.n_nodes, cfg.n_honest, cfg.n_byzantine
+    last = len(segs) - 1
+    def put(stack, value, i):
+        return jax.lax.dynamic_update_index_in_dim(stack, value.reshape(stack.shape[1:]), i, 0)
+
+    def folded(shape):
+        """A boundary is kept as whole (8, 128) tiles in row-major order
+        wherever it can be, like a row: the pass that writes it and the pass
+        that reads it then agree on one layout, and no relaid copy of the
+        whole stack stands between them."""
+        size = math.prod(shape)
+        return (size // 128, 128) if size % 1024 == 0 else tuple(shape)
+
+    # each segment's own row: the width the stream kernels read in place,
+    # folded wherever it is whole tiles, its columns in row_layout's order
+    # (all as in the (n, d) round, a segment at a time)
+    layouts = []
+    for seg in segs:
+        sub = bundle.params[seg.key]
+        width = aligned_width(n, tree_size(sub))
+        layouts.append(row_layout(sub, width, folded=len(folded((width,))) == 2))
+    opt_state0 = {seg.key: opt.init(bundle.params[seg.key]) for seg in segs}
+
+    def train_step(params, opt_state, xs, ys, key):
+        xs_h, ys_h = xs[:h], ys[:h]
+
+        def forward_of(x):
+            outs, auxes = [], {}
+            for seg in segs[:last]:
+                x = seg.apply(params[seg.key], x)
+                if seg.aux:
+                    x, auxes[seg.key] = x
+                outs.append(x)
+            return outs, auxes
+
+        # round.fwdbwd is the innermost round.* scope of everything the
+        # model computes, here as in the (n, d) round; the segment_* scope
+        # around it says which of the three passes an op belongs to
+        with jax.named_scope("round.segment_fwd"), jax.named_scope("round.fwdbwd"):
+            kept = jax.eval_shape(forward_of, xs_h[0])
+
+            def one_forward(i, carry):
+                return jax.tree_util.tree_map(
+                    lambda stack, value: put(stack, value, i), carry, forward_of(xs_h[i]))
+
+            bounds, auxes = jax.lax.fori_loop(0, h, one_forward, (
+                [jax.lax.empty((h, *folded(leaf.shape)), leaf.dtype) for leaf in kept[0]],
+                jax.tree_util.tree_map(
+                    lambda leaf: jnp.zeros((h, *leaf.shape), leaf.dtype), kept[1])))
+
+        new_params, new_opt = {}, {}
+        sum_sq = jnp.zeros((), jnp.float32)
+        losses = cot = None
+        # A segment's leaves are handed to its loop through the barrier that
+        # closes the segment after it (the head's: through one with the last
+        # boundary). Whatever the compiler derives from them alone (a
+        # weight's transposed copy, hoisted out of the loop) then cannot be
+        # made before that point, and so not for all segments at once.
+        sub, bounds[last - 1] = jax.lax.optimization_barrier(
+            (params[segs[last].key], bounds[last - 1]))
+        for k in range(last, -1, -1):
+            seg, layout = segs[k], layouts[k]
+            width = layout.width
+            row_shape = folded((width,))
+            lanes = row_shape[1:]
+            lane = math.prod(lanes)
+            rows_dtype = grad_dtype if grad_dtype is not None else layout.dtype
+
+            def one_backward(i, carry, k=k, seg=seg, layout=layout, sub=sub, cot=cot,
+                             lanes=lanes, lane=lane):
+                # worker i's input to this segment is read from the stack of
+                # boundaries kept, and the cotangent of that input is written
+                # over it: after the loop the stack holds what the segment
+                # before this one pulls back, and nothing else was allocated
+                inputs = carry["io"] if k else xs_h
+                with jax.named_scope("round.segment_recompute"), jax.named_scope("round.fwdbwd"):
+                    if k == last:
+                        def apply(p, x):
+                            return seg.apply(p, x, ys_h[i])
+                    elif seg.aux:
+                        def apply(p, x):
+                            return seg.apply(p, x)[0]
+                    else:
+                        apply = seg.apply
+                    if k:
+                        out, pullback = jax.vjp(
+                            apply, sub, inputs[i].reshape(kept[0][k - 1].shape))
+                    else:  # the batch itself: nothing flows back into it
+                        out, pullback = jax.vjp(lambda p: apply(p, inputs[i]), sub)
+                with jax.named_scope("round.segment_bwd"), jax.named_scope("round.fwdbwd"):
+                    pulled = pullback(
+                        jnp.ones_like(out) if k == last else cot[i].reshape(out.shape))
+                    grads = carry["rows"]
+                    for first, piece in zip(layout.offsets, layout.place(pulled[0], grad_dtype)):
+                        grads = jax.lax.dynamic_update_slice(
+                            grads, jax.lax.expand_dims(piece.reshape(-1, *lanes), (0,)),
+                            (i, first // lane, *(0 for _ in lanes)))
+                    carry = dict(carry, rows=grads)
+                    if k:
+                        carry["io"] = put(inputs, pulled[1], i)
+                    if k == last:
+                        carry["losses"] = put(carry["losses"], out, i)
+                return carry
+
+            carry = {"rows": jax.lax.empty((n, *row_shape), rows_dtype)}
+            if k:
+                carry["io"] = bounds[k - 1]
+            if k == last:
+                loss0 = jax.eval_shape(
+                    lambda x, seg=seg, sub=sub: seg.apply(sub, x, ys_h[0]), kept[0][k - 1])
+                carry["losses"] = jnp.zeros((h,), loss0.dtype)
+            carry = jax.lax.fori_loop(0, h, one_backward, carry)
+            losses = carry.get("losses", losses)
+            with jax.named_scope("round.build_matrix"):
+                stack = carry["rows"]
+                if b:
+                    byz = _byzantine_rows(attack, stack[:h].reshape(h, width),
+                                          jax.random.fold_in(key, k), b, layout.d)
+                    stack = stack.at[h:].set(jnp.broadcast_to(
+                        byz.reshape(byz.shape[0], *row_shape), (b, *row_shape)))
+                matrix = stack.reshape(n, width)
+            with jax.named_scope("round.aggregate"):
+                agg = aggregate(matrix).astype(layout.dtype)
+            with jax.named_scope("round.update"):
+                # (the columns past d are exactly zero: they add nothing to
+                # the norm, and unravel reads the first d alone)
+                sum_sq = sum_sq + jnp.sum(jnp.square(agg)).astype(jnp.float32)
+                updates, state = opt.update(layout.unravel(agg), opt_state[seg.key], sub)
+                done = (optax.apply_updates(sub, updates), state)
+                if k:
+                    # the segment before this one starts from the cotangents
+                    # only once this one's leaves are updated: its rows are
+                    # dead by then, and the next rows take their place
+                    done, cot, next_sub = jax.lax.optimization_barrier(
+                        (done, carry["io"], params[segs[k - 1].key]))
+                new_params[seg.key], new_opt[seg.key] = done
+                sub = next_sub if k else None
+        with jax.named_scope("round.update"):
+            metrics = {"honest_loss": jnp.mean(losses), "agg_grad_norm": jnp.sqrt(sum_sq)}
+            if auxes:
+                metrics["segment_aux"] = auxes
+        order = list(params)
+        return ({key_: new_params[key_] for key_ in order},
+                {key_: new_opt[key_] for key_ in order}, metrics)
+
+    return train_step, opt_state0
+
+
 def build_ps_train_step(
     bundle: ModelBundle,
     aggregate: AggFn,
@@ -175,6 +378,19 @@ def build_ps_train_step(
     sharded_update: Any = None,
 ) -> Tuple[Callable, Any]:
     """Build ``(train_step, opt_state0)``.
+
+    Which program a call gets. (1) ``bundle.segments`` declared and no
+    mesh (one device): the STREAMED round
+    (:func:`_streamed_train_step`): gradients, attack, aggregate and
+    update one segment at a time, so no ``(n, d)`` array exists and a
+    parameter costs 8 bytes plus n rows of one segment; exact for what
+    ``ops/coordinatewise.py`` lists, and anything else (a Gram-type
+    aggregate, a ``pre_aggregate``, a global-norm clip, a forced sharded
+    update) raises a ``ValueError`` that names the table. Its
+    ``opt_state0`` is ``{segment: opt.init(subtree)}``. (2) Everything
+    else, a segmented bundle on a mesh included: the ``(n, d)`` round
+    described below, unchanged by (1)
+    (``docs/performance.md``, "A round that is not (n, d)").
 
     ``train_step(params, opt_state, xs, ys, key)`` expects per-node batches
     stacked on a leading node axis: ``xs: (n_nodes, B, ...)``,
@@ -298,6 +514,12 @@ def build_ps_train_step(
         from ..configs.mesh import get_default_mesh
 
         mesh = get_default_mesh()
+    if bundle.segments is not None and mesh is None:
+        # one device and a model that is a chain: segment by segment
+        return _streamed_train_step(
+            bundle, aggregate, cfg, attack=attack, optimizer=optimizer, grad_dtype=grad_dtype,
+            unstreamable={"pre_aggregate": pre_aggregate is not None, "sharded_update": su.mode == "on"},
+        )
     node_spec = None
     feat_spec = None
     if mesh is not None:
@@ -438,21 +660,8 @@ def build_ps_train_step(
             # the attack is the caller's (h, width) function; a reduction
             # over workers reads the folded rows all the same
             honest = honest.reshape(h, row_width)
-        if attack is not None:
-            byz = jnp.asarray(attack(honest, key))
-        else:
-            # no attack configured: byzantine nodes echo honest
-            # gradients (cycled, so any b < n works)
-            byz = jnp.tile(honest, ((b + h - 1) // h, 1))[:b]
-        width = honest.shape[1]
-        # one row for every byzantine worker stays one row: its
-        # broadcast then fuses into the write below
-        rows_given = 1 if byz.ndim == 1 or byz.shape[0] == 1 else b
-        byz = jnp.broadcast_to(byz, (rows_given, width)).astype(honest.dtype)
-        if width != d:
-            # an attack need not map zero columns to zero (additive
-            # noise): the pad tail of its rows is forced back to zero
-            byz = jnp.where(jnp.arange(width) < d, byz, 0)
+        byz = _byzantine_rows(attack, honest, key, b, d)
+        rows_given, width = byz.shape
         if mesh is None:
             # Row writes into the loop's own buffer: a folded row is whole
             # tiles, so b rows cost b rows' bytes and the other rows are

@@ -86,7 +86,7 @@ def test_catalog_is_well_formed():
     for prefix in catalog.METRIC_PREFIXES:
         assert prefix.startswith("byzpy_"), prefix
     for scope in catalog.SCOPES:
-        assert re.fullmatch(r"(round|serving)\.[a-z_]+", scope), scope
+        assert re.fullmatch(r"(round|serving|model)\.[a-z_]+", scope), scope
     for kernel in catalog.KERNELS:
         assert re.fullmatch(r"[a-z][a-z0-9_]+", kernel), kernel
     # one namespace: an in-jit scope never reuses a host span's label

@@ -1,0 +1,301 @@
+"""The round that aggregates segment by segment (``parallel/ps.py``,
+``_streamed_train_step``): equal to the ``(n, d)`` round on the same
+segmented toy bundle for everything ``ops/coordinatewise.py`` lists,
+refused for everything else, and absent from a bundle without segments
+(whose step lowers to the text it had before the streamed round came)."""
+
+from __future__ import annotations
+
+import hashlib
+import re
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from byzpy_tpu.models.bundle import ModelBundle, Segment, chain_loss
+from byzpy_tpu.ops import attack_ops, coordinatewise, robust
+from byzpy_tpu.parallel.ps import PSStepConfig, build_ps_train_step
+
+N, B, BATCH, WIDTH, CLASSES = 8, 2, 16, 24, 5
+
+
+def _segments():
+    def dense(p, x):
+        return jnp.tanh(x @ p["kernel"] + p["bias"])
+
+    def counted(p, x):
+        y = dense(p, x)
+        return y, {"positive": jnp.sum(y > 0)}
+
+    def head(p, x, y):
+        logits = x @ p["kernel"] + p["bias"]
+        return optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
+
+    return (Segment("s0_in", dense), Segment("s1_mid", counted, aux=True),
+            Segment("s2_mid", dense), Segment("s3_head", head))
+
+
+def _params(seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    sizes = [(12, WIDTH), (WIDTH, WIDTH), (WIDTH, 40), (40, CLASSES)]
+    names = ["s0_in", "s1_mid", "s2_mid", "s3_head"]
+    return {
+        name: {"kernel": jax.random.normal(k, size) / np.sqrt(size[0]),
+               "bias": jnp.full((size[1],), 0.01)}
+        for name, k, size in zip(names, keys, sizes)
+    }
+
+
+def _batches(seed=1, steps=2):
+    k = jax.random.PRNGKey(seed)
+    out = []
+    for s in range(steps):
+        kx, ky, k = jax.random.split(k, 3)
+        out.append((jax.random.normal(kx, (N, BATCH, 12)),
+                    jax.random.randint(ky, (N, BATCH), 0, CLASSES)))
+    return out
+
+
+def _bundles():
+    segs = _segments()
+    streamed = ModelBundle(apply_fn=None, params=_params(), segments=segs)
+    whole = ModelBundle(apply_fn=None, params=_params(), loss_fn=chain_loss(segs))
+    return streamed, whole
+
+
+SIGN_FLIP = coordinatewise.RoundAttack(attack_ops.sign_flip, of="honest_mean")
+EMPIRE = coordinatewise.RoundAttack(attack_ops.empire, kwargs={"scale": -1.5})
+
+AGGREGATES = {
+    "trimmed": partial(robust.trimmed_mean, f=2),
+    "median": robust.coordinate_median,
+    "meamed": partial(robust.mean_of_medians, f=2),
+    "mean": coordinatewise.mean,
+}
+ATTACKS = {"signflip": (B, SIGN_FLIP), "empire": (B, EMPIRE), "echo": (B, None), "none": (0, None)}
+
+
+@pytest.mark.parametrize("attack", sorted(ATTACKS))
+@pytest.mark.parametrize("agg", sorted(AGGREGATES))
+def test_streamed_round_equals_the_n_by_d_round(agg, attack):
+    b, attack_fn = ATTACKS[attack]
+    cfg = PSStepConfig(n_nodes=N, n_byzantine=b, learning_rate=0.1, momentum=0.9)
+    streamed, whole = _bundles()
+    results = []
+    for bundle in (streamed, whole):
+        step, opt = build_ps_train_step(bundle, AGGREGATES[agg], cfg, attack=attack_fn)
+        step = jax.jit(step)
+        params, seen = bundle.params, []
+        for i, (xs, ys) in enumerate(_batches()):
+            params, opt, metrics = step(params, opt, xs, ys, jax.random.PRNGKey(i))
+            seen.append(metrics)
+        results.append((params, opt, seen))
+    (p_s, o_s, m_s), (p_w, o_w, m_w) = results
+    for got, want in zip(jax.tree_util.tree_leaves(p_s), jax.tree_util.tree_leaves(p_w)):
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    # the momentum trace mirrors the parameter tree in both, leaf for leaf
+    for got, want in zip(jax.tree_util.tree_leaves(o_s), jax.tree_util.tree_leaves(o_w)):
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    for got, want in zip(m_s, m_w):
+        np.testing.assert_allclose(got["honest_loss"], want["honest_loss"], rtol=1e-6)
+        np.testing.assert_allclose(got["agg_grad_norm"], want["agg_grad_norm"], rtol=1e-5)
+        assert set(want) == {"honest_loss", "agg_grad_norm"}
+        assert got["segment_aux"]["s1_mid"]["positive"].shape == (N - b,)
+
+
+def test_streamed_round_with_an_optimizer_marked_leafwise_equals_the_n_by_d_round():
+    cfg = PSStepConfig(n_nodes=N, n_byzantine=B)
+    streamed, whole = _bundles()
+    adam = optax.adam(1e-2)
+    got, want = [], []
+    for bundle, optimizer, out in ((streamed, coordinatewise.leafwise(adam), got),
+                                   (whole, adam, want)):
+        step, opt = build_ps_train_step(bundle, AGGREGATES["trimmed"], cfg, attack=SIGN_FLIP,
+                                        optimizer=optimizer)
+        params = bundle.params
+        for i, (xs, ys) in enumerate(_batches()):
+            params, opt, _ = jax.jit(step)(params, opt, xs, ys, jax.random.PRNGKey(i))
+        out.extend(jax.tree_util.tree_leaves(params))
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(a, b_, rtol=2e-5, atol=2e-6)
+
+
+def test_streamed_step_holds_no_array_of_n_rows_wider_than_a_segment():
+    cfg = PSStepConfig(n_nodes=N, n_byzantine=B)
+    streamed, _ = _bundles()
+    step, opt = build_ps_train_step(streamed, AGGREGATES["trimmed"], cfg, attack=SIGN_FLIP)
+    xs, ys = _batches()[0]
+    text = jax.jit(step).lower(streamed.params, opt, xs, ys, jax.random.PRNGKey(0)).as_text()
+    widest = max(sum(leaf.size for leaf in jax.tree_util.tree_leaves(sub))
+                 for sub in streamed.params.values())
+    widths = [int(w) for w in re.findall(r"tensor<%dx(\d+)xf32>" % N, text)]
+    assert widths and max(widths) == widest
+
+
+@pytest.mark.parametrize("what,kwargs", [
+    ("aggregate", {"aggregate": partial(robust.multi_krum, f=2, q=4)}),
+    ("aggregate", {"aggregate": lambda x: jnp.mean(x, axis=0)}),
+    ("attack", {"attack": lambda honest, key: -jnp.mean(honest, axis=0)}),
+    ("optimizer", {"optimizer": optax.chain(optax.clip_by_global_norm(1.0), optax.sgd(0.1))}),
+    ("pre_aggregate", {"pre_aggregate": lambda m: m}),
+    ("sharded_update", {"sharded_update": "on"}),
+])
+def test_what_the_table_does_not_list_is_refused_by_name(what, kwargs):
+    cfg = PSStepConfig(n_nodes=N, n_byzantine=B)
+    streamed, _ = _bundles()
+    kwargs = {"aggregate": AGGREGATES["trimmed"], **kwargs}
+    aggregate = kwargs.pop("aggregate")
+    with pytest.raises(ValueError, match=r"ops/coordinatewise\.py") as err:
+        build_ps_train_step(streamed, aggregate, cfg, **kwargs)
+    assert what in str(err.value)
+
+
+@pytest.mark.parametrize("optimizer", [
+    optax.sgd(0.1, momentum=0.9), optax.adam(1e-3), optax.adamw(1e-3),
+    optax.chain(optax.clip_by_global_norm(1.0), optax.adam(1e-3)), optax.lamb(1e-3),
+])
+def test_an_optimizer_streams_only_where_its_caller_marks_it_leafwise(optimizer):
+    """Nothing looks inside an optimizer: unmarked it is refused whatever it
+    is made of, marked it is taken at the caller's word."""
+    assert "optimizer" in coordinatewise.refusal(AGGREGATES["trimmed"], None, optimizer)
+    assert not coordinatewise.refusal(
+        AGGREGATES["trimmed"], None, coordinatewise.leafwise(optimizer))
+
+
+def test_a_segmented_bundle_on_a_mesh_runs_the_n_by_d_program():
+    from byzpy_tpu.parallel.mesh import node_mesh
+
+    cfg = PSStepConfig(n_nodes=N, n_byzantine=B)
+    streamed, whole = _bundles()
+    mesh = node_mesh(4)
+    xs, ys = _batches()[0]
+    texts = []
+    for bundle in (streamed, whole):
+        step, opt = build_ps_train_step(bundle, AGGREGATES["trimmed"], cfg, attack=SIGN_FLIP,
+                                        mesh=mesh)
+        texts.append(_canonical(jax.jit(step).lower(
+            bundle.params, opt, xs, ys, jax.random.PRNGKey(0)).as_text()))
+    assert texts[0] == texts[1]
+
+
+def test_a_segmented_bundle_checks_its_keys():
+    segs = _segments()
+    params = _params()
+    params["extra"] = params.pop("s2_mid")
+    with pytest.raises(ValueError, match="keyed by its segments"):
+        ModelBundle(apply_fn=None, params=params, segments=segs)
+
+
+# -- a bundle without segments runs the program it ran before ---------------
+
+_LOC = re.compile(r"\s*loc\([^\n]*\)|#loc[^\n]*\n")
+
+
+def _canonical(text: str) -> str:
+    return _LOC.sub("", text)
+
+
+def _unsegmented_texts():
+    """The lowered step of two toy rounds of a bundle without segments
+    (the sort family under sign flip; Multi-Krum under Empire)."""
+    def dense(p, x):
+        return jnp.tanh(x @ p["kernel"] + p["bias"])
+
+    def loss_fn(params, x, y):
+        h = dense(params["s0_in"], x)
+        h = dense(params["s1_mid"], h)
+        h = dense(params["s2_mid"], h)
+        logits = h @ params["s3_head"]["kernel"] + params["s3_head"]["bias"]
+        return optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
+
+    out = {}
+    for name, aggregate, attack in (
+        ("trimmed-signflip", partial(robust.trimmed_mean, f=2),
+         lambda honest, key: attack_ops.sign_flip(jnp.mean(honest, axis=0))),
+        ("krum-empire", partial(robust.multi_krum, f=2, q=4),
+         lambda honest, key: attack_ops.empire(honest)),
+    ):
+        bundle = ModelBundle(apply_fn=None, params=_params(), loss_fn=loss_fn)
+        cfg = PSStepConfig(n_nodes=N, n_byzantine=B, learning_rate=0.005)
+        step, opt = build_ps_train_step(bundle, aggregate, cfg, attack=attack)
+        xs, ys = _batches()[0]
+        text = jax.jit(step, donate_argnums=(0, 1)).lower(
+            bundle.params, opt, xs, ys, jax.random.PRNGKey(0)).as_text()
+        out[name] = hashlib.sha256(_canonical(text).encode()).hexdigest()
+    return out
+
+
+# sha256 of the canonical lowered text, taken on the commit before the
+# streamed round (7fbda43) with this file's own function. A PR that changes
+# the (n, d) step on purpose records its own.
+PARENT_TEXTS = {
+    "trimmed-signflip": "0edfed7ebe605b91f5f4cdf66d3aa6f14196a7bfb470f443bbb01b61681fe14e",
+    "krum-empire": "426b43f45f7b242f3ddae42123198b70a50d7975092c8ecbe01474f23cdfcab8",
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT_TEXTS))
+def test_a_bundle_without_segments_lowers_to_the_text_it_had(cell):
+    assert _unsegmented_texts()[cell] == PARENT_TEXTS[cell]
+
+
+# -- the streamed round's scopes: catalogued, held by byzlint, in the text ---
+
+_STREAM_SCOPES = ["round.segment_fwd", "round.segment_recompute", "round.segment_bwd",
+                  "round.fwdbwd", "round.build_matrix", "round.aggregate", "round.update"]
+_MODEL_SCOPES = ["model.ssm_scan", "model.attention", "model.moe_route", "model.moe_experts"]
+
+
+@pytest.fixture(scope="module")
+def streamed_op_names():
+    from byzpy_tpu.models import nemotron_h as nh
+
+    cfg = nh.NemotronHConfig(
+        hidden_size=32, pattern="M*E", vocab_size=64, mamba_num_heads=4, mamba_head_dim=8,
+        ssm_state_size=16, n_groups=2, chunk_size=8, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=8, query_block=8, n_routed_experts=16,
+        num_experts_per_tok=3, moe_intermediate_size=24,
+        moe_shared_expert_intermediate_size=40, held_experts=(4, 4))
+    bundle = nh.nemotron_h_bundle(cfg, seed=0)
+    step, opt = build_ps_train_step(bundle, AGGREGATES["trimmed"],
+                                    PSStepConfig(n_nodes=N, n_byzantine=B), attack=SIGN_FLIP)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (N, 1, 19), 0, 64)
+    text = jax.jit(step).lower(bundle.params, opt, tokens, tokens,
+                               jax.random.PRNGKey(1)).compile().as_text()
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+@pytest.mark.parametrize("scope", _STREAM_SCOPES + _MODEL_SCOPES)
+def test_streamed_step_holds_each_scope_and_the_catalog_lists_it(streamed_op_names, scope):
+    from byzpy_tpu.observability import catalog
+
+    assert scope in catalog.SCOPES
+    assert any(f"/{scope}/" in name + "/" or f"({scope})" in name or f"({scope}/" in name
+               for name in streamed_op_names)
+
+
+def test_round_fwdbwd_is_the_innermost_round_scope_of_every_pass(streamed_op_names):
+    # chipbench/scope_join.py labels an op by the LAST round.* segment of its
+    # path: the accepted readers of round.fwdbwd read the streamed round so
+    innermost = re.compile(r"round\.[A-Za-z0-9_]+")
+    passes = [name for name in streamed_op_names if "round.segment_" in name]
+    assert passes
+    assert {innermost.findall(name)[-1] for name in passes} == {"round.fwdbwd"}
+
+
+def test_byzlint_metric_contract_is_silent_on_the_streamed_rounds_modules():
+    import os
+
+    from byzpy_tpu.analysis import scan_paths
+    from byzpy_tpu.analysis.rules import METRIC_CONTRACT
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    paths = [os.path.join(root, "byzpy_tpu", *parts) for parts in (
+        ("parallel", "ps.py"), ("parallel", "moe.py"), ("models", "nemotron_h.py"),
+        ("ops", "coordinatewise.py"))]
+    result = scan_paths(paths, select=[METRIC_CONTRACT])
+    assert [f.message for f in result.findings if f.rule == METRIC_CONTRACT] == []
