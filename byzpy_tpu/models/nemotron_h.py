@@ -32,6 +32,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas_attention import causal_attention, causal_attention_serves
 from ..parallel.moe import held_experts_ffn
 from .bundle import ModelBundle, Segment
 
@@ -194,12 +195,20 @@ def mamba2_mixer(p: Dict[str, Array], x: Array, cfg: NemotronHConfig) -> Array:
 def gqa_attention(p: Dict[str, Array], x: Array, cfg: NemotronHConfig) -> Array:
     """Causal softmax attention of one sequence, ``num_attention_heads``
     query heads sharing ``num_key_value_heads`` key/value heads, no bias,
-    no positional term. Queries go ``query_block`` at a time (each block
-    rematerialised in the backward pass), so the score matrix alive at
-    once is ``(heads, query_block, T)``."""
+    no positional term. Where the block-causal kernels serve the call
+    (:func:`~byzpy_tpu.ops.pallas_attention.causal_attention_serves`: a
+    TPU, ``head_dim`` in whole lanes) no score leaves the chip's VMEM and
+    no key above the diagonal is scored. Elsewhere queries go
+    ``query_block`` at a time (each block rematerialised in the backward
+    pass), so the score matrix alive at once is ``(heads, query_block, T)``."""
     with jax.named_scope("model.attention"):
         t = x.shape[0]
         heads, kv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+        if causal_attention_serves(x, hd):
+            out = causal_attention(
+                x @ p["w_q"].astype(x.dtype), x @ p["w_k"].astype(x.dtype),
+                x @ p["w_v"].astype(x.dtype), kv_heads=kv)
+            return out @ p["w_o"].astype(x.dtype)
         per = heads // kv
         q = (x @ p["w_q"].astype(x.dtype)).reshape(t, kv, per, hd)
         k = (x @ p["w_k"].astype(x.dtype)).reshape(t, kv, hd)

@@ -165,10 +165,15 @@ SCOPES: FrozenSet[str] = frozenset(
 #: every ``pl.pallas_call(name=...)``: the enclosing ``_<name>_call``
 #: function's name without the underscore and the ``_call``. The name is
 #: the custom call's instruction name and a segment of its ``op_name``
-#: in the compiled text, so a reader finds the kernel after a refactor
+#: in the compiled text, so a reader finds the kernel after a refactor.
+#: Not every kernel is an aggregate: ``causal_attention_*`` are the
+#: model's (``ops/pallas_attention.py``, under ``model.attention``)
 KERNELS: FrozenSet[str] = frozenset(
     {
         "arc_selection_mean_stream",
+        "causal_attention_dkv",
+        "causal_attention_dq",
+        "causal_attention_fwd",
         "clip_selection_mean_stream",
         "dequantize_pallas",
         "dequantize_s4_pallas",

@@ -1,0 +1,539 @@
+"""Block-causal grouped-query attention of one sequence as Pallas TPU
+kernels: a forward kernel and the two kernels of its backward.
+
+``softmax(q k^T / sqrt(head_dim) + causal mask) v`` for ``H`` query heads
+that share ``kv_heads`` key/value heads, on the projections as they leave
+the matrix products: ``q (T, H * head_dim)``, ``k``, ``v``
+``(T, kv_heads * head_dim)``, the output ``(T, H * head_dim)``. Nothing is
+transposed or repeated in HBM on the way in or out.
+
+* The grid of every kernel is (key/value head, pair), and the pairs are the
+  (query block, key block) pairs AT OR UNDER the diagonal, listed in Python
+  and handed to the index maps as prefetched scalars: a pair wholly above
+  the diagonal is never visited, and the mask is computed only in the pairs
+  the diagonal crosses.
+* Scores, probabilities, the running maximum, the running sum and the
+  output accumulator live in VMEM (online softmax). What the forward writes
+  to HBM is the output and ONE log-sum-exp a query row a head,
+  ``(kv_heads, H / kv_heads, T)`` float32.
+* The query heads of a group are folded into the rows of the query block,
+  ``(H / kv_heads * block_q, head_dim)`` against ``(block_k, head_dim)``, so
+  a key/value block is loaded once for all of them and the MXU's weights
+  (the key block) serve sixteen times the rows.
+* The backward recomputes a pair's probabilities from q, k and the
+  log-sum-exp: one kernel for dq (the forward's walk), one for dk and dv
+  (key block outermost, the sum over a group's query heads taken inside
+  the kernel, in the transposed form ``k q^T`` so that no tile is
+  transposed).
+
+Precision: everything stored or carried is the operands' dtype (float32
+where the model is float32) or float32; exponentials, maxima and sums are
+float32. The products are ``lax.dot_general`` at ``Precision.DEFAULT``
+with a float32 result, which is what XLA gives the same contractions of
+the ``lax.map`` route on a TPU: the MXU takes bfloat16 operands and
+accumulates in float32.
+
+Mosaic on a TPU, the Pallas interpreter on a CPU
+(:func:`~byzpy_tpu.ops.pallas_kernels._resolve_interpret`).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import pallas_kernels as _pk
+
+Array = jnp.ndarray
+
+_LANES = _pk._LANES
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_FIRST, _LAST, _MASKED = 1, 2, 4  # a pair's flags
+# rows of the folded query block that one pass of a step's loop handles:
+# the score tile alive at once is (_CHUNK_ROWS, block_k) float32
+_CHUNK_ROWS = 1024
+# the widest key block of the forward and of the backward's two kernels.
+# Measured on a v5e at 32 / 2 heads x 128, 4096 positions, query block 256
+# (PR 33): forward 2.13 ms at 512, 1.31 at 1024, 1.62 at 2048; dq 1.44 at
+# 512, 1.53 at 1024; dk / dv 1.71 at 512, 3.32 at 1024
+_FORWARD_KEY_BLOCK, _BACKWARD_KEY_BLOCK = 1024, 512
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def causal_attention_serves(x: Array, head_dim: int) -> bool:
+    """THE gate of the attention route: do the kernels serve a sequence
+    whose activations are ``x``? On a TPU, for float32 / bfloat16
+    activations, a ``head_dim`` of whole lanes, an operand that is not
+    device-sharded (:func:`~byzpy_tpu.ops.pallas_kernels.
+    sharding_allows_pallas`). Any length: the wrapper pads it to whole
+    blocks. Asked once a call, in Python, by ``models.nemotron_h.
+    gqa_attention``; reads no environment variable."""
+    return bool(
+        _pk._on_tpu()
+        and x.dtype in (jnp.float32, jnp.bfloat16)
+        and head_dim % _LANES == 0
+        and _pk.sharding_allows_pallas(x)
+    )
+
+
+def _blocks(t: int, widest_key_block: int) -> Tuple[int, int, int]:
+    """``(padded length, block_q, block_k)`` for a sequence of ``t``
+    positions: the length in whole 128s, the query block 256 where that
+    divides it (a power of two: the mask reads a folded row's position
+    with a bitwise and), the key block the widest of 1024 ... 128 that
+    divides it and is no wider than asked."""
+    t_pad = _pk._round_up(t, _LANES)
+    block_q = 256 if t_pad % 256 == 0 else 128
+    block_k = next(b for b in (1024, 512, 256, 128)
+                   if b <= widest_key_block and t_pad % b == 0)
+    return t_pad, block_q, block_k
+
+
+def _pairs(n_q: int, n_k: int, block_q: int, block_k: int, *, key_major: bool):
+    """The (query block, key block) pairs at or under the diagonal, as
+    three int32 vectors: query block, key block, flags. Query-major (each
+    query block's key blocks in turn) for the forward and dq; key-major
+    for dk / dv. ``_FIRST`` / ``_LAST`` mark the ends of the outer block's
+    run, ``_MASKED`` a pair the diagonal crosses."""
+    runs = []
+    if key_major:
+        for kj in range(n_k):
+            first_q = (kj * block_k) // block_q
+            runs.append([(qi, kj) for qi in range(first_q, n_q)])
+    else:
+        for qi in range(n_q):
+            last_k = ((qi + 1) * block_q - 1) // block_k
+            runs.append([(qi, kj) for kj in range(last_k + 1)])
+    qs, ks, flags = [], [], []
+    for run in runs:
+        for i, (qi, kj) in enumerate(run):
+            crossed = (kj + 1) * block_k - 1 > qi * block_q
+            qs.append(qi)
+            ks.append(kj)
+            flags.append((_FIRST if i == 0 else 0) | (_LAST if i == len(run) - 1 else 0)
+                         | (_MASKED if crossed else 0))
+    return tuple(np.asarray(a, np.int32) for a in (qs, ks, flags))
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, precision=lax.Precision.DEFAULT,
+                           preferred_element_type=jnp.float32)
+
+
+def _eye():
+    return (lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
+            == lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1))
+
+
+def _column_to_row(col, eye):
+    """``(n, 1) -> (1, n)`` float32, ``n`` in whole 128s: each 128 rows
+    spread along the lanes, kept on the diagonal (``eye``), summed over
+    the rows."""
+    parts = [
+        jnp.sum(jnp.where(eye, jnp.broadcast_to(col[c:c + _LANES, :], (_LANES, _LANES)), 0.0),
+                axis=0, keepdims=True)
+        for c in range(0, col.shape[0], _LANES)]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def _row_to_column(row_ref, r, eye):
+    """Row ``r`` of a ``(., n)`` block as ``(n, 1)``: :func:`_column_to_row`
+    the other way, 128 lanes of the block at a time."""
+    parts = [
+        jnp.sum(jnp.where(eye, jnp.broadcast_to(row_ref[pl.ds(r, 1), c:c + _LANES],
+                                                (_LANES, _LANES)), 0.0),
+                axis=1, keepdims=True)
+        for c in range(0, row_ref.shape[1], _LANES)]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
+def _heads(per: int, body):
+    """``body(r)`` for every head of a group. Traced once and unrolled where
+    it is lowered (so a head's lanes and rows are static slices to Mosaic,
+    and one head's products hide the next head's exponentials): sixteen
+    traced copies of every per-head body were most of what tracing and
+    lowering the kernels cost a step's set-up."""
+    def step(r, carry):
+        body(r)
+        return carry
+
+    lax.fori_loop(0, per, step, 0, unroll=True)
+
+
+def _rows(r, block_q: int):
+    """Head ``r``'s rows of a folded block."""
+    return pl.ds(r * block_q, block_q)
+
+
+def _lanes(r, head_dim: int):
+    """Head ``r``'s lanes of a ``(block_q, per * head_dim)`` block."""
+    return pl.ds(r * head_dim, head_dim)
+
+
+def _fold(dst_ref, src_ref, per: int, block_q: int, head_dim: int, scale: Optional[float]):
+    """A ``(block_q, per * head_dim)`` block, a group's heads side by side,
+    into ``(per * block_q, head_dim)`` rows, head by head."""
+    def one(r):
+        part = src_ref[:, _lanes(r, head_dim)]
+        if scale is not None:
+            part = (part.astype(jnp.float32) * scale).astype(dst_ref.dtype)
+        dst_ref[_rows(r, block_q), :] = part
+
+    _heads(per, one)
+
+
+def _seen(rows0, n_rows: int, block_q: int, block_k: int, qi, kj):
+    """The causal mask of ``n_rows`` folded rows from ``rows0`` against a
+    key block: a folded row's position is ``qi * block_q`` + its row
+    within its head."""
+    row = rows0 + lax.broadcasted_iota(jnp.int32, (n_rows, block_k), 0)
+    position = qi * block_q + (row & (block_q - 1))
+    key = kj * block_k + lax.broadcasted_iota(jnp.int32, (n_rows, block_k), 1)
+    return key <= position
+
+
+def _for_chunks(rows: int, body):
+    """``body(first row, rows)`` over a folded block: ``_CHUNK_ROWS`` at a
+    time where they divide it, else all at once."""
+    chunk = _CHUNK_ROWS if rows % _CHUNK_ROWS == 0 else rows
+    if rows == chunk:
+        body(0, rows)
+    else:
+        def step(c, carry):
+            body(pl.multiple_of(c * chunk, chunk), chunk)
+            return carry
+
+        lax.fori_loop(0, rows // chunk, step, 0)
+
+
+def _masked_or_not(flag, step):
+    """``step(masked)``: the diagonal's pairs compute the mask, the others
+    carry no trace of it."""
+    pl.when((flag & _MASKED) != 0)(lambda: step(True))
+    pl.when((flag & _MASKED) == 0)(lambda: step(False))
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+
+
+def _fwd_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                qf_ref, m_ref, l_ref, acc_ref, *, per, head_dim, block_q, block_k):
+    pair = pl.program_id(1)
+    qi, kj, flag = qi_ref[pair], kj_ref[pair], flag_ref[pair]
+    rows = per * block_q
+
+    @pl.when((flag & _FIRST) != 0)
+    def _():
+        _fold(qf_ref, q_ref, per, block_q, head_dim, 1.0 / math.sqrt(head_dim))
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def step(masked):
+        def body(rows0, chunk):
+            sl = pl.ds(rows0, chunk)
+            s = _dot(qf_ref[sl, :], k_ref[...], _NT)
+            if masked:
+                s = jnp.where(_seen(rows0, chunk, block_q, block_k, qi, kj), s, -jnp.inf)
+            m_prev = m_ref[sl, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[sl, :] = alpha * l_ref[sl, :] + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[sl, :] = alpha * acc_ref[sl, :] + _dot(p.astype(v_ref.dtype), v_ref[...], _NN)
+            m_ref[sl, :] = m_new
+
+        _for_chunks(rows, body)
+
+    _masked_or_not(flag, step)
+
+    @pl.when((flag & _LAST) != 0)
+    def _():
+        eye = _eye()
+
+        def one(r):
+            head = _rows(r, block_q)
+            total = l_ref[head, :]
+            o_ref[:, _lanes(r, head_dim)] = (acc_ref[head, :] / total).astype(o_ref.dtype)
+            lse_ref[pl.ds(r, 1), :] = _column_to_row(m_ref[head, :] + jnp.log(total), eye)
+
+        _heads(per, one)
+
+
+def _grid_spec(pairs, kv_heads, in_specs, out_specs, scratch_shapes):
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(kv_heads, len(pairs[0])),
+        in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch_shapes)
+
+
+def _specs(per: int, head_dim: int, block_q: int, block_k: int):
+    """Block specs of a query-side array, a key-side array and a
+    per-row statistic, by the pair's query and key block."""
+    q_spec = pl.BlockSpec((block_q, per * head_dim), lambda g, p, qi, kj, fl: (qi[p], g))
+    k_spec = pl.BlockSpec((block_k, head_dim), lambda g, p, qi, kj, fl: (kj[p], g))
+    row_spec = pl.BlockSpec((None, per, block_q), lambda g, p, qi, kj, fl: (g, 0, qi[p]))
+    return q_spec, k_spec, row_spec
+
+
+def _params():
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"),
+                                vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _cost(pairs, kv_heads, per, head_dim, block_q, block_k, products, arrays):
+    tile = len(pairs[0]) * kv_heads * per * block_q * block_k
+    return pl.CostEstimate(
+        flops=2 * products * tile * head_dim, transcendentals=tile,
+        bytes_accessed=sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in arrays))
+
+
+def _causal_attention_fwd_call(q, k, v, *, kv_heads, head_dim, block_q, block_k, interpret):
+    t = q.shape[0]
+    per = q.shape[1] // (kv_heads * head_dim)
+    rows = per * block_q
+    pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=False)
+    q_spec, k_spec, row_spec = _specs(per, head_dim, block_q, block_k)
+    out_shape = (jax.ShapeDtypeStruct(q.shape, q.dtype),
+                 jax.ShapeDtypeStruct((kv_heads, per, t), jnp.float32))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, per=per, head_dim=head_dim, block_q=block_q,
+                          block_k=block_k),
+        out_shape=out_shape,
+        grid_spec=_grid_spec(
+            pairs, kv_heads, [q_spec, k_spec, k_spec], (q_spec, row_spec),
+            [pltpu.VMEM((rows, head_dim), q.dtype), pltpu.VMEM((rows, 1), jnp.float32),
+             pltpu.VMEM((rows, 1), jnp.float32), pltpu.VMEM((rows, head_dim), jnp.float32)]),
+        compiler_params=_params(),
+        cost_estimate=_cost(pairs, kv_heads, per, head_dim, block_q, block_k, 2,
+                            (q, k, v) + out_shape),
+        interpret=interpret,
+        name="causal_attention_fwd",
+    )(*pairs, q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# backward: dq
+# ---------------------------------------------------------------------------
+
+
+def _dq_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               dq_ref, qf_ref, dof_ref, lse_col_ref, delta_col_ref, acc_ref,
+               *, per, head_dim, block_q, block_k):
+    pair = pl.program_id(1)
+    qi, kj, flag = qi_ref[pair], kj_ref[pair], flag_ref[pair]
+    rows = per * block_q
+    scale = 1.0 / math.sqrt(head_dim)
+
+    @pl.when((flag & _FIRST) != 0)
+    def _():
+        _fold(qf_ref, q_ref, per, block_q, head_dim, scale)
+        _fold(dof_ref, do_ref, per, block_q, head_dim, None)
+        eye = _eye()
+
+        def one(r):
+            lse_col_ref[_rows(r, block_q), :] = _row_to_column(lse_ref, r, eye)
+            delta_col_ref[_rows(r, block_q), :] = _row_to_column(delta_ref, r, eye)
+
+        _heads(per, one)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def step(masked):
+        def body(rows0, chunk):
+            sl = pl.ds(rows0, chunk)
+            s = _dot(qf_ref[sl, :], k_ref[...], _NT)
+            if masked:
+                s = jnp.where(_seen(rows0, chunk, block_q, block_k, qi, kj), s, -jnp.inf)
+            p = jnp.exp(s - lse_col_ref[sl, :])
+            dp = _dot(dof_ref[sl, :], v_ref[...], _NT)
+            ds = p * (dp - delta_col_ref[sl, :])
+            acc_ref[sl, :] = acc_ref[sl, :] + _dot(ds.astype(k_ref.dtype), k_ref[...], _NN)
+
+        _for_chunks(rows, body)
+
+    _masked_or_not(flag, step)
+
+    @pl.when((flag & _LAST) != 0)
+    def _():
+        def one(r):
+            dq_ref[:, _lanes(r, head_dim)] = (
+                acc_ref[_rows(r, block_q), :] * scale).astype(dq_ref.dtype)
+
+        _heads(per, one)
+
+
+def _causal_attention_dq_call(q, k, v, do, lse, delta, *, kv_heads, head_dim, block_q,
+                              block_k, interpret):
+    t = q.shape[0]
+    per = q.shape[1] // (kv_heads * head_dim)
+    rows = per * block_q
+    pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=False)
+    q_spec, k_spec, row_spec = _specs(per, head_dim, block_q, block_k)
+    out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, per=per, head_dim=head_dim, block_q=block_q,
+                          block_k=block_k),
+        out_shape=out_shape,
+        grid_spec=_grid_spec(
+            pairs, kv_heads, [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec], q_spec,
+            [pltpu.VMEM((rows, head_dim), q.dtype), pltpu.VMEM((rows, head_dim), do.dtype),
+             pltpu.VMEM((rows, 1), jnp.float32), pltpu.VMEM((rows, 1), jnp.float32),
+             pltpu.VMEM((rows, head_dim), jnp.float32)]),
+        compiler_params=_params(),
+        cost_estimate=_cost(pairs, kv_heads, per, head_dim, block_q, block_k, 3,
+                            (q, k, v, do, lse, delta, out_shape)),
+        interpret=interpret,
+        name="causal_attention_dq",
+    )(*pairs, q, k, v, do, lse, delta)
+
+
+# ---------------------------------------------------------------------------
+# backward: dk, dv
+# ---------------------------------------------------------------------------
+
+
+def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, per, head_dim, block_q, block_k):
+    pair = pl.program_id(1)
+    qi, kj, flag = qi_ref[pair], kj_ref[pair], flag_ref[pair]
+    scale = 1.0 / math.sqrt(head_dim)
+
+    @pl.when((flag & _FIRST) != 0)
+    def _():
+        dk_acc_ref[...] = jnp.zeros(dk_acc_ref.shape, jnp.float32)
+        dv_acc_ref[...] = jnp.zeros(dv_acc_ref.shape, jnp.float32)
+
+    def step(masked):
+        # the transposed tile (keys, queries) of one head after another: the
+        # sums over a group's heads and over its query rows are the products'
+        if masked:
+            key = kj * block_k + lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
+            position = qi * block_q + lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
+            seen = key <= position
+        # unrolled where it is lowered, not a loop on the chip: as a rolled
+        # ``fori_loop`` over the heads this kernel took 2.41 ms where sixteen
+        # copies take 1.71 (v5e, PR 33); the copies cost 1.9 MB of code in HBM
+        def one(r):
+            q = (q_ref[:, _lanes(r, head_dim)].astype(jnp.float32) * scale).astype(q_ref.dtype)
+            do = do_ref[:, _lanes(r, head_dim)]
+            s = _dot(k_ref[...], q, _NT)
+            if masked:
+                s = jnp.where(seen, s, -jnp.inf)
+            p = jnp.exp(s - lse_ref[pl.ds(r, 1), :])
+            dv_acc_ref[...] = dv_acc_ref[...] + _dot(p.astype(do.dtype), do, _NN)
+            dp = _dot(v_ref[...], do, _NT)
+            ds = p * (dp - delta_ref[pl.ds(r, 1), :])
+            dk_acc_ref[...] = dk_acc_ref[...] + _dot(ds.astype(q.dtype), q, _NN)
+
+        _heads(per, one)
+
+    _masked_or_not(flag, step)
+
+    @pl.when((flag & _LAST) != 0)
+    def _():
+        dk_ref[...] = dk_acc_ref[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc_ref[...].astype(dv_ref.dtype)
+
+
+def _causal_attention_dkv_call(q, k, v, do, lse, delta, *, kv_heads, head_dim, block_q,
+                               block_k, interpret):
+    t = q.shape[0]
+    per = q.shape[1] // (kv_heads * head_dim)
+    pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=True)
+    q_spec, k_spec, row_spec = _specs(per, head_dim, block_q, block_k)
+    out_shape = (jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype))
+    return pl.pallas_call(
+        functools.partial(_dkv_kernel, per=per, head_dim=head_dim, block_q=block_q,
+                          block_k=block_k),
+        out_shape=out_shape,
+        grid_spec=_grid_spec(
+            pairs, kv_heads, [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+            (k_spec, k_spec),
+            [pltpu.VMEM((block_k, head_dim), jnp.float32),
+             pltpu.VMEM((block_k, head_dim), jnp.float32)]),
+        compiler_params=_params(),
+        cost_estimate=_cost(pairs, kv_heads, per, head_dim, block_q, block_k, 4,
+                            (q, k, v, do, lse, delta) + out_shape),
+        interpret=interpret,
+        name="causal_attention_dkv",
+    )(*pairs, q, k, v, do, lse, delta)
+
+
+# ---------------------------------------------------------------------------
+# the function and its derivative
+# ---------------------------------------------------------------------------
+
+
+def causal_attention(q: Array, k: Array, v: Array, *, kv_heads: int,
+                     interpret: Optional[bool] = None) -> Array:
+    """Causal softmax attention of one sequence: ``q (T, H * head_dim)``,
+    ``k``, ``v`` ``(T, kv_heads * head_dim)``, query head ``h`` reading
+    key/value head ``h // (H / kv_heads)``; returns ``(T, H * head_dim)``
+    in ``q``'s dtype. Differentiable in all three. Any ``T``: the tail is
+    padded to whole blocks (padded keys lie after every query; padded
+    queries are cut off and their cotangent is zero)."""
+    head_dim = k.shape[1] // kv_heads
+    if head_dim % _LANES or q.shape[1] % (kv_heads * head_dim):
+        raise ValueError(
+            f"causal_attention needs a head_dim of whole {_LANES}s and whole groups of query "
+            f"heads, got q {q.shape}, k {k.shape}, kv_heads {kv_heads}")
+    return _causal_attention(q, k, v, kv_heads, _pk._resolve_interpret(interpret))
+
+
+def _padded(t_pad: int, *arrays):
+    pad = t_pad - arrays[0].shape[0]
+    return tuple(jnp.pad(a, ((0, pad), (0, 0))) for a in arrays) if pad else arrays
+
+
+def _forward(q, k, v, kv_heads, interpret):
+    t_pad, block_q, block_k = _blocks(q.shape[0], _FORWARD_KEY_BLOCK)
+    out, lse = _causal_attention_fwd_call(
+        *_padded(t_pad, q, k, v), kv_heads=kv_heads, head_dim=k.shape[1] // kv_heads,
+        block_q=block_q, block_k=block_k, interpret=interpret)
+    return out[:q.shape[0]], lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _causal_attention(q, k, v, kv_heads, interpret):
+    return _forward(q, k, v, kv_heads, interpret)[0]
+
+
+def _causal_attention_fwd(q, k, v, kv_heads, interpret):
+    out, lse = _forward(q, k, v, kv_heads, interpret)
+    return out, (q, k, v, out, lse)
+
+
+def _causal_attention_bwd(kv_heads, interpret, residuals, d_out):
+    # the backward rule is traced outside the scope the forward stood in
+    with jax.named_scope("model.attention"):
+        q, k, v, out, lse = residuals
+        t = q.shape[0]
+        t_pad, block_q, block_k = _blocks(t, _BACKWARD_KEY_BLOCK)
+        head_dim = k.shape[1] // kv_heads
+        per = q.shape[1] // (kv_heads * head_dim)
+        # rowsum(d_out * out): what the softmax's Jacobian takes off every row
+        delta = jnp.sum(
+            (d_out.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
+                t, kv_heads, per, head_dim), axis=-1)
+        delta = jnp.pad(jnp.transpose(delta, (1, 2, 0)), ((0, 0), (0, 0), (0, t_pad - t)))
+        args = _padded(t_pad, q, k, v, d_out.astype(q.dtype)) + (lse, delta)
+        sizes = dict(kv_heads=kv_heads, head_dim=head_dim, block_q=block_q, block_k=block_k,
+                     interpret=interpret)
+        dq = _causal_attention_dq_call(*args, **sizes)
+        dk, dv = _causal_attention_dkv_call(*args, **sizes)
+        return dq[:t], dk[:t], dv[:t]
+
+
+_causal_attention.defvjp(_causal_attention_fwd, _causal_attention_bwd)
+
+__all__ = ["causal_attention", "causal_attention_serves"]

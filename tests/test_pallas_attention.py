@@ -1,0 +1,424 @@
+"""The block-causal attention kernels (``ops/pallas_attention.py``) and the
+gate that hands ``models.nemotron_h.gqa_attention`` to them.
+
+On the CPU the kernels run in the Pallas interpreter. Held here:
+
+* the kernels are the full score matrix: output and the gradients with
+  respect to q, k and v against a plain ``(T, T)`` softmax at the highest
+  precision, at 32 / 2 and 4 / 2 heads, for a sequence of one block, of
+  several, and of a length that is no whole number of blocks;
+* through ``gqa_attention`` the kernel route is the ``lax.map`` route and
+  the benchmark's reference (``chipbench/reference_nemotron_h.
+  attention_full``), output and gradients down to the four weights;
+* a block above the diagonal is skipped, not masked after the fact: keys
+  there may be NaN;
+* ``vmap`` over sequences;
+* the gate, condition by condition, asked once a call in Python; off a
+  TPU ``gqa_attention`` lowers to the text it lowered to before there was
+  a kernel;
+* the list of pairs a kernel's grid walks is the pairs at or under the
+  diagonal, no more and no fewer.
+
+The kernels' Mosaic compile at the real width is held in
+``tests/test_round_matrix_once.py`` (the one file that compiles for a
+described TPU).
+"""
+
+from __future__ import annotations
+
+import hashlib
+import inspect
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import AxisType, NamedSharding
+from jax.sharding import PartitionSpec as P
+
+from byzpy_tpu.models import nemotron_h as nh
+from byzpy_tpu.ops import pallas_attention as pa
+from byzpy_tpu.ops import pallas_kernels as pk
+from chipbench import reference_nemotron_h as ref
+
+HD = 128
+CONFIGS = {
+    "4of2": nh.NemotronHConfig(hidden_size=48, num_attention_heads=4, num_key_value_heads=2,
+                               head_dim=HD, query_block=64),
+    "32of2": nh.NemotronHConfig(hidden_size=48, num_attention_heads=32, num_key_value_heads=2,
+                                head_dim=HD, query_block=64),
+}
+# one block; no whole number of blocks (three of 128); several (query block
+# 256 against key blocks of 1024, 512 and 256: pairs the diagonal crosses
+# and pairs wholly under it)
+LENGTHS = {"4of2": [128, 300, 2048, 768], "32of2": [128, 300, 512]}
+CASES = [(name, t) for name, lengths in LENGTHS.items() for t in lengths]
+
+
+def _arch(cfg):
+    return {"num_attention_heads": cfg.num_attention_heads,
+            "num_key_value_heads": cfg.num_key_value_heads, "head_dim": cfg.head_dim}
+
+
+def _weights(cfg, seed):
+    heads, kv, hd, hidden = (cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim,
+                             cfg.hidden_size)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shapes = {"w_q": (hidden, heads * hd), "w_k": (hidden, kv * hd), "w_v": (hidden, kv * hd),
+              "w_o": (heads * hd, hidden)}
+    # keys and queries a few units long: a softmax that is far from uniform
+    return {name: jax.random.normal(k, shape) * (0.5 if name in ("w_q", "w_k") else
+                                                 1 / math.sqrt(shape[0]))
+            for k, (name, shape) in zip(keys, shapes.items())}
+
+
+def _qkv(cfg, t, seed):
+    heads, kv = cfg.num_attention_heads, cfg.num_key_value_heads
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(keys[0], (t, heads * HD)), jax.random.normal(keys[1], (t, kv * HD)),
+            jax.random.normal(keys[2], (t, kv * HD)), jax.random.normal(keys[3], (t, heads * HD)))
+
+
+def _full_scores(q, k, v, kv):
+    t = q.shape[0]
+    per = q.shape[1] // (kv * HD)
+    q, k, v = q.reshape(t, kv, per, HD), k.reshape(t, kv, HD), v.reshape(t, kv, HD)
+    scores = jnp.einsum("qgrd,kgd->grqk", q, k, precision="highest") / math.sqrt(HD)
+    seen = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+    scores = jnp.where(seen, scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("grqk,kgd->qgrd", probs, v, precision="highest").reshape(t, -1)
+    return out, jax.scipy.special.logsumexp(scores, axis=-1)
+
+
+def _close(got, want, tol=2e-5):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert got.shape == want.shape
+    scale = max(float(np.max(np.abs(want))), 1e-6)
+    assert float(np.max(np.abs(got - want))) <= tol * scale
+
+
+@pytest.fixture
+def kernel_route(monkeypatch):
+    """``kernel_route(True | False)``: what the gate answers
+    ``gqa_attention`` (the kernels themselves stay interpreted: the
+    backend is the CPU's)."""
+
+    def choose(serves):
+        monkeypatch.setattr(nh, "causal_attention_serves", lambda x, head_dim: serves)
+
+    return choose
+
+
+# -- the kernels are the full score matrix -----------------------------------
+
+
+@pytest.mark.parametrize("name, t", CASES)
+def test_kernels_are_the_full_score_matrix_forward_and_gradient(name, t):
+    cfg = CONFIGS[name]
+    kv = cfg.num_key_value_heads
+    q, k, v, probe = _qkv(cfg, t, seed=t)
+    out = pa.causal_attention(q, k, v, kv_heads=kv)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    _close(out, _full_scores(q, k, v, kv)[0])
+    got = jax.grad(lambda *a: jnp.sum(pa.causal_attention(*a, kv_heads=kv) * probe),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_full_scores(*a, kv)[0] * probe),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        _close(g, w)
+
+
+@pytest.mark.parametrize("name, t", [("4of2", 300), ("32of2", 512)])
+def test_forward_writes_one_log_sum_exp_a_query_row_a_head(name, t):
+    cfg = CONFIGS[name]
+    kv, per = cfg.num_key_value_heads, cfg.num_attention_heads // cfg.num_key_value_heads
+    q, k, v, _ = _qkv(cfg, t, seed=1)
+    out, lse = pa._forward(q, k, v, kv, True)
+    t_pad = pa._blocks(t, 512)[0]
+    assert lse.shape == (kv, per, t_pad) and lse.dtype == jnp.float32
+    _close(lse[..., :t], _full_scores(q, k, v, kv)[1])
+    assert out.shape == q.shape
+
+
+def test_bfloat16_operands_give_a_bfloat16_result_near_the_float32_one():
+    cfg = CONFIGS["4of2"]
+    q, k, v, probe = _qkv(cfg, 300, seed=2)
+    want = pa.causal_attention(q, k, v, kv_heads=2)
+    narrow = [a.astype(jnp.bfloat16) for a in (q, k, v)]
+    got = pa.causal_attention(*narrow, kv_heads=2)
+    assert got.dtype == jnp.bfloat16
+    _close(got, want, tol=3e-2)
+    grads = jax.grad(lambda *a: jnp.sum(pa.causal_attention(*a, kv_heads=2).astype(jnp.float32)
+                                        * probe), argnums=(0, 1, 2))(*narrow)
+    assert [g.dtype for g in grads] == [jnp.bfloat16] * 3
+    assert all(bool(jnp.all(jnp.isfinite(g.astype(jnp.float32)))) for g in grads)
+
+
+def test_a_head_dim_that_is_no_whole_lanes_is_refused_by_the_wrapper():
+    with pytest.raises(ValueError, match="head_dim"):
+        pa.causal_attention(jnp.zeros((16, 4 * 8)), jnp.zeros((16, 2 * 8)), jnp.zeros((16, 2 * 8)),
+                            kv_heads=2)
+
+
+# -- through gqa_attention: the kernel route, the map route, the reference ----
+
+
+def _value_and_grads(fn, p, x, probe):
+    out = fn(p, x)
+    grads = jax.grad(lambda p_, x_: jnp.sum(fn(p_, x_) * probe), argnums=(0, 1))(p, x)
+    return out, grads
+
+
+@pytest.mark.parametrize("name, t", [("4of2", 128), ("4of2", 300), ("4of2", 768),
+                                     ("32of2", 128), ("32of2", 300)])
+def test_gqa_attention_by_the_kernels_is_the_map_route_and_the_reference(name, t, kernel_route):
+    cfg = CONFIGS[name]
+    p = _weights(cfg, seed=5)
+    x = jax.random.normal(jax.random.PRNGKey(t), (t, cfg.hidden_size))
+    probe = jax.random.normal(jax.random.PRNGKey(9), (t, cfg.hidden_size))
+    kernel_route(False)
+    by_map = _value_and_grads(lambda p_, x_: nh.gqa_attention(p_, x_, cfg), p, x, probe)
+    kernel_route(True)
+    by_kernel = _value_and_grads(lambda p_, x_: nh.gqa_attention(p_, x_, cfg), p, x, probe)
+    with jax.default_matmul_precision("highest"):
+        by_reference = _value_and_grads(
+            lambda p_, x_: ref.attention_full(p_, x_, _arch(cfg)), p, x, probe)
+    for other in (by_map, by_reference):
+        _close(by_kernel[0], other[0], tol=1e-4)
+        for leaf in ("w_q", "w_k", "w_v", "w_o"):
+            _close(by_kernel[1][0][leaf], other[1][0][leaf], tol=1e-4)
+        _close(by_kernel[1][1], other[1][1], tol=1e-4)
+
+
+# -- blocks above the diagonal are skipped ------------------------------------
+
+
+@pytest.mark.parametrize("name, t", [("4of2", 2048), ("32of2", 768)])
+def test_blocks_above_the_diagonal_may_be_nan(name, t):
+    """Skipped, not masked after the fact. The LAST KEY BLOCK is NaN:
+    every query before it has it wholly above its diagonal, so their
+    output and dq never read it. The FIRST QUERY BLOCK is NaN: every key
+    block after the first has it wholly above, so their dk and dv never
+    read it. Masked after the fact, the backward's products would multiply
+    a zero by the NaN (as the ``lax.map`` route's do)."""
+    cfg = CONFIGS[name]
+    kv = cfg.num_key_value_heads
+    q, k, v, probe = _qkv(cfg, t, seed=3)
+    _, block_q, block_k = pa._blocks(t, pa._FORWARD_KEY_BLOCK)  # the wider of the two
+    assert block_k >= pa._blocks(t, pa._BACKWARD_KEY_BLOCK)[2]
+
+    def grads(q_, k_, probe_):
+        return jax.grad(lambda *a: jnp.sum(pa.causal_attention(*a, kv_heads=kv) * probe_),
+                        argnums=(0, 1, 2))(q_, k_, v)
+
+    cut = t - block_k
+    assert cut >= 512
+    poisoned = k.at[cut:].set(jnp.nan)
+    out = pa.causal_attention(q, poisoned, v, kv_heads=kv)
+    assert bool(jnp.all(jnp.isfinite(out[:cut]))) and bool(jnp.all(jnp.isnan(out[cut:])))
+    _close(out[:cut], pa.causal_attention(q[:cut], k[:cut], v[:cut], kv_heads=kv))
+    # the queries that do see the NaN keys are left out of the read-out
+    dq, _, _ = grads(q, poisoned, probe.at[cut:].set(0.0))
+    assert bool(jnp.all(jnp.isfinite(dq[:cut])))
+
+    poisoned = q.at[:block_q].set(jnp.nan)
+    out = pa.causal_attention(poisoned, k, v, kv_heads=kv)
+    assert bool(jnp.all(jnp.isfinite(out[block_q:]))) and bool(jnp.all(jnp.isnan(out[:block_q])))
+    _, dk, dv = grads(poisoned, k, probe.at[:block_q].set(0.0))
+    after = max(block_q, block_k)
+    assert bool(jnp.all(jnp.isfinite(dk[after:]))) and bool(jnp.all(jnp.isfinite(dv[after:])))
+    assert bool(jnp.all(jnp.isnan(dk[:block_q])))  # the keys the NaN queries do see
+
+
+# -- vmap over sequences -------------------------------------------------------
+
+
+def test_vmap_over_sequences_is_one_sequence_after_another(kernel_route):
+    cfg = CONFIGS["4of2"]
+    p = _weights(cfg, seed=6)
+    xs = jax.random.normal(jax.random.PRNGKey(4), (3, 200, cfg.hidden_size))
+    kernel_route(True)
+
+    def read(p_, xs_):
+        return jnp.sum(jnp.sin(jax.vmap(lambda s: nh.gqa_attention(p_, s, cfg))(xs_)))
+
+    value, (dp, dxs) = jax.jit(jax.value_and_grad(read, argnums=(0, 1)))(p, xs)
+    one_by_one = [jax.value_and_grad(
+        lambda p_, s: jnp.sum(jnp.sin(nh.gqa_attention(p_, s, cfg))), argnums=(0, 1))(p, s)
+        for s in xs]
+    _close(value, sum(v for v, _ in one_by_one), tol=1e-5)
+    _close(dxs, jnp.stack([g[1] for _, g in one_by_one]), tol=1e-4)
+    for leaf in p:
+        _close(dp[leaf], sum(g[0][leaf] for _, g in one_by_one), tol=1e-4)
+
+
+# -- the gate -------------------------------------------------------------------
+
+
+def _sds(shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "float16", "float64", "int32"])
+@pytest.mark.parametrize("head_dim", [128, 256, 8, 64, 192])
+def test_gate_table(monkeypatch, platform, dtype, head_dim):
+    monkeypatch.setattr(pk, "_on_tpu", lambda: platform == "tpu")
+    want = platform == "tpu" and dtype in ("float32", "bfloat16") and head_dim % 128 == 0
+    with jax.enable_x64(dtype == "float64"):
+        assert pa.causal_attention_serves(_sds((4096, 2688), jnp.dtype(dtype)), head_dim) is want
+
+
+def test_gate_refuses_a_device_sharded_operand(monkeypatch):
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four (virtual) devices")
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    mesh = jax.make_mesh((4,), ("seq",), devices=jax.devices()[:4],
+                         axis_types=(AxisType.Explicit,))
+    x = jnp.zeros((4096, 256), jnp.float32)
+    with jax.set_mesh(mesh):
+        sharded = jax.device_put(x, NamedSharding(mesh, P("seq", None)))
+        whole = jax.device_put(x, NamedSharding(mesh, P(None, None)))
+        assert not pa.causal_attention_serves(sharded, 128)
+        assert pa.causal_attention_serves(whole, 128)
+    auto = jax.make_mesh((4,), ("seq",), devices=jax.devices()[:4], axis_types=(AxisType.Auto,))
+    # an Auto mesh hides the real spec at trace time: stay on XLA
+    assert not pa.causal_attention_serves(
+        jax.device_put(x, NamedSharding(auto, P("seq", None))), 128)
+    assert pa.causal_attention_serves(x, 128)
+
+
+def _pallas_calls(cfg, t, *, grad):
+    p = jax.eval_shape(lambda: _weights(cfg, 0))
+    x = _sds((t, cfg.hidden_size))
+
+    def fn(p_, x_):
+        return jnp.sum(nh.gqa_attention(p_, x_, cfg))
+
+    jaxpr = jax.make_jaxpr(jax.grad(fn) if grad else fn)(p, x)
+    return str(jaxpr).count("pallas_call")
+
+
+@pytest.mark.parametrize("name, t", [("4of2", 4096), ("32of2", 4096), ("4of2", 21), ("32of2", 300)])
+def test_on_a_tpu_gqa_attention_is_the_kernels_whatever_the_length(monkeypatch, name, t):
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    assert _pallas_calls(CONFIGS[name], t, grad=False) == 1
+    assert _pallas_calls(CONFIGS[name], t, grad=True) == 3  # forward, dq, dk / dv
+    jaxpr = str(jax.make_jaxpr(lambda p_, x_: nh.gqa_attention(p_, x_, CONFIGS[name]))(
+        jax.eval_shape(lambda: _weights(CONFIGS[name], 0)), _sds((t, 48))))
+    assert "remat" not in jaxpr  # no query block rematerialised: the kernels recompute
+
+
+@pytest.mark.parametrize("platform, head_dim, dtype", [
+    ("cpu", 128, jnp.float32), ("tpu", 8, jnp.float32), ("tpu", 64, jnp.bfloat16),
+    ("tpu", 128, jnp.float16)])
+def test_elsewhere_gqa_attention_is_the_map_over_query_blocks(monkeypatch, platform, head_dim,
+                                                              dtype):
+    monkeypatch.setattr(pk, "_on_tpu", lambda: platform == "tpu")
+    cfg = nh.NemotronHConfig(hidden_size=48, num_attention_heads=4, num_key_value_heads=2,
+                             head_dim=head_dim, query_block=64)
+    p = jax.eval_shape(lambda: jax.tree_util.tree_map(lambda a: a.astype(dtype),
+                                                      _weights(cfg, 0)))
+    jaxpr = str(jax.make_jaxpr(lambda p_, x_: nh.gqa_attention(p_, x_, cfg))(
+        p, _sds((200, 48), dtype)))
+    assert "pallas_call" not in jaxpr and "scan" in jaxpr and "remat" in jaxpr
+
+
+def test_the_gate_is_a_fact_of_the_call_not_of_an_earlier_trace(monkeypatch):
+    cfg = CONFIGS["4of2"]
+    seen = []
+    for platform in ("cpu", "tpu", "cpu"):
+        monkeypatch.setattr(pk, "_on_tpu", lambda platform=platform: platform == "tpu")
+        seen.append(_pallas_calls(cfg, 256, grad=False))
+    assert seen == [0, 1, 0]
+
+
+def test_the_gate_is_asked_once_a_call(monkeypatch):
+    asked = []
+    monkeypatch.setattr(nh, "causal_attention_serves",
+                        lambda x, head_dim: asked.append((x.shape, head_dim)) or False)
+    cfg = CONFIGS["4of2"]
+    jax.make_jaxpr(lambda p_, x_: nh.gqa_attention(p_, x_, cfg))(
+        jax.eval_shape(lambda: _weights(cfg, 0)), _sds((200, 48)))
+    assert asked == [((200, 48), 128)]
+
+
+def test_no_variable_field_or_argument_chooses_the_route():
+    assert "os.environ" not in inspect.getsource(pa) and "getenv" not in inspect.getsource(pa)
+    assert list(inspect.signature(nh.gqa_attention).parameters) == ["p", "x", "cfg"]
+    assert list(inspect.signature(pa.causal_attention_serves).parameters) == ["x", "head_dim"]
+    assert list(inspect.signature(pa.causal_attention).parameters) == [
+        "q", "k", "v", "kv_heads", "interpret"]
+    # the configuration's attention fields are the four it had (query_block: the XLA route's)
+    fields = [f for f in nh.NemotronHConfig.__dataclass_fields__
+              if "attention" in f or "head" in f or "block" in f or "key_value" in f]
+    assert fields == ["mamba_num_heads", "mamba_head_dim", "num_attention_heads",
+                      "num_key_value_heads", "head_dim", "query_block"]
+
+
+# sha256 of gqa_attention's lowered text (value and gradient under vmap,
+# locations stripped) on the commit before the kernels (6625b24), taken with
+# this file's own function: off a TPU, and for a head_dim the gate refuses,
+# the route is that commit's to the letter.
+_LOC = re.compile(r"\s*loc\([^\n]*\)|#loc[^\n]*\n")
+PARENT_TEXTS = {
+    "head_dim_8": "21a6d49594b68406d4a66da763b7e0b02ff8a7a3aa5d6a53523b04d9a6233bae",
+    "head_dim_128": "22a54f0176361f59a1629b514ab7d600c8a90d7b31241fd67eb488864233c3f5",
+}
+_TEXT_SHAPES = {"head_dim_8": (8, 21), "head_dim_128": (128, 200)}
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_TEXTS))
+def test_off_a_tpu_gqa_attention_lowers_to_the_text_it_had(case):
+    hd, t = _TEXT_SHAPES[case]
+    cfg = nh.NemotronHConfig(hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+                             head_dim=hd, query_block=64)
+    p = {"w_q": jnp.zeros((32, 4 * hd)), "w_k": jnp.zeros((32, 2 * hd)),
+         "w_v": jnp.zeros((32, 2 * hd)), "w_o": jnp.zeros((4 * hd, 32))}
+    x = jnp.zeros((3, t, 32))
+    fn = jax.jit(jax.value_and_grad(
+        lambda p_, x_: jnp.sum(jax.vmap(lambda s: nh.gqa_attention(p_, s, cfg))(x_)), (0, 1)))
+    text = _LOC.sub("", fn.lower(p, x).as_text())
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_TEXTS[case]
+
+
+# -- blocks and pairs -----------------------------------------------------------
+
+
+@pytest.mark.parametrize("widest", [pa._FORWARD_KEY_BLOCK, pa._BACKWARD_KEY_BLOCK])
+@pytest.mark.parametrize("t", [1, 21, 128, 129, 300, 512, 768, 1024, 2048, 4096, 4097, 65536])
+def test_blocks_divide_the_padded_length(t, widest):
+    t_pad, block_q, block_k = pa._blocks(t, widest)
+    assert t <= t_pad < t + 128 and t_pad % block_q == 0 and t_pad % block_k == 0
+    assert block_q in (128, 256) and block_k in (128, 256, 512, 1024) and block_k <= widest
+    assert block_q & (block_q - 1) == 0  # the mask takes a row's position by a bitwise and
+    # forward and backward agree on the padded length: the log-sum-exp is handed over whole
+    assert t_pad == pa._blocks(t, 128)[0]
+    if t == 4096:
+        assert (block_q, block_k) == (256, widest)
+
+
+@pytest.mark.parametrize("key_major", [False, True])
+@pytest.mark.parametrize("block_q, block_k, t", [
+    (128, 128, 384), (256, 512, 1024), (256, 256, 768), (256, 512, 4096), (256, 1024, 4096),
+    (128, 512, 1024), (256, 128, 512)])
+def test_pairs_are_the_pairs_at_or_under_the_diagonal(block_q, block_k, t, key_major):
+    n_q, n_k = t // block_q, t // block_k
+    qs, ks, flags = pa._pairs(n_q, n_k, block_q, block_k, key_major=key_major)
+    walked = list(zip(qs.tolist(), ks.tolist()))
+    needed = {(qi, kj) for qi in range(n_q) for kj in range(n_k)
+              if kj * block_k <= (qi + 1) * block_q - 1}
+    assert len(walked) == len(set(walked)) and set(walked) == needed
+    assert len(needed) < n_q * n_k or n_q == 1 or n_k == 1
+    outer = ks if key_major else qs
+    for block in set(outer.tolist()):
+        run = [i for i, o in enumerate(outer.tolist()) if o == block]
+        assert run == list(range(run[0], run[-1] + 1))  # an outer block's pairs lie together
+        assert [bool(flags[i] & pa._FIRST) for i in run] == [True] + [False] * (len(run) - 1)
+        assert [bool(flags[i] & pa._LAST) for i in run] == [False] * (len(run) - 1) + [True]
+    for (qi, kj), flag in zip(walked, flags.tolist()):
+        wholly_seen = (kj + 1) * block_k - 1 <= qi * block_q
+        assert bool(flag & pa._MASKED) == (not wholly_seen)

@@ -641,6 +641,10 @@ def test_folded_stack_is_what_the_aggregate_is_handed(
     np.testing.assert_array_equal(matrix, want)
     assert np.count_nonzero(matrix[:, d:]) == 0
 
+# the attention kernels' compile: a sequence that is neither the query
+# heads' width (4096) nor a block's, a hidden size that is no block's
+ATTENTION_TOKENS, ATTENTION_HIDDEN = 2048, 384
+
 
 def _write_tpu_texts(out_dir):
     """The optimised text of the one-device step of every
@@ -676,6 +680,27 @@ def _write_tpu_texts(out_dir):
         with open(os.path.join(out_dir, name + ".hlo.txt"), "w", encoding="utf-8") as fh:
             fh.write(text)
 
+    # the block-causal attention kernels at the Nemotron cell's heads
+    # (32 / 2 x 128), value and gradient through gqa_attention under vmap
+    from byzpy_tpu.models import nemotron_h
+
+    pallas_kernels._on_tpu = lambda: True
+    cfg = nemotron_h.NemotronHConfig(hidden_size=ATTENTION_HIDDEN)
+    weights = {"w_q": (ATTENTION_HIDDEN, 4096), "w_k": (ATTENTION_HIDDEN, 256),
+               "w_v": (ATTENTION_HIDDEN, 256), "w_o": (4096, ATTENTION_HIDDEN)}
+    for dtype in ("float32", "bfloat16"):
+        def sds(*shape, dtype=dtype):
+            return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+
+        text = jax.jit(jax.value_and_grad(
+            lambda p, xs: jnp.sum(jax.vmap(lambda s: nemotron_h.gqa_attention(p, s, cfg))(xs)
+                                  .astype(jnp.float32)), argnums=(0, 1))).lower(
+            {name: sds(*shape) for name, shape in weights.items()},
+            sds(1, ATTENTION_TOKENS, ATTENTION_HIDDEN)).compile().as_text()
+        with open(os.path.join(out_dir, f"attention_{dtype}.hlo.txt"), "w",
+                  encoding="utf-8") as fh:
+            fh.write(text)
+
 
 @pytest.fixture(scope="module")
 def tpu_texts(tmp_path_factory):
@@ -697,21 +722,27 @@ def tpu_texts(tmp_path_factory):
         pytest.fail("compiling the toy rounds for a described v5e failed "
                     f"(exit {done.returncode}):\n{done.stderr[-3000:]}")
     texts = {}
-    for name in FOLDED_ROUNDS:
+    for name in [*FOLDED_ROUNDS, "attention_float32", "attention_bfloat16"]:
         with open(os.path.join(out_dir, name + ".hlo.txt"), encoding="utf-8") as fh:
             texts[name] = fh.read()
     return texts
 
 
-def _sublane_matrix_writes(text, d):
-    """The benchmark's own counter (``sublane_matrix_writes.train``)."""
+def _benchmark_reader(metric):
+    """One of the benchmark's own readers (``chipbench/layer_metrics``)."""
     path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                        "chipbench", "layer_metrics", "sublane_matrix_writes.train.py")
-    spec = importlib.util.spec_from_file_location("sublane_matrix_writes_train", path)
+                        "chipbench", "layer_metrics", metric + ".py")
+    spec = importlib.util.spec_from_file_location(metric.replace(".", "_"), path)
     reader = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(reader)
+    return reader
+
+
+def _sublane_matrix_writes(text, d):
+    """The benchmark's own counter (``sublane_matrix_writes.train``)."""
     config = {"n_nodes": N, "n_byzantine": B, "n_parameters": d}
-    return reader.read(SimpleNamespace(outcome={"compiled_text": text}, config=config))
+    return _benchmark_reader("sublane_matrix_writes.train").read(
+        SimpleNamespace(outcome={"compiled_text": text}, config=config))
 
 
 def _entry_instructions(text):
@@ -753,6 +784,58 @@ def test_on_the_tpu_the_sort_kernels_operand_is_the_loops_stack(tpu_texts, agg):
     assert set(path) <= {"fusion", "bitcast", "get-tuple-element"} and "fusion" in path
     # and the result is the flat aggregate: no relayout between kernel and update
     assert " copy(" not in "".join(v[2] for v in entry.values() if "round.update" in v[2])
+
+
+# -- the attention kernels, compiled by Mosaic ----------------------------------
+
+
+def _attention_calls(text):
+    """``{kernel's name: its custom call's line}`` under ``model.attention``."""
+    calls = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(causal_attention_\w+?)(?:\.\d+)? = ", line)
+        if m and "tpu_custom_call" in line:
+            assert "model.attention" in re.search(r'op_name="([^"]*)"', line).group(1)
+            assert m.group(1) not in calls
+            calls[m.group(1)] = line
+    return calls
+
+
+@pytest.mark.parametrize("dtype, narrow", [("float32", "f32"), ("bfloat16", "bf16")])
+def test_on_the_tpu_attention_is_three_mosaic_kernels(tpu_texts, dtype, narrow):
+    text = tpu_texts[f"attention_{dtype}"]
+    calls = _attention_calls(text)
+    assert sorted(calls) == ["causal_attention_dkv", "causal_attention_dq",
+                             "causal_attention_fwd"]
+    t = ATTENTION_TOKENS
+    # q (T, 32 x 128) and k, v (T, 2 x 128) go in as the projections leave them:
+    # no key/value tensor repeated over the sixteen query heads of a group
+    for line in calls.values():
+        operands = line.partition("custom-call(")[2]
+        assert f"{narrow}[{t},4096]" in line and f"{narrow}[{t},256]" in line, operands
+    # one log-sum-exp a query row a head leaves the forward, float32
+    assert f"f32[2,16,{t}]" in calls["causal_attention_fwd"].partition(" custom-call(")[0]
+
+
+def test_on_the_tpu_attention_leaves_no_score_matrix_in_the_program(tpu_texts):
+    """No array of the compiled program has a query and a key dimension:
+    neither the sequence twice nor a query block by the sequence."""
+    text = tpu_texts["attention_float32"]
+    t = ATTENTION_TOKENS
+    shapes = {m for m in re.findall(r"\b(?:f32|bf16|pred|s32)\[([\d,]+)\]", text)}
+    assert shapes
+    for shape in shapes:
+        dims = [int(d) for d in shape.split(",")]
+        assert dims.count(t) <= 1, shape
+        assert not (t in dims and {512, 1024} & set(dims)), shape
+
+
+def test_on_the_tpu_the_benchmarks_counter_reads_the_attention_kernels(tpu_texts):
+    reader = _benchmark_reader("attention_kernel_calls.train")
+    assert reader.read(SimpleNamespace(
+        outcome={"compiled_text": tpu_texts["attention_float32"]})) == 3
+    assert reader.read(SimpleNamespace(
+        outcome={"compiled_text": tpu_texts["trimmed_mean"]})) is None
 
 
 # -- (v) the layout of a row ---------------------------------------------------
