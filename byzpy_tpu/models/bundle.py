@@ -32,9 +32,17 @@ class Segment:
     parameter subtree and ``apply`` its function. Every segment but the
     last maps ``(subtree, h) -> h`` (the first is handed the worker's
     batch ``x``); the last is the loss head, ``(subtree, h, y) -> loss``.
-    With ``aux`` a segment that is not the head returns ``(h, aux)``,
-    ``aux`` a tree of small arrays the round reports per honest worker
-    (an expert layer's token counts) and takes no gradient through."""
+    ``h``, the boundary between two links, is an array or a TREE of
+    arrays: a link that needs what an earlier one made (a second loss
+    term that reads the embedded tokens again) is handed it along the
+    chain, and a link returns untouched, as the same array, whatever it
+    only hands on (a round keeps such an array once). Parameters stay
+    with one link each: a table read on two paths gets both paths'
+    gradient because both cotangents reach its link.
+    With ``aux`` a segment returns ``(h, aux)`` (the head: ``(loss,
+    aux)``), ``aux`` a tree of small arrays the round reports per honest
+    worker (an expert layer's token counts, a loss's terms) and takes no
+    gradient through."""
 
     key: str
     apply: Callable
@@ -50,7 +58,8 @@ def chain_loss(segments: Sequence[Segment]) -> Callable:
             h = seg.apply(params[seg.key], h)
             if seg.aux:
                 h = h[0]
-        return segments[-1].apply(params[segments[-1].key], h, y)
+        loss = segments[-1].apply(params[segments[-1].key], h, y)
+        return loss[0] if segments[-1].aux else loss
 
     return loss_fn
 
@@ -79,8 +88,6 @@ class ModelBundle:
                     "a segmented bundle's params are a dict keyed by its segments' "
                     f"keys (segments {keys}, params {list(self.params)})"
                 )
-            if self.segments[-1].aux:
-                raise ValueError("the loss head returns the loss alone")
             if self.loss_fn is None:
                 self.loss_fn = chain_loss(self.segments)
         if self.loss_fn is None:

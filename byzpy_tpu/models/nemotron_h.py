@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from ..ops.pallas_attention import causal_attention, causal_attention_serves
 from ..parallel.moe import held_experts_ffn
 from .bundle import ModelBundle, Segment
+from .layers import blocked_causal_attention, cross_entropy, rms_norm, token_embedding
 
 Array = jnp.ndarray
 
@@ -80,12 +81,6 @@ class NemotronHConfig:
     @property
     def d_xbc(self) -> int:
         return self.d_inner + 2 * self.n_groups * self.ssm_state_size
-
-
-def rms_norm(x: Array, scale: Array, eps: float) -> Array:
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -213,20 +208,7 @@ def gqa_attention(p: Dict[str, Array], x: Array, cfg: NemotronHConfig) -> Array:
         q = (x @ p["w_q"].astype(x.dtype)).reshape(t, kv, per, hd)
         k = (x @ p["w_k"].astype(x.dtype)).reshape(t, kv, hd)
         v = (x @ p["w_v"].astype(x.dtype)).reshape(t, kv, hd)
-        block = min(cfg.query_block, t)
-        pad = -t % block
-        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0), (0, 0))).reshape(-1, block, kv, per, hd)
-        starts = jnp.arange(q.shape[0]) * block
-
-        @jax.checkpoint
-        def one_block(args):
-            qb, start = args
-            scores = jnp.einsum("qgrd,kgd->grqk", qb, k).astype(jnp.float32) / math.sqrt(hd)
-            seen = (start + jnp.arange(block))[:, None] >= jnp.arange(t)[None, :]
-            probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
-            return jnp.einsum("grqk,kgd->qgrd", probs.astype(x.dtype), v)
-
-        out = jax.lax.map(one_block, (q, starts)).reshape(-1, heads * hd)[:t]
+        out = blocked_causal_attention(q, k, v, cfg.query_block)
         return out @ p["w_o"].astype(x.dtype)
 
 
@@ -262,20 +244,10 @@ def _block(kind: str, cfg: NemotronHConfig, dtype: Any):
     return apply
 
 
-def _embed(dtype: Any):
-    def apply(p, tokens):
-        return p["embedding"][tokens].astype(dtype)
-
-    return apply
-
-
 def _head(cfg: NemotronHConfig, dtype: Any):
     def apply(p, h, targets):
         h = rms_norm(h.astype(dtype), p["norm_scale"], cfg.norm_eps)
-        logits = (h @ p["w_head"].astype(dtype)).astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(lse - picked)
+        return jnp.mean(cross_entropy(h @ p["w_head"].astype(dtype), targets))
 
     return apply
 
@@ -352,7 +324,7 @@ def nemotron_h_bundle(cfg: NemotronHConfig, seed: int = 0, dtype: Any = jnp.floa
     """The segmented bundle: batches are ``x, y: (B, T)`` token ids and
     next tokens. ``dtype`` is the type activations are computed in."""
     keys = segment_keys(cfg)
-    segments = [Segment(keys[0], _embed(dtype))]
+    segments = [Segment(keys[0], token_embedding(dtype))]
     for key, kind in zip(keys[1:-1], cfg.pattern):
         segments.append(Segment(key, _block(kind, cfg, dtype), aux=kind == "E"))
     segments.append(Segment(keys[-1], _head(cfg, dtype)))

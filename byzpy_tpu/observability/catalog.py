@@ -139,8 +139,10 @@ SPAN_PREFIXES: Tuple[str, ...] = ("chaos.",)
 SCOPES: FrozenSet[str] = frozenset(
     {
         "model.attention",
+        "model.mla_latent",
         "model.moe_experts",
         "model.moe_route",
+        "model.mtp",
         "model.ssm_scan",
         "round.aggregate",
         "round.build_matrix",

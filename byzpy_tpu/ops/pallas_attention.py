@@ -19,7 +19,10 @@ transposed or repeated in HBM on the way in or out.
 * The query heads of a group are folded into the rows of the query block,
   ``(H / kv_heads * block_q, head_dim)`` against ``(block_k, head_dim)``, so
   a key/value block is loaded once for all of them and the MXU's weights
-  (the key block) serve sixteen times the rows.
+  (the key block) serve sixteen times the rows. Where a key/value head has
+  ONE query head (latent attention trained in its uncompressed form) there
+  is nothing to fold, and the query block itself is four times as long
+  (:func:`_blocks`).
 * The backward recomputes a pair's probabilities from q, k and the
   log-sum-exp: one kernel for dq (the forward's walk), one for dk and dv
   (key block outermost, the sum over a group's query heads taken inside
@@ -61,11 +64,20 @@ _FIRST, _LAST, _MASKED = 1, 2, 4  # a pair's flags
 # rows of the folded query block that one pass of a step's loop handles:
 # the score tile alive at once is (_CHUNK_ROWS, block_k) float32
 _CHUNK_ROWS = 1024
-# the widest key block of the forward and of the backward's two kernels.
-# Measured on a v5e at 32 / 2 heads x 128, 4096 positions, query block 256
-# (PR 33): forward 2.13 ms at 512, 1.31 at 1024, 1.62 at 2048; dq 1.44 at
-# 512, 1.53 at 1024; dk / dv 1.71 at 512, 3.32 at 1024
-_FORWARD_KEY_BLOCK, _BACKWARD_KEY_BLOCK = 1024, 512
+# The blocks (:func:`_blocks`), measured on a v5e at 4096 positions.
+# Sixteen query heads a group at head_dim 128 (32 / 2 heads, PR 33), query
+# block 256: forward 2.13 ms at key block 512, 1.31 at 1024, 1.62 at 2048;
+# dq 1.44 at 512, 1.53 at 1024; dk / dv 1.71 at 512, 3.32 at 1024. ONE
+# query head a group at head_dim 256 (20 / 20 heads, latent attention, PR
+# 34), query block x key block: forward 2.47 at 256 x 1024, 1.61 at 512 x
+# 1024, 1.45 at 1024 x 1024, 1.69 at 2048 x 1024; dq 2.76 at 256 x 512,
+# 1.93 at 1024 x 512, 1.86 at 1024 x 1024, 2.23 at 2048 x 512; dk / dv 2.88
+# at 256 x 512, 2.48 at 512 x 512, 2.39 at 1024 x 1024, 2.85 at 2048 x 512.
+# Both regimes: a key block meets up to _FOLDED_ROWS rows of queries (a
+# group's heads times the query block), and the backward's kernels, which
+# hold two products' tiles a pair, keep heads x query block x key block
+# within _BACKWARD_TILE.
+_FOLDED_ROWS, _WIDEST_BLOCK, _BACKWARD_TILE = 4096, 1024, 2 ** 21
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
@@ -76,7 +88,8 @@ def causal_attention_serves(x: Array, head_dim: int) -> bool:
     device-sharded (:func:`~byzpy_tpu.ops.pallas_kernels.
     sharding_allows_pallas`). Any length: the wrapper pads it to whole
     blocks. Asked once a call, in Python, by ``models.nemotron_h.
-    gqa_attention``; reads no environment variable."""
+    gqa_attention`` and ``models.glm4_moe_lite.mla_attention``; reads no
+    environment variable."""
     return bool(
         _pk._on_tpu()
         and x.dtype in (jnp.float32, jnp.bfloat16)
@@ -85,16 +98,22 @@ def causal_attention_serves(x: Array, head_dim: int) -> bool:
     )
 
 
-def _blocks(t: int, widest_key_block: int) -> Tuple[int, int, int]:
+def _blocks(t: int, per: int, *, backward: bool) -> Tuple[int, int, int]:
     """``(padded length, block_q, block_k)`` for a sequence of ``t``
-    positions: the length in whole 128s, the query block 256 where that
-    divides it (a power of two: the mask reads a folded row's position
-    with a bitwise and), the key block the widest of 1024 ... 128 that
-    divides it and is no wider than asked."""
+    positions whose key/value heads are read by ``per`` query heads each,
+    for the forward kernel or the backward's two. The length in whole
+    128s; the query block the widest of 1024 ... 128 that divides it and
+    folds, with a group's heads, into at most ``_FOLDED_ROWS`` rows (256
+    for sixteen heads a group, 1024 for one; a power of two: the mask
+    reads a folded row's position with a bitwise and); the key block the
+    widest of 1024 ... 128 that divides it, in the backward within
+    ``_BACKWARD_TILE`` (512 for sixteen heads a group, 1024 for one)."""
     t_pad = _pk._round_up(t, _LANES)
-    block_q = 256 if t_pad % 256 == 0 else 128
-    block_k = next(b for b in (1024, 512, 256, 128)
-                   if b <= widest_key_block and t_pad % b == 0)
+    sizes = (1024, 512, 256, 128)
+    block_q = next(b for b in sizes
+                   if t_pad % b == 0 and (per * b <= _FOLDED_ROWS or b == _LANES))
+    widest = min(_WIDEST_BLOCK, _BACKWARD_TILE // (per * block_q)) if backward else _WIDEST_BLOCK
+    block_k = next(b for b in sizes if t_pad % b == 0 and (b <= widest or b == _LANES))
     return t_pad, block_q, block_k
 
 
@@ -496,7 +515,7 @@ def _padded(t_pad: int, *arrays):
 
 
 def _forward(q, k, v, kv_heads, interpret):
-    t_pad, block_q, block_k = _blocks(q.shape[0], _FORWARD_KEY_BLOCK)
+    t_pad, block_q, block_k = _blocks(q.shape[0], q.shape[1] // k.shape[1], backward=False)
     out, lse = _causal_attention_fwd_call(
         *_padded(t_pad, q, k, v), kv_heads=kv_heads, head_dim=k.shape[1] // kv_heads,
         block_q=block_q, block_k=block_k, interpret=interpret)
@@ -518,9 +537,9 @@ def _causal_attention_bwd(kv_heads, interpret, residuals, d_out):
     with jax.named_scope("model.attention"):
         q, k, v, out, lse = residuals
         t = q.shape[0]
-        t_pad, block_q, block_k = _blocks(t, _BACKWARD_KEY_BLOCK)
         head_dim = k.shape[1] // kv_heads
         per = q.shape[1] // (kv_heads * head_dim)
+        t_pad, block_q, block_k = _blocks(t, per, backward=True)
         # rowsum(d_out * out): what the softmax's Jacobian takes off every row
         delta = jnp.sum(
             (d_out.astype(jnp.float32) * out.astype(jnp.float32)).reshape(

@@ -113,26 +113,30 @@ def moe_ffn(
     return jnp.einsum("ecd,tec->td", out_blocks, combine)
 
 
-def _expert(h_in: Array, up: Array, down: Array) -> Array:
-    """``down relu(up h_in)^2``."""
-    hidden = jnp.square(jax.nn.relu(h_in @ up.astype(h_in.dtype)))
+def _expert(h_in: Array, up: Array, down: Array, gated: Optional[Array] = None) -> Array:
+    """``down relu(up h_in)^2``, or with a third matrix
+    ``down (silu(gated h_in) * up h_in)``."""
+    if gated is None:
+        hidden = jnp.square(jax.nn.relu(h_in @ up.astype(h_in.dtype)))
+    else:
+        hidden = jax.nn.silu(h_in @ gated.astype(h_in.dtype)) * (h_in @ up.astype(h_in.dtype))
     return hidden @ down.astype(h_in.dtype)
 
 
-def _expert_round(x, w_up, w_down, gate, rank, routed, r, rows):
+def _expert_round(x, weights, gate, rank, routed, r, rows):
     """Round ``r`` of the held experts' part: every expert multiplies the
     ``rows`` of its tokens whose rank among them is in ``[r rows, (r + 1)
     rows)``. Returns the round's share of ``out (T, D)`` and how many
     (token, expert) pairs it computed."""
     tokens, d = x.shape
-    held = w_up.shape[0]
+    held = weights[0].shape[0]
     local = rank - r * rows
     mine = routed & (local >= 0) & (local < rows)  # (T, held): this round's pairs
     slot = jnp.where(mine, jnp.arange(held)[None, :] * rows + local, held * rows)
     token_at = jnp.zeros((held * rows,), jnp.int32).at[slot.reshape(-1)].set(
         jnp.repeat(jnp.arange(tokens, dtype=jnp.int32), held), mode="drop")
     per_expert = jax.vmap(_expert)(
-        x[token_at].reshape(held, rows, d), w_up, w_down).reshape(held * rows, d)
+        x[token_at].reshape(held, rows, d), *weights).reshape(held * rows, d)
     # back to the tokens: each reads its slots (a slot past the end reads
     # zero; a slot no token fills is read by none)
     read = jnp.take(per_expert, slot, axis=0, mode="fill", fill_value=0)  # (T, held, D)
@@ -140,13 +144,15 @@ def _expert_round(x, w_up, w_down, gate, rank, routed, r, rows):
             jnp.sum(mine, dtype=jnp.int32))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _expert_rounds(x, w_up, w_down, gate, rank, routed, rows):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _expert_rounds(x, weights, gate, rank, routed, rows):
     """The held experts' part of every routed token: round 0, then as many
-    more as the fullest expert needs. The count of rounds is read from the
-    batch, so the loop is a ``while`` with a backward pass of its own (the
-    same rounds again, each one's vector-Jacobian product added up)."""
-    return _expert_rounds_fwd(x, w_up, w_down, gate, rank, routed, rows)[0]
+    more as the fullest expert needs. ``weights`` are the experts' stacked
+    matrices as :func:`_expert` takes them (up, down, and the gated
+    experts' third). The count of rounds is read from the batch, so the
+    loop is a ``while`` with a backward pass of its own (the same rounds
+    again, each one's vector-Jacobian product added up)."""
+    return _expert_rounds_fwd(x, weights, gate, rank, routed, rows)[0]
 
 
 def _rounds_needed(routed, rows):
@@ -154,26 +160,26 @@ def _rounds_needed(routed, rows):
     return jnp.maximum(1, -(-fullest // rows))
 
 
-def _expert_rounds_fwd(x, w_up, w_down, gate, rank, routed, rows):
+def _expert_rounds_fwd(x, weights, gate, rank, routed, rows):
     rounds = _rounds_needed(routed, rows)
 
     def more(r, acc):
-        out, computed = _expert_round(x, w_up, w_down, gate, rank, routed, r, rows)
+        out, computed = _expert_round(x, weights, gate, rank, routed, r, rows)
         return acc[0] + out, acc[1] + computed
 
     out, computed = lax.fori_loop(
-        1, rounds, more, _expert_round(x, w_up, w_down, gate, rank, routed, 0, rows))
-    return (out, computed, rounds), (x, w_up, w_down, gate, rank, routed)
+        1, rounds, more, _expert_round(x, weights, gate, rank, routed, 0, rows))
+    return (out, computed, rounds), (x, weights, gate, rank, routed)
 
 
 def _expert_rounds_bwd(rows, kept, cotangents):
-    x, w_up, w_down, gate, rank, routed = kept
+    x, weights, gate, rank, routed = kept
     g = cotangents[0]  # the pairs computed and the rounds are counts
 
     def pulled(r):
         return jax.vjp(
             lambda *wrt: _expert_round(*wrt, rank, routed, r, rows)[0],
-            x, w_up, w_down, gate)[1](g)
+            x, weights, gate)[1](g)
 
     def more(r, acc):
         return jax.tree_util.tree_map(jnp.add, acc, pulled(r))
@@ -197,6 +203,8 @@ def held_experts_ffn(
     top_k: int,
     scale: float = 1.0,
     round_rows: Optional[int] = None,
+    w_gate: Optional[Array] = None,
+    shared_gate: Optional[Array] = None,
 ):
     """One chip's part of an expert layer whose experts are spread over
     chips: it is told which experts it holds, routes over all of them,
@@ -207,7 +215,10 @@ def held_experts_ffn(
     ``first_held .. first_held + held - 1``. Routing (DeepSeek-V3 /
     Nemotron-H style): ``s = sigmoid(x router_w)``, the ``top_k`` largest
     a token, their ``s`` normalised to sum 1 and times ``scale``. Expert:
-    ``w_down relu(w_up x)^2``. A token's routed part is the weighted sum
+    ``w_down relu(w_up x)^2``, or, where the experts come with a third
+    stacked matrix ``w_gate (held, D, F)`` (and the shared expert with
+    ``shared_gate (D, Fs)``), ``w_down (silu(w_gate x) * w_up x)``: which of
+    the two is read off the weights given. A token's routed part is the weighted sum
     over those of its ``top_k`` that are held here; what the absent
     experts would add is another chip's part. The shared expert
     (``shared_up (D, Fs)``, ``shared_down (Fs, D)``), where given, is
@@ -250,9 +261,10 @@ def held_experts_ffn(
         # a token's place among its expert's tokens
         rank = jnp.cumsum(routed, axis=0, dtype=jnp.int32) - 1
     with jax.named_scope("model.moe_experts"):
-        out, computed, rounds = _expert_rounds(x, w_up, w_down, gate, rank, routed, rows)
+        weights = (w_up, w_down) if w_gate is None else (w_up, w_down, w_gate)
+        out, computed, rounds = _expert_rounds(x, weights, gate, rank, routed, rows)
         if shared_up is not None:
-            out = out + _expert(x, shared_up, shared_down)
+            out = out + _expert(x, shared_up, shared_down, shared_gate)
     aux = {
         "held_expert_tokens": counts,
         "tokens_dropped": jnp.sum(counts) - computed,

@@ -241,14 +241,33 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
 
     def train_step(params, opt_state, xs, ys, key):
         xs_h, ys_h = xs[:h], ys[:h]
+        # A boundary is an array or a tree of arrays. An array that a segment
+        # returns untouched (the very array it was handed) is kept ONCE, under
+        # the boundary that made it: `made` lists the arrays the chain makes
+        # (the boundary each comes from), `wires` which of them each
+        # boundary's leaves are. Both are read off the chain as it is traced.
+        wiring = {}
 
         def forward_of(x):
-            outs, auxes = [], {}
-            for seg in segs[:last]:
+            outs, auxes, before = [], {}, {}
+            made, wires = [], []
+            for at, seg in enumerate(segs[:last]):
                 x = seg.apply(params[seg.key], x)
                 if seg.aux:
                     x, auxes[seg.key] = x
-                outs.append(x)
+                leaves, treedef = jax.tree_util.tree_flatten(x)
+                here = {}
+                for leaf in leaves:
+                    if id(leaf) not in before:
+                        made.append(at)
+                        outs.append(leaf)
+                    here[id(leaf)] = before.get(id(leaf), len(outs) - 1)
+                if len(here) != len(leaves):
+                    raise ValueError(
+                        f"segment {seg.key!r} returns one array twice in its boundary")
+                wires.append((treedef, [here[id(leaf)] for leaf in leaves]))
+                before = here
+            wiring.update(made=made, wires=wires)
             return outs, auxes
 
         # round.fwdbwd is the innermost round.* scope of everything the
@@ -261,21 +280,35 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                 return jax.tree_util.tree_map(
                     lambda stack, value: put(stack, value, i), carry, forward_of(xs_h[i]))
 
-            bounds, auxes = jax.lax.fori_loop(0, h, one_forward, (
+            vals, auxes = jax.lax.fori_loop(0, h, one_forward, (
                 [jax.lax.empty((h, *folded(leaf.shape)), leaf.dtype) for leaf in kept[0]],
                 jax.tree_util.tree_map(
                     lambda leaf: jnp.zeros((h, *leaf.shape), leaf.dtype), kept[1])))
+        made, wires = wiring["made"], wiring["wires"]
+
+        def boundary(k, stack_of, i):
+            """Boundary ``k`` of worker ``i``, from the stack ``stack_of(j)``
+            that holds its array ``j``."""
+            treedef, slots = wires[k]
+            return jax.tree_util.tree_unflatten(
+                treedef, [stack_of(j)[i].reshape(kept[0][j].shape) for j in slots])
 
         new_params, new_opt = {}, {}
         sum_sq = jnp.zeros((), jnp.float32)
-        losses = cot = None
+        losses = head_aux = None
+        # what flows back: the cotangent of every kept array, once a segment
+        # that reads the array has run backwards
+        cots = [None] * len(vals)
         # A segment's leaves are handed to its loop through the barrier that
         # closes the segment after it (the head's: through one with the last
         # boundary). Whatever the compiler derives from them alone (a
         # weight's transposed copy, hoisted out of the loop) then cannot be
         # made before that point, and so not for all segments at once.
-        sub, bounds[last - 1] = jax.lax.optimization_barrier(
-            (params[segs[last].key], bounds[last - 1]))
+        reads = wires[last - 1][1]
+        sub, held_back = jax.lax.optimization_barrier(
+            (params[segs[last].key], [vals[j] for j in reads]))
+        for j, stack in zip(reads, held_back):
+            vals[j] = stack
         for k in range(last, -1, -1):
             seg, layout = segs[k], layouts[k]
             width = layout.width
@@ -283,14 +316,25 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
             lanes = row_shape[1:]
             lane = math.prod(lanes)
             rows_dtype = grad_dtype if grad_dtype is not None else layout.dtype
+            reads = wires[k - 1][1] if k else []
+            # An array this segment's input holds was made by the segment
+            # before it, or handed on by it. The first is read here for the
+            # last time: its cotangent is written over it. The second is still
+            # to be read by earlier segments, so its cotangent has a stack of
+            # its own (from the first segment, going backwards, that reads it).
+            over = [made[j] == k - 1 for j in reads]
+            at = {j: place for place, j in enumerate(reads)}
+            io = [vals[j] if last_read else
+                  cots[j] if cots[j] is not None else jax.lax.empty(vals[j].shape, vals[j].dtype)
+                  for j, last_read in zip(reads, over)]
 
-            def one_backward(i, carry, k=k, seg=seg, layout=layout, sub=sub, cot=cot,
+            def one_backward(i, carry, k=k, seg=seg, layout=layout, sub=sub, at=at,
+                             over=over, cots=tuple(cots), vals=tuple(vals),
                              lanes=lanes, lane=lane):
-                # worker i's input to this segment is read from the stack of
+                # worker i's input to this segment is read from the stacks of
                 # boundaries kept, and the cotangent of that input is written
                 # over it: after the loop the stack holds what the segment
                 # before this one pulls back, and nothing else was allocated
-                inputs = carry["io"] if k else xs_h
                 with jax.named_scope("round.segment_recompute"), jax.named_scope("round.fwdbwd"):
                     if k == last:
                         def apply(p, x):
@@ -301,13 +345,18 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                     else:
                         apply = seg.apply
                     if k:
-                        out, pullback = jax.vjp(
-                            apply, sub, inputs[i].reshape(kept[0][k - 1].shape))
+                        x = boundary(
+                            k - 1, lambda j: carry["io"][at[j]] if over[at[j]] else vals[j], i)
+                        out, pullback, *aux = jax.vjp(
+                            apply, sub, x, has_aux=seg.aux and k == last)
                     else:  # the batch itself: nothing flows back into it
-                        out, pullback = jax.vjp(lambda p: apply(p, inputs[i]), sub)
+                        out, pullback = jax.vjp(lambda p: apply(p, xs_h[i]), sub)
                 with jax.named_scope("round.segment_bwd"), jax.named_scope("round.fwdbwd"):
+                    # what an array handed on pulls back so far stands in the
+                    # stack this loop writes, not in the one it started from
                     pulled = pullback(
-                        jnp.ones_like(out) if k == last else cot[i].reshape(out.shape))
+                        jnp.ones_like(out) if k == last else boundary(k, lambda j: (
+                            carry["io"][at[j]] if j in at and not over[at[j]] else cots[j]), i))
                     grads = carry["rows"]
                     for first, piece in zip(layout.offsets, layout.place(pulled[0], grad_dtype)):
                         grads = jax.lax.dynamic_update_slice(
@@ -315,20 +364,32 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                             (i, first // lane, *(0 for _ in lanes)))
                     carry = dict(carry, rows=grads)
                     if k:
-                        carry["io"] = put(inputs, pulled[1], i)
+                        carry["io"] = [put(stack, leaf, i) for stack, leaf in zip(
+                            carry["io"], jax.tree_util.tree_leaves(pulled[1]))]
                     if k == last:
                         carry["losses"] = put(carry["losses"], out, i)
+                        if aux:
+                            carry["head_aux"] = jax.tree_util.tree_map(
+                                lambda stack, value: put(stack, value, i),
+                                carry["head_aux"], aux[0])
                 return carry
 
             carry = {"rows": jax.lax.empty((n, *row_shape), rows_dtype)}
             if k:
-                carry["io"] = bounds[k - 1]
+                carry["io"] = io
             if k == last:
                 loss0 = jax.eval_shape(
-                    lambda x, seg=seg, sub=sub: seg.apply(sub, x, ys_h[0]), kept[0][k - 1])
+                    lambda x, seg=seg, sub=sub: seg.apply(sub, x, ys_h[0]),
+                    jax.tree_util.tree_unflatten(
+                        wires[k - 1][0], [kept[0][j] for j in wires[k - 1][1]]))
+                if seg.aux:
+                    loss0, aux0 = loss0
+                    carry["head_aux"] = jax.tree_util.tree_map(
+                        lambda leaf: jnp.zeros((h, *leaf.shape), leaf.dtype), aux0)
                 carry["losses"] = jnp.zeros((h,), loss0.dtype)
             carry = jax.lax.fori_loop(0, h, one_backward, carry)
             losses = carry.get("losses", losses)
+            head_aux = carry.get("head_aux", head_aux)
             with jax.named_scope("round.build_matrix"):
                 stack = carry["rows"]
                 if b:
@@ -349,12 +410,16 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                     # the segment before this one starts from the cotangents
                     # only once this one's leaves are updated: its rows are
                     # dead by then, and the next rows take their place
-                    done, cot, next_sub = jax.lax.optimization_barrier(
+                    done, flowing, next_sub = jax.lax.optimization_barrier(
                         (done, carry["io"], params[segs[k - 1].key]))
+                    for j, stack in zip(reads, flowing):
+                        cots[j] = stack
                 new_params[seg.key], new_opt[seg.key] = done
                 sub = next_sub if k else None
         with jax.named_scope("round.update"):
             metrics = {"honest_loss": jnp.mean(losses), "agg_grad_norm": jnp.sqrt(sum_sq)}
+            if head_aux is not None:
+                auxes = dict(auxes, **{segs[last].key: head_aux})
             if auxes:
                 metrics["segment_aux"] = auxes
         order = list(params)

@@ -137,7 +137,7 @@ def test_forward_writes_one_log_sum_exp_a_query_row_a_head(name, t):
     kv, per = cfg.num_key_value_heads, cfg.num_attention_heads // cfg.num_key_value_heads
     q, k, v, _ = _qkv(cfg, t, seed=1)
     out, lse = pa._forward(q, k, v, kv, True)
-    t_pad = pa._blocks(t, 512)[0]
+    t_pad = pa._blocks(t, per, backward=False)[0]
     assert lse.shape == (kv, per, t_pad) and lse.dtype == jnp.float32
     _close(lse[..., :t], _full_scores(q, k, v, kv)[1])
     assert out.shape == q.shape
@@ -207,8 +207,9 @@ def test_blocks_above_the_diagonal_may_be_nan(name, t):
     cfg = CONFIGS[name]
     kv = cfg.num_key_value_heads
     q, k, v, probe = _qkv(cfg, t, seed=3)
-    _, block_q, block_k = pa._blocks(t, pa._FORWARD_KEY_BLOCK)  # the wider of the two
-    assert block_k >= pa._blocks(t, pa._BACKWARD_KEY_BLOCK)[2]
+    per = cfg.num_attention_heads // kv
+    _, block_q, block_k = pa._blocks(t, per, backward=False)  # the wider of the two
+    assert block_k >= pa._blocks(t, per, backward=True)[2]
 
     def grads(q_, k_, probe_):
         return jax.grad(lambda *a: jnp.sum(pa.causal_attention(*a, kv_heads=kv) * probe_),
@@ -388,17 +389,25 @@ def test_off_a_tpu_gqa_attention_lowers_to_the_text_it_had(case):
 # -- blocks and pairs -----------------------------------------------------------
 
 
-@pytest.mark.parametrize("widest", [pa._FORWARD_KEY_BLOCK, pa._BACKWARD_KEY_BLOCK])
+@pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize("per", [16, 1, 2])
 @pytest.mark.parametrize("t", [1, 21, 128, 129, 300, 512, 768, 1024, 2048, 4096, 4097, 65536])
-def test_blocks_divide_the_padded_length(t, widest):
-    t_pad, block_q, block_k = pa._blocks(t, widest)
+def test_blocks_divide_the_padded_length(t, per, backward):
+    t_pad, block_q, block_k = pa._blocks(t, per, backward=backward)
     assert t <= t_pad < t + 128 and t_pad % block_q == 0 and t_pad % block_k == 0
-    assert block_q in (128, 256) and block_k in (128, 256, 512, 1024) and block_k <= widest
+    assert block_q in (128, 256, 512, 1024) and block_k in (128, 256, 512, 1024)
     assert block_q & (block_q - 1) == 0  # the mask takes a row's position by a bitwise and
-    # forward and backward agree on the padded length: the log-sum-exp is handed over whole
-    assert t_pad == pa._blocks(t, 128)[0]
+    assert per * block_q <= pa._FOLDED_ROWS
+    if backward:
+        assert per * block_q * block_k <= pa._BACKWARD_TILE
+    # forward and backward agree on the padded length and on the query block: the
+    # log-sum-exp is handed over whole
+    assert (t_pad, block_q) == pa._blocks(t, per, backward=not backward)[:2]
     if t == 4096:
-        assert (block_q, block_k) == (256, widest)
+        # sixteen heads a group (Nemotron, PR 33): 256 x 1024 forward, 256 x 512 backward;
+        # one head a group (latent attention, PR 34): 1024 x 1024 in all three kernels
+        want = {16: (256, 512 if backward else 1024), 1: (1024, 1024), 2: (1024, 1024)}[per]
+        assert (block_q, block_k) == want
 
 
 @pytest.mark.parametrize("key_major", [False, True])

@@ -701,6 +701,22 @@ def _write_tpu_texts(out_dir):
                   encoding="utf-8") as fh:
             fh.write(text)
 
+    # the same kernels in latent attention's regime (20 key/value heads of 256,
+    # one query head each), value and gradient through mla_attention under vmap
+    from byzpy_tpu.models import glm4_moe_lite
+
+    mla = glm4_moe_lite.Glm4MoeLiteConfig(hidden_size=ATTENTION_HIDDEN)
+    shapes = jax.eval_shape(lambda: glm4_moe_lite.init_params(mla)["seg02_moe"])
+    text = jax.jit(jax.value_and_grad(
+        lambda p, xs: jnp.sum(jax.vmap(lambda s: glm4_moe_lite.mla_attention(p, s, mla))(xs)),
+        argnums=(0, 1))).lower(
+        described(shapes), jax.ShapeDtypeStruct(
+            (1, ATTENTION_TOKENS, ATTENTION_HIDDEN), jnp.float32, sharding=one_chip)
+    ).compile().as_text()
+    with open(os.path.join(out_dir, "mla_attention_float32.hlo.txt"), "w",
+              encoding="utf-8") as fh:
+        fh.write(text)
+
 
 @pytest.fixture(scope="module")
 def tpu_texts(tmp_path_factory):
@@ -722,7 +738,8 @@ def tpu_texts(tmp_path_factory):
         pytest.fail("compiling the toy rounds for a described v5e failed "
                     f"(exit {done.returncode}):\n{done.stderr[-3000:]}")
     texts = {}
-    for name in [*FOLDED_ROUNDS, "attention_float32", "attention_bfloat16"]:
+    for name in [*FOLDED_ROUNDS, "attention_float32", "attention_bfloat16",
+                 "mla_attention_float32"]:
         with open(os.path.join(out_dir, name + ".hlo.txt"), encoding="utf-8") as fh:
             texts[name] = fh.read()
     return texts
@@ -815,6 +832,22 @@ def test_on_the_tpu_attention_is_three_mosaic_kernels(tpu_texts, dtype, narrow):
         assert f"{narrow}[{t},4096]" in line and f"{narrow}[{t},256]" in line, operands
     # one log-sum-exp a query row a head leaves the forward, float32
     assert f"f32[2,16,{t}]" in calls["causal_attention_fwd"].partition(" custom-call(")[0]
+
+
+def test_on_the_tpu_latent_attention_is_the_same_three_kernels_one_query_head_a_group(tpu_texts):
+    calls = _attention_calls(tpu_texts["mla_attention_float32"])
+    assert sorted(calls) == ["causal_attention_dkv", "causal_attention_dq",
+                             "causal_attention_fwd"]
+    t = ATTENTION_TOKENS
+    # q, k and v all go in as (T, 20 x 256): every head its own key/value head
+    for line in calls.values():
+        assert line.count(f"f32[{t},5120]") >= 3
+    assert f"f32[20,1,{t}]" in calls["causal_attention_fwd"].partition(" custom-call(")[0]
+    # the latent projections, norms and rotary turns stand in their own scope
+    assert "model.mla_latent" in tpu_texts["mla_attention_float32"]
+    reader = _benchmark_reader("attention_kernel_calls.train")
+    assert reader.read(SimpleNamespace(
+        outcome={"compiled_text": tpu_texts["mla_attention_float32"]})) == 3
 
 
 def test_on_the_tpu_attention_leaves_no_score_matrix_in_the_program(tpu_texts):

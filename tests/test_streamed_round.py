@@ -107,6 +107,111 @@ def test_streamed_round_equals_the_n_by_d_round(agg, attack):
         assert got["segment_aux"]["s1_mid"]["positive"].shape == (N - b,)
 
 
+# -- boundaries that are trees ----------------------------------------------
+
+
+def _tree_segments():
+    """A chain whose boundaries are trees, as a model with a second loss term
+    needs them: the first link's output is handed on beside the stream (the
+    very array, untouched) to a side link that reads it again, and the head
+    applies its one matrix to both streams and reports both terms."""
+    def dense(p, x):
+        return jnp.tanh(x @ p["kernel"] + p["bias"])
+
+    def start(p, e):  # an array in, the pair (stream, e) out
+        y = dense(p, e)
+        return (y, e), {"positive": jnp.sum(y > 0)}
+
+    def middle(p, pair):
+        h, e = pair
+        return {"stream": dense(p, h), "kept": e}  # a dict is a tree too
+
+    def side(p, tree):  # hands the stream on untouched and reads both
+        h, e = tree["stream"], tree["kept"]
+        return h, dense(p, jnp.concatenate([h, jnp.roll(e, -1, axis=0)], axis=-1))
+
+    def head(p, pair, y):
+        def term(x):
+            logits = x @ p["kernel"] + p["bias"]
+            return optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
+
+        first, second = term(pair[0]), term(pair[1])
+        return first + 0.3 * second, {"first": first, "second": second}
+
+    return (Segment("t0_in", dense), Segment("t1_start", start, aux=True),
+            Segment("t2_mid", middle), Segment("t3_side", side),
+            Segment("t4_head", head, aux=True))
+
+
+def _tree_params(seed=0):
+    sizes = {"t0_in": (12, WIDTH), "t1_start": (WIDTH, WIDTH), "t2_mid": (WIDTH, 40),
+             "t3_side": (40 + WIDTH, 40), "t4_head": (40, CLASSES)}
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(sizes))
+    return {name: {"kernel": jax.random.normal(k, size) / np.sqrt(size[0]),
+                   "bias": jnp.full((size[1],), 0.01)}
+            for (name, size), k in zip(sizes.items(), keys)}
+
+
+@pytest.mark.parametrize("attack", ["signflip", "none"])
+@pytest.mark.parametrize("agg", ["trimmed", "median", "mean"])
+def test_streamed_round_with_tree_boundaries_equals_the_n_by_d_round(agg, attack):
+    b, attack_fn = ATTACKS[attack]
+    cfg = PSStepConfig(n_nodes=N, n_byzantine=b, learning_rate=0.1, momentum=0.9)
+    segs = _tree_segments()
+    results = []
+    for bundle in (ModelBundle(apply_fn=None, params=_tree_params(), segments=segs),
+                   ModelBundle(apply_fn=None, params=_tree_params(), loss_fn=chain_loss(segs))):
+        step, opt = build_ps_train_step(bundle, AGGREGATES[agg], cfg, attack=attack_fn)
+        step = jax.jit(step)
+        params, seen = bundle.params, []
+        for i, (xs, ys) in enumerate(_batches()):
+            params, opt, metrics = step(params, opt, xs, ys, jax.random.PRNGKey(i))
+            seen.append(metrics)
+        results.append((params, opt, seen))
+    (p_s, o_s, m_s), (p_w, o_w, m_w) = results
+    for got, want in zip(jax.tree_util.tree_leaves((p_s, o_s)),
+                         jax.tree_util.tree_leaves((p_w, o_w))):
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    for got, want in zip(m_s, m_w):
+        np.testing.assert_allclose(got["honest_loss"], want["honest_loss"], rtol=1e-6)
+        np.testing.assert_allclose(got["agg_grad_norm"], want["agg_grad_norm"], rtol=1e-5)
+        terms = got["segment_aux"]["t4_head"]
+        assert terms["first"].shape == terms["second"].shape == (N - b,)
+        np.testing.assert_allclose(jnp.mean(terms["first"] + 0.3 * terms["second"]),
+                                   got["honest_loss"], rtol=1e-6)
+        assert got["segment_aux"]["t1_start"]["positive"].shape == (N - b,)
+
+
+def test_an_array_handed_on_untouched_is_kept_once():
+    """The first link's output rides beside the stream through two more
+    links: the step keeps it in ONE stack of h rows, not one a boundary."""
+    cfg = PSStepConfig(n_nodes=N, n_byzantine=B)
+    bundle = ModelBundle(apply_fn=None, params=_tree_params(), segments=_tree_segments())
+    step, opt = build_ps_train_step(bundle, AGGREGATES["trimmed"], cfg, attack=SIGN_FLIP)
+    xs, ys = _batches()[0]
+    jaxpr = jax.make_jaxpr(step)(bundle.params, opt, xs, ys, jax.random.PRNGKey(0))
+    forward = next(eqn for eqn in jaxpr.eqns if eqn.primitive.name in ("while", "scan"))
+    kept = [v.aval.shape for v in forward.outvars if len(v.aval.shape) > 1
+            and v.aval.shape[0] == N - B and v.aval.dtype == jnp.float32]
+    # e (BATCH x WIDTH), the three streams after it (WIDTH, 40, 40): four stacks
+    sizes = sorted(int(np.prod(shape[1:])) for shape in kept)
+    assert sizes == sorted([BATCH * WIDTH, BATCH * WIDTH, BATCH * 40, BATCH * 40])
+
+
+def test_a_boundary_that_holds_one_array_twice_is_refused():
+    def twice(p, x):
+        y = jnp.tanh(x @ p["kernel"] + p["bias"])
+        return y, y
+
+    segs = (Segment("s0_in", twice),) + _segments()[1:]
+    bundle = ModelBundle(apply_fn=None, params=_params(), segments=segs)
+    step, opt = build_ps_train_step(bundle, AGGREGATES["trimmed"],
+                                    PSStepConfig(n_nodes=N, n_byzantine=B), attack=SIGN_FLIP)
+    xs, ys = _batches()[0]
+    with pytest.raises(ValueError, match="one array twice"):
+        jax.make_jaxpr(step)(bundle.params, opt, xs, ys, jax.random.PRNGKey(0))
+
+
 def test_streamed_round_with_an_optimizer_marked_leafwise_equals_the_n_by_d_round():
     cfg = PSStepConfig(n_nodes=N, n_byzantine=B)
     streamed, whole = _bundles()
@@ -243,6 +348,42 @@ def test_a_bundle_without_segments_lowers_to_the_text_it_had(cell):
     assert _unsegmented_texts()[cell] == PARENT_TEXTS[cell]
 
 
+# -- a bundle whose boundaries are single arrays streams the program it streamed ----
+
+def _streamed_texts():
+    """The streamed step of the toy segmented bundle and of a toy Nemotron-H
+    bundle (every boundary one array), lowered."""
+    from byzpy_tpu.models import nemotron_h as nh
+
+    cfg = PSStepConfig(n_nodes=N, n_byzantine=B)
+    out = {}
+    streamed, _ = _bundles()
+    step, opt = build_ps_train_step(streamed, AGGREGATES["trimmed"], cfg, attack=SIGN_FLIP)
+    xs, ys = _batches()[0]
+    out["toy-segments"] = jax.jit(step).lower(
+        streamed.params, opt, xs, ys, jax.random.PRNGKey(0)).as_text()
+    bundle = nh.nemotron_h_bundle(_toy_nemotron(), seed=0)
+    step, opt = build_ps_train_step(bundle, AGGREGATES["trimmed"], cfg, attack=SIGN_FLIP)
+    tokens = jnp.zeros((N, 1, 19), jnp.int32)
+    out["toy-nemotron"] = jax.jit(step).lower(
+        bundle.params, opt, tokens, tokens, jax.random.PRNGKey(1)).as_text()
+    return {name: hashlib.sha256(_canonical(text).encode()).hexdigest()
+            for name, text in out.items()}
+
+
+# sha256 of the canonical lowered text, taken on the commit before boundaries
+# became trees (c854b01) with this file's own function.
+PARENT_STREAMED_TEXTS = {
+    "toy-segments": "fef5d5b8f8531f18c4a22b78b0228563705fa3cc64282f66187af19329f058ed",
+    "toy-nemotron": "fac038ac17422d8454755f306a1c408a210d10897ffc2015d3a95d35db893989",
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT_STREAMED_TEXTS))
+def test_single_array_boundaries_stream_the_text_they_streamed(cell):
+    assert _streamed_texts()[cell] == PARENT_STREAMED_TEXTS[cell]
+
+
 # -- the streamed round's scopes: catalogued, held by byzlint, in the text ---
 
 _STREAM_SCOPES = ["round.segment_fwd", "round.segment_recompute", "round.segment_bwd",
@@ -250,17 +391,22 @@ _STREAM_SCOPES = ["round.segment_fwd", "round.segment_recompute", "round.segment
 _MODEL_SCOPES = ["model.ssm_scan", "model.attention", "model.moe_route", "model.moe_experts"]
 
 
-@pytest.fixture(scope="module")
-def streamed_op_names():
+def _toy_nemotron():
     from byzpy_tpu.models import nemotron_h as nh
 
-    cfg = nh.NemotronHConfig(
+    return nh.NemotronHConfig(
         hidden_size=32, pattern="M*E", vocab_size=64, mamba_num_heads=4, mamba_head_dim=8,
         ssm_state_size=16, n_groups=2, chunk_size=8, num_attention_heads=4,
         num_key_value_heads=2, head_dim=8, query_block=8, n_routed_experts=16,
         num_experts_per_tok=3, moe_intermediate_size=24,
         moe_shared_expert_intermediate_size=40, held_experts=(4, 4))
-    bundle = nh.nemotron_h_bundle(cfg, seed=0)
+
+
+@pytest.fixture(scope="module")
+def streamed_op_names():
+    from byzpy_tpu.models import nemotron_h as nh
+
+    bundle = nh.nemotron_h_bundle(_toy_nemotron(), seed=0)
     step, opt = build_ps_train_step(bundle, AGGREGATES["trimmed"],
                                     PSStepConfig(n_nodes=N, n_byzantine=B), attack=SIGN_FLIP)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (N, 1, 19), 0, 64)
@@ -276,6 +422,39 @@ def test_streamed_step_holds_each_scope_and_the_catalog_lists_it(streamed_op_nam
     assert scope in catalog.SCOPES
     assert any(f"/{scope}/" in name + "/" or f"({scope})" in name or f"({scope}/" in name
                for name in streamed_op_names)
+
+
+@pytest.fixture(scope="module")
+def tree_streamed_op_names():
+    """The streamed step of a toy GLM-4.7-Flash bundle (tree boundaries)."""
+    from byzpy_tpu.models import glm4_moe_lite as glm
+
+    bundle = glm.glm47_flash_ep8(
+        0, hidden_size=32, num_hidden_layers=2, vocab_size=64, num_attention_heads=2,
+        q_lora_rank=16, kv_lora_rank=12, qk_nope_head_dim=6, qk_rope_head_dim=2, v_head_dim=8,
+        query_block=8, intermediate_size=48, n_routed_experts=16, num_experts_per_tok=3,
+        moe_intermediate_size=24, held_experts=(4, 4))
+    step, opt = build_ps_train_step(bundle, AGGREGATES["trimmed"],
+                                    PSStepConfig(n_nodes=N, n_byzantine=B), attack=SIGN_FLIP)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (N, 1, 19), 0, 64)
+    text = jax.jit(step).lower(bundle.params, opt, tokens, tokens,
+                               jax.random.PRNGKey(1)).compile().as_text()
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+@pytest.mark.parametrize("scope", ["model.attention", "model.mla_latent", "model.mtp",
+                                   "model.moe_route", "model.moe_experts"])
+def test_tree_streamed_step_holds_each_model_scope_in_all_three_passes(
+        tree_streamed_op_names, scope):
+    from byzpy_tpu.observability import catalog
+
+    assert scope in catalog.SCOPES
+    for a_pass in ("round.segment_fwd", "round.segment_recompute", "round.segment_bwd"):
+        assert any(a_pass in name and scope in name for name in tree_streamed_op_names), a_pass
+    # the latent's scope stands inside attention's
+    if scope == "model.mla_latent":
+        assert all("model.attention" in name for name in tree_streamed_op_names
+                   if "model.mla_latent" in name)
 
 
 def test_round_fwdbwd_is_the_innermost_round_scope_of_every_pass(streamed_op_names):
@@ -296,6 +475,6 @@ def test_byzlint_metric_contract_is_silent_on_the_streamed_rounds_modules():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     paths = [os.path.join(root, "byzpy_tpu", *parts) for parts in (
         ("parallel", "ps.py"), ("parallel", "moe.py"), ("models", "nemotron_h.py"),
-        ("ops", "coordinatewise.py"))]
+        ("models", "glm4_moe_lite.py"), ("models", "layers.py"), ("ops", "coordinatewise.py"))]
     result = scan_paths(paths, select=[METRIC_CONTRACT])
     assert [f.message for f in result.findings if f.rule == METRIC_CONTRACT] == []
