@@ -137,11 +137,46 @@ def _expert_round(x, weights, gate, rank, routed, r, rows):
         jnp.repeat(jnp.arange(tokens, dtype=jnp.int32), held), mode="drop")
     per_expert = jax.vmap(_expert)(
         x[token_at].reshape(held, rows, d), *weights).reshape(held * rows, d)
-    # back to the tokens: each reads its slots (a slot past the end reads
-    # zero; a slot no token fills is read by none)
+    filled = jnp.sum(mine, axis=0, dtype=jnp.int32)  # an expert's first slots
+    holds_token = (jnp.arange(rows)[None, :] < filled[:, None]).reshape(held * rows)
+    out = _combine(per_expert, gate.astype(x.dtype), slot, token_at, holds_token)
+    return out, jnp.sum(mine, dtype=jnp.int32)
+
+
+@jax.custom_vjp
+def _combine(per_expert, gate, slot, token_at, holds_token):
+    """Back to the tokens: ``sum_e gate[t, e] per_expert[slot[t, e]]``. Each
+    token reads its slots (a slot past the end reads zero; a slot no token
+    fills is read by none). A filled slot is read by exactly one (token,
+    expert) pair, so the backward is a gather too: the cotangent of slot
+    ``s`` is ``gate * d_out`` of the one token ``token_at[s]`` that reads
+    it. (Left to automatic differentiation the take's transpose is a
+    scatter-add of ``T x held`` rows, nearly all of them zero, into
+    ``held x rows``: the slowest operation of the layer on a TPU.)"""
     read = jnp.take(per_expert, slot, axis=0, mode="fill", fill_value=0)  # (T, held, D)
-    return (jnp.einsum("te,ted->td", gate.astype(x.dtype), read),
-            jnp.sum(mine, dtype=jnp.int32))
+    return jnp.einsum("te,ted->td", gate, read)
+
+
+def _combine_fwd(per_expert, gate, slot, token_at, holds_token):
+    return (_combine(per_expert, gate, slot, token_at, holds_token),
+            (per_expert, gate, slot, token_at, holds_token))
+
+
+def _combine_bwd(kept, d_out):
+    per_expert, gate, slot, token_at, holds_token = kept
+    held = gate.shape[1]
+    rows = per_expert.shape[0] // held
+    expert_at = jnp.arange(held * rows, dtype=jnp.int32) // rows
+    d_read = d_out[token_at]  # (held x rows, D): what a slot's one reader hands back
+    gate_at = gate[token_at, expert_at]
+    keep = holds_token[:, None]
+    d_per_expert = jnp.where(keep, d_read * gate_at[:, None], 0.0).astype(per_expert.dtype)
+    d_gate_at = jnp.sum(jnp.where(keep, d_read * per_expert, 0.0).astype(jnp.float32), axis=-1)
+    d_gate = jnp.take(d_gate_at, slot, axis=0, mode="fill", fill_value=0).astype(gate.dtype)
+    return d_per_expert, d_gate, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
