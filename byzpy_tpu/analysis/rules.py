@@ -1449,8 +1449,9 @@ class MetricContractRule(Rule):
         """Check the literal first argument of registry factory calls,
         tracing span/instant calls and ``named_scope`` calls, and the
         ``name=`` of every ``pallas_call``, against the catalog. Computed
-        names stay silent unless a declared dynamic prefix covers them —
-        a new dynamic family must be catalogued as a prefix."""
+        metric, span and kernel names stay silent; a computed scope label
+        has to start with the literal of a catalogued family — a new
+        dynamic family must be catalogued as a prefix."""
         for node in ast.walk(mod.tree):
             if not isinstance(node, ast.Call):
                 continue
@@ -1462,10 +1463,10 @@ class MetricContractRule(Rule):
                 yield from self._check_kernel(mod, node)
                 continue
             name = self._literal_name(node)
-            if name is None:
-                continue
             if called == SCOPE_CALL_NAME:
                 yield from self._check_scope(mod, node, name)
+                continue
+            if name is None:
                 continue
             if (
                 isinstance(func, ast.Attribute)
@@ -1539,10 +1540,38 @@ class MetricContractRule(Rule):
             "docs/observability.md span catalog",
         )
 
+    @staticmethod
+    def _literal_head(call: ast.Call) -> str:
+        """The literal a computed label starts with (``"segment." + key``,
+        ``f"segment.{key}"``); empty where it starts with none."""
+        expr: Optional[ast.AST] = call.args[0] if call.args else None
+        while isinstance(expr, ast.BinOp) and isinstance(expr.op, ast.Add):
+            expr = expr.left
+        if isinstance(expr, ast.JoinedStr) and expr.values:
+            expr = expr.values[0]
+        if isinstance(expr, ast.Constant) and isinstance(expr.value, str):
+            return expr.value
+        return ""
+
     def _check_scope(
-        self, mod: ModuleInfo, node: ast.Call, name: str
+        self, mod: ModuleInfo, node: ast.Call, name: Optional[str]
     ) -> Iterator[Finding]:
-        if name in catalog.SCOPES:
+        """A literal label is catalogued; a computed one starts with the
+        literal of a catalogued family (``SCOPE_PREFIXES``), the one way a
+        reader of the compiled text can find labels it cannot list."""
+        if name is None:
+            head = self._literal_head(node)
+            if not head.startswith(catalog.SCOPE_PREFIXES):
+                yield self.finding(
+                    mod,
+                    node,
+                    f"computed named_scope label (it starts with {head!r}) is "
+                    "under no catalogued family — start it with a literal "
+                    "prefix listed in SCOPE_PREFIXES in "
+                    "byzpy_tpu/observability/catalog.py",
+                )
+            return
+        if name in catalog.SCOPES or name.startswith(catalog.SCOPE_PREFIXES):
             return
         yield self.finding(
             mod,

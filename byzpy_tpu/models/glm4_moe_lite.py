@@ -162,7 +162,8 @@ def mla_attention(p: Dict[str, Array], x: Array, cfg: Glm4MoeLiteConfig) -> Arra
 
 def _gated_mlp(p: Dict[str, Array], x: Array) -> Array:
     cast = lambda name: p[name].astype(x.dtype)  # noqa: E731
-    return (jax.nn.silu(x @ cast("w_gate")) * (x @ cast("w_up"))) @ cast("w_down")
+    with jax.named_scope("model.mlp"):
+        return (jax.nn.silu(x @ cast("w_gate")) * (x @ cast("w_up"))) @ cast("w_down")
 
 
 def _expert_ffn(p: Dict[str, Array], x: Array, cfg: Glm4MoeLiteConfig):
@@ -209,11 +210,13 @@ def _mtp(cfg: Glm4MoeLiteConfig, dtype: Any):
     def apply(p, boundary):
         with jax.named_scope("model.mtp"):
             h, embedded = boundary
-            ahead = jnp.roll(embedded, -1, axis=1)
-            joined = jnp.concatenate([
-                rms_norm(h.astype(dtype), p["h_norm_scale"], cfg.rms_norm_eps),
-                rms_norm(ahead.astype(dtype), p["e_norm_scale"], cfg.rms_norm_eps)], axis=-1)
-            out, aux = decoder_block(p, joined @ p["w_eh"].astype(dtype), cfg, dense=False)
+            with jax.named_scope("model.mtp_join"):
+                ahead = jnp.roll(embedded, -1, axis=1)
+                joined = jnp.concatenate([
+                    rms_norm(h.astype(dtype), p["h_norm_scale"], cfg.rms_norm_eps),
+                    rms_norm(ahead.astype(dtype), p["e_norm_scale"], cfg.rms_norm_eps)], axis=-1)
+                joined = joined @ p["w_eh"].astype(dtype)
+            out, aux = decoder_block(p, joined, cfg, dense=False)
             return (h, rms_norm(out, p["head_norm_scale"], cfg.rms_norm_eps)), aux
 
     return apply
@@ -225,12 +228,13 @@ def _head(cfg: Glm4MoeLiteConfig, dtype: Any):
 
     def apply(p, boundary, targets):
         h, ahead = boundary
-        w_head = p["w_head"].astype(dtype)
-        h = rms_norm(h.astype(dtype), p["norm_scale"], cfg.rms_norm_eps)
-        main = jnp.mean(cross_entropy(h @ w_head, targets))
-        with jax.named_scope("model.mtp"):
-            mtp = jnp.mean(cross_entropy(ahead[:, :-1] @ w_head, targets[:, 1:]))
-        return main + cfg.mtp_loss_weight * mtp, {"main_loss": main, "mtp_loss": mtp}
+        with jax.named_scope("model.head"):
+            w_head = p["w_head"].astype(dtype)
+            h = rms_norm(h.astype(dtype), p["norm_scale"], cfg.rms_norm_eps)
+            main = jnp.mean(cross_entropy(h @ w_head, targets))
+            with jax.named_scope("model.mtp"):
+                mtp = jnp.mean(cross_entropy(ahead[:, :-1] @ w_head, targets[:, 1:]))
+            return main + cfg.mtp_loss_weight * mtp, {"main_loss": main, "mtp_loss": mtp}
 
     return apply
 

@@ -15,9 +15,10 @@ Array = jnp.ndarray
 
 
 def rms_norm(x: Array, scale: Array, eps: float) -> Array:
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+    with jax.named_scope("model.norm"):
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+        return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
 def token_embedding(dtype: Any):
@@ -25,7 +26,8 @@ def token_embedding(dtype: Any):
     ``dtype``."""
 
     def apply(p, tokens):
-        return p["embedding"][tokens].astype(dtype)
+        with jax.named_scope("model.embed"):
+            return p["embedding"][tokens].astype(dtype)
 
     return apply
 

@@ -163,23 +163,28 @@ def mamba2_mixer(p: Dict[str, Array], x: Array, cfg: NemotronHConfig) -> Array:
     t = x.shape[0]
     heads, hd, groups, n = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
                             cfg.ssm_state_size)
-    z = x @ p["w_z"].astype(x.dtype)
-    xbc = x @ p["w_xbc"].astype(x.dtype)
-    dt = (x @ p["w_dt"].astype(x.dtype)).astype(jnp.float32)
-    xbc = jax.nn.silu(causal_depthwise_conv(
-        xbc, p["conv_w"].astype(x.dtype), p["conv_b"].astype(x.dtype)))
-    xs = xbc[:, : cfg.d_inner].reshape(t, heads, hd)
-    b = xbc[:, cfg.d_inner: cfg.d_inner + groups * n].reshape(t, groups, n)
-    c = xbc[:, cfg.d_inner + groups * n:].reshape(t, groups, n)
-    delta = jax.nn.softplus(dt + p["dt_bias"])
-    a = -jnp.exp(p["a_log"])
-    y = ssd_chunked(xs, delta, a, b, c, cfg.chunk_size)
-    y = y + p["d_skip"][:, None] * xs.astype(jnp.float32)
-    y = (y.reshape(t, cfg.d_inner) * jax.nn.silu(z.astype(jnp.float32)))
-    # RMSNorm over each of the n_groups groups of channels
-    y = rms_norm(y.reshape(t, groups, -1), jnp.ones((), jnp.float32), cfg.norm_eps)
-    y = (y.reshape(t, cfg.d_inner) * p["gate_norm_scale"]).astype(x.dtype)
-    return y @ p["w_out"].astype(x.dtype)
+    # model.ssm_proj: the four products; model.ssm_gate: what of the mixer is
+    # neither a product nor the scan (which names itself inside it)
+    with jax.named_scope("model.ssm_proj"):
+        z = x @ p["w_z"].astype(x.dtype)
+        xbc = x @ p["w_xbc"].astype(x.dtype)
+        dt = (x @ p["w_dt"].astype(x.dtype)).astype(jnp.float32)
+    with jax.named_scope("model.ssm_gate"):
+        xbc = jax.nn.silu(causal_depthwise_conv(
+            xbc, p["conv_w"].astype(x.dtype), p["conv_b"].astype(x.dtype)))
+        xs = xbc[:, : cfg.d_inner].reshape(t, heads, hd)
+        b = xbc[:, cfg.d_inner: cfg.d_inner + groups * n].reshape(t, groups, n)
+        c = xbc[:, cfg.d_inner + groups * n:].reshape(t, groups, n)
+        delta = jax.nn.softplus(dt + p["dt_bias"])
+        a = -jnp.exp(p["a_log"])
+        y = ssd_chunked(xs, delta, a, b, c, cfg.chunk_size)
+        y = y + p["d_skip"][:, None] * xs.astype(jnp.float32)
+        y = (y.reshape(t, cfg.d_inner) * jax.nn.silu(z.astype(jnp.float32)))
+        # RMSNorm over each of the n_groups groups of channels
+        y = rms_norm(y.reshape(t, groups, -1), jnp.ones((), jnp.float32), cfg.norm_eps)
+        y = (y.reshape(t, cfg.d_inner) * p["gate_norm_scale"]).astype(x.dtype)
+    with jax.named_scope("model.ssm_proj"):
+        return y @ p["w_out"].astype(x.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -246,8 +251,9 @@ def _block(kind: str, cfg: NemotronHConfig, dtype: Any):
 
 def _head(cfg: NemotronHConfig, dtype: Any):
     def apply(p, h, targets):
-        h = rms_norm(h.astype(dtype), p["norm_scale"], cfg.norm_eps)
-        return jnp.mean(cross_entropy(h @ p["w_head"].astype(dtype), targets))
+        with jax.named_scope("model.head"):
+            h = rms_norm(h.astype(dtype), p["norm_scale"], cfg.norm_eps)
+            return jnp.mean(cross_entropy(h @ p["w_head"].astype(dtype), targets))
 
     return apply
 

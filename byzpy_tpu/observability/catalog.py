@@ -135,14 +135,25 @@ SPAN_PREFIXES: Tuple[str, ...] = ("chaos.",)
 #: training step, ``serving.*`` names the serving steps' stages. In a
 #: round that streams segment by segment ``round.segment_*`` says which
 #: pass an op of the model belongs to (``round.fwdbwd`` stays the
-#: innermost ``round.*`` of them all) and ``model.*`` which mixer
+#: innermost ``round.*`` of them all), ``model.*`` which part of the model
+#: (an op belongs to the LAST ``model.*`` of its path; ``model.mtp`` is an
+#: envelope around a whole block) and ``stream.*`` the round's own work
+#: inside ``round.fwdbwd``
 SCOPES: FrozenSet[str] = frozenset(
     {
         "model.attention",
+        "model.embed",
+        "model.head",
         "model.mla_latent",
+        "model.mlp",
         "model.moe_experts",
         "model.moe_route",
+        "model.moe_shared",
         "model.mtp",
+        "model.mtp_join",
+        "model.norm",
+        "model.ssm_gate",
+        "model.ssm_proj",
         "model.ssm_scan",
         "round.aggregate",
         "round.build_matrix",
@@ -161,8 +172,14 @@ SCOPES: FrozenSet[str] = frozenset(
         "serving.ragged_evidence",
         "serving.ragged_scale",
         "serving.staleness_scale",
+        "stream.boundary",
+        "stream.rows",
     }
 )
+
+#: dynamic scope families (``segment.<key>``: which segment of a streamed
+#: round an op belongs to, entered by the round itself for any bundle)
+SCOPE_PREFIXES: Tuple[str, ...] = ("segment.",)
 
 #: every ``pl.pallas_call(name=...)``: the enclosing ``_<name>_call``
 #: function's name without the underscore and the ``_call``. The name is
@@ -201,6 +218,7 @@ __all__ = [
     "METRICS",
     "METRIC_PREFIXES",
     "SCOPES",
+    "SCOPE_PREFIXES",
     "SPANS",
     "SPAN_PREFIXES",
 ]

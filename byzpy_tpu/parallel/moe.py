@@ -299,7 +299,8 @@ def held_experts_ffn(
         weights = (w_up, w_down) if w_gate is None else (w_up, w_down, w_gate)
         out, computed, rounds = _expert_rounds(x, weights, gate, rank, routed, rows)
         if shared_up is not None:
-            out = out + _expert(x, shared_up, shared_down, shared_gate)
+            with jax.named_scope("model.moe_shared"):
+                out = out + _expert(x, shared_up, shared_down, shared_gate)
     aux = {
         "held_expert_tokens": counts,
         "tokens_dropped": jnp.sum(counts) - computed,

@@ -252,7 +252,8 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
             outs, auxes, before = [], {}, {}
             made, wires = [], []
             for at, seg in enumerate(segs[:last]):
-                x = seg.apply(params[seg.key], x)
+                with jax.named_scope("segment." + seg.key):
+                    x = seg.apply(params[seg.key], x)
                 if seg.aux:
                     x, auxes[seg.key] = x
                 leaves, treedef = jax.tree_util.tree_flatten(x)
@@ -277,8 +278,12 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
             kept = jax.eval_shape(forward_of, xs_h[0])
 
             def one_forward(i, carry):
-                return jax.tree_util.tree_map(
-                    lambda stack, value: put(stack, value, i), carry, forward_of(xs_h[i]))
+                with jax.named_scope("stream.boundary"):
+                    x = xs_h[i]
+                out = forward_of(x)
+                with jax.named_scope("stream.boundary"):
+                    return jax.tree_util.tree_map(
+                        lambda stack, value: put(stack, value, i), carry, out)
 
             vals, auxes = jax.lax.fori_loop(0, h, one_forward, (
                 [jax.lax.empty((h, *folded(leaf.shape)), leaf.dtype) for leaf in kept[0]],
@@ -310,112 +315,125 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
         for j, stack in zip(reads, held_back):
             vals[j] = stack
         for k in range(last, -1, -1):
-            seg, layout = segs[k], layouts[k]
-            width = layout.width
-            row_shape = folded((width,))
-            lanes = row_shape[1:]
-            lane = math.prod(lanes)
-            rows_dtype = grad_dtype if grad_dtype is not None else layout.dtype
-            reads = wires[k - 1][1] if k else []
-            # An array this segment's input holds was made by the segment
-            # before it, or handed on by it. The first is read here for the
-            # last time: its cotangent is written over it. The second is still
-            # to be read by earlier segments, so its cotangent has a stack of
-            # its own (from the first segment, going backwards, that reads it).
-            over = [made[j] == k - 1 for j in reads]
-            at = {j: place for place, j in enumerate(reads)}
-            io = [vals[j] if last_read else
-                  cots[j] if cots[j] is not None else jax.lax.empty(vals[j].shape, vals[j].dtype)
-                  for j, last_read in zip(reads, over)]
+            with jax.named_scope("segment." + segs[k].key):
+                seg, layout = segs[k], layouts[k]
+                width = layout.width
+                row_shape = folded((width,))
+                lanes = row_shape[1:]
+                lane = math.prod(lanes)
+                rows_dtype = grad_dtype if grad_dtype is not None else layout.dtype
+                reads = wires[k - 1][1] if k else []
+                # An array this segment's input holds was made by the segment
+                # before it, or handed on by it. The first is read here for the
+                # last time: its cotangent is written over it. The second is still
+                # to be read by earlier segments, so its cotangent has a stack of
+                # its own (from the first segment, going backwards, that reads it).
+                over = [made[j] == k - 1 for j in reads]
+                at = {j: place for place, j in enumerate(reads)}
+                io = [vals[j] if last_read else cots[j] if cots[j] is not None
+                      else jax.lax.empty(vals[j].shape, vals[j].dtype)
+                      for j, last_read in zip(reads, over)]
 
-            def one_backward(i, carry, k=k, seg=seg, layout=layout, sub=sub, at=at,
-                             over=over, cots=tuple(cots), vals=tuple(vals),
-                             lanes=lanes, lane=lane):
-                # worker i's input to this segment is read from the stacks of
-                # boundaries kept, and the cotangent of that input is written
-                # over it: after the loop the stack holds what the segment
-                # before this one pulls back, and nothing else was allocated
-                with jax.named_scope("round.segment_recompute"), jax.named_scope("round.fwdbwd"):
-                    if k == last:
-                        def apply(p, x):
-                            return seg.apply(p, x, ys_h[i])
-                    elif seg.aux:
-                        def apply(p, x):
-                            return seg.apply(p, x)[0]
-                    else:
-                        apply = seg.apply
-                    if k:
-                        x = boundary(
-                            k - 1, lambda j: carry["io"][at[j]] if over[at[j]] else vals[j], i)
-                        out, pullback, *aux = jax.vjp(
-                            apply, sub, x, has_aux=seg.aux and k == last)
-                    else:  # the batch itself: nothing flows back into it
-                        out, pullback = jax.vjp(lambda p: apply(p, xs_h[i]), sub)
-                with jax.named_scope("round.segment_bwd"), jax.named_scope("round.fwdbwd"):
-                    # what an array handed on pulls back so far stands in the
-                    # stack this loop writes, not in the one it started from
-                    pulled = pullback(
-                        jnp.ones_like(out) if k == last else boundary(k, lambda j: (
-                            carry["io"][at[j]] if j in at and not over[at[j]] else cots[j]), i))
-                    grads = carry["rows"]
-                    for first, piece in zip(layout.offsets, layout.place(pulled[0], grad_dtype)):
-                        grads = jax.lax.dynamic_update_slice(
-                            grads, jax.lax.expand_dims(piece.reshape(-1, *lanes), (0,)),
-                            (i, first // lane, *(0 for _ in lanes)))
-                    carry = dict(carry, rows=grads)
-                    if k:
-                        carry["io"] = [put(stack, leaf, i) for stack, leaf in zip(
-                            carry["io"], jax.tree_util.tree_leaves(pulled[1]))]
-                    if k == last:
-                        carry["losses"] = put(carry["losses"], out, i)
-                        if aux:
-                            carry["head_aux"] = jax.tree_util.tree_map(
-                                lambda stack, value: put(stack, value, i),
-                                carry["head_aux"], aux[0])
-                return carry
+                def one_backward(i, carry, k=k, seg=seg, layout=layout, sub=sub, at=at,
+                                 over=over, cots=tuple(cots), vals=tuple(vals),
+                                 lanes=lanes, lane=lane):
+                    # worker i's input to this segment is read from the stacks of
+                    # boundaries kept, and the cotangent of that input is written
+                    # over it: after the loop the stack holds what the segment
+                    # before this one pulls back, and nothing else was allocated
+                    with jax.named_scope("round.segment_recompute"), \
+                            jax.named_scope("round.fwdbwd"):
+                        # stream.boundary: what a worker's turn reads of the
+                        # stacks and writes back to them; stream.rows: its
+                        # gradient placed in the segment's rows
+                        with jax.named_scope("stream.boundary"):
+                            x = boundary(k - 1, lambda j: (
+                                carry["io"][at[j]] if over[at[j]] else vals[j]), i
+                            ) if k else xs_h[i]
+                            y = ys_h[i] if k == last else None
+                        if k == last:
+                            def apply(p, x):
+                                return seg.apply(p, x, y)
+                        elif seg.aux:
+                            def apply(p, x):
+                                return seg.apply(p, x)[0]
+                        else:
+                            apply = seg.apply
+                        if k:
+                            out, pullback, *aux = jax.vjp(
+                                apply, sub, x, has_aux=seg.aux and k == last)
+                        else:  # the batch itself: nothing flows back into it
+                            out, pullback = jax.vjp(lambda p: apply(p, x), sub)
+                    with jax.named_scope("round.segment_bwd"), jax.named_scope("round.fwdbwd"):
+                        # what an array handed on pulls back so far stands in the
+                        # stack this loop writes, not in the one it started from
+                        with jax.named_scope("stream.boundary"):
+                            back = jnp.ones_like(out) if k == last else boundary(k, lambda j: (
+                                carry["io"][at[j]] if j in at and not over[at[j]] else cots[j]), i)
+                        pulled = pullback(back)
+                        grads = carry["rows"]
+                        with jax.named_scope("stream.rows"):
+                            for first, piece in zip(
+                                    layout.offsets, layout.place(pulled[0], grad_dtype)):
+                                grads = jax.lax.dynamic_update_slice(
+                                    grads,
+                                    jax.lax.expand_dims(piece.reshape(-1, *lanes), (0,)),
+                                    (i, first // lane, *(0 for _ in lanes)))
+                        carry = dict(carry, rows=grads)
+                        with jax.named_scope("stream.boundary"):
+                            if k:
+                                carry["io"] = [put(stack, leaf, i) for stack, leaf in zip(
+                                    carry["io"], jax.tree_util.tree_leaves(pulled[1]))]
+                            if k == last:
+                                carry["losses"] = put(carry["losses"], out, i)
+                                if aux:
+                                    carry["head_aux"] = jax.tree_util.tree_map(
+                                        lambda stack, value: put(stack, value, i),
+                                        carry["head_aux"], aux[0])
+                    return carry
 
-            carry = {"rows": jax.lax.empty((n, *row_shape), rows_dtype)}
-            if k:
-                carry["io"] = io
-            if k == last:
-                loss0 = jax.eval_shape(
-                    lambda x, seg=seg, sub=sub: seg.apply(sub, x, ys_h[0]),
-                    jax.tree_util.tree_unflatten(
-                        wires[k - 1][0], [kept[0][j] for j in wires[k - 1][1]]))
-                if seg.aux:
-                    loss0, aux0 = loss0
-                    carry["head_aux"] = jax.tree_util.tree_map(
-                        lambda leaf: jnp.zeros((h, *leaf.shape), leaf.dtype), aux0)
-                carry["losses"] = jnp.zeros((h,), loss0.dtype)
-            carry = jax.lax.fori_loop(0, h, one_backward, carry)
-            losses = carry.get("losses", losses)
-            head_aux = carry.get("head_aux", head_aux)
-            with jax.named_scope("round.build_matrix"):
-                stack = carry["rows"]
-                if b:
-                    byz = _byzantine_rows(attack, stack[:h].reshape(h, width),
-                                          jax.random.fold_in(key, k), b, layout.d)
-                    stack = stack.at[h:].set(jnp.broadcast_to(
-                        byz.reshape(byz.shape[0], *row_shape), (b, *row_shape)))
-                matrix = stack.reshape(n, width)
-            with jax.named_scope("round.aggregate"):
-                agg = aggregate(matrix).astype(layout.dtype)
-            with jax.named_scope("round.update"):
-                # (the columns past d are exactly zero: they add nothing to
-                # the norm, and unravel reads the first d alone)
-                sum_sq = sum_sq + jnp.sum(jnp.square(agg)).astype(jnp.float32)
-                updates, state = opt.update(layout.unravel(agg), opt_state[seg.key], sub)
-                done = (optax.apply_updates(sub, updates), state)
+                carry = {"rows": jax.lax.empty((n, *row_shape), rows_dtype)}
                 if k:
-                    # the segment before this one starts from the cotangents
-                    # only once this one's leaves are updated: its rows are
-                    # dead by then, and the next rows take their place
-                    done, flowing, next_sub = jax.lax.optimization_barrier(
-                        (done, carry["io"], params[segs[k - 1].key]))
-                    for j, stack in zip(reads, flowing):
-                        cots[j] = stack
-                new_params[seg.key], new_opt[seg.key] = done
-                sub = next_sub if k else None
+                    carry["io"] = io
+                if k == last:
+                    loss0 = jax.eval_shape(
+                        lambda x, seg=seg, sub=sub: seg.apply(sub, x, ys_h[0]),
+                        jax.tree_util.tree_unflatten(
+                            wires[k - 1][0], [kept[0][j] for j in wires[k - 1][1]]))
+                    if seg.aux:
+                        loss0, aux0 = loss0
+                        carry["head_aux"] = jax.tree_util.tree_map(
+                            lambda leaf: jnp.zeros((h, *leaf.shape), leaf.dtype), aux0)
+                    carry["losses"] = jnp.zeros((h,), loss0.dtype)
+                carry = jax.lax.fori_loop(0, h, one_backward, carry)
+                losses = carry.get("losses", losses)
+                head_aux = carry.get("head_aux", head_aux)
+                with jax.named_scope("round.build_matrix"):
+                    stack = carry["rows"]
+                    if b:
+                        byz = _byzantine_rows(attack, stack[:h].reshape(h, width),
+                                              jax.random.fold_in(key, k), b, layout.d)
+                        stack = stack.at[h:].set(jnp.broadcast_to(
+                            byz.reshape(byz.shape[0], *row_shape), (b, *row_shape)))
+                    matrix = stack.reshape(n, width)
+                with jax.named_scope("round.aggregate"):
+                    agg = aggregate(matrix).astype(layout.dtype)
+                with jax.named_scope("round.update"):
+                    # (the columns past d are exactly zero: they add nothing to
+                    # the norm, and unravel reads the first d alone)
+                    sum_sq = sum_sq + jnp.sum(jnp.square(agg)).astype(jnp.float32)
+                    updates, state = opt.update(layout.unravel(agg), opt_state[seg.key], sub)
+                    done = (optax.apply_updates(sub, updates), state)
+                    if k:
+                        # the segment before this one starts from the cotangents
+                        # only once this one's leaves are updated: its rows are
+                        # dead by then, and the next rows take their place
+                        done, flowing, next_sub = jax.lax.optimization_barrier(
+                            (done, carry["io"], params[segs[k - 1].key]))
+                        for j, stack in zip(reads, flowing):
+                            cots[j] = stack
+                    new_params[seg.key], new_opt[seg.key] = done
+                    sub = next_sub if k else None
         with jax.named_scope("round.update"):
             metrics = {"honest_loss": jnp.mean(losses), "agg_grad_norm": jnp.sqrt(sum_sq)}
             if head_aux is not None:

@@ -2,7 +2,9 @@
 names.
 
 Catalogued scope labels and kernel names, and the shapes the rule must
-resolve to nothing: computed labels and names (silent by design).
+resolve to nothing: a computed label that starts with the literal of a
+catalogued family (``SCOPE_PREFIXES``), computed kernel names (silent by
+design).
 """
 
 import jax
@@ -14,7 +16,11 @@ def step(x, stage):
         x = x * 2
     with jax.named_scope("serving.opt_update"):
         x = x + 1
-    with jax.named_scope(f"round.{stage}"):  # computed: silent
+    with jax.named_scope("segment." + stage):  # computed, under a catalogued family
+        x = x - 1
+    with jax.named_scope(f"segment.{stage}"):  # the same family, as an f-string
+        x = x * 3
+    with jax.named_scope("segment.s0_in"):  # a literal of the family
         return x
 
 

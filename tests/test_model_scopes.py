@@ -1,0 +1,278 @@
+"""Every op of a language model's streamed step says which part, which
+segment and which pass it belongs to, and saying so changes nothing else.
+
+``model.*`` is a partition of what the model computes (an op belongs to the
+LAST ``model.*`` label of its ``op_name`` path, ``model.mtp`` left out: an
+envelope around a whole block), ``stream.*`` names the round's own work
+inside ``round.fwdbwd``, and ``segment.<key>`` is entered by the round
+itself for any bundle. The labels are read off the compiled steps of toy
+sizes of both bundles, where ``chipbench/scope_parts.py`` reads them; the
+steps' text, names and locations apart, is the parent's.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import hashlib
+import os
+import re
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from byzpy_tpu.analysis import scan_paths
+from byzpy_tpu.analysis.rules import METRIC_CONTRACT
+from byzpy_tpu.observability import catalog
+from byzpy_tpu.ops import attack_ops, coordinatewise, robust
+from byzpy_tpu.parallel.ps import PSStepConfig, build_ps_train_step
+from chipbench.scope_parts import UNLABELLED, part_of  # the readers' own rule
+
+N, B = 8, 2
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FIXTURES = os.path.join(ROOT, "tests", "fixtures", "analysis")
+SIGN_FLIP = coordinatewise.RoundAttack(attack_ops.sign_flip, of="honest_mean")
+SEGMENT = re.compile(r"segment\.([A-Za-z0-9_]+)")
+PARTS = {"nemotron": ["model.norm", "model.embed", "model.head", "model.ssm_proj",
+                      "model.ssm_gate", "model.ssm_scan", "model.attention", "model.moe_route",
+                      "model.moe_experts", "model.moe_shared", "stream.rows", "stream.boundary"],
+         "glm": ["model.norm", "model.embed", "model.head", "model.mlp", "model.attention",
+                 "model.mla_latent", "model.moe_route", "model.moe_experts", "model.moe_shared",
+                 "model.mtp_join", "stream.rows", "stream.boundary"]}
+
+
+def _toy(model):
+    if model == "nemotron":
+        from byzpy_tpu.models import nemotron_h as nh
+
+        return nh.nemotron_h_bundle(nh.NemotronHConfig(
+            hidden_size=32, pattern="M*E", vocab_size=64, mamba_num_heads=4, mamba_head_dim=8,
+            ssm_state_size=16, n_groups=2, chunk_size=8, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=8, query_block=8, n_routed_experts=16,
+            num_experts_per_tok=3, moe_intermediate_size=24,
+            moe_shared_expert_intermediate_size=40, held_experts=(4, 4)), seed=0)
+    from byzpy_tpu.models import glm4_moe_lite as glm
+
+    return glm.glm47_flash_ep8(
+        0, hidden_size=32, num_hidden_layers=2, vocab_size=64, num_attention_heads=2,
+        q_lora_rank=16, kv_lora_rank=12, qk_nope_head_dim=6, qk_rope_head_dim=2, v_head_dim=8,
+        query_block=8, intermediate_size=48, n_routed_experts=16, num_experts_per_tok=3,
+        moe_intermediate_size=24, held_experts=(4, 4))
+
+
+def _lowered(model):
+    bundle = _toy(model)
+    step, opt = build_ps_train_step(bundle, partial(robust.trimmed_mean, f=2),
+                                    PSStepConfig(n_nodes=N, n_byzantine=B), attack=SIGN_FLIP)
+    tokens = jnp.zeros((N, 1, 19), jnp.int32)
+    return [seg.key for seg in bundle.segments], jax.jit(step).lower(
+        bundle.params, opt, tokens, tokens, jax.random.PRNGKey(1))
+
+
+@contextlib.contextmanager
+def _no_compile_cache():
+    """The persistent compile cache leaves metadata out of its key: a step
+    that differs from a cached one by its scopes alone comes back with the
+    cached one's ``op_name``s (PERF.md section 7). These compiles go by it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+_META = re.compile(r",?\s*metadata=\{[^}]*\}")
+_FRAMES = re.compile(r"^(?:\d+ (?:\"|\{).*|FileNames|FunctionNames|FileLocations|StackFrames)$")
+_LOC = re.compile(r"\s*loc\([^\n]*\)|#loc[^\n]*\n")
+
+
+def _bare(compiled_text):
+    """A compiled text without what a scope can reach: ``metadata={...}`` and
+    the tables of file names and stack frames it points into."""
+    return "\n".join(line for line in _META.sub("", compiled_text).splitlines()
+                     if not _FRAMES.match(line))
+
+
+@pytest.fixture(scope="module", params=["nemotron", "glm"])
+def step_text(request):
+    """``(model, segment keys, [(opcode, op_name)] of the compiled step, its
+    bare text)``."""
+    with _no_compile_cache():
+        keys, lowered = _lowered(request.param)
+        text = lowered.compile().as_text()
+    ops = []
+    for line in text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        called = re.search(r"\s([a-z][a-z0-9\-]*)\(", " " + line.split("=", 1)[-1])
+        # the program's own ops: their op_name starts at the jitted function
+        # (a reducer's region is named from inside its loop, and never runs
+        # as an op of its own)
+        if (m and called and called.group(1) != "parameter"
+                and m.group(1).startswith("jit(train_step)/")):
+            ops.append((called.group(1), m.group(1)))
+    return request.param, keys, ops, _bare(text)
+
+
+# What may stand under round.fwdbwd with no part: the blocks' residual adds
+# (and the sum of the two cotangents that meet there), the worker loops'
+# counters, the expert layer's count of dropped tokens (a difference of two
+# sums), a cast. Nothing that multiplies.
+ALLOWED = re.compile(
+    r"(?:segment\.\w+|jvp\(\)|transpose\(jvp\(\)\)|model\.mtp|jvp\(model\.mtp\)"
+    r"|transpose\(jvp\(model\.mtp\)\))/(?:add|add_any|sub|reduce_sum|convert_element_type)$"
+    r"|/while/body/add$|/while/cond/lt$")
+# an op_name that ends at a control-flow node names what the compiler put
+# around it (constants, tuples), not a primitive of the program
+PLUMBING = re.compile(r"/(?:while|closed_call|cond|body)$")
+
+
+def test_every_op_of_round_fwdbwd_holds_a_part_but_for_a_short_allow_list(step_text):
+    model, _, ops, _ = step_text
+    inside = [name for _, name in ops if "round.fwdbwd" in name and not PLUMBING.search(name)]
+    assert len(inside) > 1000
+    bare = [name for name in inside if part_of(name) == UNLABELLED]
+    assert [name for name in bare if not ALLOWED.search(name)] == []
+    assert len(bare) < 0.05 * len(inside), (len(bare), len(inside))
+
+
+def test_every_part_appears_and_the_catalog_lists_it(step_text):
+    model, _, ops, _ = step_text
+    seen = {part_of(name) for _, name in ops if "round.fwdbwd" in name}
+    assert set(PARTS[model]) <= seen
+    assert seen - {UNLABELLED} <= set(catalog.SCOPES)
+    # nothing of round.fwdbwd's parts leaks out of it
+    assert {part_of(name) for _, name in ops
+            if "round." in name and "round.fwdbwd" not in name} == {UNLABELLED}
+
+
+def test_every_op_of_a_round_scope_holds_one_segment_and_every_segment_appears(step_text):
+    _, keys, ops, _ = step_text
+    scoped = [name for _, name in ops if re.search(r"round\.[a-z_]+", name)]
+    held = [set(SEGMENT.findall(name)) for name in scoped]
+    assert max(len(found) for found in held) == 1
+    # without one: the first forward's loop over the workers, which runs every
+    # segment (its counter, and the reads and writes of a worker's turn), and
+    # the closing metrics (two means and a root); nothing of the model
+    outside = [name for name, found in zip(scoped, held) if not found]
+    first_forward = re.compile(
+        r"round\.segment_fwd/round\.fwdbwd/(?:while(?:/body/closed_call)?|while/body/add"
+        r"|while/cond/lt|while/body/closed_call/stream\.boundary/\w+)$")
+    assert outside and all(
+        first_forward.search(name) or "jit(train_step)/round.update/" in name for name in outside)
+    assert not any("model." in name for name in outside)
+    assert len(outside) < 0.06 * len(scoped)
+    assert {key for found in held for key in found} == set(keys)
+    # a segment's whole turn: its three passes and the round's three stages
+    for key in keys[1:-1]:
+        mine = [name for name, found in zip(scoped, held) if key in found]
+        for scope in ("round.segment_fwd", "round.segment_recompute", "round.segment_bwd",
+                      "round.build_matrix", "round.aggregate", "round.update"):
+            assert any(scope in name for name in mine), (key, scope)
+    # the label is the outermost of the backward sweep
+    assert any(name.startswith(f"jit(train_step)/segment.{keys[-1]}/") for name in scoped)
+
+
+def test_the_norm_is_a_part_of_its_own_inside_the_latents_and_the_gate(step_text):
+    model, _, ops, _ = step_text
+    outer = "model.ssm_gate" if model == "nemotron" else "model.mla_latent"
+    nested = [name for _, name in ops if outer in name and "model.norm" in name]
+    assert nested and all(part_of(name) == "model.norm" for name in nested)
+    for a_pass in ("round.segment_fwd", "round.segment_recompute", "round.segment_bwd"):
+        assert any(a_pass in name for name in nested), a_pass
+
+
+def test_the_head_holds_its_terms_and_glms_second_stays_in_the_envelope(step_text):
+    model, _, ops, _ = step_text
+    head = [name for _, name in ops if "model.head" in name]
+    # the head has no first forward: it runs inside its segment's turn
+    assert head and not any("round.segment_fwd" in name for name in head)
+    assert all(any(a_pass in name for name in head)
+               for a_pass in ("round.segment_recompute", "round.segment_bwd"))
+    if model != "glm":
+        assert not any("model.mtp" in name for _, name in ops)
+        return
+    second = [name for _, name in ops if "model.head" in name and "model.mtp" in name]
+    assert second and all(part_of(name) in ("model.head", "model.norm") for name in second)
+    join = [name for _, name in ops if "model.mtp_join" in name]
+    assert join and all("model.mtp" in name.replace("model.mtp_join", "") for name in join)
+    shared = [name for _, name in ops if "model.moe_shared" in name]
+    assert shared and all("model.moe_experts" in name for name in shared)
+
+
+def test_round_fwdbwd_is_still_the_innermost_round_scope(step_text):
+    _, _, ops, _ = step_text
+    passes = [name for _, name in ops if "round.segment_" in name]
+    assert {re.findall(r"round\.[A-Za-z0-9_]+", name)[-1] for name in passes} == {"round.fwdbwd"}
+
+
+def test_a_scope_is_metadata_and_nothing_else(step_text, monkeypatch):
+    """The same step with every ``jax.named_scope`` a no-op compiles to the
+    same text, ``metadata={...}`` and the frame tables apart."""
+    model, _, _, bare = step_text
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    with _no_compile_cache():
+        text = _lowered(model)[1].compile().as_text()
+    assert "model.norm" not in text and "segment." not in text
+    assert _bare(text) == bare
+
+
+# sha256 of the lowered text without its locations, taken on the parent commit
+# (9588a48) with this file's own functions before the program was touched;
+# "nemotron" is tests/test_streamed_round.py's "toy-nemotron" too.
+PARENT_LOWERED = {
+    "nemotron": "191ff157d2b31bf7912adb6a908c9e707972bc480698103951e9c3d0b396ea51",
+    "glm": "49bf5224febaa2683af482d83f21467757708436271e27f90d233e859a5a1624",
+}
+
+
+@pytest.mark.parametrize("model", sorted(PARENT_LOWERED))
+def test_the_toy_streamed_steps_lower_to_the_parents_text(model):
+    text = _LOC.sub("", _lowered(model)[1].as_text())
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_LOWERED[model]
+
+
+# -- the catalog and byzlint hold the labels ----------------------------------
+
+NEW_SCOPES = ["model.norm", "model.embed", "model.head", "model.ssm_proj", "model.ssm_gate",
+              "model.mlp", "model.moe_shared", "model.mtp_join", "stream.rows", "stream.boundary"]
+
+
+@pytest.mark.parametrize("scope", NEW_SCOPES)
+def test_the_catalog_lists_each_new_scope(scope):
+    assert scope in catalog.SCOPES
+
+
+def test_the_segment_family_is_a_catalogued_prefix():
+    assert catalog.SCOPE_PREFIXES == ("segment.",)
+    assert not any(scope.startswith(catalog.SCOPE_PREFIXES) for scope in catalog.SCOPES)
+
+
+def _contract_findings(name):
+    result = scan_paths([os.path.join(FIXTURES, name)], select=[METRIC_CONTRACT])
+    return [f.message for f in result.findings if f.rule == METRIC_CONTRACT]
+
+
+def test_byzlint_accepts_a_computed_scope_under_a_catalogued_prefix():
+    assert _contract_findings("metric_contract_scopes_fp.py") == []
+
+
+def test_byzlint_flags_a_computed_scope_outside_every_catalogued_prefix():
+    found = [m for m in _contract_findings("metric_contract_scopes_tp.py")
+             if "computed named_scope label" in m]
+    assert len(found) == 2
+    assert any("'round.'" in m for m in found)  # a literal head that is no family
+    assert any("starts with ''" in m for m in found)  # no literal head at all
+
+
+def test_byzlint_is_clean_on_the_modules_that_enter_the_labels():
+    paths = [os.path.join(ROOT, "byzpy_tpu", *parts) for parts in (
+        ("parallel", "ps.py"), ("parallel", "moe.py"), ("models", "nemotron_h.py"),
+        ("models", "glm4_moe_lite.py"), ("models", "layers.py"))]
+    result = scan_paths(paths, select=[METRIC_CONTRACT])
+    assert [f.message for f in result.findings if f.rule == METRIC_CONTRACT] == []

@@ -30,14 +30,14 @@ _IN_JIT_SECTION = "## In-jit names"
 
 def _doc_tables():
     """Parse the markdown tables: ``(metrics, metric_prefixes, spans,
-    span_prefixes, scopes, kernels)``. Metric rows may carry several
+    span_prefixes, scopes, scope_prefixes, kernels)``. Metric rows may carry several
     backticked names per cell with one shared type or a slash-separated
     type per name; ``<...>`` placeholders declare prefix families."""
     with open(DOCS, encoding="utf-8") as fh:
         text = fh.read()
     metrics, metric_prefixes = {}, set()
     spans, span_prefixes = set(), set()
-    scopes, kernels = set(), set()
+    scopes, scope_prefixes, kernels = set(), set(), set()
     in_jit = False
     for line in text.splitlines():
         if line.startswith("## "):
@@ -50,7 +50,10 @@ def _doc_tables():
             continue
         if in_jit:
             for name in names:
-                (scopes if "." in name else kernels).add(name)
+                if "<" in name:
+                    scope_prefixes.add(name.split("<", 1)[0])
+                else:
+                    (scopes if "." in name else kernels).add(name)
             continue
         types = [t.strip() for t in cells[1].split("/")] if len(cells) > 1 else []
         if all(t in _TYPES for t in types) and types:
@@ -74,7 +77,7 @@ def _doc_tables():
                 span_prefixes.add(name.split("<", 1)[0])
             else:
                 spans.add(name)
-    return metrics, metric_prefixes, spans, span_prefixes, scopes, kernels
+    return metrics, metric_prefixes, spans, span_prefixes, scopes, scope_prefixes, kernels
 
 
 def test_catalog_is_well_formed():
@@ -86,7 +89,10 @@ def test_catalog_is_well_formed():
     for prefix in catalog.METRIC_PREFIXES:
         assert prefix.startswith("byzpy_"), prefix
     for scope in catalog.SCOPES:
-        assert re.fullmatch(r"(round|serving|model)\.[a-z_]+", scope), scope
+        assert re.fullmatch(r"(round|serving|model|stream)\.[a-z_]+", scope), scope
+    for prefix in catalog.SCOPE_PREFIXES:
+        # a family never shadows a listed scope's namespace
+        assert prefix.endswith(".") and not any(s.startswith(prefix) for s in catalog.SCOPES)
     for kernel in catalog.KERNELS:
         assert re.fullmatch(r"[a-z][a-z0-9_]+", kernel), kernel
     # one namespace: an in-jit scope never reuses a host span's label
@@ -118,12 +124,13 @@ def test_docs_span_table_matches_catalog_both_ways():
 
 
 def test_docs_scope_table_matches_catalog_both_ways():
-    *_, scopes, _kernels = _doc_tables()
+    *_, scopes, scope_prefixes, _kernels = _doc_tables()
     assert scopes, "no scope rows parsed from docs/observability.md"
     unknown = sorted(scopes - set(catalog.SCOPES))
     assert not unknown, f"docs scope rows drifting from catalog: {unknown}"
     undocumented = sorted(set(catalog.SCOPES) - scopes)
     assert not undocumented, f"catalogued but not in docs: {undocumented}"
+    assert scope_prefixes == set(catalog.SCOPE_PREFIXES)
 
 
 def test_docs_kernel_table_matches_catalog_both_ways():
