@@ -25,6 +25,7 @@ router's correction bias is a buffer held at zero, so it is left out.
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, replace
 from typing import Any, Dict, Tuple
@@ -148,14 +149,54 @@ def ssd_chunked(x: Array, dt: Array, a: Array, b: Array, c: Array, chunk: int) -
         return y.reshape(nc * chunk, heads, p)[:t]
 
 
+def _rows_moved(x: Array, by: int) -> Array:
+    """``out[t] = x[t - by]`` along the first axis, zero where ``t - by``
+    falls outside: one ``pad`` that adds ``by`` rows at one end and takes
+    them off the other (no array longer than ``x``)."""
+    return jax.lax.pad(x, jnp.zeros((), x.dtype), ((by, -by, 0),) + ((0, 0, 0),) * (x.ndim - 1))
+
+
 def causal_depthwise_conv(x: Array, w: Array, bias: Array) -> Array:
     """``out[t] = bias + sum_j w[j] x[t - (K - 1) + j]``, zeros before the start."""
     k = w.shape[0]
-    padded = jnp.pad(x, ((k - 1, 0), (0, 0)))
     out = bias
     for j in range(k):
-        out = out + w[j] * jax.lax.dynamic_slice_in_dim(padded, j, x.shape[0], axis=0)
+        out = out + w[j] * _rows_moved(x, k - 1 - j)
     return out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def conv_silu(x: Array, w: Array, bias: Array, splits: Tuple[int, ...]) -> Tuple[Array, ...]:
+    """``silu(causal_depthwise_conv(x, w, bias))`` as its column blocks, cut
+    at ``splits`` (handed out apart, each block is written once, in the
+    layout its reader asks for; slices of one array are copied), with a
+    backward of its own: the same ``K`` shifted multiply-adds run the
+    other way. (Left to automatic differentiation each tap's transpose is
+    a write into a fresh zero array of ``T + K - 1`` rows, the taps are
+    added and the pad's transpose slices the sum.) Keeps ``x``, ``w`` and
+    ``bias`` alone."""
+    return tuple(jnp.split(jax.nn.silu(causal_depthwise_conv(x, w, bias)), splits, axis=1))
+
+
+def _conv_silu_fwd(x, w, bias, splits):
+    return conv_silu(x, w, bias, splits), (x, w, bias)
+
+
+def _conv_silu_bwd(splits, kept, g):
+    x, w, bias = kept
+    k = w.shape[0]
+    with jax.named_scope("model.ssm_gate"):
+        g = jnp.concatenate(g, axis=1)
+        pre = causal_depthwise_conv(x, w, bias)
+        s = jax.nn.sigmoid(pre)
+        gs = g * s * (1 + pre * (1 - s))  # through the SiLU
+        # dx[t] = sum_j w[j] gs[t + (K - 1) - j], zero past the end
+        dx = sum(w[j] * _rows_moved(gs, j + 1 - k) for j in range(k))
+        dw = jnp.stack([jnp.sum(gs * _rows_moved(x, k - 1 - j), axis=0) for j in range(k)])
+        return dx, dw, jnp.sum(gs, axis=0)
+
+
+conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 
 
 def mamba2_mixer(p: Dict[str, Array], x: Array, cfg: NemotronHConfig) -> Array:
@@ -170,11 +211,9 @@ def mamba2_mixer(p: Dict[str, Array], x: Array, cfg: NemotronHConfig) -> Array:
         xbc = x @ p["w_xbc"].astype(x.dtype)
         dt = (x @ p["w_dt"].astype(x.dtype)).astype(jnp.float32)
     with jax.named_scope("model.ssm_gate"):
-        xbc = jax.nn.silu(causal_depthwise_conv(
-            xbc, p["conv_w"].astype(x.dtype), p["conv_b"].astype(x.dtype)))
-        xs = xbc[:, : cfg.d_inner].reshape(t, heads, hd)
-        b = xbc[:, cfg.d_inner: cfg.d_inner + groups * n].reshape(t, groups, n)
-        c = xbc[:, cfg.d_inner + groups * n:].reshape(t, groups, n)
+        xs, b, c = conv_silu(xbc, p["conv_w"].astype(x.dtype), p["conv_b"].astype(x.dtype),
+                             (cfg.d_inner, cfg.d_inner + groups * n))
+        xs, b, c = xs.reshape(t, heads, hd), b.reshape(t, groups, n), c.reshape(t, groups, n)
         delta = jax.nn.softplus(dt + p["dt_bias"])
         a = -jnp.exp(p["a_log"])
         y = ssd_chunked(xs, delta, a, b, c, cfg.chunk_size)
@@ -348,6 +387,7 @@ def nemotron3_nano_ep16(seed: int = 0, dtype: Any = jnp.float32, **overrides: An
 __all__ = [
     "NemotronHConfig",
     "causal_depthwise_conv",
+    "conv_silu",
     "gqa_attention",
     "init_params",
     "mamba2_mixer",

@@ -2,10 +2,12 @@
 against the benchmark's plain reference (``chipbench/reference_nemotron_h``)
 on seeded weights, at small sizes on the CPU: the chunked scan is the
 recurrence, forward and gradient; the share of the experts a chip holds
-ties to the uncut layer; no token is dropped."""
+ties to the uncut layer; no token is dropped; the convolution and its SiLU
+carry a backward of their own that is the plain formula's gradient."""
 
 from __future__ import annotations
 
+import re
 from dataclasses import replace
 
 import jax
@@ -80,6 +82,70 @@ def test_blocked_attention_is_the_full_score_matrix(length):
     _close(y, y_ref)
     for got, want in zip(jax.tree_util.tree_leaves(g), jax.tree_util.tree_leaves(g_ref)):
         _close(got, want, tol=1e-4)
+
+
+def _plain_conv(x, w, bias):
+    """The definition, written out: ``bias + sum_j w[j] x[t - (K - 1) + j]``."""
+    k, t = w.shape[0], x.shape[0]
+    padded = jnp.concatenate([jnp.zeros((k - 1, x.shape[1]), x.dtype), x])
+    return bias + sum(w[j] * padded[j:j + t] for j in range(k))
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["jit", "vmap2"])
+@pytest.mark.parametrize("length", [1, 3, 4, 5, 17, 64])  # under, at and over the four taps
+def test_the_convolutions_own_backward_is_the_plain_formulas_gradient(length, batched):
+    channels = 200  # no whole number of lanes
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(length), 4)
+    shape = (2, length, channels) if batched else (length, channels)
+    x, probe = jax.random.normal(k1, shape), jax.random.normal(k4, shape)
+    w, bias = jax.random.normal(k2, (4, channels)), jax.random.normal(k3, (channels,))
+
+    def value_and_gradients(fn):
+        def read_out(x_, w_, bias_):
+            out = jax.vmap(fn, (0, None, None))(x_, w_, bias_) if batched else fn(x_, w_, bias_)
+            return jnp.sum(out * probe), out
+
+        (_, out), grads = jax.jit(jax.value_and_grad(read_out, argnums=(0, 1, 2), has_aux=True))(
+            x, w, bias)
+        return (out, *grads)
+
+    splits, one = (128, 136), (x[0] if batched else x)  # blocks of 128, 8 and 64 columns
+    assert [blk.shape for blk in nh.conv_silu(one, w, bias, splits)] == [
+        (length, 128), (length, 8), (length, 64)]
+    got = value_and_gradients(
+        lambda *args: jnp.concatenate(nh.conv_silu(*args, splits), axis=1))
+    want = value_and_gradients(lambda *args: jax.nn.silu(_plain_conv(*args)))
+    for mine, plain in zip(got, want):  # value, dx, dw, dbias
+        assert mine.dtype == jnp.float32 and mine.shape == plain.shape
+        _close(mine, plain, tol=1e-5)
+    _close(nh.causal_depthwise_conv(one, w, bias), _plain_conv(one, w, bias), tol=1e-6)
+
+
+def test_the_mixers_gradient_holds_no_padded_copy_of_the_convolution():
+    """What automatic differentiation made of the four taps: four
+    ``dynamic_update_slice`` into zero arrays of ``T + 3`` rows (under
+    ``vmap``: scatter-adds). The lowered gradient holds none outside the
+    scan, which reads its chunks' last rows that way, and stays float32."""
+    p = nh.init_params(TINY, seed=3)[nh.segment_keys(TINY)[1]]
+    x = jax.random.normal(jax.random.PRNGKey(1), (21, TINY.hidden_size))
+
+    def loss(p_, x_, batched):
+        mixer = lambda s: nh.mamba2_mixer(p_, s, TINY)  # noqa: E731
+        return jnp.sum(jax.vmap(mixer)(x_[None]) if batched else mixer(x_))
+
+    for batched in (False, True):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1)), static_argnums=2).lower(
+            p, x, batched).as_text(debug_info=True)
+        named = re.findall(r'^#loc\d+ = loc\("(jit\(loss\)/[^"]*)"', text, re.M)
+        moved = [name for name in named
+                 if name.rsplit("/", 1)[-1] in ("dynamic_update_slice", "scatter", "scatter-add")]
+        assert len(moved) == 1 and "model.ssm_scan" in moved[0], moved
+        assert len(re.findall(r'= "?stablehlo\.(?:dynamic_update_slice|scatter)\b', text)) == 1
+        backward = [name for name in named if re.search(
+            r"transpose\(jvp\((?:vmap\()?model\.ssm_gate\)+/model\.ssm_gate/\w+$", name)]
+        assert {name.rsplit("/", 1)[-1] for name in backward} >= {"logistic", "pad", "reduce_sum"}
+        floats = set(re.findall(r"tensor<(?:\d+x)*((?:bf|f)\d+)>", text))
+        assert floats == {"f32"}, floats
 
 
 def _moe_weights(cfg, seed, experts):

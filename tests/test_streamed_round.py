@@ -377,9 +377,13 @@ def _streamed_texts():
 # nothing else: the combine has a backward of its own (a gather where
 # automatic differentiation put a scatter-add, and the mask of filled slots
 # it goes by); ``tests/test_held_experts_combine.py`` holds the two equal.
+# And again at PR 37 (191ff157... before it), which changed one thing in the
+# Mamba-2 mixer's text and nothing else: ``nemotron_h.conv_silu``, the
+# convolution and its SiLU with a backward of their own, handing out the three
+# column blocks; ``tests/test_nemotron_h.py`` holds it to the plain formula.
 PARENT_STREAMED_TEXTS = {
     "toy-segments": "fef5d5b8f8531f18c4a22b78b0228563705fa3cc64282f66187af19329f058ed",
-    "toy-nemotron": "191ff157d2b31bf7912adb6a908c9e707972bc480698103951e9c3d0b396ea51",
+    "toy-nemotron": "38fcba49e138b03e658910938cbdf0cc89b2ce352a0279bcaeda9008cb0018c8",
 }
 
 
