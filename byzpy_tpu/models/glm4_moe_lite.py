@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from ..ops.pallas_attention import causal_attention, causal_attention_serves
 from ..parallel.moe import held_experts_ffn
 from .bundle import ModelBundle, Segment
-from .layers import blocked_causal_attention, cross_entropy, rms_norm, token_embedding
+from .layers import blocked_causal_attention, cross_entropy, rms_norm, rotary, token_embedding
 
 Array = jnp.ndarray
 
@@ -91,27 +91,6 @@ class Glm4MoeLiteConfig:
 # --------------------------------------------------------------------------
 # multi-head latent attention
 # --------------------------------------------------------------------------
-
-
-def rotary(x: Array, theta: float) -> Array:
-    """Rotary position embedding of ``x (T, ..., dim)``, position = index
-    along the first axis: the pair (``x[..., i]``, ``x[..., i + dim / 2]``)
-    turned by ``t * theta ** (-2 i / dim)``. Written as the 2 x 2 rotation
-    of every pair (a product and a sum over an axis of two), with no slice
-    of ``x``: a slice's cotangent is a zero-padded array, and the two
-    halves' padded cotangents added up fed the weight-gradient product of
-    the shared rotary key on the v5e's compiler in a form that lost it
-    (PERF.md, PR 34)."""
-    t, dim = x.shape[0], x.shape[-1]
-    half = dim // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dim)
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    # turn[t, out, in, i]: out = 0 reads (cos, -sin) of (a, b), out = 1 (sin, cos)
-    turn = jnp.stack([jnp.stack([cos, -sin], axis=1), jnp.stack([sin, cos], axis=1)], axis=1)
-    turn = turn.reshape(t, *(1,) * (x.ndim - 2), 2, 2, half).astype(x.dtype)
-    pairs = x.reshape(*x.shape[:-1], 1, 2, half)
-    return jnp.sum(turn * pairs, axis=-2).reshape(x.shape)
 
 
 def mla_attention(p: Dict[str, Array], x: Array, cfg: Glm4MoeLiteConfig) -> Array:

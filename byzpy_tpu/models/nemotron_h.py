@@ -25,7 +25,6 @@ router's correction bias is a buffer held at zero, so it is left out.
 
 from __future__ import annotations
 
-import functools
 import math
 from dataclasses import dataclass, replace
 from typing import Any, Dict, Tuple
@@ -36,7 +35,14 @@ import jax.numpy as jnp
 from ..ops.pallas_attention import causal_attention, causal_attention_serves
 from ..parallel.moe import held_experts_ffn
 from .bundle import ModelBundle, Segment
-from .layers import blocked_causal_attention, cross_entropy, rms_norm, token_embedding
+from .layers import (
+    blocked_causal_attention,
+    causal_depthwise_conv,
+    conv_silu,
+    cross_entropy,
+    rms_norm,
+    token_embedding,
+)
 
 Array = jnp.ndarray
 
@@ -147,56 +153,6 @@ def ssd_chunked(x: Array, dt: Array, a: Array, b: Array, c: Array, chunk: int) -
         from_start = jnp.exp(cum).reshape(nc, chunk, groups, per)
         y = y + jnp.einsum("clgn,cgrpn,clgr->clgrp", c, entering, from_start)
         return y.reshape(nc * chunk, heads, p)[:t]
-
-
-def _rows_moved(x: Array, by: int) -> Array:
-    """``out[t] = x[t - by]`` along the first axis, zero where ``t - by``
-    falls outside: one ``pad`` that adds ``by`` rows at one end and takes
-    them off the other (no array longer than ``x``)."""
-    return jax.lax.pad(x, jnp.zeros((), x.dtype), ((by, -by, 0),) + ((0, 0, 0),) * (x.ndim - 1))
-
-
-def causal_depthwise_conv(x: Array, w: Array, bias: Array) -> Array:
-    """``out[t] = bias + sum_j w[j] x[t - (K - 1) + j]``, zeros before the start."""
-    k = w.shape[0]
-    out = bias
-    for j in range(k):
-        out = out + w[j] * _rows_moved(x, k - 1 - j)
-    return out
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def conv_silu(x: Array, w: Array, bias: Array, splits: Tuple[int, ...]) -> Tuple[Array, ...]:
-    """``silu(causal_depthwise_conv(x, w, bias))`` as its column blocks, cut
-    at ``splits`` (handed out apart, each block is written once, in the
-    layout its reader asks for; slices of one array are copied), with a
-    backward of its own: the same ``K`` shifted multiply-adds run the
-    other way. (Left to automatic differentiation each tap's transpose is
-    a write into a fresh zero array of ``T + K - 1`` rows, the taps are
-    added and the pad's transpose slices the sum.) Keeps ``x``, ``w`` and
-    ``bias`` alone."""
-    return tuple(jnp.split(jax.nn.silu(causal_depthwise_conv(x, w, bias)), splits, axis=1))
-
-
-def _conv_silu_fwd(x, w, bias, splits):
-    return conv_silu(x, w, bias, splits), (x, w, bias)
-
-
-def _conv_silu_bwd(splits, kept, g):
-    x, w, bias = kept
-    k = w.shape[0]
-    with jax.named_scope("model.ssm_gate"):
-        g = jnp.concatenate(g, axis=1)
-        pre = causal_depthwise_conv(x, w, bias)
-        s = jax.nn.sigmoid(pre)
-        gs = g * s * (1 + pre * (1 - s))  # through the SiLU
-        # dx[t] = sum_j w[j] gs[t + (K - 1) - j], zero past the end
-        dx = sum(w[j] * _rows_moved(gs, j + 1 - k) for j in range(k))
-        dw = jnp.stack([jnp.sum(gs * _rows_moved(x, k - 1 - j), axis=0) for j in range(k)])
-        return dx, dw, jnp.sum(gs, axis=0)
-
-
-conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 
 
 def mamba2_mixer(p: Dict[str, Array], x: Array, cfg: NemotronHConfig) -> Array:

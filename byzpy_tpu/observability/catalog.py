@@ -142,6 +142,7 @@ SPAN_PREFIXES: Tuple[str, ...] = ("chaos.",)
 SCOPES: FrozenSet[str] = frozenset(
     {
         "model.attention",
+        "model.delta_rule",
         "model.embed",
         "model.head",
         "model.mla_latent",

@@ -73,7 +73,16 @@ _CHUNK_ROWS = 1024
 # 1024, 1.45 at 1024 x 1024, 1.69 at 2048 x 1024; dq 2.76 at 256 x 512,
 # 1.93 at 1024 x 512, 1.86 at 1024 x 1024, 2.23 at 2048 x 512; dk / dv 2.88
 # at 256 x 512, 2.48 at 512 x 512, 2.39 at 1024 x 1024, 2.85 at 2048 x 512.
-# Both regimes: a key block meets up to _FOLDED_ROWS rows of queries (a
+# EIGHT query heads a group at head_dim 256 (16 / 2 heads, PR 39): forward
+# 1.19 at 512 x 1024 (the rule's), 1.13 at 512 x 512, 1.14 at 256 x 512,
+# 1.19 at 256 x 1024, 1.21 at 128 x 1024, 1.24 at 1024 x 512, 1.44 at 512 x
+# 2048; dq 1.40 at 512 x 512 (the rule's, the least), 1.41 at 256 x 512, 1.44
+# at 128 x 512, 1.51 at 512 x 1024, 1.55 at 512 x 256; dk / dv 1.72 at 512 x
+# 512 (the rule's, the least), 1.73 at 256 x 512, 1.79 at 512 x 256, 1.85 at
+# 128 x 512, 2.78 at 512 x 1024, 2.79 at 1024 x 512; a query block of 1024
+# (8,192 folded rows of 256) does not fit VMEM in the forward or in dq. The
+# rule stands: its forward is within a twentieth of the least read.
+# All three regimes: a key block meets up to _FOLDED_ROWS rows of queries (a
 # group's heads times the query block), and the backward's kernels, which
 # hold two products' tiles a pair, keep heads x query block x key block
 # within _BACKWARD_TILE.
@@ -88,8 +97,10 @@ def causal_attention_serves(x: Array, head_dim: int) -> bool:
     device-sharded (:func:`~byzpy_tpu.ops.pallas_kernels.
     sharding_allows_pallas`). Any length: the wrapper pads it to whole
     blocks. Asked once a call, in Python, by ``models.nemotron_h.
-    gqa_attention`` and ``models.glm4_moe_lite.mla_attention``; reads no
-    environment variable."""
+    gqa_attention`` (sixteen query heads a key/value head of 128),
+    ``models.glm4_moe_lite.mla_attention`` (one of 256) and
+    ``models.qwen3_next.gated_attention`` (eight of 256; :func:`_blocks`
+    has what each regime measured); reads no environment variable."""
     return bool(
         _pk._on_tpu()
         and x.dtype in (jnp.float32, jnp.bfloat16)

@@ -45,8 +45,9 @@ def _round(dtype, tokens=96, held=4, rows=32, d=16):
     rank = jnp.cumsum(routed, axis=0, dtype=jnp.int32) - 1
     mine = routed & (rank < rows)
     slot = jnp.where(mine, jnp.arange(held)[None, :] * rows + rank, held * rows)
+    # a slot's one reader, as its place in the (tokens, held) gates laid flat
     token_at = jnp.zeros((held * rows,), jnp.int32).at[slot.reshape(-1)].set(
-        jnp.repeat(jnp.arange(tokens, dtype=jnp.int32), held), mode="drop")
+        jnp.arange(tokens * held, dtype=jnp.int32), mode="drop")
     filled = jnp.sum(mine, axis=0)
     holds_token = (jnp.arange(rows)[None, :] < filled[:, None]).reshape(held * rows)
     per_expert = jax.random.normal(ks[1], (held * rows, d)).astype(dtype)
@@ -155,7 +156,7 @@ def test_an_expert_no_token_reaches_gets_an_exactly_zero_gradient():
 def test_the_layer_takes_and_reports_what_it_did():
     assert list(inspect.signature(moe.held_experts_ffn).parameters) == [
         "x", "router_w", "w_up", "w_down", "shared_up", "shared_down", "first_held", "n_experts",
-        "top_k", "scale", "round_rows", "w_gate", "shared_gate"]
+        "top_k", "scale", "round_rows", "w_gate", "shared_gate", "score", "shared_weight"]
     x, p = _layer(True)
     assert sorted(_ffn(x, p, None)[1]) == ["expert_rounds", "held_expert_tokens", "tokens_dropped"]
     assert "os.environ" not in inspect.getsource(moe) and "getenv" not in inspect.getsource(moe)
@@ -168,3 +169,116 @@ def test_the_layer_lowers_to_one_batched_product_a_round(gated):
     assert "pallas" not in text and "custom_call" not in text
     # every slot of a round in one batched product: (held, rows, D) x (held, D, F)
     assert f"tensor<{HELD}x{T // 4}x{LD}xf32>" in text and "dot_general" in text
+
+
+# -- many held experts with a light load each: 32 of 512, top-10 by a softmax ------
+
+MANY_HELD, MANY_EXPERTS, MANY_TOP_K = 32, 512, 10
+
+
+def _many_layer(seed=11):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 10)
+    p = {"router_w": jax.random.normal(ks[1], (LD, MANY_EXPERTS)) * 0.3,
+         "w_up": jax.random.normal(ks[2], (MANY_HELD, LD, LF)) / 11,
+         "w_down": jax.random.normal(ks[3], (MANY_HELD, LF, LD)) / 8,
+         "w_gate": jax.random.normal(ks[4], (MANY_HELD, LD, LF)) / 11,
+         "shared_up": jax.random.normal(ks[5], (LD, LF)) / 11,
+         "shared_down": jax.random.normal(ks[6], (LF, LD)) / 8,
+         "shared_gate": jax.random.normal(ks[7], (LD, LF)) / 11,
+         "shared_weight": jax.random.normal(ks[8], (LD, 1)) / 11}
+    return jax.random.normal(ks[0], (T, LD)), p
+
+
+def _many_ffn(x, p, round_rows, first_held=64):
+    return moe.held_experts_ffn(x, **p, first_held=first_held, n_experts=MANY_EXPERTS,
+                                top_k=MANY_TOP_K, round_rows=round_rows, score=jax.nn.softmax)
+
+
+# a held expert gets about 10 of the 512 tokens (512 x 10 / 512): one round
+# of 40 slots (four times the mean), several of 8
+@pytest.mark.parametrize("round_rows, one_round", [(40, True), (8, False)])
+def test_thirty_two_held_of_512_top_10_is_the_layer_differentiated_automatically(
+        monkeypatch, round_rows, one_round):
+    x, p = _many_layer()
+    names = sorted(p)
+
+    def loss(x_, weights):
+        out, aux = _many_ffn(x_, dict(zip(names, weights)), round_rows)
+        return jnp.sum(out * jnp.cos(out)), (out, aux)
+
+    results = []
+    for combine in (_plain_combine, moe._combine):
+        monkeypatch.setattr(moe, "_combine", combine)
+        (_, (out, aux)), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+            x, [p[k] for k in names])
+        results.append((out, aux, grads))
+    (out, aux, grads), (out_own, aux_own, grads_own) = results
+    counts = np.asarray(aux_own["held_expert_tokens"])
+    assert counts.shape == (MANY_HELD,) and int(aux_own["tokens_dropped"]) == 0
+    assert (int(aux_own["expert_rounds"]) == 1) == one_round
+    assert int(aux_own["expert_rounds"]) == max(1, -(-int(counts.max()) // round_rows))
+    # what the router sent here: a token's picks among experts 64 .. 95
+    scores = jax.nn.softmax(jnp.dot(x, p["router_w"], precision=jax.lax.Precision.HIGHEST))
+    picked = np.asarray(jax.lax.top_k(scores, MANY_TOP_K)[1])
+    np.testing.assert_array_equal(
+        counts, [(picked == 64 + e).sum() for e in range(MANY_HELD)])
+    np.testing.assert_array_equal(out_own, out)
+    for got, want in zip(jax.tree_util.tree_leaves(grads_own), jax.tree_util.tree_leaves(grads)):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
+def test_a_round_moves_its_slots_and_the_tokens_picks_never_tokens_times_held_rows():
+    """At 32 held experts a ``(T, held, D)`` read is 16 times the filled
+    slots: the lowered layer, forward and backward, holds no tensor of
+    ``T x held`` rows, only the round's ``held x rows`` and the picks' ``T x k``."""
+    x, p = _many_layer()
+    text = jax.jit(jax.grad(lambda x_, p_: jnp.sum(_many_ffn(x_, p_, 40)[0] ** 2), (0, 1))).lower(
+        x, p).as_text()
+    assert f"tensor<{T}x{MANY_HELD}x{LD}xf32>" not in text
+    assert f"tensor<{T * MANY_HELD}x{LD}xf32>" not in text
+    assert f"tensor<{T}x{MANY_TOP_K}x{LD}xf32>" in text  # a token reads its picks
+    assert f"tensor<{MANY_HELD}x40x{LD}xf32>" in text  # the round's slots
+    # the one scatter left places the picks' readers in the round's slots:
+    # T x k int32 indices, no rows
+    jaxpr = str(jax.make_jaxpr(
+        jax.grad(lambda x_, p_: jnp.sum(_many_ffn(x_, p_, 40)[0] ** 2), (0, 1)))(x, p))
+    scatters = [line for line in jaxpr.splitlines() if "= scatter[" in line]
+    assert scatters and all(f":i32[{MANY_HELD * 40}] =" in line for line in scatters)
+    # the scatter-adds left are the transpose of the gather before the products
+    # (the round's held x rows slots added into the tokens they came from) and
+    # top_k's own (a token's k scores back into its row of all experts' scores)
+    adds = [line for line in jaxpr.splitlines() if "= scatter-add[" in line]
+    assert adds and all(f":f32[{T},{LD}] =" in line or f":f32[{T},{MANY_EXPERTS}] =" in line
+                        for line in adds)
+
+
+def test_a_softmax_router_weighs_by_the_softmax_over_all_experts():
+    """All 512 experts held as 16 shares of 32: the shares add up to every
+    expert on every token under the dense mask of the router's top 10."""
+    x, p = _many_layer()
+    x = x[:64]
+    ks = jax.random.split(jax.random.PRNGKey(12), 3)
+    full = {"w_up": jax.random.normal(ks[0], (MANY_EXPERTS, LD, LF)) / 11,
+            "w_down": jax.random.normal(ks[1], (MANY_EXPERTS, LF, LD)) / 8,
+            "w_gate": jax.random.normal(ks[2], (MANY_EXPERTS, LD, LF)) / 11}
+    with jax.default_matmul_precision("highest"):
+        total = 0.0
+        for first in range(0, MANY_EXPERTS, MANY_HELD):
+            part = {k: v[first: first + MANY_HELD] for k, v in full.items()}
+            out, aux = moe.held_experts_ffn(
+                x, p["router_w"], part["w_up"], part["w_down"], first_held=first,
+                n_experts=MANY_EXPERTS, top_k=MANY_TOP_K, w_gate=part["w_gate"],
+                score=jax.nn.softmax)
+            total = total + out
+            assert int(aux["tokens_dropped"]) == 0
+        probs = jax.nn.softmax(x @ p["router_w"], axis=-1)
+        top_p, top_e = jax.lax.top_k(probs, MANY_TOP_K)
+        weight = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        np.testing.assert_allclose(np.asarray(jnp.sum(weight, axis=-1)), 1.0, atol=1e-6)
+        want = jnp.zeros_like(x)
+        for j in range(MANY_TOP_K):
+            e = top_e[:, j]
+            hidden = jax.nn.silu(jnp.einsum("td,tdf->tf", x, full["w_gate"][e])) * jnp.einsum(
+                "td,tdf->tf", x, full["w_up"][e])
+            want = want + weight[:, j, None] * jnp.einsum("tf,tfd->td", hidden, full["w_down"][e])
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want), rtol=2e-4, atol=2e-5)

@@ -39,7 +39,10 @@ PARTS = {"nemotron": ["model.norm", "model.embed", "model.head", "model.ssm_proj
                       "model.moe_experts", "model.moe_shared", "stream.rows", "stream.boundary"],
          "glm": ["model.norm", "model.embed", "model.head", "model.mlp", "model.attention",
                  "model.mla_latent", "model.moe_route", "model.moe_experts", "model.moe_shared",
-                 "model.mtp_join", "stream.rows", "stream.boundary"]}
+                 "model.mtp_join", "stream.rows", "stream.boundary"],
+         "qwen": ["model.norm", "model.embed", "model.head", "model.ssm_proj", "model.ssm_gate",
+                  "model.delta_rule", "model.attention", "model.moe_route", "model.moe_experts",
+                  "model.moe_shared", "stream.rows", "stream.boundary"]}
 
 
 def _toy(model):
@@ -52,6 +55,15 @@ def _toy(model):
             num_key_value_heads=2, head_dim=8, query_block=8, n_routed_experts=16,
             num_experts_per_tok=3, moe_intermediate_size=24,
             moe_shared_expert_intermediate_size=40, held_experts=(4, 4)), seed=0)
+    if model == "qwen":
+        from byzpy_tpu.models import qwen3_next as qn
+
+        return qn.qwen3_next_ep16(
+            0, hidden_size=32, num_hidden_layers=2, full_attention_interval=2, vocab_size=64,
+            linear_num_key_heads=2, linear_num_value_heads=4, linear_key_head_dim=8,
+            linear_value_head_dim=8, chunk_size=8, num_attention_heads=4, num_key_value_heads=2,
+            head_dim=8, query_block=8, num_experts=16, num_experts_per_tok=3,
+            moe_intermediate_size=24, shared_expert_intermediate_size=24, held_experts=(4, 4))
     from byzpy_tpu.models import glm4_moe_lite as glm
 
     return glm.glm47_flash_ep8(
@@ -99,7 +111,7 @@ def _bare(compiled_text):
                      if not _FRAMES.match(line))
 
 
-@pytest.fixture(scope="module", params=["nemotron", "glm"])
+@pytest.fixture(scope="module", params=["nemotron", "glm", "qwen"])
 def step_text(request):
     """``(model, segment keys, [(opcode, op_name)] of the compiled step, its
     bare text)``."""
@@ -180,7 +192,8 @@ def test_every_op_of_a_round_scope_holds_one_segment_and_every_segment_appears(s
 
 def test_the_norm_is_a_part_of_its_own_inside_the_latents_and_the_gate(step_text):
     model, _, ops, _ = step_text
-    outer = "model.ssm_gate" if model == "nemotron" else "model.mla_latent"
+    outer = {"nemotron": "model.ssm_gate", "glm": "model.mla_latent",
+             "qwen": "model.attention"}[model]
     nested = [name for _, name in ops if outer in name and "model.norm" in name]
     assert nested and all(part_of(name) == "model.norm" for name in nested)
     for a_pass in ("round.segment_fwd", "round.segment_recompute", "round.segment_bwd"):
@@ -188,13 +201,13 @@ def test_the_norm_is_a_part_of_its_own_inside_the_latents_and_the_gate(step_text
 
 
 def test_the_convolutions_own_backward_stays_in_the_gate(step_text):
-    """``nemotron_h.conv_silu``'s backward is traced outside the mixer's
+    """``layers.conv_silu``'s backward is traced outside the mixer's
     scope (a ``custom_vjp``), enters ``model.ssm_gate`` itself, and leaves
     nothing of the gate that writes into a padded copy."""
     model, _, ops, _ = step_text
     own = [(opcode, name) for opcode, name in ops
            if re.search(r"model\.ssm_gate\)+/model\.ssm_gate/\w+$", name)]
-    if model != "nemotron":
+    if model == "glm":
         assert not own
         return
     assert {name.rsplit("/", 1)[-1] for _, name in own} >= {"pad", "mul", "add", "reduce_sum"}
@@ -246,10 +259,18 @@ def test_a_scope_is_metadata_and_nothing_else(step_text, monkeypatch):
 # Mamba-2 mixer's text and nothing else: the convolution and its SiLU are one
 # function with a backward of its own that hands out its three column blocks
 # (``nemotron_h.conv_silu``); tests/test_nemotron_h.py holds it to the plain
-# formula's value and gradient. "glm" holds as PR 36 took it.
+# formula's value and gradient. Both were taken again at PR 39 (38fcba49...
+# and 49bf5224... before it, which still hold with the parent's
+# ``parallel/moe.py`` under that PR's tree: moving ``conv_silu`` and ``rotary``
+# to ``models/layers.py`` changed nothing), which changed the expert layer's
+# text alone: a round places its readers by flat index and, where that at least
+# halves the columns, reads back by a token's picks;
+# tests/test_held_experts_combine.py holds the layer to the one it was.
+# "qwen" is PR 39's own.
 PARENT_LOWERED = {
-    "nemotron": "38fcba49e138b03e658910938cbdf0cc89b2ce352a0279bcaeda9008cb0018c8",
-    "glm": "49bf5224febaa2683af482d83f21467757708436271e27f90d233e859a5a1624",
+    "nemotron": "0914aa048638ecdaa9b21b9d2fe3dc1b7bfbb744d6551e3cff05a5e88c6a8433",
+    "glm": "a7a4f5673d35e52100fda989105cbd2be98addabfcb3ec648e89d18c9b6110bb",
+    "qwen": "04808da9bb5f3fc9dc113e83f3cd6fd50b8fad515f20f2c0f787dc59e4bd766c",
 }
 
 
@@ -262,7 +283,8 @@ def test_the_toy_streamed_steps_lower_to_the_parents_text(model):
 # -- the catalog and byzlint hold the labels ----------------------------------
 
 NEW_SCOPES = ["model.norm", "model.embed", "model.head", "model.ssm_proj", "model.ssm_gate",
-              "model.mlp", "model.moe_shared", "model.mtp_join", "stream.rows", "stream.boundary"]
+              "model.mlp", "model.moe_shared", "model.mtp_join", "stream.rows", "stream.boundary",
+              "model.delta_rule"]
 
 
 @pytest.mark.parametrize("scope", NEW_SCOPES)
@@ -295,6 +317,6 @@ def test_byzlint_flags_a_computed_scope_outside_every_catalogued_prefix():
 def test_byzlint_is_clean_on_the_modules_that_enter_the_labels():
     paths = [os.path.join(ROOT, "byzpy_tpu", *parts) for parts in (
         ("parallel", "ps.py"), ("parallel", "moe.py"), ("models", "nemotron_h.py"),
-        ("models", "glm4_moe_lite.py"), ("models", "layers.py"))]
+        ("models", "glm4_moe_lite.py"), ("models", "layers.py"), ("models", "qwen3_next.py"))]
     result = scan_paths(paths, select=[METRIC_CONTRACT])
     assert [f.message for f in result.findings if f.rule == METRIC_CONTRACT] == []
