@@ -187,7 +187,9 @@ SCOPE_PREFIXES: Tuple[str, ...] = ("segment.",)
 #: the custom call's instruction name and a segment of its ``op_name``
 #: in the compiled text, so a reader finds the kernel after a refactor.
 #: Not every kernel is an aggregate: ``causal_attention_*`` are the
-#: model's (``ops/pallas_attention.py``, under ``model.attention``)
+#: model's (``ops/pallas_attention.py``, under ``model.attention``), and so
+#: is ``rows_to_tokens`` (``ops/pallas_rows_to_tokens.py``, under
+#: ``model.moe_experts``)
 KERNELS: FrozenSet[str] = frozenset(
     {
         "arc_selection_mean_stream",
@@ -206,6 +208,7 @@ KERNELS: FrozenSet[str] = frozenset(
         "quantize_s4_pallas",
         "ragged_segment_sum",
         "ragged_segment_sum_dequant",
+        "rows_to_tokens",
         "selection_from_gram",
         "selection_mean_stream",
         "sort_columns",

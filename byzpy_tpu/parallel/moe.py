@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.pallas_rows_to_tokens import rows_to_tokens, rows_to_tokens_serves
 from . import collectives
 
 Array = jnp.ndarray
@@ -127,15 +128,16 @@ def _expert_round(x, weights, gate, expert, rank, counts, r, rows):
     """Round ``r`` of the held experts' part: every expert multiplies the
     ``rows`` of its tokens whose rank among them is in ``[r rows, (r + 1)
     rows)``. ``gate``, ``expert``, ``rank`` are ``(T, k)``, a column a pick
-    of the token's (or a held expert, picked or not): its weight, its
-    expert's place among the held (``held`` where the column is empty) and
-    the token's rank among that expert's tokens.
+    of the token's that is held here: its weight, its expert's place among
+    the held (``held`` where the column is empty) and the token's rank among
+    that expert's tokens.
     Returns the round's share of ``out (T, D)`` and how many (token,
     expert) pairs it computed. What is gathered, multiplied and read back
-    is the round's ``held x rows`` slots and the tokens' ``T x k`` picks,
-    never ``T x held`` rows (at 32 held experts of 512 and 10 picks a token,
-    0.6 of a token's picks are held: ``T x held`` rows are 50 times the
-    filled slots)."""
+    is the round's ``held x rows`` slots, of which the filled ones go back
+    to the tokens (:func:`_rows_to_tokens`): never ``T x held`` rows and
+    never ``T x k`` (at 32 held experts of 512 and 10 picks a token, 0.6 of
+    a token's picks are held: ``T x held`` rows are 50 times the filled
+    slots, ``T x k`` sixteen times)."""
     tokens, d = x.shape
     picks = gate.shape[1]
     held = weights[0].shape[0]
@@ -145,36 +147,82 @@ def _expert_round(x, weights, gate, expert, rank, counts, r, rows):
     # a filled slot's one reader, as its place in the (T, k) picks laid flat
     reader_at = jnp.zeros((held * rows,), jnp.int32).at[slot.reshape(-1)].set(
         jnp.arange(tokens * picks, dtype=jnp.int32), mode="drop")
+    # (held,): an expert's tokens fill its first slots
+    filled = jnp.clip(counts - r * rows, 0, rows)
     per_expert = jax.vmap(_expert)(
-        x[reader_at // picks].reshape(held, rows, d), *weights).reshape(held * rows, d)
-    filled = jnp.clip(counts - r * rows, 0, rows)  # an expert's first slots
-    holds_token = (jnp.arange(rows)[None, :] < filled[:, None]).reshape(held * rows)
-    out = _combine(per_expert, gate.astype(x.dtype), slot, reader_at, holds_token)
+        _dispatch(x, reader_at, slot, filled).reshape(held, rows, d), *weights).reshape(
+            held * rows, d)
+    out = _combine(per_expert, gate.astype(x.dtype), slot, reader_at, filled)
     return out, jnp.sum(mine, dtype=jnp.int32)
 
 
+def _rows_to_tokens(rows, gate, slot, reader_at, filled):
+    """Rows back to the tokens that read them: ``out[t] = sum_j gate[t, j]
+    rows[slot[t, j]]`` over a token's columns in their order, multiplied and
+    added in float32; a column whose ``slot`` is past the end of ``rows``
+    reads nothing. No ``(T, k, D)`` array is made. On a TPU one kernel that
+    walks the filled slots only, by the same map the other way (``reader_at``,
+    and ``filled (held,)``, an expert's live slots: :func:`~byzpy_tpu.ops.
+    pallas_rows_to_tokens.rows_to_tokens_serves` says where it serves);
+    elsewhere the same sum as ``k`` takes of ``(T, D)``."""
+    if rows_to_tokens_serves(rows):
+        return rows_to_tokens(rows, gate, reader_at, filled)
+    out = jnp.zeros((slot.shape[0], rows.shape[1]), jnp.float32)
+    for j in range(slot.shape[1]):
+        read = jnp.take(rows, slot[:, j], axis=0, mode="fill", fill_value=0)
+        out = out + gate[:, j, None].astype(jnp.float32) * read.astype(jnp.float32)
+    return out.astype(rows.dtype)
+
+
 @jax.custom_vjp
-def _combine(per_expert, gate, slot, reader_at, holds_token):
+def _dispatch(x, reader_at, slot, filled):
+    """To the slots: slot ``s`` gets the token of its one reader,
+    ``reader_at[s]`` of the ``(T, k)`` picks laid flat (a slot no pick fills
+    gets token 0, and what is computed there reaches nothing). The cotangent
+    into the tokens is the same read the other way with every weight one,
+    ``sum_j d_rows[slot[t, j]]`` over a token's filled picks: a filled slot
+    has exactly one reader, so it is the gather's transpose term for term.
+    (Left to automatic differentiation that transpose is a scatter-add of
+    ``held x rows`` rows, three quarters of them zero.)"""
+    return x[reader_at // slot.shape[1]]
+
+
+def _dispatch_fwd(x, reader_at, slot, filled):
+    return _dispatch(x, reader_at, slot, filled), (reader_at, slot, filled)
+
+
+def _dispatch_bwd(kept, d_rows):
+    reader_at, slot, filled = kept
+    ones = jnp.ones(slot.shape, d_rows.dtype)
+    return _rows_to_tokens(d_rows, ones, slot, reader_at, filled), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(per_expert, gate, slot, reader_at, filled):
     """Back to the tokens: ``sum_j gate[t, j] per_expert[slot[t, j]]`` over
-    a token's picks. Each token reads its slots (a slot past the end reads
-    zero; a slot no token fills is read by none). A filled slot is read by
+    a token's picks (:func:`_rows_to_tokens`: a slot past the end reads
+    nothing; a slot no token fills is read by none). A filled slot is read by
     exactly one pick, ``reader_at[s]`` of the picks laid flat, so the
     backward is a gather too: the cotangent of slot ``s`` is ``gate * d_out``
     of that one reader. (Left to automatic differentiation the take's
     transpose is a scatter-add of as many rows as there are picks, nearly
     all of them zero, into ``held x rows``: the slowest operation of the
     layer on a TPU.)"""
-    read = jnp.take(per_expert, slot, axis=0, mode="fill", fill_value=0)  # (T, k, D)
-    return jnp.einsum("te,ted->td", gate, read)
+    return _rows_to_tokens(per_expert, gate, slot, reader_at, filled)
 
 
-def _combine_fwd(per_expert, gate, slot, reader_at, holds_token):
-    return (_combine(per_expert, gate, slot, reader_at, holds_token),
-            (per_expert, gate, slot, reader_at, holds_token))
+def _combine_fwd(per_expert, gate, slot, reader_at, filled):
+    return (_combine(per_expert, gate, slot, reader_at, filled),
+            (per_expert, gate, slot, reader_at, filled))
 
 
 def _combine_bwd(kept, d_out):
-    per_expert, gate, slot, reader_at, holds_token = kept
+    per_expert, gate, slot, reader_at, filled = kept
+    rows = per_expert.shape[0] // filled.shape[0]
+    holds_token = (jnp.arange(rows)[None, :] < filled[:, None]).reshape(-1)
     d_read = d_out[reader_at // gate.shape[1]]  # (held x rows, D): what a slot's reader hands back
     gate_at = gate.reshape(-1)[reader_at]
     keep = holds_token[:, None]
@@ -309,24 +357,16 @@ def held_experts_ffn(
         counts = jnp.sum(routed, axis=0, dtype=jnp.int32)  # (held,)
         # a token's place among its expert's tokens
         rank_of = jnp.cumsum(routed, axis=0, dtype=jnp.int32) - 1
-        # what a round reads back, a token: one column a held expert, or, where
-        # that at least halves them, one column a pick of the token's that is
-        # held here, in the order of the experts (so that a token's sum does not
-        # hang on the order of its scores): column c holds its c-th, by
-        # selection over (T, c, held), no gather. (Measured on the v5e, PR 39,
-        # against a column a held expert at held = 8: 4 columns +0.43 % of the
-        # GLM cell's rate, 6 columns -1.43 % of the Nemotron cell's: a read of
-        # (T, 6, D) is relaid to whole sublanes, one of (T, 8, D) is not.)
+        # what a round reads back, a token: one column a pick of the token's
+        # that is held here, in the order of the experts (so that a token's sum
+        # does not hang on the order of its scores): column c holds its c-th,
+        # by selection over (T, c, held), no gather
         picks = min(top_k, held)
-        if 2 * picks <= held:
-            nth = jnp.cumsum(routed, axis=1, dtype=jnp.int32) - 1
-            chosen = routed[:, None, :] & (nth[:, None, :] == jnp.arange(picks)[None, :, None])
-            expert = jnp.min(jnp.where(chosen, jnp.arange(held), held), axis=-1)  # held: none
-            gate = jnp.sum(jnp.where(chosen, weight_of[:, None, :], 0.0), axis=-1)
-            rank = jnp.sum(jnp.where(chosen, rank_of[:, None, :], 0), axis=-1)
-        else:
-            expert = jnp.where(routed, jnp.arange(held)[None, :], held)
-            gate, rank = weight_of, rank_of
+        nth = jnp.cumsum(routed, axis=1, dtype=jnp.int32) - 1
+        chosen = routed[:, None, :] & (nth[:, None, :] == jnp.arange(picks)[None, :, None])
+        expert = jnp.min(jnp.where(chosen, jnp.arange(held), held), axis=-1)  # held: none
+        gate = jnp.sum(jnp.where(chosen, weight_of[:, None, :], 0.0), axis=-1)
+        rank = jnp.sum(jnp.where(chosen, rank_of[:, None, :], 0), axis=-1)
     with jax.named_scope("model.moe_experts"):
         weights = (w_up, w_down) if w_gate is None else (w_up, w_down, w_gate)
         out, computed, rounds = _expert_rounds(x, weights, gate, expert, rank, counts, rows)

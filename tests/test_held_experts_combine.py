@@ -13,12 +13,20 @@ Held here:
   weight's, two-matrix and gated experts, in one round, two and four; no token
   dropped, the counts unchanged;
 * an expert no token reaches gets an exactly zero gradient;
-* what the layer takes and reports is what it took and reported.
+* what the layer takes and reports is what it took and reported;
+* the rows' way back to the tokens (``moe._rows_to_tokens``: the combine's
+  forward and the dispatch gather's backward) is the sum written out as a
+  Python double loop; its kernel (``ops/pallas_rows_to_tokens.py``, here
+  through the interpreter) is the plain form, value and gradients, under
+  ``jit`` and ``vmap``; one predicate says which of the two runs; the layer
+  is the dense every-expert reference differentiated by ``jax.grad``; no
+  ``(T, k, D)`` array and no scatter of rows is left in its lowered gradient.
 """
 
 from __future__ import annotations
 
 import inspect
+import re
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +46,17 @@ def _plain_combine(per_expert, gate, slot, token_at=None, holds_token=None):
     return jnp.einsum("te,ted->td", gate, read)
 
 
+def _plain_dispatch(x, reader_at, slot, filled=None):
+    """The gather to the slots as the parent wrote it, left to automatic
+    differentiation (its transpose: a scatter-add of ``held x rows`` rows)."""
+    return x[reader_at // slot.shape[1]]
+
+
+def _automatic(monkeypatch):
+    monkeypatch.setattr(moe, "_combine", _plain_combine)
+    monkeypatch.setattr(moe, "_dispatch", _plain_dispatch)
+
+
 def _round(dtype, tokens=96, held=4, rows=32, d=16):
     ks = jax.random.split(jax.random.PRNGKey(5), 4)
     routed = jax.random.uniform(ks[0], (tokens, held)) < 0.3
@@ -53,7 +72,7 @@ def _round(dtype, tokens=96, held=4, rows=32, d=16):
     per_expert = jax.random.normal(ks[1], (held * rows, d)).astype(dtype)
     gate = jnp.where(mine, jax.random.uniform(ks[2], (tokens, held)), 0.0).astype(dtype)
     d_out = jax.random.normal(ks[3], (tokens, d)).astype(dtype)
-    return mine, slot, token_at, holds_token, per_expert, gate, d_out
+    return mine, slot, token_at, (filled, holds_token), per_expert, gate, d_out
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
@@ -61,10 +80,10 @@ def test_the_combines_gather_backward_is_the_takes_transpose(dtype):
     """A filled slot is read by exactly one (token, expert) pair, so what
     automatic differentiation does with a scatter-add of T x held rows the
     combine's own backward does with one gather of held x rows."""
-    mine, slot, token_at, holds_token, per_expert, gate, d_out = _round(dtype)
+    mine, slot, token_at, (filled, holds_token), per_expert, gate, d_out = _round(dtype)
     # whatever sits in a slot no token fills reaches nothing
     poisoned = jnp.where(holds_token[:, None], per_expert, jnp.nan)
-    out, pull = jax.vjp(lambda p_, g_: moe._combine(p_, g_, slot, token_at, holds_token),
+    out, pull = jax.vjp(lambda p_, g_: moe._combine(p_, g_, slot, token_at, filled),
                         poisoned, gate)
     want, pull_want = jax.vjp(lambda p_, g_: _plain_combine(p_, g_, slot),
                               jnp.where(holds_token[:, None], per_expert, 0), gate)
@@ -80,11 +99,11 @@ def test_the_combines_gather_backward_is_the_takes_transpose(dtype):
 
 
 def test_the_combines_backward_holds_no_scatter_add():
-    _, slot, token_at, holds_token, per_expert, gate, d_out = _round(jnp.float32)
+    _, slot, token_at, (filled, _holds), per_expert, gate, d_out = _round(jnp.float32)
 
     def backward(combine):
         return str(jax.make_jaxpr(lambda p_, g_, d_: jax.vjp(
-            lambda p, g: combine(p, g, slot, token_at, holds_token), p_, g_)[1](d_))(
+            lambda p, g: combine(p, g, slot, token_at, filled), p_, g_)[1](d_))(
                 per_expert, gate, d_out))
 
     assert "scatter-add" in backward(_plain_combine)
@@ -128,19 +147,22 @@ def test_the_layer_with_the_gather_backward_is_the_layer_differentiated_automati
         return jnp.sum(out * jnp.cos(out)), (out, aux)
 
     results = []
-    for combine in (_plain_combine, moe._combine):
-        monkeypatch.setattr(moe, "_combine", combine)
+    for automatic in (False, True):
+        if automatic:
+            _automatic(monkeypatch)
         (_, (out, aux)), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
             x, [p[k] for k in names])
         results.append((out, aux, grads))
-    (out, aux, grads), (out_own, aux_own, grads_own) = results
+    (out_own, aux_own, grads_own), (out, aux, grads) = results
     assert int(aux_own["expert_rounds"]) == rounds and int(aux_own["tokens_dropped"]) == 0
     assert int(jnp.max(aux_own["held_expert_tokens"])) > (rounds - 1) * (round_rows or T // 4)
     for key in aux:
         np.testing.assert_array_equal(aux[key], aux_own[key])
-    np.testing.assert_array_equal(out_own, out)  # the forward is the parent's, to the letter
+    # the forward is the parent's terms in the parent's order, multiplied and added
+    # in float32 one column at a time where the parent's einsum summed them at once
+    np.testing.assert_allclose(out_own, out, rtol=1e-6, atol=1e-6)
     for got, want in zip(jax.tree_util.tree_leaves(grads_own), jax.tree_util.tree_leaves(grads)):
-        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)  # gradients of 1 to 300
 
 
 def test_an_expert_no_token_reaches_gets_an_exactly_zero_gradient():
@@ -207,12 +229,13 @@ def test_thirty_two_held_of_512_top_10_is_the_layer_differentiated_automatically
         return jnp.sum(out * jnp.cos(out)), (out, aux)
 
     results = []
-    for combine in (_plain_combine, moe._combine):
-        monkeypatch.setattr(moe, "_combine", combine)
+    for automatic in (False, True):
+        if automatic:
+            _automatic(monkeypatch)
         (_, (out, aux)), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
             x, [p[k] for k in names])
         results.append((out, aux, grads))
-    (out, aux, grads), (out_own, aux_own, grads_own) = results
+    (out_own, aux_own, grads_own), (out, aux, grads) = results
     counts = np.asarray(aux_own["held_expert_tokens"])
     assert counts.shape == (MANY_HELD,) and int(aux_own["tokens_dropped"]) == 0
     assert (int(aux_own["expert_rounds"]) == 1) == one_round
@@ -222,7 +245,7 @@ def test_thirty_two_held_of_512_top_10_is_the_layer_differentiated_automatically
     picked = np.asarray(jax.lax.top_k(scores, MANY_TOP_K)[1])
     np.testing.assert_array_equal(
         counts, [(picked == 64 + e).sum() for e in range(MANY_HELD)])
-    np.testing.assert_array_equal(out_own, out)
+    np.testing.assert_allclose(out_own, out, rtol=1e-6, atol=1e-6)
     for got, want in zip(jax.tree_util.tree_leaves(grads_own), jax.tree_util.tree_leaves(grads)):
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
@@ -230,13 +253,15 @@ def test_thirty_two_held_of_512_top_10_is_the_layer_differentiated_automatically
 def test_a_round_moves_its_slots_and_the_tokens_picks_never_tokens_times_held_rows():
     """At 32 held experts a ``(T, held, D)`` read is 16 times the filled
     slots: the lowered layer, forward and backward, holds no tensor of
-    ``T x held`` rows, only the round's ``held x rows`` and the picks' ``T x k``."""
+    ``T x held`` rows and none of ``T x k`` rows either, only the round's
+    ``held x rows`` slots and ``(T, D)`` sums."""
     x, p = _many_layer()
     text = jax.jit(jax.grad(lambda x_, p_: jnp.sum(_many_ffn(x_, p_, 40)[0] ** 2), (0, 1))).lower(
         x, p).as_text()
     assert f"tensor<{T}x{MANY_HELD}x{LD}xf32>" not in text
     assert f"tensor<{T * MANY_HELD}x{LD}xf32>" not in text
-    assert f"tensor<{T}x{MANY_TOP_K}x{LD}xf32>" in text  # a token reads its picks
+    assert f"tensor<{T}x{MANY_TOP_K}x{LD}xf32>" not in text  # nor a token's picks side by side
+    assert f"tensor<{T * MANY_TOP_K}x{LD}xf32>" not in text
     assert f"tensor<{MANY_HELD}x40x{LD}xf32>" in text  # the round's slots
     # the one scatter left places the picks' readers in the round's slots:
     # T x k int32 indices, no rows
@@ -244,12 +269,11 @@ def test_a_round_moves_its_slots_and_the_tokens_picks_never_tokens_times_held_ro
         jax.grad(lambda x_, p_: jnp.sum(_many_ffn(x_, p_, 40)[0] ** 2), (0, 1)))(x, p))
     scatters = [line for line in jaxpr.splitlines() if "= scatter[" in line]
     assert scatters and all(f":i32[{MANY_HELD * 40}] =" in line for line in scatters)
-    # the scatter-adds left are the transpose of the gather before the products
-    # (the round's held x rows slots added into the tokens they came from) and
-    # top_k's own (a token's k scores back into its row of all experts' scores)
+    # the one scatter-add left is top_k's own (a token's k scores back into its
+    # row of all experts' scores): the dispatch gather's transpose into the
+    # tokens is a read of the filled slots since PR 40
     adds = [line for line in jaxpr.splitlines() if "= scatter-add[" in line]
-    assert adds and all(f":f32[{T},{LD}] =" in line or f":f32[{T},{MANY_EXPERTS}] =" in line
-                        for line in adds)
+    assert adds and all(f":f32[{T},{MANY_EXPERTS}] =" in line for line in adds)
 
 
 def test_a_softmax_router_weighs_by_the_softmax_over_all_experts():
@@ -282,3 +306,227 @@ def test_a_softmax_router_weighs_by_the_softmax_over_all_experts():
                 "td,tdf->tf", x, full["w_up"][e])
             want = want + weight[:, j, None] * jnp.einsum("tf,tfd->td", hidden, full["w_down"][e])
     np.testing.assert_allclose(np.asarray(total), np.asarray(want), rtol=2e-4, atol=2e-5)
+
+
+# -- rows back to the tokens: the plain form, its kernel, the predicate (PR 40) ----
+
+from byzpy_tpu.ops import pallas_rows_to_tokens as prt  # noqa: E402
+
+
+def _kernel_serves(rows):
+    """The route's own gate with the backend's answer left out: what a TPU
+    would be asked, answered here by the interpreter."""
+    return rows.dtype in (jnp.float32, jnp.bfloat16) and rows.shape[1] % 128 == 0
+
+
+def _read_back(tokens, held, per, k, d, dtype, seed=7):
+    """A round's read-back as the layer lays it out: ``held`` runs of ``per``
+    slots, a token's place in a run its rank among the run's tokens, column c
+    of a token its c-th held pick. Most columns empty; token 3 picks every
+    expert it may, token 5 none, nobody picks the last expert; a run that
+    overflows sends its later tokens to another round (a slot far past the
+    end); the slots no token fills hold NaN."""
+    rng = np.random.default_rng(seed)
+    routed = rng.random((tokens, held)) < 0.3
+    routed[:, held - 1] = False
+    routed[3 % tokens] = True
+    routed[3 % tokens, held - 1] = False
+    routed[5 % tokens] = False
+    routed &= np.cumsum(routed, axis=1) <= k  # at most k picks a token
+    rank = np.cumsum(routed, axis=0) - 1
+    n_slots = held * per
+    slot = np.full((tokens, k), n_slots, np.int32)
+    reader_at = np.zeros((n_slots,), np.int32)
+    for t in range(tokens):
+        for c, e in enumerate(np.flatnonzero(routed[t])):
+            if rank[t, e] < per:
+                slot[t, c] = e * per + rank[t, e]
+                reader_at[slot[t, c]] = t * k + c
+            else:
+                slot[t, c] = n_slots + 1000
+    filled = np.minimum(routed.sum(axis=0), per).astype(np.int32)
+    live = (np.arange(per)[None, :] < filled[:, None]).reshape(-1)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    rows = jnp.where(live[:, None], jax.random.normal(ks[0], (n_slots, d)), jnp.nan).astype(dtype)
+    gate = jax.random.uniform(ks[1], (tokens, k), minval=0.1).astype(dtype)
+    return rows, gate, jnp.asarray(slot), jnp.asarray(reader_at), jnp.asarray(filled)
+
+
+@pytest.mark.parametrize("tokens, held, per, k, d", [(37, 4, 8, 3, 16), (64, 8, 16, 6, 256),
+                                                     (9, 2, 4, 1, 130)],
+                         ids=["t37-k3", "t64-k6-d256", "t9-k1-d130"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_rows_to_tokens_is_the_sum_written_out(tokens, held, per, k, d, dtype):
+    rows, gate, slot, reader_at, filled = _read_back(tokens, held, per, k, d, dtype)
+    got = moe._rows_to_tokens(rows, gate, slot, reader_at, filled)
+    assert got.shape == (tokens, d) and got.dtype == dtype
+    rows_, gate_ = np.asarray(rows, np.float32), np.asarray(gate, np.float32)
+    want = np.zeros((tokens, d), np.float32)
+    for t in range(tokens):
+        for j in range(k):  # a token's columns in their order
+            if slot[t, j] < held * per:
+                want[t] += gate_[t, j] * rows_[slot[t, j]]
+    tol = dict(rtol=1e-6, atol=1e-6) if dtype == jnp.float32 else dict(rtol=1e-2, atol=1e-2)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, **tol)
+    assert not np.any(np.asarray(got, np.float32)[5 % tokens])  # a token with no filled pick
+    assert np.any(want[3 % tokens]) and np.all(np.isfinite(want))
+
+
+# (tokens, held, per, k, D, block): several filled picks a token; T over a block,
+# under one and no multiple of it; runs that are no whole tiles; a run that
+# overflows; more tiles a block than copies in flight
+KERNEL_SHAPES = [(64, 8, 16, 6, 256, 16), (50, 4, 9, 4, 128, 16), (300, 32, 10, 10, 256, 128),
+                 (300, 8, 80, 4, 128, None), (9, 2, 4, 1, 128, None)]
+
+
+@pytest.mark.parametrize("how", ["jit", "vmap2"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("tokens, held, per, k, d, block", KERNEL_SHAPES,
+                         ids=[f"t{s[0]}-h{s[1]}x{s[2]}-k{s[3]}-d{s[4]}" for s in KERNEL_SHAPES])
+def test_the_kernel_is_the_plain_form(tokens, held, per, k, d, block, dtype, how):
+    """The Mosaic kernel's program, run by the interpreter, against the plain
+    form: a token's terms in the same order, multiplied and added in float32;
+    a NaN in a slot no token fills reaches nothing."""
+    cases = [_read_back(tokens, held, per, k, d, dtype, seed=7 + i) for i in range(2)]
+
+    def kernel(rows, gate, slot, reader_at, filled):
+        return prt.rows_to_tokens(rows, gate, reader_at, filled, block=block)
+
+    if how == "jit":
+        got, want = jax.jit(kernel)(*cases[0]), moe._rows_to_tokens(*cases[0])
+    else:
+        stacked = [jnp.stack(a) for a in zip(*cases)]
+        got, want = jax.jit(jax.vmap(kernel))(*stacked), jax.vmap(moe._rows_to_tokens)(*stacked)
+    assert got.shape == want.shape and got.dtype == dtype
+    assert np.all(np.isfinite(np.asarray(got, np.float32)))
+    tol = dict(rtol=1e-6, atol=1e-6) if dtype == jnp.float32 else dict(rtol=1e-2, atol=1e-2)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), **tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_the_combine_and_the_dispatch_through_the_kernel_are_those_through_the_plain_form(
+        monkeypatch, dtype):
+    """Value and both gradients of the combine, and the dispatch gather's
+    cotangent into the tokens, with the kernel serving (interpreted) and with
+    the plain form; whatever sits in a slot no token fills reaches nothing."""
+    mine, slot, reader_at, (filled, holds_token), per_expert, gate, d_out = _round(dtype, d=128)
+    poisoned = jnp.where(holds_token[:, None], per_expert, jnp.nan)
+    x = jax.random.normal(jax.random.PRNGKey(9), (mine.shape[0], 128)).astype(dtype)
+
+    def both():
+        out, pull = jax.vjp(lambda p_, g_: moe._combine(p_, g_, slot, reader_at, filled),
+                            poisoned, gate)
+        gathered, pull_x = jax.vjp(lambda x_: moe._dispatch(x_, reader_at, slot, filled), x)
+        return (out, *pull(d_out), gathered, *pull_x(poisoned))
+
+    plain = both()
+    calls = []
+    monkeypatch.setattr(moe, "rows_to_tokens_serves", lambda rows: calls.append(rows.shape) or
+                        _kernel_serves(rows))
+    served = both()
+    assert len(calls) == 2  # the combine's forward, the dispatch's backward
+    tol = dict(rtol=1e-6, atol=1e-6) if dtype == jnp.float32 else dict(rtol=2e-2, atol=2e-2)
+    for got, want in zip(served, plain):
+        assert got.dtype == want.dtype == dtype and np.all(np.isfinite(np.asarray(got, np.float32)))
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), **tol)
+    # the dispatch's own backward is the gather's transpose, a scatter-add, term for
+    # term (the cotangent of a slot no token fills is zero there: `_combine`'s backward)
+    want_dx = jax.vjp(lambda x_: _plain_dispatch(x_, reader_at, slot), x)[1](
+        jnp.where(holds_token[:, None], per_expert, 0))[0]
+    np.testing.assert_allclose(np.asarray(served[-1], np.float32), np.asarray(want_dx, np.float32),
+                               **tol)
+
+
+@pytest.mark.parametrize("shape, dtype, serves", [
+    ((320, 256), jnp.float32, True), ((320, 2688), jnp.float32, True),
+    ((320, 256), jnp.bfloat16, True), ((320, 130), jnp.float32, False),
+    ((320, 200), jnp.bfloat16, False), ((320, 256), jnp.float16, False),
+    ((4, 80, 256), jnp.float32, False)],
+    ids=["f32-d256", "f32-d2688", "bf16-d256", "f32-d130", "bf16-d200", "f16", "rank3"])
+def test_one_predicate_says_whether_the_kernel_serves(monkeypatch, shape, dtype, serves):
+    """Backend, dtype and D, read in one place: on a CPU never; on a TPU for
+    float32 / bfloat16 rows of whole lanes. An odd D takes the plain form."""
+    rows = jnp.zeros(shape, dtype)
+    assert not prt.rows_to_tokens_serves(rows)  # this process runs on a CPU
+    monkeypatch.setattr(prt._pk, "_on_tpu", lambda: True)
+    assert prt.rows_to_tokens_serves(rows) == serves
+    source = inspect.getsource(prt)
+    assert "os.environ" not in source and "getenv" not in source
+
+
+def _dense_reference(x, p, first_held=0):
+    """Every held expert on every token under a dense mask of the router's
+    top picks, at full precision: what ``jax.grad`` differentiates."""
+    with jax.default_matmul_precision("highest"):
+        scores = jax.nn.sigmoid(x @ p["router_w"])
+        top_s, top_e = jax.lax.top_k(scores, TOP_K)
+        weight = top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+        out = jnp.zeros_like(x)
+        for e in range(p["w_up"].shape[0]):
+            w_e = jnp.sum(jnp.where(top_e == first_held + e, weight, 0.0), axis=-1)
+            if "w_gate" in p:
+                hidden = jax.nn.silu(x @ p["w_gate"][e]) * (x @ p["w_up"][e])
+            else:
+                hidden = jnp.square(jax.nn.relu(x @ p["w_up"][e]))
+            out = out + w_e[:, None] * (hidden @ p["w_down"][e])
+        if "shared_gate" in p:
+            shared = (jax.nn.silu(x @ p["shared_gate"]) * (x @ p["shared_up"])) @ p["shared_down"]
+        else:
+            shared = jnp.square(jax.nn.relu(x @ p["shared_up"])) @ p["shared_down"]
+        return out + shared
+
+
+@pytest.mark.parametrize("route", ["plain", "kernel"])
+@pytest.mark.parametrize("gated", [False, True], ids=["relu2", "gated"])
+def test_the_layer_is_the_dense_reference_differentiated_by_jax_grad(monkeypatch, gated, route):
+    """Value and the gradients of x, router, up, down (and gate) against
+    ``jax.grad`` of the dense every-expert reference, at a size whose fullest
+    expert needs a second round; with the kernel serving too."""
+    if route == "kernel":
+        monkeypatch.setattr(moe, "rows_to_tokens_serves", _kernel_serves)
+    x, p = _layer(gated)
+    names = sorted(p)
+
+    def loss(fn):
+        def f(x_, weights):
+            out = fn(x_, dict(zip(names, weights)))
+            return jnp.sum(out * jnp.cos(out)), out
+        return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(x, [p[k] for k in names])
+
+    with jax.default_matmul_precision("highest"):
+        aux = _ffn(x, p, None)[1]
+        assert int(aux["expert_rounds"]) == 2 and int(aux["tokens_dropped"]) == 0
+        (_, out), grads = loss(lambda x_, p_: _ffn(x_, p_, None)[0])
+    (_, out_want), grads_want = loss(_dense_reference)
+    np.testing.assert_allclose(out, out_want, rtol=2e-4, atol=2e-5)
+    for name, got, want in zip(["x"] + names, jax.tree_util.tree_leaves(grads),
+                               jax.tree_util.tree_leaves(grads_want)):
+        np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("route", ["plain", "kernel"])
+def test_the_lowered_gradient_scatters_no_rows_and_holds_no_picks_side_by_side(
+        monkeypatch, route):
+    """On the lowered gradient of a toy expert block: no scatter whose update
+    is D wide, no ``(T, k, D)`` tensor, and the kernel's calls (forward and
+    the dispatch's backward) stand under ``model.moe_experts``."""
+    if route == "kernel":
+        monkeypatch.setattr(moe, "rows_to_tokens_serves", _kernel_serves)
+    x, p = _layer(True)
+    grad = jax.grad(lambda x_, p_: jnp.sum(_ffn(x_, p_, None)[0] ** 2), (0, 1))
+    text = jax.jit(grad).lower(x, p).as_text(debug_info=True)
+    scatters = [line for line in text.splitlines() if "stablehlo.scatter" in line]
+    assert scatters and not [line for line in scatters if f"x{LD}xf32>" in line]
+    for picks in (TOP_K, HELD):
+        assert f"tensor<{T}x{picks}x{LD}xf32>" not in text
+        assert f"tensor<{T * picks}x{LD}xf32>" not in text
+    jaxpr = str(jax.make_jaxpr(grad)(x, p))
+    assert not [line for line in jaxpr.splitlines()
+                if "scatter" in line and f",{LD}]" in line.split("=")[0]]
+    if route == "kernel":
+        assert jaxpr.count("name=rows_to_tokens") >= 2  # forward and the dispatch's backward
+        # the kernel's name is a segment of its ops' `op_name`, inside the part's scope
+        compiled = jax.jit(grad).lower(x, p).compile().as_text()
+        named = set(re.findall(r'op_name="([^"]*rows_to_tokens/[^"]*)"', compiled))
+        assert named and all("model.moe_experts" in name for name in named)
+        assert any(name.startswith("jit(<lambda>)/transpose(") for name in named)  # the backward's

@@ -266,11 +266,18 @@ def test_a_scope_is_metadata_and_nothing_else(step_text, monkeypatch):
 # text alone: a round places its readers by flat index and, where that at least
 # halves the columns, reads back by a token's picks;
 # tests/test_held_experts_combine.py holds the layer to the one it was.
-# "qwen" is PR 39's own.
+# "qwen" is PR 39's own. All three were taken again at PR 40 (0914aa04...,
+# a7a4f567... and 04808da9... before it), which changed the expert layer's
+# text alone: a round's rows go back to the tokens by one read a column,
+# multiplied and added in float32 (``moe._rows_to_tokens``: the combine's
+# forward and the dispatch gather's own backward; no ``(T, k, D)`` array, no
+# scatter-add of rows), and every model takes a column a pick;
+# tests/test_held_experts_combine.py holds the layer to automatic
+# differentiation of the one it was and to the dense reference.
 PARENT_LOWERED = {
-    "nemotron": "0914aa048638ecdaa9b21b9d2fe3dc1b7bfbb744d6551e3cff05a5e88c6a8433",
-    "glm": "a7a4f5673d35e52100fda989105cbd2be98addabfcb3ec648e89d18c9b6110bb",
-    "qwen": "04808da9bb5f3fc9dc113e83f3cd6fd50b8fad515f20f2c0f787dc59e4bd766c",
+    "nemotron": "096a8c43ea20e23734bdcfdae98cae66626b4f3cb089f09496dc149ac04cd2cc",
+    "glm": "f400e96fe68b6a88edff8bd5148c5bc19d8071fd12e1c1da7a3df5c4f2fb2cca",
+    "qwen": "85d70f5d84607f6a37ed0e8a5f606c8e17dbd3f3b2878e2b0db80f9971ea188e",
 }
 
 

@@ -717,6 +717,31 @@ def _write_tpu_texts(out_dir):
               encoding="utf-8") as fh:
         fh.write(text)
 
+    # the held experts' layer, value and gradient, at the three language cells'
+    # tokens, picks, held experts, round and width (the experts' own width cut
+    # to 128: the read-back kernel sees none of it)
+    from byzpy_tpu.parallel import moe
+
+    for name, (n_experts, top_k, held, round_rows, d) in EXPERT_CELLS.items():
+        def f32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+        text = jax.jit(jax.grad(
+            lambda x, w: jnp.sum(moe.held_experts_ffn(
+                x, *w, first_held=0, n_experts=n_experts, top_k=top_k, round_rows=round_rows)[0]),
+            argnums=(0, 1))).lower(
+            f32(EXPERT_TOKENS, d),
+            (f32(d, n_experts), f32(held, d, 128), f32(held, 128, d))).compile().as_text()
+        with open(os.path.join(out_dir, f"experts_{name}.hlo.txt"), "w", encoding="utf-8") as fh:
+            fh.write(text)
+
+
+# the language cells' expert layers: experts, picks a token, experts held, a
+# round's rows, hidden size (`chipbench/configs/`), at a worker's 4096 tokens
+EXPERT_TOKENS = 4096
+EXPERT_CELLS = {"nemotron": (128, 6, 8, 1024, 2688), "glm": (64, 4, 8, 1024, 2048),
+                "qwen": (512, 10, 32, 320, 2048)}
+
 
 @pytest.fixture(scope="module")
 def tpu_texts(tmp_path_factory):
@@ -739,7 +764,7 @@ def tpu_texts(tmp_path_factory):
                     f"(exit {done.returncode}):\n{done.stderr[-3000:]}")
     texts = {}
     for name in [*FOLDED_ROUNDS, "attention_float32", "attention_bfloat16",
-                 "mla_attention_float32"]:
+                 "mla_attention_float32", *(f"experts_{cell}" for cell in EXPERT_CELLS)]:
         with open(os.path.join(out_dir, name + ".hlo.txt"), encoding="utf-8") as fh:
             texts[name] = fh.read()
     return texts
@@ -848,6 +873,34 @@ def test_on_the_tpu_latent_attention_is_the_same_three_kernels_one_query_head_a_
     reader = _benchmark_reader("attention_kernel_calls.train")
     assert reader.read(SimpleNamespace(
         outcome={"compiled_text": tpu_texts["mla_attention_float32"]})) == 3
+
+
+# -- the held experts' read-back, compiled by Mosaic ------------------------------
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+def test_on_the_tpu_the_rows_go_back_to_the_tokens_by_one_mosaic_kernel(tpu_texts, cell):
+    """At a language cell's tokens, picks, held experts, round and width Mosaic
+    takes the kernel; it runs twice a round (the combine's forward, the
+    dispatch gather's backward) under ``model.moe_experts``; the layer's
+    compiled gradient scatters no row and holds no ``(T, k, D)`` array."""
+    text = tpu_texts[f"experts_{cell}"]
+    _n_experts, top_k, held, round_rows, d = EXPERT_CELLS[cell]
+    calls = [line for line in text.splitlines()
+             if re.match(r"\s*(?:ROOT )?%rows_to_tokens(?:\.\d+)? = ", line)]
+    assert len(calls) == 2 and all("tpu_custom_call" in line for line in calls)
+    for line in calls:
+        assert "model.moe_experts" in re.search(r'op_name="([^"]*)"', line).group(1)
+        # the table stays in HBM as the products leave it; the sum comes out (T, D)
+        assert f"f32[{held * round_rows},{d}]" in line.partition("custom-call(")[2]
+        assert f"f32[{EXPERT_TOKENS},{d}]" in line.partition(" custom-call(")[0]
+    assert any("transpose(" in re.search(r'op_name="([^"]*)"', line).group(1) for line in calls)
+    for line in text.splitlines():
+        if re.search(r" scatter\(| scatter-add\(", line) or "%scatter" in line.split("=")[0]:
+            assert f",{d}]" not in line.partition("=")[2].partition("(")[0], line
+    for picks in {top_k, held}:
+        assert f"[{EXPERT_TOKENS},{picks},{d}]" not in text
+        assert f"[{EXPERT_TOKENS * picks},{d}]" not in text
 
 
 def test_on_the_tpu_attention_leaves_no_score_matrix_in_the_program(tpu_texts):

@@ -32,7 +32,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FIXTURES = os.path.join(ROOT, "tests", "fixtures", "analysis")
 ROUND_SCOPE = re.compile(r"round\.[a-z_]+")
 KERNEL_FILES = ("byzpy_tpu/ops/pallas_kernels.py", "byzpy_tpu/ops/pallas_attention.py",
-                "byzpy_tpu/parallel/quantization.py")
+                "byzpy_tpu/ops/pallas_rows_to_tokens.py", "byzpy_tpu/parallel/quantization.py")
 
 
 def _sign_flip(honest, key):

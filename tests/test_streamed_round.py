@@ -387,9 +387,14 @@ def _streamed_texts():
 # expert layer's text alone: a round places its readers by flat index and, where
 # that at least halves the columns, reads back by a token's picks;
 # ``tests/test_held_experts_combine.py`` holds the layer to the one it was.
+# And again at PR 40 (0914aa04... before it), which changed the expert layer's
+# text alone: a round's rows go back to the tokens by one read a column in
+# float32, as the combine's forward and as the dispatch gather's own backward
+# (no ``(T, k, D)`` array, no scatter-add of rows), a column a pick in every
+# model; ``tests/test_held_experts_combine.py`` holds it to the one it was.
 PARENT_STREAMED_TEXTS = {
     "toy-segments": "fef5d5b8f8531f18c4a22b78b0228563705fa3cc64282f66187af19329f058ed",
-    "toy-nemotron": "0914aa048638ecdaa9b21b9d2fe3dc1b7bfbb744d6551e3cff05a5e88c6a8433",
+    "toy-nemotron": "096a8c43ea20e23734bdcfdae98cae66626b4f3cb089f09496dc149ac04cd2cc",
 }
 
 
