@@ -37,15 +37,14 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, replace
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..ops.pallas_attention import causal_attention, causal_attention_serves
 from ..parallel.moe import held_experts_ffn
 from .bundle import ModelBundle, Segment
-from .layers import blocked_causal_attention, cross_entropy, rms_norm, rotary, token_embedding
+from .layers import YarnScaling, cross_entropy, mla_attention, rms_norm, rotary, token_embedding
 
 Array = jnp.ndarray
 
@@ -72,6 +71,7 @@ class Glm4MoeLiteConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 256
     rope_theta: float = 1e6
+    rope_scaling: Optional[YarnScaling] = None  # the source's: null
     query_block: int = 512
     # feed-forward
     intermediate_size: int = 10240
@@ -86,52 +86,6 @@ class Glm4MoeLiteConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
-
-
-# --------------------------------------------------------------------------
-# multi-head latent attention
-# --------------------------------------------------------------------------
-
-
-def mla_attention(p: Dict[str, Array], x: Array, cfg: Glm4MoeLiteConfig) -> Array:
-    """Multi-head latent attention of one sequence ``(T, hidden)``, causal.
-    A head's query and key are ``[no position | rotary]`` of
-    ``qk_nope_head_dim + qk_rope_head_dim``; the rotary key is one vector
-    a position, shared by every head. The core is ``num_attention_heads``
-    key/value heads with one query head each: the block-causal kernels
-    where they serve (:func:`~byzpy_tpu.ops.pallas_attention.
-    causal_attention_serves`: a TPU, both head sizes equal and in whole
-    lanes), :func:`~byzpy_tpu.models.layers.blocked_causal_attention`
-    elsewhere."""
-    with jax.named_scope("model.attention"):
-        t = x.shape[0]
-        heads, nope, rope, vd = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                                 cfg.qk_rope_head_dim, cfg.v_head_dim)
-        with jax.named_scope("model.mla_latent"):
-            w = {name: p[name].astype(x.dtype)
-                 for name in ("w_qa", "w_qb", "w_kva", "w_kr", "w_kvb")}
-            c_q = rms_norm(x @ w["w_qa"], p["q_norm_scale"], cfg.rms_norm_eps)
-            q = (c_q @ w["w_qb"]).reshape(t, heads, nope + rope)
-            c_kv = rms_norm(x @ w["w_kva"], p["kv_norm_scale"], cfg.rms_norm_eps)
-            kv = (c_kv @ w["w_kvb"]).reshape(t, heads, nope + vd)
-            q = jnp.concatenate([q[..., :nope], rotary(q[..., nope:], cfg.rope_theta)], axis=-1)
-            k_rope = rotary(x @ w["w_kr"], cfg.rope_theta)
-            k = jnp.concatenate(
-                [kv[..., :nope], jnp.broadcast_to(k_rope[:, None, :], (t, heads, rope))], axis=-1)
-            v = kv[..., nope:]
-        if nope + rope == vd and causal_attention_serves(x, vd):
-            out = causal_attention(
-                q.reshape(t, heads * vd), k.reshape(t, heads * vd), v.reshape(t, heads * vd),
-                kv_heads=heads)
-        else:
-            # blocked_causal_attention takes one head size and scales by it:
-            # the narrower side is padded with zeros, the scale put right
-            width = max(nope + rope, vd)
-            q = q * math.sqrt(width / (nope + rope))
-            q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, width - a.shape[-1]))) for a in (q, k, v))
-            out = blocked_causal_attention(q[:, :, None, :], k, v, cfg.query_block)
-            out = out.reshape(t, heads, width)[..., :vd].reshape(t, heads * vd)
-        return out @ p["w_o"].astype(x.dtype)
 
 
 # --------------------------------------------------------------------------

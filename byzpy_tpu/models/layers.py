@@ -1,18 +1,22 @@
 """What the language models of this package share: RMSNorm in its two
-forms, rotary positions, the chain's embedding link, next-token
-cross-entropy, the causal depthwise convolution of the state-space and
-linear-attention mixers with its SiLU, and causal attention by blocks of
-queries for a call the block-causal kernels do not serve
+forms, rotary positions (plain, or with YaRN's blended frequencies), the
+chain's embedding link, next-token cross-entropy, the causal depthwise
+convolution of the state-space and linear-attention mixers with its SiLU,
+multi-head latent attention, and causal attention by blocks of queries for
+a call the block-causal kernels do not serve
 (:func:`~byzpy_tpu.ops.pallas_attention.causal_attention_serves`)."""
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Optional, Tuple
+from dataclasses import dataclass
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ..ops.pallas_attention import causal_attention, causal_attention_serves
 
 Array = jnp.ndarray
 
@@ -31,10 +35,67 @@ def rms_norm_one_plus(x: Array, weight: Array, eps: float) -> Array:
         return rms_norm(x, 1.0 + weight.astype(jnp.float32), eps)
 
 
-def rotary(x: Array, theta: float) -> Array:
+@dataclass(frozen=True)
+class YarnScaling:
+    """A configuration's ``rope_scaling`` of ``type: yarn`` (Peng et al.,
+    arXiv:2309.00071, as DeepSeek-V2 / V3 read it): the rotary pairs that
+    turn fast keep their frequency, the slow ones' is divided by
+    ``factor``, and those between are blended."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def blend_range(self, dim: int, theta: float) -> Tuple[int, int]:
+        """``(low, high)``: pair ``i <= low`` keeps its frequency, pair
+        ``i >= high`` is interpolated. A pair that makes ``beta`` turns over
+        the original context is pair ``dim ln(original / (2 pi beta)) /
+        (2 ln theta)``."""
+        def pair_of(turns: float) -> float:
+            return dim * math.log(self.original_max_position_embeddings / (2 * math.pi * turns)) \
+                / (2 * math.log(theta))
+
+        low = max(math.floor(pair_of(self.beta_fast)), 0)
+        high = min(math.ceil(pair_of(self.beta_slow)), dim - 1)
+        return low, high
+
+    def frequencies(self, dim: int, theta: float) -> Array:
+        """Pair ``i``'s frequency, ``(dim / 2,)`` float32: ``theta_i m_i +
+        (theta_i / factor)(1 - m_i)`` with ``theta_i = theta ** (-2 i /
+        dim)`` and ``m_i = 1 - clip((i - low) / (high - low), 0, 1)``."""
+        pair = jnp.arange(dim // 2, dtype=jnp.float32)
+        plain = theta ** (-pair * 2.0 / dim)
+        low, high = self.blend_range(dim, theta)
+        keep = 1.0 - jnp.clip((pair - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return plain * keep + plain / self.factor * (1.0 - keep)
+
+    @staticmethod
+    def _mscale(factor: float, by: float) -> float:
+        return 0.1 * by * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    @property
+    def rotary_scale(self) -> float:
+        """What cos and sin are multiplied by."""
+        return self._mscale(self.factor, self.mscale) / self._mscale(self.factor,
+                                                                     self.mscale_all_dim)
+
+    @property
+    def softmax_scale(self) -> float:
+        """What the scores' ``head_dim ** -0.5`` is multiplied by:
+        ``mscale(factor, mscale_all_dim) ** 2`` (DeepSeek-V2 / V3's reading
+        of ``mscale_all_dim``)."""
+        return self._mscale(self.factor, self.mscale_all_dim) ** 2
+
+
+def rotary(x: Array, theta: float, scaling: Optional[YarnScaling] = None) -> Array:
     """Rotary position embedding of ``x (T, ..., dim)``, position = index
     along the first axis: the pair (``x[..., i]``, ``x[..., i + dim / 2]``)
-    turned by ``t * theta ** (-2 i / dim)``. Written as the 2 x 2 rotation
+    turned by ``t * theta ** (-2 i / dim)``, or by ``t`` times ``scaling``'s
+    blended frequency, cos and sin times its ``rotary_scale``. Written as the
+    2 x 2 rotation
     of every pair (a product and a sum over an axis of two), with no slice
     of ``x``: a slice's cotangent is a zero-padded array, and the two
     halves' padded cotangents added up fed the weight-gradient product of
@@ -42,9 +103,14 @@ def rotary(x: Array, theta: float) -> Array:
     (PERF.md, PR 34)."""
     t, dim = x.shape[0], x.shape[-1]
     half = dim // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dim)
+    if scaling is None:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dim)
+    else:
+        freq = scaling.frequencies(dim, theta)
     angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if scaling is not None and scaling.rotary_scale != 1.0:
+        cos, sin = cos * scaling.rotary_scale, sin * scaling.rotary_scale
     # turn[t, out, in, i]: out = 0 reads (cos, -sin) of (a, b), out = 1 (sin, cos)
     turn = jnp.stack([jnp.stack([cos, -sin], axis=1), jnp.stack([sin, cos], axis=1)], axis=1)
     turn = turn.reshape(t, *(1,) * (x.ndim - 2), 2, 2, half).astype(x.dtype)
@@ -147,11 +213,70 @@ def blocked_causal_attention(q: Array, k: Array, v: Array, query_block: int) -> 
     return jax.lax.map(one_block, (q, starts)).reshape(-1, kv * per * hd)[:t]
 
 
+def mla_attention(p: Dict[str, Array], x: Array, cfg: Any) -> Array:
+    """Multi-head latent attention (DeepSeek-V2 / V3) of one sequence
+    ``(T, hidden)``, causal, in its uncompressed training form: ONE function
+    for every configuration that has it (``models.glm4_moe_lite``: 20 heads
+    of 192 + 64 / 256; ``models.xing4``: 32 of 128 + 64 / 128 under YaRN),
+    everything read from ``cfg`` (``num_attention_heads``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+    ``rope_theta``, ``rope_scaling``: ``None`` or a :class:`YarnScaling`,
+    ``rms_norm_eps``, ``query_block``). A head's query and key are ``[no
+    position | rotary]``; the rotary key is one vector a position, shared
+    by every head; the scores' scale is ``(nope + rope) ** -0.5`` times the
+    scaling's ``softmax_scale``. The core is ``num_attention_heads``
+    key/value heads with one query head each: the block-causal kernels
+    where they serve (:func:`~byzpy_tpu.ops.pallas_attention.
+    causal_attention_serves`: a TPU; the queries and keys go in at whole
+    lanes, zero columns behind the rotary part where ``nope + rope`` is not
+    a multiple of 128, and the values at their own width, never padded),
+    :func:`blocked_causal_attention` elsewhere (the CPU's route; it takes
+    one head size, so there the narrower side is padded)."""
+    with jax.named_scope("model.attention"):
+        t = x.shape[0]
+        heads, nope, rope, vd = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                                 cfg.qk_rope_head_dim, cfg.v_head_dim)
+        scaling = cfg.rope_scaling
+        stretch = 1.0 if scaling is None else scaling.softmax_scale
+        lanes = -(nope + rope) % 128  # zero columns up to whole lanes
+        serves = causal_attention_serves(x, nope + rope + lanes, vd)
+        with jax.named_scope("model.mla_latent"):
+            w = {name: p[name].astype(x.dtype)
+                 for name in ("w_qa", "w_qb", "w_kva", "w_kr", "w_kvb")}
+            c_q = rms_norm(x @ w["w_qa"], p["q_norm_scale"], cfg.rms_norm_eps)
+            q = (c_q @ w["w_qb"]).reshape(t, heads, nope + rope)
+            c_kv = rms_norm(x @ w["w_kva"], p["kv_norm_scale"], cfg.rms_norm_eps)
+            kv = (c_kv @ w["w_kvb"]).reshape(t, heads, nope + vd)
+            blank = [jnp.zeros((t, heads, lanes), x.dtype)] if serves and lanes else []
+            q = jnp.concatenate(
+                [q[..., :nope], rotary(q[..., nope:], cfg.rope_theta, scaling)] + blank, axis=-1)
+            k_rope = rotary(x @ w["w_kr"], cfg.rope_theta, scaling)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_rope[:, None, :], (t, heads, rope))] + blank,
+                axis=-1)
+            v = kv[..., nope:]
+        if serves:
+            out = causal_attention(
+                q.reshape(t, -1), k.reshape(t, -1), v.reshape(t, heads * vd), kv_heads=heads,
+                scale=stretch / math.sqrt(nope + rope))
+        else:
+            # blocked_causal_attention takes one head size and scales by it:
+            # the narrower side is padded with zeros, the scale put right
+            width = max(nope + rope, vd)
+            q = q * (math.sqrt(width / (nope + rope)) * stretch)
+            q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, width - a.shape[-1]))) for a in (q, k, v))
+            out = blocked_causal_attention(q[:, :, None, :], k, v, cfg.query_block)
+            out = out.reshape(t, heads, width)[..., :vd].reshape(t, heads * vd)
+        return out @ p["w_o"].astype(x.dtype)
+
+
 __all__ = [
+    "YarnScaling",
     "blocked_causal_attention",
     "causal_depthwise_conv",
     "conv_silu",
     "cross_entropy",
+    "mla_attention",
     "rms_norm",
     "rms_norm_one_plus",
     "rotary",

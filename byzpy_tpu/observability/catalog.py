@@ -144,6 +144,8 @@ SCOPES: FrozenSet[str] = frozenset(
         "model.attention",
         "model.delta_rule",
         "model.embed",
+        "model.hc_maps",
+        "model.hc_mix",
         "model.head",
         "model.mla_latent",
         "model.mlp",

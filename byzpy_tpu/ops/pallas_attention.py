@@ -1,11 +1,16 @@
 """Block-causal grouped-query attention of one sequence as Pallas TPU
 kernels: a forward kernel and the two kernels of its backward.
 
-``softmax(q k^T / sqrt(head_dim) + causal mask) v`` for ``H`` query heads
-that share ``kv_heads`` key/value heads, on the projections as they leave
-the matrix products: ``q (T, H * head_dim)``, ``k``, ``v``
-``(T, kv_heads * head_dim)``, the output ``(T, H * head_dim)``. Nothing is
-transposed or repeated in HBM on the way in or out.
+``softmax(scale q k^T + causal mask) v`` for ``H`` query heads that share
+``kv_heads`` key/value heads, on the projections as they leave the matrix
+products: ``q (T, H * qk_dim)``, ``k (T, kv_heads * qk_dim)``,
+``v (T, kv_heads * v_dim)``, the output ``(T, H * v_dim)``. Queries and keys
+have one width a head and values (with the output, its cotangent and the
+softmax's row sums) another, each whole lanes: latent attention's 192 / 128
+comes with its queries and keys zero-padded to 256 (a contraction over 192
+costs the 128-wide MXU two passes as 256 does) and its values at 128, so
+``p v``, ``do v^T`` and ``p^T do`` are half what a padded value would cost.
+Nothing is transposed or repeated in HBM on the way in or out.
 
 * The grid of every kernel is (key/value head, pair), and the pairs are the
   (query block, key block) pairs AT OR UNDER the diagonal, listed in Python
@@ -82,6 +87,21 @@ _CHUNK_ROWS = 1024
 # 128 x 512, 2.78 at 512 x 1024, 2.79 at 1024 x 512; a query block of 1024
 # (8,192 folded rows of 256) does not fit VMEM in the forward or in dq. The
 # rule stands: its forward is within a twentieth of the least read.
+# ONE query head a group at 256 / 128 (32 / 32 heads: keys of 192 padded to
+# 256, values of 128) at 1024 POSITIONS, so that a block of 1024 is the whole
+# sequence and every pair is on the diagonal (PR 41): forward 0.385 at 1024 x
+# 1024 (the rule's), 0.384 at 512 x 1024 (the least), 0.390 at 512 x 512, 0.395
+# at 256 x 1024, 0.419 at 1024 x 512, 0.446 at 256 x 256, 0.82 at 1024 x 128;
+# dq 0.288 at 1024 x 1024 (the rule's), 0.268 at 512 x 512 (the least), 0.299
+# at 1024 x 512, 0.304 at 512 x 1024, 0.368 at 256 x 512; dk / dv 0.425 at
+# 1024 x 1024 (the rule's), 0.398 at 512 x 1024 (the least), 0.402 at 256 x
+# 1024, 0.405 at 512 x 512, 0.410 at 1024 x 512. With the values padded to 256
+# as well: 0.391 / 0.333 / 0.427 at 1024 x 1024. The rule stands: each kernel
+# is within a twelfth of its least, 1.5 ms of a step of 597 between them. These
+# are host-clock times a call, dispatch included: inside the traced step the
+# same three read 0.203 / 0.278 / 0.307 ms (the forward's 10.7 GFLOP at the
+# published widths: 27 % of the peak; all three: 30.8 %), so a sweep at this
+# length ranks tiles and does not time a kernel.
 # All three regimes: a key block meets up to _FOLDED_ROWS rows of queries (a
 # group's heads times the query block), and the backward's kernels, which
 # hold two products' tiles a pair, keep heads x query block x key block
@@ -90,21 +110,26 @@ _FOLDED_ROWS, _WIDEST_BLOCK, _BACKWARD_TILE = 4096, 1024, 2 ** 21
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def causal_attention_serves(x: Array, head_dim: int) -> bool:
+def causal_attention_serves(x: Array, head_dim: int, v_head_dim: Optional[int] = None) -> bool:
     """THE gate of the attention route: do the kernels serve a sequence
     whose activations are ``x``? On a TPU, for float32 / bfloat16
-    activations, a ``head_dim`` of whole lanes, an operand that is not
+    activations, BOTH head widths in whole lanes (``head_dim`` of the
+    queries and keys, ``v_head_dim`` of the values and the output; left
+    out, the values are as wide as the keys), an operand that is not
     device-sharded (:func:`~byzpy_tpu.ops.pallas_kernels.
     sharding_allows_pallas`). Any length: the wrapper pads it to whole
     blocks. Asked once a call, in Python, by ``models.nemotron_h.
     gqa_attention`` (sixteen query heads a key/value head of 128),
-    ``models.glm4_moe_lite.mla_attention`` (one of 256) and
-    ``models.qwen3_next.gated_attention`` (eight of 256; :func:`_blocks`
-    has what each regime measured); reads no environment variable."""
+    ``models.layers.mla_attention`` (one query head a key/value head:
+    GLM-4.7-Flash's 256 / 256, and 192 / 128 with the queries and keys
+    padded to 256) and ``models.qwen3_next.gated_attention`` (eight of
+    256; :func:`_blocks` has what each regime measured); reads no
+    environment variable."""
     return bool(
         _pk._on_tpu()
         and x.dtype in (jnp.float32, jnp.bfloat16)
         and head_dim % _LANES == 0
+        and (head_dim if v_head_dim is None else v_head_dim) % _LANES == 0
         and _pk.sharding_allows_pallas(x)
     )
 
@@ -118,7 +143,11 @@ def _blocks(t: int, per: int, *, backward: bool) -> Tuple[int, int, int]:
     for sixteen heads a group, 1024 for one; a power of two: the mask
     reads a folded row's position with a bitwise and); the key block the
     widest of 1024 ... 128 that divides it, in the backward within
-    ``_BACKWARD_TILE`` (512 for sixteen heads a group, 1024 for one)."""
+    ``_BACKWARD_TILE`` (512 for sixteen heads a group, 1024 for one). A
+    sequence of 1024 positions with one query head a group (32 key/value
+    heads of 256 / 128) is ONE pair of 1024 x 1024 a head in all three
+    kernels, on the diagonal. The head widths do not enter: the tiles are
+    counted in scores, and the widest head (256) fits every regime above."""
     t_pad = _pk._round_up(t, _LANES)
     sizes = (1024, 512, 256, 128)
     block_q = next(b for b in sizes
@@ -258,14 +287,14 @@ def _masked_or_not(flag, step):
 
 
 def _fwd_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                qf_ref, m_ref, l_ref, acc_ref, *, per, head_dim, block_q, block_k):
+                qf_ref, m_ref, l_ref, acc_ref, *, per, qk_dim, v_dim, scale, block_q, block_k):
     pair = pl.program_id(1)
     qi, kj, flag = qi_ref[pair], kj_ref[pair], flag_ref[pair]
     rows = per * block_q
 
     @pl.when((flag & _FIRST) != 0)
     def _():
-        _fold(qf_ref, q_ref, per, block_q, head_dim, 1.0 / math.sqrt(head_dim))
+        _fold(qf_ref, q_ref, per, block_q, qk_dim, scale)
         m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
@@ -295,7 +324,7 @@ def _fwd_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         def one(r):
             head = _rows(r, block_q)
             total = l_ref[head, :]
-            o_ref[:, _lanes(r, head_dim)] = (acc_ref[head, :] / total).astype(o_ref.dtype)
+            o_ref[:, _lanes(r, v_dim)] = (acc_ref[head, :] / total).astype(o_ref.dtype)
             lse_ref[pl.ds(r, 1), :] = _column_to_row(m_ref[head, :] + jnp.log(total), eye)
 
         _heads(per, one)
@@ -307,13 +336,19 @@ def _grid_spec(pairs, kv_heads, in_specs, out_specs, scratch_shapes):
         in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch_shapes)
 
 
-def _specs(per: int, head_dim: int, block_q: int, block_k: int):
-    """Block specs of a query-side array, a key-side array and a
-    per-row statistic, by the pair's query and key block."""
-    q_spec = pl.BlockSpec((block_q, per * head_dim), lambda g, p, qi, kj, fl: (qi[p], g))
-    k_spec = pl.BlockSpec((block_k, head_dim), lambda g, p, qi, kj, fl: (kj[p], g))
+def _specs(per: int, qk_dim: int, v_dim: int, block_q: int, block_k: int):
+    """Block specs by the pair's query and key block: of the queries (and
+    their cotangent), the keys, the values, the output (and its cotangent)
+    and a per-row statistic. Queries and keys are ``qk_dim`` a head wide,
+    values and the output ``v_dim``."""
+    def query_side(width):
+        return pl.BlockSpec((block_q, per * width), lambda g, p, qi, kj, fl: (qi[p], g))
+
+    def key_side(width):
+        return pl.BlockSpec((block_k, width), lambda g, p, qi, kj, fl: (kj[p], g))
+
     row_spec = pl.BlockSpec((None, per, block_q), lambda g, p, qi, kj, fl: (g, 0, qi[p]))
-    return q_spec, k_spec, row_spec
+    return query_side(qk_dim), key_side(qk_dim), key_side(v_dim), query_side(v_dim), row_spec
 
 
 def _params():
@@ -321,31 +356,40 @@ def _params():
                                 vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _cost(pairs, kv_heads, per, head_dim, block_q, block_k, products, arrays):
+def _cost(pairs, kv_heads, per, block_q, block_k, widths, arrays):
+    """``widths``: what each of the kernel's products contracts over or
+    writes a score for, a head (``qk_dim`` for a product with queries or
+    keys, ``v_dim`` for one with values or the output's cotangent)."""
     tile = len(pairs[0]) * kv_heads * per * block_q * block_k
     return pl.CostEstimate(
-        flops=2 * products * tile * head_dim, transcendentals=tile,
+        flops=2 * tile * sum(widths), transcendentals=tile,
         bytes_accessed=sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in arrays))
 
 
-def _causal_attention_fwd_call(q, k, v, *, kv_heads, head_dim, block_q, block_k, interpret):
+def _widths(q, k, v, kv_heads):
+    """``(query heads a key/value head, qk_dim, v_dim)`` of a call."""
+    qk_dim, v_dim = k.shape[1] // kv_heads, v.shape[1] // kv_heads
+    return q.shape[1] // (kv_heads * qk_dim), qk_dim, v_dim
+
+
+def _causal_attention_fwd_call(q, k, v, *, kv_heads, scale, block_q, block_k, interpret):
     t = q.shape[0]
-    per = q.shape[1] // (kv_heads * head_dim)
+    per, qk_dim, v_dim = _widths(q, k, v, kv_heads)
     rows = per * block_q
     pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=False)
-    q_spec, k_spec, row_spec = _specs(per, head_dim, block_q, block_k)
-    out_shape = (jax.ShapeDtypeStruct(q.shape, q.dtype),
+    q_spec, k_spec, v_spec, o_spec, row_spec = _specs(per, qk_dim, v_dim, block_q, block_k)
+    out_shape = (jax.ShapeDtypeStruct((t, kv_heads * per * v_dim), q.dtype),
                  jax.ShapeDtypeStruct((kv_heads, per, t), jnp.float32))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, per=per, head_dim=head_dim, block_q=block_q,
-                          block_k=block_k),
+        functools.partial(_fwd_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, scale=scale,
+                          block_q=block_q, block_k=block_k),
         out_shape=out_shape,
         grid_spec=_grid_spec(
-            pairs, kv_heads, [q_spec, k_spec, k_spec], (q_spec, row_spec),
-            [pltpu.VMEM((rows, head_dim), q.dtype), pltpu.VMEM((rows, 1), jnp.float32),
-             pltpu.VMEM((rows, 1), jnp.float32), pltpu.VMEM((rows, head_dim), jnp.float32)]),
+            pairs, kv_heads, [q_spec, k_spec, v_spec], (o_spec, row_spec),
+            [pltpu.VMEM((rows, qk_dim), q.dtype), pltpu.VMEM((rows, 1), jnp.float32),
+             pltpu.VMEM((rows, 1), jnp.float32), pltpu.VMEM((rows, v_dim), jnp.float32)]),
         compiler_params=_params(),
-        cost_estimate=_cost(pairs, kv_heads, per, head_dim, block_q, block_k, 2,
+        cost_estimate=_cost(pairs, kv_heads, per, block_q, block_k, (qk_dim, v_dim),
                             (q, k, v) + out_shape),
         interpret=interpret,
         name="causal_attention_fwd",
@@ -359,16 +403,15 @@ def _causal_attention_fwd_call(q, k, v, *, kv_heads, head_dim, block_q, block_k,
 
 def _dq_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dq_ref, qf_ref, dof_ref, lse_col_ref, delta_col_ref, acc_ref,
-               *, per, head_dim, block_q, block_k):
+               *, per, qk_dim, v_dim, scale, block_q, block_k):
     pair = pl.program_id(1)
     qi, kj, flag = qi_ref[pair], kj_ref[pair], flag_ref[pair]
     rows = per * block_q
-    scale = 1.0 / math.sqrt(head_dim)
 
     @pl.when((flag & _FIRST) != 0)
     def _():
-        _fold(qf_ref, q_ref, per, block_q, head_dim, scale)
-        _fold(dof_ref, do_ref, per, block_q, head_dim, None)
+        _fold(qf_ref, q_ref, per, block_q, qk_dim, scale)
+        _fold(dof_ref, do_ref, per, block_q, v_dim, None)
         eye = _eye()
 
         def one(r):
@@ -396,31 +439,31 @@ def _dq_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, d
     @pl.when((flag & _LAST) != 0)
     def _():
         def one(r):
-            dq_ref[:, _lanes(r, head_dim)] = (
+            dq_ref[:, _lanes(r, qk_dim)] = (
                 acc_ref[_rows(r, block_q), :] * scale).astype(dq_ref.dtype)
 
         _heads(per, one)
 
 
-def _causal_attention_dq_call(q, k, v, do, lse, delta, *, kv_heads, head_dim, block_q,
+def _causal_attention_dq_call(q, k, v, do, lse, delta, *, kv_heads, scale, block_q,
                               block_k, interpret):
     t = q.shape[0]
-    per = q.shape[1] // (kv_heads * head_dim)
+    per, qk_dim, v_dim = _widths(q, k, v, kv_heads)
     rows = per * block_q
     pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=False)
-    q_spec, k_spec, row_spec = _specs(per, head_dim, block_q, block_k)
+    q_spec, k_spec, v_spec, o_spec, row_spec = _specs(per, qk_dim, v_dim, block_q, block_k)
     out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
     return pl.pallas_call(
-        functools.partial(_dq_kernel, per=per, head_dim=head_dim, block_q=block_q,
-                          block_k=block_k),
+        functools.partial(_dq_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, scale=scale,
+                          block_q=block_q, block_k=block_k),
         out_shape=out_shape,
         grid_spec=_grid_spec(
-            pairs, kv_heads, [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec], q_spec,
-            [pltpu.VMEM((rows, head_dim), q.dtype), pltpu.VMEM((rows, head_dim), do.dtype),
+            pairs, kv_heads, [q_spec, k_spec, v_spec, o_spec, row_spec, row_spec], q_spec,
+            [pltpu.VMEM((rows, qk_dim), q.dtype), pltpu.VMEM((rows, v_dim), do.dtype),
              pltpu.VMEM((rows, 1), jnp.float32), pltpu.VMEM((rows, 1), jnp.float32),
-             pltpu.VMEM((rows, head_dim), jnp.float32)]),
+             pltpu.VMEM((rows, qk_dim), jnp.float32)]),
         compiler_params=_params(),
-        cost_estimate=_cost(pairs, kv_heads, per, head_dim, block_q, block_k, 3,
+        cost_estimate=_cost(pairs, kv_heads, per, block_q, block_k, (qk_dim, v_dim, qk_dim),
                             (q, k, v, do, lse, delta, out_shape)),
         interpret=interpret,
         name="causal_attention_dq",
@@ -433,10 +476,10 @@ def _causal_attention_dq_call(q, k, v, do, lse, delta, *, kv_heads, head_dim, bl
 
 
 def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, per, head_dim, block_q, block_k):
+                dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
+                *, per, qk_dim, v_dim, scale, block_q, block_k):
     pair = pl.program_id(1)
     qi, kj, flag = qi_ref[pair], kj_ref[pair], flag_ref[pair]
-    scale = 1.0 / math.sqrt(head_dim)
 
     @pl.when((flag & _FIRST) != 0)
     def _():
@@ -454,8 +497,8 @@ def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
         # ``fori_loop`` over the heads this kernel took 2.41 ms where sixteen
         # copies take 1.71 (v5e, PR 33); the copies cost 1.9 MB of code in HBM
         def one(r):
-            q = (q_ref[:, _lanes(r, head_dim)].astype(jnp.float32) * scale).astype(q_ref.dtype)
-            do = do_ref[:, _lanes(r, head_dim)]
+            q = (q_ref[:, _lanes(r, qk_dim)].astype(jnp.float32) * scale).astype(q_ref.dtype)
+            do = do_ref[:, _lanes(r, v_dim)]
             s = _dot(k_ref[...], q, _NT)
             if masked:
                 s = jnp.where(seen, s, -jnp.inf)
@@ -475,24 +518,25 @@ def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
         dv_ref[...] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
-def _causal_attention_dkv_call(q, k, v, do, lse, delta, *, kv_heads, head_dim, block_q,
+def _causal_attention_dkv_call(q, k, v, do, lse, delta, *, kv_heads, scale, block_q,
                                block_k, interpret):
     t = q.shape[0]
-    per = q.shape[1] // (kv_heads * head_dim)
+    per, qk_dim, v_dim = _widths(q, k, v, kv_heads)
     pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=True)
-    q_spec, k_spec, row_spec = _specs(per, head_dim, block_q, block_k)
+    q_spec, k_spec, v_spec, o_spec, row_spec = _specs(per, qk_dim, v_dim, block_q, block_k)
     out_shape = (jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype))
     return pl.pallas_call(
-        functools.partial(_dkv_kernel, per=per, head_dim=head_dim, block_q=block_q,
-                          block_k=block_k),
+        functools.partial(_dkv_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, scale=scale,
+                          block_q=block_q, block_k=block_k),
         out_shape=out_shape,
         grid_spec=_grid_spec(
-            pairs, kv_heads, [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-            (k_spec, k_spec),
-            [pltpu.VMEM((block_k, head_dim), jnp.float32),
-             pltpu.VMEM((block_k, head_dim), jnp.float32)]),
+            pairs, kv_heads, [q_spec, k_spec, v_spec, o_spec, row_spec, row_spec],
+            (k_spec, v_spec),
+            [pltpu.VMEM((block_k, qk_dim), jnp.float32),
+             pltpu.VMEM((block_k, v_dim), jnp.float32)]),
         compiler_params=_params(),
-        cost_estimate=_cost(pairs, kv_heads, per, head_dim, block_q, block_k, 4,
+        cost_estimate=_cost(pairs, kv_heads, per, block_q, block_k,
+                            (qk_dim, v_dim, v_dim, qk_dim),
                             (q, k, v, do, lse, delta) + out_shape),
         interpret=interpret,
         name="causal_attention_dkv",
@@ -505,19 +549,26 @@ def _causal_attention_dkv_call(q, k, v, do, lse, delta, *, kv_heads, head_dim, b
 
 
 def causal_attention(q: Array, k: Array, v: Array, *, kv_heads: int,
+                     scale: Optional[float] = None,
                      interpret: Optional[bool] = None) -> Array:
-    """Causal softmax attention of one sequence: ``q (T, H * head_dim)``,
-    ``k``, ``v`` ``(T, kv_heads * head_dim)``, query head ``h`` reading
-    key/value head ``h // (H / kv_heads)``; returns ``(T, H * head_dim)``
-    in ``q``'s dtype. Differentiable in all three. Any ``T``: the tail is
-    padded to whole blocks (padded keys lie after every query; padded
-    queries are cut off and their cotangent is zero)."""
-    head_dim = k.shape[1] // kv_heads
-    if head_dim % _LANES or q.shape[1] % (kv_heads * head_dim):
+    """Causal softmax attention of one sequence: ``q (T, H * qk_dim)``,
+    ``k (T, kv_heads * qk_dim)``, ``v (T, kv_heads * v_dim)``, query head
+    ``h`` reading key/value head ``h // (H / kv_heads)``; returns
+    ``(T, H * v_dim)`` in ``q``'s dtype. ``scale`` multiplies the scores
+    (default ``qk_dim ** -0.5``; a caller that padded its queries and keys
+    to whole lanes, or whose positions stretch the softmax, hands its
+    own). Differentiable in all three. Any ``T``: the tail is padded to
+    whole blocks (padded keys lie after every query; padded queries are
+    cut off and their cotangent is zero)."""
+    qk_dim, v_dim = k.shape[1] // kv_heads, v.shape[1] // kv_heads
+    if qk_dim % _LANES or v_dim % _LANES or q.shape[1] % (kv_heads * qk_dim):
         raise ValueError(
-            f"causal_attention needs a head_dim of whole {_LANES}s and whole groups of query "
-            f"heads, got q {q.shape}, k {k.shape}, kv_heads {kv_heads}")
-    return _causal_attention(q, k, v, kv_heads, _pk._resolve_interpret(interpret))
+            f"causal_attention needs a head_dim of whole {_LANES}s (queries / keys, and values) "
+            f"and whole groups of query "
+            f"heads, got q {q.shape}, k {k.shape}, v {v.shape}, kv_heads {kv_heads}")
+    if scale is None:
+        scale = 1.0 / math.sqrt(qk_dim)
+    return _causal_attention(q, k, v, kv_heads, scale, _pk._resolve_interpret(interpret))
 
 
 def _padded(t_pad: int, *arrays):
@@ -525,39 +576,38 @@ def _padded(t_pad: int, *arrays):
     return tuple(jnp.pad(a, ((0, pad), (0, 0))) for a in arrays) if pad else arrays
 
 
-def _forward(q, k, v, kv_heads, interpret):
+def _forward(q, k, v, kv_heads, scale, interpret):
     t_pad, block_q, block_k = _blocks(q.shape[0], q.shape[1] // k.shape[1], backward=False)
     out, lse = _causal_attention_fwd_call(
-        *_padded(t_pad, q, k, v), kv_heads=kv_heads, head_dim=k.shape[1] // kv_heads,
+        *_padded(t_pad, q, k, v), kv_heads=kv_heads, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret)
     return out[:q.shape[0]], lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _causal_attention(q, k, v, kv_heads, interpret):
-    return _forward(q, k, v, kv_heads, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _causal_attention(q, k, v, kv_heads, scale, interpret):
+    return _forward(q, k, v, kv_heads, scale, interpret)[0]
 
 
-def _causal_attention_fwd(q, k, v, kv_heads, interpret):
-    out, lse = _forward(q, k, v, kv_heads, interpret)
+def _causal_attention_fwd(q, k, v, kv_heads, scale, interpret):
+    out, lse = _forward(q, k, v, kv_heads, scale, interpret)
     return out, (q, k, v, out, lse)
 
 
-def _causal_attention_bwd(kv_heads, interpret, residuals, d_out):
+def _causal_attention_bwd(kv_heads, scale, interpret, residuals, d_out):
     # the backward rule is traced outside the scope the forward stood in
     with jax.named_scope("model.attention"):
         q, k, v, out, lse = residuals
         t = q.shape[0]
-        head_dim = k.shape[1] // kv_heads
-        per = q.shape[1] // (kv_heads * head_dim)
+        per, _, v_dim = _widths(q, k, v, kv_heads)
         t_pad, block_q, block_k = _blocks(t, per, backward=True)
         # rowsum(d_out * out): what the softmax's Jacobian takes off every row
         delta = jnp.sum(
             (d_out.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
-                t, kv_heads, per, head_dim), axis=-1)
+                t, kv_heads, per, v_dim), axis=-1)
         delta = jnp.pad(jnp.transpose(delta, (1, 2, 0)), ((0, 0), (0, 0), (0, t_pad - t)))
         args = _padded(t_pad, q, k, v, d_out.astype(q.dtype)) + (lse, delta)
-        sizes = dict(kv_heads=kv_heads, head_dim=head_dim, block_q=block_q, block_k=block_k,
+        sizes = dict(kv_heads=kv_heads, scale=scale, block_q=block_q, block_k=block_k,
                      interpret=interpret)
         dq = _causal_attention_dq_call(*args, **sizes)
         dk, dv = _causal_attention_dkv_call(*args, **sizes)

@@ -285,9 +285,10 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                     return jax.tree_util.tree_map(
                         lambda stack, value: put(stack, value, i), carry, out)
 
+            with jax.named_scope("stream.boundary"):
+                stacks = [jax.lax.empty((h, *folded(leaf.shape)), leaf.dtype) for leaf in kept[0]]
             vals, auxes = jax.lax.fori_loop(0, h, one_forward, (
-                [jax.lax.empty((h, *folded(leaf.shape)), leaf.dtype) for leaf in kept[0]],
-                jax.tree_util.tree_map(
+                stacks, jax.tree_util.tree_map(
                     lambda leaf: jnp.zeros((h, *leaf.shape), leaf.dtype), kept[1])))
         made, wires = wiring["made"], wiring["wires"]
 

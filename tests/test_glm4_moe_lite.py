@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from byzpy_tpu.models import glm4_moe_lite as glm
+from byzpy_tpu.models import layers
 from byzpy_tpu.ops import pallas_attention as pa
 from byzpy_tpu.parallel.moe import held_experts_ffn
 from chipbench import reference_glm4_moe_lite as ref
@@ -258,11 +259,11 @@ def test_latent_attention_by_the_kernels_is_the_map_route(monkeypatch):
     asked = []
     routes = []
     for serves in (False, True):
-        monkeypatch.setattr(glm, "causal_attention_serves",
-                            lambda x_, hd, serves=serves: asked.append(hd) or serves)
+        monkeypatch.setattr(layers, "causal_attention_serves",
+                            lambda x_, hd, vd, serves=serves: asked.append((hd, vd)) or serves)
         routes.append(_both(lambda p_, x_: glm.mla_attention(p_, x_, cfg),
                             lambda p_, x_: ref.mla_full(p_, x_, _arch(cfg)), p, x))
-    assert set(asked) == {128}
+    assert set(asked) == {(128, 128)}
     for (y, g), (y_ref, g_ref) in routes:
         _close(y, y_ref, tol=1e-4)
         for got, want in zip(jax.tree_util.tree_leaves(g), jax.tree_util.tree_leaves(g_ref)):

@@ -55,6 +55,13 @@ CONFIGS = {
 # and pairs wholly under it)
 LENGTHS = {"4of2": [128, 300, 2048, 768], "32of2": [128, 300, 512]}
 CASES = [(name, t) for name, lengths in LENGTHS.items() for t in lengths]
+# keys 192 / values 128 a head (latent attention's DeepSeek-V3 shape): the
+# queries and keys go in at 256, 64 zero columns behind the 192, with the
+# scale of 192; the values, the output and its cotangent at 128. One query
+# head a key/value head (three of them), and eight (two groups)
+TWO_WIDTHS = {"3of3@192/128": (3, 3), "16of2@192/128": (16, 2)}
+CASES_192_128 = [(name, t) for name in TWO_WIDTHS for t in (128, 300, 1280)]
+QK, QK_PADDED, VD = 192, 256, 128
 
 
 def _arch(cfg):
@@ -81,11 +88,11 @@ def _qkv(cfg, t, seed):
             jax.random.normal(keys[2], (t, kv * HD)), jax.random.normal(keys[3], (t, heads * HD)))
 
 
-def _full_scores(q, k, v, kv):
+def _full_scores(q, k, v, kv, qk=HD, vd=HD):
     t = q.shape[0]
-    per = q.shape[1] // (kv * HD)
-    q, k, v = q.reshape(t, kv, per, HD), k.reshape(t, kv, HD), v.reshape(t, kv, HD)
-    scores = jnp.einsum("qgrd,kgd->grqk", q, k, precision="highest") / math.sqrt(HD)
+    per = q.shape[1] // (kv * qk)
+    q, k, v = q.reshape(t, kv, per, qk), k.reshape(t, kv, qk), v.reshape(t, kv, vd)
+    scores = jnp.einsum("qgrd,kgd->grqk", q, k, precision="highest") / math.sqrt(qk)
     seen = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
     scores = jnp.where(seen, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -131,12 +138,66 @@ def test_kernels_are_the_full_score_matrix_forward_and_gradient(name, t):
         _close(g, w)
 
 
+def _padded_heads(a, heads, width, to):
+    """``(T, heads * width) -> (T, heads * to)``: zero columns behind every head."""
+    t = a.shape[0]
+    return jnp.pad(a.reshape(t, heads, width), ((0, 0), (0, 0), (0, to - width))).reshape(t, -1)
+
+
+@pytest.mark.parametrize("name, t", CASES_192_128)
+def test_kernels_at_keys_192_values_128_are_the_full_score_matrix_forward_and_gradient(name, t):
+    """Forward, dq, dk and dv against the plain softmax at float32, on the
+    PUBLISHED widths: the kernels see the padded queries and keys, and the
+    gradient with respect to the 192 real columns is what is compared (the
+    padded columns' is cut off by the pad's own transpose)."""
+    heads, kv = TWO_WIDTHS[name]
+    keys = jax.random.split(jax.random.PRNGKey(t), 4)
+    q = jax.random.normal(keys[0], (t, heads * QK))
+    k = jax.random.normal(keys[1], (t, kv * QK))
+    v = jax.random.normal(keys[2], (t, kv * VD))
+    probe = jax.random.normal(keys[3], (t, heads * VD))
+
+    def kernels(q_, k_, v_):
+        return pa.causal_attention(
+            _padded_heads(q_, heads, QK, QK_PADDED), _padded_heads(k_, kv, QK, QK_PADDED), v_,
+            kv_heads=kv, scale=1.0 / math.sqrt(QK))
+
+    out = kernels(q, k, v)
+    assert out.shape == (t, heads * VD) and out.dtype == q.dtype
+    _close(out, _full_scores(q, k, v, kv, QK, VD)[0])
+    got = jax.grad(lambda *a: jnp.sum(kernels(*a) * probe), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_full_scores(*a, kv, QK, VD)[0] * probe),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):  # dq, dk, dv
+        assert g.shape == w.shape
+        _close(g, w)
+
+
+def test_the_default_scale_is_the_keys_width_and_an_explicit_one_replaces_it():
+    q, k, v, _ = _qkv(CONFIGS["4of2"], 128, seed=3)
+    plain = pa.causal_attention(q, k, v, kv_heads=2)
+    np.testing.assert_array_equal(
+        plain, pa.causal_attention(q, k, v, kv_heads=2, scale=1.0 / math.sqrt(HD)))
+    # softmax(c q k^T) v: a scale handed over is a scale on the queries
+    _close(pa.causal_attention(q, k, v, kv_heads=2, scale=0.5 / math.sqrt(HD)),
+           pa.causal_attention(0.5 * q, k, v, kv_heads=2))
+
+
+def test_values_wider_than_keys_are_served_too():
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    q, k = (jax.random.normal(key, (200, 2 * 128)) for key in keys[:2])
+    v = jax.random.normal(keys[2], (200, 2 * 256))
+    out = pa.causal_attention(q, k, v, kv_heads=2)
+    assert out.shape == (200, 2 * 256)
+    _close(out, _full_scores(q, k, v, 2, 128, 256)[0])
+
+
 @pytest.mark.parametrize("name, t", [("4of2", 300), ("32of2", 512)])
 def test_forward_writes_one_log_sum_exp_a_query_row_a_head(name, t):
     cfg = CONFIGS[name]
     kv, per = cfg.num_key_value_heads, cfg.num_attention_heads // cfg.num_key_value_heads
     q, k, v, _ = _qkv(cfg, t, seed=1)
-    out, lse = pa._forward(q, k, v, kv, True)
+    out, lse = pa._forward(q, k, v, kv, 1.0 / math.sqrt(HD), True)
     t_pad = pa._blocks(t, per, backward=False)[0]
     assert lse.shape == (kv, per, t_pad) and lse.dtype == jnp.float32
     _close(lse[..., :t], _full_scores(q, k, v, kv)[1])
@@ -273,6 +334,17 @@ def test_gate_table(monkeypatch, platform, dtype, head_dim):
         assert pa.causal_attention_serves(_sds((4096, 2688), jnp.dtype(dtype)), head_dim) is want
 
 
+@pytest.mark.parametrize("head_dim, v_head_dim, want", [
+    (256, 128, True), (128, 256, True), (256, None, True), (192, 128, False), (256, 64, False),
+    (256, 192, False)])
+def test_gate_asks_both_widths(monkeypatch, head_dim, v_head_dim, want):
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    x = _sds((1024, 3584))
+    assert pa.causal_attention_serves(x, head_dim, v_head_dim) is want
+    monkeypatch.setattr(pk, "_on_tpu", lambda: False)
+    assert pa.causal_attention_serves(x, head_dim, v_head_dim) is False
+
+
 def test_gate_refuses_a_device_sharded_operand(monkeypatch):
     if len(jax.devices()) < 4:
         pytest.skip("needs four (virtual) devices")
@@ -350,9 +422,10 @@ def test_the_gate_is_asked_once_a_call(monkeypatch):
 def test_no_variable_field_or_argument_chooses_the_route():
     assert "os.environ" not in inspect.getsource(pa) and "getenv" not in inspect.getsource(pa)
     assert list(inspect.signature(nh.gqa_attention).parameters) == ["p", "x", "cfg"]
-    assert list(inspect.signature(pa.causal_attention_serves).parameters) == ["x", "head_dim"]
+    assert list(inspect.signature(pa.causal_attention_serves).parameters) == [
+        "x", "head_dim", "v_head_dim"]
     assert list(inspect.signature(pa.causal_attention).parameters) == [
-        "q", "k", "v", "kv_heads", "interpret"]
+        "q", "k", "v", "kv_heads", "scale", "interpret"]
     # the configuration's attention fields are the four it had (query_block: the XLA route's)
     fields = [f for f in nh.NemotronHConfig.__dataclass_fields__
               if "attention" in f or "head" in f or "block" in f or "key_value" in f]

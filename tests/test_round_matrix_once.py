@@ -644,6 +644,7 @@ def test_folded_stack_is_what_the_aggregate_is_handed(
 # the attention kernels' compile: a sequence that is neither the query
 # heads' width (4096) nor a block's, a hidden size that is no block's
 ATTENTION_TOKENS, ATTENTION_HIDDEN = 2048, 384
+TWO_WIDTH_TOKENS = 1024
 
 
 def _write_tpu_texts(out_dir):
@@ -717,6 +718,22 @@ def _write_tpu_texts(out_dir):
               encoding="utf-8") as fh:
         fh.write(text)
 
+    # and at two widths (32 key/value heads, queries and keys of 192 padded to
+    # 256, values of 128, under YaRN), at the Xing4.0 cell's 1024 positions
+    from byzpy_tpu.models import layers, xing4
+
+    two = xing4.Xing4Config(hidden_size=ATTENTION_HIDDEN)
+    shapes = jax.eval_shape(lambda: xing4.init_params(two)["seg02_moe"])
+    text = jax.jit(jax.value_and_grad(
+        lambda p, xs: jnp.sum(jax.vmap(lambda s: layers.mla_attention(p, s, two))(xs)),
+        argnums=(0, 1))).lower(
+        described(shapes), jax.ShapeDtypeStruct(
+            (1, TWO_WIDTH_TOKENS, ATTENTION_HIDDEN), jnp.float32, sharding=one_chip)
+    ).compile().as_text()
+    with open(os.path.join(out_dir, "mla_attention_192_128_float32.hlo.txt"), "w",
+              encoding="utf-8") as fh:
+        fh.write(text)
+
     # the held experts' layer, value and gradient, at the three language cells'
     # tokens, picks, held experts, round and width (the experts' own width cut
     # to 128: the read-back kernel sees none of it)
@@ -764,7 +781,8 @@ def tpu_texts(tmp_path_factory):
                     f"(exit {done.returncode}):\n{done.stderr[-3000:]}")
     texts = {}
     for name in [*FOLDED_ROUNDS, "attention_float32", "attention_bfloat16",
-                 "mla_attention_float32", *(f"experts_{cell}" for cell in EXPERT_CELLS)]:
+                 "mla_attention_float32", "mla_attention_192_128_float32",
+                 *(f"experts_{cell}" for cell in EXPERT_CELLS)]:
         with open(os.path.join(out_dir, name + ".hlo.txt"), encoding="utf-8") as fh:
             texts[name] = fh.read()
     return texts
@@ -873,6 +891,25 @@ def test_on_the_tpu_latent_attention_is_the_same_three_kernels_one_query_head_a_
     reader = _benchmark_reader("attention_kernel_calls.train")
     assert reader.read(SimpleNamespace(
         outcome={"compiled_text": tpu_texts["mla_attention_float32"]})) == 3
+
+
+def test_on_the_tpu_latent_attention_of_192_and_128_pads_its_keys_and_never_its_values(tpu_texts):
+    text = tpu_texts["mla_attention_192_128_float32"]
+    calls = _attention_calls(text)
+    assert sorted(calls) == ["causal_attention_dkv", "causal_attention_dq",
+                             "causal_attention_fwd"]
+    t = TWO_WIDTH_TOKENS
+    # q and k go in as (T, 32 x 256), v (and the output, and its cotangent) as
+    # (T, 32 x 128); no array of 32 x 192 reaches a kernel and no value is padded
+    forward = calls["causal_attention_fwd"]
+    assert forward.count(f"f32[{t},8192]") >= 2 and forward.count(f"f32[{t},4096]") >= 2
+    for line in calls.values():
+        assert f"f32[{t},6144]" not in line
+    backward = calls["causal_attention_dkv"].partition(" custom-call(")[0]
+    assert f"f32[{t},8192]" in backward and f"f32[{t},4096]" in backward  # dk, dv
+    assert f"f32[32,1,{t}]" in forward.partition(" custom-call(")[0]
+    reader = _benchmark_reader("attention_kernel_calls.train")
+    assert reader.read(SimpleNamespace(outcome={"compiled_text": text})) == 3
 
 
 # -- the held experts' read-back, compiled by Mosaic ------------------------------
