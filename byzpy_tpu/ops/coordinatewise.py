@@ -20,6 +20,11 @@ optimizer to find out: the round's own default (SGD with momentum) is
 leaf by leaf, and a caller who passes another says so with
 :func:`leafwise`; one that is not so marked is refused (a global-norm
 clip reads every leaf, and must not be marked).
+
+Whether an update is more than leaf by leaf, ELEMENT by element, is asked
+of the update itself (:func:`is_elementwise`, its jaxpr): that decides
+nothing about what may stream, only how the round hands a segment's
+leaves to it (on their tiles in the row's own order, or whole).
 """
 
 from __future__ import annotations
@@ -90,6 +95,51 @@ class RoundAttack:
         return self.fn(given, **dict(self.kwargs))
 
 
+#: primitives whose result at a place reads its operands at that place alone
+_PLACEWISE = frozenset({
+    "abs", "add", "and", "clamp", "convert_element_type", "copy", "div", "eq", "exp", "exp2",
+    "expm1", "ge", "gt", "integer_pow", "is_finite", "le", "log", "log1p", "logistic", "lt",
+    "max", "min", "mul", "ne", "neg", "not", "or", "pow", "rsqrt", "select_n", "sign", "sqrt",
+    "square", "stop_gradient", "sub", "tanh", "xor",
+})
+
+
+def _placewise(jaxpr: Any) -> bool:
+    """Whether every equation of ``jaxpr`` is arithmetic on scalars, a
+    scalar spread over an array, or a :data:`_PLACEWISE` primitive on
+    arrays of one shape (scalars beside them)."""
+    for eqn in jaxpr.eqns:
+        inner = [v for v in eqn.params.values() if hasattr(v, "eqns") or hasattr(v, "jaxpr")]
+        if inner:  # jit, custom_jvp_call, ...: as good as what it wraps
+            if not all(_placewise(getattr(v, "jaxpr", v)) for v in inner):
+                return False
+            continue
+        shapes = {tuple(v.aval.shape) for v in [*eqn.invars, *eqn.outvars]} - {()}
+        if not shapes:
+            continue
+        if eqn.primitive.name == "broadcast_in_dim":
+            if eqn.invars[0].aval.shape != ():
+                return False
+        elif eqn.primitive.name not in _PLACEWISE or len(shapes) > 1:
+            return False
+    return True
+
+
+def is_elementwise(optimizer: Any, params: Any, state: Any) -> bool:
+    """Whether ``optimizer.update`` on ``params`` and ``state`` (trees of
+    arrays or of their shapes) computes every element of its results from
+    the elements at the same place of its arguments (and scalars) alone:
+    SGD, momentum, Adam and AdamW do; a per-leaf norm, a trust ratio or a
+    factored second moment does not. Read off the update's own jaxpr,
+    equation by equation (:func:`_placewise`), so it holds for this
+    optimizer on these shapes and says nothing by name; what the reading
+    does not know counts as not elementwise. Such an update gives the
+    same elements whatever order a leaf's elements are handed to it in,
+    which is what lets the streamed round run it on a leaf's tiles in the
+    row's own order."""
+    return _placewise(jax.make_jaxpr(optimizer.update)(params, state, params).jaxpr)
+
+
 def _listed(fn: Callable) -> Callable:
     """The function under ``functools.partial`` and :class:`RoundAttack`."""
     while True:
@@ -129,6 +179,7 @@ __all__ = [
     "RoundAttack",
     "is_coordinatewise_aggregate",
     "is_coordinatewise_attack",
+    "is_elementwise",
     "leafwise",
     "mean",
     "refusal",

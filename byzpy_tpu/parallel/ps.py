@@ -47,7 +47,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.bundle import ModelBundle
-from ..utils.trees import ravel_pytree_fn, row_layout, tree_size
+from ..utils.trees import leaf_view, ravel_pytree_fn, row_layout, tile_views, tree_size
 from .collectives import reshard_q, reshard_q_ef
 from .mesh import node_axis
 from .quantization import (
@@ -239,6 +239,20 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
         layouts.append(row_layout(sub, width, folded=len(folded((width,))) == 2))
     opt_state0 = {seg.key: opt.init(bundle.params[seg.key]) for seg in segs}
 
+    def in_row_order(seg, layout):
+        """An update that computes every element from the elements at its
+        place alone (read off the optimizer's own jaxpr: SGD, momentum, Adam
+        are such) gives the same elements in whatever order a leaf is handed
+        to it: where the segment's row is whole tiles it is run on the leaves'
+        tiles in the row's order. Any other is handed whole leaves."""
+        if layout.unravel_tiles is None:
+            return False
+        whole = (bundle.params[seg.key], opt_state0[seg.key])
+        tiles = jax.eval_shape(tile_views, whole)[0]
+        return all(coordinatewise.is_elementwise(opt, sub, state) for sub, state in (whole, tiles))
+
+    in_rows = [in_row_order(seg, layout) for seg, layout in zip(segs, layouts)]
+
     def train_step(params, opt_state, xs, ys, key):
         xs_h, ys_h = xs[:h], ys[:h]
         # A boundary is an array or a tree of arrays. An array that a segment
@@ -422,9 +436,24 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                 with jax.named_scope("round.update"):
                     # (the columns past d are exactly zero: they add nothing to
                     # the norm, and unravel reads the first d alone)
-                    sum_sq = sum_sq + jnp.sum(jnp.square(agg)).astype(jnp.float32)
-                    updates, state = opt.update(layout.unravel(agg), opt_state[seg.key], sub)
-                    done = (optax.apply_updates(sub, updates), state)
+                    if in_rows[k]:
+                        # one pass a leaf: its stretch of the row read where the
+                        # kernel wrote it, parameter and state seen as those tiles
+                        (tiles, state), agg = tile_views((sub, opt_state[seg.key]), beside=agg)
+                        grads = layout.unravel_tiles(agg)
+                        sum_sq = sum_sq + sum(
+                            jnp.sum(jnp.square(leaf)).astype(jnp.float32)
+                            for leaf in jax.tree_util.tree_leaves(grads))
+                        updates, state = opt.update(grads, state, tiles)
+                        done = jax.tree_util.tree_map(
+                            lambda view, leaf: leaf_view(view, leaf.shape),
+                            (optax.apply_updates(tiles, updates), state),
+                            (sub, opt_state[seg.key]))
+                    else:
+                        sum_sq = sum_sq + jnp.sum(jnp.square(agg)).astype(jnp.float32)
+                        updates, state = opt.update(
+                            layout.unravel(agg), opt_state[seg.key], sub)
+                        done = (optax.apply_updates(sub, updates), state)
                     if k:
                         # the segment before this one starts from the cotangents
                         # only once this one's leaves are updated: its rows are

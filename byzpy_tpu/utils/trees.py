@@ -14,7 +14,7 @@ from __future__ import annotations
 import itertools
 import math
 from dataclasses import dataclass
-from typing import Any, Callable, List, Sequence, Tuple
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -107,6 +107,66 @@ def _tile_order(shape: Tuple[int, ...]) -> Tuple[int, int] | None:
     return rows, cols
 
 
+def _is_tiles(shape: Tuple[int, ...]) -> bool:
+    """Whether a tile leaf of this shape and its stretch of a folded row
+    are the same (8, 128) tiles in the same order, so that one is a view
+    of the other: it keeps the order of its tiles (:func:`_tile_order`),
+    or it is row-major, one tile wide and whole tiles high. (A narrower
+    leaf is padded to 128 lanes on the TPU: its stretch of the row has to
+    be relaid whichever side moves.)"""
+    if _tile_order(shape) is not None:
+        return True
+    return len(shape) >= 2 and shape[-1] == 128 and math.prod(shape[:-1]) % 8 == 0
+
+
+def tile_view(leaf: jnp.ndarray) -> jnp.ndarray:
+    """``leaf`` as ``(size / 1024, 8, 128)``, its (8, 128) tiles in the
+    order a folded row holds them (:func:`row_layout`): on the TPU a view
+    of the same bytes, no copy. A leaf that is not such tiles
+    (:func:`_is_tiles`) comes back as it is."""
+    shape = tuple(leaf.shape)
+    if not (leaf.size and _is_tiles(shape)):  # (such tiles are whole multiples of 1024)
+        return leaf
+    order = _tile_order(shape)
+    if order is not None:
+        rows, cols = order
+        leaf = leaf.reshape(rows // 8, 8, cols // 128, 128).transpose(0, 2, 1, 3)
+    return leaf.reshape(-1, 8, 128)
+
+
+def tile_views(tree: Any, beside: Any = None) -> Tuple[Any, Any]:
+    """Every leaf of ``tree`` as its :func:`tile_view`, and ``beside`` as
+    it is, the views and ``beside`` (nothing else) behind ONE
+    ``optimization_barrier``. Without a barrier the compiler moves a
+    unary op on a view (momentum times its decay) to the leaf's side of
+    the view, where it becomes a pass of its own. With ``beside`` (what
+    the views are about to be read with: the row) in the same barrier
+    the views exist only once it does, so nothing of them is fetched into
+    fast memory while the loops that make the row still run (PR 42: a
+    barrier of the views alone cost the Nemotron cell's loops 1.5 %). A
+    leaf that has no view is handed on outside the barrier."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    views = [tile_view(leaf) for leaf in leaves]
+    seen = [at for at, (view, leaf) in enumerate(zip(views, leaves)) if view is not leaf]
+    held, beside = jax.lax.optimization_barrier(([views[at] for at in seen], beside))
+    for at, view in zip(seen, held):
+        views[at] = view
+    return treedef.unflatten(views), beside
+
+
+def leaf_view(tiles: jnp.ndarray, shape: Tuple[int, ...]) -> jnp.ndarray:
+    """:func:`tile_view`'s inverse: the leaf of ``shape`` whose view
+    ``tiles`` is (``tiles`` itself where the leaf has no such view)."""
+    shape = tuple(shape)
+    if tuple(tiles.shape) == shape:
+        return tiles
+    order = _tile_order(shape)
+    if order is not None:
+        rows, cols = order
+        tiles = tiles.reshape(rows // 8, cols // 128, 8, 128).transpose(0, 2, 1, 3)
+    return tiles.reshape(shape)
+
+
 @dataclass(frozen=True)
 class RowLayout:
     """Where each leaf of a parameter tree sits in a ``width``-column row
@@ -119,6 +179,9 @@ class RowLayout:
     place: Callable[..., List[jnp.ndarray]]
     ravel: Callable[..., jnp.ndarray]
     unravel: Callable[[jnp.ndarray], Any]
+    # ``unravel`` with every leaf that has a tile_view as that view; None
+    # where rows are not folded (no leaf is whole tiles of such a row)
+    unravel_tiles: Optional[Callable[[jnp.ndarray], Any]] = None
 
     @property
     def tile_leaves(self) -> int:
@@ -153,8 +216,13 @@ def row_layout(example: Any, width: int, *, folded: bool) -> RowLayout:
     ``t``. ``place(tree, cast=None)`` returns the row's pieces as 1-D
     arrays (first columns: ``offsets``; the last piece is the ravelled
     rest with the tail), ``ravel`` joins them into the ``(width,)`` row,
-    and ``unravel`` takes the first ``d`` columns back to the tree. All
-    are trace-safe.
+    and ``unravel`` takes the first ``d`` columns back to the tree.
+    ``unravel_tiles`` is ``unravel`` with every leaf that is whole tiles of
+    the row in the row's own order (:func:`tile_view`) left as that view,
+    ``(size / 1024, 8, 128)``: a slice of the folded row along its major
+    dimension, which a reader takes in place, where ``unravel``'s slice of
+    the flat row stands before a change of layout and is copied out first
+    (``None`` where rows are not folded). All are trace-safe.
     """
     leaves, treedef = jax.tree_util.tree_flatten(example)
     shapes = [tuple(leaf.shape) for leaf in leaves]
@@ -207,5 +275,24 @@ def row_layout(example: Any, width: int, *, folded: bool) -> RowLayout:
             got[k] = leaf.astype(dtypes[k])
         return jax.tree_util.tree_unflatten(treedef, got)
 
+    def unravel_tiles(flat: jnp.ndarray) -> Any:
+        folded_row = flat.reshape(-1, 8, 128)
+        cut = [folded_row[first // _TILE : (first + sizes[k]) // _TILE] for k, first, _ in tiles]
+        # What is no tiles of the row has to be relaid, and is cut out of the
+        # row FIRST: behind a barrier, or the compiler turns the cut of a leaf
+        # `(R, C)` with `C` a divisor of the width into a relayout of the whole
+        # row to `C` columns (of 128 lanes each) and a cut of that
+        apart = [at for at, (k, _, _) in enumerate(tiles) if not _is_tiles(shapes[k])]
+        held = jax.lax.optimization_barrier(([cut[at] for at in apart], flat[placed:d]))
+        for at, piece in zip(apart, held[0]):
+            cut[at] = piece.reshape(shapes[tiles[at][0]])
+        got: List[Any] = [None] * len(shapes)
+        for (k, _, _), leaf in zip(tiles, cut):
+            got[k] = leaf.astype(dtypes[k])
+        for k, leaf in zip(rest_at, unravel_rest(held[1])):
+            got[k] = leaf.astype(dtypes[k])
+        return jax.tree_util.tree_unflatten(treedef, got)
+
     return RowLayout(width=width, d=d, dtype=dtype, offsets=tuple(offsets),
-                     place=place, ravel=ravel, unravel=unravel)
+                     place=place, ravel=ravel, unravel=unravel,
+                     unravel_tiles=unravel_tiles if folded else None)

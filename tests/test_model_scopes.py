@@ -338,10 +338,33 @@ PARENT_LOWERED = {
 }
 
 
+# PR 42 changed ``round.update`` alone, and only where the optimizer's update
+# is elementwise (``coordinatewise.is_elementwise``, the round's default is):
+# a segment whose row is whole tiles (on the CPU: whose d divides by 1024)
+# takes its leaves' stretches as tile views and sums the norm leaf by leaf.
+# With every optimizer sent down the other path (whole leaves, as before) the
+# four texts are still the parent's, digest for digest; down the new path:
+ROW_ORDER_LOWERED = {
+    "nemotron": "fa69c81d10845f6b28e2fbed62ef6ac4ba7a7e5bd1dc82dd983340b6205322bb",
+    "glm": "f4a024fa13517ed45c19d781bf6021787d550efd03a9bcf7c1f93f85cd3752f7",
+    "qwen": "82ac41af5e5099b1341ad04880b300bda8b84f0cfde5c8d93741ec60489eb811",
+    "xing": "7eb5d95cc99d4987ffbbca2c464f5b518826806d0a53ff95e42b6804a2fafbcb",
+}
+
+
 @pytest.mark.parametrize("model", sorted(PARENT_LOWERED))
-def test_the_toy_streamed_steps_lower_to_the_parents_text(model):
+def test_the_toy_streamed_steps_lower_to_the_parents_text(monkeypatch, model):
+    from byzpy_tpu.ops import coordinatewise
+
+    monkeypatch.setattr(coordinatewise, "is_elementwise", lambda opt, params, state: False)
     text = _LOC.sub("", _lowered(model)[1].as_text())
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_LOWERED[model]
+
+
+@pytest.mark.parametrize("model", sorted(ROW_ORDER_LOWERED))
+def test_the_toy_streamed_steps_lower_to_the_text_they_had_in_the_rows_order(model):
+    text = _LOC.sub("", _lowered(model)[1].as_text())
+    assert hashlib.sha256(text.encode()).hexdigest() == ROW_ORDER_LOWERED[model]
 
 
 # -- the catalog and byzlint hold the labels ----------------------------------

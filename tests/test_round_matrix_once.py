@@ -32,7 +32,7 @@ import numpy as np
 import optax
 import pytest
 
-from byzpy_tpu.models.bundle import ModelBundle
+from byzpy_tpu.models.bundle import ModelBundle, Segment
 from byzpy_tpu.models.nets import mnist_mlp
 from byzpy_tpu.ops import attack_ops, pallas_kernels, preagg, robust
 from byzpy_tpu.parallel.ps import (
@@ -641,6 +641,35 @@ def test_folded_stack_is_what_the_aggregate_is_handed(
     np.testing.assert_array_equal(matrix, want)
     assert np.count_nonzero(matrix[:, d:]) == 0
 
+# the streamed round's update: a segmented toy whose body holds a leaf at a
+# first column that is no multiple of its own row of tiles ((64, 1024) after
+# (16, 256): 4096 is no multiple of 8192), a leaf under 128 lanes wide
+# ((256, 64): the width of every folded row divides by 64) and ragged ones
+STREAMED_SHAPES = {
+    "body": {"w0": (16, 256), "w1": (64, 1024), "w2": (256, 64), "b": (64,), "w3": (1024, 3)},
+    "head": {"w": (64, 10), "b": (10,)},
+}
+ROW_SIZED = 32_768  # half the largest leaf; the narrow leaf's own stretch is 16,384
+
+
+def _streamed_toy():
+    def body(p, x):
+        y = jnp.tanh(x @ p["w0"])
+        y = jnp.tanh(y[:, :64] @ p["w1"])
+        return jnp.tanh(y[:, :256] @ p["w2"] + p["b"]) + (y @ p["w3"]).sum(-1, keepdims=True)
+
+    def head(p, x, y):
+        logits = x @ p["w"] + p["b"]
+        return optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
+
+    keys = iter(jax.random.split(jax.random.PRNGKey(5), 8))
+    params = {seg: {name: jax.random.normal(next(keys), shape, jnp.float32) / np.sqrt(shape[0])
+                    for name, shape in leaves.items()}
+              for seg, leaves in STREAMED_SHAPES.items()}
+    return ModelBundle(apply_fn=None, params=params,
+                       segments=(Segment("body", body), Segment("head", head)))
+
+
 # the attention kernels' compile: a sequence that is neither the query
 # heads' width (4096) nor a block's, a hidden size that is no block's
 ATTENTION_TOKENS, ATTENTION_HIDDEN = 2048, 384
@@ -680,6 +709,21 @@ def _write_tpu_texts(out_dir):
         text = jax.jit(step).lower(*args).compile().as_text()
         with open(os.path.join(out_dir, name + ".hlo.txt"), "w", encoding="utf-8") as fh:
             fh.write(text)
+
+    # the streamed round on the segmented toy, its default optimizer
+    from byzpy_tpu.ops import coordinatewise
+
+    streamed = _streamed_toy()
+    step, opt_state = build_ps_train_step(
+        streamed, AGGREGATORS["trimmed_mean"], CFG,
+        attack=coordinatewise.RoundAttack(attack_ops.sign_flip, of="honest_mean"))
+    text = jax.jit(step).lower(
+        described(streamed.params), described(opt_state),
+        jax.ShapeDtypeStruct((N, 4, 16), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((N, 4), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)).compile().as_text()
+    with open(os.path.join(out_dir, "streamed_update.hlo.txt"), "w", encoding="utf-8") as fh:
+        fh.write(text)
 
     # the block-causal attention kernels at the Nemotron cell's heads
     # (32 / 2 x 128), value and gradient through gqa_attention under vmap
@@ -780,7 +824,7 @@ def tpu_texts(tmp_path_factory):
         pytest.fail("compiling the toy rounds for a described v5e failed "
                     f"(exit {done.returncode}):\n{done.stderr[-3000:]}")
     texts = {}
-    for name in [*FOLDED_ROUNDS, "attention_float32", "attention_bfloat16",
+    for name in [*FOLDED_ROUNDS, "streamed_update", "attention_float32", "attention_bfloat16",
                  "mla_attention_float32", "mla_attention_192_128_float32",
                  *(f"experts_{cell}" for cell in EXPERT_CELLS)]:
         with open(os.path.join(out_dir, name + ".hlo.txt"), encoding="utf-8") as fh:
@@ -847,6 +891,56 @@ def test_on_the_tpu_the_sort_kernels_operand_is_the_loops_stack(tpu_texts, agg):
 
 
 # -- the attention kernels, compiled by Mosaic ----------------------------------
+
+
+def _moved_under(text, scope, at_least):
+    """Instructions of the entry computation under ``scope`` that move
+    at least ``at_least`` elements and compute nothing: ``(opcode, line)``
+    of every ``copy``, ``slice``, ``reshape``, ``transpose``,
+    ``concatenate`` and ``pad`` (a ``reshape`` that is left in a compiled
+    TPU text is a relayout: a free one is a ``bitcast``), and of every
+    fusion made of such ops alone."""
+    moves = {"copy", "slice", "reshape", "transpose", "concatenate", "pad"}
+    fused = {}
+    for block in re.split(r"\n(?=%?[\w.\-]+ \()", text):
+        head = re.match(r"%?([\w.\-]+) \(", block)
+        if head:
+            fused[head.group(1)] = set(re.findall(
+                r"= \S+ ([a-z\-]+)\(", block)) - {"parameter", "bitcast", "tuple"}
+    found = []
+    for opcode, _, line in _entry_instructions(text).values():
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if not op_name or scope not in op_name.group(1):
+            continue
+        if opcode == "fusion":
+            inside = fused.get(re.search(r"calls=%?([\w.\-]+)", line).group(1), {"?"})
+            if not inside <= moves:
+                continue
+        elif opcode not in moves:
+            continue
+        result = line.split(" = ", 1)[1].split(" " + opcode + "(", 1)[0]
+        sizes = [int(np.prod([int(n) for n in dims.split(",") if n]))
+                 for dims in re.findall(r"f32\[([\d,]*)\]", result)]
+        if sizes and max(sizes) >= at_least:
+            found.append((opcode, line.strip()[:200]))
+    return found
+
+
+def test_on_the_tpu_the_streamed_update_moves_no_row_sized_array(tpu_texts):
+    """Under ``round.update`` of the segmented toy's step nothing stands
+    that moves a row-sized array without computing: no cut of the row
+    into a buffer of its own before a leaf's update, no relayout of the
+    whole row to a narrow leaf's minor dimension (the parent of PR 42
+    compiled three: a ``slice`` to f32[65536] for ``w1``, and a
+    ``reshape`` of the body's whole row, 98,304 columns, to f32[1536, 64]
+    for ``w2`` and to f32[32768, 3] for ``w3``). What is left is smaller
+    than ``ROW_SIZED``: a narrow leaf's own stretch, relaid."""
+    text = tpu_texts["streamed_update"]
+    assert "tpu_custom_call" in text and "round.update" in text
+    assert _moved_under(text, "round.update", ROW_SIZED) == []
+    # and the reader sees what it is for: the aggregate kernel's own scope
+    # holds the row-sized bitcasts and nothing that moves
+    assert _moved_under(text, "round.aggregate", ROW_SIZED) == []
 
 
 def _attention_calls(text):
