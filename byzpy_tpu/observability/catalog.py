@@ -215,6 +215,7 @@ KERNELS: FrozenSet[str] = frozenset(
         "selection_mean_stream",
         "sort_columns",
         "sorted_reduce_stream",
+        "sorted_reduce_stream_attacked",
         "weighted_center_step",
     }
 )

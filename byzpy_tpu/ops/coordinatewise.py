@@ -25,13 +25,25 @@ Whether an update is more than leaf by leaf, ELEMENT by element, is asked
 of the update itself (:func:`is_elementwise`, its jaxpr): that decides
 nothing about what may stream, only how the round hands a segment's
 leaves to it (on their tiles in the row's own order, or whole).
+
+A second set, :data:`KERNEL_FORMED_ATTACKS` beside
+:data:`KERNEL_ATTACKED`, declares what the sort kernel can do INSIDE its
+body: form the byzantine rows of a block from the honest rows of that
+block, so that the round writes no attack row and allocates none
+(``docs/performance.md``, "How a call reaches a kernel"). A member is a
+deterministic function of the honest rows (no key), it lowers in Mosaic
+on ``(h, r, 128)`` blocks, and it keeps a column of zeros at zero (a
+row's pad columns are not masked there). The streamed round asks
+:func:`attacked_in_kernel`, once, before it traces; what the sets do not
+name keeps the round that writes the rows. It is a declaration like the
+rest: nothing tries a lowering to find out.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Callable, Dict, FrozenSet, Mapping, NamedTuple
+from typing import Any, Callable, Dict, FrozenSet, Mapping, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +69,24 @@ AGGREGATES: FrozenSet[Callable] = frozenset(
 ATTACKS: FrozenSet[Callable] = frozenset(
     {attack_ops.sign_flip, attack_ops.empire, attack_ops.little, attack_ops.mimic}
 )
+
+
+#: attacks of :data:`ATTACKS` that the sort kernel forms in its body from
+#: the block of honest rows it holds: no key read, lowers in Mosaic, a
+#: column of zeros stays zero. (``little`` is not one: its ``ndtri`` of a
+#: Python float is traced under its ``jit`` to a polynomial with array
+#: constants, which Mosaic's lowering of a nested ``jit`` refuses.)
+KERNEL_FORMED_ATTACKS: FrozenSet[Callable] = frozenset(
+    {attack_ops.sign_flip, attack_ops.empire, attack_ops.mimic}
+)
+
+#: aggregates whose kernel takes the honest rows alone and an attack to
+#: form the others from: the aggregate -> that form of it (same keywords,
+#: and ``attack=``, ``b=``)
+KERNEL_ATTACKED: Mapping[Callable, Callable] = {
+    robust.trimmed_mean: robust.trimmed_mean_attacked,
+    robust.coordinate_median: robust.coordinate_median_attacked,
+}
 
 
 class Leafwise(NamedTuple):
@@ -89,6 +119,9 @@ class RoundAttack:
     def __post_init__(self) -> None:
         if self.of not in ("honest", "honest_mean"):
             raise ValueError(f"of must be 'honest' or 'honest_mean', got {self.of!r}")
+
+    def __hash__(self) -> int:  # a static argument of the kernel's jitted call
+        return hash((self.fn, self.of, tuple(sorted(self.kwargs.items()))))
 
     def __call__(self, honest: Array, key: jax.Array) -> Array:
         given = jnp.mean(honest, axis=0) if self.of == "honest_mean" else honest
@@ -159,6 +192,25 @@ def is_coordinatewise_attack(fn: Callable) -> bool:
     return _listed(fn) in ATTACKS
 
 
+def attacked_in_kernel(aggregate: Callable, attack: Any) -> Optional[Callable]:
+    """The aggregate of the honest rows and of the rows ``attack`` makes of
+    them as ONE call ``(honest (h, width), b=) -> (width,)`` whose kernel
+    forms the attack's rows in its body, or ``None`` where the table does
+    not declare both: the aggregate one of :data:`KERNEL_ATTACKED` (seen
+    through ``partial``, its arguments kept), the attack a
+    :class:`RoundAttack` itself (which ignores the round's key; a subclass
+    may not) of one of :data:`KERNEL_FORMED_ATTACKS`. Whether a kernel
+    serves the rows at hand is the gate's to say
+    (``robust.attacked_serves``), not the table's."""
+    if type(attack) is not RoundAttack or attack.fn not in KERNEL_FORMED_ATTACKS:
+        return None
+    form = KERNEL_ATTACKED.get(_listed(aggregate))
+    if form is None:
+        return None
+    bound = aggregate if isinstance(aggregate, partial) else partial(aggregate)
+    return partial(form, *bound.args, **bound.keywords, attack=attack)
+
+
 def refusal(aggregate: Callable, attack: Any, optimizer: Any) -> Dict[str, str]:
     """What of a segmented round's three functions this table does not
     list, by role: empty where the round may stream."""
@@ -175,8 +227,11 @@ def refusal(aggregate: Callable, attack: Any, optimizer: Any) -> Dict[str, str]:
 __all__ = [
     "AGGREGATES",
     "ATTACKS",
+    "KERNEL_ATTACKED",
+    "KERNEL_FORMED_ATTACKS",
     "Leafwise",
     "RoundAttack",
+    "attacked_in_kernel",
     "is_coordinatewise_aggregate",
     "is_coordinatewise_attack",
     "is_elementwise",

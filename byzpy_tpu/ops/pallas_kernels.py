@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -365,12 +365,36 @@ def _sublane_order_sum(vals):
     return total
 
 
-def _sorted_reduce_stream_kernel(x_ref, o_ref, *, n: int, f: int, mode: str):
+def _attack_keys(attack, honest: Array, b: int):
+    """The sort keys of the ``b`` byzantine workers' rows, formed from the
+    block ``honest`` ``(h, r, 128)`` of the honest workers' rows that the
+    kernel holds. ``attack(honest, None)`` is traced here, in the kernel's
+    body (a mean over the leading axis is a chain of adds on whole vregs),
+    and gives one ``(r, 128)`` row that every byzantine worker sends, or
+    ``b`` of them; each is rounded to the rows' dtype, as a row written to
+    the stack was. A row sent ``b`` times is keyed once."""
+    out = jnp.asarray(attack(honest, None))
+    rows = [out] if out.ndim == honest.ndim - 1 else [out[i] for i in range(out.shape[0])]
+    if len(rows) not in (1, b) or rows[0].shape != honest.shape[1:]:
+        raise ValueError(
+            f"the attack gives {out.shape} of honest rows {honest.shape}: one row, or one "
+            f"for each of the {b} byzantine workers")
+    keys = [_float_sort_keys(row.astype(honest.dtype).astype(jnp.float32)) for row in rows]
+    return keys * b if len(keys) == 1 else keys
+
+
+def _sorted_reduce_stream_kernel(x_ref, o_ref, *, n: int, f: int, mode: str, attack=None):
     """Per block of folded rows: key-sort the ``n`` workers' values of
     every coordinate in VMEM and emit ONLY the reduction — the coordinate
     median or the f-trimmed mean — so the sorted matrix never returns to
     HBM. Traffic per round: 1 read of ``x`` + a (1, d) write, vs
     sort_columns' read + full write + the reduction's re-read.
+
+    With ``attack`` the block holds the honest workers' rows alone, fewer
+    than ``n``, and the others' keys are formed here from the block
+    (:func:`_attack_keys`): the byzantine rows exist in VMEM for the
+    length of one block and nowhere else. From the first compare-exchange
+    on, the kernel is the same on the same ``n`` keys.
 
     The block is ``(1, n, r, 128)``: worker ``i``'s part of it,
     ``x_ref[0, i]``, is ``r / 8`` whole (8, 128) vregs, so the key
@@ -383,9 +407,10 @@ def _sorted_reduce_stream_kernel(x_ref, o_ref, *, n: int, f: int, mode: str):
     bit-for-bit on 16-bit floats); the trimmed sum keeps the order it
     had on the TPU as a reduction over sublanes
     (:func:`_sublane_order_sum`)."""
-    srt = _batcher_network(
-        _float_sort_keys(x_ref[0, i].astype(jnp.float32)) for i in range(n)
-    )
+    keys = [_float_sort_keys(x_ref[0, i].astype(jnp.float32)) for i in range(x_ref.shape[1])]
+    if attack is not None:
+        keys += _attack_keys(attack, x_ref[0], n - len(keys))
+    srt = _batcher_network(keys)
     if mode == "median":
         lo, hi = (n - 1) // 2, n // 2
         vlo = _keys_to_float(srt[lo], jnp.float32).astype(o_ref.dtype)
@@ -406,12 +431,28 @@ def sorted_reduce_stream_pallas(
     f: int = 0,
     tile: Optional[int] = None,
     interpret: Optional[bool] = None,
+    attack: Optional[Callable] = None,
+    b: int = 0,
 ) -> Array:
     """Coordinate-wise median (``mode='median'``) or f-trimmed mean
     (``mode='trimmed'``) over ``K`` stacked rounds ``xs: (K, n, d)`` in
     one kernel launch, returning ``(K, d)``. Float dtypes only (16-bit
     floats up-convert per-block in VMEM — half the HBM traffic of a
     pre-pass conversion).
+
+    With ``attack`` (hashable, ``(honest, key) -> rows``, the key unread)
+    ``xs`` is the ``(K, h, d)`` honest rows alone and the result is that
+    of the ``n = h + b`` rows whose last ``b`` are the attack's, which the
+    kernel forms in its body block by block from the honest rows it
+    reads anyway (:func:`_attack_keys`): nobody writes them, and the
+    kernel reads ``h`` rows where it read ``n``. The attack is traced on
+    ``(h, r, 128)`` blocks, so its column j may read column j of the
+    honest rows alone, it must lower in Mosaic, and it must keep a column
+    of zeros at zero (the wrapper's pad columns and the caller's are not
+    masked): ``ops/coordinatewise.KERNEL_FORMED_ATTACKS`` declares which
+    do. The call is a kernel of its own name,
+    ``sorted_reduce_stream_attacked``; without ``attack`` nothing differs
+    from before.
 
     The kernel reads FOLDED rows: the wrapper reshapes its argument to
     ``(K, n, d_pad / 128, 128)``, so that a worker's row is whole
@@ -432,7 +473,10 @@ def sorted_reduce_stream_pallas(
     there. The interpreter takes any multiple of 128."""
     if mode not in {"median", "trimmed"}:
         raise ValueError(f"unknown mode {mode!r}")
-    K, n, d = xs.shape
+    if (attack is None) != (b == 0):
+        raise ValueError(f"an attack and the b > 0 rows it forms go together (got b={b})")
+    K, held, d = xs.shape
+    n = held + b  # the network's working set is n rows whichever way they came
     if mode == "trimmed" and not 0 <= 2 * f < n:
         raise ValueError(f"f must satisfy 0 <= 2f < n (got n={n}, f={f})")
     if xs.dtype not in _KERNEL_DTYPES:
@@ -457,8 +501,36 @@ def sorted_reduce_stream_pallas(
             f"a {xs.dtype} block of {tile} columns is {tile // _LANES} sublane rows a "
             f"worker; Mosaic is given whole tiles: a multiple of {whole} columns"
         )
+    if attack is not None:
+        return _sorted_reduce_stream_attacked_call(
+            xs, mode=mode, f=f, tile=tile, interpret=interpret, attack=attack, b=b
+        )
     return _sorted_reduce_stream_call(
         xs, mode=mode, f=f, tile=tile, interpret=interpret
+    )
+
+
+def _sorted_reduce_stream_blocks(xs: Array, tile: int):
+    """What both sorted-reduce calls hand ``pallas_call``: ``xs``
+    ``(K, rows_held, d)`` zero-padded to whole tiles and folded, and the
+    grid, block specs and output shape for it."""
+    K, held, d = xs.shape
+    d_pad = _round_up(max(d, 1), tile)
+    if d_pad != d:
+        xs = jnp.pad(xs, ((0, 0), (0, 0), (0, d_pad - d)))
+    rows, r = d_pad // _LANES, tile // _LANES
+    return xs.reshape(K, held, rows, _LANES), dict(
+        out_shape=jax.ShapeDtypeStruct((K, rows, _LANES), xs.dtype),
+        grid=(K, rows // r),
+        in_specs=[
+            pl.BlockSpec(
+                (1, held, r, _LANES), lambda k, c: (k, 0, c, 0),
+                memory_space=pltpu.VMEM,
+            )
+        ],
+        out_specs=pl.BlockSpec(
+            (1, r, _LANES), lambda k, c: (k, c, 0), memory_space=pltpu.VMEM
+        ),
     )
 
 
@@ -467,27 +539,34 @@ def _sorted_reduce_stream_call(
     xs: Array, *, mode: str, f: int, tile: int, interpret: bool
 ) -> Array:
     K, n, d = xs.shape
-    d_pad = _round_up(max(d, 1), tile)
-    if d_pad != d:
-        xs = jnp.pad(xs, ((0, 0), (0, 0), (0, d_pad - d)))
-    rows, r = d_pad // _LANES, tile // _LANES
+    folded, blocks = _sorted_reduce_stream_blocks(xs, tile)
     out = pl.pallas_call(
         functools.partial(_sorted_reduce_stream_kernel, n=n, f=f, mode=mode),
-        out_shape=jax.ShapeDtypeStruct((K, rows, _LANES), xs.dtype),
-        grid=(K, rows // r),
-        in_specs=[
-            pl.BlockSpec(
-                (1, n, r, _LANES), lambda k, c: (k, 0, c, 0),
-                memory_space=pltpu.VMEM,
-            )
-        ],
-        out_specs=pl.BlockSpec(
-            (1, r, _LANES), lambda k, c: (k, c, 0), memory_space=pltpu.VMEM
-        ),
+        **blocks,
         interpret=interpret,
         name="sorted_reduce_stream",
-    )(xs.reshape(K, n, rows, _LANES))
-    return out.reshape(K, d_pad)[:, :d]
+    )(folded)
+    return out.reshape(K, -1)[:, :d]
+
+
+@functools.partial(
+    jax.jit, static_argnames=("mode", "f", "tile", "interpret", "attack", "b"))
+def _sorted_reduce_stream_attacked_call(
+    xs: Array, *, mode: str, f: int, tile: int, interpret: bool, attack: Callable, b: int
+) -> Array:
+    """:func:`_sorted_reduce_stream_call` on the ``(K, h, d)`` honest rows,
+    the other ``b`` formed in the kernel's body: the same grid and the same
+    output, a block of ``h`` rows in place of ``n``."""
+    K, h, d = xs.shape
+    folded, blocks = _sorted_reduce_stream_blocks(xs, tile)
+    out = pl.pallas_call(
+        functools.partial(
+            _sorted_reduce_stream_kernel, n=h + b, f=f, mode=mode, attack=attack),
+        **blocks,
+        interpret=interpret,
+        name="sorted_reduce_stream_attacked",
+    )(folded)
+    return out.reshape(K, -1)[:, :d]
 
 
 # ---------------------------------------------------------------------------

@@ -189,6 +189,46 @@ def trimmed_mean(x: Array, *, f: int) -> Array:
     return _trimmed_mean_xla(x, f=f)
 
 
+def attacked_serves(honest: Array, b: int) -> bool:
+    """Whether :func:`trimmed_mean_attacked` / :func:`coordinate_median_attacked`
+    serve these ``(h, d)`` honest rows (an array or its type): the one
+    gate's answer for the ``(h + b, d)`` matrix a caller would have built
+    otherwise."""
+    h, d = honest.shape
+    return pallas_serves(jax.ShapeDtypeStruct((h + b, d), honest.dtype))
+
+
+def _sorted_reduce_attacked(honest: Array, *, attack, b: int, **reduction) -> Array:
+    if not attacked_serves(honest, b):
+        raise ValueError(
+            f"no kernel serves the ({honest.shape[0]} + {b}, {honest.shape[1]}) "
+            f"{honest.dtype} matrix here: write the attack's rows beside the honest ones "
+            "and call the aggregator on the matrix")
+    from .pallas_kernels import sorted_reduce_stream_pallas
+
+    return sorted_reduce_stream_pallas(honest[None], attack=attack, b=b, **reduction)[0]
+
+
+def trimmed_mean_attacked(honest: Array, *, f: int, attack, b: int) -> Array:
+    """:func:`trimmed_mean` of the ``(h + b, d)`` matrix whose first rows
+    are ``honest`` ``(h, d)`` and whose last ``b`` are the rows ``attack``
+    makes of them, without that matrix: the sort kernel forms the attack's
+    rows block by block from the honest rows it reads
+    (``pallas_kernels.sorted_reduce_stream_pallas``, ``attack=``), so the
+    round writes none, allocates none and reads ``h`` rows once. Only where
+    :func:`attacked_serves`, and only for an attack that
+    ``ops/coordinatewise.py`` declares formable in a kernel: a caller asks
+    both first (``coordinatewise.attacked_in_kernel``), and elsewhere
+    builds the matrix for :func:`trimmed_mean`."""
+    return _sorted_reduce_attacked(honest, attack=attack, b=b, mode="trimmed", f=f)
+
+
+def coordinate_median_attacked(honest: Array, *, attack, b: int) -> Array:
+    """:func:`coordinate_median` as :func:`trimmed_mean_attacked` is
+    :func:`trimmed_mean`."""
+    return _sorted_reduce_attacked(honest, attack=attack, b=b, mode="median")
+
+
 def _windowed_row_mean(s: Array, count, *, f: int) -> Array:
     """Mean of sorted rows ``[f, count - f)`` via a zero-masked einsum
     row contraction. ``count`` may be a static int or a traced scalar —
@@ -1605,6 +1645,9 @@ __all__ = [
     "trimmed_mean_stream",
     "mean_of_medians_stream",
     "trimmed_mean",
+    "attacked_serves",
+    "trimmed_mean_attacked",
+    "coordinate_median_attacked",
     "mean_of_medians",
     "krum_scores",
     "ranked_mean",

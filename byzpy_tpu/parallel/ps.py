@@ -197,11 +197,21 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
     parameters are updated, so its n rows are dead by then: at most one
     segment's rows exist at a time, and no ``(n, d)`` array ever does.
 
+    Where the sort kernel can form the byzantine rows itself (a trimmed
+    mean or median, an attack that ``ops/coordinatewise.py`` declares
+    formable in a kernel, ``b > 0``, and the gate serving the segment's
+    ``(n, width_segment)`` matrix: ``coordinatewise.attacked_in_kernel``,
+    ``robust.attacked_serves``, both asked here before anything traces)
+    the stack is ``(h, width_segment)``, nothing is written into it but
+    the honest gradients, and the aggregate is one call on it. Anything
+    else keeps the three sweeps above, the (n, d) round's own
+    ``_byzantine_rows`` and ``aggregate(matrix)``.
+
     Exact where aggregate and attack treat every column alone and the
     optimizer every leaf alone: ``ops/coordinatewise.py`` is the table of
     those, and anything it does not list is refused here.
     """
-    from ..ops import coordinatewise
+    from ..ops import coordinatewise, robust
     from ..ops.pallas_kernels import aligned_width
 
     refused = coordinatewise.refusal(aggregate, attack, optimizer)
@@ -252,6 +262,16 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
         return all(coordinatewise.is_elementwise(opt, sub, state) for sub, state in (whole, tiles))
 
     in_rows = [in_row_order(seg, layout) for seg, layout in zip(segs, layouts)]
+    # Where the table declares aggregate and attack so and the gate serves a
+    # segment's (n, width) matrix, nobody builds that matrix: the segment's
+    # stack holds the h honest rows and the sort kernel forms the other b in
+    # its body (ops/coordinatewise.attacked_in_kernel). Elsewhere the round
+    # writes them, as the (n, d) round does.
+    attacked = coordinatewise.attacked_in_kernel(aggregate, attack) if b else None
+    rows_dtypes = [grad_dtype if grad_dtype is not None else layout.dtype for layout in layouts]
+    formed = [attacked is not None and robust.attacked_serves(
+        jax.ShapeDtypeStruct((h, layout.width), dtype), b)
+        for layout, dtype in zip(layouts, rows_dtypes)]
 
     def train_step(params, opt_state, xs, ys, key):
         xs_h, ys_h = xs[:h], ys[:h]
@@ -336,7 +356,6 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                 row_shape = folded((width,))
                 lanes = row_shape[1:]
                 lane = math.prod(lanes)
-                rows_dtype = grad_dtype if grad_dtype is not None else layout.dtype
                 reads = wires[k - 1][1] if k else []
                 # An array this segment's input holds was made by the segment
                 # before it, or handed on by it. The first is read here for the
@@ -407,7 +426,8 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                                         carry["head_aux"], aux[0])
                     return carry
 
-                carry = {"rows": jax.lax.empty((n, *row_shape), rows_dtype)}
+                carry = {"rows": jax.lax.empty(
+                    (h if formed[k] else n, *row_shape), rows_dtypes[k])}
                 if k:
                     carry["io"] = io
                 if k == last:
@@ -423,16 +443,20 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                 carry = jax.lax.fori_loop(0, h, one_backward, carry)
                 losses = carry.get("losses", losses)
                 head_aux = carry.get("head_aux", head_aux)
-                with jax.named_scope("round.build_matrix"):
-                    stack = carry["rows"]
-                    if b:
-                        byz = _byzantine_rows(attack, stack[:h].reshape(h, width),
-                                              jax.random.fold_in(key, k), b, layout.d)
-                        stack = stack.at[h:].set(jnp.broadcast_to(
-                            byz.reshape(byz.shape[0], *row_shape), (b, *row_shape)))
-                    matrix = stack.reshape(n, width)
-                with jax.named_scope("round.aggregate"):
-                    agg = aggregate(matrix).astype(layout.dtype)
+                if formed[k]:
+                    with jax.named_scope("round.aggregate"):
+                        agg = attacked(carry["rows"].reshape(h, width), b=b).astype(layout.dtype)
+                else:
+                    with jax.named_scope("round.build_matrix"):
+                        stack = carry["rows"]
+                        if b:
+                            byz = _byzantine_rows(attack, stack[:h].reshape(h, width),
+                                                  jax.random.fold_in(key, k), b, layout.d)
+                            stack = stack.at[h:].set(jnp.broadcast_to(
+                                byz.reshape(byz.shape[0], *row_shape), (b, *row_shape)))
+                        matrix = stack.reshape(n, width)
+                    with jax.named_scope("round.aggregate"):
+                        agg = aggregate(matrix).astype(layout.dtype)
                 with jax.named_scope("round.update"):
                     # (the columns past d are exactly zero: they add nothing to
                     # the norm, and unravel reads the first d alone)

@@ -201,8 +201,8 @@ def _kernel_cases(n: int, d: int, quant_pallas: Optional[bool]) -> List[tuple]:
     fp8 codecs; the s4 kernels do not lower — ROADMAP S4). ``fn`` goes
     through the PUBLIC dispatching function where there is one, so the
     same callable serves both routes."""
+    from byzpy_tpu.ops import attack_ops, coordinatewise, preagg, robust
     from byzpy_tpu.ops import pallas_kernels as pk
-    from byzpy_tpu.ops import preagg, robust
     from byzpy_tpu.parallel.quantization import (
         dequantize_blockwise,
         encode_blockwise,
@@ -217,6 +217,17 @@ def _kernel_cases(n: int, d: int, quant_pallas: Optional[bool]) -> List[tuple]:
 
     def xla_route() -> bool:
         return os.environ.get("BYZPY_TPU_PALLAS") == "0"
+
+    flip = coordinatewise.RoundAttack(attack_ops.sign_flip, of="honest_mean")
+
+    def attacked(x):
+        # the last f workers byzantine: the kernel forms their rows from the
+        # others' in its body (the streamed round's call); XLA writes them
+        honest = x[: n - f]
+        if xla_route():
+            rows = jnp.broadcast_to(flip(honest, None).astype(x.dtype), (f, d))
+            return robust.trimmed_mean(jnp.concatenate([honest, rows]), f=f)
+        return robust.trimmed_mean_attacked(honest, f=f, attack=flip, b=f)
 
     def from_gram(x):
         with jax.default_matmul_precision("highest"):
@@ -271,6 +282,7 @@ def _kernel_cases(n: int, d: int, quant_pallas: Optional[bool]) -> List[tuple]:
          robust.coordinate_median, "select"),
         ("trimmed_mean", "sorted_reduce_stream_pallas",
          partial(robust.trimmed_mean, f=f), "mean"),
+        ("trimmed_mean_attacked", "sorted_reduce_stream_pallas[attack]", attacked, "mean"),
         ("mean_of_medians", "meamed_stream_pallas | sort_columns",
          partial(robust.mean_of_medians, f=f), "mean"),
         ("multi_krum", "selection_mean_pallas",

@@ -68,7 +68,7 @@ def test_phase_kernels_toy():
         [(8, 640, "float32"), (16, 520, "bfloat16")],
         kernel_route="1", require_mosaic=False,
     )
-    assert len(rec["cases"]) == 2 * 22
+    assert len(rec["cases"]) == 2 * 23
     assert os.environ.get("BYZPY_TPU_PALLAS") is None
 
 

@@ -34,6 +34,8 @@ from jax.sharding import PartitionSpec as P
 
 from byzpy_tpu.ops import pallas_kernels as pk
 from byzpy_tpu.ops import preagg, robust
+from byzpy_tpu.ops.attack_ops import sign_flip as _SIGN_FLIP
+from byzpy_tpu.ops.coordinatewise import RoundAttack as _ROUND_ATTACK
 from byzpy_tpu.parallel import quantization as qz
 from byzpy_tpu.pre_aggregators import NearestNeighborMixing
 
@@ -253,6 +255,36 @@ def test_entry_point_takes_the_gates_answer(backend, name):
     assert answers == [True, False]
 
 
+@pytest.mark.parametrize("name", ["trimmed_mean_attacked", "coordinate_median_attacked"])
+def test_the_attacked_entry_points_take_the_gates_answer(backend, name):
+    """The forms that are handed the honest rows alone ask the same gate for
+    the ``(h + b, d)`` matrix nobody builds: at the floor the kernel that
+    forms the other ``b`` rows, one column under it a refusal that sends the
+    caller to the matrix (the streamed round asks ``attacked_serves`` first
+    and never gets there)."""
+    from byzpy_tpu.ops import attack_ops, coordinatewise
+
+    b = 2
+    flip = coordinatewise.RoundAttack(attack_ops.sign_flip, of="honest_mean")
+    kwargs = {"f": 2} if name == "trimmed_mean_attacked" else {}
+    fn = partial(getattr(robust, name), attack=flip, b=b, **kwargs)
+    backend("tpu")
+    honest = _sds((N - b, FLOOR))
+    assert robust.attacked_serves(honest, b) is pk.pallas_serves(_sds((N, FLOOR))) is True
+    assert "name=sorted_reduce_stream_attacked" in str(jax.make_jaxpr(lambda a: fn(a))(honest))
+    under = _sds((N - b, FLOOR - 1))
+    assert robust.attacked_serves(under, b) is pk.pallas_serves(_sds((N, FLOOR - 1))) is False
+    with pytest.raises(ValueError, match="no kernel serves"):
+        jax.make_jaxpr(lambda a: fn(a))(under)
+    # the table names both, and sees through the aggregate's partial
+    form = coordinatewise.attacked_in_kernel(
+        partial(robust.trimmed_mean, f=2) if kwargs else robust.coordinate_median, flip)
+    assert form.func is getattr(robust, name) and form.keywords == {**kwargs, "attack": flip}
+    assert coordinatewise.attacked_in_kernel(partial(robust.mean_of_medians, f=2), flip) is None
+    assert coordinatewise.attacked_in_kernel(
+        robust.coordinate_median, lambda honest, key: -honest[0]) is None
+
+
 def test_mean_of_medians_past_its_cap_sorts_through_the_generic_gate(backend):
     """MeaMed asks twice: its fused kernel between its own floor and cap,
     and past the cap the sort network wherever the generic gate holds."""
@@ -308,6 +340,10 @@ WRAPPERS = {
     "_sort_columns_call": lambda x: pk.sort_columns(x[0], interpret=False),
     "_gram_pallas_call": lambda x: pk.gram_pallas(x[0], interpret=False),
     "_sorted_reduce_stream_call": _w("sorted_reduce_stream_pallas", mode="trimmed", f=2),
+    # six of the eight rows handed over, the other two formed: asked with n = 8
+    "_sorted_reduce_stream_attacked_call": lambda x: pk.sorted_reduce_stream_pallas(
+        x[:, :-2], mode="trimmed", f=2, interpret=False, b=2,
+        attack=_ROUND_ATTACK(_SIGN_FLIP, of="honest_mean")),
     "_weighted_center_step_call": lambda x: pk.weighted_center_step_pallas(
         x[0], jnp.zeros((x.shape[-1],), x.dtype), mode="clip", c_tau=1.0, interpret=False),
     "_meamed_stream_call": _w("meamed_stream_pallas", f=2),

@@ -810,6 +810,127 @@ def test_sorted_reduce_resolves_only_blocks_of_whole_native_tiles(monkeypatch, d
     assert seen[-3:] == [whole, 512, 128]
 
 
+# -- the attack formed in the kernel's body (PR 43) ---------------------------
+
+def _formed_attacks():
+    """Every attack ``ops/coordinatewise.py`` declares formable in the kernel,
+    as the rounds hand it over: ``(name, attack, h for b, exact)``. A sign
+    flip of the honest rows themselves sends a row a honest worker, so
+    ``h == b``; a copy or a sign moves no bit, a mean is a sum in some order."""
+    from byzpy_tpu.ops import attack_ops, coordinatewise
+
+    made = {
+        attack_ops.sign_flip: [
+            ("signflip-mean", coordinatewise.RoundAttack(attack_ops.sign_flip, of="honest_mean"),
+             lambda b: 6, False),
+            ("signflip-rows", coordinatewise.RoundAttack(attack_ops.sign_flip), lambda b: b, True)],
+        attack_ops.empire: [
+            ("empire", coordinatewise.RoundAttack(attack_ops.empire, kwargs={"scale": -1.5}),
+             lambda b: 6, False)],
+        attack_ops.mimic: [
+            ("mimic", coordinatewise.RoundAttack(attack_ops.mimic, kwargs={"epsilon": 1}),
+             lambda b: 6, True)],
+    }
+    assert set(made) == set(coordinatewise.KERNEL_FORMED_ATTACKS)
+    return [case for fn in sorted(made, key=lambda fn: fn.__name__) for case in made[fn]]
+
+
+_ATTACKED_CASES = [
+    pytest.param(mode, f, attack, h_of(b), b, exact, id=f"{mode}{f or ''}-{name}-b{b}")
+    for mode, f in (("trimmed", 1), ("trimmed", 2), ("median", 0))
+    for name, attack, h_of, exact in _formed_attacks()
+    for b in (1, 2)
+    if mode == "median" or 2 * f < h_of(b) + b
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("width, d", [(1024, 1024), (1000, 900)], ids=["tiles", "padded"])
+@pytest.mark.parametrize("mode, f, attack, h, b, exact", _ATTACKED_CASES)
+def test_rows_formed_in_the_kernel_equal_rows_written_to_the_matrix(
+        mode, f, attack, h, b, exact, width, d, dtype):
+    """The call with a prologue on the ``(h, width)`` honest rows against the
+    round's own ``_byzantine_rows`` written beside them and the kernel on the
+    ``(n, width)`` matrix. Where the attack is a copy or a sign the two agree
+    bit for bit. Where it takes the mean of the h rows, the two add them in
+    different orders: a sum of h values of magnitude at most ``a`` is off by
+    at most ``(h - 1) eps a`` either way, the formed row by ``|scale|`` times
+    that over h, and a sorted window's mean or midpoint moves by no more than
+    its one moved entry; 16-bit rows round that row, and the result, once more
+    (an ulp of bfloat16 each). A width that is not whole tiles is padded by the
+    wrapper; the columns past ``d``, zero in every honest row, come out zero."""
+    from byzpy_tpu.ops.pallas_kernels import sorted_reduce_stream_pallas
+    from byzpy_tpu.parallel.ps import _byzantine_rows
+
+    honest = 4 * jax.random.normal(jax.random.PRNGKey(43 * h + b), (h, width), jnp.float32)
+    honest = jnp.where(jnp.arange(width) < d, honest, 0).astype(dtype)
+    byz = _byzantine_rows(attack, honest, None, b, d)
+    matrix = jnp.concatenate([honest, jnp.broadcast_to(byz, (b, width))])
+    want = sorted_reduce_stream_pallas(matrix[None], mode=mode, f=f, tile=512, interpret=True)
+    got = sorted_reduce_stream_pallas(
+        honest[None], mode=mode, f=f, tile=512, interpret=True, attack=attack, b=b)
+    assert got.shape == want.shape == (1, width) and got.dtype == want.dtype == dtype
+    got, want = (np.asarray(a[0].astype(jnp.float32)) for a in (got, want))
+    assert not got[d:].any() and not want[d:].any()
+    if exact:
+        np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    else:
+        a = float(jnp.max(jnp.abs(honest.astype(jnp.float32))))
+        scale = abs(attack.kwargs.get("scale", -1.0))
+        atol = scale * a * ((h - 1) * np.finfo(np.float32).eps / h
+                            + (2 * float(jnp.finfo(dtype).eps) if dtype != jnp.float32 else 0))
+        np.testing.assert_allclose(got, want, rtol=0, atol=atol)
+        assert np.mean(got == want) > 0.5  # and most columns agree to the bit
+
+
+def test_an_attack_and_its_rows_go_together_and_a_wrong_count_is_refused():
+    from byzpy_tpu.ops import attack_ops, coordinatewise
+    from byzpy_tpu.ops.pallas_kernels import sorted_reduce_stream_pallas
+
+    honest = jnp.ones((1, 6, 256), jnp.float32)
+    flip = coordinatewise.RoundAttack(attack_ops.sign_flip, of="honest_mean")
+    with pytest.raises(ValueError, match="go together"):
+        sorted_reduce_stream_pallas(honest, mode="median", interpret=True, b=2)
+    with pytest.raises(ValueError, match="go together"):
+        sorted_reduce_stream_pallas(honest, mode="median", interpret=True, attack=flip)
+    with pytest.raises(ValueError, match="0 <= 2f < n"):  # n counts the formed rows
+        sorted_reduce_stream_pallas(honest, mode="trimmed", f=4, interpret=True, attack=flip, b=2)
+    with pytest.raises(ValueError, match="one row, or one for each"):  # six rows for two workers
+        sorted_reduce_stream_pallas(honest, mode="median", interpret=True,
+                                    attack=coordinatewise.RoundAttack(attack_ops.sign_flip), b=2)
+
+
+# sha256[:16] of the jaxpr of the call WITHOUT a prologue, taken on the commit
+# before the kernel could form rows (6612e71) with `_plain_jaxpr_digest`: the
+# wrapper, the jitted call, the block specs and the kernel's body, line for line
+_PARENT_PLAIN_JAXPRS = {
+    ("trimmed", 1, "float32", 1024): "9712d77464858849",
+    ("trimmed", 2, "float32", 1000): "c6dfcdfead440c65",
+    ("trimmed", 2, "bfloat16", 1024): "25d08499071462fe",
+    ("median", 0, "float32", 1024): "98082fa9f0a376e8",
+    ("median", 0, "float32", 1000): "6eba5459ac52a036",
+    ("median", 0, "bfloat16", 1000): "209003bee3155ba4",
+}
+
+
+def _plain_jaxpr_digest(mode, f, dtype, d):
+    import hashlib
+    import re
+
+    from byzpy_tpu.ops.pallas_kernels import sorted_reduce_stream_pallas
+
+    text = str(jax.make_jaxpr(lambda a: sorted_reduce_stream_pallas(
+        a, mode=mode, f=f, tile=512 if d == 1024 else 128, interpret=True))(
+            jnp.zeros((1, 8, d), dtype)))
+    text = re.sub(r" at [^\s]*pallas_kernels\.py:\d+", "", text)  # where the kernel stands
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("mode, f, dtype, d", sorted(_PARENT_PLAIN_JAXPRS))
+def test_without_a_prologue_the_kernel_traces_to_what_it_traced_to(mode, f, dtype, d):
+    assert _plain_jaxpr_digest(mode, f, dtype, d) == _PARENT_PLAIN_JAXPRS[mode, f, dtype, d]
+
+
 # ---------------------------------------------------------------------------
 # Fused MeaMed kernel
 # ---------------------------------------------------------------------------

@@ -943,6 +943,21 @@ def test_on_the_tpu_the_streamed_update_moves_no_row_sized_array(tpu_texts):
     assert _moved_under(text, "round.aggregate", ROW_SIZED) == []
 
 
+def test_on_the_tpu_the_streamed_round_has_its_kernel_form_the_byzantine_rows(tpu_texts):
+    """The same step (trimmed mean under the omniscient sign flip, kernels
+    serving): Mosaic compiles the kernel with the attack in its body, one
+    call a segment, which the benchmark's own counter reads; each stack
+    holds the h honest rows; nothing stands under ``round.build_matrix``
+    and no array leads with n where the parent of PR 43 kept n rows of a
+    segment."""
+    text = tpu_texts["streamed_update"]
+    reader = _benchmark_reader("attack_in_kernel_segments.train")
+    assert reader.read(SimpleNamespace(outcome={"compiled_text": text})) == 2  # body, head
+    assert "sorted_reduce_stream." not in text and "round.build_matrix" not in text
+    assert re.search(r"f32\[%d,\d+,128\]" % (N - B), text)
+    assert not re.search(r"f32\[(1,)?%d,\d+,128\]" % N, text)
+
+
 def _attention_calls(text):
     """``{kernel's name: its custom call's line}`` under ``model.attention``."""
     calls = {}

@@ -420,6 +420,139 @@ def test_single_array_boundaries_stream_the_text_of_the_rows_order(cell):
     assert _streamed_texts()[cell] == ROW_ORDER_STREAMED_TEXTS[cell]
 
 
+# -- the byzantine rows formed in the sort kernel's body (PR 43) --------------
+
+MIMIC = coordinatewise.RoundAttack(attack_ops.mimic, kwargs={"epsilon": 1})
+LITTLE = coordinatewise.RoundAttack(attack_ops.little, kwargs={"f": B, "n_total": N})
+
+
+class Keyed(coordinatewise.RoundAttack):
+    """A round attack that does read the key (which side the flip goes)."""
+
+    def __call__(self, honest, key):
+        return super().__call__(honest, key) * jax.random.rademacher(key, (), jnp.float32)
+
+
+def _kernel_calls(jaxpr, found=None):
+    """The sort family's pallas_calls under ``jaxpr``, by name -> how many."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            if name.startswith("sorted_reduce"):
+                found[name] = found.get(name, 0) + 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _kernel_calls(inner, found)
+    return found
+
+
+def _stepped(aggregate, attack, b, rounds=3):
+    cfg = PSStepConfig(n_nodes=N, n_byzantine=b, learning_rate=0.1, momentum=0.9)
+    streamed, _ = _bundles()
+    step, opt = build_ps_train_step(streamed, aggregate, cfg, attack=attack)
+    xs, ys = _batches()[0]
+    calls = _kernel_calls(jax.make_jaxpr(step)(
+        streamed.params, opt, xs, ys, jax.random.PRNGKey(0)).jaxpr)
+    step = jax.jit(step)
+    params, norms = streamed.params, []
+    for i, (xs, ys) in enumerate(_batches(steps=rounds)):
+        params, opt, metrics = step(params, opt, xs, ys, jax.random.PRNGKey(i))
+        norms.append(metrics["agg_grad_norm"])
+    return calls, params, opt, norms
+
+
+@pytest.mark.parametrize("attack", ["signflip", "empire", "mimic"])
+@pytest.mark.parametrize("agg", ["trimmed", "median"])
+def test_rows_formed_in_the_kernel_step_the_round_that_writes_them(monkeypatch, agg, attack):
+    """Kernels forced (interpreted here): the step whose stacks hold h rows and
+    whose one call a segment forms the other b, against today's three sweeps
+    (the table's second set emptied), over three rounds. A mean formed in
+    another order of addition moves a row by an ulp or two and the aggregate by
+    no more (``tests/test_pallas_kernels.py`` has the bound), so the two are
+    held as close as the streamed round is held to the (n, d) round above."""
+    monkeypatch.setenv("BYZPY_TPU_PALLAS", "1")
+    attack_fn = {"signflip": SIGN_FLIP, "empire": EMPIRE, "mimic": MIMIC}[attack]
+    calls, *formed = _stepped(AGGREGATES[agg], attack_fn, B)
+    assert calls == {"sorted_reduce_stream_attacked": len(_segments())}
+    monkeypatch.setattr(coordinatewise, "KERNEL_FORMED_ATTACKS", frozenset())
+    calls, *written = _stepped(AGGREGATES[agg], attack_fn, B)
+    assert calls == {"sorted_reduce_stream": len(_segments())}
+    for got, want in zip(jax.tree_util.tree_leaves(formed[:2]),
+                         jax.tree_util.tree_leaves(written[:2])):
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(formed[2], written[2], rtol=1e-5)
+
+
+def test_the_step_that_forms_its_rows_holds_no_array_of_n_rows(monkeypatch):
+    """Lowered and compiled: h rows of a segment's width, folded, and nothing
+    with a leading n of that width, folded or flat; no op under
+    ``round.build_matrix``."""
+    monkeypatch.setenv("BYZPY_TPU_PALLAS", "1")
+    cfg = PSStepConfig(n_nodes=N, n_byzantine=B)
+    streamed, _ = _bundles()
+    step, opt = build_ps_train_step(streamed, AGGREGATES["trimmed"], cfg, attack=SIGN_FLIP)
+    xs, ys = _batches()[0]
+    lowered = jax.jit(step).lower(streamed.params, opt, xs, ys, jax.random.PRNGKey(0))
+    text = lowered.as_text()
+    assert f"tensor<{N - B}x128x128xf32>" in text
+    assert not re.search(r"tensor<(1x)?%dx(128x128|16384)xf32>" % N, text)
+    compiled = lowered.compile().as_text()
+    assert re.search(r"f32\[%d,128,128\]" % (N - B), compiled)
+    assert not re.search(r"f32\[(1,)?%d,(128,128|16384)\]" % N, compiled)
+    assert "round.build_matrix" not in compiled and "round.aggregate" in compiled
+
+
+def _written_texts():
+    """The streamed toy step with the kernels forced, lowered, for each way the
+    round keeps writing its byzantine rows: an attack the table's second set
+    does not name, one that reads the key, no byzantine worker, and the two
+    aggregates the sort kernel does not serve."""
+    streamed, _ = _bundles()
+    xs, ys = _batches()[0]
+    out = {}
+    for name, agg, attack, b in (
+        ("little", "trimmed", LITTLE, B),
+        ("keyed", "trimmed", Keyed(attack_ops.sign_flip, of="honest_mean"), B),
+        ("echo", "trimmed", None, B),
+        ("b0", "trimmed", None, 0),
+        ("mean", "mean", SIGN_FLIP, B),
+        ("meamed", "meamed", SIGN_FLIP, B),
+    ):
+        step, opt = build_ps_train_step(
+            streamed, AGGREGATES[agg], PSStepConfig(n_nodes=N, n_byzantine=b), attack=attack)
+        text = jax.jit(step).lower(streamed.params, opt, xs, ys, jax.random.PRNGKey(0)).as_text()
+        out[name] = hashlib.sha256(_canonical(text).encode()).hexdigest()
+    return out
+
+
+# sha256 of the canonical lowered text, taken on the commit before the kernel
+# could form rows (6612e71) with this file's own function under
+# BYZPY_TPU_PALLAS=1: what the new form does not serve is the round it was.
+PARENT_WRITTEN_TEXTS = {
+    "little": "b7f0424a47a680efded6007147a436d0852b67d0f087bf8cbee819caf5594a89",
+    "keyed": "e173199835e2bb903f1eeaa034f6e2f30aeef80ade3ee6b3ed367d9143a21ca2",
+    "echo": "51c8a85dc31c4a91962da3cfde2c3673c6966d4df19a482bf0739f364ffeb1b3",
+    "b0": "b02096c455bfe2400b8ea09bd3b4491c5bc00be8554398f96761baefff72e86a",
+    "mean": "348dbed3646d169438e155cde98f51efcb0c5253c369c8756797ff2478f8f086",
+    "meamed": "575782b1355a0853b7cd4947a3b0920cfaf9b8a19d51ae4e9fcec128df66ab18",
+}
+
+
+@pytest.fixture(scope="module")
+def written_texts():
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("BYZPY_TPU_PALLAS", "1")
+        return _written_texts()
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_WRITTEN_TEXTS))
+def test_what_the_kernel_does_not_form_is_written_as_it_was(written_texts, case):
+    assert written_texts[case] == PARENT_WRITTEN_TEXTS[case]
+
+
 # -- the streamed round's scopes: catalogued, held by byzlint, in the text ---
 
 _STREAM_SCOPES = ["round.segment_fwd", "round.segment_recompute", "round.segment_bwd",
