@@ -4,31 +4,28 @@ The reference's PS round is host-orchestrated actor traffic — stream honest
 gradients as-completed, feed them to byzantine actors, pickle everything
 through pipes/shm, aggregate, fan the update back out
 (ref: ``byzpy/engine/parameter_server/ps.py:103-144``). On TPU that entire
-round collapses into a single compiled step over a ``Mesh``:
+round collapses into a single compiled step, which
+:func:`build_ps_train_step` picks among three (its docstring is the table):
 
-* per-node gradients: data is sharded ``P("nodes", ...)``; a ``vmap`` over
-  the node axis computes every node's gradient in parallel, each on its own
-  chip (on one device: the honest nodes' only, one after another);
+* per-node gradients: on a mesh data is sharded ``P("nodes", ...)`` and a
+  ``vmap`` over the node axis computes every node's gradient in parallel,
+  each on its own chip (:func:`_mesh_train_step`); on one device the honest
+  nodes' only, one after another (:func:`_one_device_train_step`; segment
+  by segment for a model that is a chain: :func:`_streamed_train_step`);
 * byzantine behavior: honest rows are a static slice of the stacked
   gradient matrix; the attack is a pure function of them writing the
   byzantine rows (SURVEY §7e — functional masking instead of separate
   actor code paths);
-* aggregation: the ``(n, d)`` matrix is re-laid-out feature-sharded via a
-  sharding constraint — XLA inserts the ``all_to_all`` "gradient
-  transpose" over ICI — so coordinate-wise aggregators run fully locally
-  per chip and geometric ones psum an ``(n, n)`` Gram block;
-* update: the round stays sharded end-to-end. The aggregated flat
-  gradient keeps the feature layout through ``opt.update`` /
-  ``optax.apply_updates`` — optimizer state is initialized and carried
-  feature-sharded over the same grid (per-chip opt-state HBM and update
-  flops both drop ~n×) and ONE params all-gather (optionally bf16/int8
-  via :func:`~byzpy_tpu.parallel.collectives.reshard_q`) replaces the
-  implicit f32 aggregated-gradient all-gather of a replicated update
-  ("Automatic Cross-Replica Sharding of Weight Update in Data-Parallel
-  Training", PAPERS.md). :class:`ShardedUpdateConfig` switches the
-  transform (``auto`` default: on whenever the mesh feature grid spans
-  more than one chip; ``off`` reproduces the replicated update
-  bit-for-bit).
+* aggregation: on a mesh the ``(n, d)`` matrix is re-laid-out
+  feature-sharded via a sharding constraint — XLA inserts the
+  ``all_to_all`` "gradient transpose" over ICI — so coordinate-wise
+  aggregators run fully locally per chip and geometric ones psum an
+  ``(n, n)`` Gram block;
+* update: a mesh round stays sharded end-to-end
+  (:class:`ShardedUpdateConfig`: optimizer state carried feature-sharded
+  over the same grid, ONE params all-gather in place of the aggregated
+  gradient's; "Automatic Cross-Replica Sharding of Weight Update in
+  Data-Parallel Training", PAPERS.md).
 
 No pickling, no shm, no host round-trips — the collectives ARE the
 parameter server.
@@ -182,9 +179,83 @@ def _byzantine_rows(attack, honest, key, b: int, d: int):
     return byz
 
 
+# -- what the two one-device rounds share --------------------------------------
+# Plain functions that trace into their caller: a jit around one would put its
+# name into every op_name the benchmark's readers join on.
+
+
+def _folded(shape):
+    """``shape`` as whole (8, 128) tiles in row-major order wherever its
+    size allows: ``(size / 128, 128)``. A row of a stack kept so is
+    contiguous at one index of the stack; as one row of an ``(n, width)``
+    array it is a sublane of every tile, ten times the cost to write and an
+    eighth of every vreg to compute on. (Any width the stream kernels read
+    in place is a multiple of 1024.) A boundary kept so is written by one
+    pass and read by another in one layout, with no relaid copy of the
+    whole stack between them."""
+    size = math.prod(shape)
+    return (size // 128, 128) if size % 1024 == 0 else tuple(shape)
+
+
+def _one_device_layout(n: int, tree):
+    """The row of a parameter (sub)tree on one device: as wide as the
+    stream kernels read in place, with no padded copy of the whole matrix
+    (:func:`~byzpy_tpu.ops.pallas_kernels.aligned_width`; ``d`` itself
+    wherever they will not serve an ``n``-row matrix), the ``d`` real
+    columns first and an exactly-zero tail after them. Folded wherever
+    that width is whole tiles, and then the order of its columns is the
+    round's own (:func:`~byzpy_tpu.utils.trees.row_layout`): every leaf
+    that is whole tiles has a place to itself, in the order its gradient
+    lies in memory, so that a worker's loop writes it there as it is made;
+    elsewhere the order is ``ravel_pytree``'s. The same for every row, the
+    aggregate and whatever flat state is carried."""
+    from ..ops.pallas_kernels import aligned_width
+
+    width = aligned_width(n, tree_size(tree))
+    return row_layout(tree, width, folded=len(_folded((width,))) == 2)
+
+
+def _write_row(stack, i, offsets, pieces):
+    """Row ``i`` of a stack of rows, folded or flat, written piece by piece
+    (``layout.place`` of one worker's gradient, first columns
+    ``layout.offsets``): a leaf's gradient, whole tiles of the row, is
+    written once, from where the backward pass left it, with no row-wide
+    ``concatenate`` and no relayout of a weight gradient in front."""
+    # a row's first axis counts units of `lane` columns: 128 where rows are
+    # folded, single columns where they are flat
+    lanes = stack.shape[2:]
+    lane = math.prod(lanes)
+    for first, piece in zip(offsets, pieces):
+        stack = jax.lax.dynamic_update_slice(
+            stack, jax.lax.expand_dims(piece.reshape(-1, *lanes), (0,)),
+            (i, first // lane, *(0 for _ in lanes)))
+    return stack
+
+
+def _set_byzantine_rows(stack, h: int, byz):
+    """Rows ``h..n-1`` of the loop's own ``(n, *row)`` stack, folded or
+    flat, set to what :func:`_byzantine_rows` made of its first h (the
+    attack is the caller's ``(h, width)`` function: it is handed
+    ``stack[:h].reshape(h, width)``, and a reduction over workers reads
+    folded rows all the same). A folded row is whole tiles, so b rows cost b
+    rows' bytes and the other rows are not touched."""
+    n, *row = stack.shape
+    return stack.at[h:].set(jnp.broadcast_to(byz.reshape(byz.shape[0], *row), (n - h, *row)))
+
+
+def _update_leaves(opt, layout, agg, state, params):
+    """``(params, state)`` after the optimizer's update of whole leaves:
+    ``agg``, the aggregate in the layout's order, goes back to the tree
+    first (``unravel`` reads its first ``d`` columns alone)."""
+    updates, state = opt.update(layout.unravel(agg), state, params)
+    return optax.apply_updates(params, updates), state
+
+
 def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtype, unstreamable):
     """:func:`build_ps_train_step` for a bundle that declares segments, on
-    one device: the round whose working set is not ``(n, d)``.
+    one device: the round whose working set is not ``(n, d)``, so that a
+    parameter costs 8 bytes plus n rows of one segment
+    (``docs/performance.md``, "A round that is not (n, d)").
 
     Forward for the h honest workers, one after another, keeping each
     segment's output (a block-boundary activation) for every one of them.
@@ -209,10 +280,12 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
 
     Exact where aggregate and attack treat every column alone and the
     optimizer every leaf alone: ``ops/coordinatewise.py`` is the table of
-    those, and anything it does not list is refused here.
+    those, and anything it does not list (a Gram-type aggregate, a
+    global-norm clip) or that ``unstreamable`` names as given (a
+    ``pre_aggregate``, a forced flat update) is refused here.
+    ``opt_state0`` is ``{segment: opt.init(subtree)}``.
     """
     from ..ops import coordinatewise, robust
-    from ..ops.pallas_kernels import aligned_width
 
     refused = coordinatewise.refusal(aggregate, attack, optimizer)
     refused.update({name: "given" for name, given in unstreamable.items() if given})
@@ -231,22 +304,8 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
     def put(stack, value, i):
         return jax.lax.dynamic_update_index_in_dim(stack, value.reshape(stack.shape[1:]), i, 0)
 
-    def folded(shape):
-        """A boundary is kept as whole (8, 128) tiles in row-major order
-        wherever it can be, like a row: the pass that writes it and the pass
-        that reads it then agree on one layout, and no relaid copy of the
-        whole stack stands between them."""
-        size = math.prod(shape)
-        return (size // 128, 128) if size % 1024 == 0 else tuple(shape)
-
-    # each segment's own row: the width the stream kernels read in place,
-    # folded wherever it is whole tiles, its columns in row_layout's order
-    # (all as in the (n, d) round, a segment at a time)
-    layouts = []
-    for seg in segs:
-        sub = bundle.params[seg.key]
-        width = aligned_width(n, tree_size(sub))
-        layouts.append(row_layout(sub, width, folded=len(folded((width,))) == 2))
+    # each segment's own row, as the (n, d) round's, a segment at a time
+    layouts = [_one_device_layout(n, bundle.params[seg.key]) for seg in segs]
     opt_state0 = {seg.key: opt.init(bundle.params[seg.key]) for seg in segs}
 
     def in_row_order(seg, layout):
@@ -320,7 +379,7 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                         lambda stack, value: put(stack, value, i), carry, out)
 
             with jax.named_scope("stream.boundary"):
-                stacks = [jax.lax.empty((h, *folded(leaf.shape)), leaf.dtype) for leaf in kept[0]]
+                stacks = [jax.lax.empty((h, *_folded(leaf.shape)), leaf.dtype) for leaf in kept[0]]
             vals, auxes = jax.lax.fori_loop(0, h, one_forward, (
                 stacks, jax.tree_util.tree_map(
                     lambda leaf: jnp.zeros((h, *leaf.shape), leaf.dtype), kept[1])))
@@ -353,9 +412,6 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
             with jax.named_scope("segment." + segs[k].key):
                 seg, layout = segs[k], layouts[k]
                 width = layout.width
-                row_shape = folded((width,))
-                lanes = row_shape[1:]
-                lane = math.prod(lanes)
                 reads = wires[k - 1][1] if k else []
                 # An array this segment's input holds was made by the segment
                 # before it, or handed on by it. The first is read here for the
@@ -369,8 +425,7 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                       for j, last_read in zip(reads, over)]
 
                 def one_backward(i, carry, k=k, seg=seg, layout=layout, sub=sub, at=at,
-                                 over=over, cots=tuple(cots), vals=tuple(vals),
-                                 lanes=lanes, lane=lane):
+                                 over=over, cots=tuple(cots), vals=tuple(vals)):
                     # worker i's input to this segment is read from the stacks of
                     # boundaries kept, and the cotangent of that input is written
                     # over it: after the loop the stack holds what the segment
@@ -405,15 +460,10 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                             back = jnp.ones_like(out) if k == last else boundary(k, lambda j: (
                                 carry["io"][at[j]] if j in at and not over[at[j]] else cots[j]), i)
                         pulled = pullback(back)
-                        grads = carry["rows"]
                         with jax.named_scope("stream.rows"):
-                            for first, piece in zip(
-                                    layout.offsets, layout.place(pulled[0], grad_dtype)):
-                                grads = jax.lax.dynamic_update_slice(
-                                    grads,
-                                    jax.lax.expand_dims(piece.reshape(-1, *lanes), (0,)),
-                                    (i, first // lane, *(0 for _ in lanes)))
-                        carry = dict(carry, rows=grads)
+                            carry = dict(carry, rows=_write_row(
+                                carry["rows"], i, layout.offsets,
+                                layout.place(pulled[0], grad_dtype)))
                         with jax.named_scope("stream.boundary"):
                             if k:
                                 carry["io"] = [put(stack, leaf, i) for stack, leaf in zip(
@@ -427,7 +477,7 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                     return carry
 
                 carry = {"rows": jax.lax.empty(
-                    (h if formed[k] else n, *row_shape), rows_dtypes[k])}
+                    (h if formed[k] else n, *_folded((width,))), rows_dtypes[k])}
                 if k:
                     carry["io"] = io
                 if k == last:
@@ -450,10 +500,9 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                     with jax.named_scope("round.build_matrix"):
                         stack = carry["rows"]
                         if b:
-                            byz = _byzantine_rows(attack, stack[:h].reshape(h, width),
-                                                  jax.random.fold_in(key, k), b, layout.d)
-                            stack = stack.at[h:].set(jnp.broadcast_to(
-                                byz.reshape(byz.shape[0], *row_shape), (b, *row_shape)))
+                            stack = _set_byzantine_rows(stack, h, _byzantine_rows(
+                                attack, stack[:h].reshape(h, width), jax.random.fold_in(key, k),
+                                b, layout.d))
                         matrix = stack.reshape(n, width)
                     with jax.named_scope("round.aggregate"):
                         agg = aggregate(matrix).astype(layout.dtype)
@@ -475,9 +524,7 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                             (sub, opt_state[seg.key]))
                     else:
                         sum_sq = sum_sq + jnp.sum(jnp.square(agg)).astype(jnp.float32)
-                        updates, state = opt.update(
-                            layout.unravel(agg), opt_state[seg.key], sub)
-                        done = (optax.apply_updates(sub, updates), state)
+                        done = _update_leaves(opt, layout, agg, opt_state[seg.key], sub)
                     if k:
                         # the segment before this one starts from the cotangents
                         # only once this one's leaves are updated: its rows are
@@ -501,6 +548,391 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
     return train_step, opt_state0
 
 
+def _one_device_train_step(
+        bundle, aggregate, cfg, *, attack, pre_aggregate, optimizer, grad_dtype, flat_update):
+    """:func:`build_ps_train_step` on one device, for a bundle without
+    segments: the ``(n, d_pad)`` round.
+
+    Nothing reads a byzantine worker's own gradient or loss (its row of
+    the matrix is the attack's, or an honest row echoed; ``honest_loss`` is
+    the honest mean), so forward/backward runs for the first ``h = n_nodes -
+    n_byzantine`` workers only, one after another (a ``fori_loop``;
+    ``xs[h:]``, ``ys[h:]`` are not read): under ``vmap`` the TPU compiler
+    turns each convolution's per-worker weight gradient into one grouped
+    convolution over the worker axis and relays activations out around the
+    merged-batch convolutions, at about twice the cost a worker for
+    ResNet-18 and none less for an MLP (``docs/performance.md``).
+    Signature, shapes, state, metrics and values are those of a round that
+    computes all n rows and overwrites b of them.
+
+    The loop carries the n-row stack, each row ``d_pad`` wide and folded
+    wherever that is whole tiles (:func:`_one_device_layout`,
+    :func:`_folded`: ``(n, d_pad / 128, 128)``), and writes row i of it
+    leaf by leaf (:func:`_write_row`); the byzantine rows (their tail
+    forced to zero: :func:`_byzantine_rows`) are written into rows h..n-1
+    of the same buffer (:func:`_set_byzantine_rows`), and
+    ``pre_aggregate`` / ``aggregate`` are handed ``stack.reshape(n,
+    d_pad)``. Who relays out is decided by the compiler from what that
+    function does with it: the sort family's kernel folds its argument
+    again and reads the loop's buffer; a consumer that wants the workers in
+    sublanes (Multi-Krum's Gram, any XLA sort) gets the one relayout pass
+    it needs, where it reads the matrix (``docs/performance.md``, "A
+    folded row").
+
+    The aggregate's zero tail is cut before the update of the parameter
+    tree. ``flat_update`` (a caller's ``sharded_update="on"``; there is no
+    grid to shard over here) carries ``(flat_params, inner_opt_state)``
+    over the ``d_pad``-wide flat vector in the layout's order instead, and
+    re-zeroes the tail.
+    """
+    opt = optimizer or default_optimizer(cfg)
+    grad_of = jax.value_and_grad(bundle.loss_fn)
+    n, h, b = cfg.n_nodes, cfg.n_honest, cfg.n_byzantine
+    layout = _one_device_layout(n, bundle.params)
+    d, d_pad = layout.d, layout.width
+    if flat_update:
+        flat0 = layout.ravel(bundle.params)
+        opt_state0 = (flat0, opt.init(flat0))
+    else:
+        opt_state0 = opt.init(bundle.params)
+
+    def train_step(params, opt_state, xs, ys, key):
+        # Every op lies in exactly one innermost round.* scope
+        # (observability.catalog.SCOPES): the label rides each HLO
+        # instruction's op_name metadata, and the benchmark reads
+        # per-scope device time through the compiled text (the note in
+        # build_serving_ps_step says how).
+        with jax.named_scope("round.fwdbwd"):
+            # With no byzantine worker the slices are the whole arrays and
+            # emit nothing.
+            xs_h, ys_h = xs[:h], ys[:h]
+            loss0, _ = jax.eval_shape(lambda: grad_of(params, xs_h[0], ys_h[0]))
+
+            def one_worker(i, carry):
+                losses, grads = carry
+                loss, g = grad_of(params, xs_h[i], ys_h[i])
+                pieces = layout.place(g, grad_dtype)
+                losses = jax.lax.dynamic_update_index_in_dim(losses, loss, i, 0)
+                return losses, _write_row(grads, i, layout.offsets, pieces)
+
+            # (an uninitialised buffer: every row is written, h here and b
+            # by the attack; zeros would cost a pass over it)
+            losses, stack = jax.lax.fori_loop(0, h, one_worker, (
+                jnp.zeros((h,), loss0.dtype),
+                jax.lax.empty((n, *_folded((d_pad,))),
+                              grad_dtype if grad_dtype is not None else layout.dtype)))
+        with jax.named_scope("round.build_matrix"):
+            if b:
+                stack = _set_byzantine_rows(
+                    stack, h, _byzantine_rows(attack, stack[:h].reshape(h, d_pad), key, b, d))
+            matrix = stack.reshape(n, d_pad)
+        if pre_aggregate is not None:
+            with jax.named_scope("round.pre_aggregate"):
+                matrix = pre_aggregate(matrix)
+        with jax.named_scope("round.aggregate"):
+            agg_flat = aggregate(matrix).astype(layout.dtype)
+        with jax.named_scope("round.update"):
+            if d_pad != d and flat_update:
+                # the flat state is carried d_pad wide: pin the pad tail to
+                # exactly zero so padded params and momenta never drift (and
+                # the norm matches the unpadded round)
+                agg_flat = jnp.where(jnp.arange(d_pad) < d, agg_flat, 0.0)
+            elif d_pad != d:
+                # the state mirrors the parameter tree: the tail is cut
+                agg_flat = agg_flat[:d]
+            agg_norm = jnp.sqrt(jnp.sum(jnp.square(agg_flat)))
+            if flat_update:
+                flat_params, inner = opt_state
+                updates, inner = opt.update(agg_flat, inner, flat_params)
+                new_flat = optax.apply_updates(flat_params, updates)
+                params = layout.unravel(new_flat[:d])
+                opt_state = (new_flat, inner)
+            else:
+                params, opt_state = _update_leaves(opt, layout, agg_flat, opt_state, params)
+            metrics = {"honest_loss": jnp.mean(losses), "agg_grad_norm": agg_norm}
+        return params, opt_state, metrics
+
+    return train_step, opt_state0
+
+
+def _select_byzantine_rows(matrix, h: int, byz):
+    """The ``(n, width)`` matrix of all n workers' rows with rows h..n-1
+    replaced by what :func:`_byzantine_rows` made of its first h: one
+    elementwise pass over it, in place. (Its rows are sublanes of the
+    TPU's (8, 128) tiles, so a two-row ``dynamic_update_slice`` touches
+    every tile too, as 1 KB DMA chunks, and measured slower than this pass
+    or the concatenate it replaces.) A pure function of the rows: it runs
+    node-sharded in the uncompressed fabric and feature-sharded after a
+    compressed transpose, and every attack is coordinate-wise over the node
+    axis, so both layouts partition cleanly."""
+    n = matrix.shape[0]
+    at = jnp.arange(n)[:, None]
+    if byz.shape[0] == 1:
+        return jnp.where(at >= h, byz, matrix)
+    for r in range(n - h):
+        matrix = jnp.where(at == h + r, byz[r], matrix)
+    return matrix
+
+
+def _mesh_train_step(bundle, aggregate, cfg, mesh, *, attack, pre_aggregate, optimizer,
+                     grad_dtype, comm, su):
+    """:func:`build_ps_train_step` on a mesh: the ``(n, d)`` round across
+    chips, a segmented bundle's too (its ``loss_fn`` is the chain's).
+
+    Batches are constrained to ``P("nodes", ...)`` and all n workers'
+    gradients are computed under ``vmap``: the node axis carries every
+    worker, a byzantine worker's chip runs beside the others (skipping it
+    frees no time) and h need not divide the axis. Rows are ``d`` wide
+    and flat, in ``ravel_pytree``'s order (the note at ``layout`` below has
+    the one exception), cross the wire so, and the byzantine ones are
+    selected into the matrix in one pass (:func:`_select_byzantine_rows`). The matrix then transposes to feature
+    sharding, is padded to the sharded update's grid where that is on, and
+    ``pre_aggregate`` / ``aggregate`` run chip-local per coordinate.
+
+    ``comm`` (a :class:`~byzpy_tpu.parallel.quantization.CommPrecision`)
+    compresses the gradient-transpose wire traffic — the round's dominant
+    collective at ``d >= 1e5``: the stacked gradient matrix is encoded
+    *before* the node->feature resharding constraint, so the all-to-all
+    XLA inserts moves coded bytes (int8/fp8 codes + per-block f32 scales,
+    packed s4 nibbles at half a byte per value, or bf16) instead of f32,
+    and every device decodes after the transpose. Aggregation always runs
+    on the decoded full-precision matrix. ``"off"`` produces a program
+    bit-identical to the uncompressed fabric. With ``error_feedback=True``
+    on the precision, each node's ``(n, d)`` residual rides the carried
+    state (node-sharded, donated): round ``t`` transmits ``g_t + e_{t-1}``
+    and carries ``e_t = (g_t + e_{t-1}) - decode(encode(g_t + e_{t-1}))``,
+    so the per-node transmitted stream telescopes to the true gradient
+    stream plus one round's bounded error — sub-int8 compression stops
+    compounding (the EF convergence study in
+    ``benchmarks/ef_convergence_study.py`` measures exactly this).
+
+    ``su`` (a :class:`ShardedUpdateConfig`) controls the weight update's
+    layout. When active, the flat param vector is padded to the shard
+    grid (and to the quantization block for a blockwise params gather),
+    ``opt_state0`` is ``(flat_params, inner_opt_state)`` over the padded
+    FLAT vector, carried feature-sharded — each chip owns the
+    authoritative exact shard of the flat params and of every optimizer
+    moment — and ``train_step`` applies the update per shard, all-gathers
+    only the refreshed flat params (optionally compressed), and unravels
+    once. The returned params pytree stays replicated either way, so
+    callers thread state identically.
+
+    Error feedback (of the transpose or of the gather) changes the
+    carried-state STRUCTURE: ``opt_state0`` becomes ``(base_opt_state,
+    ef_state)`` and the step returns the updated residuals in the same
+    slot — callers thread it opaquely. The aggregated-gradient norm is
+    computed shard-locally as a psum of per-shard partial sums of squares:
+    the aggregated gradient is never gathered just for the norm.
+    """
+    opt = optimizer or default_optimizer(cfg)
+    gather_p = as_comm_precision(su.param_gather_precision)
+    grad_of = jax.value_and_grad(bundle.loss_fn)
+    n, h, b = cfg.n_nodes, cfg.n_honest, cfg.n_byzantine
+    axis = node_axis(mesh)
+    # extra mesh axes join in: per-node batches shard over the FIRST
+    # extra axis (intra-node data parallelism — XLA psums the
+    # batch-mean gradient automatically), and the aggregation matrix
+    # feature-shards over ALL axes so no chip idles during the
+    # robust reduce (a 1-D mesh degenerates to the plain layout)
+    extra = tuple(
+        a for a in mesh.axis_names if a != axis and mesh.shape[a] > 1
+    )
+    node_spec = NamedSharding(mesh, P(axis, *extra[:1]))
+    feat_spec = NamedSharding(mesh, P(None, (axis, *extra)))
+    # rows of the stacked (n, d) gradient matrix live on the node axis
+    # before the transpose; pinning the encoded payload there first
+    # forces the reshard (the wire hop) to move the COMPRESSED tensor
+    # — with only the post-transpose constraint XLA may reshard the
+    # f32 input and encode/decode locally, moving full-precision bytes
+    row_spec = NamedSharding(mesh, P(axis))
+    # The flat (d,) layout matching the aggregation matrix's feature
+    # columns: a (d,) vector sharded over (axis, *extra) lines up
+    # coordinate-for-coordinate with the feature-sharded (n, d) matrix, so
+    # opt.update consumes the aggregate with NO reshard at all. The norm
+    # metric reduces over it shard-locally in both update modes, and the
+    # sharded update carries state in it.
+    flat_sharding = NamedSharding(mesh, P((axis, *extra)))
+    repl_sharding = NamedSharding(mesh, P())
+    feat_shards = math.prod(mesh.shape[a] for a in (axis, *extra))
+    su_on = su.resolve(feat_shards)
+
+    d = tree_size(bundle.params)
+    # The rows cross the wire d wide; after the transpose the matrix, the
+    # aggregate and the carried flat state have d_pad columns, of which
+    # the last d_pad - d are exactly zero (the tail is re-zeroed after the
+    # aggregate regardless).
+    d_pad = d
+    if su_on and feat_shards > 1:
+        # the shard grid, so every chip owns an equal slice; blockwise
+        # gathers (int8/fp8/s4) pad to the quantization block too, so no
+        # shard ever splits a block (scales shard alongside the codes,
+        # and the packed s4 payload's half-length stays grid-divisible)
+        pad_grid = feat_shards * (gather_p.block if gather_p.blockwise else 1)
+        d_pad = -(-d // pad_grid) * pad_grid
+    # (a d that is whole tiles takes row_layout's tile order here too, as
+    # it did when one builder served every round: ROADMAP.md, D18)
+    layout = row_layout(bundle.params, d, folded=d % 1024 == 0)
+    param_dtype = layout.dtype
+
+    if su_on:
+        # optax init builds state via zeros_like, so every (d_pad,) moment
+        # is BORN sharded like the flat params — nothing replicated to
+        # re-slice later; scalar leaves (e.g. Adam's count) stay tiny.
+        # The carried state leads with each chip's authoritative flat
+        # param shard: re-deriving it from ravel(params) per round would
+        # be free in principle (a local slice of the replicated pytree),
+        # but GSPMD partitions the ravel concat into a d-size all-reduce
+        # however the pytree/flat constraints are pinned — one extra
+        # d_pad/g buffer per chip buys a clean single-gather program AND
+        # makes a lossy params gather safe (the exact shard never passes
+        # through the compressed wire).
+        flat_padded0 = jax.device_put(
+            jnp.pad(layout.ravel(bundle.params), (0, d_pad - d)), flat_sharding)
+        opt_state0 = (flat_padded0, opt.init(flat_padded0))
+    else:
+        opt_state0 = opt.init(bundle.params)
+
+    # The EF residuals are ROUND STATE: they live beside the optimizer
+    # state (donated with it, feature-/node-sharded like the tensors
+    # they compensate) and change the carried-state structure only when
+    # EF is actually on — the default round's opt_state is untouched.
+    ef_transpose = comm.enabled and comm.error_feedback
+    ef_gather = su_on and gather_p.enabled and gather_p.error_feedback
+    ef0 = {}
+    if ef_transpose:
+        ef0["transpose"] = jax.device_put(
+            jnp.zeros((n, d), grad_dtype if grad_dtype is not None else param_dtype), row_spec)
+    if ef_gather:
+        ef0["gather"] = jax.device_put(jnp.zeros((d_pad,), param_dtype), flat_sharding)
+    if ef0:
+        opt_state0 = (opt_state0, ef0)
+
+    def train_step(params, opt_state, xs, ys, key):
+        # (scopes: as in the one-device round)
+        ef_state = {}
+        if ef0:
+            opt_state, ef_state = opt_state
+        with jax.named_scope("round.fwdbwd"):
+            xs = jax.lax.with_sharding_constraint(xs, node_spec)
+            ys = jax.lax.with_sharding_constraint(ys, node_spec)
+
+            # Every node's forward/backward runs in parallel across
+            # the mesh: vmap over the node axis of node-sharded data
+            # with replicated params.
+            def per_node_row(params, x, y):
+                loss, g = grad_of(params, x, y)
+                return loss, layout.ravel(g, grad_dtype)
+
+            losses, grads = jax.vmap(per_node_row, in_axes=(None, 0, 0))(
+                params, xs, ys
+            )
+        if comm.enabled:
+            # Compressed fabric: every node's RAW gradient row crosses the
+            # wire encoded (exactly what a deployment ships — byzantine
+            # nodes transmit too), and the attack/masking runs on the
+            # decoded, feature-sharded rows: the omniscient adversary sees
+            # the wire view of the honest gradients. The encoded payload
+            # is pinned to the node layout and re-pinned to the feature
+            # layout (the reshard between the two constraints IS the wire
+            # hop), and the decoded matrix is constrained too, else the
+            # partitioner replicates the aggregation input with an (n, d)
+            # f32 all-reduce that dwarfs the transpose.
+            with jax.named_scope("round.transpose"):
+                if ef_transpose:
+                    # EF: the wire carries g + e, the new residual stays
+                    # node-sharded beside the optimizer state
+                    grads, new_tr = reshard_q_ef(
+                        grads, ef_state["transpose"], row_spec, feat_spec,
+                        precision=comm,
+                    )
+                    ef_state = {**ef_state, "transpose": new_tr}
+                else:
+                    grads = reshard_q(grads, row_spec, feat_spec, precision=comm)
+        with jax.named_scope("round.build_matrix"):
+            matrix = grads
+            if b:
+                matrix = _select_byzantine_rows(
+                    grads, h, _byzantine_rows(attack, grads[:h], key, b, d))
+        # Gradient transpose: node-sharded rows -> feature-sharded
+        # columns (XLA lowers this constraint to an all_to_all over
+        # ICI), so the robust aggregation below is chip-local per
+        # coordinate.
+        with jax.named_scope("round.transpose"):
+            matrix = jax.lax.with_sharding_constraint(matrix, feat_spec)
+        if d_pad != d:
+            # zero-pad the feature axis to the shard grid BEFORE the
+            # robust reduce
+            with jax.named_scope("round.build_matrix"):
+                matrix = jnp.pad(matrix, ((0, 0), (0, d_pad - d)))
+                matrix = jax.lax.with_sharding_constraint(matrix, feat_spec)
+        if pre_aggregate is not None:
+            with jax.named_scope("round.pre_aggregate"):
+                matrix = pre_aggregate(matrix)
+        with jax.named_scope("round.aggregate"):
+            agg_flat = aggregate(matrix).astype(param_dtype)
+            agg_flat = jax.lax.with_sharding_constraint(agg_flat, flat_sharding)
+        with jax.named_scope("round.update"):
+            if d_pad != d:
+                # the flat state is carried d_pad wide: pin the pad tail to
+                # exactly zero so padded params/momenta never drift (and
+                # the norm below matches the unpadded round)
+                agg_flat = jnp.where(jnp.arange(d_pad) < d, agg_flat, 0.0)
+                agg_flat = jax.lax.with_sharding_constraint(agg_flat, flat_sharding)
+            # shard-local norm: per-shard partial sums of squares + a scalar
+            # psum — the aggregated gradient is never gathered for a metric
+            agg_norm = jnp.sqrt(jnp.sum(jnp.square(agg_flat)))
+            if su_on:
+                flat_params, inner = opt_state
+                flat_params = jax.lax.with_sharding_constraint(flat_params, flat_sharding)
+                updates, inner = opt.update(agg_flat, inner, flat_params)
+                new_flat = optax.apply_updates(flat_params, updates)
+                new_flat = jax.lax.with_sharding_constraint(new_flat, flat_sharding)
+                inner = jax.tree_util.tree_map(
+                    lambda leaf: jax.lax.with_sharding_constraint(
+                        leaf, flat_sharding
+                    )
+                    if getattr(leaf, "shape", None) == (d_pad,)
+                    else leaf,
+                    inner,
+                )
+                # The sharded round's ONE parameter collective: all-gather
+                # the refreshed flat params from the feature shards back to
+                # every chip (optionally compressed on the wire — the exact
+                # shard each chip owns stays in the carried opt state, so
+                # gather loss never compounds across rounds; with EF the
+                # gather residual rides ``ef_state`` and dithers the replica
+                # error around zero).
+                with jax.named_scope("round.param_gather"):
+                    if ef_gather:
+                        gathered, new_r = reshard_q_ef(
+                            new_flat, ef_state["gather"], flat_sharding, repl_sharding,
+                            precision=gather_p,
+                        )
+                        ef_state = {**ef_state, "gather": new_r}
+                    else:
+                        gathered = reshard_q(
+                            new_flat, flat_sharding, repl_sharding, precision=gather_p)
+                params = layout.unravel(gathered[:d])
+                opt_state = (new_flat, inner)
+            else:
+                params, opt_state = _update_leaves(opt, layout, agg_flat, opt_state, params)
+            metrics = {
+                "honest_loss": jnp.mean(losses[:h]),
+                "agg_grad_norm": agg_norm,
+            }
+            # shard-local residual-energy metrics (the convergence study
+            # watches these stay bounded — a drifting residual is the
+            # "EF compounding" failure mode)
+            for name in ef0:
+                metrics[f"ef_{name}_norm"] = jnp.sqrt(
+                    jnp.sum(jnp.square(ef_state[name].astype(jnp.float32))))
+            if ef0:
+                opt_state = (opt_state, ef_state)
+        return params, opt_state, metrics
+
+    return train_step, opt_state0
+
+
 def build_ps_train_step(
     bundle: ModelBundle,
     aggregate: AggFn,
@@ -514,524 +946,78 @@ def build_ps_train_step(
     comm_precision: Any = None,
     sharded_update: Any = None,
 ) -> Tuple[Callable, Any]:
-    """Build ``(train_step, opt_state0)``.
+    """Build ``(train_step, opt_state0)``: decide which round a call gets.
 
-    Which program a call gets. (1) ``bundle.segments`` declared and no
-    mesh (one device): the STREAMED round
-    (:func:`_streamed_train_step`): gradients, attack, aggregate and
-    update one segment at a time, so no ``(n, d)`` array exists and a
-    parameter costs 8 bytes plus n rows of one segment; exact for what
-    ``ops/coordinatewise.py`` lists, and anything else (a Gram-type
-    aggregate, a ``pre_aggregate``, a global-norm clip, a forced sharded
-    update) raises a ``ValueError`` that names the table. Its
-    ``opt_state0`` is ``{segment: opt.init(subtree)}``. (2) Everything
-    else, a segmented bundle on a mesh included: the ``(n, d)`` round
-    described below, unchanged by (1)
-    (``docs/performance.md``, "A round that is not (n, d)").
+    ==========================  ==========  =================================
+    ``bundle``                  ``mesh``    the round
+    ==========================  ==========  =================================
+    declares ``segments``       none        :func:`_streamed_train_step`
+    no segments                 none        :func:`_one_device_train_step`
+    either                      given       :func:`_mesh_train_step`
+    ==========================  ==========  =================================
+
+    ``mesh=None`` means the default mesh (``configs.mesh``), and one
+    device where none is set. Each round's docstring says what its program
+    is; what a caller has to know is here.
 
     ``train_step(params, opt_state, xs, ys, key)`` expects per-node batches
     stacked on a leading node axis: ``xs: (n_nodes, B, ...)``,
-    ``ys: (n_nodes, B)``. With ``mesh`` given, batches are constrained to
-    ``P("nodes", ...)`` and the gradient matrix transposes to feature
-    sharding before aggregation; without a mesh it is the same program on
-    one device.
+    ``ys: (n_nodes, B)``. It returns ``(params, opt_state, metrics)``,
+    metrics with the mean honest loss (``honest_loss``), the
+    aggregated-gradient norm (``agg_grad_norm``) and, from the streamed
+    round, what its segments report (``segment_aux``).
 
+    ``pre_aggregate``, ``aggregate`` and ``attack`` see ``(·, d_pad)``
+    matrices whose columns are the parameters in an order the round fixes
+    (the same for every row and for the aggregate), the ``d`` real columns
+    first and an exactly-zero tail after them; the streamed round hands
+    them one segment's columns at a time. They must map all-zero columns
+    to zero and give the other columns what they would give from the
+    ``(n, d)`` matrix with its columns in the same order: true of anything
+    that works coordinate by coordinate or reads rows through norms and
+    inner products, so of every shipped aggregator, pre-aggregator and
+    attack (``docs/performance.md``, "The contract an aggregator or
+    pre-aggregator meets"). The streamed round is exact only for what
+    ``ops/coordinatewise.py`` lists, and raises a ``ValueError`` that
+    names that table for anything else (a Gram-type aggregate, a
+    ``pre_aggregate``, a global-norm clip, ``sharded_update="on"``).
+
+    ``opt_state0``, which callers thread opaquely: the streamed round's is
+    ``{segment: opt.init(subtree)}``; the other two rounds' is
+    ``opt.init(params)``, or ``(flat_params, opt.init(flat_params))`` over
+    the padded flat vector where the sharded update is on
+    (``sharded_update``: a :class:`ShardedUpdateConfig`, a mode string, a
+    bool, or ``None`` = auto: on where the mesh's feature grid spans more
+    than one chip; on one device only a caller's ``"on"`` turns it on).
     ``comm_precision`` (``"off"``/``"bf16"``/``"int8"``/``"fp8"``/
     ``"fp8_e5m2"``/``"s4"`` or a
     :class:`~byzpy_tpu.parallel.quantization.CommPrecision`) compresses
-    the gradient-transpose wire traffic — the round's dominant collective
-    at ``d >= 1e5``: the stacked gradient matrix is encoded *before* the
-    node->feature resharding constraint, so the all-to-all XLA inserts
-    moves coded bytes (int8/fp8 codes + per-block f32 scales, packed s4
-    nibbles at half a byte per value, or bf16) instead of f32, and
-    every device decodes after the transpose. Aggregation always runs on
-    the decoded full-precision matrix. The default ``"off"`` produces a
-    program bit-identical to the uncompressed fabric. With
-    ``error_feedback=True`` on the precision, each node's ``(n, d)``
-    residual rides the carried state (node-sharded, donated): round
-    ``t`` transmits ``g_t + e_{t-1}`` and carries
-    ``e_t = (g_t + e_{t-1}) - decode(encode(g_t + e_{t-1}))``, so the
-    per-node transmitted stream telescopes to the true gradient stream
-    plus one round's bounded error — sub-int8 compression stops
-    compounding (the EF convergence study in
-    ``benchmarks/ef_convergence_study.py`` measures exactly this).
-    Error feedback changes the carried-state STRUCTURE: ``opt_state0``
-    becomes ``(base_opt_state, ef_state)`` and the step returns the
-    updated residuals in the same slot — callers thread it opaquely.
-
-    ``sharded_update`` (:class:`ShardedUpdateConfig`, a mode string, a
-    bool, or ``None`` = auto) controls the weight update's layout. When
-    active, the flat param vector is padded to the shard grid (and to
-    the quantization block for an int8 params gather), ``opt_state0`` is
-    ``(flat_params, inner_opt_state)`` over the padded FLAT vector,
-    carried feature-sharded — each chip owns the authoritative exact
-    shard of the flat params and of every optimizer moment — and
-    ``train_step`` applies the update per shard, all-gathers only the
-    refreshed flat params (optionally compressed), and unravels once.
-    The returned params pytree stays replicated either way, so callers
-    thread state identically.
-
-    Which workers' gradients are computed, and how: nothing reads a
-    byzantine worker's own gradient or loss (its row of the matrix is
-    the attack's, or an honest row echoed; ``honest_loss`` is the honest
-    mean). On one device (no mesh) the step therefore runs
-    forward/backward for the first ``h = n_nodes - n_byzantine`` workers
-    only, one after another (a ``fori_loop``; ``xs[h:]``, ``ys[h:]`` are
-    not read): on the TPU a convolutional model's per-worker gradients cost
-    about half as much one worker at a time as vmapped, and no more for
-    an MLP (``docs/performance.md``). Signature, shapes, state, metrics
-    and values are those of a round that computes all n rows and
-    overwrites b of them. On a mesh all n are computed under ``vmap``:
-    the node axis carries every worker, a byzantine worker's chip runs
-    beside the others (skipping it frees no time) and h need not divide
-    the axis.
-
-    The gradient matrix is ``d_pad`` columns wide with an exactly-zero
-    tail: on one device ``d_pad`` is the width the Pallas stream
-    kernels read in place
-    (:func:`~byzpy_tpu.ops.pallas_kernels.aligned_width`; ``d`` wherever
-    they will not serve the matrix) and each computed row is made at
-    that width; on a mesh it is the sharded update's grid.
-
-    On one device a row is kept FOLDED wherever ``d_pad % 1024 == 0``:
-    shaped ``(d_pad / 128, 128)``, whole (8, 128) TPU tiles, and not one
-    sublane of every tile of an ``(n, d_pad)`` matrix. The loop carries
-    the ``(n, d_pad / 128, 128)`` stack and writes row i of it LEAF BY
-    LEAF (:func:`~byzpy_tpu.utils.trees.row_layout`): every leaf whose
-    size is a multiple of 1024 has whole tiles of the row to itself and
-    its gradient is written there once, in the order it lies in memory
-    (a leaf ``(..., C)`` with ``C`` a multiple of 128 above 128: (8, 128)
-    tile after tile; else row-major), and the other leaves follow,
-    ravelled together with the zero tail. No row-wide ``concatenate`` and
-    no relayout of a weight gradient stands in front of the write. So
-    the ORDER OF THE COLUMNS of a folded row is the round's own: fixed by
-    the parameter tree and ``d_pad``, the same for every row, the
-    aggregate and (sharded update) the carried flat state, the ``d`` real
-    columns first and the zero tail last, and NOT ``ravel_pytree``'s.
-    Where rows are not folded (a CPU, a mesh, ``d`` under the kernels'
-    floor) no leaf is placed on its own and the order is
-    ``ravel_pytree``'s. The byzantine rows (their tail forced to zero)
-    are written into rows h..n-1 of the same buffer, and
-    ``pre_aggregate`` / ``aggregate`` are handed
-    ``stack.reshape(n, d_pad)``. Who relays out is decided by the
-    compiler from what that function does with it: the sort family's
-    kernel folds its argument again and reads the loop's buffer; a
-    consumer that wants the workers in sublanes (Multi-Krum's Gram, any
-    XLA sort) gets the one relayout pass it needs, where it reads the
-    matrix (``docs/performance.md``, "A folded row"). On a mesh the
-    ``(n, d)`` stack has all n rows and the byzantine ones are selected
-    into it in one pass.
-
-    ``pre_aggregate``, ``aggregate`` and ``attack`` see ``(·, d_pad)``
-    matrices whose columns are the parameters in that fixed order. They
-    must map all-zero columns to zero and give the other columns what
-    they would give from the ``(n, d)`` matrix with its columns in the
-    same order: true of anything that works coordinate by coordinate or
-    reads rows through norms and inner products, so of every shipped
-    aggregator, pre-aggregator and attack (``docs/performance.md``,
-    "The contract an aggregator or pre-aggregator meets"). The
-    aggregate's tail is cut or re-zeroed before the update, which maps
-    the columns back to the tree.
-
-    Returns ``(params, opt_state, metrics)`` where metrics carries the mean
-    honest loss and the aggregated-gradient norm (computed shard-locally
-    as a psum of per-shard partial sums of squares — the aggregated
-    gradient is never gathered just for the norm).
+    the mesh round's gradient transpose; one device has no wire and
+    ignores it. With error feedback on either precision the mesh round's
+    state is ``(that, ef_state)``.
     """
-    opt = optimizer or default_optimizer(cfg)
     comm = as_comm_precision(comm_precision)
     su = as_sharded_update(sharded_update)
-    gather_p = as_comm_precision(su.param_gather_precision)
-    grad_of = jax.value_and_grad(bundle.loss_fn)
-    h, b = cfg.n_honest, cfg.n_byzantine
-    if not 0 <= b < cfg.n_nodes:
-        raise ValueError(f"need 0 <= n_byzantine < n_nodes (got {b}/{cfg.n_nodes})")
-
+    if not 0 <= cfg.n_byzantine < cfg.n_nodes:
+        raise ValueError(
+            f"need 0 <= n_byzantine < n_nodes (got {cfg.n_byzantine}/{cfg.n_nodes})")
     if mesh is None:
         from ..configs.mesh import get_default_mesh
 
         mesh = get_default_mesh()
-    if bundle.segments is not None and mesh is None:
+    if mesh is not None:
+        return _mesh_train_step(
+            bundle, aggregate, cfg, mesh, attack=attack, pre_aggregate=pre_aggregate,
+            optimizer=optimizer, grad_dtype=grad_dtype, comm=comm, su=su)
+    forced_flat = su.resolve(1)  # no grid here: only a caller's "on"
+    if bundle.segments is not None:
         # one device and a model that is a chain: segment by segment
         return _streamed_train_step(
             bundle, aggregate, cfg, attack=attack, optimizer=optimizer, grad_dtype=grad_dtype,
-            unstreamable={"pre_aggregate": pre_aggregate is not None, "sharded_update": su.mode == "on"},
-        )
-    node_spec = None
-    feat_spec = None
-    if mesh is not None:
-        axis = node_axis(mesh)
-        # extra mesh axes join in: per-node batches shard over the FIRST
-        # extra axis (intra-node data parallelism — XLA psums the
-        # batch-mean gradient automatically), and the aggregation matrix
-        # feature-shards over ALL axes so no chip idles during the
-        # robust reduce (a 1-D mesh degenerates to the plain layout)
-        extra = tuple(
-            a for a in mesh.axis_names if a != axis and mesh.shape[a] > 1
-        )
-        node_spec = NamedSharding(mesh, P(axis, *extra[:1]))
-        feat_spec = NamedSharding(mesh, P(None, (axis, *extra)))
-        # rows of the stacked (n, d) gradient matrix live on the node axis
-        # before the transpose; pinning the encoded payload there first
-        # forces the reshard (the wire hop) to move the COMPRESSED tensor
-        # — with only the post-transpose constraint XLA may reshard the
-        # f32 input and encode/decode locally, moving full-precision bytes
-        row_spec = NamedSharding(mesh, P(axis))
-        feat_shards = mesh.shape[axis]
-        for a in extra:
-            feat_shards *= mesh.shape[a]
-
-    d = tree_size(bundle.params)
-
-    # -- sharded weight update setup -------------------------------------
-    # The flat layouts reuse the aggregation grid: a (d,) vector sharded
-    # over (axis, *extra) lines up coordinate-for-coordinate with the
-    # feature-sharded (n, d) aggregation matrix, so opt.update consumes
-    # the aggregate with NO reshard at all.
-    su_on = su.resolve(feat_shards if mesh is not None else 1)
-    flat_sharding = repl_sharding = None
-    if mesh is not None:
-        # the flat (d,) layout matching the aggregation matrix's feature
-        # columns — the norm metric reduces over it shard-locally in both
-        # update modes, and the sharded update carries state in it
-        flat_sharding = NamedSharding(mesh, P((axis, *extra)))
-        repl_sharding = NamedSharding(mesh, P())
-    # ONE padded width for the round: the (n, d_pad) gradient matrix, the
-    # aggregate and, under the sharded update, the carried flat state all
-    # have d_pad columns, of which the last d_pad - d are exactly zero
-    # (the contract in the docstring; the tail is re-zeroed or cut after
-    # the aggregate regardless).
-    d_pad = d
-    if mesh is None:
-        # the width the stream kernels read without a padded copy of the
-        # whole matrix (d itself wherever the kernels will not serve it)
-        from ..ops.pallas_kernels import aligned_width
-
-        d_pad = aligned_width(cfg.n_nodes, d)
-    elif su_on and feat_shards > 1:
-        # the shard grid, so every chip owns an equal slice; blockwise
-        # gathers (int8/fp8/s4) pad to the quantization block too, so no
-        # shard ever splits a block (scales shard alongside the codes,
-        # and the packed s4 payload's half-length stays grid-divisible)
-        pad_grid = feat_shards * (gather_p.block if gather_p.blockwise else 1)
-        d_pad = -(-d // pad_grid) * pad_grid
-    # Where no transpose stands between the rows and the aggregate, each
-    # row is born d_pad wide and the matrix is never rebuilt; on a mesh
-    # the rows cross the wire d wide and are padded after the transpose.
-    row_width = d_pad if mesh is None else d
-    # On one device a row is FOLDED wherever it can be: (row_width / 128,
-    # 128), whole (8, 128) tiles, contiguous at one index of the stack. As
-    # one row of an (n, row_width) array it is a sublane of every tile:
-    # ten times the cost to write, and an eighth of every vreg to compute
-    # on. (Any width the stream kernels read in place is a multiple of
-    # 1024.)
-    row_shape = (row_width // 128, 128) if row_width % 1024 == 0 else (row_width,)
-    # The order of a row's columns is the round's own, the same for every
-    # row, the aggregate and (sharded update) the carried flat state.
-    # Folded, each leaf that is whole tiles has its own place in the row,
-    # in the order its gradient lies in memory, so that the loop below
-    # writes it there as it is made; elsewhere the order is ravel_pytree's.
-    # The real columns are the first d either way, the zero tail the rest.
-    layout = row_layout(bundle.params, row_width, folded=len(row_shape) == 2)
-    param_dtype = layout.dtype
-
-    if su_on:
-        flat_padded0 = jnp.pad(layout.ravel(bundle.params), (0, d_pad - row_width))
-        if flat_sharding is not None:
-            flat_padded0 = jax.device_put(flat_padded0, flat_sharding)
-        # optax init builds state via zeros_like, so every (d_pad,) moment
-        # is BORN sharded like the flat params — nothing replicated to
-        # re-slice later; scalar leaves (e.g. Adam's count) stay tiny.
-        # The carried state leads with each chip's authoritative flat
-        # param shard: re-deriving it from ravel(params) per round would
-        # be free in principle (a local slice of the replicated pytree),
-        # but GSPMD partitions the ravel concat into a d-size all-reduce
-        # however the pytree/flat constraints are pinned — one extra
-        # d_pad/g buffer per chip buys a clean single-gather program AND
-        # makes a lossy params gather safe (the exact shard never passes
-        # through the compressed wire).
-        opt_state0 = (flat_padded0, opt.init(flat_padded0))
-    else:
-        opt_state0 = opt.init(bundle.params)
-
-    # -- error-feedback residual state ------------------------------------
-    # The EF residuals are ROUND STATE: they live beside the optimizer
-    # state (donated with it, feature-/node-sharded like the tensors
-    # they compensate) and change the carried-state structure only when
-    # EF is actually on — the default round's opt_state is untouched.
-    grad_res_dtype = grad_dtype if grad_dtype is not None else param_dtype
-    ef_transpose = mesh is not None and comm.enabled and comm.error_feedback
-    ef_gather = (
-        su_on
-        and flat_sharding is not None
-        and gather_p.enabled
-        and gather_p.error_feedback
-    )
-    ef0 = {}
-    if ef_transpose:
-        ef0["transpose"] = jax.device_put(
-            jnp.zeros((cfg.n_nodes, d), grad_res_dtype), row_spec
-        )
-    if ef_gather:
-        ef0["gather"] = jax.device_put(
-            jnp.zeros((d_pad,), param_dtype), flat_sharding
-        )
-    has_ef = bool(ef0)
-    if has_ef:
-        opt_state0 = (opt_state0, ef0)
-
-    def build_matrix(grads_n, key):
-        """Honest rows + byzantine rows from the per-node gradient stack,
-        whose first h rows are the honest workers'. On a mesh the stack
-        is ``(n, width)`` and all n rows are computed (pure function of
-        the rows — runs node-sharded in the uncompressed fabric,
-        feature-sharded after a compressed transpose; all attacks are
-        coordinate-wise over the node axis, so both layouts partition
-        cleanly). On one device it is the loop's ``(n, *row_shape)``
-        buffer with rows h..n-1 still to write, and it is returned in
-        that shape."""
-        if not b:
-            return grads_n
-        honest = grads_n[:h]
-        if mesh is None:
-            # the attack is the caller's (h, width) function; a reduction
-            # over workers reads the folded rows all the same
-            honest = honest.reshape(h, row_width)
-        byz = _byzantine_rows(attack, honest, key, b, d)
-        rows_given, width = byz.shape
-        if mesh is None:
-            # Row writes into the loop's own buffer: a folded row is whole
-            # tiles, so b rows cost b rows' bytes and the other rows are
-            # not touched.
-            byz = jnp.broadcast_to(byz.reshape(rows_given, *row_shape), (b, *row_shape))
-            return grads_n.at[h:].set(byz)
-        # The byzantine rows are selected into the (n, width) matrix: one
-        # elementwise pass over it, in place. (Its rows are sublanes of the
-        # TPU's (8, 128) tiles, so a two-row dynamic-update-slice touches
-        # every tile too, as 1 KB DMA chunks, and measured slower than this
-        # pass or the concatenate it replaces.)
-        at = jnp.arange(cfg.n_nodes)[:, None]
-        if rows_given == 1:
-            return jnp.where(at >= h, byz, grads_n)
-        for r in range(b):
-            grads_n = jnp.where(at == h + r, byz[r], grads_n)
-        return grads_n
-
-    def transpose_compressed(grads_n):
-        """Encoded gradient transpose: pin the encoded payload to the node
-        layout, re-pin it to the feature layout (the reshard between the
-        two constraints IS the wire hop — so the all-to-all moves coded
-        bytes), and decode feature-sharded. The decoded matrix is
-        constrained too, else the partitioner replicates the aggregation
-        input with an (n, d) f32 all-reduce that dwarfs the transpose.
-        (One call into :func:`~byzpy_tpu.parallel.collectives.reshard_q`,
-        the fabric-wide compressed-reshard primitive.)"""
-        return reshard_q(grads_n, row_spec, feat_spec, precision=comm)
-
-    def gather_flat_params(new_flat, ef_state):
-        """The sharded round's ONE parameter collective: all-gather the
-        refreshed flat params from the feature shards back to every chip
-        (optionally compressed on the wire — the exact shard each chip
-        owns stays in the carried opt state, so gather loss never
-        compounds across rounds; with EF the gather residual rides
-        ``ef_state`` and dithers the replica error around zero)."""
-        if flat_sharding is None:
-            return new_flat, ef_state
-        if ef_gather:
-            gathered, new_r = reshard_q_ef(
-                new_flat, ef_state["gather"], flat_sharding, repl_sharding,
-                precision=gather_p,
-            )
-            return gathered, {**ef_state, "gather": new_r}
-        return (
-            reshard_q(new_flat, flat_sharding, repl_sharding, precision=gather_p),
-            ef_state,
-        )
-
-    def train_step(params, opt_state, xs, ys, key):
-        # Every op lies in exactly one innermost round.* scope
-        # (observability.catalog.SCOPES): the label rides each HLO
-        # instruction's op_name metadata, and the benchmark reads
-        # per-scope device time through the compiled text (the note in
-        # build_serving_ps_step says how).
-        ef_state = {}
-        if has_ef:
-            opt_state, ef_state = opt_state
-        with jax.named_scope("round.fwdbwd"):
-            if node_spec is not None:
-                xs = jax.lax.with_sharding_constraint(xs, node_spec)
-                ys = jax.lax.with_sharding_constraint(ys, node_spec)
-            if mesh is None:
-                # One device: nothing reads a byzantine worker's own
-                # gradient or loss (its row of the matrix is the attack's),
-                # so only the h honest workers run, and one after another:
-                # under vmap the TPU compiler turns each convolution's
-                # per-worker weight gradient into one grouped convolution
-                # over the worker axis and relays activations out around
-                # the merged-batch convolutions, at about twice the cost a
-                # worker for ResNet-18 and none less for an MLP
-                # (docs/performance.md). The loop carries the n-row stack
-                # and writes row i (what lax.map does with h rows): the
-                # byzantine rows are written into the same buffer after it.
-                # With no byzantine worker the slices are the whole arrays
-                # and emit nothing.
-                xs_h, ys_h = xs[:h], ys[:h]
-                loss0, _ = jax.eval_shape(lambda: grad_of(params, xs_h[0], ys_h[0]))
-                # a row's first axis counts units of `lane` columns: 128
-                # where rows are folded, single columns where they are flat
-                lanes = row_shape[1:]
-                lane = math.prod(lanes)
-
-                def one_worker(i, carry):
-                    losses, grads = carry
-                    loss, g = grad_of(params, xs_h[i], ys_h[i])
-                    pieces = layout.place(g, grad_dtype)
-                    losses = jax.lax.dynamic_update_index_in_dim(losses, loss, i, 0)
-                    # each piece of the row goes where the layout has it:
-                    # a leaf's gradient, whole tiles of row i, is written
-                    # once, from where the backward pass left it
-                    for first, piece in zip(layout.offsets, pieces):
-                        grads = jax.lax.dynamic_update_slice(
-                            grads, jax.lax.expand_dims(piece.reshape(-1, *lanes), (0,)),
-                            (i, first // lane, *(0 for _ in lanes)))
-                    return losses, grads
-
-                # (an uninitialised buffer: every row is written, h here
-                # and b by the attack; zeros would cost a pass over it)
-                losses, grads = jax.lax.fori_loop(0, h, one_worker, (
-                    jnp.zeros((h,), loss0.dtype),
-                    jax.lax.empty((cfg.n_nodes, *row_shape), grad_res_dtype)))
-            else:
-                # Every node's forward/backward runs in parallel across
-                # the mesh: vmap over the node axis of node-sharded data
-                # with replicated params.
-                def per_node_row(params, x, y):
-                    loss, g = grad_of(params, x, y)
-                    return loss, layout.ravel(g, grad_dtype)
-
-                losses, grads = jax.vmap(per_node_row, in_axes=(None, 0, 0))(
-                    params, xs, ys
-                )
-        if feat_spec is not None and comm.enabled:
-            # Compressed fabric: every node's RAW gradient row crosses the
-            # wire encoded (exactly what a deployment ships — byzantine
-            # nodes transmit too), and the attack/masking runs on the
-            # decoded, feature-sharded rows: the omniscient adversary sees
-            # the wire view of the honest gradients.
-            with jax.named_scope("round.transpose"):
-                if ef_transpose:
-                    # EF: the wire carries g + e, the new residual stays
-                    # node-sharded beside the optimizer state
-                    decoded, new_tr = reshard_q_ef(
-                        grads, ef_state["transpose"], row_spec, feat_spec,
-                        precision=comm,
-                    )
-                    ef_state = {**ef_state, "transpose": new_tr}
-                else:
-                    decoded = transpose_compressed(grads)
-            with jax.named_scope("round.build_matrix"):
-                matrix = build_matrix(decoded, key)
-            with jax.named_scope("round.transpose"):
-                matrix = jax.lax.with_sharding_constraint(matrix, feat_spec)
-        else:
-            with jax.named_scope("round.build_matrix"):
-                matrix = build_matrix(grads, key)
-                if mesh is None:
-                    # The aggregate is the caller's (n, d_pad) function. One
-                    # that folds its rows again (the sort family's kernel)
-                    # cancels this reshape and reads the loop's buffer; one
-                    # that wants workers in sublanes (a Gram, any XLA
-                    # route) makes the compiler emit the one relayout here.
-                    matrix = matrix.reshape(cfg.n_nodes, row_width)
-            if feat_spec is not None:
-                # Gradient transpose: node-sharded rows -> feature-sharded
-                # columns (XLA lowers this constraint to an all_to_all over
-                # ICI), so the robust aggregation below is chip-local per
-                # coordinate.
-                with jax.named_scope("round.transpose"):
-                    matrix = jax.lax.with_sharding_constraint(matrix, feat_spec)
-        if d_pad != row_width:
-            # zero-pad the feature axis to the shard grid BEFORE the
-            # robust reduce
-            with jax.named_scope("round.build_matrix"):
-                matrix = jnp.pad(matrix, ((0, 0), (0, d_pad - d)))
-                if feat_spec is not None:
-                    matrix = jax.lax.with_sharding_constraint(matrix, feat_spec)
-        if pre_aggregate is not None:
-            with jax.named_scope("round.pre_aggregate"):
-                matrix = pre_aggregate(matrix)
-        with jax.named_scope("round.aggregate"):
-            agg_flat = aggregate(matrix).astype(param_dtype)
-            if flat_sharding is not None:
-                agg_flat = jax.lax.with_sharding_constraint(
-                    agg_flat, flat_sharding
-                )
-        with jax.named_scope("round.update"):
-            if d_pad != d and not su_on:
-                # the state mirrors the parameter tree: the tail is cut
-                agg_flat = agg_flat[:d]
-            elif d_pad != d:
-                # the flat state is carried d_pad wide: pin the pad tail to
-                # exactly zero so padded params/momenta never drift (and
-                # the norm below matches the unpadded round)
-                agg_flat = jnp.where(jnp.arange(d_pad) < d, agg_flat, 0.0)
-                if flat_sharding is not None:
-                    agg_flat = jax.lax.with_sharding_constraint(
-                        agg_flat, flat_sharding
-                    )
-            # shard-local norm: per-shard partial sums of squares + a scalar
-            # psum — the aggregated gradient is never gathered for a metric
-            agg_norm = jnp.sqrt(jnp.sum(jnp.square(agg_flat)))
-            if su_on:
-                flat_params, inner = opt_state
-                if flat_sharding is not None:
-                    flat_params = jax.lax.with_sharding_constraint(
-                        flat_params, flat_sharding
-                    )
-                updates, inner = opt.update(agg_flat, inner, flat_params)
-                new_flat = optax.apply_updates(flat_params, updates)
-                if flat_sharding is not None:
-                    new_flat = jax.lax.with_sharding_constraint(
-                        new_flat, flat_sharding
-                    )
-                    inner = jax.tree_util.tree_map(
-                        lambda leaf: jax.lax.with_sharding_constraint(
-                            leaf, flat_sharding
-                        )
-                        if getattr(leaf, "shape", None) == (d_pad,)
-                        else leaf,
-                        inner,
-                    )
-                with jax.named_scope("round.param_gather"):
-                    gathered, ef_state = gather_flat_params(new_flat, ef_state)
-                params = layout.unravel(gathered[:d])
-                opt_state = (new_flat, inner)
-            else:
-                update = layout.unravel(agg_flat)
-                updates, opt_state = opt.update(update, opt_state, params)
-                params = optax.apply_updates(params, updates)
-            metrics = {
-                "honest_loss": jnp.mean(losses[:h]),
-                "agg_grad_norm": agg_norm,
-            }
-            if has_ef:
-                # shard-local residual-energy metrics (the convergence study
-                # watches these stay bounded — a drifting residual is the
-                # "EF compounding" failure mode)
-                if ef_transpose:
-                    metrics["ef_transpose_norm"] = jnp.sqrt(
-                        jnp.sum(
-                            jnp.square(ef_state["transpose"].astype(jnp.float32))
-                        )
-                    )
-                if ef_gather:
-                    metrics["ef_gather_norm"] = jnp.sqrt(
-                        jnp.sum(jnp.square(ef_state["gather"].astype(jnp.float32)))
-                    )
-                opt_state = (opt_state, ef_state)
-        return params, opt_state, metrics
-
-    return train_step, opt_state0
+            unstreamable={"pre_aggregate": pre_aggregate is not None, "sharded_update": forced_flat})
+    return _one_device_train_step(
+        bundle, aggregate, cfg, attack=attack, pre_aggregate=pre_aggregate,
+        optimizer=optimizer, grad_dtype=grad_dtype, flat_update=forced_flat)
 
 
 def build_serving_ps_step(

@@ -1,19 +1,28 @@
 """SPMD parameter-server step: single-device vs 8-device-mesh parity, and
 end-to-end robustness (training under attack still converges)."""
 
+import os
+import re
+import subprocess
+import sys
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
 
 from byzpy_tpu.models import mnist_mlp, synthetic_classification, ShardedDataset
-from byzpy_tpu.ops import attack_ops, robust
+from byzpy_tpu.models.bundle import ModelBundle, Segment
+from byzpy_tpu.ops import attack_ops, coordinatewise, robust
 from byzpy_tpu.parallel import (
     PSStepConfig,
     build_ps_train_step,
     jit_ps_train_step,
     node_mesh,
 )
+from byzpy_tpu.parallel.ps import default_optimizer
 
 N_NODES = 8
 N_BYZ = 2
@@ -220,3 +229,88 @@ def test_actor_ps_matches_fused_spmd_ps(setup):
             [np.ravel(l) for l in jax.tree_util.tree_leaves(node.params)]
         )
         np.testing.assert_allclose(f_actor, f_spmd, rtol=2e-4, atol=2e-5)
+
+
+def _chain():
+    """The MLP as a chain of two links, each with its own subtree."""
+    def body(p, x):
+        return jnp.tanh(x.reshape(x.shape[0], -1) @ p["w"])
+
+    def head(p, hidden, y):
+        return optax.softmax_cross_entropy_with_integer_labels(hidden @ p["w"], y).mean()
+
+    kb, kh = jax.random.split(jax.random.PRNGKey(0))
+    return ModelBundle(
+        apply_fn=None, segments=(Segment("body", body), Segment("head", head)),
+        params={"body": {"w": 0.05 * jax.random.normal(kb, (784, 16))},
+                "head": {"w": 0.1 * jax.random.normal(kh, (16, 10))}})
+
+
+def _like(tree):
+    return jax.tree_util.tree_map(lambda a: (a.shape, str(a.dtype)), tree)
+
+
+# case: (a segmented bundle, a mesh, keyword arguments, the round it gets)
+DISPATCH = {
+    "plain_no_mesh": (False, False, {}, "one_device"),
+    "plain_mesh": (False, True, {}, "mesh"),
+    "segmented_no_mesh": (True, False, {}, "streamed"),
+    "segmented_mesh": (True, True, {}, "mesh"),
+    "flat_update_no_mesh": (False, False, {"sharded_update": "on"}, "one_device_flat"),
+    "streamed_refusal": (True, False, {"aggregate": lambda m: robust.multi_krum(m, f=2, q=4)},
+                         "refused"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DISPATCH))
+def test_the_dispatcher_gives_each_call_its_round(setup, case):
+    """``build_ps_train_step`` decides one thing, which round a call gets.
+    What tells the three apart from outside: the structure of ``opt_state0``
+    and whether the compiled step holds a collective. Every round's step is
+    called ``train_step`` (the traced module ``jit_train_step`` is what the
+    benchmark's drivers and the scope tests name)."""
+    segmented, on_mesh, kwargs, want = DISPATCH[case]
+    kwargs = dict(kwargs)
+    _, xs, ys = setup
+    bundle = _chain() if segmented else mnist_mlp(hidden=16)
+    cfg = PSStepConfig(n_nodes=N_NODES, n_byzantine=N_BYZ)
+    aggregate = kwargs.pop("aggregate", partial(robust.trimmed_mean, f=N_BYZ))
+    attack = coordinatewise.RoundAttack(attack_ops.sign_flip, of="honest_mean")
+    mesh = node_mesh(N_NODES) if on_mesh else None
+    if want == "refused":
+        with pytest.raises(ValueError, match=re.escape("byzpy_tpu/ops/coordinatewise.py")):
+            build_ps_train_step(bundle, aggregate, cfg, attack=attack, mesh=mesh, **kwargs)
+        return
+    step, opt0 = build_ps_train_step(bundle, aggregate, cfg, attack=attack, mesh=mesh, **kwargs)
+    assert step.__name__ == "train_step"
+    opt = default_optimizer(cfg)
+    d = sum(leaf.size for leaf in jax.tree_util.tree_leaves(bundle.params))
+    if want == "one_device":
+        assert _like(opt0) == _like(opt.init(bundle.params))
+    elif want == "streamed":
+        assert _like(opt0) == _like({key: opt.init(sub) for key, sub in bundle.params.items()})
+    else:  # the flat update: on one device d wide, on the mesh padded to its grid and sharded
+        flat, inner = opt0
+        width = d if want == "one_device_flat" else -(-d // N_NODES) * N_NODES
+        assert flat.shape == (width,) and _like(inner) == _like(opt.init(flat))
+        assert len(flat.sharding.device_set) == (N_NODES if on_mesh else 1)
+    text = jax.jit(step).lower(bundle.params, opt0, xs, ys, jax.random.PRNGKey(0)).compile().as_text()
+    collectives = re.findall(r"= \S+ (all-to-all|all-gather|all-reduce|collective-permute)\(", text)
+    assert bool(collectives) == on_mesh
+
+
+if __name__ == "__main__":
+    # python tests/test_parallel_ps.py <parent tree> <out dir> [group ...]: the
+    # round programs of that tree and of this one, as text, side by side
+    # (tests/round_texts.py; groups default to the toys, `tpu` and `mesh`)
+    here = os.path.dirname(os.path.abspath(__file__))
+    parent, out, *groups = sys.argv[1:]
+    sides = {"parent": parent, "change": os.path.dirname(here)}
+    env = dict(os.environ, ALLOW_MULTIPLE_LIBTPU_LOAD="1")
+    for group in groups or ["tpu", "mesh"]:
+        for side, tree in sides.items():
+            subprocess.run([sys.executable, os.path.join(here, "round_texts.py"), "write", tree,
+                            os.path.join(out, side), group], env=env, check=True)
+    sys.exit(subprocess.run(
+        [sys.executable, os.path.join(here, "round_texts.py"), "compare",
+         *(os.path.join(out, side) for side in sides)], check=False).returncode)

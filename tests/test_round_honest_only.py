@@ -6,7 +6,7 @@ matrix is the attack's (or, with no attack, an honest row echoed). So
 where no node axis forces n rows, ``build_ps_train_step`` runs
 ``per_node_grad`` over ``xs[:h]`` in a loop (``lax.map``: on the TPU a
 convolutional model's per-worker gradients cost far less one worker at a
-time than vmapped, ``docs/performance.md``), and ``build_matrix`` makes
+time than vmapped, ``docs/performance.md``), and ``_set_byzantine_rows`` makes
 the ``(n, .)`` matrix from the ``(h, .)`` stack. The results are the same
 function of the honest inputs as a round that computes all n rows at once
 and overwrites b of them; on a mesh the step keeps all n under ``vmap``
