@@ -39,6 +39,16 @@ class Segment:
     only hands on (a round keeps such an array once). Parameters stay
     with one link each: a table read on two paths gets both paths'
     gradient because both cotangents reach its link.
+    A link that reads ANOTHER link's parameters (a head that multiplies by
+    the embedding's own table) names the links that own them in ``reads``,
+    each EARLIER in the chain, and is handed their subtrees as one more,
+    last, argument, ``{key: subtree}``: ``(subtree, h, read) -> h``, the
+    head ``(subtree, h, y, read) -> loss``. The parameter still has one
+    owner, one place in one row, one aggregate and one update a step: a
+    worker's gradient through the reader is added to the owner's row
+    before the owner's aggregate (a round that streams starts the owner's
+    rows at the reader and keeps them until the owner's turn; nothing of
+    the parameter's size is kept besides).
     With ``aux`` a segment returns ``(h, aux)`` (the head: ``(loss,
     aux)``), ``aux`` a tree of small arrays the round reports per honest
     worker (an expert layer's token counts, a loss's terms) and takes no
@@ -47,6 +57,12 @@ class Segment:
     key: str
     apply: Callable
     aux: bool = False
+    reads: Tuple[str, ...] = ()
+
+    def read_of(self, params: Any) -> Tuple[Any, ...]:
+        """What ``apply`` is handed after its other arguments: nothing, or
+        the subtrees of the links in ``reads``."""
+        return ({key: params[key] for key in self.reads},) if self.reads else ()
 
 
 def chain_loss(segments: Sequence[Segment]) -> Callable:
@@ -55,11 +71,12 @@ def chain_loss(segments: Sequence[Segment]) -> Callable:
     def loss_fn(params: Any, x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
         h = x
         for seg in segments[:-1]:
-            h = seg.apply(params[seg.key], h)
+            h = seg.apply(params[seg.key], h, *seg.read_of(params))
             if seg.aux:
                 h = h[0]
-        loss = segments[-1].apply(params[segments[-1].key], h, y)
-        return loss[0] if segments[-1].aux else loss
+        head = segments[-1]
+        loss = head.apply(params[head.key], h, y, *head.read_of(params))
+        return loss[0] if head.aux else loss
 
     return loss_fn
 
@@ -88,6 +105,11 @@ class ModelBundle:
                     "a segmented bundle's params are a dict keyed by its segments' "
                     f"keys (segments {keys}, params {list(self.params)})"
                 )
+            for at, seg in enumerate(self.segments):
+                if not set(seg.reads) <= set(keys[:at]):
+                    raise ValueError(
+                        f"segment {seg.key!r} reads {list(seg.reads)}: a link reads the "
+                        f"parameters of links before it in the chain ({keys[:at]})")
             if self.loss_fn is None:
                 self.loss_fn = chain_loss(self.segments)
         if self.loss_fn is None:

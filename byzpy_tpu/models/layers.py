@@ -2,7 +2,8 @@
 forms, rotary positions (plain, or with YaRN's blended frequencies), the
 chain's embedding link, next-token cross-entropy, the causal depthwise
 convolution of the state-space and linear-attention mixers with its SiLU,
-multi-head latent attention, and causal attention by blocks of queries for
+the gated short convolution (two elementwise gates around that convolution,
+no activation), multi-head latent attention, and causal attention by blocks of queries for
 a call the block-causal kernels do not serve
 (:func:`~byzpy_tpu.ops.pallas_attention.causal_attention_serves`)."""
 
@@ -189,6 +190,46 @@ def _conv_silu_bwd(splits, kept, g):
 conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 
 
+@jax.custom_vjp
+def gated_short_conv(bcx: Array, w: Array) -> Array:
+    """The gated short convolution of one sequence (LFM2's operator between
+    its two projections): ``bcx (T, 3 * hidden)`` holds the column blocks
+    ``[B | C | X]``, ``w (K, hidden)`` the depthwise taps; returns ``C *
+    causal_depthwise_conv(B * X, w)``, ``(T, hidden)``: no bias, NO
+    activation. With a backward of its own: it keeps ``bcx`` and ``w``
+    alone (the gated product and the convolution are made again: two
+    elementwise passes, against two more arrays of ``(T, hidden)`` kept a
+    block), runs the same ``K`` shifted multiply-adds the other way, and
+    writes the three blocks' cotangents ONCE, side by side, as the
+    projection's transpose reads them (left to automatic differentiation
+    each block's slice is padded to the whole row and the padded rows are
+    added up; each tap's transpose is a write into a fresh zero array)."""
+    b, c, x = jnp.split(bcx, 3, axis=1)
+    return c * causal_depthwise_conv(b * x, w)
+
+
+def _gated_short_conv_fwd(bcx, w):
+    return gated_short_conv(bcx, w), (bcx, w)
+
+
+def _gated_short_conv_bwd(kept, d_out):
+    # the backward rule is traced outside the scope the forward stood in
+    with jax.named_scope("model.short_conv"):
+        bcx, w = kept
+        k = w.shape[0]
+        b, c, x = jnp.split(bcx, 3, axis=1)
+        g = b * x
+        d_conv = d_out * c
+        # dg[t] = sum_j w[j] d_conv[t + (K - 1) - j], zero past the end
+        dg = sum(w[j] * _rows_moved(d_conv, j + 1 - k) for j in range(k))
+        dw = jnp.stack([jnp.sum(d_conv * _rows_moved(g, k - 1 - j), axis=0) for j in range(k)])
+        d_bcx = jnp.concatenate([dg * x, d_out * causal_depthwise_conv(g, w), dg * b], axis=1)
+        return d_bcx, dw
+
+
+gated_short_conv.defvjp(_gated_short_conv_fwd, _gated_short_conv_bwd)
+
+
 def blocked_causal_attention(q: Array, k: Array, v: Array, query_block: int) -> Array:
     """Causal softmax attention of one sequence, ``query_block`` queries at a
     time: ``q (T, kv, per, head_dim)`` (``per`` query heads read key/value
@@ -276,6 +317,7 @@ __all__ = [
     "causal_depthwise_conv",
     "conv_silu",
     "cross_entropy",
+    "gated_short_conv",
     "mla_attention",
     "rms_norm",
     "rms_norm_one_plus",

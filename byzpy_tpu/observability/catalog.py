@@ -155,6 +155,8 @@ SCOPES: FrozenSet[str] = frozenset(
         "model.mtp",
         "model.mtp_join",
         "model.norm",
+        "model.short_conv",
+        "model.short_conv_proj",
         "model.ssm_gate",
         "model.ssm_proj",
         "model.ssm_scan",
@@ -177,6 +179,7 @@ SCOPES: FrozenSet[str] = frozenset(
         "serving.staleness_scale",
         "stream.boundary",
         "stream.rows",
+        "stream.shared_rows",
     }
 )
 

@@ -10,7 +10,13 @@ softmax's row sums) another, each whole lanes: latent attention's 192 / 128
 comes with its queries and keys zero-padded to 256 (a contraction over 192
 costs the 128-wide MXU two passes as 256 does) and its values at 128, so
 ``p v``, ``do v^T`` and ``p^T do`` are half what a padded value would cost.
-Nothing is transposed or repeated in HBM on the way in or out.
+A head NARROWER than a lane tile (64, 32) is taken as it is: such heads
+lie two (four) to a tile, a grid step is the key/value heads of one tile
+with all their query heads, and a query head's tile is turned inside the
+kernel until its values stand under the lanes of the key/value head it
+reads, every other lane zero (:func:`_head`), so a product with the whole
+key or value block serves that head alone. Nothing is padded, transposed
+or repeated in HBM on the way in or out.
 
 * The grid of every kernel is (key/value head, pair), and the pairs are the
   (query block, key block) pairs AT OR UNDER the diagonal, listed in Python
@@ -102,11 +108,28 @@ _CHUNK_ROWS = 1024
 # same three read 0.203 / 0.278 / 0.307 ms (the forward's 10.7 GFLOP at the
 # published widths: 27 % of the peak; all three: 30.8 %), so a sweep at this
 # length ranks tiles and does not time a kernel.
-# All three regimes: a key block meets up to _FOLDED_ROWS rows of queries (a
+# HEADS NARROWER THAN A LANE TILE (PR 46): 32 query / 8 key-value heads of 64,
+# two key/value heads a tile, so a grid step holds EIGHT query heads against a
+# key block of 128 lanes, at 4096 positions (host-clock times a call, 16 tile
+# pairs a kernel): forward 1.329 at 512 x 1024 (the rule's, the least with 1024 x
+# 1024), 1.352 at 256 x 1024, 1.407 at 128 x 1024, 1.632 at 512 x 2048, 2.331 at
+# 512 x 512, 4.39 at 512 x 256; dq 1.460 at 512 x 512 (the rule's, the least),
+# 1.502 at 256 x 512, 1.540 at 1024 x 1024, 1.545 at 512 x 1024, 1.586 at 1024 x
+# 512, 1.673 at 512 x 256; dk / dv 1.702 at 512 x 512 (the rule's, the least),
+# 1.767 at 512 x 256, 1.803 at 256 x 512, 1.843 at 512 x 1024, 1.856 at 1024 x
+# 512, 2.73 at 1024 x 1024, 3.17 at 512 x 2048. The rule stands as it is (the
+# picks of eight heads a group). Forward and backward of one sequence: 4.52 ms
+# as the kernels take the heads, 5.16 with q, k, v zero-padded to 128 a head
+# beforehand and 5.71 with the pads and the slice counted (the form to beat),
+# 28.1 through the `lax.map` route.
+# All four regimes: a key block meets up to _FOLDED_ROWS rows of queries (a
 # group's heads times the query block), and the backward's kernels, which
 # hold two products' tiles a pair, keep heads x query block x key block
 # within _BACKWARD_TILE.
 _FOLDED_ROWS, _WIDEST_BLOCK, _BACKWARD_TILE = 4096, 1024, 2 ** 21
+# head widths under a lane tile that the kernels take unpadded, 128 / width
+# heads to a tile
+_NARROW_HEADS = (64, 32)
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
@@ -115,21 +138,26 @@ def causal_attention_serves(x: Array, head_dim: int, v_head_dim: Optional[int] =
     whose activations are ``x``? On a TPU, for float32 / bfloat16
     activations, BOTH head widths in whole lanes (``head_dim`` of the
     queries and keys, ``v_head_dim`` of the values and the output; left
-    out, the values are as wide as the keys), an operand that is not
+    out, the values are as wide as the keys) or both ONE width that lies
+    two or four to a lane tile (``_NARROW_HEADS``; the caller's key/value
+    heads must fill whole tiles, which :func:`causal_attention` checks: it
+    is told their number, the gate is not), an operand that is not
     device-sharded (:func:`~byzpy_tpu.ops.pallas_kernels.
     sharding_allows_pallas`). Any length: the wrapper pads it to whole
     blocks. Asked once a call, in Python, by ``models.nemotron_h.
     gqa_attention`` (sixteen query heads a key/value head of 128),
     ``models.layers.mla_attention`` (one query head a key/value head:
     GLM-4.7-Flash's 256 / 256, and 192 / 128 with the queries and keys
-    padded to 256) and ``models.qwen3_next.gated_attention`` (eight of
-    256; :func:`_blocks` has what each regime measured); reads no
+    padded to 256), ``models.qwen3_next.gated_attention`` (eight of 256)
+    and ``models.lfm2_moe.gqa_attention`` (four of 64, two key/value heads a
+    tile; :func:`_blocks` has what each regime measured); reads no
     environment variable."""
+    v_head_dim = head_dim if v_head_dim is None else v_head_dim
     return bool(
         _pk._on_tpu()
         and x.dtype in (jnp.float32, jnp.bfloat16)
-        and head_dim % _LANES == 0
-        and (head_dim if v_head_dim is None else v_head_dim) % _LANES == 0
+        and ((head_dim % _LANES == 0 and v_head_dim % _LANES == 0)
+             or (head_dim in _NARROW_HEADS and v_head_dim == head_dim))
         and _pk.sharding_allows_pallas(x)
     )
 
@@ -147,7 +175,10 @@ def _blocks(t: int, per: int, *, backward: bool) -> Tuple[int, int, int]:
     sequence of 1024 positions with one query head a group (32 key/value
     heads of 256 / 128) is ONE pair of 1024 x 1024 a head in all three
     kernels, on the diagonal. The head widths do not enter: the tiles are
-    counted in scores, and the widest head (256) fits every regime above."""
+    counted in scores, and the widest head (256) fits every regime above.
+    Where heads share a lane tile ``per`` counts the query heads of a grid
+    step (a tile's key/value heads times their group: 8 for four heads of
+    64 a group, 512 x 1024 forward and 512 x 512 backward)."""
     t_pad = _pk._round_up(t, _LANES)
     sizes = (1024, 512, 256, 128)
     block_q = next(b for b in sizes
@@ -215,12 +246,19 @@ def _row_to_column(row_ref, r, eye):
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
 
 
-def _heads(per: int, body):
+def _heads(per: int, body, pack: int = 1):
     """``body(r)`` for every head of a group. Traced once and unrolled where
     it is lowered (so a head's lanes and rows are static slices to Mosaic,
     and one head's products hide the next head's exponentials): sixteen
     traced copies of every per-head body were most of what tracing and
-    lowering the kernels cost a step's set-up."""
+    lowering the kernels cost a step's set-up. Heads that share a lane tile
+    (``pack > 1``) are traced one by one: where a head lies in its tile is
+    then a Python number."""
+    if pack > 1:
+        for r in range(per):
+            body(r)
+        return
+
     def step(r, carry):
         body(r)
         return carry
@@ -238,16 +276,69 @@ def _lanes(r, head_dim: int):
     return pl.ds(r * head_dim, head_dim)
 
 
-def _fold(dst_ref, src_ref, per: int, block_q: int, head_dim: int, scale: Optional[float]):
-    """A ``(block_q, per * head_dim)`` block, a group's heads side by side,
-    into ``(per * block_q, head_dim)`` rows, head by head."""
-    def one(r):
-        part = src_ref[:, _lanes(r, head_dim)]
-        if scale is not None:
-            part = (part.astype(jnp.float32) * scale).astype(dst_ref.dtype)
-        dst_ref[_rows(r, block_q), :] = part
+def _lane_group(shape, head_dim: int):
+    """Which head of its lane tile a lane belongs to (``head_dim`` a power
+    of two under 128)."""
+    return lax.shift_right_logical(
+        lax.broadcasted_iota(jnp.int32, shape, 1), int(math.log2(head_dim)))
 
-    _heads(per, one)
+
+def _head(ref, r, width: int, per: int, pack: int):
+    """Head ``r``'s ``(rows, width)`` tile of a block that holds a grid
+    step's heads side by side. ``pack == 1``: its own lanes. Heads
+    NARROWER than a lane tile lie ``pack`` to a tile of ``width`` = 128
+    lanes, and so do the ``pack`` key/value heads of the step in the key
+    and value blocks: head ``r`` reads key/value head ``r // (per / pack)``
+    of them, so its tile is turned until its values stand under that
+    head's lanes and every other lane is zero (float32). A product with
+    the whole key (value) block then contracts over, or writes, that head
+    alone: nothing is padded in HBM, and no value is sliced off a lane
+    boundary."""
+    if pack == 1:
+        return ref[:, _lanes(r, width)]
+    head_dim = width // pack
+    tile = ref[:, pl.ds(r // pack * width, width)].astype(jnp.float32)
+    want, has = r // (per // pack), r % pack
+    if want != has:
+        tile = pltpu.roll(tile, ((want - has) * head_dim) % width, 1)
+    return jnp.where(_lane_group(tile.shape, head_dim) == want, tile, 0.0)
+
+
+def _put_heads(dst_ref, tile_of, per: int, width: int, pack: int):
+    """:func:`_head` the other way: ``tile_of(r)``, head ``r``'s ``(rows,
+    width)`` result (float32; where heads share a tile, good under the
+    lanes of the key/value head it read), into its place in a block that
+    holds the step's heads side by side."""
+    if pack == 1:
+        def one(r):
+            dst_ref[:, _lanes(r, width)] = tile_of(r).astype(dst_ref.dtype)
+
+        _heads(per, one)
+        return
+    head_dim = width // pack
+    for chunk in range(per // pack):
+        out = None
+        for has in range(pack):
+            r = chunk * pack + has
+            want, tile = r // (per // pack), tile_of(r)
+            if want != has:
+                tile = pltpu.roll(tile, ((has - want) * head_dim) % width, 1)
+            tile = jnp.where(_lane_group(tile.shape, head_dim) == has, tile, 0.0)
+            out = tile if out is None else out + tile
+        dst_ref[:, pl.ds(chunk * width, width)] = out.astype(dst_ref.dtype)
+
+
+def _fold(dst_ref, src_ref, per: int, block_q: int, head_dim: int, scale: Optional[float],
+          pack: int = 1):
+    """A ``(block_q, per / pack * head_dim)`` block, a step's heads side by
+    side, into ``(per * block_q, head_dim)`` rows, head by head."""
+    def one(r):
+        part = _head(src_ref, r, head_dim, per, pack)
+        if scale is not None:
+            part = part.astype(jnp.float32) * scale
+        dst_ref[_rows(r, block_q), :] = part.astype(dst_ref.dtype)
+
+    _heads(per, one, pack)
 
 
 def _seen(rows0, n_rows: int, block_q: int, block_k: int, qi, kj):
@@ -287,14 +378,15 @@ def _masked_or_not(flag, step):
 
 
 def _fwd_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                qf_ref, m_ref, l_ref, acc_ref, *, per, qk_dim, v_dim, scale, block_q, block_k):
+                qf_ref, m_ref, l_ref, acc_ref,
+                *, per, qk_dim, v_dim, pack, scale, block_q, block_k):
     pair = pl.program_id(1)
     qi, kj, flag = qi_ref[pair], kj_ref[pair], flag_ref[pair]
     rows = per * block_q
 
     @pl.when((flag & _FIRST) != 0)
     def _():
-        _fold(qf_ref, q_ref, per, block_q, qk_dim, scale)
+        _fold(qf_ref, q_ref, per, block_q, qk_dim, scale, pack)
         m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
@@ -324,10 +416,14 @@ def _fwd_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         def one(r):
             head = _rows(r, block_q)
             total = l_ref[head, :]
-            o_ref[:, _lanes(r, v_dim)] = (acc_ref[head, :] / total).astype(o_ref.dtype)
+            if pack == 1:
+                o_ref[:, _lanes(r, v_dim)] = (acc_ref[head, :] / total).astype(o_ref.dtype)
             lse_ref[pl.ds(r, 1), :] = _column_to_row(m_ref[head, :] + jnp.log(total), eye)
 
-        _heads(per, one)
+        _heads(per, one, pack)
+        if pack > 1:
+            _put_heads(o_ref, lambda r: acc_ref[_rows(r, block_q), :] / l_ref[_rows(r, block_q), :],
+                       per, v_dim, pack)
 
 
 def _grid_spec(pairs, kv_heads, in_specs, out_specs, scratch_shapes):
@@ -336,13 +432,14 @@ def _grid_spec(pairs, kv_heads, in_specs, out_specs, scratch_shapes):
         in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch_shapes)
 
 
-def _specs(per: int, qk_dim: int, v_dim: int, block_q: int, block_k: int):
+def _specs(per: int, qk_dim: int, v_dim: int, block_q: int, block_k: int, pack: int = 1):
     """Block specs by the pair's query and key block: of the queries (and
     their cotangent), the keys, the values, the output (and its cotangent)
     and a per-row statistic. Queries and keys are ``qk_dim`` a head wide,
-    values and the output ``v_dim``."""
+    values and the output ``v_dim`` (a lane TILE where ``pack`` heads
+    share one)."""
     def query_side(width):
-        return pl.BlockSpec((block_q, per * width), lambda g, p, qi, kj, fl: (qi[p], g))
+        return pl.BlockSpec((block_q, per // pack * width), lambda g, p, qi, kj, fl: (qi[p], g))
 
     def key_side(width):
         return pl.BlockSpec((block_k, width), lambda g, p, qi, kj, fl: (kj[p], g))
@@ -367,22 +464,28 @@ def _cost(pairs, kv_heads, per, block_q, block_k, widths, arrays):
 
 
 def _widths(q, k, v, kv_heads):
-    """``(query heads a key/value head, qk_dim, v_dim)`` of a call."""
+    """``(grid steps, query heads a step, qk_dim, v_dim, pack)`` of a call.
+    A step is a key/value head with its query heads and the widths are a
+    head's; where heads are narrower than a lane tile a step is the
+    ``pack`` key/value heads of one tile with all their query heads, and
+    the widths are the tile's."""
     qk_dim, v_dim = k.shape[1] // kv_heads, v.shape[1] // kv_heads
-    return q.shape[1] // (kv_heads * qk_dim), qk_dim, v_dim
+    per = q.shape[1] // (kv_heads * qk_dim)
+    pack = _LANES // qk_dim if qk_dim < _LANES else 1
+    return kv_heads // pack, per * pack, qk_dim * pack, v_dim * pack, pack
 
 
 def _causal_attention_fwd_call(q, k, v, *, kv_heads, scale, block_q, block_k, interpret):
     t = q.shape[0]
-    per, qk_dim, v_dim = _widths(q, k, v, kv_heads)
+    kv_heads, per, qk_dim, v_dim, pack = _widths(q, k, v, kv_heads)
     rows = per * block_q
     pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=False)
-    q_spec, k_spec, v_spec, o_spec, row_spec = _specs(per, qk_dim, v_dim, block_q, block_k)
-    out_shape = (jax.ShapeDtypeStruct((t, kv_heads * per * v_dim), q.dtype),
+    q_spec, k_spec, v_spec, o_spec, row_spec = _specs(per, qk_dim, v_dim, block_q, block_k, pack)
+    out_shape = (jax.ShapeDtypeStruct((t, kv_heads * (per // pack) * v_dim), q.dtype),
                  jax.ShapeDtypeStruct((kv_heads, per, t), jnp.float32))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, scale=scale,
-                          block_q=block_q, block_k=block_k),
+        functools.partial(_fwd_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, pack=pack,
+                          scale=scale, block_q=block_q, block_k=block_k),
         out_shape=out_shape,
         grid_spec=_grid_spec(
             pairs, kv_heads, [q_spec, k_spec, v_spec], (o_spec, row_spec),
@@ -403,22 +506,22 @@ def _causal_attention_fwd_call(q, k, v, *, kv_heads, scale, block_q, block_k, in
 
 def _dq_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dq_ref, qf_ref, dof_ref, lse_col_ref, delta_col_ref, acc_ref,
-               *, per, qk_dim, v_dim, scale, block_q, block_k):
+               *, per, qk_dim, v_dim, pack, scale, block_q, block_k):
     pair = pl.program_id(1)
     qi, kj, flag = qi_ref[pair], kj_ref[pair], flag_ref[pair]
     rows = per * block_q
 
     @pl.when((flag & _FIRST) != 0)
     def _():
-        _fold(qf_ref, q_ref, per, block_q, qk_dim, scale)
-        _fold(dof_ref, do_ref, per, block_q, v_dim, None)
+        _fold(qf_ref, q_ref, per, block_q, qk_dim, scale, pack)
+        _fold(dof_ref, do_ref, per, block_q, v_dim, None, pack)
         eye = _eye()
 
         def one(r):
             lse_col_ref[_rows(r, block_q), :] = _row_to_column(lse_ref, r, eye)
             delta_col_ref[_rows(r, block_q), :] = _row_to_column(delta_ref, r, eye)
 
-        _heads(per, one)
+        _heads(per, one, pack)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     def step(masked):
@@ -438,24 +541,20 @@ def _dq_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, d
 
     @pl.when((flag & _LAST) != 0)
     def _():
-        def one(r):
-            dq_ref[:, _lanes(r, qk_dim)] = (
-                acc_ref[_rows(r, block_q), :] * scale).astype(dq_ref.dtype)
-
-        _heads(per, one)
+        _put_heads(dq_ref, lambda r: acc_ref[_rows(r, block_q), :] * scale, per, qk_dim, pack)
 
 
 def _causal_attention_dq_call(q, k, v, do, lse, delta, *, kv_heads, scale, block_q,
                               block_k, interpret):
     t = q.shape[0]
-    per, qk_dim, v_dim = _widths(q, k, v, kv_heads)
+    kv_heads, per, qk_dim, v_dim, pack = _widths(q, k, v, kv_heads)
     rows = per * block_q
     pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=False)
-    q_spec, k_spec, v_spec, o_spec, row_spec = _specs(per, qk_dim, v_dim, block_q, block_k)
+    q_spec, k_spec, v_spec, o_spec, row_spec = _specs(per, qk_dim, v_dim, block_q, block_k, pack)
     out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
     return pl.pallas_call(
-        functools.partial(_dq_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, scale=scale,
-                          block_q=block_q, block_k=block_k),
+        functools.partial(_dq_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, pack=pack,
+                          scale=scale, block_q=block_q, block_k=block_k),
         out_shape=out_shape,
         grid_spec=_grid_spec(
             pairs, kv_heads, [q_spec, k_spec, v_spec, o_spec, row_spec, row_spec], q_spec,
@@ -477,7 +576,7 @@ def _causal_attention_dq_call(q, k, v, do, lse, delta, *, kv_heads, scale, block
 
 def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
-                *, per, qk_dim, v_dim, scale, block_q, block_k):
+                *, per, qk_dim, v_dim, pack, scale, block_q, block_k):
     pair = pl.program_id(1)
     qi, kj, flag = qi_ref[pair], kj_ref[pair], flag_ref[pair]
 
@@ -497,8 +596,9 @@ def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
         # ``fori_loop`` over the heads this kernel took 2.41 ms where sixteen
         # copies take 1.71 (v5e, PR 33); the copies cost 1.9 MB of code in HBM
         def one(r):
-            q = (q_ref[:, _lanes(r, qk_dim)].astype(jnp.float32) * scale).astype(q_ref.dtype)
-            do = do_ref[:, _lanes(r, v_dim)]
+            q = (_head(q_ref, r, qk_dim, per, pack).astype(jnp.float32) * scale
+                 ).astype(q_ref.dtype)
+            do = _head(do_ref, r, v_dim, per, pack).astype(do_ref.dtype)
             s = _dot(k_ref[...], q, _NT)
             if masked:
                 s = jnp.where(seen, s, -jnp.inf)
@@ -508,7 +608,7 @@ def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
             ds = p * (dp - delta_ref[pl.ds(r, 1), :])
             dk_acc_ref[...] = dk_acc_ref[...] + _dot(ds.astype(q.dtype), q, _NN)
 
-        _heads(per, one)
+        _heads(per, one, pack)
 
     _masked_or_not(flag, step)
 
@@ -521,13 +621,13 @@ def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
 def _causal_attention_dkv_call(q, k, v, do, lse, delta, *, kv_heads, scale, block_q,
                                block_k, interpret):
     t = q.shape[0]
-    per, qk_dim, v_dim = _widths(q, k, v, kv_heads)
+    kv_heads, per, qk_dim, v_dim, pack = _widths(q, k, v, kv_heads)
     pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=True)
-    q_spec, k_spec, v_spec, o_spec, row_spec = _specs(per, qk_dim, v_dim, block_q, block_k)
+    q_spec, k_spec, v_spec, o_spec, row_spec = _specs(per, qk_dim, v_dim, block_q, block_k, pack)
     out_shape = (jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype))
     return pl.pallas_call(
-        functools.partial(_dkv_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, scale=scale,
-                          block_q=block_q, block_k=block_k),
+        functools.partial(_dkv_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, pack=pack,
+                          scale=scale, block_q=block_q, block_k=block_k),
         out_shape=out_shape,
         grid_spec=_grid_spec(
             pairs, kv_heads, [q_spec, k_spec, v_spec, o_spec, row_spec, row_spec],
@@ -561,10 +661,15 @@ def causal_attention(q: Array, k: Array, v: Array, *, kv_heads: int,
     whole blocks (padded keys lie after every query; padded queries are
     cut off and their cotangent is zero)."""
     qk_dim, v_dim = k.shape[1] // kv_heads, v.shape[1] // kv_heads
-    if qk_dim % _LANES or v_dim % _LANES or q.shape[1] % (kv_heads * qk_dim):
+    if qk_dim in _NARROW_HEADS:
+        fits = v_dim == qk_dim and kv_heads % (_LANES // qk_dim) == 0
+    else:
+        fits = qk_dim % _LANES == 0 and v_dim % _LANES == 0
+    if not fits or q.shape[1] % (kv_heads * qk_dim):
         raise ValueError(
-            f"causal_attention needs a head_dim of whole {_LANES}s (queries / keys, and values) "
-            f"and whole groups of query "
+            f"causal_attention needs a head_dim of whole {_LANES}s (queries / keys, and values), "
+            f"or one of {_NARROW_HEADS} for all three with the key/value heads filling whole "
+            f"lane tiles, and whole groups of query "
             f"heads, got q {q.shape}, k {k.shape}, v {v.shape}, kv_heads {kv_heads}")
     if scale is None:
         scale = 1.0 / math.sqrt(qk_dim)
@@ -577,7 +682,8 @@ def _padded(t_pad: int, *arrays):
 
 
 def _forward(q, k, v, kv_heads, scale, interpret):
-    t_pad, block_q, block_k = _blocks(q.shape[0], q.shape[1] // k.shape[1], backward=False)
+    t_pad, block_q, block_k = _blocks(
+        q.shape[0], _widths(q, k, v, kv_heads)[1], backward=False)
     out, lse = _causal_attention_fwd_call(
         *_padded(t_pad, q, k, v), kv_heads=kv_heads, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret)
@@ -599,12 +705,13 @@ def _causal_attention_bwd(kv_heads, scale, interpret, residuals, d_out):
     with jax.named_scope("model.attention"):
         q, k, v, out, lse = residuals
         t = q.shape[0]
-        per, _, v_dim = _widths(q, k, v, kv_heads)
+        steps, per, _, _, _ = _widths(q, k, v, kv_heads)
         t_pad, block_q, block_k = _blocks(t, per, backward=True)
         # rowsum(d_out * out): what the softmax's Jacobian takes off every row
+        # (a step's heads are consecutive heads: its key/value heads' groups)
         delta = jnp.sum(
             (d_out.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
-                t, kv_heads, per, v_dim), axis=-1)
+                t, steps, per, -1), axis=-1)
         delta = jnp.pad(jnp.transpose(delta, (1, 2, 0)), ((0, 0), (0, 0), (0, t_pad - t)))
         args = _padded(t_pad, q, k, v, d_out.astype(q.dtype)) + (lse, delta)
         sizes = dict(kv_heads=kv_heads, scale=scale, block_q=block_q, block_k=block_k,

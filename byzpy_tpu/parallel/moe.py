@@ -297,6 +297,7 @@ def held_experts_ffn(
     shared_gate: Optional[Array] = None,
     score: Callable[[Array], Array] = jax.nn.sigmoid,
     shared_weight: Optional[Array] = None,
+    denominator_eps: float = 0.0,
 ):
     """One chip's part of an expert layer whose experts are spread over
     chips: it is told which experts it holds, routes over all of them,
@@ -308,7 +309,8 @@ def held_experts_ffn(
     router_w)`` over all ``n_experts`` (``score`` is the model's: the
     sigmoid of DeepSeek-V3 / Nemotron-H / GLM, or ``jax.nn.softmax`` over
     the experts as Qwen3-Next has it), the ``top_k`` largest a token, their
-    ``s`` normalised to sum 1 and times ``scale``. Expert:
+    ``s`` over their sum (plus ``denominator_eps`` where a model's code adds
+    one: LFM2's ``1e-6``; 0 adds no op) and times ``scale``. Expert:
     ``w_down relu(w_up x)^2``, or, where the experts come with a third
     stacked matrix ``w_gate (held, D, F)`` (and the shared expert with
     ``shared_gate (D, Fs)``), ``w_down (silu(w_gate x) * w_up x)``: which of
@@ -346,7 +348,10 @@ def held_experts_ffn(
             x.astype(jnp.float32), router_w.astype(jnp.float32),
             precision=lax.Precision.HIGHEST))
         top_s, top_e = lax.top_k(scores, top_k)  # (T, k)
-        weight = top_s / jnp.sum(top_s, axis=-1, keepdims=True) * scale
+        total = jnp.sum(top_s, axis=-1, keepdims=True)
+        if denominator_eps:
+            total = total + denominator_eps
+        weight = top_s / total * scale
         local = top_e - first_held
         here = (local >= 0) & (local < held)  # (T, k): this pick's expert is held
         # (T, held): the weight of each held expert for each token (an

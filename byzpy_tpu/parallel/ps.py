@@ -215,20 +215,24 @@ def _one_device_layout(n: int, tree):
     return row_layout(tree, width, folded=len(_folded((width,))) == 2)
 
 
-def _write_row(stack, i, offsets, pieces):
+def _write_row(stack, i, offsets, pieces, add=False):
     """Row ``i`` of a stack of rows, folded or flat, written piece by piece
     (``layout.place`` of one worker's gradient, first columns
     ``layout.offsets``): a leaf's gradient, whole tiles of the row, is
     written once, from where the backward pass left it, with no row-wide
-    ``concatenate`` and no relayout of a weight gradient in front."""
+    ``concatenate`` and no relayout of a weight gradient in front. With
+    ``add`` the pieces are added to what the row holds (a parameter's
+    gradient through a second link, ``Segment.reads``)."""
     # a row's first axis counts units of `lane` columns: 128 where rows are
     # folded, single columns where they are flat
     lanes = stack.shape[2:]
     lane = math.prod(lanes)
     for first, piece in zip(offsets, pieces):
-        stack = jax.lax.dynamic_update_slice(
-            stack, jax.lax.expand_dims(piece.reshape(-1, *lanes), (0,)),
-            (i, first // lane, *(0 for _ in lanes)))
+        piece = jax.lax.expand_dims(piece.reshape(-1, *lanes), (0,))
+        at = (i, first // lane, *(0 for _ in lanes))
+        if add:
+            piece = jax.lax.dynamic_slice(stack, at, piece.shape) + piece
+        stack = jax.lax.dynamic_update_slice(stack, piece, at)
     return stack
 
 
@@ -267,6 +271,15 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
     segment's leaves. The next segment starts only when this one's
     parameters are updated, so its n rows are dead by then: at most one
     segment's rows exist at a time, and no ``(n, d)`` array ever does.
+
+    A segment that reads an earlier one's parameters (``Segment.reads``: a
+    tied table) is handed them as they stand before the step; a worker's
+    gradient of them through the reader starts the OWNER's row of that
+    worker there and then (``stream.shared_rows``), and the owner's own
+    turn, and any reader between, adds to it. So the owner's rows live from
+    its first reader's turn to its own (the one exception to "one segment's
+    rows at a time"), they hold both paths before the one aggregate and the
+    one update, and nothing else of the parameters' size is kept.
 
     Where the sort kernel can form the byzantine rows itself (a trimmed
     mean or median, an attack that ``ops/coordinatewise.py`` declares
@@ -346,7 +359,7 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
             made, wires = [], []
             for at, seg in enumerate(segs[:last]):
                 with jax.named_scope("segment." + seg.key):
-                    x = seg.apply(params[seg.key], x)
+                    x = seg.apply(params[seg.key], x, *seg.read_of(params))
                 if seg.aux:
                     x, auxes[seg.key] = x
                 leaves, treedef = jax.tree_util.tree_flatten(x)
@@ -398,14 +411,22 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
         # what flows back: the cotangent of every kept array, once a segment
         # that reads the array has run backwards
         cots = [None] * len(vals)
+        # the rows of a segment whose parameters a later one reads, from that
+        # reader's turn to its own
+        at_key = {seg.key: k for k, seg in enumerate(segs)}
+        started = {}
+
+        def fresh_rows(k):
+            return jax.lax.empty(
+                (h if formed[k] else n, *_folded((layouts[k].width,))), rows_dtypes[k])
         # A segment's leaves are handed to its loop through the barrier that
         # closes the segment after it (the head's: through one with the last
         # boundary). Whatever the compiler derives from them alone (a
         # weight's transposed copy, hoisted out of the loop) then cannot be
         # made before that point, and so not for all segments at once.
         reads = wires[last - 1][1]
-        sub, held_back = jax.lax.optimization_barrier(
-            (params[segs[last].key], [vals[j] for j in reads]))
+        (sub, read), held_back = jax.lax.optimization_barrier(
+            ((params[segs[last].key], segs[last].read_of(params)), [vals[j] for j in reads]))
         for j, stack in zip(reads, held_back):
             vals[j] = stack
         for k in range(last, -1, -1):
@@ -424,8 +445,9 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                       else jax.lax.empty(vals[j].shape, vals[j].dtype)
                       for j, last_read in zip(reads, over)]
 
-                def one_backward(i, carry, k=k, seg=seg, layout=layout, sub=sub, at=at,
-                                 over=over, cots=tuple(cots), vals=tuple(vals)):
+                def one_backward(i, carry, k=k, seg=seg, layout=layout, sub=sub, read=read,
+                                 at=at, over=over, cots=tuple(cots), vals=tuple(vals),
+                                 adds=seg.key in started, known=frozenset(started)):
                     # worker i's input to this segment is read from the stacks of
                     # boundaries kept, and the cotangent of that input is written
                     # over it: after the loop the stack holds what the segment
@@ -441,16 +463,16 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                             ) if k else xs_h[i]
                             y = ys_h[i] if k == last else None
                         if k == last:
-                            def apply(p, x):
-                                return seg.apply(p, x, y)
+                            def apply(p, x, *read):
+                                return seg.apply(p, x, y, *read)
                         elif seg.aux:
-                            def apply(p, x):
-                                return seg.apply(p, x)[0]
+                            def apply(p, x, *read):
+                                return seg.apply(p, x, *read)[0]
                         else:
                             apply = seg.apply
                         if k:
                             out, pullback, *aux = jax.vjp(
-                                apply, sub, x, has_aux=seg.aux and k == last)
+                                apply, sub, x, *read, has_aux=seg.aux and k == last)
                         else:  # the batch itself: nothing flows back into it
                             out, pullback = jax.vjp(lambda p: apply(p, x), sub)
                     with jax.named_scope("round.segment_bwd"), jax.named_scope("round.fwdbwd"):
@@ -463,7 +485,17 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                         with jax.named_scope("stream.rows"):
                             carry = dict(carry, rows=_write_row(
                                 carry["rows"], i, layout.offsets,
-                                layout.place(pulled[0], grad_dtype)))
+                                layout.place(pulled[0], grad_dtype), add=adds))
+                        if read:
+                            # what reaches another segment's parameters through
+                            # this one: into THEIR row of worker i, kept to their turn
+                            with jax.named_scope("stream.shared_rows"):
+                                carry["shared"] = {
+                                    key_: _write_row(
+                                        carry["shared"][key_], i, layouts[at_key[key_]].offsets,
+                                        layouts[at_key[key_]].place(pulled[2][key_], grad_dtype),
+                                        add=key_ in known)
+                                    for key_ in seg.reads}
                         with jax.named_scope("stream.boundary"):
                             if k:
                                 carry["io"] = [put(stack, leaf, i) for stack, leaf in zip(
@@ -476,13 +508,15 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                                         carry["head_aux"], aux[0])
                     return carry
 
-                carry = {"rows": jax.lax.empty(
-                    (h if formed[k] else n, *_folded((width,))), rows_dtypes[k])}
+                carry = {"rows": started.pop(seg.key) if seg.key in started else fresh_rows(k)}
+                if read:
+                    carry["shared"] = {key_: started[key_] if key_ in started
+                                       else fresh_rows(at_key[key_]) for key_ in seg.reads}
                 if k:
                     carry["io"] = io
                 if k == last:
                     loss0 = jax.eval_shape(
-                        lambda x, seg=seg, sub=sub: seg.apply(sub, x, ys_h[0]),
+                        lambda x, seg=seg, sub=sub, read=read: seg.apply(sub, x, ys_h[0], *read),
                         jax.tree_util.tree_unflatten(
                             wires[k - 1][0], [kept[0][j] for j in wires[k - 1][1]]))
                     if seg.aux:
@@ -491,6 +525,7 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                             lambda leaf: jnp.zeros((h, *leaf.shape), leaf.dtype), aux0)
                     carry["losses"] = jnp.zeros((h,), loss0.dtype)
                 carry = jax.lax.fori_loop(0, h, one_backward, carry)
+                started.update(carry.get("shared", {}))
                 losses = carry.get("losses", losses)
                 head_aux = carry.get("head_aux", head_aux)
                 if formed[k]:
@@ -529,12 +564,12 @@ def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtyp
                         # the segment before this one starts from the cotangents
                         # only once this one's leaves are updated: its rows are
                         # dead by then, and the next rows take their place
-                        done, flowing, next_sub = jax.lax.optimization_barrier(
-                            (done, carry["io"], params[segs[k - 1].key]))
+                        done, flowing, (sub, read) = jax.lax.optimization_barrier(
+                            (done, carry["io"],
+                             (params[segs[k - 1].key], segs[k - 1].read_of(params))))
                         for j, stack in zip(reads, flowing):
                             cots[j] = stack
                     new_params[seg.key], new_opt[seg.key] = done
-                    sub = next_sub if k else None
         with jax.named_scope("round.update"):
             metrics = {"honest_loss": jnp.mean(losses), "agg_grad_norm": jnp.sqrt(sum_sq)}
             if head_aux is not None:

@@ -178,7 +178,8 @@ def test_an_expert_no_token_reaches_gets_an_exactly_zero_gradient():
 def test_the_layer_takes_and_reports_what_it_did():
     assert list(inspect.signature(moe.held_experts_ffn).parameters) == [
         "x", "router_w", "w_up", "w_down", "shared_up", "shared_down", "first_held", "n_experts",
-        "top_k", "scale", "round_rows", "w_gate", "shared_gate", "score", "shared_weight"]
+        "top_k", "scale", "round_rows", "w_gate", "shared_gate", "score", "shared_weight",
+        "denominator_eps"]
     x, p = _layer(True)
     assert sorted(_ffn(x, p, None)[1]) == ["expert_rounds", "held_expert_tokens", "tokens_dropped"]
     assert "os.environ" not in inspect.getsource(moe) and "getenv" not in inspect.getsource(moe)

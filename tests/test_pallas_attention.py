@@ -224,6 +224,110 @@ def test_a_head_dim_that_is_no_whole_lanes_is_refused_by_the_wrapper():
                             kv_heads=2)
 
 
+def test_narrow_heads_that_do_not_fill_whole_lane_tiles_are_refused_by_the_wrapper():
+    with pytest.raises(ValueError, match="whole"):  # three key/value heads of 64: a tile and a half
+        pa.causal_attention(jnp.zeros((16, 6 * 64)), jnp.zeros((16, 3 * 64)),
+                            jnp.zeros((16, 3 * 64)), kv_heads=3)
+    with pytest.raises(ValueError, match="whole"):  # values at another width than the keys
+        pa.causal_attention(jnp.zeros((16, 4 * 64)), jnp.zeros((16, 2 * 64)),
+                            jnp.zeros((16, 2 * 128)), kv_heads=2)
+
+
+# -- heads narrower than a lane tile: two (four) to a tile, nothing padded -------
+
+# (query heads, key/value heads, head width): four query heads a key/value head
+# in one tile (a step of eight); the cell's 32 / 8 (four steps); one query head a
+# key/value head; four heads of 32 a tile
+NARROW = {"8of2@64": (8, 2, 64), "32of8@64": (32, 8, 64), "4of4@64": (4, 4, 64),
+          "8of4@32": (8, 4, 32)}
+NARROW_CASES = [(name, t) for name in NARROW for t in ((128, 300, 768) if name != "32of8@64"
+                                                       else (300,))]
+
+
+@pytest.mark.parametrize("name, t", NARROW_CASES)
+def test_kernels_at_narrow_heads_are_the_full_score_matrix_forward_and_gradient(name, t):
+    heads, kv, hd = NARROW[name]
+    keys = jax.random.split(jax.random.PRNGKey(t), 4)
+    q, probe = (jax.random.normal(k, (t, heads * hd)) for k in keys[:2])
+    k, v = (jax.random.normal(k_, (t, kv * hd)) for k_ in keys[2:])
+    out = pa.causal_attention(q, k, v, kv_heads=kv)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    want_out, want_lse = _full_scores(q, k, v, kv, hd, hd)
+    _close(out, want_out)
+    got = jax.grad(lambda *a: jnp.sum(pa.causal_attention(*a, kv_heads=kv) * probe),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_full_scores(*a, kv, hd, hd)[0] * probe),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        _close(g, w)
+    # one log-sum-exp a query row a head, a step's heads in the heads' own order
+    pack = 128 // hd
+    t_pad, block_q, block_k = pa._blocks(t, heads // kv * pack, backward=False)
+    _, lse = pa._causal_attention_fwd_call(
+        *pa._padded(t_pad, q, k, v), kv_heads=kv, scale=1 / math.sqrt(hd), block_q=block_q,
+        block_k=block_k, interpret=True)
+    assert lse.shape == (kv // pack, heads // kv * pack, t_pad)
+    _close(lse.reshape(kv, heads // kv, t_pad)[..., :t], want_lse, tol=1e-5)
+
+
+def test_narrow_heads_in_bfloat16_give_a_bfloat16_result_near_the_float32_one():
+    heads, kv, hd, t = 8, 2, 64, 300
+    keys = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = jax.random.normal(keys[0], (t, heads * hd))
+    k, v = (jax.random.normal(k_, (t, kv * hd)) for k_ in keys[1:])
+    want = pa.causal_attention(q, k, v, kv_heads=kv)
+    lo = [a.astype(jnp.bfloat16) for a in (q, k, v)]
+    got = pa.causal_attention(*lo, kv_heads=kv)
+    assert got.dtype == jnp.bfloat16
+    _close(got, want, tol=0.05)
+    grads = jax.grad(lambda *a: jnp.sum(pa.causal_attention(*a, kv_heads=kv).astype(jnp.float32)),
+                     argnums=(0, 1, 2))(*lo)
+    assert all(g.dtype == jnp.bfloat16 for g in grads)
+    assert all(bool(jnp.all(jnp.isfinite(g.astype(jnp.float32)))) for g in grads)
+
+
+@pytest.mark.parametrize("t", [21, 300, 768])
+def test_lfm2_attention_by_the_kernels_is_the_map_route_and_the_reference(monkeypatch, t):
+    """Head width 64, per-head norms and the rotary turn in front: the
+    kernel route against the ``lax.map`` route and the benchmark's
+    reference, the output and the gradient of every weight and of the
+    input."""
+    from byzpy_tpu.models import lfm2_moe
+    from chipbench import reference_lfm2_moe
+
+    cfg = lfm2_moe.Lfm2MoeConfig(hidden_size=256, num_attention_heads=4, num_key_value_heads=2,
+                                 query_block=64)
+    keys = jax.random.split(jax.random.PRNGKey(t), 8)
+    p = {"w_q": jax.random.normal(keys[0], (256, 256)) / 16,
+         "w_k": jax.random.normal(keys[1], (256, 128)) / 16,
+         "w_v": jax.random.normal(keys[2], (256, 128)) / 16,
+         "w_o": jax.random.normal(keys[3], (256, 256)) / 16,
+         "q_norm_scale": jax.random.uniform(keys[4], (64,), minval=1.0, maxval=3.0),
+         "k_norm_scale": jax.random.uniform(keys[5], (64,), minval=1.0, maxval=3.0)}
+    x, probe = jax.random.normal(keys[6], (t, 256)), jax.random.normal(keys[7], (t, 256))
+    arch = {"num_attention_heads": 4, "num_key_value_heads": 2, "norm_eps": cfg.norm_eps,
+            "rope_theta": cfg.rope_theta}
+    routes = {}
+    for serves in (True, False):
+        monkeypatch.setattr(lfm2_moe, "causal_attention_serves", lambda x_, hd: serves)
+        routes[serves] = _value_and_grads(lambda p_, x_: lfm2_moe.gqa_attention(p_, x_, cfg),
+                                          p, x, probe)
+    want = _value_and_grads(lambda p_, x_: reference_lfm2_moe.attention_full(p_, x_, arch),
+                            p, x, probe)
+    for got in routes.values():
+        for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+            _close(g, w, tol=1e-4)
+    calls = str(jax.make_jaxpr(jax.grad(
+        lambda p_, x_: jnp.sum(lfm2_moe.gqa_attention(p_, x_, cfg))))(p, x)).count("pallas_call")
+    assert calls == 0  # the gate's last answer was no
+    monkeypatch.setattr(lfm2_moe, "causal_attention_serves", lambda x_, hd: True)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p_, x_: jnp.sum(lfm2_moe.gqa_attention(p_, x_, cfg))))(p, x))
+    # the forward and the backward's two, and no pad of a head to 128 in front
+    assert text.count("pallas_call") == 3
+    assert f"f32[{t},4,128]" not in text and f"f32[{t},2,128]" not in text
+
+
 # -- through gqa_attention: the kernel route, the map route, the reference ----
 
 
@@ -329,14 +433,17 @@ def _sds(shape, dtype=jnp.float32):
 @pytest.mark.parametrize("head_dim", [128, 256, 8, 64, 192])
 def test_gate_table(monkeypatch, platform, dtype, head_dim):
     monkeypatch.setattr(pk, "_on_tpu", lambda: platform == "tpu")
-    want = platform == "tpu" and dtype in ("float32", "bfloat16") and head_dim % 128 == 0
+    # whole lanes, or (PR 46) a head that lies two or four to a lane tile
+    want = (platform == "tpu" and dtype in ("float32", "bfloat16")
+            and (head_dim % 128 == 0 or head_dim in (64, 32)))
     with jax.enable_x64(dtype == "float64"):
         assert pa.causal_attention_serves(_sds((4096, 2688), jnp.dtype(dtype)), head_dim) is want
 
 
 @pytest.mark.parametrize("head_dim, v_head_dim, want", [
     (256, 128, True), (128, 256, True), (256, None, True), (192, 128, False), (256, 64, False),
-    (256, 192, False)])
+    (256, 192, False), (64, None, True), (64, 64, True), (64, 128, False), (128, 64, False),
+    (32, 32, True), (96, 96, False)])
 def test_gate_asks_both_widths(monkeypatch, head_dim, v_head_dim, want):
     monkeypatch.setattr(pk, "_on_tpu", lambda: True)
     x = _sds((1024, 3584))
@@ -386,7 +493,7 @@ def test_on_a_tpu_gqa_attention_is_the_kernels_whatever_the_length(monkeypatch, 
 
 
 @pytest.mark.parametrize("platform, head_dim, dtype", [
-    ("cpu", 128, jnp.float32), ("tpu", 8, jnp.float32), ("tpu", 64, jnp.bfloat16),
+    ("cpu", 128, jnp.float32), ("tpu", 8, jnp.float32), ("tpu", 96, jnp.bfloat16),
     ("tpu", 128, jnp.float16)])
 def test_elsewhere_gqa_attention_is_the_map_over_query_blocks(monkeypatch, platform, head_dim,
                                                               dtype):
@@ -463,7 +570,7 @@ def test_off_a_tpu_gqa_attention_lowers_to_the_text_it_had(case):
 
 
 @pytest.mark.parametrize("backward", [False, True])
-@pytest.mark.parametrize("per", [16, 1, 2])
+@pytest.mark.parametrize("per", [16, 1, 2, 8])
 @pytest.mark.parametrize("t", [1, 21, 128, 129, 300, 512, 768, 1024, 2048, 4096, 4097, 65536])
 def test_blocks_divide_the_padded_length(t, per, backward):
     t_pad, block_q, block_k = pa._blocks(t, per, backward=backward)
@@ -479,7 +586,10 @@ def test_blocks_divide_the_padded_length(t, per, backward):
     if t == 4096:
         # sixteen heads a group (Nemotron, PR 33): 256 x 1024 forward, 256 x 512 backward;
         # one head a group (latent attention, PR 34): 1024 x 1024 in all three kernels
-        want = {16: (256, 512 if backward else 1024), 1: (1024, 1024), 2: (1024, 1024)}[per]
+        # eight heads a step: eight of 256 a key/value head (PR 39), or two key/value
+        # heads of 64 in one lane tile with four query heads each (PR 46)
+        want = {16: (256, 512 if backward else 1024), 1: (1024, 1024), 2: (1024, 1024),
+                8: (512, 512 if backward else 1024)}[per]
         assert (block_q, block_k) == want
 
 

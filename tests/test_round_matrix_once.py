@@ -778,6 +778,24 @@ def _write_tpu_texts(out_dir):
               encoding="utf-8") as fh:
         fh.write(text)
 
+    # and at heads NARROWER than a lane tile (32 query / 8 key-value heads of 64,
+    # per-head norms and the rotary turn in front, the LFM2 cell's heads):
+    # nothing padded, two key/value heads a tile
+    from byzpy_tpu.models import lfm2_moe
+
+    narrow = lfm2_moe.Lfm2MoeConfig()
+    shapes = jax.eval_shape(lambda: lfm2_moe.init_params(narrow)["seg02_attn_moe"])
+    for dtype in ("float32", "bfloat16"):
+        text = jax.jit(jax.value_and_grad(
+            lambda p, xs: jnp.sum(jax.vmap(lambda s: lfm2_moe.gqa_attention(p, s, narrow))(xs)
+                                  .astype(jnp.float32)), argnums=(0, 1))).lower(
+            described(shapes), jax.ShapeDtypeStruct(
+                (1, ATTENTION_TOKENS, narrow.hidden_size), jnp.dtype(dtype), sharding=one_chip)
+        ).compile().as_text()
+        with open(os.path.join(out_dir, f"attention_64_{dtype}.hlo.txt"), "w",
+                  encoding="utf-8") as fh:
+            fh.write(text)
+
     # the held experts' layer, value and gradient, at the three language cells'
     # tokens, picks, held experts, round and width (the experts' own width cut
     # to 128: the read-back kernel sees none of it)
@@ -826,6 +844,7 @@ def tpu_texts(tmp_path_factory):
     texts = {}
     for name in [*FOLDED_ROUNDS, "streamed_update", "attention_float32", "attention_bfloat16",
                  "mla_attention_float32", "mla_attention_192_128_float32",
+                 "attention_64_float32", "attention_64_bfloat16",
                  *(f"experts_{cell}" for cell in EXPERT_CELLS)]:
         with open(os.path.join(out_dir, name + ".hlo.txt"), encoding="utf-8") as fh:
             texts[name] = fh.read()
@@ -1017,6 +1036,30 @@ def test_on_the_tpu_latent_attention_of_192_and_128_pads_its_keys_and_never_its_
     backward = calls["causal_attention_dkv"].partition(" custom-call(")[0]
     assert f"f32[{t},8192]" in backward and f"f32[{t},4096]" in backward  # dk, dv
     assert f"f32[32,1,{t}]" in forward.partition(" custom-call(")[0]
+    reader = _benchmark_reader("attention_kernel_calls.train")
+    assert reader.read(SimpleNamespace(outcome={"compiled_text": text})) == 3
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_on_the_tpu_heads_of_64_are_the_same_three_kernels_two_heads_a_lane_tile(tpu_texts,
+                                                                                 dtype):
+    """LFM2's 32 query / 8 key-value heads of 64: Mosaic takes
+    all three kernels; q goes in as (T, 2048) and k, v as (T, 512), as the
+    projections leave them (no head padded to 128: no (T, 4096) or (T, 1024)
+    operand); a grid step is the two key/value heads of one lane tile with
+    their eight query heads, so the forward's log-sum-exp is (4, 8, T)."""
+    text = tpu_texts[f"attention_64_{dtype}"]
+    calls = _attention_calls(text)
+    assert sorted(calls) == ["causal_attention_dkv", "causal_attention_dq",
+                             "causal_attention_fwd"]
+    t, kind = ATTENTION_TOKENS, {"float32": "f32", "bfloat16": "bf16"}[dtype]
+    forward = calls["causal_attention_fwd"]
+    assert forward.count(f"{kind}[{t},2048]") >= 2 and forward.count(f"{kind}[{t},512]") >= 2
+    for line in calls.values():
+        assert f"{kind}[{t},4096]" not in line and f"{kind}[{t},1024]" not in line
+    assert f"f32[4,8,{t}]" in forward.partition(" custom-call(")[0]
+    backward = calls["causal_attention_dkv"].partition(" custom-call(")[0]
+    assert backward.count(f"{kind}[{t},512]") == 2  # dk, dv
     reader = _benchmark_reader("attention_kernel_calls.train")
     assert reader.read(SimpleNamespace(outcome={"compiled_text": text})) == 3
 
