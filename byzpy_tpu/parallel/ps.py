@@ -7,11 +7,12 @@ through pipes/shm, aggregate, fan the update back out
 round collapses into a single compiled step, which
 :func:`build_ps_train_step` picks among three (its docstring is the table):
 
-* per-node gradients: on a mesh data is sharded ``P("nodes", ...)`` and a
-  ``vmap`` over the node axis computes every node's gradient in parallel,
-  each on its own chip (:func:`_mesh_train_step`); on one device the honest
-  nodes' only, one after another (:func:`_one_device_train_step`; segment
-  by segment for a model that is a chain: :func:`_streamed_train_step`);
+* per-node gradients: on a mesh data is sharded ``P("nodes", ...)`` and
+  every chip computes the gradients of the nodes it holds, one after
+  another, in lockstep with the other chips (:func:`_mesh_train_step`); on
+  one device the honest nodes' only, one after another
+  (:func:`_one_device_train_step`; segment by segment for a model that is
+  a chain: :func:`_streamed_train_step`);
 * byzantine behavior: honest rows are a static slice of the stacked
   gradient matrix; the attack is a pure function of them writing the
   byzantine rows (SURVEY §7e — functional masking instead of separate
@@ -715,12 +716,23 @@ def _mesh_train_step(bundle, aggregate, cfg, mesh, *, attack, pre_aggregate, opt
     chips, a segmented bundle's too (its ``loss_fn`` is the chain's).
 
     Batches are constrained to ``P("nodes", ...)`` and all n workers'
-    gradients are computed under ``vmap``: the node axis carries every
-    worker, a byzantine worker's chip runs beside the others (skipping it
-    frees no time) and h need not divide the axis. Rows are ``d`` wide
-    and flat, in ``ravel_pytree``'s order (the note at ``layout`` below has
-    the one exception), cross the wire so, and the byzantine ones are
-    selected into the matrix in one pass (:func:`_select_byzantine_rows`). The matrix then transposes to feature
+    gradients are computed: the node axis carries every worker, a
+    byzantine worker's chip runs in lockstep with the others (skipping it
+    frees no time) and h need not divide the axis. Where the node axis
+    divides n, each chip runs the ``n / k`` workers it holds one after
+    another (a ``shard_map`` over the node axis around a ``lax.map``,
+    whose loop writes row i of the chip's ``(n / k, d)`` block; any
+    further mesh axis, a worker's batch sharded over it, is left to the
+    partitioner): side by side under ``vmap`` they cost a convolutional
+    model about twice as much a worker (:func:`_one_device_train_step`
+    says why). Where it does not, no chip holds whole workers and the
+    partitioner is handed a ``vmap`` over all n. The choice is read off
+    the mesh's shape and n alone.
+
+    Rows are ``d`` wide and flat, in ``ravel_pytree``'s order (the note at
+    ``layout`` below has the one exception), cross the wire so, and the
+    byzantine ones are selected into the matrix in one pass
+    (:func:`_select_byzantine_rows`). The matrix then transposes to feature
     sharding, is padded to the sharded update's grid where that is on, and
     ``pre_aggregate`` / ``aggregate`` run chip-local per coordinate.
 
@@ -764,6 +776,7 @@ def _mesh_train_step(bundle, aggregate, cfg, mesh, *, attack, pre_aggregate, opt
     grad_of = jax.value_and_grad(bundle.loss_fn)
     n, h, b = cfg.n_nodes, cfg.n_honest, cfg.n_byzantine
     axis = node_axis(mesh)
+    chips = mesh.shape[axis]  # along the node axis: each holds n / chips workers
     # extra mesh axes join in: per-node batches shard over the FIRST
     # extra axis (intra-node data parallelism — XLA psums the
     # batch-mean gradient automatically), and the aggregation matrix
@@ -851,16 +864,32 @@ def _mesh_train_step(bundle, aggregate, cfg, mesh, *, attack, pre_aggregate, opt
             xs = jax.lax.with_sharding_constraint(xs, node_spec)
             ys = jax.lax.with_sharding_constraint(ys, node_spec)
 
-            # Every node's forward/backward runs in parallel across
-            # the mesh: vmap over the node axis of node-sharded data
-            # with replicated params.
             def per_node_row(params, x, y):
                 loss, g = grad_of(params, x, y)
                 return loss, layout.ravel(g, grad_dtype)
 
-            losses, grads = jax.vmap(per_node_row, in_axes=(None, 0, 0))(
-                params, xs, ys
-            )
+            def chip_rows(params, xs, ys):
+                # one chip's block of the node axis: the workers it holds one
+                # after another, row i of the block written as it is made
+                return jax.lax.map(lambda held: per_node_row(params, *held), (xs, ys))
+
+            if n % chips == 0:
+                # Every chip runs the same loop over the workers it holds,
+                # in lockstep with the others; any further mesh axis (a
+                # worker's batch sharded over it) is left to the partitioner.
+                # (check_vma off: nothing in the body crosses chips, and
+                # tracking what varies over the axis through every op of the
+                # model is paid in each run's trace.)
+                losses, grads = jax.shard_map(
+                    chip_rows, mesh=mesh, in_specs=(P(), P(axis), P(axis)),
+                    out_specs=(P(axis), P(axis)), axis_names={axis},
+                    check_vma=False)(params, xs, ys)
+            else:
+                # the node axis does not divide n: no chip holds whole
+                # workers, the partitioner splits a vmap over all n
+                losses, grads = jax.vmap(per_node_row, in_axes=(None, 0, 0))(
+                    params, xs, ys
+                )
         if comm.enabled:
             # Compressed fabric: every node's RAW gradient row crosses the
             # wire encoded (exactly what a deployment ships — byzantine
@@ -951,6 +980,10 @@ def _mesh_train_step(bundle, aggregate, cfg, mesh, *, attack, pre_aggregate, opt
                 opt_state = (new_flat, inner)
             else:
                 params, opt_state = _update_leaves(opt, layout, agg_flat, opt_state, params)
+                if n % chips == 0:
+                    # the loop reads the parameters replicated: handed back
+                    # any other way, the next call is another program
+                    params = jax.lax.with_sharding_constraint(params, repl_sharding)
             metrics = {
                 "honest_loss": jnp.mean(losses[:h]),
                 "agg_grad_norm": agg_norm,

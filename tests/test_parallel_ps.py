@@ -19,10 +19,12 @@ from byzpy_tpu.ops import attack_ops, coordinatewise, robust
 from byzpy_tpu.parallel import (
     PSStepConfig,
     build_ps_train_step,
+    grid_mesh,
     jit_ps_train_step,
     node_mesh,
 )
-from byzpy_tpu.parallel.ps import default_optimizer
+from byzpy_tpu.parallel.ps import ShardedUpdateConfig, default_optimizer
+from byzpy_tpu.parallel.quantization import CommPrecision
 
 N_NODES = 8
 N_BYZ = 2
@@ -141,6 +143,97 @@ def test_ps_step_2d_grid_mesh_matches_single_device(setup):
     np.testing.assert_allclose(
         float(m2["honest_loss"]), float(m1["honest_loss"]), rtol=1e-4
     )
+
+
+def _flat(tree):
+    return np.concatenate([np.ravel(leaf) for leaf in jax.tree_util.tree_leaves(tree)])
+
+
+def _three_steps(step, params, opt, xs, ys):
+    """Flat parameters after three steps, and every step's metrics."""
+    seen = []
+    for i in range(3):
+        params, opt, metrics = step(params, opt, xs, ys, jax.random.PRNGKey(i))
+        seen.append({name: float(value) for name, value in metrics.items()})
+    return _flat(params), seen
+
+
+# case: (workers, the mesh's shape). On a mesh a chip runs the workers it holds
+# one after another where the node axis divides n, and the partitioner is
+# handed a ``vmap`` over all n where it does not; a grid's second axis splits
+# each worker's batch under either.
+MESH_SHAPES = {
+    "two_a_chip": (8, (4,)),
+    "one_a_chip": (8, (8,)),
+    "axis_does_not_divide": (6, (4,)),
+    "eight_on_three": (8, (3,)),
+    "grid_4x2": (4, (4, 2)),
+    "grid_2x2_two_a_chip": (4, (2, 2)),
+    "grid_does_not_divide": (6, (4, 2)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MESH_SHAPES))
+def test_three_mesh_steps_give_the_single_device_rounds_values(setup, case):
+    n, shape = MESH_SHAPES[case]
+    bundle, xs, ys = setup
+    xs, ys = xs[:n], ys[:n]
+    cfg = PSStepConfig(n_nodes=n, n_byzantine=1)
+    aggregate = partial(robust.trimmed_mean, f=1)
+    devices = jax.devices()[:int(np.prod(shape))]
+    mesh = node_mesh(shape[0], devices=devices) if len(shape) == 1 else grid_mesh(
+        *shape, devices=devices)
+    one, opt1 = jit_ps_train_step(bundle, aggregate, cfg, attack=_attack, donate=False)
+    many, opt_m = jit_ps_train_step(
+        bundle, aggregate, cfg, attack=_attack, mesh=mesh, donate=False)
+    want, want_metrics = _three_steps(one, bundle.params, opt1, xs, ys)
+    got, got_metrics = _three_steps(many, bundle.params, opt_m, xs, ys)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    for a, b in zip(got_metrics, want_metrics):
+        assert a == pytest.approx(b, rel=1e-4)
+
+
+_S4_EF = CommPrecision(mode="s4", error_feedback=True)
+FABRICS = {
+    "update_sharded": dict(sharded_update="on"),
+    "update_replicated": dict(sharded_update="off"),
+    "transpose_s4_ef": dict(comm_precision=_S4_EF),
+    "transpose_int8_update_off": dict(comm_precision="int8", sharded_update="off"),
+    "transpose_and_gather_s4_ef": dict(comm_precision=_S4_EF, sharded_update=ShardedUpdateConfig(
+        mode="on", param_gather_precision=_S4_EF)),
+}
+
+
+@pytest.mark.parametrize("fabric", sorted(FABRICS))
+def test_the_loops_rows_cross_every_fabric_as_the_vmapped_rows_do(setup, fabric):
+    """What follows ``round.fwdbwd`` (the compressed transpose and its
+    residual, the sharded update, the gather) is handed the same ``(n, d)``
+    node-sharded rows by the per-chip loop as by the ``vmap`` over all n:
+    three steps of 8 workers on 4 chips against the parent's program, which
+    a node axis that does not divide n still gets (8 on 3). Error feedback
+    keeps an ``(n, d)`` residual on the node axis, which has to divide n:
+    there the other side is a worker a chip (8 on 8), whose loop of one trip
+    is no loop once compiled. (A compressed fabric rounds: a float's last bit
+    can move one code, so the values are held as the mesh shapes' are.)"""
+    bundle, xs, ys = setup
+    cfg = PSStepConfig(n_nodes=N_NODES, n_byzantine=N_BYZ)
+    aggregate = partial(robust.trimmed_mean, f=N_BYZ)
+    error_feedback = "ef" in fabric
+
+    def three_steps_on(chips):
+        mesh = node_mesh(chips, devices=jax.devices()[:chips])
+        step, opt = jit_ps_train_step(
+            bundle, aggregate, cfg, attack=_attack, mesh=mesh, donate=False, **FABRICS[fabric])
+        text = step.lower(bundle.params, opt, xs, ys, jax.random.PRNGKey(0)).as_text()
+        assert ("stablehlo.while" in text) == (N_NODES % chips == 0)
+        return _three_steps(step, bundle.params, opt, xs, ys)
+
+    looped, looped_metrics = three_steps_on(4)
+    other, other_metrics = three_steps_on(8 if error_feedback else 3)
+    np.testing.assert_allclose(looped, other, rtol=2e-4, atol=2e-5)
+    assert len(looped_metrics) == len(other_metrics) == 3
+    for a, b in zip(looped_metrics, other_metrics):
+        assert set(a) == set(b) and a == pytest.approx(b, rel=1e-4)
 
 
 class _ActorHonestNode:

@@ -9,8 +9,10 @@ convolutional model's per-worker gradients cost far less one worker at a
 time than vmapped, ``docs/performance.md``), and ``_set_byzantine_rows`` makes
 the ``(n, .)`` matrix from the ``(h, .)`` stack. The results are the same
 function of the honest inputs as a round that computes all n rows at once
-and overwrites b of them; on a mesh the step keeps all n under ``vmap``
-(each byzantine worker's chip runs beside the others).
+and overwrites b of them. On a mesh the step computes all n rows (a
+byzantine worker's chip runs in lockstep with the others: skipping it frees
+no time), each chip the rows of the workers it holds one after another
+(section (d); ``vmap`` over all n only where the node axis does not divide n).
 """
 
 from __future__ import annotations
@@ -24,7 +26,7 @@ import numpy as np
 import optax
 import pytest
 
-from byzpy_tpu.models.nets import mnist_mlp
+from byzpy_tpu.models.nets import mnist_cnn, mnist_mlp
 from byzpy_tpu.ops import attack_ops, robust
 from byzpy_tpu.parallel.mesh import node_mesh, replicated
 from byzpy_tpu.parallel.ps import PSStepConfig, build_ps_train_step, jit_ps_train_step
@@ -269,31 +271,49 @@ def test_the_aggregate_is_handed_n_rows_made_from_an_h_row_stack(bundle, batches
                                rtol=2e-6, atol=1e-8)
 
 
-# -- (d) on a mesh the step computes all n workers' rows ----------------------
+# -- (d) on a mesh the step computes all n workers' rows, a chip's own in a loop --
 
 
 @pytest.fixture(scope="module")
 def mesh():
     if len(jax.devices()) < 4:
         pytest.skip("needs four (virtual) devices")
-    return node_mesh(4)
+    return node_mesh(4, devices=jax.devices()[:4])
+
+
+def _fwdbwd_loops(compiled_text):
+    """Trip counts (the CPU backend writes them on the line) of the loops
+    the compiled step runs under ``round.fwdbwd``."""
+    return [int(n) for line in compiled_text.splitlines()
+            if " while(" in line and "round.fwdbwd" in line
+            for n in re.findall(r'"known_trip_count":\{"n":"(\d+)"', line)]
 
 
 @pytest.mark.parametrize("sharded_update", ["off", "on"])
 def test_on_a_mesh_every_workers_row_is_computed(bundle, batches, mesh, sharded_update):
     cfg = _cfg(2)
+    chips = mesh.shape["nodes"]
     step, opt_state = jit_ps_train_step(
         bundle, AGGREGATORS["trimmed_mean"], cfg, attack=_sign_flip, mesh=mesh,
         donate=False, sharded_update=sharded_update)
     params = jax.device_put(bundle.params, replicated(mesh))
     xs, ys, keys = batches
     args = (params, opt_state, xs[0], ys[0], keys[0])
+    # all n rows: the batches are handed over whole, none is cut to the honest
     assert _slices_of_the_batches(step, args) == []
-    text = step.lower(*args).as_text()
+    lowered = step.lower(*args)
+    text = lowered.as_text()
     counts = _sample_counts(text)
     assert (N, IMAGES) in counts and (cfg.n_honest, IMAGES) not in counts
-    # vmapped over the node axis, not looped
-    assert f"tensor<{N}x{IMAGES}x784xf32>" in text and "stablehlo.while" not in text
+    d = sum(leaf.size for leaf in jax.tree_util.tree_leaves(bundle.params))
+    assert f"tensor<{N}x{d}xf32>" in text  # the matrix the loops' blocks make up
+    # a chip's block of the node axis, and a loop over it whose body holds ONE
+    # worker's samples: no merged batch, of all n or of a chip's n / k
+    assert (N // chips, IMAGES) in counts and "stablehlo.while" in text
+    assert f"tensor<{IMAGES}x784xf32>" in text
+    for merged in (N, N // chips):
+        assert f"tensor<{merged}x{IMAGES}x784xf32>" not in text
+    assert _fwdbwd_loops(lowered.compile().as_text()) == [N // chips]
     ref_step, ref_opt = _all_rows_round(bundle, cfg, AGGREGATORS["trimmed_mean"], _sign_flip)
     got = _drive(step, params, opt_state, batches)
     want = _drive(ref_step, bundle.params, ref_opt, batches)
@@ -301,3 +321,38 @@ def test_on_a_mesh_every_workers_row_is_computed(bundle, batches, mesh, sharded_
     # both rounds have, the parameters and the metrics
     for a, w in zip((got[0], got[2], got[3]), (want[0], want[2], want[3])):
         np.testing.assert_allclose(a, w, rtol=1e-5, atol=1e-7)
+
+
+def _grouped_convolutions(compiled_text):
+    return [line for line in compiled_text.splitlines() if " convolution(" in line
+            and any(int(g) > 1 for g in re.findall(r"(?:feature|batch)_group_count=(\d+)", line))]
+
+
+@pytest.mark.parametrize("n, loops", [(8, [2]), (4, []), (6, [])],
+                         ids=["two_a_chip", "one_a_chip", "axis_does_not_divide"])
+def test_a_chips_convolutions_are_one_workers(mesh, n, loops):
+    """Under ``vmap`` a convolution's per-worker weight gradient is ONE
+    convolution grouped over the worker axis, and the forward's batch the
+    workers' merged; the loop's body holds plain convolutions of one worker's
+    samples (a loop of one trip is no loop once compiled). The choice is read
+    off the mesh and ``n`` alone: where the node axis does not divide ``n``
+    the step is the ``vmap`` over all n."""
+    cnn = mnist_cnn(0)
+    looped = n % mesh.shape["nodes"] == 0
+    cfg = PSStepConfig(n_nodes=n, n_byzantine=1)
+    step, opt_state = jit_ps_train_step(
+        cnn, partial(robust.trimmed_mean, f=1), cfg, attack=_sign_flip, mesh=mesh, donate=False)
+    args = (jax.device_put(cnn.params, replicated(mesh)), opt_state,
+            jnp.zeros((n, IMAGES, 28, 28, 1), jnp.float32), jnp.zeros((n, IMAGES), jnp.int32),
+            jax.random.PRNGKey(0))
+    text = step.lower(*args).compile().as_text()
+    convolutions = [line for line in text.splitlines() if " convolution(" in line]
+    assert convolutions
+    assert _fwdbwd_loops(text) == loops
+    assert bool(_grouped_convolutions(text)) == (not looped)
+    if looped:
+        # forward and input-gradient convolutions carry the samples in their
+        # first dimension: IMAGES of them, whatever the chip holds
+        assert {int(b) for line in convolutions
+                for b in re.findall(r"= f32\[(\d+),\d+,\d+,\d+\]\S* convolution\(", line)
+                if "b01f_01" in line and "->b01f" in line} == {IMAGES}
