@@ -89,12 +89,28 @@ class ModelBundle:
     :func:`chain_loss` of them. A round may then take gradients, aggregate
     and update one segment at a time
     (:func:`~byzpy_tpu.parallel.ps.build_ps_train_step`); everything else
-    uses ``loss_fn`` and never looks."""
+    uses ``loss_fn`` and never looks.
+
+    ``example_mean_loss``, where a bundle declares it, says that
+    ``loss_fn(params, x, y)`` is the MEAN over the examples of the batch of
+    a term that depends on that example alone: no layer mixes the examples
+    of a batch (no BatchNorm in training mode, no statistic, contrast or
+    routing capacity taken over the batch) and the loss weighs them equally.
+    Then, and only then, the loss and gradient of a batch are the means of
+    the losses and gradients of equal parts of it, and an ``(n, d)`` round
+    may take a worker's batch in passes that fit the chip's fast memory
+    (``parallel/ps.py: _worker_passes``; ``docs/performance.md``, "A batch in
+    passes"). A fact about model and loss together, so whoever writes both
+    declares it: :func:`~byzpy_tpu.models.nets.make_bundle` does for its own
+    loss over its own modules. Not declared (the default, a caller's own
+    ``loss_fn``, every language bundle: a packed sequence is one example) a
+    batch is never split."""
 
     apply_fn: Callable[[Any, jnp.ndarray], jnp.ndarray]
     params: Any
     loss_fn: Optional[Callable[[Any, jnp.ndarray, jnp.ndarray], jnp.ndarray]] = None
     segments: Optional[Tuple[Segment, ...]] = None
+    example_mean_loss: bool = False
 
     def __post_init__(self) -> None:
         if self.segments is not None:

@@ -165,10 +165,25 @@ def make_bundle(
     seed: int = 0,
     loss_fn: Callable | None = None,
 ) -> ModelBundle:
-    """Initialize ``model`` and wrap it as a :class:`ModelBundle`."""
+    """Initialize ``model`` and wrap it as a :class:`ModelBundle`. With the
+    default loss (the mean cross-entropy over the batch) and a model of this
+    file that keeps a batch's examples apart, the bundle declares
+    ``example_mean_loss``; a caller's own ``loss_fn`` or module does not."""
     rng = jax.random.PRNGKey(seed)
     params = model.init(rng, jnp.zeros(tuple(input_shape), jnp.float32))
-    return ModelBundle(apply_fn=model.apply, params=params, loss_fn=loss_fn)
+    return ModelBundle(apply_fn=model.apply, params=params, loss_fn=loss_fn,
+                       example_mean_loss=loss_fn is None and _keeps_examples_apart(model))
+
+
+def _keeps_examples_apart(model: nn.Module) -> bool:
+    """Whether no layer of ``model`` mixes the examples of a batch, as far
+    as this file can know: true of its own modules as written (``Dense``,
+    ``Conv``, pooling within an image, ``GroupNorm``: the statistics of ONE
+    example's channel groups), not known of a caller's module, and not of a
+    ResNet handed another ``norm``."""
+    if isinstance(model, ResNet):
+        return model.norm is nn.GroupNorm
+    return isinstance(model, (MLP, SmallCNN))
 
 
 def mnist_mlp(seed: int = 0, hidden: int = 128) -> ModelBundle:

@@ -138,7 +138,8 @@ SPAN_PREFIXES: Tuple[str, ...] = ("chaos.",)
 #: innermost ``round.*`` of them all), ``model.*`` which part of the model
 #: (an op belongs to the LAST ``model.*`` of its path; ``model.mtp`` is an
 #: envelope around a whole block) and ``stream.*`` the round's own work
-#: inside ``round.fwdbwd``
+#: inside ``round.fwdbwd`` (``stream.passes``, in the ``(n, d)`` rounds: the
+#: loop over the passes of one worker's batch)
 SCOPES: FrozenSet[str] = frozenset(
     {
         "model.attention",
@@ -178,6 +179,7 @@ SCOPES: FrozenSet[str] = frozenset(
         "serving.ragged_scale",
         "serving.staleness_scale",
         "stream.boundary",
+        "stream.passes",
         "stream.rows",
         "stream.shared_rows",
     }

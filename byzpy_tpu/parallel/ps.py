@@ -256,6 +256,131 @@ def _update_leaves(opt, layout, agg, state, params):
     return optax.apply_updates(params, updates), state
 
 
+# -- a worker's loss and gradient, in passes that stay in fast memory ----------
+# Shared by the two (n, d) rounds. The streamed round is not a caller: a
+# language cell's worker holds one packed sequence.
+
+#: The share of a TPU's fast memory (VMEM; ``get_tpu_info``) that one pass's
+#: largest activation may take. Set from ``round.fwdbwd`` of a step of two
+#: workers of 512 images on ONE v5e (ResNet-18 in float32, a stage-1
+#: activation 256 KiB an image; 128 MiB of VMEM), passes of 512 / 256 / 128 /
+#: 64 images: PERF.md section 3 has the four readings. 128 images (32 MiB, a
+#: quarter) is what the share has to let through whole and 256 (a half) what
+#: it has to split.
+_PASS_BUDGET = 3 / 8
+
+
+def _fast_memory_bytes(device) -> Optional[int]:
+    """The fast memory (VMEM) of the TPU core a step is built for, and
+    ``None`` for a device that is no TPU: nothing is known of a cache there
+    that a pass could be sized for, and a step is then never split."""
+    if device.platform != "tpu":
+        return None
+    from jax.experimental.pallas import tpu as pltpu
+
+    with jax.default_device(device):  # whose kind get_tpu_info reads
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+
+
+def _default_device():
+    """The device a one-device step is built for: the one its caller's
+    ``jax.default_device`` names, else the process's first."""
+    dev = jax.config.jax_default_device
+    return jax.devices(dev)[0] if dev is None or isinstance(dev, str) else dev
+
+
+def _largest_activation_bytes(loss_fn, params, x, y) -> int:
+    """Bytes of the largest array the forward pass of ``loss_fn`` makes for
+    ONE example: one abstract trace of the forward at a batch of one (shapes
+    alone: nothing compiles, and the backward is not traced), every result
+    of every equation counted, those of the calls it holds too."""
+    def struct(a, lead=None):
+        return jax.ShapeDtypeStruct(a.shape if lead is None else (lead, *a.shape[1:]), a.dtype)
+
+    def largest(jaxpr):
+        sizes = [math.prod(v.aval.shape) * v.aval.dtype.itemsize
+                 for eqn in jaxpr.eqns for v in eqn.outvars if hasattr(v.aval, "shape")]
+        sizes += [largest(sub) for eqn in jaxpr.eqns
+                  for sub in jax.core.jaxprs_in_params(eqn.params)]
+        return max(sizes, default=0)
+
+    return largest(jax.make_jaxpr(loss_fn)(
+        jax.tree_util.tree_map(struct, params), struct(x, 1), struct(y, 1)).jaxpr)
+
+
+def _passes_for(batch: int, bytes_per_example: int, budget: int) -> int:
+    """The smallest divisor ``p`` of ``batch`` for which ``batch / p``
+    examples' largest activation fits ``budget`` bytes: passes are equal,
+    or the mean of their means is not the batch's mean. 1 where the whole
+    batch fits, and 1 where not even one example does (no divisor fits:
+    splitting buys nothing the rule can see)."""
+    for p in range(1, batch + 1):
+        if batch % p == 0 and (batch // p) * bytes_per_example <= budget:
+            return p
+    return 1
+
+
+def _one_worker_of(xs, ys):
+    """The shapes of one worker's batch of the stacked ``(n, B, ...)``
+    batches (shapes alone: an index into a traced array is an op)."""
+    return tuple(jax.ShapeDtypeStruct(a.shape[1:], a.dtype) for a in (xs, ys))
+
+
+def _worker_passes(bundle, params, x, y, device) -> int:
+    """In how many equal passes a worker's batch ``x, y`` (``(B, ...)``)
+    goes through the model: from the batch's shape, the bundle's declaration
+    and the device alone. 1 (the batch whole) unless the bundle SAYS that its
+    loss is a mean of per-example terms (``ModelBundle.example_mean_loss``:
+    only then is the gradient of the batch the mean of its parts'), the
+    device is a TPU, and the batch's largest activation does not fit
+    ``_PASS_BUDGET`` of its fast memory; then the fewest passes whose own
+    do (:func:`_passes_for`)."""
+    if not bundle.example_mean_loss:
+        return 1
+    fast = _fast_memory_bytes(device)
+    if fast is None:
+        return 1
+    return _passes_for(x.shape[0], _largest_activation_bytes(bundle.loss_fn, params, x, y),
+                       int(fast * _PASS_BUDGET))
+
+
+def _worker_loss_and_grad(grad_of, passes: int):
+    """``(params, x, y) -> (loss, gradient)`` of one worker, its batch
+    taken in ``passes`` equal passes. With one pass it IS ``grad_of``, the
+    very function: a program that does not split is the program it was,
+    traced at the depth it was. With more, a ``scan`` (one trace of the
+    model) over ``x.reshape(passes, B / passes, ...)`` that carries the
+    float32 gradient tree, adds each pass's gradient into it in place and
+    scales once by ``1 / passes``; the loss is the mean of the passes'
+    losses. The same sum in another order, exact to float32 summation
+    error, ONLY for a loss that is a mean over examples of per-example
+    terms: :func:`_worker_passes` is who says how many."""
+    if passes == 1:
+        return grad_of
+
+    def parts(a):
+        return a.reshape(passes, a.shape[0] // passes, *a.shape[1:])
+
+    def in_passes(params, x, y):
+        def one_pass(total, part):
+            loss, g = grad_of(params, *part)
+            return jax.tree_util.tree_map(
+                lambda t, leaf: t + leaf.astype(t.dtype), total, g), loss
+
+        # stream.passes: no round.* name, so round.fwdbwd stays the innermost
+        # round.* scope of everything the model computes
+        with jax.named_scope("stream.passes"):
+            total, losses = jax.lax.scan(
+                one_pass,
+                jax.tree_util.tree_map(lambda leaf: jnp.zeros(leaf.shape, jnp.float32), params),
+                (parts(x), parts(y)))
+            grads = jax.tree_util.tree_map(
+                lambda t, leaf: (t / passes).astype(leaf.dtype), total, params)
+            return jnp.mean(losses.astype(jnp.float32)).astype(losses.dtype), grads
+
+    return in_passes
+
+
 def _streamed_train_step(bundle, aggregate, cfg, *, attack, optimizer, grad_dtype, unstreamable):
     """:func:`build_ps_train_step` for a bundle that declares segments, on
     one device: the round whose working set is not ``(n, d)``, so that a
@@ -599,7 +724,10 @@ def _one_device_train_step(
     merged-batch convolutions, at about twice the cost a worker for
     ResNet-18 and none less for an MLP (``docs/performance.md``).
     Signature, shapes, state, metrics and values are those of a round that
-    computes all n rows and overwrites b of them.
+    computes all n rows and overwrites b of them. A worker's batch goes
+    through the model whole, or in equal passes where whole it would not
+    stay in the chip's fast memory (:func:`_worker_passes` says how many,
+    :func:`_worker_loss_and_grad` runs them).
 
     The loop carries the n-row stack, each row ``d_pad`` wide and folded
     wherever that is whole tiles (:func:`_one_device_layout`,
@@ -643,10 +771,12 @@ def _one_device_train_step(
             # emit nothing.
             xs_h, ys_h = xs[:h], ys[:h]
             loss0, _ = jax.eval_shape(lambda: grad_of(params, xs_h[0], ys_h[0]))
+            worker_grad = _worker_loss_and_grad(grad_of, _worker_passes(
+                bundle, params, *_one_worker_of(xs_h, ys_h), _default_device()))
 
             def one_worker(i, carry):
                 losses, grads = carry
-                loss, g = grad_of(params, xs_h[i], ys_h[i])
+                loss, g = worker_grad(params, xs_h[i], ys_h[i])
                 pieces = layout.place(g, grad_dtype)
                 losses = jax.lax.dynamic_update_index_in_dim(losses, loss, i, 0)
                 return losses, _write_row(grads, i, layout.offsets, pieces)
@@ -727,7 +857,12 @@ def _mesh_train_step(bundle, aggregate, cfg, mesh, *, attack, pre_aggregate, opt
     model about twice as much a worker (:func:`_one_device_train_step`
     says why). Where it does not, no chip holds whole workers and the
     partitioner is handed a ``vmap`` over all n. The choice is read off
-    the mesh's shape and n alone.
+    the mesh's shape and n alone. A worker of the loop takes its batch in
+    passes as on one device (:func:`_worker_passes`, asked for the mesh's
+    first device). On a mesh with a further axis the rule is handed the
+    worker's WHOLE batch, as the body of the ``shard_map`` sees it: a chip
+    then holds ``1 / axis`` of a pass, and the passes are more and smaller
+    than the chip needs (no cell runs such a mesh).
 
     Rows are ``d`` wide and flat, in ``ravel_pytree``'s order (the note at
     ``layout`` below has the one exception), cross the wire so, and the
@@ -864,8 +999,15 @@ def _mesh_train_step(bundle, aggregate, cfg, mesh, *, attack, pre_aggregate, opt
             xs = jax.lax.with_sharding_constraint(xs, node_spec)
             ys = jax.lax.with_sharding_constraint(ys, node_spec)
 
+            # the loop's workers alone take their batch in passes: the vmap
+            # over all n is left to the partitioner as it is
+            passes = _worker_passes(
+                bundle, params, *_one_worker_of(xs, ys), mesh.devices.flat[0]
+            ) if n % chips == 0 else 1
+            worker_grad = _worker_loss_and_grad(grad_of, passes)
+
             def per_node_row(params, x, y):
-                loss, g = grad_of(params, x, y)
+                loss, g = worker_grad(params, x, y)
                 return loss, layout.ravel(g, grad_dtype)
 
             def chip_rows(params, xs, ys):

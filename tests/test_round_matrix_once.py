@@ -725,6 +725,31 @@ def _write_tpu_texts(out_dir):
     with open(os.path.join(out_dir, "streamed_update.hlo.txt"), "w", encoding="utf-8") as fh:
         fh.write(text)
 
+    # a worker's batch in passes: the GroupNorm toy of test_worker_passes,
+    # declared, on the described chip, whose fast memory the rule reads
+    # itself (128 MiB); the budget cut so that a worker's 8 images (2 KiB
+    # of activation each) go through whole, in two passes and in four
+    import dataclasses
+
+    import test_worker_passes as passes
+    from byzpy_tpu.models.nets import make_bundle
+    from byzpy_tpu.parallel import ps
+
+    ps._default_device = lambda: topo.devices[0]
+    images = dataclasses.replace(
+        make_bundle(passes.GroupNormCNN(), (1, 8, 8, 3)), example_mean_loss=True)
+    for count in PASS_COUNTS:
+        ps._PASS_BUDGET = (8 // count) * 2048 / (128 << 20)
+        step, opt_state = build_ps_train_step(
+            images, AGGREGATORS["trimmed_mean"], CFG, attack=ATTACKS["sign_flip"])
+        text = jax.jit(step).lower(
+            described(images.params), described(opt_state),
+            jax.ShapeDtypeStruct((N, 8, 8, 8, 3), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((N, 8), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)).compile().as_text()
+        with open(os.path.join(out_dir, f"passes_{count}.hlo.txt"), "w", encoding="utf-8") as fh:
+            fh.write(text)
+
     # the block-causal attention kernels at the Nemotron cell's heads
     # (32 / 2 x 128), value and gradient through gqa_attention under vmap
     from byzpy_tpu.models import nemotron_h
@@ -822,6 +847,9 @@ EXPERT_CELLS = {"nemotron": (128, 6, 8, 1024, 2688), "glm": (64, 4, 8, 1024, 204
                 "qwen": (512, 10, 32, 320, 2048)}
 
 
+PASS_COUNTS = (1, 2, 4)  # in how many passes the toy worker's 8 images go
+
+
 @pytest.fixture(scope="module")
 def tpu_texts(tmp_path_factory):
     """``{round's name: its text}`` from :func:`_write_tpu_texts`, run once
@@ -845,7 +873,8 @@ def tpu_texts(tmp_path_factory):
     for name in [*FOLDED_ROUNDS, "streamed_update", "attention_float32", "attention_bfloat16",
                  "mla_attention_float32", "mla_attention_192_128_float32",
                  "attention_64_float32", "attention_64_bfloat16",
-                 *(f"experts_{cell}" for cell in EXPERT_CELLS)]:
+                 *(f"experts_{cell}" for cell in EXPERT_CELLS),
+                 *(f"passes_{count}" for count in PASS_COUNTS)]:
         with open(os.path.join(out_dir, name + ".hlo.txt"), encoding="utf-8") as fh:
             texts[name] = fh.read()
     return texts
@@ -1111,6 +1140,17 @@ def test_on_the_tpu_the_benchmarks_counter_reads_the_attention_kernels(tpu_texts
         outcome={"compiled_text": tpu_texts["attention_float32"]})) == 3
     assert reader.read(SimpleNamespace(
         outcome={"compiled_text": tpu_texts["trimmed_mean"]})) is None
+
+
+@pytest.mark.parametrize("count", PASS_COUNTS)
+def test_on_the_tpu_the_benchmarks_counter_reads_a_workers_passes(tpu_texts, count):
+    """The rule itself chose them (a declared bundle, the described chip's
+    128 MiB of fast memory, the budget cut to the toy's size), and the
+    TPU's text, which writes no trip count on a loop's line, says how many."""
+    text = tpu_texts[f"passes_{count}"]
+    assert _benchmark_reader("worker_passes.train").read(
+        SimpleNamespace(outcome={"compiled_text": text})) == count
+    assert ("stream.passes" in text) == (count > 1)
 
 
 # -- (v) the layout of a row ---------------------------------------------------
