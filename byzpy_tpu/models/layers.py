@@ -230,13 +230,15 @@ def _gated_short_conv_bwd(kept, d_out):
 gated_short_conv.defvjp(_gated_short_conv_fwd, _gated_short_conv_bwd)
 
 
-def blocked_causal_attention(q: Array, k: Array, v: Array, query_block: int) -> Array:
+def blocked_causal_attention(q: Array, k: Array, v: Array, query_block: int,
+                             window: Optional[int] = None) -> Array:
     """Causal softmax attention of one sequence, ``query_block`` queries at a
     time: ``q (T, kv, per, head_dim)`` (``per`` query heads read key/value
     head ``kv``), ``k (T, kv, head_dim)``, ``v (T, kv, head_dim)``; returns
-    ``(T, kv * per * head_dim)``. Each block is rematerialised in the
-    backward pass, so the score matrix alive at once is
-    ``(heads, query_block, T)``."""
+    ``(T, kv * per * head_dim)``. With ``window = W`` query ``i`` reads keys
+    ``j`` with ``0 <= i - j < W`` (the kernels' ``window``); ``None`` adds
+    no op. Each block is rematerialised in the backward pass, so the score
+    matrix alive at once is ``(heads, query_block, T)``."""
     t, kv, per, hd = q.shape
     block = min(query_block, t)
     pad = -t % block
@@ -247,7 +249,10 @@ def blocked_causal_attention(q: Array, k: Array, v: Array, query_block: int) -> 
     def one_block(args):
         qb, start = args
         scores = jnp.einsum("qgrd,kgd->grqk", qb, k).astype(jnp.float32) / math.sqrt(hd)
-        seen = (start + jnp.arange(block))[:, None] >= jnp.arange(t)[None, :]
+        position, key = (start + jnp.arange(block))[:, None], jnp.arange(t)[None, :]
+        seen = position >= key
+        if window is not None:
+            seen = seen & (position - key < window)
         probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
         return jnp.einsum("grqk,kgd->qgrd", probs.astype(q.dtype), v)
 

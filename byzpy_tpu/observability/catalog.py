@@ -193,7 +193,8 @@ SCOPE_PREFIXES: Tuple[str, ...] = ("segment.",)
 #: function's name without the underscore and the ``_call``. The name is
 #: the custom call's instruction name and a segment of its ``op_name``
 #: in the compiled text, so a reader finds the kernel after a refactor.
-#: Not every kernel is an aggregate: ``causal_attention_*`` are the
+#: Not every kernel is an aggregate: ``causal_attention_*`` and, where a
+#: call has a ``window``, ``window_attention_*`` are the
 #: model's (``ops/pallas_attention.py``, under ``model.attention``), and so
 #: is ``rows_to_tokens`` (``ops/pallas_rows_to_tokens.py``, under
 #: ``model.moe_experts``)
@@ -222,6 +223,9 @@ KERNELS: FrozenSet[str] = frozenset(
         "sorted_reduce_stream",
         "sorted_reduce_stream_attacked",
         "weighted_center_step",
+        "window_attention_dkv",
+        "window_attention_dq",
+        "window_attention_fwd",
     }
 )
 

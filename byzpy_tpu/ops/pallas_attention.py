@@ -1,7 +1,8 @@
 """Block-causal grouped-query attention of one sequence as Pallas TPU
 kernels: a forward kernel and the two kernels of its backward.
 
-``softmax(scale q k^T + causal mask) v`` for ``H`` query heads that share
+``softmax(scale q k^T + mask) v``, the mask causal (``j <= i``) or causal
+inside a sliding window (``0 <= i - j < W``), for ``H`` query heads that share
 ``kv_heads`` key/value heads, on the projections as they leave the matrix
 products: ``q (T, H * qk_dim)``, ``k (T, kv_heads * qk_dim)``,
 ``v (T, kv_heads * v_dim)``, the output ``(T, H * v_dim)``. Queries and keys
@@ -22,7 +23,12 @@ or repeated in HBM on the way in or out.
   (query block, key block) pairs AT OR UNDER the diagonal, listed in Python
   and handed to the index maps as prefetched scalars: a pair wholly above
   the diagonal is never visited, and the mask is computed only in the pairs
-  the diagonal crosses.
+  the diagonal crosses. With a ``window`` shorter than the sequence the pairs
+  wholly OLDER than the window are left out the same way, the window's edge is
+  masked only in the pairs it crosses, a kernel lowers one body for each
+  combination of the two masks that its pairs hold, and the calls carry names
+  of their own (``window_attention_fwd / _dq / _dkv``: the same bodies, another
+  count of operations). ``window=None`` is the program it always was.
 * Scores, probabilities, the running maximum, the running sum and the
   output accumulator live in VMEM (online softmax). What the forward writes
   to HBM is the output and ONE log-sum-exp a query row a head,
@@ -71,7 +77,7 @@ Array = jnp.ndarray
 _LANES = _pk._LANES
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
-_FIRST, _LAST, _MASKED = 1, 2, 4  # a pair's flags
+_FIRST, _LAST, _MASKED, _EDGED = 1, 2, 4, 8  # a pair's flags
 # rows of the folded query block that one pass of a step's loop handles:
 # the score tile alive at once is (_CHUNK_ROWS, block_k) float32
 _CHUNK_ROWS = 1024
@@ -122,7 +128,27 @@ _CHUNK_ROWS = 1024
 # as the kernels take the heads, 5.16 with q, k, v zero-padded to 128 a head
 # beforehand and 5.71 with the pads and the slice counted (the form to beat),
 # 28.1 through the `lax.map` route.
-# All four regimes: a key block meets up to _FOLDED_ROWS rows of queries (a
+# SEVEN query heads a group at head_dim 128 (28 / 4 heads, PR 49) at 8192
+# POSITIONS, causal | inside a window of 4096 (the same bodies; the windowed
+# call walks 60 of the causal 72 pairs at 512 x 1024, and three quarters of the
+# entries), host-clock ms a call: forward 3.93 | 3.35 at 512 x 1024 (the rule's),
+# 3.86 | 3.31 at 1024 x 1024 (the least: 7,168 folded rows, past _FOLDED_ROWS), 3.99
+# | 3.39 at 256 x 1024, 4.04 | 3.47 at 128 x 1024, 4.42 | 4.17 at 512 x 2048, 7.10 |
+# 5.69 at 512 x 512, 7.18 | 5.77 at 256 x 512; dq 4.57 | 3.68 at 512 x 512 (the rule's,
+# the least), 4.62 | 3.91 at 512 x 1024, 4.69 | 3.77 at 256 x 512, 4.68 | 3.93 at 256 x
+# 1024, 4.94 | 3.98 at 128 x 512, 5.40 | 4.37 at 512 x 256; dk / dv 5.56 | 4.47 at 512 x
+# 512 (the rule's, the least), 5.75 | 11.12 at 512 x 1024 (twice the causal time at
+# that tile and NOT the walk's doing: its key-major list is 60 of the causal 72 pairs
+# in 8 runs, a key block's run written once, 16 pairs on the diagonal and 8 on the
+# edge, as at 512 x 512 where 108 of 136 pairs read 0.80; what Mosaic makes of three
+# bodies on a (1024, 896) score tile is not measured, the rule does not pick it), 5.87 |
+# 4.70 at 512 x 256, 5.92 | 4.74 at 256 x 512, 5.96 | 4.99 at 256 x 1024, 6.37 | 4.98 at
+# 256 x 256, 6.53 | 5.23 at 128 x 512. Seven heads fold 7 x 512 = 3584 rows, which
+# _CHUNK_ROWS does not divide: a step's loop takes them 896 at a time
+# (:func:`_chunk_rows`). The rule stands as it is (the picks of eight heads a
+# group): its forward is within a fiftieth of the least read. Forward and backward
+# of one sequence by the rule: 14.56 ms causal, 12.38 windowed (0.85).
+# All five regimes: a key block meets up to _FOLDED_ROWS rows of queries (a
 # group's heads times the query block), and the backward's kernels, which
 # hold two products' tiles a pair, keep heads x query block x key block
 # within _BACKWARD_TILE.
@@ -149,9 +175,10 @@ def causal_attention_serves(x: Array, head_dim: int, v_head_dim: Optional[int] =
     ``models.layers.mla_attention`` (one query head a key/value head:
     GLM-4.7-Flash's 256 / 256, and 192 / 128 with the queries and keys
     padded to 256), ``models.qwen3_next.gated_attention`` (eight of 256)
-    and ``models.lfm2_moe.gqa_attention`` (four of 64, two key/value heads a
-    tile; :func:`_blocks` has what each regime measured); reads no
-    environment variable."""
+    ``models.lfm2_moe.gqa_attention`` (four of 64, two key/value heads a
+    tile) and ``models.smallthinker.attention`` (seven of 128, with a window
+    in six blocks of eight; :func:`_blocks` has what each regime measured);
+    reads no environment variable."""
     v_head_dim = head_dim if v_head_dim is None else v_head_dim
     return bool(
         _pk._on_tpu()
@@ -178,7 +205,9 @@ def _blocks(t: int, per: int, *, backward: bool) -> Tuple[int, int, int]:
     counted in scores, and the widest head (256) fits every regime above.
     Where heads share a lane tile ``per`` counts the query heads of a grid
     step (a tile's key/value heads times their group: 8 for four heads of
-    64 a group, 512 x 1024 forward and 512 x 512 backward)."""
+    64 a group, 512 x 1024 forward and 512 x 512 backward). Seven heads a
+    group get the same picks (3584 folded rows). A window does not enter:
+    it shortens the list of pairs, not a pair."""
     t_pad = _pk._round_up(t, _LANES)
     sizes = (1024, 512, 256, 128)
     block_q = next(b for b in sizes
@@ -188,29 +217,40 @@ def _blocks(t: int, per: int, *, backward: bool) -> Tuple[int, int, int]:
     return t_pad, block_q, block_k
 
 
-def _pairs(n_q: int, n_k: int, block_q: int, block_k: int, *, key_major: bool):
+def _pairs(n_q: int, n_k: int, block_q: int, block_k: int, *, key_major: bool,
+           window: Optional[int] = None):
     """The (query block, key block) pairs at or under the diagonal, as
     three int32 vectors: query block, key block, flags. Query-major (each
     query block's key blocks in turn) for the forward and dq; key-major
     for dk / dv. ``_FIRST`` / ``_LAST`` mark the ends of the outer block's
-    run, ``_MASKED`` a pair the diagonal crosses."""
+    run, ``_MASKED`` a pair the diagonal crosses. With a ``window`` (query
+    ``i`` reads keys ``j`` with ``0 <= i - j < window``) the pairs wholly
+    OLDER than the window are left out as those above the diagonal are: a
+    query block's run starts at the key block the window of its first row
+    reaches, a key block's run ends at the last query block whose window
+    still reaches its last key; ``_EDGED`` marks a pair the window's edge
+    crosses (some ``i - j >= window`` in it)."""
     runs = []
     if key_major:
         for kj in range(n_k):
             first_q = (kj * block_k) // block_q
-            runs.append([(qi, kj) for qi in range(first_q, n_q)])
+            last_q = n_q - 1 if window is None else min(
+                n_q - 1, ((kj + 1) * block_k - 1 + window - 1) // block_q)
+            runs.append([(qi, kj) for qi in range(first_q, last_q + 1)])
     else:
         for qi in range(n_q):
+            first_k = 0 if window is None else max(0, qi * block_q - (window - 1)) // block_k
             last_k = ((qi + 1) * block_q - 1) // block_k
-            runs.append([(qi, kj) for kj in range(last_k + 1)])
+            runs.append([(qi, kj) for kj in range(first_k, last_k + 1)])
     qs, ks, flags = [], [], []
     for run in runs:
         for i, (qi, kj) in enumerate(run):
             crossed = (kj + 1) * block_k - 1 > qi * block_q
+            edged = window is not None and (qi + 1) * block_q - 1 - kj * block_k >= window
             qs.append(qi)
             ks.append(kj)
             flags.append((_FIRST if i == 0 else 0) | (_LAST if i == len(run) - 1 else 0)
-                         | (_MASKED if crossed else 0))
+                         | (_MASKED if crossed else 0) | (_EDGED if edged else 0))
     return tuple(np.asarray(a, np.int32) for a in (qs, ks, flags))
 
 
@@ -341,20 +381,44 @@ def _fold(dst_ref, src_ref, per: int, block_q: int, head_dim: int, scale: Option
     _heads(per, one, pack)
 
 
-def _seen(rows0, n_rows: int, block_q: int, block_k: int, qi, kj):
-    """The causal mask of ``n_rows`` folded rows from ``rows0`` against a
-    key block: a folded row's position is ``qi * block_q`` + its row
-    within its head."""
+def _visible(position, key, diagonal: bool, edge: Optional[int]):
+    """The mask of a pair from its entries' positions and keys: under the
+    diagonal where the pair crosses it, inside the window (``edge``: its
+    width) where the pair crosses that."""
+    seen = None
+    if diagonal:
+        seen = key <= position
+    if edge is not None:
+        inside = position - key < edge
+        seen = inside if seen is None else seen & inside
+    return seen
+
+
+def _seen(rows0, n_rows: int, block_q: int, block_k: int, qi, kj, diagonal: bool = True,
+          edge: Optional[int] = None):
+    """The mask of ``n_rows`` folded rows from ``rows0`` against a key
+    block: a folded row's position is ``qi * block_q`` + its row within
+    its head."""
     row = rows0 + lax.broadcasted_iota(jnp.int32, (n_rows, block_k), 0)
     position = qi * block_q + (row & (block_q - 1))
     key = kj * block_k + lax.broadcasted_iota(jnp.int32, (n_rows, block_k), 1)
-    return key <= position
+    return _visible(position, key, diagonal, edge)
+
+
+def _chunk_rows(rows: int) -> int:
+    """The rows of a folded block that one pass of a step's loop handles:
+    ``_CHUNK_ROWS`` where they divide it; for a longer block that they do
+    not divide (seven heads a group: 7 x 512 = 3584) its largest divisor in
+    whole lane tiles under them (896); else the block at once."""
+    if rows % _CHUNK_ROWS == 0 or rows < _CHUNK_ROWS:
+        return min(rows, _CHUNK_ROWS)
+    return next((c for c in range(_CHUNK_ROWS - _LANES, 0, -_LANES) if rows % c == 0), rows)
 
 
 def _for_chunks(rows: int, body):
-    """``body(first row, rows)`` over a folded block: ``_CHUNK_ROWS`` at a
-    time where they divide it, else all at once."""
-    chunk = _CHUNK_ROWS if rows % _CHUNK_ROWS == 0 else rows
+    """``body(first row, rows)`` over a folded block, :func:`_chunk_rows` at
+    a time."""
+    chunk = _chunk_rows(rows)
     if rows == chunk:
         body(0, rows)
     else:
@@ -365,11 +429,24 @@ def _for_chunks(rows: int, body):
         lax.fori_loop(0, rows // chunk, step, 0)
 
 
-def _masked_or_not(flag, step):
-    """``step(masked)``: the diagonal's pairs compute the mask, the others
-    carry no trace of it."""
-    pl.when((flag & _MASKED) != 0)(lambda: step(True))
-    pl.when((flag & _MASKED) == 0)(lambda: step(False))
+def _masked_or_not(flag, step, window: Optional[int] = None, kinds=None):
+    """``step(diagonal, edge)``: the diagonal's pairs compute its mask, the
+    pairs the window's edge crosses that edge's (``edge`` is the window
+    there, else ``None``), the others carry no trace of either. ``kinds``
+    (with a window): the flag combinations the call's pairs hold, so that
+    no body is lowered for a combination that never comes."""
+    if window is None:
+        pl.when((flag & _MASKED) != 0)(lambda: step(True, None))
+        pl.when((flag & _MASKED) == 0)(lambda: step(False, None))
+        return
+    for kind in kinds:
+        pl.when((flag & (_MASKED | _EDGED)) == kind)(
+            functools.partial(step, bool(kind & _MASKED), window if kind & _EDGED else None))
+
+
+def _kinds(pairs):
+    """The mask combinations among a call's pairs, in a fixed order."""
+    return tuple(sorted({int(f) & (_MASKED | _EDGED) for f in pairs[2]}))
 
 
 # ---------------------------------------------------------------------------
@@ -379,7 +456,7 @@ def _masked_or_not(flag, step):
 
 def _fwd_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 qf_ref, m_ref, l_ref, acc_ref,
-                *, per, qk_dim, v_dim, pack, scale, block_q, block_k):
+                *, per, qk_dim, v_dim, pack, scale, block_q, block_k, window=None, kinds=None):
     pair = pl.program_id(1)
     qi, kj, flag = qi_ref[pair], kj_ref[pair], flag_ref[pair]
     rows = per * block_q
@@ -391,23 +468,27 @@ def _fwd_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    def step(masked):
+    def step(diagonal, edge):
         def body(rows0, chunk):
             sl = pl.ds(rows0, chunk)
             s = _dot(qf_ref[sl, :], k_ref[...], _NT)
-            if masked:
-                s = jnp.where(_seen(rows0, chunk, block_q, block_k, qi, kj), s, -jnp.inf)
+            if diagonal or edge is not None:
+                s = jnp.where(_seen(rows0, chunk, block_q, block_k, qi, kj, diagonal, edge),
+                              s, -jnp.inf)
             m_prev = m_ref[sl, :]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
+            # a row whose window starts past this key block has seen nothing
+            # yet: its maximum is still -inf, and it takes 0 for the exponents
+            m_at = m_new if edge is None else jnp.where(m_new == -jnp.inf, 0.0, m_new)
+            alpha = jnp.exp(m_prev - m_at)
+            p = jnp.exp(s - m_at)
             l_ref[sl, :] = alpha * l_ref[sl, :] + jnp.sum(p, axis=1, keepdims=True)
             acc_ref[sl, :] = alpha * acc_ref[sl, :] + _dot(p.astype(v_ref.dtype), v_ref[...], _NN)
             m_ref[sl, :] = m_new
 
         _for_chunks(rows, body)
 
-    _masked_or_not(flag, step)
+    _masked_or_not(flag, step, window, kinds)
 
     @pl.when((flag & _LAST) != 0)
     def _():
@@ -463,6 +544,12 @@ def _cost(pairs, kv_heads, per, block_q, block_k, widths, arrays):
         bytes_accessed=sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in arrays))
 
 
+def _edges(pairs, window):
+    """What a windowed call's kernel is told beside the sizes; a causal
+    call's kernel is told nothing (and is the kernel it always was)."""
+    return {} if window is None else dict(window=window, kinds=_kinds(pairs))
+
+
 def _widths(q, k, v, kv_heads):
     """``(grid steps, query heads a step, qk_dim, v_dim, pack)`` of a call.
     A step is a key/value head with its query heads and the widths are a
@@ -475,17 +562,23 @@ def _widths(q, k, v, kv_heads):
     return kv_heads // pack, per * pack, qk_dim * pack, v_dim * pack, pack
 
 
-def _causal_attention_fwd_call(q, k, v, *, kv_heads, scale, block_q, block_k, interpret):
+def _fwd_parts(q, k, v, *, kv_heads, scale, block_q, block_k, interpret,
+               window=None):
+    """What a call of the forward kernel is made of, causal or windowed: the
+    kernel, ``pallas_call``'s other arguments, the operands."""
     t = q.shape[0]
     kv_heads, per, qk_dim, v_dim, pack = _widths(q, k, v, kv_heads)
     rows = per * block_q
-    pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=False)
+    pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=False,
+                   window=window)
     q_spec, k_spec, v_spec, o_spec, row_spec = _specs(per, qk_dim, v_dim, block_q, block_k, pack)
     out_shape = (jax.ShapeDtypeStruct((t, kv_heads * (per // pack) * v_dim), q.dtype),
                  jax.ShapeDtypeStruct((kv_heads, per, t), jnp.float32))
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, pack=pack,
-                          scale=scale, block_q=block_q, block_k=block_k),
+    kernel = functools.partial(
+        _fwd_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, pack=pack,
+        scale=scale, block_q=block_q, block_k=block_k,
+        **_edges(pairs, window))
+    return kernel, dict(
         out_shape=out_shape,
         grid_spec=_grid_spec(
             pairs, kv_heads, [q_spec, k_spec, v_spec], (o_spec, row_spec),
@@ -494,9 +587,17 @@ def _causal_attention_fwd_call(q, k, v, *, kv_heads, scale, block_q, block_k, in
         compiler_params=_params(),
         cost_estimate=_cost(pairs, kv_heads, per, block_q, block_k, (qk_dim, v_dim),
                             (q, k, v) + out_shape),
-        interpret=interpret,
-        name="causal_attention_fwd",
-    )(*pairs, q, k, v)
+        interpret=interpret), (*pairs, q, k, v)
+
+
+def _causal_attention_fwd_call(q, k, v, **sizes):
+    kernel, rest, operands = _fwd_parts(q, k, v, **sizes)
+    return pl.pallas_call(kernel, name="causal_attention_fwd", **rest)(*operands)
+
+
+def _window_attention_fwd_call(q, k, v, *, window, **sizes):
+    kernel, rest, operands = _fwd_parts(q, k, v, window=window, **sizes)
+    return pl.pallas_call(kernel, name="window_attention_fwd", **rest)(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -506,7 +607,7 @@ def _causal_attention_fwd_call(q, k, v, *, kv_heads, scale, block_q, block_k, in
 
 def _dq_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dq_ref, qf_ref, dof_ref, lse_col_ref, delta_col_ref, acc_ref,
-               *, per, qk_dim, v_dim, pack, scale, block_q, block_k):
+               *, per, qk_dim, v_dim, pack, scale, block_q, block_k, window=None, kinds=None):
     pair = pl.program_id(1)
     qi, kj, flag = qi_ref[pair], kj_ref[pair], flag_ref[pair]
     rows = per * block_q
@@ -524,12 +625,13 @@ def _dq_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, d
         _heads(per, one, pack)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    def step(masked):
+    def step(diagonal, edge):
         def body(rows0, chunk):
             sl = pl.ds(rows0, chunk)
             s = _dot(qf_ref[sl, :], k_ref[...], _NT)
-            if masked:
-                s = jnp.where(_seen(rows0, chunk, block_q, block_k, qi, kj), s, -jnp.inf)
+            if diagonal or edge is not None:
+                s = jnp.where(_seen(rows0, chunk, block_q, block_k, qi, kj, diagonal, edge),
+                              s, -jnp.inf)
             p = jnp.exp(s - lse_col_ref[sl, :])
             dp = _dot(dof_ref[sl, :], v_ref[...], _NT)
             ds = p * (dp - delta_col_ref[sl, :])
@@ -537,24 +639,29 @@ def _dq_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, d
 
         _for_chunks(rows, body)
 
-    _masked_or_not(flag, step)
+    _masked_or_not(flag, step, window, kinds)
 
     @pl.when((flag & _LAST) != 0)
     def _():
         _put_heads(dq_ref, lambda r: acc_ref[_rows(r, block_q), :] * scale, per, qk_dim, pack)
 
 
-def _causal_attention_dq_call(q, k, v, do, lse, delta, *, kv_heads, scale, block_q,
-                              block_k, interpret):
+def _dq_parts(q, k, v, do, lse, delta, *, kv_heads, scale, block_q,
+              block_k, interpret, window=None):
+    """What a call of the dq kernel is made of, causal or windowed: the
+    kernel, ``pallas_call``'s other arguments, the operands."""
     t = q.shape[0]
     kv_heads, per, qk_dim, v_dim, pack = _widths(q, k, v, kv_heads)
     rows = per * block_q
-    pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=False)
+    pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=False,
+                   window=window)
     q_spec, k_spec, v_spec, o_spec, row_spec = _specs(per, qk_dim, v_dim, block_q, block_k, pack)
     out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
-    return pl.pallas_call(
-        functools.partial(_dq_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, pack=pack,
-                          scale=scale, block_q=block_q, block_k=block_k),
+    kernel = functools.partial(
+        _dq_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, pack=pack,
+        scale=scale, block_q=block_q, block_k=block_k,
+        **_edges(pairs, window))
+    return kernel, dict(
         out_shape=out_shape,
         grid_spec=_grid_spec(
             pairs, kv_heads, [q_spec, k_spec, v_spec, o_spec, row_spec, row_spec], q_spec,
@@ -564,9 +671,17 @@ def _causal_attention_dq_call(q, k, v, do, lse, delta, *, kv_heads, scale, block
         compiler_params=_params(),
         cost_estimate=_cost(pairs, kv_heads, per, block_q, block_k, (qk_dim, v_dim, qk_dim),
                             (q, k, v, do, lse, delta, out_shape)),
-        interpret=interpret,
-        name="causal_attention_dq",
-    )(*pairs, q, k, v, do, lse, delta)
+        interpret=interpret), (*pairs, q, k, v, do, lse, delta)
+
+
+def _causal_attention_dq_call(q, k, v, do, lse, delta, **sizes):
+    kernel, rest, operands = _dq_parts(q, k, v, do, lse, delta, **sizes)
+    return pl.pallas_call(kernel, name="causal_attention_dq", **rest)(*operands)
+
+
+def _window_attention_dq_call(q, k, v, do, lse, delta, *, window, **sizes):
+    kernel, rest, operands = _dq_parts(q, k, v, do, lse, delta, window=window, **sizes)
+    return pl.pallas_call(kernel, name="window_attention_dq", **rest)(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -576,7 +691,7 @@ def _causal_attention_dq_call(q, k, v, do, lse, delta, *, kv_heads, scale, block
 
 def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
-                *, per, qk_dim, v_dim, pack, scale, block_q, block_k):
+                *, per, qk_dim, v_dim, pack, scale, block_q, block_k, window=None, kinds=None):
     pair = pl.program_id(1)
     qi, kj, flag = qi_ref[pair], kj_ref[pair], flag_ref[pair]
 
@@ -585,13 +700,14 @@ def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
         dk_acc_ref[...] = jnp.zeros(dk_acc_ref.shape, jnp.float32)
         dv_acc_ref[...] = jnp.zeros(dv_acc_ref.shape, jnp.float32)
 
-    def step(masked):
+    def step(diagonal, edge):
         # the transposed tile (keys, queries) of one head after another: the
         # sums over a group's heads and over its query rows are the products'
+        masked = diagonal or edge is not None
         if masked:
             key = kj * block_k + lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
             position = qi * block_q + lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
-            seen = key <= position
+            seen = _visible(position, key, diagonal, edge)
         # unrolled where it is lowered, not a loop on the chip: as a rolled
         # ``fori_loop`` over the heads this kernel took 2.41 ms where sixteen
         # copies take 1.71 (v5e, PR 33); the copies cost 1.9 MB of code in HBM
@@ -610,7 +726,7 @@ def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
 
         _heads(per, one, pack)
 
-    _masked_or_not(flag, step)
+    _masked_or_not(flag, step, window, kinds)
 
     @pl.when((flag & _LAST) != 0)
     def _():
@@ -618,16 +734,21 @@ def _dkv_kernel(qi_ref, kj_ref, flag_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, 
         dv_ref[...] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
-def _causal_attention_dkv_call(q, k, v, do, lse, delta, *, kv_heads, scale, block_q,
-                               block_k, interpret):
+def _dkv_parts(q, k, v, do, lse, delta, *, kv_heads, scale, block_q,
+               block_k, interpret, window=None):
+    """What a call of the dk / dv kernel is made of, causal or windowed: the
+    kernel, ``pallas_call``'s other arguments, the operands."""
     t = q.shape[0]
     kv_heads, per, qk_dim, v_dim, pack = _widths(q, k, v, kv_heads)
-    pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=True)
+    pairs = _pairs(t // block_q, t // block_k, block_q, block_k, key_major=True,
+                   window=window)
     q_spec, k_spec, v_spec, o_spec, row_spec = _specs(per, qk_dim, v_dim, block_q, block_k, pack)
     out_shape = (jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype))
-    return pl.pallas_call(
-        functools.partial(_dkv_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, pack=pack,
-                          scale=scale, block_q=block_q, block_k=block_k),
+    kernel = functools.partial(
+        _dkv_kernel, per=per, qk_dim=qk_dim, v_dim=v_dim, pack=pack,
+        scale=scale, block_q=block_q, block_k=block_k,
+        **_edges(pairs, window))
+    return kernel, dict(
         out_shape=out_shape,
         grid_spec=_grid_spec(
             pairs, kv_heads, [q_spec, k_spec, v_spec, o_spec, row_spec, row_spec],
@@ -638,9 +759,17 @@ def _causal_attention_dkv_call(q, k, v, do, lse, delta, *, kv_heads, scale, bloc
         cost_estimate=_cost(pairs, kv_heads, per, block_q, block_k,
                             (qk_dim, v_dim, v_dim, qk_dim),
                             (q, k, v, do, lse, delta) + out_shape),
-        interpret=interpret,
-        name="causal_attention_dkv",
-    )(*pairs, q, k, v, do, lse, delta)
+        interpret=interpret), (*pairs, q, k, v, do, lse, delta)
+
+
+def _causal_attention_dkv_call(q, k, v, do, lse, delta, **sizes):
+    kernel, rest, operands = _dkv_parts(q, k, v, do, lse, delta, **sizes)
+    return pl.pallas_call(kernel, name="causal_attention_dkv", **rest)(*operands)
+
+
+def _window_attention_dkv_call(q, k, v, do, lse, delta, *, window, **sizes):
+    kernel, rest, operands = _dkv_parts(q, k, v, do, lse, delta, window=window, **sizes)
+    return pl.pallas_call(kernel, name="window_attention_dkv", **rest)(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -650,16 +779,20 @@ def _causal_attention_dkv_call(q, k, v, do, lse, delta, *, kv_heads, scale, bloc
 
 def causal_attention(q: Array, k: Array, v: Array, *, kv_heads: int,
                      scale: Optional[float] = None,
-                     interpret: Optional[bool] = None) -> Array:
+                     interpret: Optional[bool] = None,
+                     window: Optional[int] = None) -> Array:
     """Causal softmax attention of one sequence: ``q (T, H * qk_dim)``,
     ``k (T, kv_heads * qk_dim)``, ``v (T, kv_heads * v_dim)``, query head
     ``h`` reading key/value head ``h // (H / kv_heads)``; returns
     ``(T, H * v_dim)`` in ``q``'s dtype. ``scale`` multiplies the scores
     (default ``qk_dim ** -0.5``; a caller that padded its queries and keys
     to whole lanes, or whose positions stretch the softmax, hands its
-    own). Differentiable in all three. Any ``T``: the tail is padded to
-    whole blocks (padded keys lie after every query; padded queries are
-    cut off and their cotangent is zero)."""
+    own). ``window = W``: query ``i`` reads keys ``j`` with ``0 <= i - j <
+    W`` (its own position counted) through the ``window_attention_*``
+    kernels; ``None``, or a window no shorter than the sequence, is the
+    causal call, kernel for kernel. Differentiable in all three. Any ``T``:
+    the tail is padded to whole blocks (padded keys lie after every query;
+    padded queries are cut off and their cotangent is zero)."""
     qk_dim, v_dim = k.shape[1] // kv_heads, v.shape[1] // kv_heads
     if qk_dim in _NARROW_HEADS:
         fits = v_dim == qk_dim and kv_heads % (_LANES // qk_dim) == 0
@@ -671,9 +804,14 @@ def causal_attention(q: Array, k: Array, v: Array, *, kv_heads: int,
             f"or one of {_NARROW_HEADS} for all three with the key/value heads filling whole "
             f"lane tiles, and whole groups of query "
             f"heads, got q {q.shape}, k {k.shape}, v {v.shape}, kv_heads {kv_heads}")
+    if window is not None and window < 1:
+        raise ValueError(f"causal_attention: a window holds at least the query's own position, "
+                         f"got {window}")
     if scale is None:
         scale = 1.0 / math.sqrt(qk_dim)
-    return _causal_attention(q, k, v, kv_heads, scale, _pk._resolve_interpret(interpret))
+    if window is not None and window >= q.shape[0]:
+        window = None
+    return _causal_attention(q, k, v, kv_heads, scale, _pk._resolve_interpret(interpret), window)
 
 
 def _padded(t_pad: int, *arrays):
@@ -681,26 +819,39 @@ def _padded(t_pad: int, *arrays):
     return tuple(jnp.pad(a, ((0, pad), (0, 0))) for a in arrays) if pad else arrays
 
 
-def _forward(q, k, v, kv_heads, scale, interpret):
+def _calls(window):
+    """``(forward, dq, dk / dv, the keyword a windowed call adds)``: a
+    windowed call is three kernels of their own names (what counts a causal
+    call's operations, ``T^2 / 2`` entries a head, would count theirs
+    wrongly), a causal call the three it always was."""
+    if window is None:
+        return (_causal_attention_fwd_call, _causal_attention_dq_call,
+                _causal_attention_dkv_call, {})
+    return (_window_attention_fwd_call, _window_attention_dq_call,
+            _window_attention_dkv_call, {"window": window})
+
+
+def _forward(q, k, v, kv_heads, scale, interpret, window=None):
     t_pad, block_q, block_k = _blocks(
         q.shape[0], _widths(q, k, v, kv_heads)[1], backward=False)
-    out, lse = _causal_attention_fwd_call(
+    forward, _, _, windowed = _calls(window)
+    out, lse = forward(
         *_padded(t_pad, q, k, v), kv_heads=kv_heads, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=interpret)
+        block_q=block_q, block_k=block_k, interpret=interpret, **windowed)
     return out[:q.shape[0]], lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _causal_attention(q, k, v, kv_heads, scale, interpret):
-    return _forward(q, k, v, kv_heads, scale, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _causal_attention(q, k, v, kv_heads, scale, interpret, window):
+    return _forward(q, k, v, kv_heads, scale, interpret, window)[0]
 
 
-def _causal_attention_fwd(q, k, v, kv_heads, scale, interpret):
-    out, lse = _forward(q, k, v, kv_heads, scale, interpret)
+def _causal_attention_fwd(q, k, v, kv_heads, scale, interpret, window):
+    out, lse = _forward(q, k, v, kv_heads, scale, interpret, window)
     return out, (q, k, v, out, lse)
 
 
-def _causal_attention_bwd(kv_heads, scale, interpret, residuals, d_out):
+def _causal_attention_bwd(kv_heads, scale, interpret, window, residuals, d_out):
     # the backward rule is traced outside the scope the forward stood in
     with jax.named_scope("model.attention"):
         q, k, v, out, lse = residuals
@@ -714,10 +865,11 @@ def _causal_attention_bwd(kv_heads, scale, interpret, residuals, d_out):
                 t, steps, per, -1), axis=-1)
         delta = jnp.pad(jnp.transpose(delta, (1, 2, 0)), ((0, 0), (0, 0), (0, t_pad - t)))
         args = _padded(t_pad, q, k, v, d_out.astype(q.dtype)) + (lse, delta)
+        _, dq_call, dkv_call, windowed = _calls(window)
         sizes = dict(kv_heads=kv_heads, scale=scale, block_q=block_q, block_k=block_k,
-                     interpret=interpret)
-        dq = _causal_attention_dq_call(*args, **sizes)
-        dk, dv = _causal_attention_dkv_call(*args, **sizes)
+                     interpret=interpret, **windowed)
+        dq = dq_call(*args, **sizes)
+        dk, dv = dkv_call(*args, **sizes)
         return dq[:t], dk[:t], dv[:t]
 
 

@@ -114,17 +114,18 @@ def moe_ffn(
     return jnp.einsum("ecd,tec->td", out_blocks, combine)
 
 
-def _expert(h_in: Array, up: Array, down: Array, gated: Optional[Array] = None) -> Array:
+def _expert(h_in: Array, up: Array, down: Array, gated: Optional[Array] = None,
+            activation: Callable[[Array], Array] = jax.nn.silu) -> Array:
     """``down relu(up h_in)^2``, or with a third matrix
-    ``down (silu(gated h_in) * up h_in)``."""
+    ``down (activation(gated h_in) * up h_in)``."""
     if gated is None:
         hidden = jnp.square(jax.nn.relu(h_in @ up.astype(h_in.dtype)))
     else:
-        hidden = jax.nn.silu(h_in @ gated.astype(h_in.dtype)) * (h_in @ up.astype(h_in.dtype))
+        hidden = activation(h_in @ gated.astype(h_in.dtype)) * (h_in @ up.astype(h_in.dtype))
     return hidden @ down.astype(h_in.dtype)
 
 
-def _expert_round(x, weights, gate, expert, rank, counts, r, rows):
+def _expert_round(x, weights, gate, expert, rank, counts, r, rows, activation):
     """Round ``r`` of the held experts' part: every expert multiplies the
     ``rows`` of its tokens whose rank among them is in ``[r rows, (r + 1)
     rows)``. ``gate``, ``expert``, ``rank`` are ``(T, k)``, a column a pick
@@ -149,7 +150,7 @@ def _expert_round(x, weights, gate, expert, rank, counts, r, rows):
         jnp.arange(tokens * picks, dtype=jnp.int32), mode="drop")
     # (held,): an expert's tokens fill its first slots
     filled = jnp.clip(counts - r * rows, 0, rows)
-    per_expert = jax.vmap(_expert)(
+    per_expert = jax.vmap(functools.partial(_expert, activation=activation))(
         _dispatch(x, reader_at, slot, filled).reshape(held, rows, d), *weights).reshape(
             held * rows, d)
     out = _combine(per_expert, gate.astype(x.dtype), slot, reader_at, filled)
@@ -235,40 +236,42 @@ def _combine_bwd(kept, d_out):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _expert_rounds(x, weights, gate, expert, rank, counts, rows):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _expert_rounds(x, weights, gate, expert, rank, counts, rows, activation):
     """The held experts' part of every routed token: round 0, then as many
     more as the fullest expert needs. ``weights`` are the experts' stacked
     matrices as :func:`_expert` takes them (up, down, and the gated
-    experts' third). The count of rounds is read from the batch, so the
-    loop is a ``while`` with a backward pass of its own (the same rounds
-    again, each one's vector-Jacobian product added up)."""
-    return _expert_rounds_fwd(x, weights, gate, expert, rank, counts, rows)[0]
+    experts' third, whose ``activation`` is the model's). The count of
+    rounds is read from the batch, so the loop is a ``while`` with a
+    backward pass of its own (the same rounds again, each one's
+    vector-Jacobian product added up)."""
+    return _expert_rounds_fwd(x, weights, gate, expert, rank, counts, rows, activation)[0]
 
 
 def _rounds_needed(counts, rows):
     return jnp.maximum(1, -(-jnp.max(counts) // rows))
 
 
-def _expert_rounds_fwd(x, weights, gate, expert, rank, counts, rows):
+def _expert_rounds_fwd(x, weights, gate, expert, rank, counts, rows, activation):
     rounds = _rounds_needed(counts, rows)
 
     def more(r, acc):
-        out, computed = _expert_round(x, weights, gate, expert, rank, counts, r, rows)
+        out, computed = _expert_round(x, weights, gate, expert, rank, counts, r, rows, activation)
         return acc[0] + out, acc[1] + computed
 
     out, computed = lax.fori_loop(
-        1, rounds, more, _expert_round(x, weights, gate, expert, rank, counts, 0, rows))
+        1, rounds, more,
+        _expert_round(x, weights, gate, expert, rank, counts, 0, rows, activation))
     return (out, computed, rounds), (x, weights, gate, expert, rank, counts)
 
 
-def _expert_rounds_bwd(rows, kept, cotangents):
+def _expert_rounds_bwd(rows, activation, kept, cotangents):
     x, weights, gate, expert, rank, counts = kept
     g = cotangents[0]  # the pairs computed and the rounds are counts
 
     def pulled(r):
         return jax.vjp(
-            lambda *wrt: _expert_round(*wrt, expert, rank, counts, r, rows)[0],
+            lambda *wrt: _expert_round(*wrt, expert, rank, counts, r, rows, activation)[0],
             x, weights, gate)[1](g)
 
     def more(r, acc):
@@ -298,6 +301,8 @@ def held_experts_ffn(
     score: Callable[[Array], Array] = jax.nn.sigmoid,
     shared_weight: Optional[Array] = None,
     denominator_eps: float = 0.0,
+    router_input: Optional[Array] = None,
+    activation: Callable[[Array], Array] = jax.nn.silu,
 ):
     """One chip's part of an expert layer whose experts are spread over
     chips: it is told which experts it holds, routes over all of them,
@@ -313,8 +318,12 @@ def held_experts_ffn(
     one: LFM2's ``1e-6``; 0 adds no op) and times ``scale``. Expert:
     ``w_down relu(w_up x)^2``, or, where the experts come with a third
     stacked matrix ``w_gate (held, D, F)`` (and the shared expert with
-    ``shared_gate (D, Fs)``), ``w_down (silu(w_gate x) * w_up x)``: which of
-    the two is read off the weights given. A token's routed part is the weighted sum
+    ``shared_gate (D, Fs)``), ``w_down (activation(w_gate x) * w_up x)``:
+    which of the two is read off the weights given, and ``activation`` is
+    the model's (``jax.nn.silu``; SmallThinker's ``jax.nn.relu``). Where
+    the router reads ANOTHER tensor than the experts do (SmallThinker
+    routes on the block's normed input, before attention) it is handed as
+    ``router_input (T, D)``; ``None`` routes on ``x``. A token's routed part is the weighted sum
     over those of its ``top_k`` that are held here; what the absent
     experts would add is another chip's part. The shared expert
     (``shared_up (D, Fs)``, ``shared_down (Fs, D)``), where given, is
@@ -344,8 +353,9 @@ def held_experts_ffn(
         # in float32 at full precision whatever the activations' type: a
         # rounding that swaps a token's sixth and seventh expert is a
         # different result, not a small error (and the matrix is small)
+        routed_on = x if router_input is None else router_input
         scores = score(jnp.dot(
-            x.astype(jnp.float32), router_w.astype(jnp.float32),
+            routed_on.astype(jnp.float32), router_w.astype(jnp.float32),
             precision=lax.Precision.HIGHEST))
         top_s, top_e = lax.top_k(scores, top_k)  # (T, k)
         total = jnp.sum(top_s, axis=-1, keepdims=True)
@@ -374,10 +384,11 @@ def held_experts_ffn(
         rank = jnp.sum(jnp.where(chosen, rank_of[:, None, :], 0), axis=-1)
     with jax.named_scope("model.moe_experts"):
         weights = (w_up, w_down) if w_gate is None else (w_up, w_down, w_gate)
-        out, computed, rounds = _expert_rounds(x, weights, gate, expert, rank, counts, rows)
+        out, computed, rounds = _expert_rounds(
+            x, weights, gate, expert, rank, counts, rows, activation)
         if shared_up is not None:
             with jax.named_scope("model.moe_shared"):
-                shared = _expert(x, shared_up, shared_down, shared_gate)
+                shared = _expert(x, shared_up, shared_down, shared_gate, activation)
                 if shared_weight is not None:
                     shared = shared * jax.nn.sigmoid(x @ shared_weight.astype(x.dtype))
                 out = out + shared

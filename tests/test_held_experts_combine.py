@@ -179,7 +179,7 @@ def test_the_layer_takes_and_reports_what_it_did():
     assert list(inspect.signature(moe.held_experts_ffn).parameters) == [
         "x", "router_w", "w_up", "w_down", "shared_up", "shared_down", "first_held", "n_experts",
         "top_k", "scale", "round_rows", "w_gate", "shared_gate", "score", "shared_weight",
-        "denominator_eps"]
+        "denominator_eps", "router_input", "activation"]
     x, p = _layer(True)
     assert sorted(_ffn(x, p, None)[1]) == ["expert_rounds", "held_expert_tokens", "tokens_dropped"]
     assert "os.environ" not in inspect.getsource(moe) and "getenv" not in inspect.getsource(moe)

@@ -48,7 +48,11 @@ PARTS = {"nemotron": ["model.norm", "model.embed", "model.head", "model.ssm_proj
                   "model.hc_maps", "model.hc_mix", "stream.rows", "stream.boundary"],
          "lfm": ["model.norm", "model.embed", "model.head", "model.mlp", "model.attention",
                  "model.short_conv", "model.short_conv_proj", "model.moe_route",
-                 "model.moe_experts", "stream.rows", "stream.shared_rows", "stream.boundary"]}
+                 "model.moe_experts", "stream.rows", "stream.shared_rows", "stream.boundary"],
+         # SmallThinker (PR 49): both kinds of attention under ONE label, the router
+         # (which reads the block's normed input) under the expert layers' own
+         "small": ["model.norm", "model.embed", "model.head", "model.attention",
+                   "model.moe_route", "model.moe_experts", "stream.rows", "stream.boundary"]}
 
 
 def _toy(model):
@@ -85,6 +89,14 @@ def _toy(model):
             0, hidden_size=32, layer_types=["conv", "full_attention", "conv"], vocab_size=64,
             num_attention_heads=4, num_key_value_heads=2, query_block=8, intermediate_size=48,
             num_experts=16, num_experts_per_tok=3, moe_intermediate_size=24, held_experts=(4, 4))
+    if model == "small":
+        from byzpy_tpu.models import smallthinker
+
+        return smallthinker.smallthinker_21b_ep8(
+            0, hidden_size=32, vocab_size=64, num_attention_heads=4, num_key_value_heads=2,
+            head_dim=8, sliding_window_layout=[0, 1], rope_layout=[0, 1], sliding_window_size=6,
+            query_block=8, moe_num_primary_experts=16, moe_num_active_primary_experts=3,
+            moe_ffn_hidden_size=24, held_experts=(4, 4))
     from byzpy_tpu.models import glm4_moe_lite as glm
 
     return glm.glm47_flash_ep8(
@@ -138,7 +150,7 @@ def _renamed(text):
     return re.sub(r"%[\w.\-]+", lambda m: names.setdefault(m.group(0), f"%i{len(names)}"), text)
 
 
-@pytest.fixture(scope="module", params=["nemotron", "glm", "qwen", "xing", "lfm"])
+@pytest.fixture(scope="module", params=["nemotron", "glm", "qwen", "xing", "lfm", "small"])
 def step_text(request):
     """``(model, segment keys, [(opcode, op_name)] of the compiled step, its
     bare text)``."""
@@ -206,9 +218,10 @@ def test_every_op_of_a_round_scope_holds_one_segment_and_every_segment_appears(s
     assert outside and all(
         first_forward.search(name) or "jit(train_step)/round.update/" in name for name in outside)
     assert not any("model." in name for name in outside)
-    # (LFM2's blocks are a third of the others' ops against the same loop
-    # plumbing a segment: 6.2 % there)
-    assert len(outside) < (0.07 if model == "lfm" else 0.06) * len(scoped)
+    # (LFM2's and SmallThinker's blocks are a third of the others' ops against
+    # the same loop plumbing a segment: 6.2 % there and, with two blocks in the
+    # toy where LFM2's has three, 7.1 %)
+    assert len(outside) < {"lfm": 0.07, "small": 0.08}.get(model, 0.06) * len(scoped)
     assert {key for found in held for key in found} == set(keys)
     # a segment's whole turn: its three passes and the round's three stages
     for key in keys[1:-1]:
@@ -224,7 +237,11 @@ def test_the_norm_is_a_part_of_its_own_inside_the_latents_and_the_gate(step_text
     model, _, ops, _ = step_text
     outer = {"nemotron": "model.ssm_gate", "glm": "model.mla_latent",
              "qwen": "model.attention", "xing": "model.mla_latent",
-             "lfm": "model.attention"}[model]
+             "lfm": "model.attention", "small": None}[model]
+    if outer is None:  # no norm inside a mixer: every norm stands between the parts
+        assert not [name for _, name in ops if "model.norm" in name
+                    and re.search(r"model\.(?:attention|moe_)", name)]
+        return
     nested = [name for _, name in ops if outer in name and "model.norm" in name]
     assert nested and all(part_of(name) == "model.norm" for name in nested)
     for a_pass in ("round.segment_fwd", "round.segment_recompute", "round.segment_bwd"):
@@ -238,7 +255,7 @@ def test_the_convolutions_own_backward_stays_in_the_gate(step_text):
     model, _, ops, _ = step_text
     own = [(opcode, name) for opcode, name in ops
            if re.search(r"model\.ssm_gate\)+/model\.ssm_gate/\w+$", name)]
-    if model in ("glm", "xing", "lfm"):
+    if model in ("glm", "xing", "lfm", "small"):
         assert not own
         return
     assert {name.rsplit("/", 1)[-1] for _, name in own} >= {"pad", "mul", "add", "reduce_sum"}
@@ -345,6 +362,26 @@ def test_a_tied_tables_way_to_its_owners_row_has_a_label_of_its_own(step_text):
     assert any(name.endswith("/dynamic_update_slice") for name in first)
     # the table's second use is the head's
     assert any("model.head" in name and name.endswith("dot_general") for _, name in ops)
+
+
+def test_both_kinds_of_attention_hold_one_label_and_the_router_the_expert_layers(step_text):
+    """SmallThinker alone: a global block and a windowed one stand under
+    ``model.attention`` in every pass (the rotary turn of the windowed block
+    with them), and the router, which reads the block's normed input, under
+    ``model.moe_route`` in every pass of both blocks."""
+    model, keys, ops, _ = step_text
+    if model != "small":
+        return
+    assert keys == ["seg00_embed", "seg01_global", "seg02_window", "seg03_head"]
+    for key in keys[1:-1]:
+        for label in ("model.attention", "model.moe_route", "model.moe_experts"):
+            mine = [name for _, name in ops if f"segment.{key}/" in name and label in name]
+            assert mine and all(part_of(name) == label for name in mine), (key, label)
+            for a_pass in ("round.segment_fwd", "round.segment_recompute", "round.segment_bwd"):
+                assert any(a_pass in name for name in mine), (key, label, a_pass)
+    turned = [name for _, name in ops if "model.attention" in name
+              and re.search(r"/(?:cos|sin)$", name)]
+    assert turned and all("segment.seg02_window/" in name for name in turned)
 
 
 def test_round_fwdbwd_is_still_the_innermost_round_scope(step_text):
@@ -472,6 +509,7 @@ def test_byzlint_is_clean_on_the_modules_that_enter_the_labels():
     paths = [os.path.join(ROOT, "byzpy_tpu", *parts) for parts in (
         ("parallel", "ps.py"), ("parallel", "moe.py"), ("models", "nemotron_h.py"),
         ("models", "glm4_moe_lite.py"), ("models", "layers.py"), ("models", "qwen3_next.py"),
-        ("models", "xing4.py"), ("models", "lfm2_moe.py"), ("models", "bundle.py"))]
+        ("models", "xing4.py"), ("models", "lfm2_moe.py"), ("models", "smallthinker.py"),
+        ("models", "bundle.py"))]
     result = scan_paths(paths, select=[METRIC_CONTRACT])
     assert [f.message for f in result.findings if f.rule == METRIC_CONTRACT] == []

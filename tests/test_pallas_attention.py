@@ -17,7 +17,13 @@ On the CPU the kernels run in the Pallas interpreter. Held here:
   TPU ``gqa_attention`` lowers to the text it lowered to before there was
   a kernel;
 * the list of pairs a kernel's grid walks is the pairs at or under the
-  diagonal, no more and no fewer.
+  diagonal, no more and no fewer;
+* with a ``window`` (SmallThinker's sliding-window blocks) the kernels are
+  the plain masked form ``0 <= i - j < W``, forward, dq, dk and dv, at 7 / 16
+  / 1 heads a group, head widths 128 and 64, lengths and windows that are no
+  whole blocks, a window of one; the pairs are those the window reaches, no
+  more and no fewer; ``W >= T`` and ``window=None`` are the causal call bit
+  for bit, with the same pair lists and the same kernels' names.
 
 The kernels' Mosaic compile at the real width is held in
 ``tests/test_round_matrix_once.py`` (the one file that compiles for a
@@ -532,7 +538,7 @@ def test_no_variable_field_or_argument_chooses_the_route():
     assert list(inspect.signature(pa.causal_attention_serves).parameters) == [
         "x", "head_dim", "v_head_dim"]
     assert list(inspect.signature(pa.causal_attention).parameters) == [
-        "q", "k", "v", "kv_heads", "scale", "interpret"]
+        "q", "k", "v", "kv_heads", "scale", "interpret", "window"]
     # the configuration's attention fields are the four it had (query_block: the XLA route's)
     fields = [f for f in nh.NemotronHConfig.__dataclass_fields__
               if "attention" in f or "head" in f or "block" in f or "key_value" in f]
@@ -614,3 +620,143 @@ def test_pairs_are_the_pairs_at_or_under_the_diagonal(block_q, block_k, t, key_m
     for (qi, kj), flag in zip(walked, flags.tolist()):
         wholly_seen = (kj + 1) * block_k - 1 <= qi * block_q
         assert bool(flag & pa._MASKED) == (not wholly_seen)
+
+
+# -- a window ------------------------------------------------------------------------
+
+
+def _masked_form(q, k, v, kv, hd, window):
+    """``softmax(q k^T / sqrt(hd) + mask) v`` from the definition, the mask
+    ``0 <= i - j`` and, with a window, ``i - j < window``."""
+    t = q.shape[0]
+    per = q.shape[1] // (kv * hd)
+    q, k, v = q.reshape(t, kv, per, hd), k.reshape(t, kv, hd), v.reshape(t, kv, hd)
+    scores = jnp.einsum("qgrd,kgd->grqk", q, k, precision="highest") / math.sqrt(hd)
+    behind = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    seen = behind >= 0 if window is None else (behind >= 0) & (behind < window)
+    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("grqk,kgd->qgrd", probs, v, precision="highest").reshape(t, -1)
+
+
+def _windowed_operands(per, kv, hd, t, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(keys[0], (t, per * kv * hd)), jax.random.normal(keys[1], (t, kv * hd)),
+            jax.random.normal(keys[2], (t, kv * hd)), jax.random.normal(keys[3], (t, per * kv * hd)))
+
+
+# (query heads a group, key/value heads, head width, T, W): seven heads a group
+# (SmallThinker), sixteen, one; heads of 64 two to a tile; lengths of no whole
+# block; windows of no whole block, of one block, of one position, of one less
+# than the sequence
+WINDOW_CASES = [
+    (7, 1, 128, 300, 130), (7, 2, 128, 520, 24), (7, 1, 128, 300, 1), (7, 1, 128, 384, 128),
+    (16, 1, 128, 300, 33), (1, 2, 128, 640, 257), (1, 1, 128, 300, 299),
+    (4, 2, 64, 300, 70), (2, 2, 64, 200, 128)]
+
+
+@pytest.mark.parametrize("per, kv, hd, t, window", WINDOW_CASES)
+def test_windowed_kernels_are_the_plain_masked_form_forward_and_gradient(per, kv, hd, t, window):
+    q, k, v, probe = _windowed_operands(per, kv, hd, t)
+    got = pa.causal_attention(q, k, v, kv_heads=kv, window=window)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    _close(got, _masked_form(q, k, v, kv, hd, window))
+    grads = jax.grad(lambda *a: jnp.sum(pa.causal_attention(*a, kv_heads=kv, window=window) * probe),
+                     (0, 1, 2))(q, k, v)
+    wanted = jax.grad(lambda *a: jnp.sum(_masked_form(*a, kv, hd, window) * probe),
+                      (0, 1, 2))(q, k, v)
+    for name, g, w in zip(("dq", "dk", "dv"), grads, wanted):
+        assert bool(jnp.all(jnp.isfinite(g))), name
+        # (a window of one: the softmax of one score has no gradient, dq = dk = 0)
+        np.testing.assert_allclose(g, w, rtol=0, atol=5e-5 * max(float(jnp.max(jnp.abs(w))), 0.1))
+
+
+def _kernel_names(fn, *args):
+    """The kernels a function calls, by the names in its lowered text (the
+    interpreter's lowering carries them in its locations)."""
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    return sorted(set(re.findall(r"(?<![\w.])((?:causal|window)_attention_(?:fwd|dq|dkv))(?!_call)\b",
+                                 text)))
+
+
+@pytest.mark.parametrize("window", [None, 300, 301, 4096])
+def test_a_window_no_shorter_than_the_sequence_is_the_causal_call_bit_for_bit(window):
+    q, k, v, probe = _windowed_operands(7, 1, 128, 300)
+
+    def both(window_):
+        def value(*a):
+            return jnp.sum(pa.causal_attention(*a, kv_heads=1, window=window_) * probe)
+
+        return pa.causal_attention(q, k, v, kv_heads=1, window=window_), jax.grad(
+            value, (0, 1, 2))(q, k, v), _kernel_names(jax.grad(value, (0, 1, 2)), q, k, v)
+
+    (got, got_grads, got_names), (want, want_grads, want_names) = both(window), both(None)
+    np.testing.assert_array_equal(got, want)
+    for g, w in zip(got_grads, want_grads):
+        np.testing.assert_array_equal(g, w)
+    assert got_names == want_names == [
+        "causal_attention_dkv", "causal_attention_dq", "causal_attention_fwd"]
+    # and a window that cuts has kernels of its own names, and no causal one
+    assert _kernel_names(jax.grad(lambda *a: jnp.sum(
+        pa.causal_attention(*a, kv_heads=1, window=299) * probe), (0, 1, 2)), q, k, v) == [
+            "window_attention_dkv", "window_attention_dq", "window_attention_fwd"]
+    with pytest.raises(ValueError, match="window"):
+        pa.causal_attention(q, k, v, kv_heads=1, window=0)
+
+
+@pytest.mark.parametrize("key_major", [False, True])
+@pytest.mark.parametrize("window", [1, 24, 128, 129, 300, 512, 4096])
+@pytest.mark.parametrize("block_q, block_k, t", [
+    (128, 128, 384), (256, 512, 1024), (512, 1024, 8192), (512, 512, 8192), (128, 512, 1024),
+    (256, 128, 512)])
+def test_windowed_pairs_are_the_pairs_the_window_reaches(block_q, block_k, t, window, key_major):
+    n_q, n_k = t // block_q, t // block_k
+    qs, ks, flags = pa._pairs(n_q, n_k, block_q, block_k, key_major=key_major, window=window)
+    walked = list(zip(qs.tolist(), ks.tolist()))
+
+    def entries(qi, kj):  # (i - j) over the pair: least and most
+        return (qi * block_q - ((kj + 1) * block_k - 1), (qi + 1) * block_q - 1 - kj * block_k)
+
+    needed = {(qi, kj) for qi in range(n_q) for kj in range(n_k)
+              if entries(qi, kj)[1] >= 0 and entries(qi, kj)[0] < window}
+    assert len(walked) == len(set(walked)) and set(walked) == needed
+    causal = pa._pairs(n_q, n_k, block_q, block_k, key_major=key_major)
+    assert set(walked) <= set(zip(causal[0].tolist(), causal[1].tolist()))
+    if window >= t:  # nothing to leave out, no edge to mask: the causal lists themselves
+        for got, want in zip((qs, ks, flags), causal):
+            np.testing.assert_array_equal(got, want)
+    outer = ks if key_major else qs
+    assert sorted(set(outer.tolist())) == list(range(n_k if key_major else n_q))
+    for block in set(outer.tolist()):
+        run = [i for i, o in enumerate(outer.tolist()) if o == block]
+        assert run == list(range(run[0], run[-1] + 1))
+        assert [bool(flags[i] & pa._FIRST) for i in run] == [True] + [False] * (len(run) - 1)
+        assert [bool(flags[i] & pa._LAST) for i in run] == [False] * (len(run) - 1) + [True]
+    for (qi, kj), flag in zip(walked, flags.tolist()):
+        least, most = entries(qi, kj)
+        assert bool(flag & pa._MASKED) == (least < 0)
+        assert bool(flag & pa._EDGED) == (most >= window)
+    if (block_q, block_k, t, window) == (512, 1024, 8192, 4096):
+        # the cell's forward: 60 of the causal 72 block pairs (three quarters of the
+        # ENTRIES lie inside the window; the pairs its edge crosses are walked whole)
+        assert (len(walked), len(causal[0])) == (60, 72)
+
+
+def test_a_windowed_head_visits_three_quarters_of_the_causal_entries_at_8192():
+    """The benchmark's count of a windowed call's entries, against the mask
+    itself counted row by row."""
+    from chipbench import opcount_window_attention
+
+    t, window = 8192, 4096
+    inside = sum(min(i + 1, window) for i in range(t))
+    assert inside == 25_167_872 == opcount_window_attention.window_entries(t, window)
+    assert 4 * inside == 3 * (t * (t + 1) // 2) - window  # three quarters of the causal half
+
+
+@pytest.mark.parametrize("rows, want", [
+    (4096, 1024), (1024, 1024), (768, 768), (128, 128), (3584, 896), (1792, 896), (1536, 768),
+    (2048, 1024)])
+def test_a_folded_block_is_walked_in_pieces_that_divide_it(rows, want):
+    """Seven heads a group fold 7 x 512 = 3584 rows, which 1024 does not
+    divide: the pieces are 896 (the blocks that 1024 divides, or that are
+    shorter, are walked as they always were)."""
+    assert pa._chunk_rows(rows) == want and rows % want == 0

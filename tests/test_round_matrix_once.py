@@ -821,6 +821,26 @@ def _write_tpu_texts(out_dir):
                   encoding="utf-8") as fh:
             fh.write(text)
 
+    # and SmallThinker's attention of both kinds at its cell's own shape (28 query /
+    # 4 key-value heads of 128, SEVEN heads a group, 8192 positions): a windowed
+    # block (window 4096, rotary) and a global one (no positions)
+    from byzpy_tpu.models import smallthinker
+
+    seven = smallthinker.SmallThinkerConfig()
+    shapes = jax.eval_shape(lambda: smallthinker.init_params(seven)["seg02_window"])
+    for name, kind, dtype in (("window_float32", (True, True), "float32"),
+                              ("window_bfloat16", (True, True), "bfloat16"),
+                              ("global_float32", (False, False), "float32")):
+        text = jax.jit(jax.value_and_grad(
+            lambda p, xs: jnp.sum(jax.vmap(lambda s: smallthinker.attention(p, s, seven, kind))(xs)
+                                  .astype(jnp.float32)), argnums=(0, 1))).lower(
+            described(shapes), jax.ShapeDtypeStruct(
+                (1, WINDOW_TOKENS, seven.hidden_size), jnp.dtype(dtype), sharding=one_chip)
+        ).compile().as_text()
+        with open(os.path.join(out_dir, f"attention_7_{name}.hlo.txt"), "w",
+                  encoding="utf-8") as fh:
+            fh.write(text)
+
     # the held experts' layer, value and gradient, at the three language cells'
     # tokens, picks, held experts, round and width (the experts' own width cut
     # to 128: the read-back kernel sees none of it)
@@ -848,6 +868,7 @@ EXPERT_CELLS = {"nemotron": (128, 6, 8, 1024, 2688), "glm": (64, 4, 8, 1024, 204
 
 
 PASS_COUNTS = (1, 2, 4)  # in how many passes the toy worker's 8 images go
+WINDOW_TOKENS = 8192  # the SmallThinker cell's tokens a worker: twice its window
 
 
 @pytest.fixture(scope="module")
@@ -873,6 +894,8 @@ def tpu_texts(tmp_path_factory):
     for name in [*FOLDED_ROUNDS, "streamed_update", "attention_float32", "attention_bfloat16",
                  "mla_attention_float32", "mla_attention_192_128_float32",
                  "attention_64_float32", "attention_64_bfloat16",
+                 "attention_7_window_float32", "attention_7_window_bfloat16",
+                 "attention_7_global_float32",
                  *(f"experts_{cell}" for cell in EXPERT_CELLS),
                  *(f"passes_{count}" for count in PASS_COUNTS)]:
         with open(os.path.join(out_dir, name + ".hlo.txt"), encoding="utf-8") as fh:
@@ -1010,7 +1033,7 @@ def _attention_calls(text):
     """``{kernel's name: its custom call's line}`` under ``model.attention``."""
     calls = {}
     for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%(causal_attention_\w+?)(?:\.\d+)? = ", line)
+        m = re.match(r"\s*(?:ROOT )?%((?:causal|window)_attention_\w+?)(?:\.\d+)? = ", line)
         if m and "tpu_custom_call" in line:
             assert "model.attention" in re.search(r'op_name="([^"]*)"', line).group(1)
             assert m.group(1) not in calls
@@ -1091,6 +1114,36 @@ def test_on_the_tpu_heads_of_64_are_the_same_three_kernels_two_heads_a_lane_tile
     assert backward.count(f"{kind}[{t},512]") == 2  # dk, dv
     reader = _benchmark_reader("attention_kernel_calls.train")
     assert reader.read(SimpleNamespace(outcome={"compiled_text": text})) == 3
+
+
+@pytest.mark.parametrize("name, kernels", [
+    ("window_float32", "window"), ("window_bfloat16", "window"), ("global_float32", "causal")])
+def test_on_the_tpu_seven_heads_a_group_are_three_kernels_windowed_or_causal(tpu_texts, name,
+                                                                             kernels):
+    """SmallThinker's 28 query / 4 key-value heads of 128 at 8192 positions:
+    Mosaic takes all three kernels at seven heads a group (3584 folded rows,
+    walked 896 at a time), a windowed block's under names of their own and a
+    global block's under the causal names; q goes in as (T, 3584) and k, v as
+    (T, 512), the forward's log-sum-exp is (4, 7, T); the benchmark's two
+    counters read each kind's calls and not the other's."""
+    text = tpu_texts[f"attention_7_{name}"]
+    calls = _attention_calls(text)
+    assert sorted(calls) == [f"{kernels}_attention_dkv", f"{kernels}_attention_dq",
+                             f"{kernels}_attention_fwd"]
+    t, kind = WINDOW_TOKENS, "bf16" if name.endswith("bfloat16") else "f32"
+    forward = calls[f"{kernels}_attention_fwd"]
+    assert f"{kind}[{t},3584]" in forward and forward.count(f"{kind}[{t},512]") >= 2
+    assert f"f32[4,7,{t}]" in forward.partition(" custom-call(")[0]
+    ctx = SimpleNamespace(outcome={"compiled_text": text}, config={"reference": {"arch": {
+        "sliding_window_size": 4096}}})
+    assert _benchmark_reader("attention_kernel_calls.train").read(ctx) == 3
+    assert _benchmark_reader("window_attention_kernel_calls.train").read(ctx) == (
+        3 if kernels == "window" else 0)
+    # a configuration without a window has nothing for the new counter to say
+    assert _benchmark_reader("window_attention_kernel_calls.train").read(SimpleNamespace(
+        outcome={"compiled_text": text}, config={"reference": {"arch": {}}})) is None
+    # no (T, T) or (T, window) score matrix in the program
+    assert not re.search(r"\[(?:\d+,)*%d,(?:%d|4096)\]" % (t, t), text)
 
 
 # -- the held experts' read-back, compiled by Mosaic ------------------------------
