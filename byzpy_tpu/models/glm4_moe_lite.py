@@ -100,7 +100,7 @@ def _gated_mlp(p: Dict[str, Array], x: Array) -> Array:
 
 
 def _expert_ffn(p: Dict[str, Array], x: Array, cfg: Glm4MoeLiteConfig):
-    # an expert's round is held_experts_ffn's own: a quarter of the tokens
+    # an expert's round is held_experts_ffn's own: an eighth of the tokens
     return held_experts_ffn(
         x, p["router"], p["experts_up"], p["experts_down"], p["shared_up"], p["shared_down"],
         first_held=cfg.held_experts[0], n_experts=cfg.n_routed_experts,

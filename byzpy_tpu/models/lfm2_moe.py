@@ -158,7 +158,7 @@ def gqa_attention(p: Dict[str, Array], x: Array, cfg: Lfm2MoeConfig) -> Array:
 
 
 def _expert_ffn(p: Dict[str, Array], x: Array, cfg: Lfm2MoeConfig):
-    # an expert's round is held_experts_ffn's own: a quarter of the tokens
+    # an expert's round is held_experts_ffn's own: an eighth of the tokens
     return held_experts_ffn(
         x, p["router"], p["experts_up"], p["experts_down"],
         first_held=cfg.held_experts[0], n_experts=cfg.num_experts,

@@ -305,7 +305,8 @@ def gated_attention(p: Dict[str, Array], x: Array, cfg: Qwen3NextConfig) -> Arra
 
 def round_rows(cfg: Qwen3NextConfig, tokens: int) -> int:
     """The size of a round of the held experts: four times an expert's
-    mean load of ``tokens`` (the accepted cells' rule), in whole sublanes:
+    mean load of ``tokens`` (``held_experts_ffn``'s own eighth of the tokens
+    would be 6.6 times it), in whole sublanes:
     320 rows for 4096 tokens, top-10 of 512."""
     mean = tokens * cfg.num_experts_per_tok / cfg.num_experts
     return max(8, -(-math.ceil(4 * mean) // 8) * 8)

@@ -137,7 +137,7 @@ def decoder_block(p: Dict[str, Array], h: Array, cfg: SmallThinkerConfig,
     h = h + jax.vmap(lambda s: attention(p, s, cfg, kind))(u)
     m = rms_norm(h, p["ffn_norm_scale"], cfg.rms_norm_eps)
     # the expert layer is token by token: sequences are laid end to end; an
-    # expert's round is held_experts_ffn's own, a quarter of the tokens
+    # expert's round is held_experts_ffn's own, an eighth of the tokens
     out, aux = held_experts_ffn(
         m.reshape(-1, m.shape[-1]), p["router"], p["experts_up"], p["experts_down"],
         first_held=cfg.held_experts[0], n_experts=cfg.moe_num_primary_experts,

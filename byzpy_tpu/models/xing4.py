@@ -247,12 +247,16 @@ def hyper_connected(p: Dict[str, Array], x: Array, norm_scale: Array,
 
 
 def _expert_ffn(p: Dict[str, Array], x: Array, cfg: Xing4Config):
-    # an expert's round is held_experts_ffn's own: a quarter of the tokens
+    # an expert's round is a quarter of the tokens, four times its mean load at
+    # the published 4 picks of 64: held_experts_ffn's own eighth is twice the
+    # mean, under this router's fullest expert (2.2 times), and a second round
+    # reads the experts' matrices again, which products this short wait for
     return held_experts_ffn(
         x, p["router"], p["experts_up"], p["experts_down"], p["shared_up"], p["shared_down"],
         first_held=cfg.held_experts[0], n_experts=cfg.n_routed_experts,
         top_k=cfg.num_experts_per_tok, scale=cfg.routed_scaling_factor,
-        w_gate=p["experts_gate"], shared_gate=p["shared_gate"])
+        w_gate=p["experts_gate"], shared_gate=p["shared_gate"],
+        round_rows=-(-max(x.shape[0] // 4, 1) // 8) * 8)
 
 
 def _connection(p: Dict[str, Array], which: str) -> Dict[str, Array]:

@@ -335,11 +335,17 @@ def held_experts_ffn(
     experts a round), and there are as many rounds as the fullest expert
     of the batch at hand needs. ``round_rows`` is a size and not a limit:
     any value gives the same result; a small one spends more rounds on a
-    popular expert, a large one multiplies more empty rows. Default: a
-    quarter of the tokens (in whole sublanes of 8), so a second round
-    runs only where one held expert draws more than a quarter of the
-    batch; a model whose held experts are many and lightly loaded hands
-    its own (a few times the mean load).
+    popular expert, a large one multiplies more empty rows. Default: an
+    eighth of the tokens (in whole sublanes of 8). The loop over the rounds
+    already sizes the work to the batch's fullest expert, in steps of
+    ``round_rows``: an even router's fullest expert (1.2 to 1.4 times the
+    mean of ``tokens top_k / n_experts`` where an expert's mean is a
+    sixteenth of the tokens or so) fits one round of an eighth, half the
+    rows a quarter multiplied; a skewed one's takes two or three, never more
+    rows than the rounds of a quarter it took before. A model whose held
+    experts are many and lightly loaded hands its own (a few times the mean
+    load): a second round reads the experts' matrices again, and under a
+    few hundred rows the products wait for those.
 
     Returns ``(out (T, D), aux)``: ``aux["held_expert_tokens"]`` the
     ``(held,)`` tokens each held expert got, ``aux["tokens_dropped"]``
@@ -348,7 +354,7 @@ def held_experts_ffn(
     """
     tokens = x.shape[0]
     held = w_up.shape[0]
-    rows = round_rows or -(-max(tokens // 4, 1) // 8) * 8
+    rows = round_rows or -(-max(tokens // 8, 1) // 8) * 8
     with jax.named_scope("model.moe_route"):
         # in float32 at full precision whatever the activations' type: a
         # rounding that swaps a token's sixth and seventh expert is a

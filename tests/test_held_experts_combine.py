@@ -13,6 +13,12 @@ Held here:
   weight's, two-matrix and gated experts, in one round, two and four; no token
   dropped, the counts unchanged;
 * an expert no token reaches gets an exactly zero gradient;
+* the round's own size, an eighth of the tokens, serves whatever the router
+  does: loads steered to one round, to its edge, to two, three and five rounds
+  give the value and the gradients of the layer at a quarter (bit for bit
+  where both take one round), no token dropped, never more rows multiplied
+  than the rounds of a quarter would; the lowered layer is one batched product
+  a matrix a round over the weights as they stand, with no branch;
 * what the layer takes and reports is what it took and reported;
 * the rows' way back to the tokens (``moe._rows_to_tokens``: the combine's
   forward and the dispatch gather's backward) is the sum written out as a
@@ -130,12 +136,12 @@ def _ffn(x, p, round_rows):
                                 round_rows=round_rows)
 
 
-# a held expert gets 170-200 of the 512 tokens: one round of 256 slots,
-# two of 128 (the default: a quarter of the tokens), and where every token
-# picks expert 0, four
+# a held expert gets 170-200 of the 512 tokens: one round of 256 slots, two
+# of 128 (a quarter of the tokens), four of 64 (the default: an eighth), and
+# where every token picks expert 0, four of 128
 @pytest.mark.parametrize("gated", [False, True], ids=["relu2", "gated"])
-@pytest.mark.parametrize("round_rows, favour, rounds", [(256, 0.0, 1), (None, 0.0, 2),
-                                                        (128, 20.0, 4)])
+@pytest.mark.parametrize("round_rows, favour, rounds", [(256, 0.0, 1), (None, 0.0, 4),
+                                                        (T // 4, 0.0, 2), (128, 20.0, 4)])
 def test_the_layer_with_the_gather_backward_is_the_layer_differentiated_automatically(
         monkeypatch, gated, round_rows, favour, rounds):
     x, p = _layer(gated)
@@ -155,7 +161,7 @@ def test_the_layer_with_the_gather_backward_is_the_layer_differentiated_automati
         results.append((out, aux, grads))
     (out_own, aux_own, grads_own), (out, aux, grads) = results
     assert int(aux_own["expert_rounds"]) == rounds and int(aux_own["tokens_dropped"]) == 0
-    assert int(jnp.max(aux_own["held_expert_tokens"])) > (rounds - 1) * (round_rows or T // 4)
+    assert int(jnp.max(aux_own["held_expert_tokens"])) > (rounds - 1) * (round_rows or T // 8)
     for key in aux:
         np.testing.assert_array_equal(aux[key], aux_own[key])
     # the forward is the parent's terms in the parent's order, multiplied and added
@@ -190,8 +196,131 @@ def test_the_layer_lowers_to_one_batched_product_a_round(gated):
     x, p = _layer(gated)
     text = jax.jit(lambda x_, p_: _ffn(x_, p_, None)[0]).lower(x, p).as_text()
     assert "pallas" not in text and "custom_call" not in text
-    # every slot of a round in one batched product: (held, rows, D) x (held, D, F)
-    assert f"tensor<{HELD}x{T // 4}x{LD}xf32>" in text and "dot_general" in text
+    # every slot of a round in one batched product: (held, rows, D) x (held, D, F),
+    # the rows an eighth of the tokens; round 0 and the loop's body, no branch
+    slots = f"(tensor<{HELD}x{T // 8}x{LD}xf32>, tensor<{HELD}x{LD}x{LF}xf32>)"
+    assert text.count(slots) == 2 * (2 if gated else 1) and "stablehlo.case" not in text
+    assert f"tensor<{HELD}x{T // 4}x{LD}xf32>" not in text
+
+
+# -- a round of an eighth of the tokens, whatever the router does ----------------------
+
+# (held, experts, top_k, the score): 8 held of 64 as the GLM, Xing, LFM2 and
+# SmallThinker cells hold them, 32 of 512 as Qwen3-Next's
+SHARES = {"8of64": (8, 64, 4, jax.nn.sigmoid), "32of512": (32, 512, 10, jax.nn.softmax)}
+STEERED_T = 2048  # its eighth is a round of 256 rows, its quarter one of 512
+# tokens sent to the first held expert (the others get under 256)
+STEERED = (200, 256, 257, 512, 513, 1100)
+
+
+def _steered_layer(share, gated, load):
+    """A layer whose router sends exactly ``load`` tokens (the first ones) to
+    the first held expert: feature 0 is one for them and feature 1 for the
+    rest, and that expert's two router weights decide."""
+    held, n_experts, _top_k, _score = SHARES[share]
+    first = n_experts // 8
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    p = {"router_w": (jax.random.normal(ks[1], (LD, n_experts)) * 0.3).at[:2].set(0.0)
+         .at[0, first].set(20.0).at[1, first].set(-20.0),
+         "w_up": jax.random.normal(ks[2], (held, LD, LF)) / 11,
+         "w_down": jax.random.normal(ks[3], (held, LF, LD)) / 8}
+    if gated:
+        p["w_gate"] = jax.random.normal(ks[4], (held, LD, LF)) / 11
+    chosen = (jnp.arange(STEERED_T) < load).astype(jnp.float32)
+    x = jax.random.normal(ks[0], (STEERED_T, LD)).at[:, 0].set(chosen).at[:, 1].set(1.0 - chosen)
+    return x, p, first
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["relu2", "gated"])
+@pytest.mark.parametrize("load", STEERED)
+@pytest.mark.parametrize("share", sorted(SHARES))
+def test_a_round_of_an_eighth_serves_whatever_the_router_does(monkeypatch, share, load, gated):
+    """At its own size the layer is the layer at a quarter of the tokens (the
+    size it had before PR 50): where both take one round its value bit for
+    bit in the compiled program (the same pairs through the same matrices, a
+    token's picks summed in the same order), past that the same terms summed a
+    round at a time; its gradients those of the layer at a quarter and of the
+    layer differentiated automatically. It never multiplies more rows than
+    the rounds of a quarter would."""
+    held, n_experts, top_k, score = SHARES[share]
+    x, p, first = _steered_layer(share, gated, load)
+    names = sorted(p)
+    eighth, quarter = STEERED_T // 8, STEERED_T // 4
+
+    def run(round_rows):
+        def loss(x_, weights):
+            out, aux = moe.held_experts_ffn(
+                x_, **dict(zip(names, weights)), first_held=first, n_experts=n_experts,
+                top_k=top_k, round_rows=round_rows, score=score)
+            return jnp.sum(out * jnp.cos(out)), (out, aux)
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))(
+            x, [p[k] for k in names])
+
+    (_, (out, aux)), grads = run(None)
+    counts = np.asarray(aux["held_expert_tokens"])
+    assert counts[0] == load == counts.max() and counts.shape == (held,)
+    rounds = -(-load // eighth)
+    assert int(aux["tokens_dropped"]) == 0 and int(aux["expert_rounds"]) == rounds
+    (_, (out_quarter, aux_quarter)), grads_quarter = run(quarter)
+    assert int(aux_quarter["expert_rounds"]) == -(-load // quarter)
+    assert rounds * eighth <= int(aux_quarter["expert_rounds"]) * quarter
+    np.testing.assert_array_equal(counts, aux_quarter["held_expert_tokens"])
+    if rounds == 1:
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(out_quarter))
+    else:
+        np.testing.assert_allclose(out, out_quarter, rtol=1e-6, atol=1e-6)
+    _automatic(monkeypatch)
+    (_, (out_auto, _)), grads_auto = run(None)
+    np.testing.assert_allclose(out, out_auto, rtol=1e-6, atol=1e-6)
+    for want in (grads_quarter, grads_auto):
+        for got, ref in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(want)):
+            np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
+
+
+def test_the_weights_are_read_by_the_rounds_products_and_by_nothing_else():
+    """The stacked matrices are arguments of the lowered layer, read by the
+    products of round 0 and carried by the loop over further rounds: nothing
+    makes an array of their shape."""
+    x, p = _layer(True)
+    text = jax.jit(lambda x_, p_: _ffn(x_, p_, None)[0]).lower(x, p).as_text()
+    main = next(line for line in text.splitlines() if "func.func public @main(" in line)
+    stacked = re.findall(rf"(%arg\d+): tensor<{HELD}x(?:{LD}x{LF}|{LF}x{LD})xf32>", main)
+    assert len(stacked) == 3
+    reads = re.compile(rf"({'|'.join(stacked)})\b")
+    readers = [line for line in text.splitlines() if line != main and reads.search(line)]
+    assert readers and all("stablehlo.dot_general" in r or "stablehlo.while" in r for r in readers)
+    assert text.count("stablehlo.while") == 1
+
+
+@pytest.mark.parametrize("model, config, layer, asked", [
+    ("nemotron_h", "NemotronHConfig", "_moe_mixer", lambda cfg, tokens: None),
+    ("glm4_moe_lite", "Glm4MoeLiteConfig", "_expert_ffn", lambda cfg, tokens: None),
+    ("lfm2_moe", "Lfm2MoeConfig", "_expert_ffn", lambda cfg, tokens: None),
+    ("smallthinker", "SmallThinkerConfig", None, lambda cfg, tokens: None),
+    ("xing4", "Xing4Config", "_expert_ffn", lambda cfg, tokens: tokens // 4),
+    ("qwen3_next", "Qwen3NextConfig", "_expert_ffn",
+     lambda cfg, tokens: 4 * tokens * cfg.num_experts_per_tok // cfg.num_experts),
+])
+@pytest.mark.parametrize("tokens", [1024, 4096])
+def test_which_round_each_model_asks_for(monkeypatch, model, config, layer, asked, tokens):
+    """Four models take the layer's own round; the two whose held experts'
+    mean load is light hand four times it, which for Xing (4 picks of 64) is
+    the quarter of the tokens it had: a second round reads the experts'
+    matrices again, and products that short wait for those."""
+    import collections
+    import importlib
+
+    module = importlib.import_module(f"byzpy_tpu.models.{model}")
+    cfg = getattr(module, config)()
+    if layer is None:  # written into the block
+        assert "round_rows" not in inspect.getsource(module.decoder_block)
+        return
+    seen = {}
+    monkeypatch.setattr(module, "held_experts_ffn",
+                        lambda x, *args, **kwargs: seen.update(kwargs) or (x, {}))
+    getattr(module, layer)(collections.defaultdict(lambda: None), jnp.zeros((tokens, 8)), cfg)
+    assert seen.get("round_rows") == asked(cfg, tokens)
 
 
 # -- many held experts with a light load each: 32 of 512, top-10 by a softmax ------
@@ -482,7 +611,7 @@ def _dense_reference(x, p, first_held=0):
 def test_the_layer_is_the_dense_reference_differentiated_by_jax_grad(monkeypatch, gated, route):
     """Value and the gradients of x, router, up, down (and gate) against
     ``jax.grad`` of the dense every-expert reference, at a size whose fullest
-    expert needs a second round; with the kernel serving too."""
+    expert needs four rounds; with the kernel serving too."""
     if route == "kernel":
         monkeypatch.setattr(moe, "rows_to_tokens_serves", _kernel_serves)
     x, p = _layer(gated)
@@ -496,7 +625,7 @@ def test_the_layer_is_the_dense_reference_differentiated_by_jax_grad(monkeypatch
 
     with jax.default_matmul_precision("highest"):
         aux = _ffn(x, p, None)[1]
-        assert int(aux["expert_rounds"]) == 2 and int(aux["tokens_dropped"]) == 0
+        assert int(aux["expert_rounds"]) == 4 and int(aux["tokens_dropped"]) == 0
         (_, out), grads = loss(lambda x_, p_: _ffn(x_, p_, None)[0])
     (_, out_want), grads_want = loss(_dense_reference)
     np.testing.assert_allclose(out, out_want, rtol=2e-4, atol=2e-5)

@@ -852,7 +852,8 @@ def _write_tpu_texts(out_dir):
 
         text = jax.jit(jax.grad(
             lambda x, w: jnp.sum(moe.held_experts_ffn(
-                x, *w, first_held=0, n_experts=n_experts, top_k=top_k, round_rows=round_rows)[0]),
+                x, *w, first_held=0, n_experts=n_experts, top_k=top_k,
+                round_rows=None if round_rows == EXPERT_TOKENS // 8 else round_rows)[0]),
             argnums=(0, 1))).lower(
             f32(EXPERT_TOKENS, d),
             (f32(d, n_experts), f32(held, d, 128), f32(held, 128, d))).compile().as_text()
@@ -861,9 +862,10 @@ def _write_tpu_texts(out_dir):
 
 
 # the language cells' expert layers: experts, picks a token, experts held, a
-# round's rows, hidden size (`chipbench/configs/`), at a worker's 4096 tokens
+# round's rows (the layer's own eighth of the tokens; Qwen3-Next's four times
+# the mean load), hidden size (`chipbench/configs/`), at a worker's 4096 tokens
 EXPERT_TOKENS = 4096
-EXPERT_CELLS = {"nemotron": (128, 6, 8, 1024, 2688), "glm": (64, 4, 8, 1024, 2048),
+EXPERT_CELLS = {"nemotron": (128, 6, 8, 512, 2688), "glm": (64, 4, 8, 512, 2048),
                 "qwen": (512, 10, 32, 320, 2048)}
 
 

@@ -171,7 +171,7 @@ def test_rounds_of_any_size_are_the_reference_whatever_the_router_does(round_row
     np.testing.assert_array_equal(aux["held_expert_tokens"], want_counts)
     assert int(aux["tokens_dropped"]) == 0
     counts = np.asarray(want_counts)
-    assert int(aux["expert_rounds"]) == max(1, -(-int(counts.max()) // (round_rows or 56)))
+    assert int(aux["expert_rounds"]) == max(1, -(-int(counts.max()) // (round_rows or 32)))  # its own: an eighth of 200, in whole sublanes
     if skew:  # one expert far over the others
         assert counts.max() > 2 * np.median(counts)
 
