@@ -31,7 +31,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from chipbench import opcount, reference, seeded, stated_types
+from chipbench import expert_round, opcount, reference, seeded, stated_types
 from chipbench.drivers.train_round_streamed import (
     _difference_norms, _Lane, _leaf_norms, _short_mantissa)
 from chipbench.harness import Ctx, resolve
@@ -106,7 +106,7 @@ def run(ctx: Ctx) -> Dict[str, Any]:
     d = sum(int(np.prod(leaf.shape)) for leaf in jax.tree_util.tree_leaves(shapes))
     d_largest = max(sum(int(np.prod(leaf.shape)) for leaf in jax.tree_util.tree_leaves(sub))
                     for sub in shapes.values())
-    params = seeded_lm.make_params(shapes, ctx.seed, arch)
+    params = seeded_lm.make_params(shapes, ctx.weights_seed, arch)
     bundle = held.pop("bundle").with_params(params)
     aggregate = partial(resolve(mix["aggregate"]["fn"]), **mix["aggregate"].get("kwargs", {}))
     attack = RoundAttack(resolve(mix["attack"]["fn"]), of=mix["attack"].get("input", "honest"),
@@ -172,7 +172,7 @@ def run(ctx: Ctx) -> Dict[str, Any]:
     short_share = (sum(int(v) for v in short) / max(1, sum(int(v) for v in nonzero)))
     for _ in range(followed_rounds - 1):
         robust.advance()
-    got_change = _change_norms(robust.params, shapes, ctx.seed, arch, seeded_lm)
+    got_change = _change_norms(robust.params, shapes, ctx.weights_seed, arch, seeded_lm)
     steps(robust, 1)
     platforms = {dev.platform
                  for leaf in jax.tree_util.tree_leaves((robust.params, robust.opt))
@@ -266,12 +266,17 @@ def run(ctx: Ctx) -> Dict[str, Any]:
             expert_layer_passes_with_more_than_one_round=int(
                 np.count_nonzero(counts["rounds"] > 1)),
             of_expert_layer_passes=int(counts["rounds"].size))
+    # each layer's fullest expert beside the rows of its round (the untraced
+    # run reads the compiled text here, after the window: no part of set-up)
+    loads = expert_round.facts(robust.aux[0], counts["tokens"], compiled_text or step.as_text())
+    out["measured"].update(loads)
+    ctx.say(**loads)
 
     # -- the chip to the reference: the program's state and executable go
     del step
     robust.params = robust.opt = robust.step = None
     gc.collect()
-    params0 = seeded_lm.make_params(shapes, ctx.seed, arch)
+    params0 = seeded_lm.make_params(shapes, ctx.weights_seed, arch)
     followed = resolve(ref_cfg["follow_rounds"])(
         arch, params0,
         [(xs[i % pool], ys[i % pool]) for i in range(followed_rounds)],
@@ -281,7 +286,7 @@ def run(ctx: Ctx) -> Dict[str, Any]:
         dtype=jnp.dtype(ref_cfg["dtype"]), precision=ref_cfg["precision"], report=ctx.say,
     )
     del params0
-    want_change = _change_norms(followed.pop("params"), shapes, ctx.seed, arch, seeded_lm)
+    want_change = _change_norms(followed.pop("params"), shapes, ctx.weights_seed, arch, seeded_lm)
     want_first = followed["first_aggregate_leaf_norms"]
     got_tokens = counts["tokens"][:followed_rounds]
     want_tokens = followed["held_expert_tokens"]

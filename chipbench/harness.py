@@ -14,6 +14,7 @@ import json
 import os
 import shutil
 import statistics
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence
@@ -70,6 +71,15 @@ class Ctx:
     outcome: Dict[str, Any] = field(default_factory=dict)
     peaks: Dict[str, Any] = field(default_factory=dict)
     _tracing: bool = False
+
+    @property
+    def weights_seed(self) -> int:
+        """What the WEIGHTS are drawn from: the configuration's
+        ``checkpoint_seed`` where it states one (a deployment trains one
+        checkpoint on changing data, so which model is no part of the
+        traffic), else ``--seed`` as ever. Batches, step keys and the
+        aggregator's roofline matrix are ``--seed``'s in either case."""
+        return int(self.config.get("checkpoint_seed", self.seed))
 
     def say(self, **facts: Any) -> None:
         """One information line; every line names the device."""
@@ -209,8 +219,8 @@ def run_cell(
     first = ctx.devices[0]
     if first.platform == "tpu" and first.device_kind not in ctx.peaks["devices"]:
         raise SystemExit(f"chipbench: no peaks for device kind {first.device_kind!r}")
-    ctx.say(run="start", seed=seed, seconds=seconds, trace=int(trace), control=control,
-            config=cell["config"], traffic=cell["traffic"])
+    ctx.say(run="start", seed=seed, weights_seed=ctx.weights_seed, seconds=seconds,
+            trace=int(trace), control=control, config=cell["config"], traffic=cell["traffic"])
     driver = importlib.import_module("chipbench.drivers." + mix["driver"])
     out = driver.run(ctx)
     ctx.outcome = out
@@ -245,13 +255,19 @@ def run_cell(
             metrics[metric["name"]] = {"value": values[metric["name"]], "unit": metric["unit"]}
 
     correct = True
+    compared: Dict[str, Any] = {}
     for name, value, op, limit in out["checks"]:
         ok = {"<=": value <= limit, "<": value < limit, "==": value == limit,
               ">": value > limit}[op]
         ok = bool(ok) and bool(value == value)  # a NaN never passes
         correct = correct and ok
         ctx.say(compared=name, value=value, must_be=op, limit=limit, ok=ok)
+        # and once more where the record of a run that is not correct keeps them:
+        # the end of standard error, and the result line's last key
+        compared[name] = {"value": value, "must_be": op, "limit": limit, "ok": ok}
+        print(json.dumps({"compared": name, **compared[name]}, default=float),
+              file=sys.stderr, flush=True)
     line = {"correct": correct, "attempted": out["attempted"], "failed": out["failed"],
-            "metrics": metrics, "device": device, **line}
+            "metrics": metrics, "device": device, **line, "compared": compared}
     emit(json.dumps(line, default=float))
     return line

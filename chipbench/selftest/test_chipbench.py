@@ -18,7 +18,8 @@ from chipbench.selftest import manifest_rules
 
 ROOT = harness.ROOT
 HERE = os.path.dirname(os.path.abspath(__file__))
-CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# the five the driver reads, and the numbers compared beside their limits, last in the line
+CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 def _real_manifest():
@@ -106,8 +107,11 @@ def test_manifest_rules_refuse_what_the_ledger_refused():
                                   "toy.trimmed-signflip.mesh4"])
 def test_toy_cell_prints_the_contracts_line(cell):
     line = _run_toy(cell, trace=False)
-    assert set(line) == CONTRACT_KEYS
+    assert set(line) == CONTRACT_KEYS and list(line)[-1] == "compared"
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
+    assert line["compared"] and all(
+        set(said) == {"value", "must_be", "limit", "ok"} and said["ok"] is True
+        for said in line["compared"].values())
     assert set(line["metrics"]) == {"train_samples_per_s", "setup_s"}
     assert all(set(v) == {"value", "unit"} for v in line["metrics"].values())
     assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}

@@ -78,7 +78,8 @@ def test_streamed_qwen3_toy_cell_traced_feeds_the_accepted_readers_and_its_own()
     # the thirteen readers without a list read this cell as they read the others
     manifest = _real_manifest()
     unlisted = {m["name"] for m in manifest["per_layer"] if "workloads" not in m}
-    assert len(unlisted) == 13 and unlisted - {"agg_roofline.train"} <= got
+    # (thirteen when the cell was added; `matrix_build_device_ms.train` got a list at PR 44)
+    assert 12 <= len(unlisted) <= 13 and unlisted - {"agg_roofline.train"} <= got
     # no peak on a CPU: the shares of one are None here, as in the other rehearsals
     on_a_cpu = {"attention_kernel_mxu_pct.train", "delta_rule_roofline_pct.train"}
     assert (APPENDED | NEW) - on_a_cpu <= got
@@ -155,7 +156,8 @@ def test_the_qwen3_next_cell_is_in_the_manifest_and_the_manifest_meets_the_rules
     cell = harness.find_cell(manifest, CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "qwen3-next-ep16-ps", "trimmed-signflip-tok4k-lm", 1)
-    assert manifest["workloads"][-1] == cell and manifest["configs"][-1]["name"] == cell["config"]
+    # (by name, not by place: later configurations are appended behind this one)
+    assert cell["config"] in [c["name"] for c in manifest["configs"]]
     mine = {m["name"] for m in harness.metrics_of_cell(manifest, CELL, "per_layer")}
     assert APPENDED | NEW <= mine
     for absent in ("ssm_scan_device_ms.train", "mla_latent_device_ms.train",
@@ -164,10 +166,12 @@ def test_the_qwen3_next_cell_is_in_the_manifest_and_the_manifest_meets_the_rules
     by_name = {m["name"]: m for m in manifest["per_layer"]}
     for name in NEW:  # a reader that may return None has a list from the start
         assert by_name[name]["workloads"] == [CELL]
-    assert [m["name"] for m in manifest["per_layer"][-2:]] == sorted(NEW)
+    names = [m["name"] for m in manifest["per_layer"]]
+    at = names.index(sorted(NEW)[0])  # appended together, in this order
+    assert names[at:at + 2] == sorted(NEW)
     assert by_name["delta_rule_roofline_pct.train"]["layer"] == "kernels"
-    # six cells, one of them on four chips
-    assert len(manifest["workloads"]) == 6
+    # six cells then, more since; one of them on four chips
+    assert len(manifest["workloads"]) >= 6
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
 
 

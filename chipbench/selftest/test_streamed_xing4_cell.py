@@ -77,7 +77,8 @@ def test_streamed_xing4_toy_cell_traced_feeds_the_accepted_readers_and_its_own()
     got = set(line["metrics"])
     manifest = _real_manifest()
     unlisted = {m["name"] for m in manifest["per_layer"] if "workloads" not in m}
-    assert len(unlisted) == 13 and unlisted - {"agg_roofline.train"} <= got
+    # (thirteen when the cell was added; `matrix_build_device_ms.train` got a list at PR 44)
+    assert 12 <= len(unlisted) <= 13 and unlisted - {"agg_roofline.train"} <= got
     # no peak on a CPU: the shares of one are None here, as in the other rehearsals
     on_a_cpu = {"hc_hbm_roofline_pct.train", "attention_qk_v_mxu_pct.train"}
     assert (APPENDED | set(NEW)) - on_a_cpu <= got
@@ -173,8 +174,10 @@ def test_the_xing4_cell_is_in_the_manifest_and_the_manifest_meets_the_rules():
                    "mtp_device_ms.train", "attention_kernel_mxu_pct.train"):
         assert absent not in mine
     by_name = {m["name"]: m for m in manifest["per_layer"]}
-    for name in NEW:  # a reader that may return None has a list from the start
-        assert by_name[name]["workloads"] == [CELL]
+    for name in NEW:  # a reader that may return None has a list from the start;
+        # a later cell with the same mechanism is appended to it (LFM2: the products' widths)
+        assert by_name[name]["workloads"][0] == CELL
+    assert by_name["hc_device_ms.train"]["workloads"] == [CELL]
     assert set(NEW) <= set(by_name)
     assert by_name["hc_hbm_roofline_pct.train"]["layer"] == "kernels"
     # the mix is the accepted one at a quarter of the tokens, and nothing else
