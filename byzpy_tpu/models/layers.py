@@ -102,21 +102,22 @@ def rotary(x: Array, theta: float, scaling: Optional[YarnScaling] = None) -> Arr
     halves' padded cotangents added up fed the weight-gradient product of
     the shared rotary key on the v5e's compiler in a form that lost it
     (PERF.md, PR 34)."""
-    t, dim = x.shape[0], x.shape[-1]
-    half = dim // 2
-    if scaling is None:
-        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dim)
-    else:
-        freq = scaling.frequencies(dim, theta)
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    if scaling is not None and scaling.rotary_scale != 1.0:
-        cos, sin = cos * scaling.rotary_scale, sin * scaling.rotary_scale
-    # turn[t, out, in, i]: out = 0 reads (cos, -sin) of (a, b), out = 1 (sin, cos)
-    turn = jnp.stack([jnp.stack([cos, -sin], axis=1), jnp.stack([sin, cos], axis=1)], axis=1)
-    turn = turn.reshape(t, *(1,) * (x.ndim - 2), 2, 2, half).astype(x.dtype)
-    pairs = x.reshape(*x.shape[:-1], 1, 2, half)
-    return jnp.sum(turn * pairs, axis=-2).reshape(x.shape)
+    with jax.named_scope("model.rotary"):
+        t, dim = x.shape[0], x.shape[-1]
+        half = dim // 2
+        if scaling is None:
+            freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dim)
+        else:
+            freq = scaling.frequencies(dim, theta)
+        angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
+        cos, sin = jnp.cos(angle), jnp.sin(angle)
+        if scaling is not None and scaling.rotary_scale != 1.0:
+            cos, sin = cos * scaling.rotary_scale, sin * scaling.rotary_scale
+        # turn[t, out, in, i]: out = 0 reads (cos, -sin) of (a, b), out = 1 (sin, cos)
+        turn = jnp.stack([jnp.stack([cos, -sin], axis=1), jnp.stack([sin, cos], axis=1)], axis=1)
+        turn = turn.reshape(t, *(1,) * (x.ndim - 2), 2, 2, half).astype(x.dtype)
+        pairs = x.reshape(*x.shape[:-1], 1, 2, half)
+        return jnp.sum(turn * pairs, axis=-2).reshape(x.shape)
 
 
 def token_embedding(dtype: Any):
@@ -238,12 +239,11 @@ def blocked_causal_attention(q: Array, k: Array, v: Array, query_block: int,
     ``(T, kv * per * head_dim)``. With ``window = W`` query ``i`` reads keys
     ``j`` with ``0 <= i - j < W`` (the kernels' ``window``); ``None`` adds
     no op. Each block is rematerialised in the backward pass, so the score
-    matrix alive at once is ``(heads, query_block, T)``."""
+    matrix alive at once is ``(heads, query_block, T)``. Its ops stand under
+    ``model.attention_core``, the label the kernels' call enters."""
     t, kv, per, hd = q.shape
     block = min(query_block, t)
     pad = -t % block
-    q = jnp.pad(q, ((0, pad), (0, 0), (0, 0), (0, 0))).reshape(-1, block, kv, per, hd)
-    starts = jnp.arange(q.shape[0]) * block
 
     @jax.checkpoint
     def one_block(args):
@@ -256,7 +256,20 @@ def blocked_causal_attention(q: Array, k: Array, v: Array, query_block: int,
         probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
         return jnp.einsum("grqk,kgd->qgrd", probs.astype(q.dtype), v)
 
-    return jax.lax.map(one_block, (q, starts)).reshape(-1, kv * per * hd)[:t]
+    with jax.named_scope("model.attention_core"):
+        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0), (0, 0))).reshape(-1, block, kv, per, hd)
+        starts = jnp.arange(q.shape[0]) * block
+        return jax.lax.map(one_block, (q, starts)).reshape(-1, kv * per * hd)[:t]
+
+
+def attention_proj(x: Array, w: Array) -> Array:
+    """``x @ w`` in ``x``'s dtype under ``model.attention_proj``: attention's
+    products with ``w_q``, ``w_k``, ``w_v`` and ``w_o`` (Qwen3-Next's
+    ``w_q_gate`` too) in every model of this package, so that one label says
+    what of ``model.attention`` is projections. Latent attention's own down-
+    and up-projections stay ``model.mla_latent``."""
+    with jax.named_scope("model.attention_proj"):
+        return x @ w.astype(x.dtype)
 
 
 def mla_attention(p: Dict[str, Array], x: Array, cfg: Any) -> Array:
@@ -313,10 +326,11 @@ def mla_attention(p: Dict[str, Array], x: Array, cfg: Any) -> Array:
             q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, width - a.shape[-1]))) for a in (q, k, v))
             out = blocked_causal_attention(q[:, :, None, :], k, v, cfg.query_block)
             out = out.reshape(t, heads, width)[..., :vd].reshape(t, heads * vd)
-        return out @ p["w_o"].astype(x.dtype)
+        return attention_proj(out, p["w_o"])
 
 
 __all__ = [
+    "attention_proj",
     "YarnScaling",
     "blocked_causal_attention",
     "causal_depthwise_conv",

@@ -64,6 +64,7 @@ from ..parallel.moe import held_experts_ffn
 from .bundle import ModelBundle, Segment
 from .glm4_moe_lite import _gated_mlp  # the same SiLU-gated MLP, under model.mlp
 from .layers import (
+    attention_proj,
     blocked_causal_attention,
     cross_entropy,
     gated_short_conv,
@@ -145,16 +146,16 @@ def gqa_attention(p: Dict[str, Array], x: Array, cfg: Lfm2MoeConfig) -> Array:
         def placed(a, scale):  # (T, n, head_dim): normed, then turned
             return rotary(rms_norm(a, scale, cfg.norm_eps), cfg.rope_theta)
 
-        q = placed((x @ p["w_q"].astype(x.dtype)).reshape(t, heads, hd), p["q_norm_scale"])
-        k = placed((x @ p["w_k"].astype(x.dtype)).reshape(t, kv, hd), p["k_norm_scale"])
-        v = x @ p["w_v"].astype(x.dtype)
+        q = placed(attention_proj(x, p["w_q"]).reshape(t, heads, hd), p["q_norm_scale"])
+        k = placed(attention_proj(x, p["w_k"]).reshape(t, kv, hd), p["k_norm_scale"])
+        v = attention_proj(x, p["w_v"])
         if causal_attention_serves(x, hd):
             out = causal_attention(q.reshape(t, heads * hd), k.reshape(t, kv * hd), v,
                                    kv_heads=kv)
         else:
             out = blocked_causal_attention(q.reshape(t, kv, heads // kv, hd), k,
                                            v.reshape(t, kv, hd), cfg.query_block)
-        return out @ p["w_o"].astype(x.dtype)
+        return attention_proj(out, p["w_o"])
 
 
 def _expert_ffn(p: Dict[str, Array], x: Array, cfg: Lfm2MoeConfig):

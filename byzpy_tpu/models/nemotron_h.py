@@ -36,6 +36,7 @@ from ..ops.pallas_attention import causal_attention, causal_attention_serves
 from ..parallel.moe import held_experts_ffn
 from .bundle import ModelBundle, Segment
 from .layers import (
+    attention_proj,
     blocked_causal_attention,
     causal_depthwise_conv,
     conv_silu,
@@ -201,15 +202,15 @@ def gqa_attention(p: Dict[str, Array], x: Array, cfg: NemotronHConfig) -> Array:
         heads, kv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         if causal_attention_serves(x, hd):
             out = causal_attention(
-                x @ p["w_q"].astype(x.dtype), x @ p["w_k"].astype(x.dtype),
-                x @ p["w_v"].astype(x.dtype), kv_heads=kv)
-            return out @ p["w_o"].astype(x.dtype)
+                attention_proj(x, p["w_q"]), attention_proj(x, p["w_k"]),
+                attention_proj(x, p["w_v"]), kv_heads=kv)
+            return attention_proj(out, p["w_o"])
         per = heads // kv
-        q = (x @ p["w_q"].astype(x.dtype)).reshape(t, kv, per, hd)
-        k = (x @ p["w_k"].astype(x.dtype)).reshape(t, kv, hd)
-        v = (x @ p["w_v"].astype(x.dtype)).reshape(t, kv, hd)
+        q = attention_proj(x, p["w_q"]).reshape(t, kv, per, hd)
+        k = attention_proj(x, p["w_k"]).reshape(t, kv, hd)
+        v = attention_proj(x, p["w_v"]).reshape(t, kv, hd)
         out = blocked_causal_attention(q, k, v, cfg.query_block)
-        return out @ p["w_o"].astype(x.dtype)
+        return attention_proj(out, p["w_o"])
 
 
 # --------------------------------------------------------------------------

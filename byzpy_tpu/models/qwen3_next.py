@@ -57,6 +57,7 @@ from ..ops.pallas_attention import causal_attention, causal_attention_serves
 from ..parallel.moe import held_experts_ffn
 from .bundle import ModelBundle, Segment
 from .layers import (
+    attention_proj,
     blocked_causal_attention,
     conv_silu,
     cross_entropy,
@@ -285,17 +286,17 @@ def gated_attention(p: Dict[str, Array], x: Array, cfg: Qwen3NextConfig) -> Arra
             return jnp.concatenate(
                 [rotary(a[..., :turned], cfg.rope_theta), a[..., turned:]], axis=-1)
 
-        q = placed((x @ p["w_q"].astype(x.dtype)).reshape(t, heads, hd), p["q_norm_weight"])
-        k = placed((x @ p["w_k"].astype(x.dtype)).reshape(t, kv, hd), p["k_norm_weight"])
-        v = x @ p["w_v"].astype(x.dtype)
+        q = placed(attention_proj(x, p["w_q"]).reshape(t, heads, hd), p["q_norm_weight"])
+        k = placed(attention_proj(x, p["w_k"]).reshape(t, kv, hd), p["k_norm_weight"])
+        v = attention_proj(x, p["w_v"])
         if causal_attention_serves(x, hd):
             out = causal_attention(q.reshape(t, heads * hd), k.reshape(t, kv * hd), v,
                                    kv_heads=kv)
         else:
             out = blocked_causal_attention(q.reshape(t, kv, heads // kv, hd), k,
                                            v.reshape(t, kv, hd), cfg.query_block)
-        out = out * jax.nn.sigmoid(x @ p["w_q_gate"].astype(x.dtype))
-        return out @ p["w_o"].astype(x.dtype)
+        out = out * jax.nn.sigmoid(attention_proj(x, p["w_q_gate"]))
+        return attention_proj(out, p["w_o"])
 
 
 # --------------------------------------------------------------------------

@@ -58,7 +58,14 @@ import jax.numpy as jnp
 from ..ops.pallas_attention import causal_attention, causal_attention_serves
 from ..parallel.moe import held_experts_ffn
 from .bundle import ModelBundle, Segment
-from .layers import blocked_causal_attention, cross_entropy, rms_norm, rotary, token_embedding
+from .layers import (
+    attention_proj,
+    blocked_causal_attention,
+    cross_entropy,
+    rms_norm,
+    rotary,
+    token_embedding,
+)
 
 Array = jnp.ndarray
 
@@ -112,9 +119,9 @@ def attention(p: Dict[str, Array], u: Array, cfg: SmallThinkerConfig,
         t = u.shape[0]
         heads, kv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         window = cfg.sliding_window_size if windowed else None
-        q = (u @ p["w_q"].astype(u.dtype)).reshape(t, heads, hd)
-        k = (u @ p["w_k"].astype(u.dtype)).reshape(t, kv, hd)
-        v = u @ p["w_v"].astype(u.dtype)
+        q = attention_proj(u, p["w_q"]).reshape(t, heads, hd)
+        k = attention_proj(u, p["w_k"]).reshape(t, kv, hd)
+        v = attention_proj(u, p["w_v"])
         if turned:
             q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
         if causal_attention_serves(u, hd):
@@ -123,7 +130,7 @@ def attention(p: Dict[str, Array], u: Array, cfg: SmallThinkerConfig,
         else:
             out = blocked_causal_attention(q.reshape(t, kv, heads // kv, hd), k,
                                            v.reshape(t, kv, hd), cfg.query_block, window)
-        return out @ p["w_o"].astype(u.dtype)
+        return attention_proj(out, p["w_o"])
 
 
 def decoder_block(p: Dict[str, Array], h: Array, cfg: SmallThinkerConfig,

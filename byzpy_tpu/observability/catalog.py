@@ -143,6 +143,13 @@ SPAN_PREFIXES: Tuple[str, ...] = ("chaos.",)
 SCOPES: FrozenSet[str] = frozenset(
     {
         "model.attention",
+        # inside model.attention: the kernels with what their call puts around
+        # them (attention_core_device_ms.train; less the kernels' own time,
+        # attention_wrap_device_ms.train)
+        "model.attention_core",
+        # inside model.attention: the products with w_q, w_k, w_v, w_o
+        # (attention_proj_device_ms.train)
+        "model.attention_proj",
         "model.delta_rule",
         "model.embed",
         "model.hc_maps",
@@ -156,6 +163,11 @@ SCOPES: FrozenSet[str] = frozenset(
         "model.mtp",
         "model.mtp_join",
         "model.norm",
+        # inside model.attention (latent attention: inside model.mla_latent,
+        # whose part by last label it leaves; mla_latent_device_ms.train asks
+        # what a path HOLDS and keeps it): the turn by position
+        # (rotary_device_ms.train)
+        "model.rotary",
         "model.short_conv",
         "model.short_conv_proj",
         "model.ssm_gate",

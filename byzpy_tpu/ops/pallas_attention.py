@@ -811,7 +811,9 @@ def causal_attention(q: Array, k: Array, v: Array, *, kv_heads: int,
         scale = 1.0 / math.sqrt(qk_dim)
     if window is not None and window >= q.shape[0]:
         window = None
-    return _causal_attention(q, k, v, kv_heads, scale, _pk._resolve_interpret(interpret), window)
+    with jax.named_scope("model.attention_core"):
+        return _causal_attention(
+            q, k, v, kv_heads, scale, _pk._resolve_interpret(interpret), window)
 
 
 def _padded(t_pad: int, *arrays):
@@ -852,8 +854,8 @@ def _causal_attention_fwd(q, k, v, kv_heads, scale, interpret, window):
 
 
 def _causal_attention_bwd(kv_heads, scale, interpret, window, residuals, d_out):
-    # the backward rule is traced outside the scope the forward stood in
-    with jax.named_scope("model.attention"):
+    # the backward rule is traced outside the scopes the forward stood in
+    with jax.named_scope("model.attention"), jax.named_scope("model.attention_core"):
         q, k, v, out, lse = residuals
         t = q.shape[0]
         steps, per, _, _, _ = _widths(q, k, v, kv_heads)

@@ -27,6 +27,7 @@ from byzpy_tpu.analysis.rules import METRIC_CONTRACT
 from byzpy_tpu.observability import catalog
 from byzpy_tpu.ops import attack_ops, coordinatewise, robust
 from byzpy_tpu.parallel.ps import PSStepConfig, build_ps_train_step
+from chipbench import harness, scope_parts, scope_paths
 from chipbench.scope_parts import UNLABELLED, part_of  # the readers' own rule
 
 N, B = 8, 2
@@ -53,6 +54,12 @@ PARTS = {"nemotron": ["model.norm", "model.embed", "model.head", "model.ssm_proj
          # (which reads the block's normed input) under the expert layers' own
          "small": ["model.norm", "model.embed", "model.head", "model.attention",
                    "model.moe_route", "model.moe_experts", "stream.rows", "stream.boundary"]}
+# PR 53: what attention does is three parts more (no turn by position in Nemotron-H)
+INSIDE_ATTENTION = ("model.attention_proj", "model.rotary", "model.attention_core")
+WHOLE_ATTENTION = re.compile(r"model\.attention(?!_)")  # the label itself, not its prefix
+for _model, _parts in PARTS.items():
+    _parts.extend(label for label in INSIDE_ATTENTION
+                  if (_model, label) != ("nemotron", "model.rotary"))
 
 
 def _toy(model):
@@ -151,12 +158,18 @@ def _renamed(text):
 
 
 @pytest.fixture(scope="module", params=["nemotron", "glm", "qwen", "xing", "lfm", "small"])
-def step_text(request):
-    """``(model, segment keys, [(opcode, op_name)] of the compiled step, its
-    bare text)``."""
+def compiled_text(request):
+    """``(model, segment keys, the compiled step's text)``."""
     with _no_compile_cache():
         keys, lowered = _lowered(request.param)
-        text = lowered.compile().as_text()
+        return request.param, keys, lowered.compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def step_text(compiled_text):
+    """``(model, segment keys, [(opcode, op_name)] of the compiled step, its
+    bare text)``."""
+    model, keys, text = compiled_text
     ops = []
     for line in text.splitlines():
         m = re.search(r'op_name="([^"]*)"', line)
@@ -167,7 +180,7 @@ def step_text(request):
         if (m and called and called.group(1) != "parameter"
                 and m.group(1).startswith("jit(train_step)/")):
             ops.append((called.group(1), m.group(1)))
-    return request.param, keys, ops, _bare(text)
+    return model, keys, ops, _bare(text)
 
 
 # What may stand under round.fwdbwd with no part: the blocks' residual adds
@@ -195,7 +208,9 @@ def test_every_op_of_round_fwdbwd_holds_a_part_but_for_a_short_allow_list(step_t
 def test_every_part_appears_and_the_catalog_lists_it(step_text):
     model, _, ops, _ = step_text
     seen = {part_of(name) for _, name in ops if "round.fwdbwd" in name}
-    assert set(PARTS[model]) <= seen
+    # (the toy GLM's attention is its latents, its turn, its core and w_o's
+    # product: with the three labels inside it nothing ends at model.attention)
+    assert set(PARTS[model]) - ({"model.attention"} if model == "glm" else set()) <= seen
     assert seen - {UNLABELLED} <= set(catalog.SCOPES)
     # nothing of round.fwdbwd's parts leaks out of it
     assert {part_of(name) for _, name in ops
@@ -376,12 +391,157 @@ def test_both_kinds_of_attention_hold_one_label_and_the_router_the_expert_layers
     for key in keys[1:-1]:
         for label in ("model.attention", "model.moe_route", "model.moe_experts"):
             mine = [name for _, name in ops if f"segment.{key}/" in name and label in name]
-            assert mine and all(part_of(name) == label for name in mine), (key, label)
+            # (of model.attention's ops: those that hold none of the labels inside it)
+            assert mine and all(part_of(name) == label for name in mine if not any(
+                inside in name for inside in INSIDE_ATTENTION)), (key, label)
             for a_pass in ("round.segment_fwd", "round.segment_recompute", "round.segment_bwd"):
                 assert any(a_pass in name for name in mine), (key, label, a_pass)
     turned = [name for _, name in ops if "model.attention" in name
               and re.search(r"/(?:cos|sin)$", name)]
-    assert turned and all("segment.seg02_window/" in name for name in turned)
+    assert turned and all("segment.seg02_window/" in name and part_of(name) == "model.rotary"
+                          for name in turned)
+    assert not [name for _, name in ops if "model.rotary" in name
+                and "segment.seg02_window/" not in name]
+
+
+def test_what_attention_does_is_three_labels_inside_it_in_every_pass(step_text):
+    """``model.attention_proj``, ``model.rotary`` (not Nemotron-H's: no
+    positional term) and ``model.attention_core``: each in all three passes
+    of a block with attention, each an op's part where it is the last label
+    (a head's norm in front of a turn stays ``model.norm``), and nothing of
+    them outside ``model.attention``."""
+    model, keys, ops, _ = step_text
+    for label in (label for label in INSIDE_ATTENTION if label in PARTS[model]):
+        mine = [name for _, name in ops if label in name]
+        assert mine and all(part_of(name) in (label, "model.norm") for name in mine), label
+        assert any(part_of(name) == label for name in mine)
+        for a_pass in ("round.segment_fwd", "round.segment_recompute", "round.segment_bwd"):
+            assert any(a_pass in name for name in mine), (label, a_pass)
+        # a block's: never the embedding's segment, never the head's
+        assert all(any(f"segment.{key}/" in name for key in keys[1:-1])
+                   or "round.segment_fwd" in name for name in mine)
+    if model == "nemotron":
+        assert not [name for _, name in ops if "model.rotary" in name]
+
+
+def test_nothing_of_the_three_labels_leaks_out_of_model_attention(step_text):
+    """Every caller of ``rotary``, ``attention_proj``, ``causal_attention``
+    and ``blocked_causal_attention`` stands inside ``model.attention``: an op
+    whose path holds one of the three holds ``model.attention`` itself too
+    (the label is a prefix of two of them: asked by ``WHOLE_ATTENTION``)."""
+    _, _, ops, _ = step_text
+    held = [name for _, name in ops if any(label in name for label in INSIDE_ATTENTION)]
+    assert held and all(WHOLE_ATTENTION.search(name) for name in held)
+
+
+def test_the_three_labels_never_nest_in_one_another_and_the_products_are_projs(step_text):
+    model, _, ops, _ = step_text
+    for name in {name for _, name in ops}:
+        assert sum(label in name for label in INSIDE_ATTENTION) <= 1, name
+    # every product of model.attention's own is a projection or the core's
+    # (latent attention: or the latents')
+    products = [name for opcode, name in ops if "model.attention" in name
+                and name.endswith("dot_general")]
+    assert products and {part_of(name) for name in products} <= {
+        "model.attention_proj", "model.attention_core", "model.mla_latent"}
+    assert not [name for _, name in ops if part_of(name) == "model.rotary"
+                and name.endswith("dot_general")]
+
+
+def test_the_cores_backward_stays_in_the_core(step_text):
+    """The core's backward ops hold ``model.attention_core`` (the CPU's route:
+    the transpose of ``blocked_causal_attention``'s own ops; the kernels'
+    ``custom_vjp`` rule, traced outside the forward's scopes, enters both
+    labels itself: ``tests/test_round_matrix_once.py`` reads it off the
+    texts compiled for the TPU)."""
+    _, _, ops, _ = step_text
+    backward = [name for _, name in ops if "round.segment_bwd" in name
+                and "model.attention_core" in name]
+    assert backward and all(part_of(name) == "model.attention_core" for name in backward)
+    assert any("transpose(" in name and name.endswith("dot_general") for name in backward)
+
+
+# -- the benchmark's readers of the three labels, on a toy step's text ---------------
+
+ATTENTION_READERS = ["attention_proj_device_ms", "rotary_device_ms", "attention_core_device_ms",
+                     "attention_wrap_device_ms", "attention_rest_device_ms", "attention_moved_mb"]
+
+
+class _Traced:
+    """What a reader touches of a ``harness.Ctx``, for a text with no trace:
+    every instruction owns a microsecond of one execution, and the step holds
+    no kernel (the CPU's route)."""
+
+    def __init__(self, text):
+        instructions = scope_paths.read_text(text)
+        owned = {name: 1000.0 for name, ins in instructions.items() if ins["paths"]}
+        self.outcome = {"compiled_text": text, "measured": {
+            "step_module": "train_step", "scope_paths_text": instructions,
+            "scope_paths": {"instructions": instructions, "owned": [[owned]]},
+            "scope_join": {"kernel_ms": {}}, "scope_parts_executions": {}}}
+
+    def say(self, **facts):
+        pass
+
+
+def _read(name, ctx):
+    return harness.load_by_path(
+        os.path.join(harness.HERE, "layer_metrics", name + ".train.py"), name + ".train").read(ctx)
+
+
+@pytest.fixture(scope="module")
+def read_by_the_readers(compiled_text):
+    """``(model, reader -> its value on the step's text, reader -> its value
+    on the text with the three labels taken out: the parent's)``, and the part
+    ``model.mla_latent`` of both."""
+    model, _, text = compiled_text
+    parent = text
+    for label in INSIDE_ATTENTION:
+        parent = parent.replace(label, "")
+    sides = []
+    for side in (text, parent):
+        ctx = _Traced(side)
+        values = {name: _read(name, ctx) for name in ATTENTION_READERS}
+        values["model.mla_latent"] = scope_parts.part_ms(ctx, "model.mla_latent")
+        sides.append(values)
+    return (model, *sides)
+
+
+@pytest.mark.parametrize("reader", ATTENTION_READERS)
+def test_each_reader_reads_its_label_off_a_toy_steps_text(read_by_the_readers, reader):
+    model, change, parent = read_by_the_readers
+    value = change[reader]
+    if reader == "attention_wrap_device_ms":  # no kernel in the CPU's step
+        assert value is None and parent[reader] is None
+    elif reader == "attention_moved_mb":
+        # what a path HOLDS: the same number with and without the labels inside
+        # (0 where the CPU's compiler fused every move into its consumer; the
+        # texts compiled for the TPU, tests/test_round_matrix_once.py, hold some)
+        assert value is not None and value >= 0 and value == parent[reader]
+    elif reader == "attention_rest_device_ms":
+        # (0 where the three labels name all there is: the toy GLM's attention)
+        assert value is not None and 0 <= value < parent[reader]
+        assert value > 0 or model == "glm"
+    elif reader == "rotary_device_ms" and model == "nemotron":
+        assert value is None and parent[reader] is None
+    else:
+        assert value is not None and value > 0 and parent[reader] is None
+
+
+def test_the_new_parts_and_the_remainder_add_up_to_the_parents_part(read_by_the_readers):
+    """With a turn inside the latents (GLM, Xing) the projections, the core
+    and the remainder are the parent's ``model.attention`` and the turn is
+    what ``model.mla_latent`` lost; elsewhere all four are the parent's."""
+    model, change, parent = read_by_the_readers
+    inside = sum(change[name] or 0.0 for name in (
+        "attention_proj_device_ms", "attention_core_device_ms", "attention_rest_device_ms"))
+    turn = change["rotary_device_ms"] or 0.0
+    if model in ("glm", "xing"):
+        assert inside == pytest.approx(parent["attention_rest_device_ms"], rel=1e-9)
+        assert change["model.mla_latent"] + turn == pytest.approx(
+            parent["model.mla_latent"], rel=1e-9)
+    else:
+        assert inside + turn == pytest.approx(parent["attention_rest_device_ms"], rel=1e-9)
 
 
 def test_round_fwdbwd_is_still_the_innermost_round_scope(step_text):
@@ -475,7 +635,7 @@ def test_the_toy_streamed_steps_lower_to_the_text_they_had_in_the_rows_order(mod
 NEW_SCOPES = ["model.norm", "model.embed", "model.head", "model.ssm_proj", "model.ssm_gate",
               "model.mlp", "model.moe_shared", "model.mtp_join", "stream.rows", "stream.boundary",
               "model.delta_rule", "model.hc_maps", "model.hc_mix", "model.short_conv",
-              "model.short_conv_proj", "stream.shared_rows"]
+              "model.short_conv_proj", "stream.shared_rows", *INSIDE_ATTENTION]
 
 
 @pytest.mark.parametrize("scope", NEW_SCOPES)

@@ -1148,6 +1148,80 @@ def test_on_the_tpu_seven_heads_a_group_are_three_kernels_windowed_or_causal(tpu
     assert not re.search(r"\[(?:\d+,)*%d,(?:%d|4096)\]" % (t, t), text)
 
 
+# the attention texts above: name -> whether the call turns by position
+ATTENTION_TEXTS = {
+    "attention_float32": False, "attention_bfloat16": False, "mla_attention_float32": True,
+    "mla_attention_192_128_float32": True, "attention_64_float32": True,
+    "attention_64_bfloat16": True, "attention_7_window_float32": True,
+    "attention_7_window_bfloat16": True, "attention_7_global_float32": False}
+INSIDE_ATTENTION = ("model.attention_proj", "model.rotary", "model.attention_core")
+_WHOLE_ATTENTION = re.compile(r"model\.attention(?!_)")  # the label itself, not its prefix
+
+
+def _op_names(text):
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+@pytest.mark.parametrize("name", sorted(ATTENTION_TEXTS))
+def test_on_the_tpu_the_kernels_and_their_own_backward_rule_stand_in_the_core(tpu_texts, name):
+    """The three calls hold ``model.attention_core`` inside
+    ``model.attention``; so does what ``_causal_attention_bwd`` (a
+    ``custom_vjp``'s rule, traced outside the forward's scopes, which enters
+    both labels itself) puts in front of its two calls: the ``delta``
+    row-sum's multiply and reduction. The products with ``w_q``, ``w_k``,
+    ``w_v`` and ``w_o`` hold ``model.attention_proj`` and no kernel does;
+    ``model.rotary`` is there where the call turns by position, and inside
+    ``model.mla_latent`` where the turn is the latents'."""
+    text = tpu_texts[name]
+    calls = _attention_calls(text)
+    assert len(calls) == 3
+    for line in calls.values():
+        path = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert "model.attention_core" in path and _WHOLE_ATTENTION.search(path)
+        assert "model.attention_proj" not in path and "model.rotary" not in path
+    names = _op_names(text)
+    # the rule's own ops: the labels entered by hand, one inside the other
+    own = [path for path in names if "model.attention/model.attention_core/" in path]
+    assert own and all("transpose(" in path for path in own)
+    assert any(path.endswith("model.attention_core/reduce_sum") for path in own)
+    assert any("_attention_dq/" in path for path in own)
+    assert any("_attention_dkv/" in path for path in own)
+    products = [path for path in names if path.endswith("dot_general")]
+    proj = [path for path in products if "model.attention_proj" in path]
+    assert len(proj) >= 4 and all(_WHOLE_ATTENTION.search(path) for path in proj)
+    turned = [path for path in names if "model.rotary" in path]
+    assert bool(turned) == ATTENTION_TEXTS[name]
+    if name.startswith("mla_"):
+        assert turned and all("model.mla_latent" in path for path in turned)
+        assert [path for path in products if "model.mla_latent" in path
+                and "model.attention_proj" not in path]
+    # nothing of the three outside model.attention
+    assert all(_WHOLE_ATTENTION.search(path) for path in names
+               if any(label in path for label in INSIDE_ATTENTION))
+
+
+@pytest.mark.parametrize("name", sorted(ATTENTION_TEXTS))
+def test_on_the_tpu_what_attention_moves_reads_the_same_with_and_without_the_labels(tpu_texts,
+                                                                                    name):
+    """``attention_moved_mb.train`` on a text compiled for the TPU: a
+    number (the relayouts the compiler left standing under
+    ``model.attention``), the same for the text with the three labels taken
+    out of every ``op_name`` (it asks what a path HOLDS), and at most what
+    every top-level move of the program writes."""
+    text = tpu_texts[name]
+    reader = _benchmark_reader("attention_moved_mb.train")
+    moved = reader.read(SimpleNamespace(outcome={"compiled_text": text}))
+    assert moved is not None and moved > 0
+    parent = text
+    for label in INSIDE_ATTENTION:
+        parent = parent.replace(label + "/", "")
+    assert "model.attention_core" not in parent and "model.attention" in parent
+    assert reader.read(SimpleNamespace(outcome={"compiled_text": parent})) == moved
+    everywhere = reader.read(SimpleNamespace(outcome={"compiled_text": re.sub(
+        r'op_name="[^"]*"', 'op_name="model.attention"', text)}))
+    assert moved <= everywhere
+
+
 # -- the held experts' read-back, compiled by Mosaic ------------------------------
 
 
