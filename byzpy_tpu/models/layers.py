@@ -91,6 +91,22 @@ class YarnScaling:
         return self._mscale(self.factor, self.mscale_all_dim) ** 2
 
 
+def _turn_cos_sin(t: int, dim: int, theta: float, scaling: Optional[YarnScaling]
+                  ) -> Tuple[Array, Array]:
+    """Cosine and sine ``(t, dim / 2)`` float32 of position times pair
+    frequency (``theta ** (-2 i / dim)``, or ``scaling``'s blend), times
+    ``scaling``'s ``rotary_scale``."""
+    if scaling is None:
+        freq = theta ** (-jnp.arange(dim // 2, dtype=jnp.float32) * 2.0 / dim)
+    else:
+        freq = scaling.frequencies(dim, theta)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if scaling is not None and scaling.rotary_scale != 1.0:
+        cos, sin = cos * scaling.rotary_scale, sin * scaling.rotary_scale
+    return cos, sin
+
+
 def rotary(x: Array, theta: float, scaling: Optional[YarnScaling] = None) -> Array:
     """Rotary position embedding of ``x (T, ..., dim)``, position = index
     along the first axis: the pair (``x[..., i]``, ``x[..., i + dim / 2]``)
@@ -105,14 +121,7 @@ def rotary(x: Array, theta: float, scaling: Optional[YarnScaling] = None) -> Arr
     with jax.named_scope("model.rotary"):
         t, dim = x.shape[0], x.shape[-1]
         half = dim // 2
-        if scaling is None:
-            freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dim)
-        else:
-            freq = scaling.frequencies(dim, theta)
-        angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
-        cos, sin = jnp.cos(angle), jnp.sin(angle)
-        if scaling is not None and scaling.rotary_scale != 1.0:
-            cos, sin = cos * scaling.rotary_scale, sin * scaling.rotary_scale
+        cos, sin = _turn_cos_sin(t, dim, theta, scaling)
         # turn[t, out, in, i]: out = 0 reads (cos, -sin) of (a, b), out = 1 (sin, cos)
         turn = jnp.stack([jnp.stack([cos, -sin], axis=1), jnp.stack([sin, cos], axis=1)], axis=1)
         turn = turn.reshape(t, *(1,) * (x.ndim - 2), 2, 2, half).astype(x.dtype)
@@ -290,7 +299,33 @@ def mla_attention(p: Dict[str, Array], x: Array, cfg: Any) -> Array:
     lanes, zero columns behind the rotary part where ``nope + rope`` is not
     a multiple of 128, and the values at their own width, never padded),
     :func:`blocked_causal_attention` elsewhere (the CPU's route; it takes
-    one head size, so there the narrower side is padded)."""
+    one head size, so there the narrower side is padded).
+
+    The kernels read ``q, k (T, heads * width)`` and ``v (T, heads *
+    v_head_dim)``, rows of whole heads side by side. Two forms make them,
+    the same numbers, and :func:`_rows_route_pays` picks one from the
+    shapes alone (``T``, the two latents' ranks, the zero lanes a head):
+
+    * BORN IN ROWS (:func:`_latent_qkv_rows`) where the heads are whole
+      lanes and the sequence is three times as long as the latents have
+      rows: every head's cut, zero pad and rotary swap is made on the
+      weights' columns, and no activation takes a third axis.
+    * CUT ON THREE AXES (the lines below) elsewhere: the up-projections'
+      results reshaped to ``(T, heads, width)``, sliced, turned, joined and
+      reshaped back. The TPU's compiler lays such an array out with the
+      positions on the lanes, so each slice, join and reshape is a pass over
+      it, and each one's way back a pad, a select and an ``add_any``.
+
+    What decides (PERF.md, PR 54: ``jax.vjp`` of this function under
+    ``vmap`` over one sequence on a v5e, forward and backward, the kernels'
+    5.5 ms among them): at GLM-4.7-Flash's cell (4096 positions, latents of
+    768 + 512 rows, 20 heads of 256 / 256) the three axes read 12.27 ms and
+    the rows 10.77; at 2048 positions 4.47 and 4.67; at Xing4.0's cell
+    (1024 positions, the same latents, 32 heads of 192 padded to 256 / 128)
+    2.76 and 3.54, and with those heads at 4096 positions 16.93 and 16.61:
+    a sequence barely longer than the latents spares little, and 64 zero
+    lanes a head add a third to the queries' products and double the
+    keys'."""
     with jax.named_scope("model.attention"):
         t = x.shape[0]
         heads, nope, rope, vd = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
@@ -299,6 +334,10 @@ def mla_attention(p: Dict[str, Array], x: Array, cfg: Any) -> Array:
         stretch = 1.0 if scaling is None else scaling.softmax_scale
         lanes = -(nope + rope) % 128  # zero columns up to whole lanes
         serves = causal_attention_serves(x, nope + rope + lanes, vd)
+        if serves and _rows_route_pays(t, p["w_qb"].shape[0], p["w_kvb"].shape[0], lanes):
+            q, k, v = _latent_qkv_rows(p, x, cfg, lanes)
+            out = causal_attention(q, k, v, kv_heads=heads, scale=stretch / math.sqrt(nope + rope))
+            return attention_proj(out, p["w_o"])
         with jax.named_scope("model.mla_latent"):
             w = {name: p[name].astype(x.dtype)
                  for name in ("w_qa", "w_qb", "w_kva", "w_kr", "w_kvb")}
@@ -327,6 +366,106 @@ def mla_attention(p: Dict[str, Array], x: Array, cfg: Any) -> Array:
             out = blocked_causal_attention(q[:, :, None, :], k, v, cfg.query_block)
             out = out.reshape(t, heads, width)[..., :vd].reshape(t, heads * vd)
         return attention_proj(out, p["w_o"])
+
+
+def _rows_route_pays(t: int, q_rank: int, kv_rank: int, lanes: int) -> bool:
+    """Does :func:`mla_attention` hand the kernels q, k and v born in rows
+    (:func:`_latent_qkv_rows`)? That form spares passes over the ``t`` rows
+    of the activations and pays with passes over the ``q_rank + kv_rank``
+    rows of the up-projections' weights and a second up-projection of the
+    queries: it wants a sequence three times as long as the latents have
+    rows, and heads of whole lanes, because ``lanes`` zero columns a head
+    would ride on five of its products (:func:`mla_attention` has the
+    readings both ways)."""
+    return not lanes and t >= 3 * (q_rank + kv_rank)
+
+
+_GROUP_LANES = 1024  # the widest block of heads one product of q or k writes
+
+
+def _latent_qkv_rows(p: Dict[str, Array], x: Array, cfg: Any, lanes: int
+                     ) -> Tuple[Array, Array, Array]:
+    """Latent attention's ``q, k (T, heads * width)`` and ``v (T, heads *
+    v_head_dim)`` in the rows :func:`~byzpy_tpu.ops.pallas_attention.
+    causal_attention` reads, ``width = nope + rope + lanes``: no activation
+    takes a third axis on the way. What the three-axis form cuts, pads and
+    joins a position is cut, padded and joined here on the WEIGHTS' columns,
+    a head at a time (768 or 512 rows, once a call; the gradients come back
+    through the same small cuts):
+
+    * ``v = c_kv @ w_v``, ``w_v`` the value columns of ``w_kvb``;
+    * ``k = c_kv @ w_k + k_r``: ``w_k`` the no-position columns of
+      ``w_kvb`` with zero columns in every head's rotary and blank places,
+      ``k_r`` the turned shared key at one head's width, zero elsewhere,
+      side by side for the heads (adding an exact zero is exact);
+    * ``q = (c_q @ w_q) * C + (c_q @ w_q') * S``: ``w_q'`` is ``w_q`` with
+      each rotary pair's halves swapped and the other columns zero; ``C`` is
+      1 on the no-position columns and the cosine on the rotary ones, ``S``
+      0, minus the sine on a pair's first half and the sine on its second:
+      :func:`rotary`'s 2 x 2 turn, the same two products and one sum an
+      element in float32, written on two axes.
+
+    q and k are made a GROUP of heads a product (as many whole heads as
+    ``_GROUP_LANES`` hold: four of 256), each group's block written where
+    the kernels read it. ``C``, ``S`` and ``k_r`` are ``(T, width)``; a
+    product reads them laid side by side for its heads, and the compiler
+    writes that out in full: over all twenty heads at once three chains of
+    twenty updates of ``(T, 5120)`` a forward (17.8 ms of the GLM step) and
+    84 MB of table beside each query product's operands; a head at a time
+    nothing to lay out, and sixty products a call to trace and compile.
+    The layer's forward and backward alone on a v5e at GLM's sizes (PERF.md,
+    PR 54): 11.13 ms a head at a time, 10.91 two, 10.77 four, 12.22 ten,
+    11.50 all twenty (the three-axis form 12.27).
+
+    The tables and the cut weights pass an ``optimization_barrier``: the
+    tables so that the cosines are computed once a call and not again
+    inside every product that reads them, the weights so that the compiler
+    does not pull the heads' axis back through the products (under ``vmap``
+    it did, and transposed four cotangents of ``T`` rows to do so)."""
+    t = x.shape[0]
+    heads, nope, rope, vd = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                             cfg.qk_rope_head_dim, cfg.v_head_dim)
+    half, width = rope // 2, nope + rope + lanes
+    per = max(n for n in range(1, heads + 1)
+              if heads % n == 0 and (n == 1 or n * width <= _GROUP_LANES))
+
+    def grouped(w, before, behind):  # (rank, heads, n) -> (groups, rank, per * (before + n + behind))
+        w = jnp.pad(w, ((0, 0), (0, 0), (before, behind)))
+        return jnp.swapaxes(w.reshape(w.shape[0], heads // per, -1), 0, 1)
+
+    def swapped(w):  # a rotary pair's two halves change places, along the last axis
+        return jnp.concatenate([w[..., half:], w[..., :half]], axis=-1)
+
+    def side_by_side(blocks):  # whole-lane blocks of T rows, written in place
+        return jnp.concatenate(blocks, axis=1)
+
+    with jax.named_scope("model.mla_latent"):
+        w = {name: p[name].astype(x.dtype)
+             for name in ("w_qa", "w_qb", "w_kva", "w_kr", "w_kvb")}
+        with jax.named_scope("model.rotary"):
+            cos, sin = _turn_cos_sin(t, rope, cfg.rope_theta, cfg.rope_scaling)
+            around = ((0, 0), (nope, lanes))
+            tables = (jnp.pad(jnp.concatenate([cos, cos], axis=1), around, constant_values=1.0),
+                      jnp.pad(jnp.concatenate([-sin, sin], axis=1), around))
+            cos, sin = jax.lax.optimization_barrier(tuple(a.astype(x.dtype) for a in tables))
+        w_qb = w["w_qb"].reshape(-1, heads, nope + rope)
+        w_kvb = w["w_kvb"].reshape(-1, heads, nope + vd)
+        w_kr = jnp.pad(w["w_kr"], ((0, 0), (nope, lanes)))
+        w_q, w_q_swapped, w_k, w_v, w_kr, w_kr_swapped = jax.lax.optimization_barrier((
+            grouped(w_qb, 0, lanes), grouped(swapped(w_qb[..., nope:]), nope, lanes),
+            grouped(w_kvb[..., :nope], 0, rope + lanes),
+            w_kvb[..., nope:].reshape(w_kvb.shape[0], -1),
+            w_kr, jnp.pad(swapped(w["w_kr"]), ((0, 0), (nope, lanes)))))
+        c_q = rms_norm(x @ w["w_qa"], p["q_norm_scale"], cfg.rms_norm_eps)
+        c_kv = rms_norm(x @ w["w_kva"], p["kv_norm_scale"], cfg.rms_norm_eps)
+        with jax.named_scope("model.rotary"):
+            k_rope = side_by_side([(x @ w_kr) * cos + (x @ w_kr_swapped) * sin] * per)
+            cos, sin = side_by_side([cos] * per), side_by_side([sin] * per)
+            q = side_by_side([(c_q @ w_q[g]) * cos + (c_q @ w_q_swapped[g]) * sin
+                              for g in range(heads // per)])
+        k = side_by_side([c_kv @ w_k[g] + k_rope for g in range(heads // per)])
+        v = c_kv @ w_v
+    return q, k, v
 
 
 __all__ = [

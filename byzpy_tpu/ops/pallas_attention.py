@@ -173,8 +173,10 @@ def causal_attention_serves(x: Array, head_dim: int, v_head_dim: Optional[int] =
     blocks. Asked once a call, in Python, by ``models.nemotron_h.
     gqa_attention`` (sixteen query heads a key/value head of 128),
     ``models.layers.mla_attention`` (one query head a key/value head:
-    GLM-4.7-Flash's 256 / 256, and 192 / 128 with the queries and keys
-    padded to 256), ``models.qwen3_next.gated_attention`` (eight of 256)
+    GLM-4.7-Flash's 256 / 256, whose q, k and v are born in these kernels'
+    rows where the sequence is long against the latents, and 192 / 128
+    with the queries and keys padded to 256, cut and joined on three
+    axes), ``models.qwen3_next.gated_attention`` (eight of 256)
     ``models.lfm2_moe.gqa_attention`` (four of 64, two key/value heads a
     tile) and ``models.smallthinker.attention`` (seven of 128, with a window
     in six blocks of eight; :func:`_blocks` has what each regime measured);

@@ -9,6 +9,7 @@ leaves; the kernels at one query head a group of 256."""
 
 from __future__ import annotations
 
+import functools
 from dataclasses import replace
 
 import jax
@@ -268,3 +269,98 @@ def test_latent_attention_by_the_kernels_is_the_map_route(monkeypatch):
         _close(y, y_ref, tol=1e-4)
         for got, want in zip(jax.tree_util.tree_leaves(g), jax.tree_util.tree_leaves(g_ref)):
             _close(got, want, tol=5e-4)
+
+
+# -- the kernels' route in two forms: q, k and v cut on the activations, or born in rows ----
+
+MLA_LEAVES = ("w_qa", "q_norm_scale", "w_qb", "w_kva", "w_kr", "kv_norm_scale", "w_kvb", "w_o")
+LONG = 140  # positions, against latents of 16 and 12 rows
+
+
+def _forms_config(yarn):
+    """Whole-lane heads (96 + 32 / 128, nothing padded), the GLM cell's regime."""
+    scaling = layers.YarnScaling(factor=8.0, original_max_position_embeddings=32, mscale=2.0,
+                                 mscale_all_dim=1.0) if yarn else None
+    return replace(TINY, qk_nope_head_dim=96, qk_rope_head_dim=32, v_head_dim=128,
+                   num_attention_heads=2, rope_scaling=scaling)
+
+
+@functools.lru_cache(maxsize=None)
+def _two_forms(yarn):
+    """``{rows: (output, {leaf: gradient})}`` of latent attention by the
+    kernels, the form forced: cut on three axes (``rows`` False, the plain
+    reference here) and born in the kernels' rows."""
+    cfg = _forms_config(yarn)
+    block = _seeded_bundle(cfg, 5).params["seg02_moe"]
+    p = {name: block[name] for name in MLA_LEAVES}
+    x = jax.random.normal(jax.random.PRNGKey(8), (LONG, cfg.hidden_size))
+    probe = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    forms = {}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(layers, "causal_attention_serves", lambda x_, hd, vd: True)
+        for rows in (False, True):
+            patch.setattr(layers, "_rows_route_pays", lambda *sizes, rows=rows: rows)
+            grads, dx = jax.grad(lambda p_, x_: jnp.sum(glm.mla_attention(p_, x_, cfg) * probe),
+                                 argnums=(0, 1))(p, x)
+            forms[rows] = (glm.mla_attention(p, x, cfg), {**grads, "x": dx})
+    return forms
+
+
+@pytest.mark.parametrize("what", ["output", "x", *MLA_LEAVES])
+@pytest.mark.parametrize("yarn", [False, True], ids=["plain", "yarn"])
+def test_whole_lane_heads_born_in_rows_are_the_three_axis_form(yarn, what):
+    forms = _two_forms(yarn)
+    got, want = (forms[rows][0] if what == "output" else forms[rows][1][what]
+                 for rows in (True, False))
+    assert float(jnp.max(jnp.abs(want))) > 0
+    _close(got, want, tol=5e-6)
+
+
+def _rows_of_activations(fn, p, x):
+    """Shapes of three or more axes that start with the sequence's length
+    among what ``fn`` computes outside the attention core."""
+    def shapes(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name.startswith("custom_vjp"):
+                continue  # the kernels' call
+            for name in ("jaxpr", "call_jaxpr"):
+                if name in eqn.params:
+                    inner = eqn.params[name]
+                    yield from shapes(getattr(inner, "jaxpr", inner))
+            for var in eqn.outvars:
+                yield var.aval.shape
+
+    return {s for s in shapes(jax.make_jaxpr(lambda p_, x_: fn(p_, x_))(p, x).jaxpr)
+            if len(s) >= 3 and s[0] == x.shape[0]}
+
+
+def test_born_in_rows_no_activation_takes_a_third_axis(monkeypatch):
+    cfg = _forms_config(False)
+    p = _seeded_bundle(cfg, 5).params["seg02_moe"]
+    x = jax.random.normal(jax.random.PRNGKey(8), (LONG, cfg.hidden_size))
+    monkeypatch.setattr(layers, "causal_attention_serves", lambda x_, hd, vd: True)
+    asked = []
+    monkeypatch.setattr(layers, "_rows_route_pays", lambda *sizes: asked.append(sizes) or True)
+    assert _rows_of_activations(lambda p_, x_: glm.mla_attention(p_, x_, cfg), p, x) == set()
+    # the rule is handed the sequence, both latents' rows and the zero lanes a head
+    assert asked == [(LONG, cfg.q_lora_rank, cfg.kv_lora_rank, 0)]
+    monkeypatch.setattr(layers, "_rows_route_pays", lambda *sizes: False)
+    assert (LONG, 2, 128) in _rows_of_activations(
+        lambda p_, x_: glm.mla_attention(p_, x_, cfg), p, x)
+
+
+@pytest.mark.parametrize("sizes, pays", [
+    ((4096, 768, 512, 0), True),    # the GLM cell
+    ((1024, 768, 512, 64), False),  # the Xing4.0 cell
+    ((2048, 768, 512, 0), False), ((3840, 768, 512, 0), True),
+    ((4096, 768, 512, 64), False), ((8192, 768, 512, 64), False)])
+def test_the_rule_takes_the_rows_at_whole_lane_heads_and_a_sequence_long_against_the_latents(
+        sizes, pays):
+    assert layers._rows_route_pays(*sizes) is pays
+
+
+def test_the_cpu_never_asks_the_rule(monkeypatch):
+    monkeypatch.setattr(layers, "_rows_route_pays", lambda *sizes: pytest.fail("asked"))
+    cfg = _forms_config(False)
+    p = _seeded_bundle(cfg, 5).params["seg02_moe"]
+    glm.mla_attention(p, jnp.ones((16, cfg.hidden_size)), cfg)

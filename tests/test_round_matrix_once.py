@@ -18,6 +18,7 @@ against the layout's order, case for case.
 
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import os
 import re
@@ -674,6 +675,22 @@ def _streamed_toy():
 # heads' width (4096) nor a block's, a hidden size that is no block's
 ATTENTION_TOKENS, ATTENTION_HIDDEN = 2048, 384
 TWO_WIDTH_TOKENS = 1024
+GLM_CELL_TOKENS = 4096
+
+
+def _latent_attention_lowered(module, cfg, tokens, one_chip):
+    """Value and gradient of ``layers.mla_attention`` under ``vmap`` over
+    one sequence of ``tokens`` positions, on ``module``'s weights of an
+    expert block at ``cfg``'s sizes, lowered for ``one_chip``."""
+    from byzpy_tpu.models import layers
+
+    shapes = jax.eval_shape(lambda: module.init_params(cfg)["seg02_moe"])
+    return jax.jit(jax.value_and_grad(
+        lambda p, xs: jnp.sum(jax.vmap(lambda s: layers.mla_attention(p, s, cfg))(xs)),
+        argnums=(0, 1))).lower(
+        jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes),
+        jax.ShapeDtypeStruct((1, tokens, cfg.hidden_size), jnp.float32, sharding=one_chip))
 
 
 def _write_tpu_texts(out_dir):
@@ -693,6 +710,19 @@ def _write_tpu_texts(out_dir):
     pallas_kernels._resolve_interpret = lambda interpret: False
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     one_chip = SingleDeviceSharding(topo.devices[0])
+
+    # FIRST, before this process has traced anything (what a lowered text calls
+    # and what it inlines depends on that): latent attention at the Xing4.0
+    # cell's own sizes as LOWERED, the kernels' bodies decoded (they hold file
+    # names): there the rule keeps the three-axis form
+    import round_texts
+    from byzpy_tpu.models import xing4
+
+    lowered = _latent_attention_lowered(xing4, xing4.Xing4Config(), TWO_WIDTH_TOKENS, one_chip)
+    with open(os.path.join(out_dir, "mla_attention_xing_cell_float32.hlo.txt"), "w",
+              encoding="utf-8") as fh:
+        fh.write(round_texts._decoded(lowered.as_text()))
+
     toy = mnist_mlp(0, hidden=16)
 
     def described(tree):
@@ -803,6 +833,16 @@ def _write_tpu_texts(out_dir):
               encoding="utf-8") as fh:
         fh.write(text)
 
+    # latent attention at the GLM cell's OWN sizes (hidden size and sequence as
+    # `chipbench/configs/` has them), where the rule takes q, k and v born in
+    # the kernels' rows
+    cell = glm4_moe_lite.Glm4MoeLiteConfig()
+    text = _latent_attention_lowered(glm4_moe_lite, cell, GLM_CELL_TOKENS,
+                                     one_chip).compile().as_text()
+    with open(os.path.join(out_dir, "mla_attention_glm_cell_float32.hlo.txt"), "w",
+              encoding="utf-8") as fh:
+        fh.write(text)
+
     # and at heads NARROWER than a lane tile (32 query / 8 key-value heads of 64,
     # per-head norms and the rotary turn in front, the LFM2 cell's heads):
     # nothing padded, two key/value heads a tile
@@ -895,6 +935,7 @@ def tpu_texts(tmp_path_factory):
     texts = {}
     for name in [*FOLDED_ROUNDS, "streamed_update", "attention_float32", "attention_bfloat16",
                  "mla_attention_float32", "mla_attention_192_128_float32",
+                 "mla_attention_glm_cell_float32", "mla_attention_xing_cell_float32",
                  "attention_64_float32", "attention_64_bfloat16",
                  "attention_7_window_float32", "attention_7_window_bfloat16",
                  "attention_7_global_float32",
@@ -1092,6 +1133,64 @@ def test_on_the_tpu_latent_attention_of_192_and_128_pads_its_keys_and_never_its_
     assert f"f32[32,1,{t}]" in forward.partition(" custom-call(")[0]
     reader = _benchmark_reader("attention_kernel_calls.train")
     assert reader.read(SimpleNamespace(outcome={"compiled_text": text})) == 3
+
+
+def _instructions(text):
+    """``(opcode, shape, op_name)`` of every instruction of a compiled text
+    that has an ``op_name``."""
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if m and name:
+            yield m.group(2), tuple(int(d) for d in m.group(1).split(",") if d), name.group(1)
+
+
+@pytest.mark.parametrize("opcode", ["copy", "slice", "concatenate", "pad"])
+def test_at_the_glm_cells_sizes_no_activation_is_cut_or_joined_on_three_axes(tpu_texts, opcode):
+    """q, k and v are born ``(T, 20 x 256)`` in the kernels' rows: outside
+    the attention core no ``copy``, ``slice``, ``concatenate`` or ``pad``
+    under ``model.attention`` writes an array of three or more axes with
+    the sequence's 4096 rows (a leading axis of one is ``vmap``'s), and no
+    ``copy`` stands at ``model.attention/reshape``, where the caller's
+    ``q.reshape(t, -1)`` stood. What is cut, padded and joined has the
+    latents' 768 or 512 rows."""
+    text = tpu_texts["mla_attention_glm_cell_float32"]
+    outside = [(op, shape, name) for op, shape, name in _instructions(text)
+               if _WHOLE_ATTENTION.search(name) and "model.attention_core" not in name]
+    assert any(op == "fusion" and GLM_CELL_TOKENS in shape for op, shape, _ in outside)
+    found = [(shape, name) for op, shape, name in outside
+             if op == opcode and GLM_CELL_TOKENS in shape
+             and len([d for d in shape if d != 1]) >= 3]
+    assert not found, found
+    if opcode == "copy":
+        assert not [name for op, _, name in _instructions(text)
+                    if op == "copy" and name.endswith("model.attention/reshape")]
+    else:  # the cuts, pads and joins are there, on the weights
+        assert any(op in (opcode, "fusion") and shape[0] in (768, 512) and len(shape) == 3
+                   for op, shape, _ in outside)
+
+
+def test_at_the_glm_cells_sizes_the_labels_and_the_three_kernels_stand(tpu_texts):
+    text = tpu_texts["mla_attention_glm_cell_float32"]
+    calls = _attention_calls(text)
+    assert sorted(calls) == ["causal_attention_dkv", "causal_attention_dq",
+                             "causal_attention_fwd"]
+    for line in calls.values():
+        assert line.count(f"f32[{GLM_CELL_TOKENS},5120]") >= 3
+    names = _op_names(text)
+    for label in ("model.mla_latent/model.rotary", "model.mla_latent", "model.attention_proj",
+                  "model.attention_core"):
+        assert any(label in name for name in names), label
+
+
+# sha256 of the Xing4.0 cell's latent attention as LOWERED (kernel bodies
+# decoded), taken on the commit before the rows' route (986fa54)
+XING_CELL_LOWERED = "62eaa75b9ee444a06de98fed6b831748a2ad5c60e42547eb5826a7efd789d531"
+
+
+def test_at_the_xing_cells_sizes_the_rule_keeps_the_lowered_text_it_had(tpu_texts):
+    text = tpu_texts["mla_attention_xing_cell_float32"]
+    assert hashlib.sha256(text.encode()).hexdigest() == XING_CELL_LOWERED
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])

@@ -9,6 +9,7 @@ tie to the uncut layer of 64; the streamed round is the (n, d) round."""
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import replace
 from functools import partial
@@ -221,6 +222,67 @@ def test_latent_attention_by_the_kernels_pads_queries_and_keys_and_never_the_val
         for name in MLA_LEAVES:
             _close(g[0][name], g_ref[0][name], tol=5e-4)
         _close(g[1], g_ref[1], tol=5e-4)
+
+
+SHORT = 40  # positions: barely longer than the latents' 16 + 12 rows
+
+
+@functools.lru_cache(maxsize=None)
+def _two_forms(yarn):
+    """``{rows: (output, {leaf: gradient})}`` of latent attention by the
+    kernels at padded heads (128 + 64 and 64 zero lanes / 128) over a short
+    sequence, the Xing4.0 cell's regime, the form forced: cut on three axes
+    (``rows`` False, what the rule keeps there and the plain reference
+    here) and born in the kernels' rows."""
+    cfg = replace(TINY, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128)
+    if not yarn:
+        cfg = replace(cfg, rope_scaling=None)
+    block = _seeded_bundle(cfg, 5).params["seg02_moe"]
+    p = {name: block[name] for name in MLA_LEAVES}
+    x = jax.random.normal(jax.random.PRNGKey(8), (SHORT, cfg.hidden_size))
+    probe = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    forms = {}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(layers, "causal_attention_serves", lambda x_, hd, vd: True)
+        for rows in (False, True):
+            patch.setattr(layers, "_rows_route_pays", lambda *sizes, rows=rows: rows)
+            grads, dx = jax.grad(
+                lambda p_, x_: jnp.sum(layers.mla_attention(p_, x_, cfg) * probe),
+                argnums=(0, 1))(p, x)
+            forms[rows] = (layers.mla_attention(p, x, cfg), {**grads, "x": dx})
+    return forms
+
+
+@pytest.mark.parametrize("what", ["output", "x", *MLA_LEAVES])
+@pytest.mark.parametrize("yarn", [True, False], ids=["yarn", "plain"])
+def test_padded_heads_born_in_rows_are_the_three_axis_form(yarn, what):
+    forms = _two_forms(yarn)
+    got, want = (forms[rows][0] if what == "output" else forms[rows][1][what]
+                 for rows in (True, False))
+    assert float(jnp.max(jnp.abs(want))) > 0
+    _close(got, want, tol=5e-6)
+
+
+def test_born_in_rows_the_zero_lanes_are_exact_zeros_on_the_weights_columns(monkeypatch):
+    """What the kernels are handed: q and k at 256 a head with the last 64
+    columns exactly zero, k's rotary columns the ONE shared key in every
+    head, v at its own 128."""
+    cfg = replace(TINY, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128)
+    p = _seeded_bundle(cfg, 5).params["seg02_moe"]
+    x = jax.random.normal(jax.random.PRNGKey(8), (SHORT, cfg.hidden_size))
+    handed = []
+    monkeypatch.setattr(layers, "causal_attention_serves", lambda x_, hd, vd: True)
+    monkeypatch.setattr(layers, "_rows_route_pays", lambda *sizes: True)
+    monkeypatch.setattr(layers, "causal_attention",
+                        lambda q, k, v, **kwargs: handed.append((q, k, v)) or v)
+    layers.mla_attention(p, x, cfg)
+    (q, k, v), = handed
+    assert q.shape == k.shape == (SHORT, 2 * 256) and v.shape == (SHORT, 2 * 128)
+    q, k = q.reshape(SHORT, 2, 256), k.reshape(SHORT, 2, 256)
+    assert not np.any(np.asarray(q[..., 192:])) and not np.any(np.asarray(k[..., 192:]))
+    np.testing.assert_array_equal(np.asarray(k[:, 0, 128:192]), np.asarray(k[:, 1, 128:192]))
+    shared = layers.rotary(x @ p["w_kr"].astype(x.dtype), cfg.rope_theta, cfg.rope_scaling)
+    _close(k[:, 0, 128:192], shared, tol=1e-6)
 
 
 # -- a hyper-connected sublayer ------------------------------------------------------
